@@ -16,12 +16,12 @@ import subprocess
 import sys
 import threading
 import time
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Dict, List, Optional
 
 from . import AGENT_TYPES
-from .._compat import tomllib
 from ..obs import instruments as obs
 
 log = logging.getLogger("aios.spawner")

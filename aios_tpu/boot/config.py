@@ -10,9 +10,8 @@ addresses (handled in aios_tpu.services) and model/runtime knobs.
 from __future__ import annotations
 
 import os
+import tomllib
 from dataclasses import dataclass, field
-
-from .._compat import tomllib
 from pathlib import Path
 from typing import Any, Dict, List
 

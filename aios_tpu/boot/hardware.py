@@ -1,9 +1,11 @@
 """Hardware detection at boot.
 
 Reference parity (initd/src/hardware.rs:37+): CPU/memory/disk discovery from
-/proc and /sys. TPU-specific addition: detects attached TPU chips through
-JAX (deferred import so boot works on hosts without accelerators) — the
-reference's GPU detection has no TPU notion at all.
+/proc and /sys. TPU-specific addition: lists the accelerator device nodes
+WITHOUT opening them — a chip belongs to one process, and that process is
+the runtime service the supervisor is about to spawn, so nothing on the
+boot path may import JAX (the reference's GPU detection has no TPU notion
+at all).
 """
 
 from __future__ import annotations
@@ -23,14 +25,20 @@ class HardwareInfo:
     memory_total_mb: int = 0
     disks: List[Dict] = field(default_factory=list)
     tpu_devices: List[str] = field(default_factory=list)
-    tpu_backend: str = ""
-
-    @property
-    def has_tpu(self) -> bool:
-        return bool(self.tpu_devices)
 
 
-def detect(probe_tpu: bool = True) -> HardwareInfo:
+def tpu_device_nodes() -> List[str]:
+    """Accelerator device nodes a TPU VM exposes (/dev/accel* on the
+    older driver, numbered /dev/vfio groups on the newer one). Listing
+    them opens nothing."""
+    nodes = sorted(str(p) for p in Path("/dev").glob("accel*"))
+    nodes += sorted(
+        str(p) for p in Path("/dev/vfio").glob("[0-9]*")
+    )
+    return nodes
+
+
+def detect() -> HardwareInfo:
     info = HardwareInfo()
     try:
         for line in Path("/proc/cpuinfo").read_text().splitlines():
@@ -50,12 +58,5 @@ def detect(probe_tpu: bool = True) -> HardwareInfo:
         info.disks.append(
             {"mount": part.mountpoint, "total_gb": round(usage.total / 1e9, 1)}
         )
-    if probe_tpu:
-        try:
-            import jax
-
-            info.tpu_devices = [str(d) for d in jax.devices()]
-            info.tpu_backend = jax.default_backend()
-        except Exception:  # no accelerator / no jax — boot proceeds
-            pass
+    info.tpu_devices = tpu_device_nodes()
     return info

@@ -245,9 +245,10 @@ def main() -> int:
     )
     from .hardware import detect
 
+    # the runtime child owns the chip: this process never imports JAX
     hw = detect()
     log.info(
-        "hardware: %d cores, %d MB RAM, TPU=%s",
+        "hardware: %d cores, %d MB RAM, accelerator nodes=%s",
         hw.cpu_threads, hw.memory_total_mb,
         ",".join(hw.tpu_devices) or "none",
     )

@@ -36,7 +36,7 @@ import numpy as np
 
 from . import model, paged, sampling, spec
 from .config import ModelConfig
-from .. import faults
+from .. import backend, faults
 from ..analysis.locks import make_lock
 from ..obs import instruments as obs
 from ..obs import devprof, flightrec
@@ -519,12 +519,7 @@ class TPUEngine:
                 "seq_sharded_cache: the shard_map ragged kernel assumes "
                 "each device holds whole slots' context"
             )
-        on_tpu = False
-        try:
-            on_tpu = jax.default_backend() == "tpu"
-        # aios: waive(silent-except): backend probe at construction — no backend registered means "not TPU", the default already set
-        except Exception:
-            pass
+        on_tpu = backend.on_tpu()
         if shardings is not None and not self.quant_cache and not self.seq_sharded:
             enable = (
                 sharded_attention
@@ -2246,15 +2241,20 @@ class TPUEngine:
         executes — no device state moves, nothing donates — so the whole
         serving surface can warm behind the readiness gate in compile
         time alone. Counts the same compile-event accounting a lazy
-        first dispatch would; if this backend combination cannot AOT-
-        lower the graph, fall back to the lazy instrumented wrapper (the
-        first real dispatch then compiles, visibly)."""
+        first dispatch would. On the TPU a graph that cannot lower or
+        compile (Mosaic rejection, HBM overflow) raises, so LoadModel fails
+        instead of reporting ``ready`` for a model whose first request
+        would die; an intended CPU run keeps the lazy instrumented wrapper
+        (the first real dispatch then compiles, visibly)."""
         if key in store:
             return
         t0 = time.perf_counter()
         try:
             fn = jitfn.lower(*example_args).compile()
-        except Exception:  # noqa: BLE001 - lazy compile still serves
+        except Exception:  # noqa: BLE001 - lazy compile still serves on CPU
+            if backend.on_tpu():
+                log.error("AOT compile failed for %s graph %r", kind, key)
+                raise
             log.exception(
                 "AOT lowering failed for %s graph %r; deferring to "
                 "first-dispatch compile", kind, key,

@@ -108,6 +108,47 @@ def matmul(x: jnp.ndarray, w, qmm=None, kind: str = "col") -> jnp.ndarray:
     return x @ w
 
 
+def _int4_group(key: str, K: int, N: int, tp: int = 1,
+                target: str = "auto") -> int:
+    """Scale-group size for serving weight ``key`` [K, N] as an int4 leaf,
+    or 0 when it must stay int8 — the one eligibility rule shared by
+    ``quantize_params`` and ``init_quantized_params``.
+
+    Under a tp-sharded plan the kernel runs per device on a [K, N/tp]
+    (column-parallel) or [K/tp, N] (row-parallel) shard, so eligibility —
+    and the scale-group size — must hold for the SHARD dims, not the
+    global ones. lm_head shards its vocab like a column projection.
+
+    On TPU a q4 leaf the kernel can't serve would dequantize to bf16 in
+    HBM every step — strictly worse than int8 — so kernel-ineligible dims
+    fall back to int8 there. On an intended CPU run every quantized leaf
+    dequantizes inline anyway, so storage eligibility is enough (keeps
+    tiny test geometries on int4). ``target="tpu"`` forces the strict
+    kernel rule regardless of the local backend — prepare_model uses it so
+    a checkpoint prepared on a CPU build box never bakes in leaves a TPU
+    can only serve through the HBM-dequant path.
+    """
+    from ..ops.int4_matmul import kernel_supported, pick_group, supports_int4
+
+    local_K, local_N = K, N
+    if tp > 1:
+        if key in ("wo", "w_down"):
+            local_K = K // tp if K % tp == 0 else 0
+        else:
+            local_N = N // tp if N % tp == 0 else 0
+    group = pick_group(local_K)
+    eligible = (
+        local_K > 0
+        and local_N > 0
+        and supports_int4(K, N, group)
+        and (
+            kernel_supported(local_K, local_N, group)
+            or (target != "tpu" and not ops.use_pallas())
+        )
+    )
+    return group if eligible else 0
+
+
 def quantize_params(
     params: Params, include_head: bool = True, fuse: bool = True,
     mode: str = "int8", target: str = "auto", tp: int = 1,
@@ -163,45 +204,10 @@ def quantize_params(
         to_quant = tuple((k, src[k]) for k in QUANT_KEYS if k in src)
     def quant_leaf(key, w):
         if mode == "int4" and not key.startswith("we_"):
-            from ..ops.int4_matmul import (
-                kernel_supported,
-                pick_group,
-                quantize_int4,
-                supports_int4,
-            )
+            from ..ops.int4_matmul import quantize_int4
 
-            K, N = w.shape[-2], w.shape[-1]
-            # Under a tp-sharded plan the kernel runs per device on a
-            # [K, N/tp] (column-parallel) or [K/tp, N] (row-parallel)
-            # shard, so eligibility — and the scale-group size — must
-            # hold for the SHARD dims, not the global ones. lm_head
-            # shards its vocab like a column projection.
-            local_K, local_N = K, N
-            if tp > 1:
-                if key in ("wo", "w_down"):
-                    local_K = K // tp if K % tp == 0 else 0
-                else:
-                    local_N = N // tp if N % tp == 0 else 0
-            group = pick_group(local_K)
-            # On TPU a q4 leaf the kernel can't serve would dequantize to
-            # bf16 in HBM every step — strictly worse than int8 — so
-            # kernel-ineligible dims fall back to int8 there. Off-TPU every
-            # quantized leaf dequantizes inline anyway, so storage
-            # eligibility is enough (keeps tiny test geometries on int4).
-            # ``target="tpu"`` forces the strict kernel rule regardless of
-            # the local backend — prepare_model uses it so a checkpoint
-            # prepared on a CPU build box never bakes in leaves a TPU
-            # can only serve through the HBM-dequant path.
-            eligible = (
-                local_K > 0
-                and local_N > 0
-                and supports_int4(K, N, group)
-                and (
-                    kernel_supported(local_K, local_N, group)
-                    or (target != "tpu" and not ops.use_pallas())
-                )
-            )
-            if eligible:
+            group = _int4_group(key, w.shape[-2], w.shape[-1], tp, target)
+            if group:
                 p, s = quantize_int4(w, group=group)
                 return {"q4": p, "s4": s}
         q, s = ops.quantize_int8(w, axis=-2)
@@ -870,6 +876,24 @@ def decode_step(
     return logits, k_cache, v_cache
 
 
+def _scan_layers_over_pool(block, x, layers, k_pool, v_pool, cache_scales):
+    """Run ``block`` over the layer stack with the page pools (and int8
+    scales) as the scan CARRY: layer ``l`` reads and writes
+    ``pool[l, page, row]`` in place. Scanning the pools as xs -> ys instead
+    makes XLA hold a second whole pool while the loop runs (the stacked ys
+    buffer cannot share the donated input's) — on a 16 GB chip that is the
+    difference between Mistral-7B's 4096-row context fitting and not.
+
+    ``block((x, k_pool, v_pool, *scales), (layer_params, l))`` returns the
+    same carry. Returns (x, k_pool, v_pool, scales-or-None)."""
+    L = k_pool.shape[0]
+    carry = (x, k_pool, v_pool) + tuple(cache_scales or ())
+    (x, k_pool, v_pool, *scales), _ = jax.lax.scan(
+        block, carry, (layers, jnp.arange(L))
+    )
+    return x, k_pool, v_pool, tuple(scales) or None
+
+
 def prefill_chunk_paged(
     params: Params,
     cfg: ModelConfig,
@@ -931,23 +955,30 @@ def prefill_chunk_paged(
     t = min(512, C_log)
     kv_tile = t if C_log % t == 0 else P
 
-    def block(x, layer):
-        if quant_pool:
-            lp, k_l, v_l, k_s, v_s = layer
-        else:
-            lp, k_l, v_l = layer
-            k_s = v_s = None
+    def block(carry, layer):
+        x, k_pool, v_pool, *scales = carry
+        lp, l = layer
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
         if quant_pool:
-            k_l, k_s = scatter_quant(k_l, k_s, pages, offs, k_new[0])
-            v_l, v_s = scatter_quant(v_l, v_s, pages, offs, v_new[0])
-            k_all = gather_dequant(k_l, k_s, table_row, q.dtype)[None]
-            v_all = gather_dequant(v_l, v_s, table_row, q.dtype)[None]
+            k_s, v_s = scales
+            k_pool, k_s = scatter_quant(
+                k_pool, k_s, (l, pages, offs), k_new[0]
+            )
+            v_pool, v_s = scatter_quant(
+                v_pool, v_s, (l, pages, offs), v_new[0]
+            )
+            k_all = gather_dequant(k_pool, k_s, table_row, q.dtype, l)[None]
+            v_all = gather_dequant(v_pool, v_s, table_row, q.dtype, l)[None]
+            scales = (k_s, v_s)
         else:
-            k_l = k_l.at[pages, offs].set(k_new[0].astype(k_l.dtype))
-            v_l = v_l.at[pages, offs].set(v_new[0].astype(v_l.dtype))
-            k_all = k_l[table_row].reshape(1, C_log, *k_l.shape[2:])
-            v_all = v_l[table_row].reshape(1, C_log, *v_l.shape[2:])
+            k_pool = k_pool.at[l, pages, offs].set(
+                k_new[0].astype(k_pool.dtype)
+            )
+            v_pool = v_pool.at[l, pages, offs].set(
+                v_new[0].astype(v_pool.dtype)
+            )
+            k_all = k_pool[l, table_row].reshape(1, C_log, *k_pool.shape[3:])
+            v_all = v_pool[l, table_row].reshape(1, C_log, *v_pool.shape[3:])
         attn = blockwise_cache_attention(
             q,
             k_all.astype(q.dtype),
@@ -960,21 +991,14 @@ def prefill_chunk_paged(
         )
         x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
         x = x + _mlp(x, lp, cfg, qmm=qmm)
-        if quant_pool:
-            return x, (k_l, v_l, k_s, v_s)
-        return x, (k_l, v_l)
+        return (x, k_pool, v_pool, *scales), None
 
-    if quant_pool:
-        k_scales, v_scales = cache_scales
-        x, (k_pool, v_pool, k_scales, v_scales) = jax.lax.scan(
-            block, x, (params["layers"], k_pool, v_pool, k_scales, v_scales)
-        )
-        logits = _final_logits(x, params, cfg, qmm)
-        return logits, k_pool, v_pool, (k_scales, v_scales)
-    x, (k_pool, v_pool) = jax.lax.scan(
-        block, x, (params["layers"], k_pool, v_pool)
+    x, k_pool, v_pool, scales = _scan_layers_over_pool(
+        block, x, params["layers"], k_pool, v_pool, cache_scales
     )
     logits = _final_logits(x, params, cfg, qmm)
+    if quant_pool:
+        return logits, k_pool, v_pool, scales
     return logits, k_pool, v_pool
 
 
@@ -1055,67 +1079,73 @@ def decode_step_paged(
     x = params["embed"][tokens][:, None, :]  # [B, 1, E]
     cos, sin = rope_tables(lengths[:, None], cfg.head_dim, cfg.rope_theta)
 
-    def block(x, layer):
-        if quant_pool:
-            lp, k_l, v_l, k_s, v_s = layer
-        else:
-            lp, k_l, v_l = layer
-            k_s = v_s = None
+    def block(carry, layer):
+        x, k_pool, v_pool, *scales = carry
+        lp, l = layer
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
-        if quant_pool and pool_impl is not None:
-            attn, k_l, v_l, k_s, v_s = pool_impl(
-                q[:, 0], k_new[:, 0], v_new[:, 0], k_l, v_l, k_s, v_s,
-                tables, read_lengths, pages, offs,
-            )
+        if pool_impl is not None:
+            # the shard_map twin writes and attends one layer's pool slice
+            if quant_pool:
+                k_s, v_s = scales
+                attn, k_l, v_l, k_sl, v_sl = pool_impl(
+                    q[:, 0], k_new[:, 0], v_new[:, 0], k_pool[l], v_pool[l],
+                    k_s[l], v_s[l], tables, read_lengths, pages, offs,
+                )
+                scales = (k_s.at[l].set(k_sl), v_s.at[l].set(v_sl))
+            else:
+                attn, k_l, v_l = pool_impl(
+                    q[:, 0], k_new[:, 0], v_new[:, 0], k_pool[l], v_pool[l],
+                    tables, read_lengths, pages, offs,
+                )
+            k_pool = k_pool.at[l].set(k_l)
+            v_pool = v_pool.at[l].set(v_l)
             attn = attn[:, None]
         elif quant_pool:
-            k_l, k_s = scatter_quant(k_l, k_s, pages, offs, k_new[:, 0])
-            v_l, v_s = scatter_quant(v_l, v_s, pages, offs, v_new[:, 0])
+            k_s, v_s = scales
+            k_pool, k_s = scatter_quant(
+                k_pool, k_s, (l, pages, offs), k_new[:, 0]
+            )
+            v_pool, v_s = scatter_quant(
+                v_pool, v_s, (l, pages, offs), v_new[:, 0]
+            )
             attn = paged_int8_attend(
-                q[:, 0], k_l, v_l, k_s, v_s, tables, read_lengths,
+                q[:, 0], k_pool[l], v_pool[l], k_s[l], v_s[l], tables,
+                read_lengths,
                 window=cfg.sliding_window,
                 use_int8_kernel=use_int8_kernel,
                 win_starts=win_starts, sink=sink_rows,
             )[:, None]
-        elif pool_impl is not None:
-            attn, k_l, v_l = pool_impl(
-                q[:, 0], k_new[:, 0], v_new[:, 0], k_l, v_l, tables,
-                read_lengths, pages, offs,
-            )
-            attn = attn[:, None]
+            scales = (k_s, v_s)
         else:
-            k_l = k_l.at[pages, offs].set(k_new[:, 0].astype(k_l.dtype))
-            v_l = v_l.at[pages, offs].set(v_new[:, 0].astype(v_l.dtype))
+            k_pool = k_pool.at[l, pages, offs].set(
+                k_new[:, 0].astype(k_pool.dtype)
+            )
+            v_pool = v_pool.at[l, pages, offs].set(
+                v_new[:, 0].astype(v_pool.dtype)
+            )
             if use_kernel:
                 attn = ops.paged_decode_attention(
-                    q[:, 0], k_l, v_l, tables, read_lengths,
+                    q[:, 0], k_pool[l], v_pool[l], tables, read_lengths,
                     window=cfg.sliding_window,
                     win_starts=win_starts,
                     sink=sink_rows if win_starts is not None else None,
                 )[:, None]
             else:
                 attn = ops.paged_decode_attention_reference(
-                    q[:, 0], k_l, v_l, tables, read_lengths,
+                    q[:, 0], k_pool[l], v_pool[l], tables, read_lengths,
                     window=cfg.sliding_window,
                     win_starts=win_starts, sink=sink_rows,
                 )[:, None]
         x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
         x = x + _mlp(x, lp, cfg, moe_impl, qmm)
-        if quant_pool:
-            return x, (k_l, v_l, k_s, v_s)
-        return x, (k_l, v_l)
+        return (x, k_pool, v_pool, *scales), None
 
-    if quant_pool:
-        k_scales, v_scales = cache_scales
-        x, (k_pool, v_pool, k_scales, v_scales) = jax.lax.scan(
-            block, x, (params["layers"], k_pool, v_pool, k_scales, v_scales)
-        )
-        logits = _final_logits(x[:, 0], params, cfg, qmm)
-        return logits, k_pool, v_pool, (k_scales, v_scales)
-    x, (k_pool, v_pool) = jax.lax.scan(
-        block, x, (params["layers"], k_pool, v_pool)
+    x, k_pool, v_pool, scales = _scan_layers_over_pool(
+        block, x, params["layers"], k_pool, v_pool, cache_scales
     )
     logits = _final_logits(x[:, 0], params, cfg, qmm)
+    if quant_pool:
+        return logits, k_pool, v_pool, scales
     return logits, k_pool, v_pool
 
 
@@ -1176,43 +1206,35 @@ def verify_step_paged(
     x = params["embed"][tokens]  # [B, T, E]
     cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
-    def block(x, layer):
-        if quant_pool:
-            lp, k_l, v_l, k_s, v_s = layer
-        else:
-            lp, k_l, v_l = layer
-            k_s = v_s = None
+    def block(carry, layer):
+        x, k_pool, v_pool, *scales = carry
+        lp, l = layer
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
         if quant_pool:
-            k_l, k_s = scatter_quant(k_l, k_s, pages, offs, k_new)
-            v_l, v_s = scatter_quant(v_l, v_s, pages, offs, v_new)
-            k_all = gather_dequant(k_l, k_s, tables, q.dtype)
-            v_all = gather_dequant(v_l, v_s, tables, q.dtype)
+            k_s, v_s = scales
+            k_pool, k_s = scatter_quant(k_pool, k_s, (l, pages, offs), k_new)
+            v_pool, v_s = scatter_quant(v_pool, v_s, (l, pages, offs), v_new)
+            k_all = gather_dequant(k_pool, k_s, tables, q.dtype, l)
+            v_all = gather_dequant(v_pool, v_s, tables, q.dtype, l)
+            scales = (k_s, v_s)
         else:
-            k_l = k_l.at[pages, offs].set(k_new.astype(k_l.dtype))
-            v_l = v_l.at[pages, offs].set(v_new.astype(v_l.dtype))
+            k_pool = k_pool.at[l, pages, offs].set(k_new.astype(k_pool.dtype))
+            v_pool = v_pool.at[l, pages, offs].set(v_new.astype(v_pool.dtype))
             # logical per-slot views; same HBM bytes as the dense masked
             # read
-            k_all = k_l[tables].reshape(B, C, *k_l.shape[2:])
-            v_all = v_l[tables].reshape(B, C, *v_l.shape[2:])
+            k_all = k_pool[l, tables].reshape(B, C, *k_pool.shape[3:])
+            v_all = v_pool[l, tables].reshape(B, C, *v_pool.shape[3:])
         attn = gqa_attention(q, k_all, v_all, mask)
         x = x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row")
         x = x + _mlp(x, lp, cfg, moe_impl, qmm)
-        if quant_pool:
-            return x, (k_l, v_l, k_s, v_s)
-        return x, (k_l, v_l)
+        return (x, k_pool, v_pool, *scales), None
 
-    if quant_pool:
-        k_scales, v_scales = cache_scales
-        x, (k_pool, v_pool, k_scales, v_scales) = jax.lax.scan(
-            block, x, (params["layers"], k_pool, v_pool, k_scales, v_scales)
-        )
-        logits = _final_logits(x, params, cfg, qmm)
-        return logits, k_pool, v_pool, (k_scales, v_scales)
-    x, (k_pool, v_pool) = jax.lax.scan(
-        block, x, (params["layers"], k_pool, v_pool)
+    x, k_pool, v_pool, scales = _scan_layers_over_pool(
+        block, x, params["layers"], k_pool, v_pool, cache_scales
     )
     logits = _final_logits(x, params, cfg, qmm)
+    if quant_pool:
+        return logits, k_pool, v_pool, scales
     return logits, k_pool, v_pool
 
 
@@ -1363,135 +1385,180 @@ def verify_step(
 # ---------------------------------------------------------------------------
 
 
-def init_params(
-    cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16
-) -> Params:
-    """Random params (scaled-normal init) — for tests, benches and training."""
-    keys = iter(jax.random.split(key, 16))
+class _LeafBuilder:
+    """Builds the leaves of a random params tree, one key per random leaf.
 
-    def normal(shape, scale=0.02):
-        return (jax.random.normal(next(keys), shape, jnp.float32) * scale).astype(
-            dtype
-        )
+    With ``shardings`` (a path -> Sharding callable,
+    ShardingPlan.sharding_for) every leaf is built under jit with that
+    out-sharding, so each device generates only its own shard and neither
+    one chip nor device 0 of a mesh ever holds a whole leaf; without it
+    the leaf is built eagerly on the default device. Values do not depend
+    on ``shardings``."""
+
+    def __init__(self, key: jax.Array, dtype, shardings=None) -> None:
+        self._keys = iter(jax.random.split(key, 16))
+        self.dtype = dtype
+        self._shardings = shardings
+
+    def key(self) -> jax.Array:
+        return next(self._keys)
+
+    def place(self, path: str, fn):
+        if self._shardings is None:
+            return fn()
+        return jax.jit(fn, out_shardings=self._shardings(path))()
+
+    def ones(self, path: str, shape):
+        return self.place(path, lambda: jnp.ones(shape, self.dtype))
+
+    def normal(self, path: str, shape, scale: float = 0.02):
+        k = self.key()
+        return self.place(path, lambda: (
+            jax.random.normal(k, shape, jnp.float32) * scale
+        ).astype(self.dtype))
+
+
+def init_params(
+    cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16, shardings=None
+) -> Params:
+    """Random params (scaled-normal init) — for tests, benches and training.
+    ``shardings`` — see ``_LeafBuilder``."""
+    build = _LeafBuilder(key, dtype, shardings)
+    ones, normal = build.ones, build.normal
 
     L, E, F, D = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
     layers = {
-        "attn_norm": jnp.ones((L, E), dtype),
-        "ffn_norm": jnp.ones((L, E), dtype),
-        "wq": normal((L, E, cfg.q_dim)),
-        "wk": normal((L, E, cfg.kv_dim)),
-        "wv": normal((L, E, cfg.kv_dim)),
-        "wo": normal((L, cfg.q_dim, E)),
+        "attn_norm": ones("layers/attn_norm", (L, E)),
+        "ffn_norm": ones("layers/ffn_norm", (L, E)),
+        "wq": normal("layers/wq", (L, E, cfg.q_dim)),
+        "wk": normal("layers/wk", (L, E, cfg.kv_dim)),
+        "wv": normal("layers/wv", (L, E, cfg.kv_dim)),
+        "wo": normal("layers/wo", (L, cfg.q_dim, E)),
     }
     if cfg.moe:
         X, Fm = cfg.num_experts, cfg.expert_dim
-        layers["w_router"] = normal((L, E, X))
-        layers["we_gate"] = normal((L, X, E, Fm))
-        layers["we_up"] = normal((L, X, E, Fm))
-        layers["we_down"] = normal((L, X, Fm, E))
+        layers["w_router"] = normal("layers/w_router", (L, E, X))
+        layers["we_gate"] = normal("layers/we_gate", (L, X, E, Fm))
+        layers["we_up"] = normal("layers/we_up", (L, X, E, Fm))
+        layers["we_down"] = normal("layers/we_down", (L, X, Fm, E))
     else:
-        layers["w_gate"] = normal((L, E, F))
-        layers["w_up"] = normal((L, E, F))
-        layers["w_down"] = normal((L, F, E))
+        layers["w_gate"] = normal("layers/w_gate", (L, E, F))
+        layers["w_up"] = normal("layers/w_up", (L, E, F))
+        layers["w_down"] = normal("layers/w_down", (L, F, E))
     if cfg.qk_norm:
-        layers["q_norm"] = jnp.ones((L, D), dtype)
-        layers["k_norm"] = jnp.ones((L, D), dtype)
+        layers["q_norm"] = ones("layers/q_norm", (L, D))
+        layers["k_norm"] = ones("layers/k_norm", (L, D))
     params: Params = {
-        "embed": normal((cfg.vocab_size, E)),
+        "embed": normal("embed", (cfg.vocab_size, E)),
         "layers": layers,
-        "final_norm": jnp.ones((E,), dtype),
+        "final_norm": ones("final_norm", (E,)),
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = normal((E, cfg.vocab_size))
+        params["lm_head"] = normal("lm_head", (E, cfg.vocab_size))
     return params
 
 
 def init_quantized_params(
     cfg: ModelConfig, key: jax.Array, fuse: bool = True, dtype=jnp.bfloat16,
-    mode: str = "int8",
+    mode: str = "int8", tp: int = 1, shardings=None,
 ) -> Params:
     """Random params built DIRECTLY in the quantized serving layout
     (``quantize_params`` output shapes) — the bf16 weights never
     materialize, so a 7B model inits in ~7 GB of HBM instead of ~22 GB
-    (int4: ~3.5 GB). Benchmarks and dry-runs only: decode throughput is
-    weight-value-independent (same bytes streamed, same FLOPs), and each
-    quantized tensor tiles one random 2-D block over the layer axis to
-    keep the init's own peak memory at one layer's worth.
+    (int4: ~3.5 GB). For ``synthetic://`` sources, benchmarks and
+    dry-runs: decode throughput is weight-value-independent (same bytes
+    streamed, same FLOPs), and each quantized tensor tiles one random 2-D
+    block over the layer axis to keep the init's own peak memory at one
+    layer's worth. ``tp`` applies ``quantize_params``'s shard-local int4
+    eligibility (with ``fuse=False``); ``shardings`` — see
+    ``_LeafBuilder``.
     """
-    keys = iter(jax.random.split(key, 16))
+    build = _LeafBuilder(key, dtype, shardings)
+    ones, normal, place = build.ones, build.normal, build.place
     L, E, F, D = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
     V = cfg.vocab_size
 
-    def qleaf(shape, force_int8: bool = False):
+    def qleaf(path, shape, force_int8: bool = False):
+        K, N = shape[-2], shape[-1]
+        k = build.key()
+        g = 0
         if mode == "int4" and not force_int8:
-            from ..ops.int4_matmul import (
-                kernel_supported,
-                pick_group,
-                supports_int4,
-            )
-
-            K, N = shape[-2], shape[-1]
-            # same eligibility rule as quantize_params.quant_leaf
-            if supports_int4(K, N) and (
-                kernel_supported(K, N, pick_group(K)) or not ops.use_pallas()
-            ):
-                g = pick_group(K)
-                block = jax.random.randint(
-                    next(keys), (K // 2, N), 0, 256, jnp.int32
-                ).astype(jnp.uint8)
-                q = jnp.asarray(jnp.broadcast_to(block, shape[:-2] + (K // 2, N)))
-                s_shape = shape[:-2] + (K // g, 1, N)
-                return {"q4": q, "s4": jnp.full(s_shape, 0.02 / 7.0, jnp.float32)}
-        block = jax.random.randint(
-            next(keys), shape[-2:], -127, 128, jnp.int32
-        ).astype(jnp.int8)
-        q = jnp.asarray(jnp.broadcast_to(block, shape))
-        s_shape = shape[:-2] + (1, shape[-1])
-        return {"q": q, "s": jnp.full(s_shape, 0.02 / 127.0, jnp.float32)}
+            g = _int4_group(path.rsplit("/", 1)[-1], K, N, tp)
+        # raw random bytes, not randint: on the TPU randint's unbiased
+        # range reduction takes 30-50 s to COMPILE per 100M-element shape
+        # (measured, PR 21) where bits takes ~2 s — and every byte is a
+        # valid int8 weight / pair of int4 nibbles as it stands
+        if g:
+            return {
+                "q4": place(path + "/q4", lambda: jnp.broadcast_to(
+                    jax.random.bits(k, (K // 2, N), jnp.uint8),
+                    shape[:-2] + (K // 2, N),
+                )),
+                "s4": place(path + "/s4", lambda: jnp.full(
+                    shape[:-2] + (K // g, 1, N), 0.02 / 7.0, jnp.float32
+                )),
+            }
+        return {
+            "q": place(path + "/q", lambda: jnp.broadcast_to(
+                jax.lax.bitcast_convert_type(
+                    jax.random.bits(k, (K, N), jnp.uint8), jnp.int8
+                ),
+                shape,
+            )),
+            "s": place(path + "/s", lambda: jnp.full(
+                shape[:-2] + (1, N), 0.02 / 127.0, jnp.float32
+            )),
+        }
 
     layers = {
-        "attn_norm": jnp.ones((L, E), dtype),
-        "ffn_norm": jnp.ones((L, E), dtype),
+        "attn_norm": ones("layers/attn_norm", (L, E)),
+        "ffn_norm": ones("layers/ffn_norm", (L, E)),
     }
     if cfg.qk_norm:
-        layers["q_norm"] = jnp.ones((L, D), dtype)
-        layers["k_norm"] = jnp.ones((L, D), dtype)
+        layers["q_norm"] = ones("layers/q_norm", (L, D))
+        layers["k_norm"] = ones("layers/k_norm", (L, D))
     if fuse:
-        layers["w_qkv"] = qleaf((L, E, cfg.q_dim + 2 * cfg.kv_dim))
-        layers["wo"] = qleaf((L, cfg.q_dim, E))
+        layers["w_qkv"] = qleaf("layers/w_qkv", (L, E, cfg.q_dim + 2 * cfg.kv_dim))
+        layers["wo"] = qleaf("layers/wo", (L, cfg.q_dim, E))
     else:
-        layers["wq"] = qleaf((L, E, cfg.q_dim))
-        layers["wk"] = qleaf((L, E, cfg.kv_dim))
-        layers["wv"] = qleaf((L, E, cfg.kv_dim))
-        layers["wo"] = qleaf((L, cfg.q_dim, E))
+        layers["wq"] = qleaf("layers/wq", (L, E, cfg.q_dim))
+        layers["wk"] = qleaf("layers/wk", (L, E, cfg.kv_dim))
+        layers["wv"] = qleaf("layers/wv", (L, E, cfg.kv_dim))
+        layers["wo"] = qleaf("layers/wo", (L, cfg.q_dim, E))
     if cfg.moe:
         X, Fm = cfg.num_experts, cfg.expert_dim
-        layers["w_router"] = (
-            jax.random.normal(next(keys), (L, E, X), jnp.float32) * 0.02
-        ).astype(dtype)
+        layers["w_router"] = normal("layers/w_router", (L, E, X))
         # expert leaves stay int8 in int4 mode (the gathered-expert decode
         # path is int8-specialized, matching quantize_params)
         if fuse:
-            layers["we_gateup"] = qleaf((L, X, E, 2 * Fm), force_int8=True)
-            layers["we_down"] = qleaf((L, X, Fm, E), force_int8=True)
+            layers["we_gateup"] = qleaf(
+                "layers/we_gateup", (L, X, E, 2 * Fm), force_int8=True
+            )
+            layers["we_down"] = qleaf(
+                "layers/we_down", (L, X, Fm, E), force_int8=True
+            )
         else:
-            layers["we_gate"] = qleaf((L, X, E, Fm), force_int8=True)
-            layers["we_up"] = qleaf((L, X, E, Fm), force_int8=True)
-            layers["we_down"] = qleaf((L, X, Fm, E), force_int8=True)
+            layers["we_gate"] = qleaf(
+                "layers/we_gate", (L, X, E, Fm), force_int8=True
+            )
+            layers["we_up"] = qleaf(
+                "layers/we_up", (L, X, E, Fm), force_int8=True
+            )
+            layers["we_down"] = qleaf(
+                "layers/we_down", (L, X, Fm, E), force_int8=True
+            )
     elif fuse:
-        layers["w_gateup"] = qleaf((L, E, 2 * F))
-        layers["w_down"] = qleaf((L, F, E))
+        layers["w_gateup"] = qleaf("layers/w_gateup", (L, E, 2 * F))
+        layers["w_down"] = qleaf("layers/w_down", (L, F, E))
     else:
-        layers["w_gate"] = qleaf((L, E, F))
-        layers["w_up"] = qleaf((L, E, F))
-        layers["w_down"] = qleaf((L, F, E))
+        layers["w_gate"] = qleaf("layers/w_gate", (L, E, F))
+        layers["w_up"] = qleaf("layers/w_up", (L, E, F))
+        layers["w_down"] = qleaf("layers/w_down", (L, F, E))
     return {
-        "embed": (
-            jax.random.normal(next(keys), (V, E), jnp.float32) * 0.02
-        ).astype(dtype),
+        "embed": normal("embed", (V, E)),
         "layers": layers,
-        "final_norm": jnp.ones((E,), dtype),
-        "lm_head": qleaf((E, V)),
+        "final_norm": ones("final_norm", (E,)),
+        "lm_head": qleaf("lm_head", (E, V)),
     }
 
 
@@ -1552,29 +1619,30 @@ def paged_int8_attend(q, k_l, v_l, k_s, v_s, tables, lengths, *, window,
 
 
 def scatter_quant(
-    pool: jnp.ndarray,  # [N, P, KH, D] int8
-    scales: jnp.ndarray,  # [N, P, KH] f32
-    pages: jnp.ndarray,
-    offs: jnp.ndarray,
-    rows: jnp.ndarray,  # [..., KH, D] new rows (pages/offs broadcast-match)
+    pool: jnp.ndarray,  # [..., N, P, KH, D] int8
+    scales: jnp.ndarray,  # [..., N, P, KH] f32
+    idx: tuple,  # (pages, offs), or (layer, pages, offs) on the whole pool
+    rows: jnp.ndarray,  # [..., KH, D] new rows (idx arrays broadcast-match)
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Quantize rows and scatter values + scales into an int8 page pool —
     the single write-side quantization contract for every paged path."""
     q, s = quantize_kv(rows)
-    return pool.at[pages, offs].set(q), scales.at[pages, offs].set(s)
+    return pool.at[idx].set(q), scales.at[idx].set(s)
 
 
 def gather_dequant(
-    pool: jnp.ndarray,  # [N, P, KH, D] int8
-    scales: jnp.ndarray,  # [N, P, KH] f32
+    pool: jnp.ndarray,  # [N, P, KH, D] int8, or [L, N, P, KH, D] with layer
+    scales: jnp.ndarray,  # [N, P, KH] f32 (same leading axes as pool)
     tables: jnp.ndarray,  # [..., MB] int32
     dtype,
+    layer=None,  # scalar layer index into a whole [L, ...] pool
 ) -> jnp.ndarray:
     """Materialize dequantized logical views [..., MB*P, KH, D] from an
     int8 page pool — the read-side twin of ``scatter_quant``."""
-    out = dequantize_kv(pool[tables], scales[tables], dtype)
+    idx = tables if layer is None else (layer, tables)
+    out = dequantize_kv(pool[idx], scales[idx], dtype)
     MB = tables.shape[-1]
-    P, KH, D = pool.shape[1], pool.shape[2], pool.shape[3]
+    P, KH, D = pool.shape[-3], pool.shape[-2], pool.shape[-1]
     return out.reshape(*tables.shape[:-1], MB * P, KH, D)
 
 

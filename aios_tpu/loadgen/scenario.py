@@ -12,10 +12,9 @@ from __future__ import annotations
 
 import json
 import os
+import tomllib
 from dataclasses import dataclass, field, fields
 from typing import Tuple
-
-from .._compat import tomllib
 
 TENANT_CLASSES = ("interactive", "agent", "batch", "abusive", "reactive")
 ARRIVALS = ("poisson", "uniform", "diurnal", "burst")

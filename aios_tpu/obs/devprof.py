@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import logging
 import os
+import sys
 import threading
 import time
 import weakref
@@ -154,18 +155,10 @@ def local_device_kind() -> str:
 
 def resolve_peaks(device_kind: str) -> Optional[Tuple[float, float]]:
     """(peak FLOP/s, peak HBM bytes/s) for a device kind, or None when
-    the kind is not in the table (utilization gauges are then omitted)."""
-    if not device_kind:
-        return None
-    hit = DEVICE_PEAKS.get(device_kind)
-    if hit is not None:
-        return hit
-    # lenient prefix match: libtpu has shipped kinds like
-    # "TPU v5 lite" vs "TPU v5litepod" across versions
-    for name, peaks in DEVICE_PEAKS.items():
-        if device_kind.startswith(name):
-            return peaks
-    return None
+    the kind is not in the table (utilization gauges are then omitted).
+    Exact ``device_kind`` keys only: a chip the table does not list gets
+    no denominator, never a neighbour's."""
+    return DEVICE_PEAKS.get(device_kind)
 
 
 def _cost_of(compiled) -> Optional[Tuple[float, float]]:
@@ -444,6 +437,12 @@ def start_capture(secs: float) -> dict:
     if not dump_dir:
         raise CaptureDisabled(
             "profiler capture disabled: set AIOS_TPU_DEVPROF_DUMP_DIR"
+        )
+    if "jax" not in sys.modules:
+        # only the process that holds the chip can trace it; a service
+        # that never imported JAX must not open the device to find out
+        raise CaptureDisabled(
+            "profiler capture disabled: this process does not run JAX"
         )
     secs = min(max(float(secs), 0.05), CAPTURE_MAX_SECS)
     with _capture_lock:

@@ -236,7 +236,7 @@ def _ragged_call(q, k_cache, v_cache, lengths, scales, *, window, block_kv,
         sm_scale=1.0 / float(np.sqrt(D)),
         quantized=quantized,
     )
-    cache_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * (
+    cache_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (
         2 + (2 if quantized else 0)
     )
     args = [

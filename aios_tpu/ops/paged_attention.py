@@ -225,7 +225,7 @@ def _paged_call(q, k_pool, v_pool, tables, lengths, scales, *, window,
         sm_scale=1.0 / float(np.sqrt(D)),
         quantized=quantized,
     )
-    pool_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * (
+    pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (
         2 + (2 if quantized else 0)
     )
     args = [

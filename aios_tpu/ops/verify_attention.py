@@ -221,7 +221,7 @@ def _mq_call(q, k_cache, v_cache, lengths, strides, scales, *, window,
         sm_scale=1.0 / float(np.sqrt(D)),
         quantized=quantized,
     )
-    cache_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * (
+    cache_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (
         2 + (2 if quantized else 0)
     )
     args = [

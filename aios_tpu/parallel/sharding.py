@@ -130,6 +130,9 @@ class ShardingPlan:
                 return P(*base[:-1], None, base[-1])
         raise KeyError(f"no partition rule for param {path!r}")
 
+    def sharding_for(self, path: str) -> NamedSharding:
+        return NamedSharding(self.mesh, self.spec_for(path))
+
     def params_shardings(self, params) -> Dict:
         def walk(tree, prefix=""):
             out = {}
@@ -138,7 +141,7 @@ class ShardingPlan:
                 if isinstance(v, dict):
                     out[k] = walk(v, path + "/")
                 else:
-                    out[k] = NamedSharding(self.mesh, self.spec_for(path))
+                    out[k] = self.sharding_for(path)
             return out
 
         return walk(params)
@@ -171,8 +174,6 @@ class ShardingPlan:
         Returns attn(q [B,H,D], k_l [B,C,KH,D], v_l [B,C,KH,D], lengths [B])
         -> [B, H, D], for model.decode_step's ``attn_impl`` hook.
         """
-        from jax.experimental.shard_map import shard_map
-
         from .. import ops
 
         def local(q, k_l, v_l, lengths):
@@ -182,7 +183,7 @@ class ShardingPlan:
                 q, k_l, v_l, lengths, window=window
             )
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(
@@ -192,7 +193,7 @@ class ShardingPlan:
                 P("dp"),
             ),
             out_specs=P("dp", "tp", None),
-            check_rep=False,
+            check_vma=False,
         )
 
     def int4_matmul_impl(self, use_kernel: bool):
@@ -220,8 +221,6 @@ class ShardingPlan:
 
         Returns f(x, leaf, kind) -> y for model.matmul's ``qmm`` hook.
         """
-        from jax.experimental.shard_map import shard_map
-
         from ..ops.int4_matmul import (
             infer_group,
             int4_matmul,
@@ -262,12 +261,12 @@ class ShardingPlan:
                 y = local_mm(x_l, q4_l, s4_l)
                 return jax.lax.psum(y, "tp") if _reduce else y
 
-            fns[kind] = shard_map(
+            fns[kind] = jax.shard_map(
                 local,
                 mesh=mesh,
                 in_specs=in_specs,
                 out_specs=out_spec,
-                check_rep=False,
+                check_vma=False,
             )
 
         def qmm(x, leaf, kind):
@@ -296,8 +295,6 @@ class ShardingPlan:
         to inputs and outputs. Plugged into model.decode_step_paged's
         ``pool_impl`` hook.
         """
-        from jax.experimental.shard_map import shard_map
-
         from .. import ops
         from ..engine import model as model_mod
 
@@ -317,8 +314,8 @@ class ShardingPlan:
 
         def local_int8(q, k_new, v_new, k_l, v_l, k_s, v_s, tables,
                        lengths, pages, offs):
-            k_l, k_s = model_mod.scatter_quant(k_l, k_s, pages, offs, k_new)
-            v_l, v_s = model_mod.scatter_quant(v_l, v_s, pages, offs, v_new)
+            k_l, k_s = model_mod.scatter_quant(k_l, k_s, (pages, offs), k_new)
+            v_l, v_s = model_mod.scatter_quant(v_l, v_s, (pages, offs), v_new)
             attn = model_mod.paged_int8_attend(
                 q, k_l, v_l, k_s, v_s, tables, lengths, window=window,
                 use_int8_kernel=(
@@ -340,9 +337,9 @@ class ShardingPlan:
                         P("dp", None), P("dp"), P("dp"), P("dp"))
             out_specs = (vec, pool, pool)
             fn = local_bf16
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
 
     def paged_prefill_scatter(self, quantized: bool):
@@ -359,8 +356,6 @@ class ShardingPlan:
         int8: scales [L,N,P,KH] and per-row scale values [L,T,KH] ride
               along (inputs and outputs).
         """
-        from jax.experimental.shard_map import shard_map
-
         def local_bf16(k_l, v_l, kq, vq, pages, offs, owner):
             mine = jax.lax.axis_index("dp") == owner
             pg = jnp.where(mine, pages, 0)
@@ -391,9 +386,9 @@ class ShardingPlan:
             in_specs = (pool, pool, rows, rows, P(None), P(None), P())
             out_specs = (pool, pool)
             fn = local_bf16
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
 
     @property

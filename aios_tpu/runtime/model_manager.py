@@ -20,6 +20,7 @@ of that architecture (benchmarks and tests run without weight files).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
@@ -31,6 +32,7 @@ from typing import Dict, List, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from .. import backend
 from ..analysis.locks import make_lock
 from ..engine import gguf as gguf_mod
 from ..engine import model as model_mod
@@ -93,6 +95,10 @@ class ManagedModel:
     # source/geometry hot-swaps instead of returning the stale pool
     model_path: str = ""
     context_length: int = 0
+    # where the load's wall time went (set-up is a cost every cold start
+    # pays): weights = read/build to device, engines = placement + state
+    # allocation, warmup = AOT compiles behind the readiness gate
+    setup_seconds: Dict[str, float] = field(default_factory=dict)
     lock: threading.Lock = field(default_factory=threading.Lock)
 
     def touch(self) -> None:
@@ -124,9 +130,9 @@ def _plan_from_env():
     """Build a sharding plan from AIOS_TPU_MESH ("dp=2,sp=2,tp=2"; missing
     axes default to 1) — how a multi-chip deployment's boot config selects
     its mesh (the [models] mesh knob -> serving_env()). Returns None when
-    unset, malformed, or when the visible devices can't fill the mesh (a
-    bad tuning knob must not take down boot — the lenient pattern of the
-    sibling env parsers)."""
+    unset or when every axis is 1. A spec that cannot be honoured —
+    malformed, or needing more devices than are visible — raises: a
+    deployment sized for four chips must not come up on one."""
     spec = os.environ.get("AIOS_TPU_MESH", "").strip().lower()
     if not spec:
         return None
@@ -140,41 +146,42 @@ def _plan_from_env():
             if axes[k] < 1:
                 raise ValueError(f"axis {k} must be >= 1")
     except ValueError as exc:
-        log.warning("AIOS_TPU_MESH=%r ignored (%s); serving single-chip",
-                    spec, exc)
-        return None
+        raise ValueError(f"AIOS_TPU_MESH={spec!r} is malformed: {exc}") from exc
     n = axes["dp"] * axes["sp"] * axes["ep"] * axes["tp"]
     if n == 1:
         return None
     from ..parallel.sharding import ShardingPlan, build_mesh
 
     if len(jax.devices()) < n:
-        log.warning(
-            "AIOS_TPU_MESH=%r needs %d devices, found %d; serving "
-            "single-chip", spec, n, len(jax.devices()),
+        raise ValueError(
+            f"AIOS_TPU_MESH={spec!r} needs {n} devices, found "
+            f"{len(jax.devices())}"
         )
-        return None
     return ShardingPlan(build_mesh(
         n, dp=axes["dp"], sp=axes["sp"], ep=axes["ep"], tp=axes["tp"]
     ))
 
 
-def _chip_hbm_bytes() -> float:
-    """Per-device HBM capacity: AIOS_TPU_HBM_GB override, else the
-    backend's reported limit, else the v5e default (16 GB)."""
+def _chip_hbm_bytes() -> Optional[float]:
+    """Per-device HBM capacity: AIOS_TPU_HBM_GB override, else the TPU's
+    reported limit. None on an intended CPU run (host RAM is not budgeted);
+    a TPU that reports no limit raises rather than being assumed a v5e."""
     env = os.environ.get("AIOS_TPU_HBM_GB", "")
     if env:
         try:
             return float(env) * 1e9
         except ValueError:
             log.warning("AIOS_TPU_HBM_GB=%r ignored (not a number)", env)
-    try:
-        stats = jax.devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return float(stats["bytes_limit"])
-    except Exception:  # noqa: BLE001 - stats are best-effort off-TPU
-        pass
-    return 16e9
+    if not backend.on_tpu():
+        return None
+    dev = jax.devices()[0]
+    stats = dev.memory_stats()
+    if not stats or not stats.get("bytes_limit"):
+        raise RuntimeError(
+            f"{dev.device_kind} reports no HBM bytes_limit; set "
+            "AIOS_TPU_HBM_GB to its per-chip capacity"
+        )
+    return float(stats["bytes_limit"])
 
 
 class ModelManager:
@@ -188,6 +195,7 @@ class ModelManager:
         quantize: Union[bool, str, None] = None,  # None=auto, bool, "int8"/"int4"
     ) -> None:
         self.models: Dict[str, ManagedModel] = {}
+        self.autoload_failures: Dict[str, str] = {}
         self.num_slots = num_slots
         if sharding_plan is None:
             sharding_plan = _plan_from_env()
@@ -195,9 +203,9 @@ class ModelManager:
         self.warm_compile = warm_compile
         # int8 serving weights: the default on single-chip TPU (the reference
         # serves Q4 GGUF through llama.cpp, so int8 is *more* precise than
-        # its default); AIOS_TPU_QUANTIZE=0 forces bf16 serving. CPU-fallback
-        # backends keep dense weights — without the TPU int8 dot they would
-        # re-dequantize every matmul.
+        # its default); AIOS_TPU_QUANTIZE=0 forces bf16 serving. Intended CPU
+        # runs (tests, smokes) keep dense weights — without the TPU int8 dot
+        # they would re-dequantize every matmul.
         # explicit = the operator chose a mode (param or env); auto-derived
         # defaults must not argue with a prepared checkpoint's stored mode.
         # Derived as "did not fall through to the auto branch" so the
@@ -222,17 +230,14 @@ class ModelManager:
                         "unrecognized AIOS_TPU_QUANTIZE=%r (expected 0/1/"
                         "int8/int4); using the auto default", env,
                     )
-                try:
-                    import jax
-
-                    on_tpu = jax.default_backend() == "tpu"
-                except Exception:  # noqa: BLE001
-                    on_tpu = False
                 # default: int8 on single-chip TPU; sharded serving keeps
                 # the conservative bf16 default until measured on a real
                 # mesh — but an EXPLICIT AIOS_TPU_QUANTIZE=1 is honored
                 # either way (the engine shards the unfused int8 layout)
-                quantize = "int8" if (sharding_plan is None and on_tpu) else False
+                quantize = (
+                    "int8" if sharding_plan is None and backend.on_tpu()
+                    else False
+                )
         elif quantize is True:
             quantize = "int8"
         self.quantize = quantize or False
@@ -383,6 +388,19 @@ class ModelManager:
             for i in range(n)
         ]
 
+    def _replica_devices(self, n: int, shared: bool) -> List:
+        """The device each replica commits to when there is no plan:
+        replica i gets device i once enough devices are visible (four
+        one-chip replicas on a four-chip host), so params, cache and page
+        pool follow it instead of piling onto device 0. ``[None] * n``
+        (everything on the default device) under a plan — submeshes place
+        those — for a single replica, on an oversubscribed host, and when
+        the replicas ``shared`` one device-resident draft model."""
+        devs = jax.local_devices()
+        if self.plan is not None or shared or n <= 1 or len(devs) < n:
+            return [None] * n
+        return devs[:n]
+
     def load_model(
         self,
         name: str,
@@ -416,6 +434,9 @@ class ModelManager:
         t0 = time.time()
         try:
             cfg, params, tokenizer = self._load_weights(name, path, context_length)
+            jax.block_until_ready(params)  # weight builds dispatch async
+            setup = {"weights": time.time() - t0, "engines": 0.0,
+                     "warmup": 0.0}
             serving_cfg = ServingConfig.from_env(
                 cfg.replicas,
                 draft_model_default=getattr(cfg, "draft_model", ""),
@@ -426,9 +447,14 @@ class ModelManager:
             # hosts one replica); replicas sharing a device set multiply
             # the per-chip footprint — both the budget check below and the
             # recorded hbm_chip_bytes must use the same factor
+            devices = self._replica_devices(
+                n_replicas, shared=bool(serving_cfg.draft_model)
+            )
             repl_factor = n_replicas
-            if n_replicas > 1 and self.plan is not None \
-                    and plans[0] is not self.plan:
+            if n_replicas > 1 and (
+                devices[0] is not None
+                or (self.plan is not None and plans[0] is not self.plan)
+            ):
                 repl_factor = 1
             cache_dtype = self.cache_dtype
             ctx = context_length or cfg.max_context
@@ -539,7 +565,8 @@ class ModelManager:
             weight_chip = model_mod.serving_weight_bytes(params) * factor / tp
             kv_chip = self._kv_bytes_per_chip(cfg, ctx, cache_dtype, kw)
             hbm_estimate = weight_chip + kv_chip
-            if not kw.get("seq_sharded_cache"):
+            chip_hbm = _chip_hbm_bytes()
+            if chip_hbm is not None and not kw.get("seq_sharded_cache"):
                 # Long-context auto-degradation (the graceful path a boot
                 # config with sp > 1 selects without any extra knob): when
                 # this model's KV cache cannot fit the per-chip HBM budget
@@ -559,7 +586,7 @@ class ModelManager:
                     if mm.name != name or mm.state == STATE_READY
                 )
                 budget = (
-                    _chip_hbm_bytes() * 0.85
+                    chip_hbm * 0.85
                     - weight_chip * repl_factor - resident - draft_bytes
                 )
                 sp = self.plan.sp if self.plan is not None else 1
@@ -632,33 +659,47 @@ class ModelManager:
             engines = []
             try:
                 for i in range(n_replicas):
-                    engine = TPUEngine(
-                        cfg,
-                        params,
-                        num_slots=self.num_slots,
-                        max_context=ctx,
-                        shardings=plans[i],
-                        quantize=quantize,
-                        cache_dtype=cache_dtype,
-                        # the per-step history scatter serves only the
-                        # speculative proposers — skip it (and its
-                        # serial scan dependency) when speculative
-                        # serving is off
-                        track_history=spec_on,
-                        draft=draft,
-                        **kw,
+                    # the engine commits params and allocates its state on
+                    # the ambient default device
+                    scope = (
+                        jax.default_device(devices[i])
+                        if devices[i] is not None
+                        else contextlib.nullcontext()
                     )
-                    if self.warm_compile:
-                        # json-mode deployments dispatch the grammar-masked
-                        # step; compile it behind the readiness gate too
-                        # (AOT, no dispatch). Speculative round graphs are
-                        # covered when the pool's batchers attach below —
-                        # ContinuousBatcher AOT-compiles its ACTUAL chunk
-                        # sizes, still before STATE_READY
-                        from .service import json_mode_forced
+                    with scope:
+                        t_engine = time.time()
+                        engine = TPUEngine(
+                            cfg,
+                            params,
+                            num_slots=self.num_slots,
+                            max_context=ctx,
+                            shardings=plans[i],
+                            quantize=quantize,
+                            cache_dtype=cache_dtype,
+                            # the per-step history scatter serves only
+                            # the speculative proposers — skip it (and
+                            # its serial scan dependency) when
+                            # speculative serving is off
+                            track_history=spec_on,
+                            draft=draft,
+                            **kw,
+                        )
+                        engines.append(engine)
+                        jax.block_until_ready((engine.params, engine.state))
+                        t_warm = time.time()
+                        setup["engines"] += t_warm - t_engine
+                        if self.warm_compile:
+                            # json-mode deployments dispatch the
+                            # grammar-masked step; compile it behind the
+                            # readiness gate too (AOT, no dispatch).
+                            # Speculative round graphs are covered when
+                            # the pool's batchers attach below —
+                            # ContinuousBatcher AOT-compiles its ACTUAL
+                            # chunk sizes, still before STATE_READY
+                            from .service import json_mode_forced
 
-                        engine.warmup(masked_step=json_mode_forced())
-                    engines.append(engine)
+                            engine.warmup(masked_step=json_mode_forced())
+                            setup["warmup"] += time.time() - t_warm
             except BaseException:
                 # a failed replica build must not strand its siblings'
                 # HBM until a gc pass
@@ -727,6 +768,7 @@ class ModelManager:
                 pool=pool,
                 model_path=path,
                 context_length=context_length or 0,
+                setup_seconds={k: round(v, 2) for k, v in setup.items()},
             )
             # keep the replica-0 snapshot fresh across crash-respawns
             # (the pool swaps Replica.batcher; the ManagedModel field
@@ -838,10 +880,12 @@ class ModelManager:
             p = Path(source)
             if source.endswith(".gguf") or "/" in source or p.exists():
                 dcfg, dparams, dtok = self._load_weights(
-                    p.stem.lower() or "draft", source, 0
+                    p.stem.lower() or "draft", source, 0, draft=True
                 )
             else:
-                dcfg, dparams, dtok = self._load_weights(source, "", 0)
+                dcfg, dparams, dtok = self._load_weights(
+                    source, "", 0, draft=True
+                )
         except Exception as exc:  # noqa: BLE001 - lenient knob pattern
             log.warning(
                 "%s: draft model %r failed to load (%s); serving with "
@@ -884,14 +928,36 @@ class ModelManager:
         )
         return draft
 
-    def _load_weights(self, name: str, path: str, context_length: int):
-        """Resolve (config, params, tokenizer) from a model source."""
+    def _synthetic_params(self, cfg: ModelConfig, quantize, plan):
+        """Seeded random weights built in the layout they will be SERVED
+        in: quantized modes go straight to the int8/int4 serving leaves
+        (fused on one chip, unfused and tp-eligible under a plan) and every
+        leaf under a plan is generated already sharded — so neither one
+        chip nor device 0 of a mesh ever holds a dense 7B tree plus its
+        fp32 transients."""
+        key = jax.random.PRNGKey(0)
+        shardings = plan.sharding_for if plan is not None else None
+        if quantize:
+            return model_mod.init_quantized_params(
+                cfg, key, fuse=plan is None, mode=quantize,
+                tp=plan.tp if plan is not None else 1, shardings=shardings,
+            )
+        return model_mod.init_params(
+            cfg, key, dtype=jnp.bfloat16, shardings=shardings
+        )
+
+    def _load_weights(self, name: str, path: str, context_length: int,
+                      draft: bool = False):
+        """Resolve (config, params, tokenizer) from a model source.
+        ``draft`` — the source is a paired draft model: single-device,
+        int4 (spec.DraftModel's serving mode)."""
         if path.startswith("synthetic://") or not path:
             preset_name = path.removeprefix("synthetic://") or name
             cfg = self._resolve_preset(preset_name)
-            params = model_mod.init_params(
-                cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16
-            )
+            if draft:
+                params = self._synthetic_params(cfg, "int4", None)
+            else:
+                params = self._synthetic_params(cfg, self.quantize, self.plan)
             return cfg, params, ByteTokenizer()
 
         p = Path(path)
@@ -958,7 +1024,10 @@ class ModelManager:
         raise KeyError(f"no preset matches {name!r}")
 
     def autoload(self, model_dir: Optional[str] = None) -> List[str]:
-        """Scan AIOS_MODEL_DIR for *.gguf and load each (main.rs:65-132)."""
+        """Scan AIOS_MODEL_DIR for *.gguf and load each (main.rs:65-132).
+        A model that fails to load is logged with its traceback and
+        recorded in ``autoload_failures`` (name -> error); the caller
+        decides whether a boot with failures may continue."""
         model_dir = model_dir or os.environ.get(
             "AIOS_MODEL_DIR", "/var/lib/aios/models"
         )
@@ -972,8 +1041,9 @@ class ModelManager:
             try:
                 self.load_model(name, str(f), context_length=ctx)
                 loaded.append(name)
-            except Exception:
-                continue
+            except Exception as exc:  # noqa: BLE001 - next file still loads
+                log.exception("autoload of %s failed", f)
+                self.autoload_failures[name] = repr(exc)
         return loaded
 
     # -- unloading ----------------------------------------------------------

@@ -18,12 +18,14 @@ from __future__ import annotations
 
 import logging
 import os
+import sys
 import time
 from typing import Iterator, Optional
 
 import grpc
+import jax
 
-from .. import rpc
+from .. import backend, rpc
 from ..fleet import disagg as fleet_disagg
 from ..fleet import drain as fleet_drain
 from ..fleet import gprefix as fleet_gprefix
@@ -105,6 +107,13 @@ class RuntimeService(AIRuntimeServicer):
         models = list(self.manager.models.values())
         details = {m.name: m.state for m in models}
         details["backend"] = "jax-tpu"
+        # the device as JAX reports it: this is the one process that holds
+        # the chip, so the tools service's hw.info reads it from here
+        devices = jax.devices()
+        details["platform"] = devices[0].platform
+        details["device_kind"] = devices[0].device_kind
+        # "; "-joined: a TPU device's own string carries commas
+        details["devices"] = "; ".join(str(d) for d in devices)
         # per-model serving counters (spec acceptance, KV page usage,
         # prefix-cache hits, evictions) — additive observability the
         # reference's llama-server health probe has no equivalent for
@@ -510,8 +519,12 @@ def serve(
     return server, service, port
 
 
-if __name__ == "__main__":
+def main() -> int:
     logging.basicConfig(level=logging.INFO)
+    # the backend is decided before anything compiles: without
+    # JAX_PLATFORMS=cpu a missing TPU stops the service here, not after a
+    # 7B model has been loaded onto the host
+    log.info("serving backend: %s", backend.decide())
     # multi-host deployments set AIOS_TPU_COORDINATOR (+NUM_PROCESSES,
     # +PROCESS_ID) so every host's runtime joins one process group and the
     # engines see the global mesh; single-host is a no-op
@@ -519,5 +532,16 @@ if __name__ == "__main__":
 
     multihost.initialize_from_env()
     manager = ModelManager()
-    manager.autoload()
+    loaded = manager.autoload()
+    if manager.autoload_failures and not loaded:
+        log.error(
+            "every model in AIOS_MODEL_DIR failed to load: %s",
+            ", ".join(sorted(manager.autoload_failures)),
+        )
+        return 1
     serve(manager=manager)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

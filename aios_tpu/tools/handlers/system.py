@@ -13,6 +13,7 @@ import subprocess
 import time
 from pathlib import Path
 
+import grpc
 import psutil
 
 from . import ToolError, ToolSpec, run_cmd
@@ -275,15 +276,34 @@ def hw_info(args: dict) -> dict:
         "memory_total_mb": round(psutil.virtual_memory().total / 1e6),
         "boot_time": int(psutil.boot_time()),
     }
-    # TPU presence (the reference detects GPUs; we detect the TPU chip)
+    # Accelerators (the reference detects GPUs; here the TPU chip). The
+    # chip belongs to ONE process — the runtime service — so this tool
+    # asks it over gRPC instead of importing JAX and contending for the
+    # device; an unreachable runtime is reported, not guessed around.
     try:
-        import jax
-
-        info["accelerators"] = [str(d) for d in jax.devices()]
-        info["accelerator_backend"] = jax.default_backend()
-    except Exception:
+        info.update(_runtime_accelerators())
+    except grpc.RpcError as exc:
         info["accelerators"] = []
+        info["accelerator_error"] = f"runtime unreachable: {exc.code().name}"
     return info
+
+
+def _runtime_accelerators() -> dict:
+    from ... import rpc
+    from ...proto_gen import common_pb2
+    from ...services import AIRuntimeStub, service_address
+
+    with rpc.insecure_channel(service_address("runtime")) as channel:
+        details = AIRuntimeStub(channel).HealthCheck(
+            common_pb2.Empty(), timeout=5
+        ).details
+    return {
+        "accelerators": [
+            d for d in details.get("devices", "").split("; ") if d
+        ],
+        "accelerator_backend": details.get("platform", ""),
+        "accelerator_kind": details.get("device_kind", ""),
+    }
 
 
 TOOLS = {

@@ -8,9 +8,8 @@ from __future__ import annotations
 
 import threading
 import time
+import tomllib
 from pathlib import Path
-
-from .._compat import tomllib
 from typing import Dict, Optional
 
 CACHE_TTL = 3600.0

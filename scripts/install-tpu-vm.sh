@@ -38,15 +38,11 @@ import sys
 assert sys.version_info >= (3, 11), f"need python >= 3.11, have {sys.version}"
 import jax
 print(f"[install] jax {jax.__version__}")
-try:
-    devs = jax.devices()
-    kinds = {d.platform for d in devs}
-    print(f"[install] devices: {devs}")
-    if "tpu" not in kinds:
-        print("[install] WARNING: no TPU visible — serving will run on CPU")
-except Exception as exc:
-    print(f"[install] WARNING: backend init failed ({exc}); "
-          "the runtime retries at boot")
+devs = jax.devices()
+print(f"[install] devices: {devs}")
+if devs[0].platform != "tpu":
+    sys.exit("[install] no TPU visible: this host cannot serve "
+             "(JAX reports %r devices)" % devs[0].platform)
 EOF
 
 # --- 2. directory tree -----------------------------------------------------

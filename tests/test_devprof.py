@@ -96,9 +96,9 @@ def test_unknown_device_kind_omits_utilization():
     # raw seconds kept, utilization gauges omitted (no invented peaks)
     assert "device_seconds" in snap
     assert "mfu" not in snap and "hbm_bw_util" not in snap
-    # known kinds resolve, including lenient prefixes
+    # known kinds resolve by exact device_kind; nothing else does
     assert devprof.resolve_peaks("TPU v4") == (275e12, 1228e9)
-    assert devprof.resolve_peaks("TPU v5 litepod") == (197e12, 819e9)
+    assert devprof.resolve_peaks("TPU v5 litepod") is None
     assert devprof.resolve_peaks("") is None
 
 

@@ -9,6 +9,14 @@ chip: libtpu's AOT compiler builds each kernel against a v5e topology
 description, so a Mosaic-invalid layout fails in CI the way it would fail
 in serving.
 
+The Mistral-7B cases at the bottom are the gate to run BEFORE any chip call
+that touches ``aios_tpu/ops/`` or ``engine/model.py`` (docs/TESTING.md): the
+attention kernels at the geometry chip_smoke.py serves (H=32, KH=8, D=128,
+window 4096, with and without the window+sink operands) and the composed
+prefill / chunk / paged-decode graphs on int8 and int4 weights, each of
+which must also FIT — a graph that holds a second copy of the page pool
+compiles on a big host and dies on a 16 GB chip.
+
 Skips cleanly when no libtpu is importable (non-TPU dev machines).
 """
 
@@ -213,3 +221,141 @@ def test_aot_decode_step_int4_weights(rep_sharding):
     args = (params, toks, lens, k, v)
     sh = jax.tree.map(lambda a: rep_sharding, args)
     jax.jit(step, in_shardings=sh).trace(*args).lower().compile()
+
+
+# ---------------------------------------------------------------------------
+# Mistral-7B geometry — what chip_smoke.py serves (docs/TESTING.md: the gate
+# before chip time is spent). Abstract operands only: nothing 7B-sized is
+# materialized on the host.
+# ---------------------------------------------------------------------------
+
+MB, MH, MKH, MD, MC, MW = 8, 32, 8, 128, 4096, 4096
+MP = 128  # page size
+MN = 1 + (MB + 1) * MC // MP  # the "auto" pool: (slots + 1) x context rows
+
+
+def sds(rep, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+
+@pytest.mark.parametrize("T", [1024, 4096])
+def test_aot_mistral_flash_prefill(rep_sharding, T):
+    from aios_tpu import ops
+
+    q = sds(rep_sharding, (1, T, MH, MD), jnp.bfloat16)
+    kv = sds(rep_sharding, (1, T, MKH, MD), jnp.bfloat16)
+    aot_compile(rep_sharding, ops.flash_attention, q, kv, kv, causal=True,
+                window=MW)
+
+
+def test_aot_mistral_ragged_decode_both_dtypes(rep_sharding):
+    from aios_tpu import ops
+
+    q = sds(rep_sharding, (MB, MH, MD), jnp.bfloat16)
+    lens = sds(rep_sharding, (MB,), jnp.int32)
+    kc = sds(rep_sharding, (MB, MC, MKH, MD), jnp.bfloat16)
+    aot_compile(rep_sharding, ops.decode_attention, q, kc, kc, lens,
+                window=MW)
+    kq = sds(rep_sharding, (MB, MC, MKH, MD), jnp.int8)
+    ks = sds(rep_sharding, (MB, MC, MKH), jnp.float32)
+    aot_compile(rep_sharding, ops.decode_attention_int8, q, kq, kq, ks, ks,
+                lens, window=MW)
+
+
+@pytest.mark.parametrize("compressed", [False, True])
+def test_aot_mistral_paged_decode_both_dtypes(rep_sharding, compressed):
+    """window=4096, and the win_starts/sink operands PR 13 added."""
+    from aios_tpu import ops
+
+    q = sds(rep_sharding, (MB, MH, MD), jnp.bfloat16)
+    tbl = sds(rep_sharding, (MB, MC // MP), jnp.int32)
+    lens = sds(rep_sharding, (MB,), jnp.int32)
+    kp = sds(rep_sharding, (MN, MP, MKH, MD), jnp.bfloat16)
+    kq = sds(rep_sharding, (MN, MP, MKH, MD), jnp.int8)
+    ps = sds(rep_sharding, (MN, MP, MKH), jnp.float32)
+    if compressed:
+        def bf16(q, k, v, t, l, ws):
+            return ops.paged_decode_attention(
+                q, k, v, t, l, window=MW, win_starts=ws, sink=MP)
+
+        def int8(q, k, v, ks, vs, t, l, ws):
+            return ops.paged_decode_attention_int8(
+                q, k, v, ks, vs, t, l, window=MW, win_starts=ws, sink=MP)
+
+        aot_compile(rep_sharding, bf16, q, kp, kp, tbl, lens, lens)
+        aot_compile(rep_sharding, int8, q, kq, kq, ps, ps, tbl, lens, lens)
+    else:
+        aot_compile(rep_sharding, ops.paged_decode_attention, q, kp, kp,
+                    tbl, lens, window=MW)
+        aot_compile(rep_sharding, ops.paged_decode_attention_int8, q, kq,
+                    kq, ps, ps, tbl, lens, window=MW)
+
+
+def test_aot_mistral_multiquery_verify_both_dtypes(rep_sharding):
+    from aios_tpu import ops
+
+    qt = sds(rep_sharding, (MB, 8, MH, MD), jnp.bfloat16)
+    lens = sds(rep_sharding, (MB,), jnp.int32)
+    kc = sds(rep_sharding, (MB, MC, MKH, MD), jnp.bfloat16)
+    aot_compile(rep_sharding, ops.multiquery_decode_attention, qt, kc, kc,
+                lens, lens, window=MW)
+    kq = sds(rep_sharding, (MB, MC, MKH, MD), jnp.int8)
+    ks = sds(rep_sharding, (MB, MC, MKH), jnp.float32)
+    aot_compile(rep_sharding, ops.multiquery_decode_attention_int8, qt, kq,
+                kq, ks, ks, lens, lens, window=MW)
+
+
+@pytest.mark.parametrize("mode", ["int8", "int4"])
+def test_aot_mistral_serving_graphs_compile_and_fit(
+    rep_sharding, monkeypatch, mode
+):
+    """The composed graphs chip_smoke.py phases 1-2 dispatch — a prefill
+    bucket (flash kernel), a chunked-admission chunk and the paged decode
+    step — traced as on the chip (kernels on, int8 mixed dot / int4
+    kernel), pools donated. Beside compiling, each must leave the v5e's
+    HBM room: temporaries stay under ONE page pool, i.e. the layer loop
+    updates the pool in place instead of building a second one."""
+    from aios_tpu import backend
+    from aios_tpu.engine import model as M
+    from aios_tpu.engine.config import MISTRAL_7B as cfg
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    rep = rep_sharding
+    params = jax.tree.map(
+        lambda a: sds(rep, a.shape, a.dtype),
+        jax.eval_shape(lambda: M.init_quantized_params(
+            cfg, jax.random.PRNGKey(0), mode=mode)),
+    )
+    leaf = params["layers"]["w_gateup"]
+    assert ("q4" if mode == "int4" else "q") in leaf  # the kernel's layout
+    pool = sds(rep, (cfg.num_layers, MN, MP, MKH, MD), jnp.bfloat16)
+    pool_bytes = 2 * cfg.num_layers * MN * MP * MKH * MD  # one of k / v
+    i32 = lambda *shape: sds(rep, shape, jnp.int32)  # noqa: E731
+
+    def prefill(p, toks):
+        return M.prefill(p, cfg, toks, kernels=True)
+
+    def chunk(p, k, v, toks, start, row):
+        return M.prefill_chunk_paged(p, cfg, toks, start, k, v, row)
+
+    def step(p, k, v, toks, lens, tables):
+        return M.decode_step_paged(p, cfg, toks, lens, k, v, tables,
+                                   kernels=True)
+
+    graphs = {
+        "prefill-1024": (prefill, (params, i32(1, 1024)), ()),
+        "chunk-512": (chunk, (params, pool, pool, i32(1, 512), i32(),
+                              i32(MC // MP)), (1, 2)),
+        "decode-step": (step, (params, pool, pool, i32(MB), i32(MB),
+                               i32(MB, MC // MP)), (1, 2)),
+    }
+    for name, (fn, args, donate) in graphs.items():
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < pool_bytes, (
+            f"{name}: {mem.temp_size_in_bytes / 1e9:.2f} GB of temporaries "
+            "— a second copy of the page pool?"
+        )
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"

@@ -172,8 +172,8 @@ def test_paged_auto_sizes_pool_from_slots_and_context(monkeypatch):
 
 def test_mesh_env_builds_sharding_plan(monkeypatch):
     """AIOS_TPU_MESH (the [models] mesh boot knob) gives the production
-    runtime a multi-chip plan; malformed or oversized specs degrade to
-    single-chip serving instead of failing boot."""
+    runtime a multi-chip plan (a spec that cannot be honoured raises:
+    tests/test_backend.py)."""
     from aios_tpu.runtime.model_manager import ModelManager
 
     monkeypatch.setenv("AIOS_TPU_MESH", "dp=2,tp=2")
@@ -187,10 +187,6 @@ def test_mesh_env_builds_sharding_plan(monkeypatch):
     finally:
         mgr.unload_model("tiny")
 
-    monkeypatch.setenv("AIOS_TPU_MESH", "tp=999")
-    assert ModelManager(num_slots=2, warm_compile=False).plan is None
-    monkeypatch.setenv("AIOS_TPU_MESH", "bogus")
-    assert ModelManager(num_slots=2, warm_compile=False).plan is None
     monkeypatch.setenv("AIOS_TPU_MESH", "tp=1")
     assert ModelManager(num_slots=2, warm_compile=False).plan is None
 
