@@ -21,6 +21,7 @@ import itertools
 import logging
 import os
 import queue
+import statistics
 import threading
 import time
 from collections import deque
@@ -82,6 +83,24 @@ SPEC_PROBE_DISPATCHES = 3
 # (the pool's failover budget was exhausted, or there was no pool)
 DEFAULT_RETRY_AFTER_MS = 1000
 
+# A dispatch is a stall when it takes more than this many times the
+# running median of its step count: the median over the last
+# STALL_MEDIAN_OVER dispatches of that size, judged once it holds
+# STALL_MEDIAN_MIN of them.
+STALL_DISPATCH_FACTOR = 2.0
+STALL_MEDIAN_OVER = 32
+STALL_MEDIAN_MIN = 8
+
+# A request the batcher holds (waiting, prefilling or live) that has not
+# moved for this many times the last decode dispatch's duration, and for
+# at least NO_PROGRESS_MIN_SECS (a tiny model's dispatch is milliseconds,
+# and a queue behind full slots legitimately stands for seconds), leaves
+# one recorder snapshot with cause "no_progress". Looked at once every
+# NO_PROGRESS_CHECK_SECS.
+NO_PROGRESS_DISPATCHES = 64
+NO_PROGRESS_MIN_SECS = 10.0
+NO_PROGRESS_CHECK_SECS = 1.0
+
 
 @dataclass
 class Request:
@@ -133,6 +152,11 @@ class _Live:
     # the truncated output as a normal completion
     abort_reason: str = ""
     constraint: object = None  # jsonmode.JsonConstraint when json_mode
+    # instant of the last progress: submitted, admitted, a prefill chunk,
+    # a dispatch that emitted for it (one stamp per dispatch, not a token)
+    progress_at: float = 0.0
+    slot_free: bool = False  # a slot stood free when it was submitted
+    stuck_noted: bool = False  # its no_progress record was taken
 
 
 @dataclass
@@ -257,6 +281,17 @@ class ContinuousBatcher:
         self.host_gap_seconds = 0.0
         self._gap_mark: Optional[float] = None
         self._gap_wait = 0.0  # time blocked in consume-wait since the mark
+        # the loop's phases (obs/flightrec.py PHASES), shared with the
+        # engine's dispatch bodies; what _tick_done judges a stall from
+        self.phases = engine.phases
+        self.loop_stalls = 0
+        self.loop_stall_seconds = 0.0
+        # the size of the dispatch this tick completed (the key of the
+        # running median it is judged against), None when it made none
+        self._dispatched: Optional[object] = None
+        self._last_dispatch_s = 0.0
+        self._dispatch_hist: Dict[object, deque] = {}
+        self._progress_checked = 0.0
         self._mask_base = None  # cached all-zeros [slots, vocab] device mask
         self.tokenizer = tokenizer
         self._json_masks = None  # lazy jsonmode.JsonMaskCache
@@ -663,7 +698,8 @@ class ContinuousBatcher:
             )
         elif not req.rec.request_id:
             req.rec.request_id = req.request_id  # auto-assigned id above
-        live = _Live(req=req, slot=-1, submitted_at=time.monotonic())
+        now = time.monotonic()
+        live = _Live(req=req, slot=-1, submitted_at=now, progress_at=now)
         if req.json_schema is not None:
             from . import jsonmode
 
@@ -690,6 +726,9 @@ class ContinuousBatcher:
                 # would never be scheduled NOR terminated — its consumer
                 # would block forever (the UnloadModel/submit race)
                 raise RuntimeError("batcher is shut down")
+            # a slot nobody ahead in the queue will take: what wait
+            # follows is for the loop to come round, not for capacity
+            live.slot_free = len(self._free_slots()) > len(self._waiting)
             self._waiting.append(live)
         self._wake.set()
         return RequestHandle(live, self)
@@ -774,6 +813,7 @@ class ContinuousBatcher:
                     live.out_q.put(_END)
                     return
         self._prefill_chunks += 1
+        live.progress_at = time.monotonic()
         # tokens = rows actually consumed this chunk (the FINAL chunk is
         # usually partial — recording the nominal chunk size would
         # overstate the prompt in every chunked timeline)
@@ -792,11 +832,14 @@ class ContinuousBatcher:
                 self._live[live.slot] = live
             self._emit(live, first)
 
+    def _free_slots(self) -> List[int]:
+        return [
+            s for s in self.engine.free_slots() if s != self._reserved_slot
+        ]
+
     def _admit(self) -> None:
         while True:
-            free = [
-                s for s in self.engine.free_slots() if s != self._reserved_slot
-            ]
+            free = self._free_slots()
             if not free:
                 return
             with self._qlock:
@@ -818,7 +861,7 @@ class ContinuousBatcher:
                 # first slot assignment ends the queue wait (requeues —
                 # pool-exhaustion retries, chunked-admission turns — keep
                 # their original boundary)
-                live.admitted_at = time.monotonic()
+                live.admitted_at = live.progress_at = time.monotonic()
                 if self.queue_wait_obs is not None:
                     self.queue_wait_obs.observe(
                         live.admitted_at - live.submitted_at
@@ -829,7 +872,8 @@ class ContinuousBatcher:
                         live.admitted_at - live.submitted_at
                     ) * 1000.0
                     rec.queue_wait_ms = wait_ms
-                    rec.event("queue", wait_ms=round(wait_ms, 3))
+                    rec.event("queue", wait_ms=round(wait_ms, 3),
+                              slot_free=live.slot_free)
             alloc = self.engine.allocator
             if alloc is not None and alloc.replicas > 1:
                 # dp-partitioned pool: admit onto the replica with the
@@ -988,7 +1032,8 @@ class ContinuousBatcher:
         loop's dispatch-site handling."""
         t0 = time.monotonic()
         try:
-            tokens = tick.pending.wait()
+            with self.phases.phase("engine.readback"):
+                tokens = tick.pending.wait()
         except PoolExhausted as e:
             self._gap_wait += time.monotonic() - t0
             # the depth-2 buffer already issued the NEXT dispatch against
@@ -1032,12 +1077,21 @@ class ContinuousBatcher:
                     self._emit(
                         live, int(row[slot]), slot_len=int(lrow[slot])
                     )
+            self._progressed(tick.lives.values())
             return
         for row in tokens:
             for slot, live in tick.lives.items():
                 if live.done:
                     continue
                 self._emit(live, int(row[slot]), slot_len=int(lengths[slot]))
+        self._progressed(tick.lives.values())
+
+    @staticmethod
+    def _progressed(lives) -> None:
+        """One stamp for every request a dispatch just emitted for."""
+        now = time.monotonic()
+        for live in lives:
+            live.progress_at = now
 
     def _mega_operands(
         self, slots: Dict[int, "_Live"]
@@ -1077,7 +1131,8 @@ class ContinuousBatcher:
         obs.ENGINE_DISPATCH_FLUSHES.labels(
             model=self.engine.cfg.name, cause=cause
         ).inc()
-        self._consume(tick)
+        with self.phases.phase("batcher.emit"):
+            self._consume(tick)
 
     def _note_dispatch(self) -> Optional[float]:
         """Record and return the host gap since the previous decode
@@ -1301,43 +1356,44 @@ class ContinuousBatcher:
 
         Returns "evicted", "empty" (nothing live to evict), or "blocked"
         (only higher-priority victims exist)."""
-        # land the in-flight pipelined tokens first: the victim keeps what
-        # it already produced (matching the sync loop), and a retirement
-        # during the flush may itself free the pages this hunt is after
-        self._flush_pending("evict")
-        alloc = self.engine.allocator
-        with self._lock:
-            candidates = [
-                l for l in self._live.values()
-                if replica is None or alloc.replica_of(l.slot) == replica
-            ]
-            if not candidates:
-                return "empty"
-            victim = min(
-                candidates,
-                key=lambda l: (
-                    l.req.priority, -self.engine.slot_length(l.slot)
-                ),
+        with self.phases.phase("batcher.evict"):
+            # land the in-flight pipelined tokens first: the victim keeps what
+            # it already produced (matching the sync loop), and a retirement
+            # during the flush may itself free the pages this hunt is after
+            self._flush_pending("evict")
+            alloc = self.engine.allocator
+            with self._lock:
+                candidates = [
+                    l for l in self._live.values()
+                    if replica is None or alloc.replica_of(l.slot) == replica
+                ]
+                if not candidates:
+                    return "empty"
+                victim = min(
+                    candidates,
+                    key=lambda l: (
+                        l.req.priority, -self.engine.slot_length(l.slot)
+                    ),
+                )
+            if (
+                requester_priority is not None
+                and victim.req.priority > requester_priority
+            ):
+                return "blocked"
+            log.warning(
+                "KV page pool exhausted; retiring lowest-priority longest "
+                "request %s (priority %d, %d rows) to free pages",
+                victim.req.request_id,
+                victim.req.priority,
+                self.engine.slot_length(victim.slot),
             )
-        if (
-            requester_priority is not None
-            and victim.req.priority > requester_priority
-        ):
-            return "blocked"
-        log.warning(
-            "KV page pool exhausted; retiring lowest-priority longest "
-            "request %s (priority %d, %d rows) to free pages",
-            victim.req.request_id,
-            victim.req.priority,
-            self.engine.slot_length(victim.slot),
-        )
-        self.pool_evictions += 1
-        self._obs_evictions.inc()
-        # the victim's stream is a truncation: mark it aborted so the
-        # serving layer returns an error/resubmittable status instead of
-        # a silently short normal completion
-        self._finish(victim, abort_reason="evicted: KV pool exhausted")
-        return "evicted"
+            self.pool_evictions += 1
+            self._obs_evictions.inc()
+            # the victim's stream is a truncation: mark it aborted so the
+            # serving layer returns an error/resubmittable status instead of
+            # a silently short normal completion
+            self._finish(victim, abort_reason="evicted: KV pool exhausted")
+            return "evicted"
 
     def _abort_all(self, exc: BaseException) -> None:
         """A scheduler-thread failure must surface, not strand callers: every
@@ -1381,11 +1437,143 @@ class ContinuousBatcher:
             live.out_q.put(_END)
 
     def _run(self) -> None:
+        self.phases.tick_thread = threading.get_ident()
         while not self._stop:
             try:
                 self._tick()
             except Exception as exc:  # noqa: BLE001
                 self._abort_all(exc)
+
+    # -- the loop's own record (stalls, requests that stopped moving) -------
+
+    def _tick_done(self, host: Dict[str, float],
+                   under_dispatch: Dict[str, float]) -> None:
+        """Judge the tick that just ended (the next one calls this from
+        inside its first phase, so that the loop's bookkeeping has a
+        name too). A stall is a tick whose host
+        time (flightrec.Phases: everything outside batcher.dispatch and
+        batcher.idle that is no wait on the device) was longer than the
+        last decode dispatch — the device starved for more than a whole
+        dispatch — or whose dispatch took more than STALL_DISPATCH_FACTOR
+        times the running median for its step count. It is counted and
+        leaves one model event naming the phase that took longest. The
+        pipelined loop issues its dispatches without waiting, so it has
+        no dispatch time to judge by and is not judged."""
+        dispatched, self._dispatched = self._dispatched, None
+        if not self.pipeline:
+            self._judge_stall(host, under_dispatch, dispatched)
+        now = time.monotonic()
+        if now - self._progress_checked >= NO_PROGRESS_CHECK_SECS:
+            self._progress_checked = now
+            self._check_progress(now)
+
+    def _judge_stall(self, host: Dict[str, float],
+                     under_dispatch: Dict[str, float],
+                     dispatched: Optional[object]) -> None:
+        stalled, phases = 0.0, None
+        dispatch_s = sum(under_dispatch.values())
+        if dispatched is not None:
+            dur = dispatch_s
+            hist = self._dispatch_hist.get(dispatched)
+            if hist is None:
+                hist = self._dispatch_hist[dispatched] = deque(
+                    maxlen=STALL_MEDIAN_OVER
+                )
+            if len(hist) >= STALL_MEDIAN_MIN:
+                median = statistics.median(hist)
+                if dur > STALL_DISPATCH_FACTOR * median:
+                    stalled, phases = dur - median, under_dispatch
+            hist.append(dur)
+            self._last_dispatch_s = dur
+        host_s = sum(host.values())
+        if self._last_dispatch_s and host_s > max(
+            self._last_dispatch_s, stalled
+        ):
+            stalled, phases = host_s, host
+        if not phases:
+            return
+        self.loop_stalls += 1
+        self.loop_stall_seconds += stalled
+        longest = max(phases, key=phases.get)
+        flightrec.RECORDER.model_event(
+            self.engine.cfg.name, "stall", phase=longest,
+            ms=round(phases[longest] * 1e3, 3),
+            tick_ms=round((host_s + dispatch_s) * 1e3, 3),
+            live=self.active_count, waiting=self.queue_depth(),
+        )
+
+    def _held(self) -> List[Tuple[str, _Live]]:
+        """Every request the batcher holds, with where it sits."""
+        with self._qlock:
+            held = [("_waiting", l) for l in self._waiting]
+        prefilling = self._prefilling
+        if prefilling is not None:
+            held.append(("_prefilling", prefilling[0]))
+        with self._lock:
+            held.extend(("_live", l) for l in self._live.values())
+        return held
+
+    def oldest_no_progress_s(self) -> float:
+        """Seconds since the request that has stood still longest last
+        moved (0 with nothing held)."""
+        now = time.monotonic()
+        return max(
+            (now - l.progress_at for _, l in self._held() if not l.done),
+            default=0.0,
+        )
+
+    def _check_progress(self, now: float) -> None:
+        limit = max(
+            NO_PROGRESS_DISPATCHES * self._last_dispatch_s,
+            NO_PROGRESS_MIN_SECS,
+        )
+        for where, live in self._held():
+            if (live.done or live.stuck_noted
+                    or now - live.progress_at <= limit):
+                continue
+            live.stuck_noted = True
+            state = {
+                "request_id": live.req.request_id,
+                "where": where,
+                "no_progress_s": round(now - live.progress_at, 3),
+                "slot": live.slot,
+                "produced": live.produced,
+                "max_tokens": live.req.max_tokens,
+                "prompt_tokens": len(live.req.prompt_ids),
+                "slot_length": (
+                    self.engine.slot_length(live.slot)
+                    if live.slot >= 0 else 0
+                ),
+                "engine_active": (
+                    bool(self.engine.active[live.slot])
+                    if live.slot >= 0 else False
+                ),
+                "cancelled": live.cancelled,
+                "done": live.done,
+                "live": self.active_count,
+                "waiting": self.queue_depth(),
+                "phases": self.phases.recent(64),
+            }
+            log.warning(
+                "request %s (%s, slot %d, %d of %d tokens) has not moved "
+                "for %.1f s", live.req.request_id, where, live.slot,
+                live.produced, live.req.max_tokens, now - live.progress_at,
+            )
+            # one snapshot an episode: the recorder's cooldown drops the
+            # rest, and the first holds the state worth having
+            flightrec.RECORDER.snapshot(
+                self.engine.cfg.name, "no_progress", sync=False,
+                detail=state,
+            )
+
+    def stats(self) -> Dict[str, float]:
+        """The loop's counters, flat: seconds and count per phase, the
+        stalls, and how long the stillest held request has stood."""
+        out = self.phases.stats()
+        out["loop_stalls"] = self.loop_stalls
+        out["loop_stall_seconds"] = round(self.loop_stall_seconds, 6)
+        out["oldest_no_progress_s"] = round(self.oldest_no_progress_s(), 3)
+        return out
 
     # -- speculative auto-disable (per-proposer EWMA acceptance floor) ------
 
@@ -1530,13 +1718,20 @@ class ContinuousBatcher:
             forced[s_, : len(run)] = run
             counts[s_] = len(run)
         try:
-            gap = self._note_dispatch()
-            t0 = time.monotonic()
-            self.engine.jump_step(forced, counts)
-            self._gap_mark = time.monotonic()
+            with self.phases.phase("batcher.dispatch"):
+                gap = self._note_dispatch()
+                t0 = time.monotonic()
+                self.engine.jump_step(forced, counts)
+                self._gap_mark = time.monotonic()
         except PoolExhausted as e:
             self._evict_longest(e.replica)  # retry next tick
             return True
+        self._dispatched = ("jump", k)
+        with self.phases.phase("batcher.emit"):
+            self._jump_emit(constrained, runs, gap, t0)
+        return True
+
+    def _jump_emit(self, constrained, runs, gap, t0) -> None:
         dur_ms = round((self._gap_mark - t0) * 1e3, 3)
         dev_us = None
         est_us = 0.0
@@ -1568,9 +1763,36 @@ class ContinuousBatcher:
                 self._emit(live, tok)
                 if live.done:
                     break
-        return True
+        self._progressed(by_slot[s_] for s_ in runs)
 
     def _tick(self) -> None:
+        phase = self.phases.phase
+        last_tick = self.phases.take_tick()
+        if self._pending is not None:
+            # ordering fence: the pipelined dispatch handed to the worker
+            # last tick must HOLD the engine lock before this tick issues
+            # any engine call (slot releases, admissions, chunk writes) —
+            # those must land after it, or the slot set it was issued
+            # against could change under it
+            with phase("batcher.fence"):
+                self._pending.pending.wait_started()
+        with phase("batcher.reap"):
+            self._tick_done(*last_tick)
+            self._refresh_rate()
+            self._reap_cancelled()
+        # the next two get a span only in the ticks in which they have
+        # work: every running stream waits through them
+        if self._prefilling is not None:
+            with phase("batcher.prefill"):
+                self._advance_prefill()
+        with self._qlock:
+            anyone_waiting = bool(self._waiting)
+        if anyone_waiting and self._free_slots():
+            with phase("batcher.admit"):
+                self._admit()
+        self._decode_tick()
+
+    def _refresh_rate(self) -> None:
         now = time.monotonic()
         if now - self._rate_t0 >= 1.0:
             rate = self._rate_tokens / (now - self._rate_t0)
@@ -1581,16 +1803,11 @@ class ContinuousBatcher:
                 self.last_tps = rate
             self._rate_tokens = 0
             self._rate_t0 = now
-        if self._pending is not None:
-            # ordering fence: the pipelined dispatch handed to the worker
-            # last tick must HOLD the engine lock before this tick issues
-            # any engine call (slot releases, admissions, chunk writes) —
-            # those must land after it, or the slot set it was issued
-            # against could change under it
-            self._pending.pending.wait_started()
-        self._reap_cancelled()
-        self._advance_prefill()
-        self._admit()
+
+    def _decode_tick(self) -> None:
+        """The tick's second half: one decode dispatch for the live
+        slots and the emission of its tokens, or the idle wait."""
+        phase = self.phases.phase
         with self._lock:
             slots = {s: l for s, l in self._live.items()}
         if slots:
@@ -1612,8 +1829,9 @@ class ContinuousBatcher:
             self._gap_mark = None
             if self._prefilling is not None:
                 return  # nothing to decode; keep chunking
-            self._wake.wait(timeout=0.05)
-            self._wake.clear()
+            with phase("batcher.idle"):
+                self._wake.wait(timeout=0.05)
+                self._wake.clear()
             return
         constrained = [
             (s_, l) for s_, l in slots.items() if l.constraint is not None
@@ -1654,24 +1872,28 @@ class ContinuousBatcher:
                     jnp.stack(rows)
                 )
             try:
-                gap = self._note_dispatch()
-                t0 = time.monotonic()
-                tokens = self.engine.step_masked(mask)
-                self._gap_mark = time.monotonic()
+                with phase("batcher.dispatch"):
+                    gap = self._note_dispatch()
+                    t0 = time.monotonic()
+                    tokens = self.engine.step_masked(mask)
+                    self._gap_mark = time.monotonic()
             except PoolExhausted as e:
                 self._evict_longest(e.replica)
                 return
-            self._rec_dispatch(
-                slots.values(), "decode", 1, gap,
-                self._gap_mark - t0, graph="masked", constrained=True,
-            )
-            for slot, live in list(slots.items()):
-                if live.done:
-                    continue
-                tok = int(tokens[0, slot])
-                if live.constraint is not None:
-                    live.constraint.advance(tok)
-                self._emit(live, tok)
+            self._dispatched = 1
+            with phase("batcher.emit"):
+                self._rec_dispatch(
+                    slots.values(), "decode", 1, gap,
+                    self._gap_mark - t0, graph="masked", constrained=True,
+                )
+                for slot, live in list(slots.items()):
+                    if live.done:
+                        continue
+                    tok = int(tokens[0, slot])
+                    if live.constraint is not None:
+                        live.constraint.advance(tok)
+                    self._emit(live, tok)
+                self._progressed(slots.values())
             return
         # keep admission latency low when someone is waiting (constrained
         # ticks above ignore chunking — they are always 1 step). n is
@@ -1700,129 +1922,31 @@ class ContinuousBatcher:
             self._flush_pending("spec")
             proposed = None
             try:
-                gap = self._note_dispatch()
-                t0 = time.monotonic()
-                if proposer == "draft":
-                    tokens, counts, proposed = self.engine.spec_step_draft(
-                        n, draft_len=self.spec_draft_len
-                    )
-                else:
-                    tokens, counts = self.engine.spec_step(
-                        n, draft_len=self.spec_draft_len,
-                        ngram=self.spec_ngram,
-                    )
-                self._gap_mark = time.monotonic()
+                with phase("batcher.dispatch"):
+                    gap = self._note_dispatch()
+                    t0 = time.monotonic()
+                    if proposer == "draft":
+                        tokens, counts, proposed = (
+                            self.engine.spec_step_draft(
+                                n, draft_len=self.spec_draft_len
+                            )
+                        )
+                    else:
+                        tokens, counts = self.engine.spec_step(
+                            n, draft_len=self.spec_draft_len,
+                            ngram=self.spec_ngram,
+                        )
+                    self._gap_mark = time.monotonic()
             except PoolExhausted as e:
                 self._evict_longest(e.replica)  # retry next tick
                 return
-            dur_ms = round((self._gap_mark - t0) * 1e3, 3)
-            graph = "draft_spec" if proposer == "draft" else "spec"
-            dev_us = None
-            est_us = 0.0
-            if self.engine._devprof is not None:
-                s = self.engine.devprof_take_sample()
-                if s is not None and s[0] == graph:
-                    dev_us = round(s[1] * 1e6, 1)
-                est = self.engine.devprof_est_s(graph)
-                if est:
-                    est_us = est * 1e6 / max(len(slots), 1)
-            consumed: Dict[int, int] = {}
-            for r in range(tokens.shape[0]):
-                for slot, live in list(slots.items()):
-                    if live.done:
-                        continue
-                    consumed[slot] = r + 1  # this round's tokens are served
-                    for j in range(int(counts[r, slot])):
-                        self._emit(live, int(tokens[r, slot, j]))
-                        if live.done:
-                            break
-            for slot, live in slots.items():
-                rounds = consumed.get(slot)
-                rec = live.req.rec
-                if rec is not None and rounds:
-                    # emitted = rounds + accepted drafts for this slot's
-                    # SERVED rounds (the _spec_measure accounting)
-                    rec.event(
-                        "spec", rounds=rounds, proposer=proposer,
-                        emitted=int(counts[:rounds, slot].sum()),
-                        draft_len=self.spec_draft_len, dur_ms=dur_ms,
-                        **({"gap_ms": round(gap * 1e3, 3)}
-                           if gap is not None else {}),
-                        **({"dev_us": dev_us}
-                           if dev_us is not None else {}),
-                    )
-                    rec.device_us += est_us
-            self._spec_measure(proposer, counts, consumed, proposed)
+            self._dispatched = ("spec", proposer, n)
+            with phase("batcher.emit"):
+                self._spec_emit(slots, proposer, tokens, counts, proposed,
+                                gap, t0)
             return
         if self.engine.mega_ticks:
-            # device-resident multi-tick window: ONE megagraph dispatch
-            # runs up to min(n, mega_ticks) decode ticks with sampling,
-            # stop/budget/cap checks on device and early exit the moment
-            # no live slot needs another tick — the host round-trip
-            # (readback, emit, recorder) amortizes over the k real
-            # ticks. Constrained and speculative batches never reach
-            # here (their branches above return first): a constrained
-            # tick's mask depends on every emitted token, so "a
-            # constrained tick is due" is realized as routing, not as a
-            # device predicate. The window size equals the plain loop's
-            # dispatch size, so the key fanout (split(key, K+1)) — and
-            # with it every sampled stream — matches the off arm
-            # key-for-key.
-            window = min(n, self.engine.mega_ticks)
-            cap = self.engine.max_context - 1
-            stuck = [
-                live for slot, live in slots.items()
-                if not live.done and self.engine.slot_length(slot) >= cap
-            ]
-            if stuck:
-                # a slot already AT the context cap can never run a
-                # device tick (the loop's live predicate excludes it) —
-                # finish it here or a 0-tick dispatch would emit nothing
-                # and the scheduler would spin on it forever
-                for live in stuck:
-                    self._finish(live)
-                slots = {s: l for s, l in slots.items() if not l.done}
-                if not slots:
-                    return
-            stops, budgets = self._mega_operands(slots)
-            if self.pipeline:
-                prev = self._pending
-                gap = self._note_dispatch()
-                handle = self.engine.mega_step_async(window, stops, budgets)
-                self._gap_mark = time.monotonic()
-                # recorded with the REQUESTED window; _consume late-joins
-                # the real k (early exit) onto these events
-                evs = self._rec_dispatch(
-                    slots.values(), "decode", window, gap, pipelined=True,
-                    join_sample=False, graph="mega",
-                )
-                self._pending = _PendingTick(handle, slots, tuple(evs))
-                if prev is not None:
-                    self._consume(prev)
-                return
-            try:
-                gap = self._note_dispatch()
-                t0 = time.monotonic()
-                tokens, lengths, k = self.engine.mega_step(
-                    window, stops, budgets
-                )
-                self._gap_mark = time.monotonic()
-            except PoolExhausted as e:
-                self._evict_longest(e.replica)
-                return
-            # k REAL ticks — never the requested window when the device
-            # loop exited early (the SLO/TPOT accounting contract)
-            self._rec_dispatch(
-                slots.values(), "decode", k, gap, self._gap_mark - t0,
-                graph="mega",
-            )
-            for row, lrow in zip(tokens, lengths):
-                for slot, live in list(slots.items()):
-                    if live.done:
-                        continue
-                    self._emit(
-                        live, int(row[slot]), slot_len=int(lrow[slot])
-                    )
+            self._mega_tick(slots, n)
             return
         if self.pipeline:
             # depth-2 double buffer: hand dispatch N+1 to the engine's
@@ -1836,34 +1960,163 @@ class ContinuousBatcher:
             # columns the sync loop would never have dispatched. A
             # PoolExhausted surfaces at consume time (_consume evicts).
             prev = self._pending
-            gap = self._note_dispatch()
-            handle = self.engine.step_async(n)
-            self._gap_mark = time.monotonic()
-            # the worker's timing sample (if this dispatch drew one)
-            # joins these events at consume time — see _consume
-            evs = self._rec_dispatch(
-                slots.values(), "decode", n, gap, pipelined=True,
-                join_sample=False,
-            )
-            self._pending = _PendingTick(handle, slots, tuple(evs))
-            if prev is not None:
-                self._consume(prev)
+            with phase("batcher.dispatch"):
+                gap = self._note_dispatch()
+                handle = self.engine.step_async(n)
+                self._gap_mark = time.monotonic()
+            with phase("batcher.emit"):
+                # the worker's timing sample (if this dispatch drew one)
+                # joins these events at consume time — see _consume
+                evs = self._rec_dispatch(
+                    slots.values(), "decode", n, gap, pipelined=True,
+                    join_sample=False,
+                )
+                self._pending = _PendingTick(handle, slots, tuple(evs))
+                if prev is not None:
+                    self._consume(prev)
             return
         try:
-            gap = self._note_dispatch()
-            t0 = time.monotonic()
-            tokens = self.engine.step(n)  # [n, num_slots]
-            self._gap_mark = time.monotonic()
+            with phase("batcher.dispatch"):
+                gap = self._note_dispatch()
+                t0 = time.monotonic()
+                tokens = self.engine.step(n)  # [n, num_slots]
+                self._gap_mark = time.monotonic()
         except PoolExhausted as e:
             # retire the longest request and retry on the next tick; the
             # failed ensure() left all engine state untouched
             self._evict_longest(e.replica)
             return
-        self._rec_dispatch(
-            slots.values(), "decode", n, gap, self._gap_mark - t0
-        )
-        for step_row in tokens:
+        self._dispatched = n
+        with phase("batcher.emit"):
+            self._rec_dispatch(
+                slots.values(), "decode", n, gap, self._gap_mark - t0
+            )
+            for step_row in tokens:
+                for slot, live in list(slots.items()):
+                    if live.done:
+                        continue
+                    self._emit(live, int(step_row[slot]))
+            self._progressed(slots.values())
+
+    def _spec_emit(self, slots, proposer, tokens, counts, proposed,
+                   gap, t0) -> None:
+        """Emit one speculative dispatch's accepted runs, record it on
+        every served timeline and fold its acceptance into the
+        proposer's EWMA."""
+        dur_ms = round((self._gap_mark - t0) * 1e3, 3)
+        graph = "draft_spec" if proposer == "draft" else "spec"
+        dev_us = None
+        est_us = 0.0
+        if self.engine._devprof is not None:
+            s = self.engine.devprof_take_sample()
+            if s is not None and s[0] == graph:
+                dev_us = round(s[1] * 1e6, 1)
+            est = self.engine.devprof_est_s(graph)
+            if est:
+                est_us = est * 1e6 / max(len(slots), 1)
+        consumed: Dict[int, int] = {}
+        for r in range(tokens.shape[0]):
             for slot, live in list(slots.items()):
                 if live.done:
                     continue
-                self._emit(live, int(step_row[slot]))
+                consumed[slot] = r + 1  # this round's tokens are served
+                for j in range(int(counts[r, slot])):
+                    self._emit(live, int(tokens[r, slot, j]))
+                    if live.done:
+                        break
+        for slot, live in slots.items():
+            rounds = consumed.get(slot)
+            rec = live.req.rec
+            if rec is not None and rounds:
+                # emitted = rounds + accepted drafts for this slot's
+                # SERVED rounds (the _spec_measure accounting)
+                rec.event(
+                    "spec", rounds=rounds, proposer=proposer,
+                    emitted=int(counts[:rounds, slot].sum()),
+                    draft_len=self.spec_draft_len, dur_ms=dur_ms,
+                    **({"gap_ms": round(gap * 1e3, 3)}
+                       if gap is not None else {}),
+                    **({"dev_us": dev_us}
+                       if dev_us is not None else {}),
+                )
+                rec.device_us += est_us
+        self._progressed(slots[s_] for s_ in consumed)
+        self._spec_measure(proposer, counts, consumed, proposed)
+
+    def _mega_tick(self, slots: Dict[int, _Live], n: int) -> None:
+        """Device-resident multi-tick window: ONE megagraph dispatch
+        runs up to min(n, mega_ticks) decode ticks with sampling,
+        stop/budget/cap checks on device and early exit the moment no
+        live slot needs another tick — the host round-trip (readback,
+        emit, recorder) amortizes over the k real ticks. Constrained and
+        speculative batches never reach here (their branches in _tick
+        return first): a constrained tick's mask depends on every
+        emitted token, so "a constrained tick is due" is realized as
+        routing, not as a device predicate. The window size equals the
+        plain loop's dispatch size, so the key fanout (split(key, K+1))
+        — and with it every sampled stream — matches the off arm
+        key-for-key."""
+        phase = self.phases.phase
+        window = min(n, self.engine.mega_ticks)
+        cap = self.engine.max_context - 1
+        stuck = [
+            live for slot, live in slots.items()
+            if not live.done and self.engine.slot_length(slot) >= cap
+        ]
+        if stuck:
+            # a slot already AT the context cap can never run a device
+            # tick (the loop's live predicate excludes it) — finish it
+            # here or a 0-tick dispatch would emit nothing and the
+            # scheduler would spin on it forever
+            with phase("batcher.emit"):
+                for live in stuck:
+                    self._finish(live)
+            slots = {s: l for s, l in slots.items() if not l.done}
+            if not slots:
+                return
+        stops, budgets = self._mega_operands(slots)
+        if self.pipeline:
+            prev = self._pending
+            with phase("batcher.dispatch"):
+                gap = self._note_dispatch()
+                handle = self.engine.mega_step_async(window, stops, budgets)
+                self._gap_mark = time.monotonic()
+            with phase("batcher.emit"):
+                # recorded with the REQUESTED window; _consume late-joins
+                # the real k (early exit) onto these events
+                evs = self._rec_dispatch(
+                    slots.values(), "decode", window, gap, pipelined=True,
+                    join_sample=False, graph="mega",
+                )
+                self._pending = _PendingTick(handle, slots, tuple(evs))
+                if prev is not None:
+                    self._consume(prev)
+            return
+        try:
+            with phase("batcher.dispatch"):
+                gap = self._note_dispatch()
+                t0 = time.monotonic()
+                tokens, lengths, k = self.engine.mega_step(
+                    window, stops, budgets
+                )
+                self._gap_mark = time.monotonic()
+        except PoolExhausted as e:
+            self._evict_longest(e.replica)
+            return
+        # judged against windows that ran as many REAL ticks
+        self._dispatched = ("mega", k)
+        with phase("batcher.emit"):
+            # k REAL ticks — never the requested window when the device
+            # loop exited early (the SLO/TPOT accounting contract)
+            self._rec_dispatch(
+                slots.values(), "decode", k, gap, self._gap_mark - t0,
+                graph="mega",
+            )
+            for row, lrow in zip(tokens, lengths):
+                for slot, live in list(slots.items()):
+                    if live.done:
+                        continue
+                    self._emit(
+                        live, int(row[slot]), slot_len=int(lrow[slot])
+                    )
+            self._progressed(slots.values())

@@ -357,6 +357,9 @@ class TPUEngine:
             b for b in DEFAULT_BUCKETS if b <= self.max_context
         ) or (self.max_context,)
         self._lock = make_lock("engine")
+        # the dispatch bodies' phases (flightrec.PHASES); the batcher
+        # that drives this engine adds its own to the same object
+        self.phases = flightrec.Phases(cfg.name)
         self.plan = shardings
         # normalize the quantize knob to a mode: True -> int8 (the measured
         # single-chip default), "int4" -> packed-nibble group-wise int4
@@ -1314,12 +1317,13 @@ class TPUEngine:
                 moe_impl=self._moe_impl,
                 qmm=self._qmm_impl,
             )
-        if mask is not None:
-            logits = logits + mask
-        next_tokens = sampling.sample(
-            logits, sub, st["temps"], st["top_ps"],
-            exact=mask is not None,
-        )
+        with jax.named_scope("sampling"):
+            if mask is not None:
+                logits = logits + mask
+            next_tokens = sampling.sample(
+                logits, sub, st["temps"], st["top_ps"],
+                exact=mask is not None,
+            )
         slots = jnp.arange(self.num_slots)
         # new token's history col is lengths+1 (<= C, inside the pad);
         # inactive slots — retired or MID-CHUNKED-PREFILL — write to the
@@ -2035,7 +2039,8 @@ class TPUEngine:
             if not state["first"]:
                 return fn(*args, **kwargs)
             t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
+            with self.phases.phase("engine.compile"):
+                out = fn(*args, **kwargs)
             dt = time.perf_counter() - t0
             state["first"] = False
             self.compile_seconds += dt
@@ -3165,7 +3170,7 @@ class TPUEngine:
         padded = np.zeros((1, bucket), dtype=np.int32)
         padded[0, :true_len] = token_ids
 
-        with self._lock:
+        with self.phases.phase("engine.prefill"), self._lock:
             args = [
                 self.params,
                 self.state,
@@ -3221,7 +3226,7 @@ class TPUEngine:
         bucket = self.bucket_for(true_len)
         padded = np.zeros((1, bucket), dtype=np.int32)
         padded[0, :true_len] = ids
-        with self._lock:
+        with self.phases.phase("engine.prefill"), self._lock:
             self.allocator.ensure(slot, true_len)
             dtok = self._devprof_note("seq_prefill", bucket)
             self.state, first = self._seq_prefill_fn(bucket)(
@@ -3310,40 +3315,49 @@ class TPUEngine:
         array, post-dispatch host lengths). ``started`` (the step_async
         worker path) is set the moment the engine lock is held, so a
         caller can fence later engine calls behind this dispatch."""
+        ph = self.phases
         try:
+            lock_wait = ph.begin("engine.lock_wait")
             with self._lock:
+                ph.end(lock_wait)
                 if started is not None:
                     started.set()
-                tables = ()
-                if self.paged:
-                    self._back_active_slots(n_steps)
-                    tables = (self._tables_operand(),)
-                if self.unified_step:
-                    fn, m = self._unified_fn(n_steps)
-                    # worker dispatches sample only with double-buffer
-                    # slack (nothing queued behind this one), so a
-                    # measurement never delays the next submission
-                    dtok = self._devprof_note(
-                        "step", ("uni", m), need_slack=started is not None
-                    )
-                    self.state, tokens = fn(
-                        self.params, self.state, *tables, jnp.int32(n_steps)
-                    )
-                else:
-                    fn = self._step_fn(n_steps)
-                    dtok = self._devprof_note(
-                        "step", n_steps, need_slack=started is not None
-                    )
-                    self.state, tokens = fn(
-                        self.params, self.state, *tables
-                    )
+                with ph.phase("engine.enqueue", n=n_steps,
+                              occ=int(self.active.sum())):
+                    tables = ()
+                    if self.paged:
+                        self._back_active_slots(n_steps)
+                        tables = (self._tables_operand(),)
+                    if self.unified_step:
+                        fn, m = self._unified_fn(n_steps)
+                        # worker dispatches sample only with
+                        # double-buffer slack (nothing queued behind this
+                        # one), so a measurement never delays the next
+                        # submission
+                        dtok = self._devprof_note(
+                            "step", ("uni", m),
+                            need_slack=started is not None,
+                        )
+                        self.state, tokens = fn(
+                            self.params, self.state, *tables,
+                            jnp.int32(n_steps),
+                        )
+                    else:
+                        fn = self._step_fn(n_steps)
+                        dtok = self._devprof_note(
+                            "step", n_steps, need_slack=started is not None
+                        )
+                        self.state, tokens = fn(
+                            self.params, self.state, *tables
+                        )
                 self.decode_steps += n_steps
                 self._obs_decode_steps.inc(n_steps)
                 self._host_lengths = np.minimum(
                     self._host_lengths + n_steps, self.max_context - 1
                 )
                 lengths = self._host_lengths.copy()
-            host_tokens = np.asarray(tokens)[:n_steps]
+            with ph.phase("engine.readback"):
+                host_tokens = np.asarray(tokens)[:n_steps]
             # the readback above already blocked until the tokens
             # materialized, so the sample is the graph-call -> ready
             # delta at zero extra synchronization
@@ -3399,34 +3413,40 @@ class TPUEngine:
         under the lock in ``_step_dispatch``; on TPU this serializes
         admissions behind the window's device execution (the documented
         K>1 tradeoff, docs/ENGINE_PERF.md)."""
+        ph = self.phases
         try:
+            lock_wait = ph.begin("engine.lock_wait")
             with self._lock:
+                ph.end(lock_wait)
                 if started is not None:
                     started.set()
-                tables = ()
-                if self.paged:
-                    self._back_active_slots(n_ticks)
-                    tables = (self._tables_operand(),)
-                abort_after = n_ticks
-                act = faults.point("pool.megatick_abort", self.cfg.name)
-                if act is not None and n_ticks > 1:
-                    # injected host-attention demand: cap the device loop
-                    # mid-window (ticks param, default half the window) —
-                    # the early-exit path fires with slots still live
-                    abort_after = min(
-                        max(act.ticks or n_ticks // 2, 1), n_ticks - 1
+                with ph.phase("engine.enqueue", n=n_ticks,
+                              occ=int(self.active.sum())):
+                    tables = ()
+                    if self.paged:
+                        self._back_active_slots(n_ticks)
+                        tables = (self._tables_operand(),)
+                    abort_after = n_ticks
+                    act = faults.point("pool.megatick_abort", self.cfg.name)
+                    if act is not None and n_ticks > 1:
+                        # injected host-attention demand: cap the device loop
+                        # mid-window (ticks param, default half the window) —
+                        # the early-exit path fires with slots still live
+                        abort_after = min(
+                            max(act.ticks or n_ticks // 2, 1), n_ticks - 1
+                        )
+                    fn, m = self._mega_fn(n_ticks)
+                    dtok = self._devprof_note(
+                        "mega", m, need_slack=started is not None
                     )
-                fn, m = self._mega_fn(n_ticks)
-                dtok = self._devprof_note(
-                    "mega", m, need_slack=started is not None
-                )
-                self.state, tokens, k_dev = fn(
-                    self.params, self.state, *tables, jnp.int32(n_ticks),
-                    jnp.asarray(stops, jnp.int32),
-                    jnp.asarray(budgets, jnp.int32),
-                    jnp.int32(abort_after),
-                )
-                k = int(k_dev)
+                    self.state, tokens, k_dev = fn(
+                        self.params, self.state, *tables, jnp.int32(n_ticks),
+                        jnp.asarray(stops, jnp.int32),
+                        jnp.asarray(budgets, jnp.int32),
+                        jnp.int32(abort_after),
+                    )
+                with ph.phase("engine.readback"):
+                    k = int(k_dev)
                 self.mega_dispatches += 1
                 self.mega_tick_total += k
                 self.decode_steps += k
@@ -3443,7 +3463,8 @@ class TPUEngine:
                 base[None, :] + np.arange(1, k + 1, dtype=np.int64)[:, None],
                 self.max_context - 1,
             )
-            host_tokens = np.asarray(tokens)[:k]
+            with ph.phase("engine.readback"):
+                host_tokens = np.asarray(tokens)[:k]
             sample_s = self._devprof_sample(dtok)
             return host_tokens, lengths, k, sample_s
         finally:
@@ -4186,7 +4207,7 @@ class ChunkedPrefill:
         bucket = eng.bucket_for(n) if final else self.chunk
         padded = np.zeros((1, bucket), dtype=np.int32)
         padded[0, :n] = self.ids[self.pos : self.pos + n]
-        with eng._lock:
+        with eng.phases.phase("engine.prefill"), eng._lock:
             extra = ()
             if eng.paged:
                 # back this chunk's rows before dispatching; PoolExhausted

@@ -1076,12 +1076,20 @@ def decode_step_paged(
         # survive into their (ignored) mask either
         win_starts = jnp.where(act, win_starts, 0)
 
-    x = params["embed"][tokens][:, None, :]  # [B, 1, E]
-    cos, sin = rope_tables(lengths[:, None], cfg.head_dim, cfg.rope_theta)
+    # jax.named_scope below names the parts of one step in the operations'
+    # metadata (what a profile's op_name shows); no module or instruction
+    # is renamed by it
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens][:, None, :]  # [B, 1, E]
+        cos, sin = rope_tables(lengths[:, None], cfg.head_dim, cfg.rope_theta)
+    ffn_scope = "moe" if cfg.num_experts else "ffn"
 
     def block(carry, layer):
         x, k_pool, v_pool, *scales = carry
         lp, l = layer
+        # no scope of its own: one around the projection renumbers the
+        # compiled graph's instructions, and the benchmark's records name
+        # operations by those numbers
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
         if pool_impl is not None:
             # the shard_map twin writes and attends one layer's pool slice
@@ -1102,48 +1110,60 @@ def decode_step_paged(
             attn = attn[:, None]
         elif quant_pool:
             k_s, v_s = scales
-            k_pool, k_s = scatter_quant(
-                k_pool, k_s, (l, pages, offs), k_new[:, 0]
-            )
-            v_pool, v_s = scatter_quant(
-                v_pool, v_s, (l, pages, offs), v_new[:, 0]
-            )
-            attn = paged_int8_attend(
-                q[:, 0], k_pool[l], v_pool[l], k_s[l], v_s[l], tables,
-                read_lengths,
-                window=cfg.sliding_window,
-                use_int8_kernel=use_int8_kernel,
-                win_starts=win_starts, sink=sink_rows,
-            )[:, None]
-            scales = (k_s, v_s)
-        else:
-            k_pool = k_pool.at[l, pages, offs].set(
-                k_new[:, 0].astype(k_pool.dtype)
-            )
-            v_pool = v_pool.at[l, pages, offs].set(
-                v_new[:, 0].astype(v_pool.dtype)
-            )
-            if use_kernel:
-                attn = ops.paged_decode_attention(
-                    q[:, 0], k_pool[l], v_pool[l], tables, read_lengths,
+            with jax.named_scope("kv_write"):
+                k_pool, k_s = scatter_quant(
+                    k_pool, k_s, (l, pages, offs), k_new[:, 0]
+                )
+                v_pool, v_s = scatter_quant(
+                    v_pool, v_s, (l, pages, offs), v_new[:, 0]
+                )
+            with jax.named_scope("page_gather"):
+                k_l, v_l, k_sl, v_sl = k_pool[l], v_pool[l], k_s[l], v_s[l]
+            with jax.named_scope("attention"):
+                attn = paged_int8_attend(
+                    q[:, 0], k_l, v_l, k_sl, v_sl, tables,
+                    read_lengths,
                     window=cfg.sliding_window,
-                    win_starts=win_starts,
-                    sink=sink_rows if win_starts is not None else None,
-                )[:, None]
-            else:
-                attn = ops.paged_decode_attention_reference(
-                    q[:, 0], k_pool[l], v_pool[l], tables, read_lengths,
-                    window=cfg.sliding_window,
+                    use_int8_kernel=use_int8_kernel,
                     win_starts=win_starts, sink=sink_rows,
                 )[:, None]
-        x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
-        x = x + _mlp(x, lp, cfg, moe_impl, qmm)
+            scales = (k_s, v_s)
+        else:
+            with jax.named_scope("kv_write"):
+                k_pool = k_pool.at[l, pages, offs].set(
+                    k_new[:, 0].astype(k_pool.dtype)
+                )
+                v_pool = v_pool.at[l, pages, offs].set(
+                    v_new[:, 0].astype(v_pool.dtype)
+                )
+            # this layer's pages out of the pool the scan carries
+            with jax.named_scope("page_gather"):
+                k_l, v_l = k_pool[l], v_pool[l]
+            with jax.named_scope("attention"):
+                if use_kernel:
+                    attn = ops.paged_decode_attention(
+                        q[:, 0], k_l, v_l, tables, read_lengths,
+                        window=cfg.sliding_window,
+                        win_starts=win_starts,
+                        sink=sink_rows if win_starts is not None else None,
+                    )[:, None]
+                else:
+                    attn = ops.paged_decode_attention_reference(
+                        q[:, 0], k_l, v_l, tables, read_lengths,
+                        window=cfg.sliding_window,
+                        win_starts=win_starts, sink=sink_rows,
+                    )[:, None]
+        with jax.named_scope("attn_out"):
+            x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
+        with jax.named_scope(ffn_scope):
+            x = x + _mlp(x, lp, cfg, moe_impl, qmm)
         return (x, k_pool, v_pool, *scales), None
 
     x, k_pool, v_pool, scales = _scan_layers_over_pool(
         block, x, params["layers"], k_pool, v_pool, cache_scales
     )
-    logits = _final_logits(x[:, 0], params, cfg, qmm)
+    with jax.named_scope("final_logits"):
+        logits = _final_logits(x[:, 0], params, cfg, qmm)
     if quant_pool:
         return logits, k_pool, v_pool, scales
     return logits, k_pool, v_pool

@@ -26,6 +26,12 @@ Timelines correlate with the span tree through the request's trace id:
 ``tracing.set_exporter`` hook so finished RPC spans fold into the
 matching timeline as ``span`` events.
 
+The scheduler loop has a record of its own beside the requests':
+``Phases`` closes each phase of a batcher tick and of an engine dispatch
+body (the closed list ``PHASES``) into a ``jax.profiler.TraceAnnotation``,
+always-on counters and a bounded per-model ring — the "scheduler" track
+of ``/debug/trace`` — all on ``time.monotonic()``.
+
 Export surfaces (obs/http.py): ``/debug/requests`` (recent timelines as
 JSON), ``/debug/trace`` (Chrome trace-event / Perfetto JSON),
 ``/debug/spans`` (the finished-span ring), and anomaly auto-snapshots —
@@ -93,6 +99,10 @@ EVENT_KINDS = (
     # window + snapshot + fault journal + devprof + lock-watchdog state
     # around an anomaly trigger (aios_tpu/obs/incidents.py)
     "incident",
+    # "stall": one scheduler tick that starved the device for more than
+    # a whole decode dispatch, or a dispatch over twice its running
+    # median (model lane; engine/batching.py _tick_done)
+    "stall",
 )
 
 # Shed causes — THE closed enum; serving/admission.py raises with these
@@ -131,8 +141,33 @@ RETRYABLE_ABORT_CAUSES = ("scheduler_failed", "evicted")
 STATES = ("live", "retired", "cancelled", "aborted", "shed")
 
 # Anomaly snapshot causes.
+# "no_progress": a request the batcher holds that has not moved for
+# NO_PROGRESS_DISPATCHES dispatches (engine/batching.py).
 SNAPSHOT_CAUSES = ("shed_spike", "crash_respawn", "slo_breach", "abort",
-                   "manual")
+                   "manual", "no_progress")
+
+# Scheduler-loop phases — THE closed list (closing a phase of any other
+# name raises). batcher.* wrap the parts of ContinuousBatcher._tick:
+# fence (wait_started of a pipelined dispatch), reap (_reap_cancelled
+# and the loop's bookkeeping: the last tick's stall verdict, the rate
+# gauge, the no-progress check), prefill (_advance_prefill, in the ticks
+# that have one), admit (_admit, likewise), idle (the 50 ms wake
+# wait), dispatch (_note_dispatch through the engine call's return),
+# emit (the token loops, _consume, _finish, _rec_close), evict
+# (_evict_longest). engine.* wrap the dispatch bodies: lock_wait (until
+# the engine lock is held), enqueue (lock held until the graph call
+# returns), readback (the blocking device->host copy of the tokens),
+# prefill (one prefill / chunk dispatch, lock to first token), compile
+# (the first call of a lazily compiled graph).
+PHASES = (
+    "batcher.fence", "batcher.reap", "batcher.prefill", "batcher.admit",
+    "batcher.idle", "batcher.dispatch", "batcher.emit", "batcher.evict",
+    "engine.lock_wait", "engine.enqueue", "engine.readback",
+    "engine.prefill", "engine.compile",
+)
+# phases that wait on the device, not on the host: a tick's host time
+# leaves them out wherever they nest
+DEVICE_WAIT_PHASES = ("engine.prefill", "engine.readback")
 
 
 def abort_cause(reason: str) -> str:
@@ -168,6 +203,10 @@ SHED_SPIKE_WINDOW_SECS = 10.0
 
 # trace_id -> timeline index bound (client-driven cardinality).
 _MAX_TRACE_INDEX = 4096
+
+# Scheduler phase spans kept per model: ~8 spans a tick, so a few
+# minutes of a loaded loop and half a minute of an idle one.
+PHASE_RING = 4096
 
 
 class Timeline:
@@ -287,6 +326,12 @@ class FlightRecorder:
         self._lock = make_lock("recorder")
         self._rings: Dict[str, deque] = {}  #: guarded_by _lock
         self._model_events: Dict[str, deque] = {}  #: guarded_by _lock
+        # scheduler phase spans (name, t0, t1) on time.monotonic(), one
+        # ring per model; appended lock-free by the loop's own threads
+        self._phases: Dict[str, deque] = {}  #: guarded_by _lock
+        # one (wall, monotonic) pair: what places monotonic instants on
+        # the Chrome trace's wall-clock axis
+        self.wall0, self.mono0 = time.time(), time.monotonic()
         # trace_id -> recent timelines sharing it: an agent task's RPCs
         # all propagate ONE traceparent, so a single-slot map would make
         # every begin() steal the previous request's span correlation
@@ -413,6 +458,23 @@ class FlightRecorder:
                 model, deque(maxlen=MAX_EVENTS)
             ).append(entry)
 
+    # -- scheduler phases (Phases below writes, the debug routes read) ------
+
+    def phase_ring(self, model: str) -> deque:
+        with self._lock:
+            return self._phases.setdefault(model, deque(maxlen=PHASE_RING))
+
+    def phases(self, model: str = "") -> List[tuple]:
+        """Phase spans as (model, name, t0, t1) tuples on the monotonic
+        clock, each model's oldest first."""
+        with self._lock:
+            rings = (
+                {model: self._phases.get(model, ())}
+                if model else dict(self._phases)
+            )
+        # list(deque) is atomic under the GIL: the loop appends unlocked
+        return [(m, *span) for m, ring in rings.items() for span in list(ring)]
+
     # -- span folding (the dormant tracing.set_exporter hook) --------------
 
     def export_span(self, span) -> None:
@@ -421,8 +483,9 @@ class FlightRecorder:
         server spans close AFTER the request retires). An agent task's
         RPCs share ONE propagated traceparent, so among the trace's
         recent timelines the span lands on the newest one whose lifetime
-        overlaps it (1 s grace for clock jitter), not blindly on the
-        latest begin()."""
+        overlaps it — both on time.monotonic(), the clock of the
+        timelines and the scheduler phases — not blindly on the latest
+        begin()."""
         if not self.enabled:
             return
         with self._lock:
@@ -430,12 +493,12 @@ class FlightRecorder:
             candidates = list(peers) if peers else ()
         if not candidates:
             return
-        start = getattr(span, "start", 0.0)
-        end = getattr(span, "end", 0.0) or time.time()
+        start = getattr(span, "start_mono", 0.0)
+        end = getattr(span, "end_mono", 0.0) or time.monotonic()
         tl = candidates[-1]
         for cand in reversed(candidates):  # newest first
-            cand_end = cand.t0_wall + cand.duration_ms / 1000.0
-            if cand.t0_wall - 1.0 <= end and start <= cand_end + 1.0:
+            cand_end = cand.finished_at or time.monotonic()
+            if cand.t0 <= end and start <= cand_end:
                 tl = cand
                 break
         tl.event(
@@ -470,8 +533,8 @@ class FlightRecorder:
 
     # -- anomaly snapshots ---------------------------------------------------
 
-    def snapshot(self, model: str, cause: str,
-                 sync: bool = True) -> Optional[dict]:
+    def snapshot(self, model: str, cause: str, sync: bool = True,
+                 detail: Optional[dict] = None) -> Optional[dict]:
         """Freeze the model's last N timelines (+ model-lane events) so a
         transient anomaly survives ring churn. Cooldown-limited per
         (model, cause); returns the snapshot dict, or None when skipped
@@ -480,7 +543,8 @@ class FlightRecorder:
         scheduler / gRPC threads, and the O(ring x events) to_dict()
         pass must not stall decode scheduling exactly while the plane is
         degraded). The cooldown stamp and snapshot id are still claimed
-        synchronously, so a burst of triggers freezes exactly one."""
+        synchronously, so a burst of triggers freezes exactly one.
+        ``detail`` is the trigger's own evidence, kept as given."""
         if cause not in SNAPSHOT_CAUSES:
             cause = "manual"
         now = time.monotonic()
@@ -499,14 +563,15 @@ class FlightRecorder:
         if not sync:
             threading.Thread(
                 target=self._build_snapshot,
-                args=(snap_id, model, cause, tls, lane),
+                args=(snap_id, model, cause, tls, lane, detail),
                 name="flightrec-snapshot", daemon=True,
             ).start()
             return None
-        return self._build_snapshot(snap_id, model, cause, tls, lane)
+        return self._build_snapshot(snap_id, model, cause, tls, lane, detail)
 
     def _build_snapshot(self, snap_id: int, model: str, cause: str,
-                        tls: list, lane: list) -> dict:
+                        tls: list, lane: list,
+                        detail: Optional[dict] = None) -> dict:
         snap = {
             "id": snap_id,
             "model": model,
@@ -517,6 +582,8 @@ class FlightRecorder:
                 {"t_wall": w, "kind": k, **f} for _, w, k, f in lane
             ],
         }
+        if detail is not None:
+            snap["detail"] = detail
         with self._lock:
             self._snapshots.append(snap)
         # Every fired snapshot is also an incident trigger: the bundle
@@ -558,10 +625,137 @@ class FlightRecorder:
         with self._lock:
             self._rings.clear()
             self._model_events.clear()
+            for ring in self._phases.values():
+                ring.clear()  # Phases objects keep their ring: empty it
             self._by_trace.clear()
             self._snapshots.clear()
             self._snapshot_at.clear()
             self._shed_marks.clear()
+
+
+# -- scheduler phases ---------------------------------------------------------
+
+
+class _Span:
+    """One open phase. ``Phases.begin`` returns it; leaving a ``with``
+    block or ``Phases.end`` closes it."""
+
+    __slots__ = ("owner", "name", "t0", "ann", "inner", "root")
+
+    def __init__(self, owner: "Phases", name: str) -> None:
+        self.owner = owner
+        self.name = name
+        self.inner = 0.0  # seconds of the phases that nested in this one
+        self.ann = self.root = None
+
+    def __enter__(self) -> "_Span":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.owner.end(self)
+        return False
+
+
+class Phases:
+    """What the scheduler loop of one model replica is doing, by phase.
+
+    The engine makes one and its batcher shares it. Closing a phase
+    writes to three places: a ``jax.profiler.TraceAnnotation`` (so the
+    phase lies in the host plane of the profiler's trace, on the same
+    clock as the device's programs; a flag test when no profile is being
+    taken), the always-on ``seconds`` / ``counts`` per phase (summed into
+    ``ServingPool.stats()``), and — recorder enabled — the model's
+    bounded ring of (name, t0, t1) on ``time.monotonic()`` that
+    ``chrome_trace`` renders as the "scheduler" track.
+
+    On the scheduler thread (``tick_thread``) phases also nest, and
+    each closes with its OWN seconds, its children's left out, into one
+    of two per-tick sums: ``tick_host`` — the tick's host time,
+    everything outside ``batcher.dispatch`` / ``batcher.idle`` that is
+    not a wait on the device — or ``tick_dispatch``, what lay under
+    ``batcher.dispatch``. The batcher reads and resets both once a tick
+    (``take_tick``)."""
+
+    def __init__(self, model: str) -> None:
+        # imported here: the other services import obs and stay JAX-free
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._ring = RECORDER.phase_ring(model)
+        # the scheduler thread, the pipelined dispatch worker and direct
+        # engine callers all close phases: the two sums share one lock
+        self._sums = threading.Lock()
+        self.seconds: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.counts: Dict[str, int] = dict.fromkeys(PHASES, 0)
+        self.tick_thread = 0
+        self.tick_host: Dict[str, float] = {}
+        self.tick_dispatch: Dict[str, float] = {}
+        self._stack: List[_Span] = []
+
+    def begin(self, name: str, **args) -> _Span:
+        """Open phase ``name`` (one of PHASES). ``args`` ride on the
+        trace annotation only."""
+        span = _Span(self, name)
+        if self._annotation.is_enabled():
+            span.ann = self._annotation(name, **args)
+            span.ann.__enter__()
+        if threading.get_ident() == self.tick_thread:
+            stack = self._stack
+            span.root = stack[0].name if stack else name
+            stack.append(span)
+        span.t0 = time.monotonic()
+        return span
+
+    phase = begin  # reads better in a ``with``
+
+    def end(self, span: _Span) -> None:
+        t1 = time.monotonic()
+        dt = t1 - span.t0
+        name = span.name
+        with self._sums:
+            self.seconds[name] += dt  # KeyError: not one of PHASES
+            self.counts[name] += 1
+        if span.ann is not None:
+            span.ann.__exit__(None, None, None)
+        if RECORDER.enabled:
+            self._ring.append((name, span.t0, t1))
+        if span.root is not None:
+            stack = self._stack
+            stack.pop()
+            if stack:
+                stack[-1].inner += dt
+            own = dt - span.inner
+            if span.root == "batcher.dispatch":
+                into = self.tick_dispatch
+            elif span.root == "batcher.idle" or name in DEVICE_WAIT_PHASES:
+                return
+            else:
+                into = self.tick_host
+            into[name] = into.get(name, 0.0) + own
+
+    def take_tick(self) -> Tuple[Dict[str, float], Dict[str, float]]:
+        """(host, under-dispatch) own seconds per phase of the tick that
+        just ended, and a clean slate for the next."""
+        out = self.tick_host, self.tick_dispatch
+        self.tick_host, self.tick_dispatch = {}, {}
+        del self._stack[:]
+        return out
+
+    def recent(self, n: int) -> List[dict]:
+        """The last ``n`` spans of this model, for a snapshot."""
+        now = time.monotonic()
+        return [
+            {"name": name, "ago_ms": round((now - t0) * 1e3, 3),
+             "dur_ms": round((t1 - t0) * 1e3, 3)}
+            for name, t0, t1 in list(self._ring)[-n:]
+        ]
+
+    def stats(self) -> Dict[str, float]:
+        out: Dict[str, float] = {}
+        for name in PHASES:
+            out[f"phase_{name}_seconds"] = self.seconds[name]
+            out[f"phase_{name}_count"] = self.counts[name]
+        return out
 
 
 # -- Chrome trace-event export ----------------------------------------------
@@ -599,14 +793,22 @@ def _tl_view(tl) -> tuple:
     )
 
 
-def chrome_trace(timelines: list, model_events: List[tuple] = ()) -> dict:
+# the scheduler track's thread id: above any request's (those count from 1)
+_SCHEDULER_TID = 1 << 20
+
+
+def chrome_trace(timelines: list, model_events: List[tuple] = (),
+                 phases: List[tuple] = ()) -> dict:
     """Render timelines (live :class:`Timeline` objects or a snapshot's
     frozen dicts) as Chrome trace-event JSON (chrome://tracing /
     Perfetto "JSON Object Format"): one pid per model, one tid per
     request, X (complete) events for the request envelope + queue wait +
     dur-carrying dispatches, i (instant) events for decisions. ts/dur
     are microseconds of wall time. ``model_events`` are the recorder's
-    (wall_ts, model, kind, fields) lane tuples, rendered on tid 0."""
+    (wall_ts, model, kind, fields) lane tuples, rendered on tid 0;
+    ``phases`` its (model, name, t0, t1) scheduler spans, one more track
+    per model, put on the wall axis through the recorder's one (wall,
+    monotonic) pair."""
     events: List[dict] = []
     pids: Dict[str, int] = {}
 
@@ -668,6 +870,21 @@ def chrome_trace(timelines: list, model_events: List[tuple] = ()) -> dict:
         events.append({
             "ph": "i", "pid": pid_of(model), "tid": 0, "name": kind,
             "cat": kind, "ts": wall * 1e6, "s": "p", "args": dict(fields),
+        })
+    wall0, mono0 = RECORDER.wall0, RECORDER.mono0
+    tracked = set()
+    for model, name, t0, t1 in phases:
+        pid = pid_of(model)
+        if pid not in tracked:
+            tracked.add(pid)
+            events.append({
+                "ph": "M", "pid": pid, "tid": _SCHEDULER_TID,
+                "name": "thread_name", "args": {"name": "scheduler"},
+            })
+        events.append({
+            "ph": "X", "pid": pid, "tid": _SCHEDULER_TID, "name": name,
+            "cat": "phase", "ts": (wall0 + t0 - mono0) * 1e6,
+            "dur": max((t1 - t0) * 1e6, 1.0),
         })
     events.sort(key=lambda e: e.get("ts", 0))
     return {"traceEvents": events, "displayTimeUnit": "ms"}

@@ -96,7 +96,8 @@ ROUTES = (
     ("GET", "/debug/requests",
      "recent flight-recorder timelines (?model=&limit=&trace=)"),
     ("GET", "/debug/trace",
-     "timelines as Chrome-trace JSON (?model=&limit=&snapshot=)"),
+     "timelines + the scheduler's phases as Chrome-trace JSON "
+     "(?model=&limit=&snapshot=)"),
     ("GET", "/debug/trace/fleet",
      "one trace id stitched across the fleet (?trace=<id>)"),
     ("GET", "/debug/spans",
@@ -234,6 +235,7 @@ def _debug_response(
                     model=model, limit=qint("limit", 64)
                 ),
                 flightrec.RECORDER.model_events(model),
+                flightrec.RECORDER.phases(model),
             ))
     elif path == "/debug/spans":
         spans = tracing.recent_spans(

@@ -61,8 +61,11 @@ log = logging.getLogger("aios.incidents")
 # action journal, "breaker_open" the quarantine board's open edge,
 # "fault" the injection layer's fired-fault record. A new trigger is a
 # reviewed enum change, never a stray label value.
+# "no_progress" is the batcher's record of a held request that stopped
+# moving (a flightrec snapshot cause, so it rides notify() verbatim).
 TRIGGER_CAUSES = ("abort", "autoscale", "breaker_open", "crash_respawn",
-                  "fault", "manual", "shed_spike", "slo_breach")
+                  "fault", "manual", "no_progress", "shed_spike",
+                  "slo_breach")
 
 # Bundle store bound: bundles are heavy (a tsdb window + a snapshot);
 # 16 spans the recent past without letting /debug/incidents balloon.

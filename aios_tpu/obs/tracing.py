@@ -55,6 +55,10 @@ class Span:
     parent_id: str = ""
     start: float = field(default_factory=time.time)
     end: float = 0.0
+    # the same two instants on time.monotonic(), the clock of the flight
+    # recorder's timelines and scheduler phases (wall time can step)
+    start_mono: float = field(default_factory=time.monotonic)
+    end_mono: float = 0.0
     status: str = "ok"  # ok | error
     attributes: Dict[str, object] = field(default_factory=dict)
 
@@ -63,7 +67,7 @@ class Span:
 
     @property
     def duration_s(self) -> float:
-        return max(0.0, (self.end or time.time()) - self.start)
+        return max(0.0, (self.end_mono or time.monotonic()) - self.start_mono)
 
     @property
     def traceparent(self) -> str:
@@ -116,6 +120,7 @@ def clear_spans() -> None:
 
 def _finish(span: Span, token, parent: Optional[Span]) -> None:
     span.end = time.time()
+    span.end_mono = time.monotonic()
     try:
         _current.reset(token)
     except ValueError:

@@ -583,6 +583,14 @@ class ReplicaPool:
             out["pool_evictions"] = (
                 out.get("pool_evictions", 0) + r.batcher.pool_evictions
             )
+            # the loop's own record: seconds and count per phase and the
+            # stalls add up; the request that has stood still longest is
+            # one request, so the largest over the replicas
+            for k, v in r.batcher.stats().items():
+                if k == "oldest_no_progress_s":
+                    out[k] = max(out.get(k, 0.0), v)
+                else:
+                    out[k] = out.get(k, 0) + v
             out["num_slots"] = out.get("num_slots", 0) + r.engine.num_slots
             out[f"replica{r.idx}_occupancy"] = round(r.occupancy(), 3)
         if occ:

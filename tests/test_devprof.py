@@ -136,6 +136,12 @@ def _wave(monkeypatch, enabled):
                 prompt_ids=[7 + i, 23, 55], max_tokens=11,
                 temperature=0.7, top_p=0.9,
             )).tokens())
+        # the loop issued one more dispatch before the last request
+        # retired: let it land, or decode_steps is read before or after
+        # the worker counts it
+        deadline = time.monotonic() + 30
+        while b._pending is not None and time.monotonic() < deadline:
+            time.sleep(0.005)
         return {
             "outs": outs,
             "decode_steps": eng.stats()["decode_steps"],

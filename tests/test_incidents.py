@@ -102,7 +102,7 @@ def test_unknown_cause_normalizes_to_manual():
     assert b["cause"] == "manual"
     assert set(TRIGGER_CAUSES) == {
         "abort", "autoscale", "breaker_open", "crash_respawn", "fault",
-        "manual", "shed_spike", "slo_breach",
+        "manual", "no_progress", "shed_spike", "slo_breach",
     }
 
 

@@ -960,7 +960,7 @@ def test_incident_trigger_causes_closed_and_iterated_at_registration():
 
     assert incidents.TRIGGER_CAUSES == (
         "abort", "autoscale", "breaker_open", "crash_respawn", "fault",
-        "manual", "shed_spike", "slo_breach",
+        "manual", "no_progress", "shed_spike", "slo_breach",
     )
     mi = module_info_for(incidents)
     assert "TRIGGER_CAUSES" in names_used_in(
