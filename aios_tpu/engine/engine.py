@@ -36,7 +36,7 @@ import numpy as np
 
 from . import model, paged, sampling, spec
 from .config import ModelConfig
-from .. import backend, faults
+from .. import backend, faults, ops
 from ..analysis.locks import make_lock
 from ..obs import instruments as obs
 from ..obs import devprof, flightrec
@@ -596,9 +596,11 @@ class TPUEngine:
             self.allocator = paged.PageAllocator(
                 num_pages, page_size, num_slots, max_blocks, replicas=R
             )
+            # THE stored layout (paged.py's header): a row's kv heads
+            # merged on the last axis
             shape = (
                 cfg.num_layers, num_pages, page_size,
-                cfg.num_kv_heads, cfg.head_dim,
+                cfg.num_kv_heads * cfg.head_dim,
             )
             k, v = jnp.zeros(shape, cache_dtype), jnp.zeros(shape, cache_dtype)
             if R > 1:
@@ -791,7 +793,9 @@ class TPUEngine:
                 # routed buckets are powers of two >= sp (sp is a
                 # power-of-two mesh axis), so the shard split is exact
                 self.seq_prefill_min = max(self.seq_prefill_min, sp)
-        if shardings is not None:
+        if shardings is not None and self.paged:
+            k, v = shardings.put_pool(k), shardings.put_pool(v)
+        elif shardings is not None:
             k = shardings.put_cache(k, seq_shard=self.seq_sharded)
             v = shardings.put_cache(v, seq_shard=self.seq_sharded)
         self.state: DecodeState = {
@@ -1832,46 +1836,28 @@ class TPUEngine:
             params, self.cfg, tokens, kernels=self._kernels,
             qmm=self._qmm_gspmd, attn_fn=attn_fn,
         )
-        T = tokens.shape[1]
-        P = state["k"].shape[2]
-        nb = -(-T // P)  # blocks this bucket spans (static)
-        # static repeat, not table_row[rows // P]: an index-array gather
-        # serializes on TPU (same lesson as spec.propose_ngram)
-        pages = jnp.repeat(table_row[:nb], P)[:T]  # [T]
-        offs = jnp.arange(T) % P
-        # ks/vs [L, 1, T, KH, D] -> pool [L, N, P, KH, D]
-        if self._paged_scatter is not None:
-            # dp-replicated pool: table ids are replica-local, so the
-            # scatter must run per device (only the owning replica's
-            # writes target real pages — ShardingPlan.paged_prefill_scatter)
-            owner = self.allocator.replica_of(slot)
-            if self.quant_cache:
-                kq, ks_scale = model.quantize_kv(ks[:, 0])
-                vq, vs_scale = model.quantize_kv(vs[:, 0])
-                k, v, k_s, v_s = self._paged_scatter(
-                    state["k"], state["v"], state["k_s"], state["v_s"],
-                    kq, vq, ks_scale, vs_scale, pages, offs, owner,
-                )
-            else:
-                k, v = self._paged_scatter(
-                    state["k"], state["v"],
-                    ks[:, 0].astype(state["k"].dtype),
-                    vs[:, 0].astype(state["v"].dtype),
-                    pages, offs, owner,
-                )
-        elif self.quant_cache:
+        # ks/vs [L, 1, T, KH, D] -> the pool's rows [L, T, KH*D], written
+        # from row 0 of the slot's first page, by whole pages
+        if self.quant_cache:
             kq, ks_scale = model.quantize_kv(ks[:, 0])  # [L, T, KH, D/·]
             vq, vs_scale = model.quantize_kv(vs[:, 0])
-            k = state["k"].at[:, pages, offs].set(kq)
-            v = state["v"].at[:, pages, offs].set(vq)
-            k_s = state["k_s"].at[:, pages, offs].set(ks_scale)
-            v_s = state["v_s"].at[:, pages, offs].set(vs_scale)
+            pools = (state["k"], state["v"], state["k_s"], state["v_s"])
+            rows = (ops.merge_heads(kq), ops.merge_heads(vq),
+                    ks_scale, vs_scale)
         else:
-            k = state["k"].at[:, pages, offs].set(
-                ks[:, 0].astype(state["k"].dtype)
+            pools = (state["k"], state["v"])
+            rows = (ops.merge_heads(ks[:, 0]), ops.merge_heads(vs[:, 0]))
+        if self._paged_scatter is not None:
+            # dp-replicated pool: table ids are replica-local, so the
+            # write must run per device (only the owning replica's
+            # targets real pages — ShardingPlan.paged_prefill_scatter)
+            k, v, *scales = self._paged_scatter(
+                *pools, *rows, table_row, self.allocator.replica_of(slot)
             )
-            v = state["v"].at[:, pages, offs].set(
-                vs[:, 0].astype(state["v"].dtype)
+        else:
+            k, v, *scales = (
+                ops.write_rows(p, None, r, table_row)
+                for p, r in zip(pools, rows)
             )
         key, sub = jax.random.split(state["key"])
         last = logits[0, true_len - 1][None, :]  # [1, V]
@@ -1891,8 +1877,7 @@ class TPUEngine:
             "key": key,
         }
         if self.quant_cache:
-            out["k_s"] = k_s
-            out["v_s"] = v_s
+            out["k_s"], out["v_s"] = scales
         return out, first
 
     def _prefill_impl(
@@ -2477,10 +2462,8 @@ class TPUEngine:
         if nb in self._restore_fns or not self.paged:
             return
         cfg, P = self.cfg, self.allocator.page_size
-        z = jnp.zeros(
-            (cfg.num_layers, nb, P, cfg.num_kv_heads, cfg.head_dim),
-            self.state["k"].dtype,
-        )
+        pool = self.state["k"]  # nb pages of it: [L, nb, P, KH*D]
+        z = jnp.zeros((pool.shape[0], nb, *pool.shape[2:]), pool.dtype)
         args = [self.state, z, z]
         if self.quant_cache:
             s = jnp.zeros((cfg.num_layers, nb, P, cfg.num_kv_heads),

@@ -899,8 +899,8 @@ def prefill_chunk_paged(
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [1, Tc] int32 — one chunk of one prompt
     start: jnp.ndarray,  # scalar int32 — absolute position of tokens[0]
-    k_pool: jnp.ndarray,  # [L, N, P, KH, D]
-    v_pool: jnp.ndarray,  # [L, N, P, KH, D]
+    k_pool: jnp.ndarray,  # [L, N, P, KH*D]
+    v_pool: jnp.ndarray,  # [L, N, P, KH*D]
     table_row: jnp.ndarray,  # [MB] int32 — the slot's block->page map
     cache_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
@@ -914,7 +914,8 @@ def prefill_chunk_paged(
     rows scattered into the page pool through ``table_row``. Because chunk
     sizes and page sizes are both powers of two, a chunk either spans whole
     pages (Tc >= P, start page-aligned) or sits inside one page (Tc < P) —
-    the write indices are static repeats, never an index-array gather.
+    the rows are written by whole pages or as one slice of a page
+    (ops.write_rows), never by an index-array scatter of single rows.
     Chunk attention gathers the slot's logical view from the pool per layer
     (a copy, but prefill is compute-bound; the decode hot path reads pages
     in place via the kernel). The caller must have backed rows
@@ -934,6 +935,8 @@ def prefill_chunk_paged(
     positions = start + jnp.arange(Tc)[None, :]  # [1, Tc]
     cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
+    # where the chunk's rows land (ops.write_rows: whole pages, or inside
+    # one): the pages, and the row of the first page it starts on
     if Tc >= P:  # page-aligned chunk spanning Tc/P whole pages
         nb = Tc // P
         # pad with sacrificial entries so a final bucket whose padding
@@ -944,13 +947,10 @@ def prefill_chunk_paged(
         table_ext = jnp.concatenate(
             [table_row, jnp.zeros((nb,), table_row.dtype)]
         )
-        pages_blk = jax.lax.dynamic_slice(table_ext, (start // P,), (nb,))
-        pages = jnp.repeat(pages_blk, P)  # [Tc]
-        offs = jnp.arange(Tc) % P
+        pages = jax.lax.dynamic_slice(table_ext, (start // P,), (nb,))
     else:  # chunk inside one page
-        page = jax.lax.dynamic_slice(table_row, (start // P,), (1,))[0]
-        pages = jnp.broadcast_to(page, (Tc,))
-        offs = (start % P) + jnp.arange(Tc)
+        pages = jax.lax.dynamic_slice(table_row, (start // P,), (1,))
+    off = start % P
 
     t = min(512, C_log)
     kv_tile = t if C_log % t == 0 else P
@@ -961,24 +961,24 @@ def prefill_chunk_paged(
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
         if quant_pool:
             k_s, v_s = scales
-            k_pool, k_s = scatter_quant(
-                k_pool, k_s, (l, pages, offs), k_new[0]
-            )
-            v_pool, v_s = scatter_quant(
-                v_pool, v_s, (l, pages, offs), v_new[0]
-            )
-            k_all = gather_dequant(k_pool, k_s, table_row, q.dtype, l)[None]
-            v_all = gather_dequant(v_pool, v_s, table_row, q.dtype, l)[None]
+            kq, ks = quantize_kv(k_new[0])
+            vq, vs = quantize_kv(v_new[0])
+            k_pool = ops.write_rows(k_pool, l, ops.merge_heads(kq), pages, off)
+            v_pool = ops.write_rows(v_pool, l, ops.merge_heads(vq), pages, off)
+            k_s = ops.write_rows(k_s, l, ks, pages, off)
+            v_s = ops.write_rows(v_s, l, vs, pages, off)
+            k_all = gather_dequant(k_pool, k_s, l, table_row, q.dtype)[None]
+            v_all = gather_dequant(v_pool, v_s, l, table_row, q.dtype)[None]
             scales = (k_s, v_s)
         else:
-            k_pool = k_pool.at[l, pages, offs].set(
-                k_new[0].astype(k_pool.dtype)
+            k_pool = ops.write_rows(
+                k_pool, l, ops.merge_heads(k_new[0]), pages, off
             )
-            v_pool = v_pool.at[l, pages, offs].set(
-                v_new[0].astype(v_pool.dtype)
+            v_pool = ops.write_rows(
+                v_pool, l, ops.merge_heads(v_new[0]), pages, off
             )
-            k_all = k_pool[l, table_row].reshape(1, C_log, *k_pool.shape[3:])
-            v_all = v_pool[l, table_row].reshape(1, C_log, *v_pool.shape[3:])
+            k_all = ops.gather_pages(k_pool, l, table_row, cfg.head_dim)[None]
+            v_all = ops.gather_pages(v_pool, l, table_row, cfg.head_dim)[None]
         attn = blockwise_cache_attention(
             q,
             k_all.astype(q.dtype),
@@ -1007,8 +1007,8 @@ def decode_step_paged(
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [B] int32 — one new token per slot
     lengths: jnp.ndarray,  # [B] int32 — logical rows already in each slot
-    k_pool: jnp.ndarray,  # [L, N, P, KH, D] — shared page pool
-    v_pool: jnp.ndarray,  # [L, N, P, KH, D]
+    k_pool: jnp.ndarray,  # [L, N, P, KH*D] — shared page pool
+    v_pool: jnp.ndarray,  # [L, N, P, KH*D]
     tables: jnp.ndarray,  # [B, MB] int32 — logical block -> physical page
     kernels: Optional[bool] = None,
     cache_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
@@ -1025,10 +1025,15 @@ def decode_step_paged(
     page pool read through per-slot tables (ops/paged_attention.py): the
     new row is scattered to (page ``tables[b, lengths[b] // P]``, offset
     ``lengths[b] % P``), and attention reads only the pages that hold valid
-    rows. Inactive slots write the sacrificial page 0 (paged.py) and read
-    zero rows. The caller must have BACKED row ``lengths[b]`` for every
-    active slot (PageAllocator.ensure) — an unbacked entry maps page 0 and
-    would silently cross-talk through the sacrificial page.
+    rows. The pool rides the layer scan as its carry and is handed to the
+    attention whole, with the layer's index: of the pool, a step touches
+    the rows it scatters and the pages the tables name, and makes no slice
+    or relayout of a layer's pages (tests/test_paged_kernel.py holds the
+    step's jaxpr to that). Inactive slots write the sacrificial page 0
+    (paged.py) and read zero rows. The caller must have BACKED row
+    ``lengths[b]`` for every active slot (PageAllocator.ensure) — an
+    unbacked entry maps page 0 and would silently cross-talk through the
+    sacrificial page.
 
     ``cache_scales`` — (k_scales, v_scales) [L, N, P, KH] f32 marks an
     int8 POOL: rows quantize on write; attention either streams the int8
@@ -1117,11 +1122,9 @@ def decode_step_paged(
                 v_pool, v_s = scatter_quant(
                     v_pool, v_s, (l, pages, offs), v_new[:, 0]
                 )
-            with jax.named_scope("page_gather"):
-                k_l, v_l, k_sl, v_sl = k_pool[l], v_pool[l], k_s[l], v_s[l]
             with jax.named_scope("attention"):
                 attn = paged_int8_attend(
-                    q[:, 0], k_l, v_l, k_sl, v_sl, tables,
+                    q[:, 0], k_pool, v_pool, k_s, v_s, l, tables,
                     read_lengths,
                     window=cfg.sliding_window,
                     use_int8_kernel=use_int8_kernel,
@@ -1131,25 +1134,24 @@ def decode_step_paged(
         else:
             with jax.named_scope("kv_write"):
                 k_pool = k_pool.at[l, pages, offs].set(
-                    k_new[:, 0].astype(k_pool.dtype)
+                    ops.merge_heads(k_new[:, 0]).astype(k_pool.dtype)
                 )
                 v_pool = v_pool.at[l, pages, offs].set(
-                    v_new[:, 0].astype(v_pool.dtype)
+                    ops.merge_heads(v_new[:, 0]).astype(v_pool.dtype)
                 )
-            # this layer's pages out of the pool the scan carries
-            with jax.named_scope("page_gather"):
-                k_l, v_l = k_pool[l], v_pool[l]
+            # the carried pool goes to the kernel whole, with the layer's
+            # index: it reads the pages the tables name where they lie
             with jax.named_scope("attention"):
                 if use_kernel:
                     attn = ops.paged_decode_attention(
-                        q[:, 0], k_l, v_l, tables, read_lengths,
+                        q[:, 0], k_pool, v_pool, l, tables, read_lengths,
                         window=cfg.sliding_window,
                         win_starts=win_starts,
                         sink=sink_rows if win_starts is not None else None,
                     )[:, None]
                 else:
                     attn = ops.paged_decode_attention_reference(
-                        q[:, 0], k_l, v_l, tables, read_lengths,
+                        q[:, 0], k_pool, v_pool, l, tables, read_lengths,
                         window=cfg.sliding_window,
                         win_starts=win_starts, sink=sink_rows,
                     )[:, None]
@@ -1174,8 +1176,8 @@ def verify_step_paged(
     cfg: ModelConfig,
     tokens: jnp.ndarray,  # [B, T] int32 — [last_token, draft_0..draft_{T-2}]
     lengths: jnp.ndarray,  # [B] int32
-    k_pool: jnp.ndarray,  # [L, N, P, KH, D]
-    v_pool: jnp.ndarray,  # [L, N, P, KH, D]
+    k_pool: jnp.ndarray,  # [L, N, P, KH*D]
+    v_pool: jnp.ndarray,  # [L, N, P, KH*D]
     tables: jnp.ndarray,  # [B, MB] int32
     cache_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     active: Optional[jnp.ndarray] = None,  # [B] bool
@@ -1234,16 +1236,20 @@ def verify_step_paged(
             k_s, v_s = scales
             k_pool, k_s = scatter_quant(k_pool, k_s, (l, pages, offs), k_new)
             v_pool, v_s = scatter_quant(v_pool, v_s, (l, pages, offs), v_new)
-            k_all = gather_dequant(k_pool, k_s, tables, q.dtype, l)
-            v_all = gather_dequant(v_pool, v_s, tables, q.dtype, l)
+            k_all = gather_dequant(k_pool, k_s, l, tables, q.dtype)
+            v_all = gather_dequant(v_pool, v_s, l, tables, q.dtype)
             scales = (k_s, v_s)
         else:
-            k_pool = k_pool.at[l, pages, offs].set(k_new.astype(k_pool.dtype))
-            v_pool = v_pool.at[l, pages, offs].set(v_new.astype(v_pool.dtype))
+            k_pool = k_pool.at[l, pages, offs].set(
+                ops.merge_heads(k_new).astype(k_pool.dtype)
+            )
+            v_pool = v_pool.at[l, pages, offs].set(
+                ops.merge_heads(v_new).astype(v_pool.dtype)
+            )
             # logical per-slot views; same HBM bytes as the dense masked
             # read
-            k_all = k_pool[l, tables].reshape(B, C, *k_pool.shape[3:])
-            v_all = v_pool[l, tables].reshape(B, C, *v_pool.shape[3:])
+            k_all = ops.gather_pages(k_pool, l, tables, cfg.head_dim)
+            v_all = ops.gather_pages(v_pool, l, tables, cfg.head_dim)
         attn = gqa_attention(q, k_all, v_all, mask)
         x = x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row")
         x = x + _mlp(x, lp, cfg, moe_impl, qmm)
@@ -1608,22 +1614,23 @@ def init_kv_scales(
     return jnp.ones(shape, jnp.float32), jnp.ones(shape, jnp.float32)
 
 
-def paged_int8_attend(q, k_l, v_l, k_s, v_s, tables, lengths, *, window,
-                      use_int8_kernel, win_starts=None, sink=0):
-    """Decode attention over an int8 page pool for ONE layer ([B,H,D] ->
-    [B,H,D]): the kernel path streams int8 pages with scales folded into
-    the dots; the XLA path dequantizes a gathered per-slot view. The single
-    source of truth for the int8-pool read — decode_step_paged AND the
-    dp-replicated shard_map body (sharding.paged_pool_impl) both call it,
-    so mask/window semantics cannot drift between them.
+def paged_int8_attend(q, k_pool, v_pool, k_s, v_s, layer, tables, lengths,
+                      *, window, use_int8_kernel, win_starts=None, sink=0):
+    """Decode attention over layer ``layer`` of an int8 page pool
+    ([B,H,D] -> [B,H,D]; pools [L,N,P,KH*D], scales [L,N,P,KH]): the kernel
+    path streams int8 pages with scales folded into the dots; the XLA path
+    dequantizes a gathered per-slot view. The single source of truth for
+    the int8-pool read — decode_step_paged AND the dp-replicated shard_map
+    body (sharding.paged_pool_impl) both call it, so mask/window semantics
+    cannot drift between them.
     ``win_starts``/``sink`` apply the window+sink compressed mask."""
     if use_int8_kernel:
         return ops.paged_decode_attention_int8(
-            q, k_l, v_l, k_s, v_s, tables, lengths, window=window,
-            win_starts=win_starts,
+            q, k_pool, v_pool, k_s, v_s, layer, tables, lengths,
+            window=window, win_starts=win_starts,
             sink=sink if win_starts is not None else None,
         )
-    C = tables.shape[1] * k_l.shape[1]
+    C = tables.shape[1] * k_pool.shape[2]
     cols = jnp.arange(C)[None, :]
     mask = cols <= lengths[:, None]
     if window is not None:
@@ -1632,14 +1639,14 @@ def paged_int8_attend(q, k_l, v_l, k_s, v_s, tables, lengths, *, window,
         mask = mask & ((cols < sink) | (cols >= win_starts[:, None]))
     return gqa_attention(
         q[:, None],
-        gather_dequant(k_l, k_s, tables, q.dtype),
-        gather_dequant(v_l, v_s, tables, q.dtype),
+        gather_dequant(k_pool, k_s, layer, tables, q.dtype),
+        gather_dequant(v_pool, v_s, layer, tables, q.dtype),
         mask[:, None, :],
     )[:, 0]
 
 
 def scatter_quant(
-    pool: jnp.ndarray,  # [..., N, P, KH, D] int8
+    pool: jnp.ndarray,  # [..., N, P, KH*D] int8
     scales: jnp.ndarray,  # [..., N, P, KH] f32
     idx: tuple,  # (pages, offs), or (layer, pages, offs) on the whole pool
     rows: jnp.ndarray,  # [..., KH, D] new rows (idx arrays broadcast-match)
@@ -1647,23 +1654,22 @@ def scatter_quant(
     """Quantize rows and scatter values + scales into an int8 page pool —
     the single write-side quantization contract for every paged path."""
     q, s = quantize_kv(rows)
-    return pool.at[idx].set(q), scales.at[idx].set(s)
+    return pool.at[idx].set(ops.merge_heads(q)), scales.at[idx].set(s)
 
 
 def gather_dequant(
-    pool: jnp.ndarray,  # [N, P, KH, D] int8, or [L, N, P, KH, D] with layer
-    scales: jnp.ndarray,  # [N, P, KH] f32 (same leading axes as pool)
+    pool: jnp.ndarray,  # [L, N, P, KH*D] int8
+    scales: jnp.ndarray,  # [L, N, P, KH] f32
+    layer,  # scalar layer index
     tables: jnp.ndarray,  # [..., MB] int32
     dtype,
-    layer=None,  # scalar layer index into a whole [L, ...] pool
 ) -> jnp.ndarray:
     """Materialize dequantized logical views [..., MB*P, KH, D] from an
     int8 page pool — the read-side twin of ``scatter_quant``."""
-    idx = tables if layer is None else (layer, tables)
-    out = dequantize_kv(pool[idx], scales[idx], dtype)
-    MB = tables.shape[-1]
-    P, KH, D = pool.shape[-3], pool.shape[-2], pool.shape[-1]
-    return out.reshape(*tables.shape[:-1], MB * P, KH, D)
+    s = scales[layer, tables]  # [..., MB, P, KH]
+    s = s.reshape(*tables.shape[:-1], -1, s.shape[-1])
+    D = pool.shape[-1] // s.shape[-1]
+    return dequantize_kv(ops.gather_pages(pool, layer, tables, D), s, dtype)
 
 
 def quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:

@@ -1,10 +1,21 @@
 """Host-side page-table management for the paged KV cache.
 
-The device holds a fixed page pool ([L, N, P, KH, D] per k/v) and reads it
-through per-slot page tables; THIS module owns the mapping. Allocation is a
-free-list pop, release a push — O(1), no compaction, no device traffic
-beyond the [S, MAX_BLOCKS] int32 table that rides along with each dispatch
-(a few hundred bytes). The scheduler's admission/retire cycle calls
+The device holds a fixed page pool and reads it through per-slot page
+tables; THIS module owns the mapping. The pool has ONE stored layout,
+[L, N, P, KH*D] per k/v (layers, physical pages, rows of a page, and a
+row's kv heads side by side: head h is [h*D, (h+1)*D) of the last axis, for
+any head size). The paged decode kernel takes that array whole with the
+layer's index and DMAs the pages it needs from where they lie
+(ops/paged_attention.py); every other reader and writer indexes it by
+(layer, page, row) and reshapes only what is small — new rows on the way
+in (ops.merge_heads; a prompt's or a chunk's rows go in by whole pages,
+ops.write_rows), a slot's gathered pages on the way out
+(ops.gather_pages). Nothing slices, reshapes or copies a layer of it. An
+int8 pool keeps its per-(row, kv head) scales beside it as [L, N, P, KH].
+
+Allocation is a free-list pop, release a push — O(1), no compaction, no
+device traffic beyond the [S, MAX_BLOCKS] int32 table that rides along with
+each dispatch (a few hundred bytes). The scheduler's admission/retire cycle calls
 `ensure`/`free_slot`; a pool that can't back a grow request raises
 `PoolExhausted` so the batcher can retire a victim request instead of
 corrupting anyone's cache.

@@ -40,6 +40,9 @@ from .paged_attention import (
     paged_decode_attention_int8,
     paged_decode_attention_int8_reference,
     paged_decode_attention_reference,
+    merge_heads,
+    split_heads,
+    write_rows,
 )
 from .int4_matmul import (
     dequantize_int4,
@@ -72,6 +75,9 @@ __all__ = [
     "paged_decode_attention_int8_reference",
     "paged_decode_attention_reference",
     "gather_pages",
+    "merge_heads",
+    "split_heads",
+    "write_rows",
     "multiquery_decode_attention",
     "multiquery_decode_attention_int8",
     "multiquery_decode_attention_int8_reference",

@@ -87,6 +87,9 @@ PARAM_RULES: Dict[str, P] = {
 CACHE_SPEC = P(None, "dp", None, "tp", None)
 # int8 KV-cache scales [L, slots, C, KH] ride the same placement.
 CACHE_SCALE_SPEC = P(None, "dp", None, "tp")
+# Page pool [L, N, P, KH*D] (engine/paged.py): pages over dp, and the
+# merged head axis over tp — a contiguous 1/tp of it is KH/tp whole heads.
+POOL_SPEC = P(None, "dp", None, "tp")
 # Context-sharded variant: the C axis additionally splits over sp, so one
 # slot's KV can exceed a single chip's HBM (long-context serving). XLA
 # partitions the decode attention over the sharded contraction itself —
@@ -155,6 +158,9 @@ class ShardingPlan:
     def put_cache(self, cache, seq_shard: bool = False):
         spec = CACHE_SPEC_SEQ if seq_shard else CACHE_SPEC
         return jax.device_put(cache, NamedSharding(self.mesh, spec))
+
+    def put_pool(self, pool):
+        return jax.device_put(pool, NamedSharding(self.mesh, POOL_SPEC))
 
     def put_cache_scales(self, scales, seq_shard: bool = False):
         spec = CACHE_SCALE_SPEC_SEQ if seq_shard else CACHE_SCALE_SPEC
@@ -288,7 +294,7 @@ class ShardingPlan:
         over tp, like the dense cache.
 
         Returns, for the bf16 pool,
-          f(q [B,H,D], k_new [B,KH,D], v_new, k_l [N,P,KH,D], v_l,
+          f(q [B,H,D], k_new [B,KH,D], v_new, k_l [N,P,KH*D], v_l,
             tables [B,MB], lengths [B], pages [B], offs [B])
             -> (attn [B,H,D], k_l', v_l')
         and for the int8 pool the same with (k_s [N,P,KH], v_s) appended
@@ -300,15 +306,23 @@ class ShardingPlan:
 
         def local_bf16(q, k_new, v_new, k_l, v_l, tables, lengths, pages,
                        offs):
-            k_l = k_l.at[pages, offs].set(k_new.astype(k_l.dtype))
-            v_l = v_l.at[pages, offs].set(v_new.astype(v_l.dtype))
+            k_l = k_l.at[pages, offs].set(
+                ops.merge_heads(k_new).astype(k_l.dtype)
+            )
+            v_l = v_l.at[pages, offs].set(
+                ops.merge_heads(v_new).astype(v_l.dtype)
+            )
+            # the kernels take a stacked pool and a layer: this device's
+            # slice of ONE layer is a one-layer stack
             if use_kernel:
                 attn = ops.paged_decode_attention(
-                    q, k_l, v_l, tables, lengths, window=window
+                    q, k_l[None], v_l[None], 0, tables, lengths,
+                    window=window,
                 )
             else:
                 attn = ops.paged_decode_attention_reference(
-                    q, k_l, v_l, tables, lengths, window=window
+                    q, k_l[None], v_l[None], 0, tables, lengths,
+                    window=window,
                 )
             return attn, k_l, v_l
 
@@ -317,15 +331,15 @@ class ShardingPlan:
             k_l, k_s = model_mod.scatter_quant(k_l, k_s, (pages, offs), k_new)
             v_l, v_s = model_mod.scatter_quant(v_l, v_s, (pages, offs), v_new)
             attn = model_mod.paged_int8_attend(
-                q, k_l, v_l, k_s, v_s, tables, lengths, window=window,
+                q, k_l[None], v_l[None], k_s[None], v_s[None], 0, tables,
+                lengths, window=window,
                 use_int8_kernel=(
                     use_kernel and model_mod._int8_ragged_enabled()
                 ),
             )
             return attn, k_l, v_l, k_s, v_s
 
-        pool = P("dp", None, "tp", None)
-        scale = P("dp", None, "tp")
+        pool = scale = P("dp", None, "tp")
         vec = P("dp", "tp", None)
         if quantized:
             in_specs = (vec, vec, vec, pool, pool, scale, scale,
@@ -343,51 +357,38 @@ class ShardingPlan:
         )
 
     def paged_prefill_scatter(self, quantized: bool):
-        """Per-device scatter of a whole prefilled prompt's K/V rows into
+        """Per-device write of a whole prefilled prompt's K/V rows into
         the dp-sharded page pool (replica-local page ids, like
         ``paged_pool_impl``). The prompt's forward pass itself is
         replicated over dp (B=1 — dp has nothing to split), so every
-        device computes the same rows; only the OWNING replica's scatter
+        device computes the same rows; only the OWNING replica's write
         targets real pages — the rest write their local sacrificial
         page 0, which is never read.
 
-        bf16: f(k_pool [L,N,P,KH,D], v_pool, kq [L,T,KH,D], vq, pages [T],
-               offs [T], owner scalar) -> (k_pool', v_pool')
-        int8: scales [L,N,P,KH] and per-row scale values [L,T,KH] ride
-              along (inputs and outputs).
+        bf16: f(k_pool [L,N,P,KH*D], v_pool, k_rows [L,T,KH*D], v_rows,
+               blocks [MB] (the slot's table row), owner scalar)
+               -> (k_pool', v_pool')
+        int8: scales [L,N,P,KH] and their rows [L,T,KH] ride along, after
+              the values, among the pools and among the rows.
         """
-        def local_bf16(k_l, v_l, kq, vq, pages, offs, owner):
-            mine = jax.lax.axis_index("dp") == owner
-            pg = jnp.where(mine, pages, 0)
-            k_l = k_l.at[:, pg, offs].set(kq.astype(k_l.dtype))
-            v_l = v_l.at[:, pg, offs].set(vq.astype(v_l.dtype))
-            return k_l, v_l
+        from .. import ops
 
-        def local_int8(k_l, v_l, k_s, v_s, kq, vq, ks, vs, pages, offs,
-                       owner):
-            mine = jax.lax.axis_index("dp") == owner
-            pg = jnp.where(mine, pages, 0)
-            k_l = k_l.at[:, pg, offs].set(kq)
-            v_l = v_l.at[:, pg, offs].set(vq)
-            k_s = k_s.at[:, pg, offs].set(ks)
-            v_s = v_s.at[:, pg, offs].set(vs)
-            return k_l, v_l, k_s, v_s
+        n = 4 if quantized else 2
 
-        pool = P(None, "dp", None, "tp", None)
-        scale = P(None, "dp", None, "tp")
-        rows = P(None, None, "tp", None)
-        rows_s = P(None, None, "tp")
-        if quantized:
-            in_specs = (pool, pool, scale, scale, rows, rows, rows_s,
-                        rows_s, P(None), P(None), P())
-            out_specs = (pool, pool, scale, scale)
-            fn = local_int8
-        else:
-            in_specs = (pool, pool, rows, rows, P(None), P(None), P())
-            out_specs = (pool, pool)
-            fn = local_bf16
+        def local(*args):
+            pools, rows, (blocks, owner) = args[:n], args[n:2 * n], args[2 * n:]
+            mine = jax.lax.axis_index("dp") == owner
+            pg = jnp.where(mine, blocks, 0)
+            return tuple(
+                ops.write_rows(p, None, r, pg) for p, r in zip(pools, rows)
+            )
+
         return jax.shard_map(
-            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            local,
+            mesh=self.mesh,
+            in_specs=(POOL_SPEC,) * n + (P(None, None, "tp"),) * n
+            + (P(None), P()),
+            out_specs=(POOL_SPEC,) * n,
             check_vma=False,
         )
 

@@ -391,16 +391,18 @@ def kernel_phase(rehearsal: bool) -> dict:
                   ops.flash_attention, ops.flash_attention_reference,
                   q, k, v, causal=True, window=window)
         qd = rand(next(keys), (B, H, D), dtype)
-        kp = rand(next(keys), (N, P, KH, D), dtype)
-        vp = rand(next(keys), (N, P, KH, D), dtype)
+        # a three-layer stack, read at its middle layer: the kernel takes
+        # the whole pool and the layer's index
+        kp = rand(next(keys), (3, N, P, KH * D), dtype)
+        vp = rand(next(keys), (3, N, P, KH * D), dtype)
         check(f"paged_decode[{tag}]", tol, precision,
               ops.paged_decode_attention,
               ops.paged_decode_attention_reference,
-              qd, kp, vp, tables, lengths, window=W)
+              qd, kp, vp, 1, tables, lengths, window=W)
         check(f"paged_decode[{tag},win_starts+sink]", tol, precision,
               ops.paged_decode_attention,
               ops.paged_decode_attention_reference,
-              qd, kp, vp, tables, lengths, window=None,
+              qd, kp, vp, 1, tables, lengths, window=None,
               win_starts=win_starts, sink=P)
         kc = rand(next(keys), (B, C, KH, D), dtype)
         vc = rand(next(keys), (B, C, KH, D), dtype)

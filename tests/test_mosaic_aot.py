@@ -22,6 +22,7 @@ Skips cleanly when no libtpu is importable (non-TPU dev machines).
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -138,19 +139,25 @@ def test_aot_ragged_decode_int8(rep_sharding):
 
 
 def test_aot_paged_decode_both_dtypes(rep_sharding):
+    """The stacked-pool kernel at TinyLlama's geometry: head_dim 64 is a
+    half-vreg lane slice of the stored [L, N, P, KH*D] page, which Mosaic
+    has to take (a shipped tier serves through this kernel)."""
     from aios_tpu import ops
 
-    N_, P = 64, 128
+    L_, N_, P = 2, 64, 128
     q = jnp.ones((B, H, D), jnp.bfloat16)
     tbl = jnp.zeros((B, 32), jnp.int32)
     lens = jnp.ones((B,), jnp.int32)
-    kp = jnp.ones((N_, P, KH, D), jnp.bfloat16)
-    aot_compile(rep_sharding, ops.paged_decode_attention, q, kp, kp, tbl, lens)
-    kq = jnp.ones((N_, P, KH, D), jnp.int8)
-    ps = jnp.ones((N_, P, KH), jnp.float32)
+    lyr = jnp.ones((), jnp.int32)
+    kp = jnp.ones((L_, N_, P, KH * D), jnp.bfloat16)
+    aot_compile(
+        rep_sharding, ops.paged_decode_attention, q, kp, kp, lyr, tbl, lens
+    )
+    kq = jnp.ones((L_, N_, P, KH * D), jnp.int8)
+    ps = jnp.ones((L_, N_, P, KH), jnp.float32)
     aot_compile(
         rep_sharding, ops.paged_decode_attention_int8,
-        q, kq, kq, ps, ps, tbl, lens,
+        q, kq, kq, ps, ps, lyr, tbl, lens,
     )
 
 
@@ -234,6 +241,27 @@ MP = 128  # page size
 MN = 1 + (MB + 1) * MC // MP  # the "auto" pool: (slots + 1) x context rows
 
 
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (?P<type>\(.*?\)|\S+) (?P<op>[\w\-]+)\("
+)
+# results that are another buffer's bytes under a new name, not work
+_HLO_VIEWS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+              "conditional", "call"}
+
+
+def _hlo_results(text):
+    """(opcode, [element count of each array result]) for every
+    instruction of an optimised HLO module that computes something."""
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None or m["op"] in _HLO_VIEWS:
+            continue
+        yield m["op"], [
+            int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(r"\w+\[([\d,]*)\]", m["type"])
+        ]
+
+
 def sds(rep, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
 
@@ -262,33 +290,39 @@ def test_aot_mistral_ragged_decode_both_dtypes(rep_sharding):
                 lens, window=MW)
 
 
+ML = 32  # Mistral-7B's layers: the kernel takes the whole stacked pool
+
+
 @pytest.mark.parametrize("compressed", [False, True])
 def test_aot_mistral_paged_decode_both_dtypes(rep_sharding, compressed):
-    """window=4096, and the win_starts/sink operands PR 13 added."""
+    """window=4096, and the win_starts/sink operands PR 13 added, on the
+    stacked [32, 289, 128, ...] pool the layer loop carries."""
     from aios_tpu import ops
 
     q = sds(rep_sharding, (MB, MH, MD), jnp.bfloat16)
     tbl = sds(rep_sharding, (MB, MC // MP), jnp.int32)
     lens = sds(rep_sharding, (MB,), jnp.int32)
-    kp = sds(rep_sharding, (MN, MP, MKH, MD), jnp.bfloat16)
-    kq = sds(rep_sharding, (MN, MP, MKH, MD), jnp.int8)
-    ps = sds(rep_sharding, (MN, MP, MKH), jnp.float32)
+    lyr = sds(rep_sharding, (), jnp.int32)
+    kp = sds(rep_sharding, (ML, MN, MP, MKH * MD), jnp.bfloat16)
+    kq = sds(rep_sharding, (ML, MN, MP, MKH * MD), jnp.int8)
+    ps = sds(rep_sharding, (ML, MN, MP, MKH), jnp.float32)
     if compressed:
-        def bf16(q, k, v, t, l, ws):
+        def bf16(q, k, v, i, t, l, ws):
             return ops.paged_decode_attention(
-                q, k, v, t, l, window=MW, win_starts=ws, sink=MP)
+                q, k, v, i, t, l, window=MW, win_starts=ws, sink=MP)
 
-        def int8(q, k, v, ks, vs, t, l, ws):
+        def int8(q, k, v, ks, vs, i, t, l, ws):
             return ops.paged_decode_attention_int8(
-                q, k, v, ks, vs, t, l, window=MW, win_starts=ws, sink=MP)
+                q, k, v, ks, vs, i, t, l, window=MW, win_starts=ws, sink=MP)
 
-        aot_compile(rep_sharding, bf16, q, kp, kp, tbl, lens, lens)
-        aot_compile(rep_sharding, int8, q, kq, kq, ps, ps, tbl, lens, lens)
+        aot_compile(rep_sharding, bf16, q, kp, kp, lyr, tbl, lens, lens)
+        aot_compile(rep_sharding, int8, q, kq, kq, ps, ps, lyr, tbl, lens,
+                    lens)
     else:
         aot_compile(rep_sharding, ops.paged_decode_attention, q, kp, kp,
-                    tbl, lens, window=MW)
+                    lyr, tbl, lens, window=MW)
         aot_compile(rep_sharding, ops.paged_decode_attention_int8, q, kq,
-                    kq, ps, ps, tbl, lens, window=MW)
+                    kq, ps, ps, lyr, tbl, lens, window=MW)
 
 
 def test_aot_mistral_multiquery_verify_both_dtypes(rep_sharding):
@@ -328,7 +362,7 @@ def test_aot_mistral_serving_graphs_compile_and_fit(
     )
     leaf = params["layers"]["w_gateup"]
     assert ("q4" if mode == "int4" else "q") in leaf  # the kernel's layout
-    pool = sds(rep, (cfg.num_layers, MN, MP, MKH, MD), jnp.bfloat16)
+    pool = sds(rep, (cfg.num_layers, MN, MP, MKH * MD), jnp.bfloat16)
     pool_bytes = 2 * cfg.num_layers * MN * MP * MKH * MD  # one of k / v
     i32 = lambda *shape: sds(rep, shape, jnp.int32)  # noqa: E731
 
@@ -351,6 +385,19 @@ def test_aot_mistral_serving_graphs_compile_and_fit(
     }
     for name, (fn, args, donate) in graphs.items():
         compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        if name == "decode-step":
+            # PR 25: the step reads the layer's pages where they lie in the
+            # carried pool. No operation of the compiled graph makes one
+            # layer's slice of it (75.8 MB: the copy, reshape and
+            # dynamic-slice fusions that took 43 % of the step), and none
+            # copies a whole pool
+            slice_elems = MN * MP * MKH * MD
+            made = [
+                (op, res) for op, res in _hlo_results(compiled.as_text())
+                if slice_elems in res
+                or (op == "copy" and cfg.num_layers * slice_elems in res)
+            ]
+            assert made == [], f"{name}: pool-sized results {made[:4]}"
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes < pool_bytes, (
             f"{name}: {mem.temp_size_in_bytes / 1e9:.2f} GB of temporaries "

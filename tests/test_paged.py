@@ -4,7 +4,7 @@ The paged cache must be OBSERVABLY identical to the dense slot cache —
 same tokens, same masks — while reserving HBM per page in use instead of
 per num_slots x max_context (SURVEY.md section 7.2, hard part no. 1's
 fixed-shape half). Kernel parity runs under the Pallas interpreter on CPU,
-like the other kernels (tests/test_ops.py pattern).
+in tests/test_paged_kernel.py (tier 1).
 """
 
 import jax
@@ -17,11 +17,6 @@ from aios_tpu.engine.batching import ContinuousBatcher, Request
 from aios_tpu.engine.config import TINY_TEST
 from aios_tpu.engine.engine import TPUEngine
 from aios_tpu.engine.paged import PageAllocator, PoolExhausted
-from aios_tpu.ops import (
-    decode_attention_reference,
-    paged_decode_attention,
-    paged_decode_attention_reference,
-)
 
 # compile-heavy tier: excluded from the fast commit gate (pytest -m fast)
 pytestmark = pytest.mark.slow
@@ -72,90 +67,9 @@ def test_allocator_pages_are_exclusive():
 
 
 # ---------------------------------------------------------------------------
-# paged attention parity
+# paged attention parity: tests/test_paged_kernel.py (tier 1) — the kernel
+# and the reference on a stacked three-layer pool, every mask, both dtypes
 # ---------------------------------------------------------------------------
-
-
-def _scattered_equivalent(rng, B, C, KH, D, P, dtype=jnp.float32):
-    """Dense [B, C, KH, D] caches and a paged pool holding the same rows
-    behind a shuffled page table."""
-    MB = C // P
-    dense = jnp.asarray(rng.normal(size=(B, C, KH, D)), dtype)
-    # physical pages shuffled: logical block b of slot s -> some unique page
-    perm = rng.permutation(B * MB)
-    tables = jnp.asarray(1 + perm.reshape(B, MB), jnp.int32)
-    pool = jnp.zeros((1 + B * MB, P, KH, D), dtype)
-    for s in range(B):
-        for b in range(MB):
-            pool = pool.at[int(tables[s, b])].set(
-                dense[s, b * P : (b + 1) * P]
-            )
-    return dense, pool, tables
-
-
-@pytest.mark.parametrize("window", [None, 24])
-def test_paged_reference_matches_dense_reference(window):
-    rng = np.random.default_rng(0)
-    B, C, KH, D, H, P = 3, 64, 2, 8, 4, 16
-    kd, kp, tables = _scattered_equivalent(rng, B, C, KH, D, P)
-    vd, vp, _ = _scattered_equivalent(rng, B, C, KH, D, P)
-    # v pool must use the same tables as k: rebuild it under k's tables
-    vp = jnp.zeros_like(kp)
-    for s in range(B):
-        for b in range(C // P):
-            vp = vp.at[int(tables[s, b])].set(vd[s, b * P : (b + 1) * P])
-    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    lengths = jnp.asarray([5, 31, 63], jnp.int32)
-    ref = decode_attention_reference(q, kd, vd, lengths, window=window)
-    got = paged_decode_attention_reference(
-        q, kp, vp, tables, lengths, window=window
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5,
-                               atol=1e-5)
-
-
-@pytest.mark.parametrize("window", [None, 24])
-def test_paged_kernel_matches_reference(window):
-    rng = np.random.default_rng(1)
-    B, C, KH, D, H, P = 2, 64, 2, 8, 4, 16
-    kd, kp, tables = _scattered_equivalent(rng, B, C, KH, D, P)
-    vd, vp0, _ = _scattered_equivalent(rng, B, C, KH, D, P)
-    vp = jnp.zeros_like(kp)
-    for s in range(B):
-        for b in range(C // P):
-            vp = vp.at[int(tables[s, b])].set(vd[s, b * P : (b + 1) * P])
-    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    lengths = jnp.asarray([9, 50], jnp.int32)
-    ref = paged_decode_attention_reference(
-        q, kp, vp, tables, lengths, window=window
-    )
-    got = paged_decode_attention(
-        q, kp, vp, tables, lengths, window=window, interpret=True
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5,
-                               atol=1e-5)
-
-
-def test_paged_kernel_ignores_unmapped_pages():
-    """Rows beyond a slot's length live on pages the table never maps —
-    poisoning every unmapped pool page must not change the output."""
-    rng = np.random.default_rng(2)
-    B, C, KH, D, H, P = 1, 64, 2, 8, 4, 16
-    kd, kp, tables = _scattered_equivalent(rng, B, C, KH, D, P)
-    vd, _, _ = _scattered_equivalent(rng, B, C, KH, D, P)
-    vp = jnp.zeros_like(kp)
-    for b in range(C // P):
-        vp = vp.at[int(tables[0, b])].set(vd[0, b * P : (b + 1) * P])
-    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    lengths = jnp.asarray([20], jnp.int32)  # blocks 0-1 valid; 2-3 unread
-    base = paged_decode_attention(q, kp, vp, tables, lengths, interpret=True)
-    # poison the pages holding blocks 2..3 AND the sacrificial page
-    for pg in (0, int(tables[0, 2]), int(tables[0, 3])):
-        kp = kp.at[pg].set(1e9)
-        vp = vp.at[pg].set(1e9)
-    got = paged_decode_attention(q, kp, vp, tables, lengths, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(base), rtol=1e-6,
-                               atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -723,33 +637,6 @@ def test_paged_pool_composes_with_sp_mesh(params, cpu_devices):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("window", [None, 24])
-def test_paged_int8_kernel_parity(window):
-    from aios_tpu.ops import (
-        paged_decode_attention_int8,
-        paged_decode_attention_int8_reference,
-    )
-
-    rng = np.random.default_rng(9)
-    B, H, KH, D, N, P, MB = 3, 8, 2, 16, 16, 16, 4
-    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    k = jnp.asarray(rng.integers(-127, 128, (N, P, KH, D)), jnp.int8)
-    v = jnp.asarray(rng.integers(-127, 128, (N, P, KH, D)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.005, 0.02, (N, P, KH)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.005, 0.02, (N, P, KH)), jnp.float32)
-    tables = jnp.asarray(
-        rng.permutation(np.arange(1, N))[: B * MB].reshape(B, MB), jnp.int32
-    )
-    lens = jnp.asarray([0, 29, 63], jnp.int32)
-    got = paged_decode_attention_int8(
-        q, k, v, ks, vs, tables, lens, window=window, interpret=True
-    )
-    ref = paged_decode_attention_int8_reference(
-        q, k, v, ks, vs, tables, lens, window=window
-    )
-    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=2e-5)
-
-
 def test_paged_decode_step_int8_kernel_wiring(monkeypatch):
     """AIOS_TPU_INT8_RAGGED=1 routes the int8 POOL decode through the
     paged kernel (reference body stands in on CPU); outputs match the
@@ -761,7 +648,7 @@ def test_paged_decode_step_int8_kernel_wiring(monkeypatch):
     B, N, P, MB = 2, 9, 16, 4
     toks = jnp.asarray([1, 2], jnp.int32)
     lens = jnp.asarray([5, 11], jnp.int32)
-    k = jnp.zeros((cfg.num_layers, N, P, cfg.num_kv_heads, cfg.head_dim),
+    k = jnp.zeros((cfg.num_layers, N, P, cfg.num_kv_heads * cfg.head_dim),
                   jnp.int8)
     v = jnp.zeros_like(k)
     scales = (
@@ -777,10 +664,12 @@ def test_paged_decode_step_int8_kernel_wiring(monkeypatch):
 
     called = {}
 
-    def fake_kernel(q, k_l, v_l, k_s, v_s, tbl, lengths, window=None):
+    def fake_kernel(q, k_pool, v_pool, k_s, v_s, layer, tbl, lengths,
+                    window=None, win_starts=None, sink=None):
         called["hit"] = True
+        assert k_pool.shape == k.shape and k_s.shape == scales[0].shape
         return ops_mod.paged_decode_attention_int8_reference(
-            q, k_l, v_l, k_s, v_s, tbl, lengths, window=window
+            q, k_pool, v_pool, k_s, v_s, layer, tbl, lengths, window=window
         )
 
     monkeypatch.setenv("AIOS_TPU_INT8_RAGGED", "1")
