@@ -20,8 +20,6 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
-from .tokenizer import WIDTH
-
 KINDS = ("closed_agents", "open_arrivals")
 GREEDY_TEMPERATURE = 5e-5  # under the engine's greedy threshold; 0 means "unset" on the wire
 
@@ -142,13 +140,15 @@ def _hex_text(n_chars: int, rng: random.Random) -> str:
     return f"{rng.getrandbits(4 * n_chars):0{n_chars}x}" if n_chars > 0 else ""
 
 
-def fill(turn: Turn, seed: int, overhead: Dict[bool, int]) -> Tuple[str, str]:
+def fill(turn: Turn, seed: int, overhead: Dict[bool, int], width: int
+         ) -> Tuple[str, str]:
     """(system, prompt) text of a turn: bytes from --seed, lengths from the
     schedule. `overhead[with_system]` is the chat template's own characters,
-    so that the rendered prompt is exactly `turn.prompt_tokens` tokens."""
-    sys_chars = WIDTH * turn.system_tokens
+    so that the rendered prompt is exactly `turn.prompt_tokens` tokens of
+    `width` characters (the tokenizer's in use)."""
+    sys_chars = width * turn.system_tokens
     system = _hex_text(sys_chars, random.Random(f"{seed}/system/{turn.agent}"))
-    chars = WIDTH * turn.prompt_tokens - overhead[bool(system)] - sys_chars
+    chars = width * turn.prompt_tokens - overhead[bool(system)] - sys_chars
     if chars < 1:
         raise ValueError(f"turn {turn} leaves no room for a prompt")
     prompt = _hex_text(chars, random.Random(f"{seed}/prompt/{turn.agent}/{turn.index}"))
@@ -164,8 +164,9 @@ class LoadGenerator:
     open-loop request in flight."""
 
     def __init__(self, mix: dict, seed: int, overhead: Dict[bool, int],
-                 stream_fn: StreamFn, model: str) -> None:
+                 stream_fn: StreamFn, model: str, width: int) -> None:
         self.mix = mix
+        self.width = width  # characters per token of the tokenizer in use
         self.seed = seed
         self.overhead = overhead
         self.stream_fn = stream_fn
@@ -206,7 +207,7 @@ class LoadGenerator:
             rec.returned = True
 
     def _record(self, turn: Turn) -> Record:
-        system, prompt = fill(turn, self.seed, self.overhead)
+        system, prompt = fill(turn, self.seed, self.overhead, self.width)
         return Record(turn=turn, system=system, prompt=prompt,
                       task_id=f"bench-{max(turn.agent, 0)}-{turn.index}")
 

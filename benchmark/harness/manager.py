@@ -5,9 +5,11 @@ edit it, so ONE subclass of `ModelManager` overrides `_load_weights` for
 `synthetic://` sources and nothing else:
 
 1. weights from `--seed` (`_synthetic_params` hard-codes `PRNGKey(0)`), made
-   by the benchmark's own `weights.py` in the fused int8 serving layout;
+   by the architecture's own file (`benchmark/archs/`) in the serving layout;
 2. the configuration's sizes, depth cut included, from the benchmark's
-   configuration file (`_resolve_preset` takes preset names only);
+   configuration file (`_resolve_preset` takes preset names only): the
+   architecture's `model_fields` gives them as a plain dict, and only here
+   do they become the program's `ModelConfig`;
 3. a tokenizer in which every id decodes to text and there is no eos.
 
 The rest of this file is the client's end of the gRPC surface and the
@@ -28,35 +30,22 @@ from aios_tpu.proto_gen import runtime_pb2
 from aios_tpu.runtime.model_manager import ModelManager
 from aios_tpu.runtime.service import serve
 
-from . import weights
 from .tokenizer import FixedWidthTokenizer
 
 
-def model_config(config: dict, context: int) -> ModelConfig:
-    d = weights.dims_of(config)
-    return ModelConfig(
-        name=config["assumed"]["served_name"], vocab_size=d.vocab,
-        hidden_size=d.hidden, intermediate_size=d.ffn, num_layers=d.layers,
-        num_heads=d.heads, num_kv_heads=d.kv_heads, head_dim=d.head_dim,
-        max_context=context, rope_theta=d.rope_theta, rms_norm_eps=d.eps,
-        sliding_window=d.window, num_experts=d.experts,
-        num_experts_per_tok=d.top_k or 2,
-    )
-
-
 class SeededManager(ModelManager):
-    def __init__(self, config: dict, seed: int, **kw) -> None:
+    def __init__(self, arch, config: dict, seed: int, **kw) -> None:
         super().__init__(**kw)
+        self._bench_arch = arch
         self._bench_config = config
         self._bench_seed = seed
 
     def _load_weights(self, name, path, context_length, draft=False):
         if not path.startswith("synthetic://") or draft:
             return super()._load_weights(name, path, context_length, draft)
-        cfg = model_config(self._bench_config, context_length)
-        params = weights.build_params(
-            weights.dims_of(self._bench_config), self._bench_seed
-        )
+        arch = self._bench_arch
+        cfg = ModelConfig(**arch.model_fields(self._bench_config, context_length))
+        params = arch.build_params(arch.dims_of(self._bench_config), self._bench_seed)
         return cfg, params, FixedWidthTokenizer(cfg.vocab_size)
 
 
@@ -64,12 +53,12 @@ class Served:
     """The system under test, started in this process: the real AIRuntime
     gRPC server on a localhost port, and a stub to it."""
 
-    def __init__(self, config: dict, seed: int) -> None:
+    def __init__(self, arch, config: dict, seed: int) -> None:
         # the environment the default boot config produces (paged KV "auto")
         os.environ.update(serving_env(AiosConfig()))
         assumed = config["assumed"]
         self.name = assumed["served_name"]
-        self.manager = SeededManager(config, seed, num_slots=int(assumed["slots"]))
+        self.manager = SeededManager(arch, config, seed, num_slots=int(assumed["slots"]))
         self.server, self.service, port = serve(
             address="127.0.0.1:0", manager=self.manager, block=False
         )
@@ -80,7 +69,7 @@ class Served:
         flightrec.RECORDER.add_listener(self._listener)
         status = self.stub.LoadModel(runtime_pb2.LoadModelRequest(
             model_name=self.name, model_path=f"synthetic://{self.name}",
-            context_length=int(config["max_position_embeddings"]),
+            context_length=int(arch.context_length(config)),
         ), timeout=1500)
         if status.status != "ready":
             raise RuntimeError(f"LoadModel -> {status.status!r}")

@@ -4,16 +4,21 @@
 found by name under the benchmark's directory: `configs/<config>.json`,
 `traffic/<traffic>.json`, and every `layer_metrics/*.json` whose `kinds`
 lists the traffic's kind (or `all`) — never a cell's name, so that a later
-cell picks up the metrics that are there without an edit.
+cell picks up the metrics that are there without an edit. Code that belongs
+to one architecture or one metric is a file of its own there too, loaded by
+its location: `archs/<arch>.py` for the `arch` a configuration file states,
+`layer_metrics/<file>.py` for a metric's reader.
 """
 
 from __future__ import annotations
 
 import glob
+import importlib.util
 import json
 import os
 import re
-from typing import List
+import sys
+from typing import Dict, List
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -23,6 +28,27 @@ MAX_END_TO_END = 4  # besides setup_s
 
 class ManifestError(ValueError):
     pass
+
+
+_LOADED: Dict[str, object] = {}
+
+
+def load_file(path: str, prefix: str):
+    """The module in the file at `path`, loaded once a process by its
+    location and not by a package name, so that a later PR adds such a file
+    without editing an index. It is entered in `sys.modules` under
+    `<prefix>_<file>` (dataclasses look their module up there)."""
+    path = os.path.abspath(path)
+    if path not in _LOADED:
+        name = f"{prefix}_{os.path.basename(path)[:-3]}"
+        if not os.path.isfile(path):
+            raise ManifestError(f"no file {path}")
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+        _LOADED[path] = mod
+    return _LOADED[path]
 
 
 def _json(path: str) -> dict:
@@ -50,6 +76,18 @@ class Manifest:
 
     def config(self, name: str) -> dict:
         return _json(os.path.join(self.root, self.config_entry(name)["file"]))
+
+    def arch_file(self, config: dict) -> str:
+        """Where the file of the architecture a configuration names lies:
+        `archs/<arch>.py` under the benchmark's directory."""
+        name = config.get("arch")
+        if not isinstance(name, str) or not NAME.match(name):
+            raise ManifestError(f"a configuration's `arch` is a name, not {name!r}")
+        return os.path.join(self.dir, "archs", f"{name}.py")
+
+    def arch(self, config: dict):
+        """That file's module (it imports JAX)."""
+        return load_file(self.arch_file(config), "benchmark_arch")
 
     def traffic(self, name: str) -> dict:
         return _json(os.path.join(self.dir, "traffic", f"{name}.json"))
@@ -111,7 +149,9 @@ def check(m: Manifest) -> None:
                 raise ManifestError(f"{key} of {entry['name']} is not 1 to 200 characters on one line")
     cells = {w["name"]: w for w in doc["workloads"]}
     for w in doc["workloads"]:
-        m.config(w["config"])
+        arch_file = m.arch_file(m.config(w["config"]))
+        if not os.path.isfile(arch_file):
+            raise ManifestError(f"configuration {w['config']}: there is no {arch_file}")
         m.traffic(w["traffic"])
         if len(m.end_to_end_of(w["name"])) < 2:
             raise ManifestError(f"cell {w['name']} reports no end-to-end metric besides setup_s")

@@ -3,7 +3,9 @@ and returns a number, or None where it finds nothing to read (the harness
 then leaves the metric out of the line). A metric file under
 `benchmark/layer_metrics/` names its reader: `"reader": "fn"` is a function
 here, `"reader": "file.py:fn"` one in a file beside the metric file, so a
-later PR adds a metric without editing this module.
+later PR adds a metric without editing this module. What a reader needs of
+the model (its sizes, its roofline counts, its trace markers) it takes from
+the Context: `ctx.arch` is the architecture's module, `ctx.dims` its sizes.
 """
 
 from __future__ import annotations
@@ -14,12 +16,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from . import metrics, roofline, xplane
 from .loadgen import Record
-from .weights import Dims
 
 
 @dataclass
 class Context:
-    dims: Dims
+    arch: object  # the architecture's module (benchmark/archs/<arch>.py)
+    dims: object  # its `dims_of(config)`
     mix: dict
     slots: int
     records: Sequence[Record]
@@ -135,7 +137,8 @@ def _decode(ctx) -> List[Tuple[float, int]]:
     if ctx.planes is None:
         return []
     if "decode" not in ctx.cache:
-        ctx.cache["decode"] = xplane.decode_steps(ctx.planes, ctx.dims.layers)
+        ctx.cache["decode"] = xplane.decode_steps(
+            ctx.planes, **ctx.arch.trace_markers(ctx.dims))
     return ctx.cache["decode"]
 
 
@@ -180,8 +183,8 @@ def kernels_decode_roofline_pct(ctx):
         active, rows = _load_at(ctx, t)
         if active:
             least += roofline.least_seconds(
-                roofline.decode_step_ops(ctx.dims, active, rows),
-                roofline.decode_step_bytes(ctx.dims, active, rows), ctx.peaks,
+                ctx.arch.decode_step_ops(ctx.dims, active, rows),
+                ctx.arch.decode_step_bytes(ctx.dims, active, rows), ctx.peaks,
             )["seconds"]
             n += 1
         t += 0.05
@@ -225,8 +228,8 @@ def kernels_prefill_roofline_pct(ctx):
         return None
     least = sum(
         roofline.least_seconds(
-            roofline.prefill_ops(ctx.dims, [b + n], [b]),
-            roofline.prefill_bytes(ctx.dims, n), ctx.peaks,
+            ctx.arch.prefill_ops(ctx.dims, [b + n], [b]),
+            ctx.arch.prefill_bytes(ctx.dims, n), ctx.peaks,
         )["seconds"]
         for b, n in runs
     )

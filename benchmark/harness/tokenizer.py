@@ -2,9 +2,11 @@
 
 The program's ByteTokenizer decodes ids >= 256 to nothing, so a client would
 see under 1 % of a 32,000-row vocabulary's tokens, and its eos id would end
-a sampled request early. Here every id decodes to WIDTH hex characters and
+a sampled request early. Here every id decodes to `width` hex characters and
 there is no eos, so a request of `max_tokens` returns exactly that many
-chunks and the client reads each served id back from its chunk.
+chunks and the client reads each served id back from its chunk. The width
+follows the vocabulary: 4 digits up to 65,536 rows, 5 above; the load
+generator and the check take it from the tokenizer in use.
 """
 
 from __future__ import annotations
@@ -12,7 +14,7 @@ from __future__ import annotations
 import zlib
 from typing import List, Optional, Sequence
 
-WIDTH = 4  # characters per token; 16**4 ids at most
+WIDTHS = (4, 5)  # characters per token: the first that holds every id
 
 
 class FixedWidthTokenizer:
@@ -20,34 +22,33 @@ class FixedWidthTokenizer:
     eos_id: Optional[int] = None
 
     def __init__(self, vocab_size: int) -> None:
-        if not 0 < vocab_size <= 16 ** WIDTH:
-            raise ValueError(f"vocab {vocab_size} does not fit {WIDTH} hex digits")
+        fits = [w for w in WIDTHS if 0 < vocab_size <= 16 ** w]
+        if not fits:
+            raise ValueError(f"vocab {vocab_size} does not fit {WIDTHS[-1]} hex digits")
         self._vocab = vocab_size
+        self.width = fits[0]
 
     @property
     def vocab_size(self) -> int:
         return self._vocab
 
     def piece_id(self, piece: str) -> int:
-        """The id of one WIDTH-character piece: its own hex value where that
+        """The id of one `width`-character piece: its own hex value where that
         is an id, else a hash of it (template text, padding)."""
-        if len(piece) == WIDTH:
+        if len(piece) == self.width:
             try:
                 v = int(piece, 16)
             except ValueError:
                 v = -1
-            if 0 <= v < self._vocab and piece == f"{v:0{WIDTH}x}":
+            if 0 <= v < self._vocab and piece == f"{v:0{self.width}x}":
                 return v
         return zlib.crc32(piece.encode("utf-8")) % self._vocab
 
     def encode(self, text: str, add_bos: bool = True) -> List[int]:
         return [
-            self.piece_id(text[i:i + WIDTH]) for i in range(0, len(text), WIDTH)
+            self.piece_id(text[i:i + self.width])
+            for i in range(0, len(text), self.width)
         ]
 
     def decode(self, ids: Sequence[int]) -> str:
-        return "".join(f"{int(i):0{WIDTH}x}" for i in ids)
-
-
-def token_count(n_chars: int) -> int:
-    return -(-n_chars // WIDTH)
+        return "".join(f"{int(i):0{self.width}x}" for i in ids)

@@ -8,6 +8,11 @@ A device plane is `/device:TPU:<n>`. Its "XLA Modules" line has one event per
 execution of a compiled program (named `jit_<function>(<id>)`), its "XLA Ops"
 line one event per executed HLO operation (named by the instruction's text;
 control-flow operations enclose the operations of their bodies).
+
+Which kernel marks a decode step, and how often a step runs it, is the
+architecture's (`trace_markers(d)` of its file under `benchmark/archs/`):
+`decode_steps` takes both as arguments. The names of the engine's decode and
+prefill programs are the program's own, the same whatever model it serves.
 """
 
 from __future__ import annotations
@@ -25,7 +30,6 @@ MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 # operations that only enclose others: counted in no ranking, but busy all the same
 _CONTROL = re.compile(r"^%?(while|conditional|call)[.\d]*$")
-DECODE_KERNEL = "paged_decode_attention"  # the program's kernel name, once per layer per step
 DECODE_MODULE = "jit__lambda"  # the engine's step graphs are lambdas
 PREFILL_MODULE = re.compile(r"prefill|chunk")
 
@@ -129,23 +133,26 @@ def module_kind(name: str) -> str:
     return "prefill" if PREFILL_MODULE.search(name) else "other"
 
 
-def decode_steps(planes: Dict[str, Plane], layers: int) -> List[Tuple[float, int]]:
+def decode_steps(planes: Dict[str, Plane], decode_kernel: str, kernels_per_step: int
+                 ) -> List[Tuple[float, int]]:
     """(device seconds, steps) of each decode program execution. A program
-    runs several steps per dispatch; the paged attention kernel runs once per
-    layer per step, so its count inside a program's interval gives the steps."""
+    runs several steps per dispatch; a step runs the kernel named
+    `decode_kernel` `kernels_per_step` times (an attention kernel, once per
+    layer that has it), so its count inside a program's interval gives the
+    steps."""
     dev = device_planes(planes)
     if not dev:
         return []
     first = dev[sorted(dev)[0]]
     kernels = sorted(s for name, s, _ in first.get(OPS_LINE, [])
-                     if DECODE_KERNEL in name)
+                     if decode_kernel in name)
     out = []
     for name, s, d in modules(planes):
         if module_kind(name) != "decode":
             continue
         n = bisect.bisect_left(kernels, s + d) - bisect.bisect_left(kernels, s)
-        if n >= layers:
-            out.append((d / 1e9, n // layers))
+        if n >= kernels_per_step:
+            out.append((d / 1e9, n // kernels_per_step))
     return out
 
 

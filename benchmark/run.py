@@ -8,7 +8,11 @@ gRPC server on a localhost port in this process (one process holds the
 chip), drives it with client threads through `StreamInfer`, measures for
 `--seconds`, checks what was served against the plain reference, and prints
 one JSON object as the last line. Everything a cell is made of is data under
-this directory, found by the names in BENCHMARK.json (harness/manifest.py).
+this directory, found by the names in BENCHMARK.json (harness/manifest.py):
+its configuration and traffic files, its per-layer metrics' readers, and the
+one file of its architecture (`archs/<arch>.py`, named by the configuration's
+`arch`), which makes the weights, is the plain reference's forward pass,
+counts the roofline's bytes and operations and names the trace's markers.
 """
 
 from __future__ import annotations
@@ -18,7 +22,7 @@ import time
 T_START = time.time()  # set-up is counted from here
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
+import ctypes  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import random  # noqa: E402
@@ -33,7 +37,6 @@ sys.path.insert(0, REPO)
 
 from benchmark.harness import manifest as manifest_mod  # noqa: E402
 from benchmark.harness import metrics  # noqa: E402
-from benchmark.harness.tokenizer import WIDTH  # noqa: E402
 
 TRACE_SECONDS = 5.0
 
@@ -51,7 +54,8 @@ def parse(argv=None) -> argparse.Namespace:
     p.add_argument("--root", default=REPO,
                    help="directory that holds BENCHMARK.json and its data (tests)")
     p.add_argument("--control", type=int, choices=(0, 1), default=0,
-                   help="also read the int4 control's gap (PERF.md, limits)")
+                   help="also read the gap of the configuration's control, the "
+                        "reference one precision below (PERF.md, limits)")
     p.add_argument("--rehearsal-cpu", action="store_true",
                    help="tests only: run on the CPU and print counts, never a time")
     return p.parse_args(argv)
@@ -62,11 +66,7 @@ def load_reader(spec: str, metric_path: str):
     if ":" in spec:
         file, fn = spec.split(":", 1)
         path = os.path.join(os.path.dirname(metric_path), file)
-        mod_spec = importlib.util.spec_from_file_location(
-            f"benchmark_reader_{os.path.basename(file)[:-3]}", path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return getattr(mod, fn)
+        return getattr(manifest_mod.load_file(path, "benchmark_reader"), fn)
     from benchmark.harness import readers
 
     return getattr(readers, spec)
@@ -90,10 +90,24 @@ class Sampler(threading.Thread):
         self.join(5)
 
 
-def served_ids(rec) -> list:
+def trim_heap() -> float:
+    """Hand the heap's freed pages back to the system now, in set-up; the
+    seconds it took. Left to itself glibc does it when some free() finds the
+    top of the heap empty: from whichever thread, inside a C call that holds
+    the GIL, 0.7-0.9 s after LoadModel's compilations, and that fell 4-5 s
+    into the window of every checkout's first run (PERF.md section 7)."""
+    t = time.monotonic()
+    try:
+        ctypes.CDLL("libc.so.6").malloc_trim(0)
+    except (OSError, AttributeError):
+        pass  # another libc: nothing to hand back
+    return time.monotonic() - t
+
+
+def served_ids(rec, width: int) -> list:
     ids = []
     for text in rec.texts:
-        if len(text) != WIDTH:
+        if len(text) != width:
             raise ValueError(f"chunk {text!r} is not one token")
         ids.append(int(text, 16))
     return ids
@@ -111,22 +125,22 @@ def check_sample(records, seed: int, n: int) -> list:
     return [longest] + rest[:max(n - 1, 0)]
 
 
-def compare_with_reference(served, dims, seed: int, sample, control: bool,
+def compare_with_reference(served, arch, dims, seed: int, sample, control: str,
                            pad_to: int) -> dict:
     """Per checked position: the served token's gap, the reference's own
-    router margin there, and with `control` the gap of the int4 reference's
-    first token."""
+    router margin there, and with `control` (a precision below the
+    configuration's) the gap of that reference's first token."""
     from benchmark.harness import reference
 
     seqs, keep, answers = [], [], []
     for rec in sample:
         prompt = served.prompt_ids(rec.prompt, rec.system)
-        ids = served_ids(rec)
+        ids = served_ids(rec, served.tokenizer.width)
         seqs.append(prompt + ids)
         keep.append(len(prompt) - 1)
         answers.append(ids)
-    precisions = ("float32", "int4") if control else ("float32",)
-    logits = reference.logits_for(dims, seed, seqs, keep, precisions, pad_to)
+    precisions = ("float32", control) if control else ("float32",)
+    logits = reference.logits_for(arch, dims, seed, seqs, keep, precisions, pad_to)
     gaps, margins, low_gaps, where, std = [], [], [], [], 0.0
     for i, ids in enumerate(answers):
         ref = logits["float32"][i][:len(ids)]
@@ -136,7 +150,7 @@ def compare_with_reference(served, dims, seed: int, sample, control: bool,
         std = float(ref.std())
         if control:
             low_gaps.extend(reference.control_gaps(
-                ref, logits["int4"][i][:len(ids)]).tolist())
+                ref, logits[control][i][:len(ids)]).tolist())
     return {"gaps": gaps, "margins": margins, "control_gaps": low_gaps,
             "where": where, "logit_std": std, "rows": [len(s) for s in seqs]}
 
@@ -212,6 +226,8 @@ def main(argv=None) -> int:
         import jax
 
         from benchmark.harness.manager import Served
+
+        arch = man.arch(config)
     except ImportError as exc:
         say(f"the program is not in this directory ({exc}); nothing to measure")
         return 3
@@ -232,33 +248,38 @@ def main(argv=None) -> int:
         f"seconds {args.seconds} trace {args.trace}")
     t_backend = time.time()
 
-    served = Served(config, args.seed)
+    served = Served(arch, config, args.seed)
     t_loaded = time.time()
     try:
-        return measure(args, man, cell, config, mix, served, dev, len(devices),
+        return measure(args, man, cell, config, mix, arch, served, dev, len(devices),
                        t_backend, t_loaded)
     finally:
         served.close()
 
 
-def measure(args, man, cell, config, mix, served, dev, n_devices,
+def measure(args, man, cell, config, mix, arch, served, dev, n_devices,
             t_backend, t_loaded) -> int:
     import jax
 
-    from benchmark.harness import readers, reference, weights, xplane
+    from benchmark.harness import readers, reference, xplane
     from benchmark.harness.loadgen import LoadGenerator
     from benchmark.harness.peaks import peaks_of
 
     rehearsal = args.rehearsal_cpu
-    dims = weights.dims_of(config)
+    dims = arch.dims_of(config)
     peaks = peaks_of(dev.device_kind) if on_chip(dev) else None  # unknown kind: an error
     gen = LoadGenerator(mix, args.seed, served.template_overhead(),
-                        served.stream, served.name)
+                        served.stream, served.name, served.tokenizer.width)
+    trimmed = [trim_heap()]  # what loading and compiling freed, before a request is sent
     gen.start()
     warm_until = gen.t0 + float(mix["warm_s"])
     for ev in gen.first_turn_done:
         if not ev.wait(300):
             raise RuntimeError("an agent never finished its first turn")
+    # and what the first requests freed, a second before the window opens: the
+    # window still opens at warm_until, on the schedule's own instant
+    time.sleep(max(warm_until - 1.0 - time.monotonic(), 0.0))
+    trimmed.append(trim_heap())
     time.sleep(max(warm_until - time.monotonic(), 0.0))
 
     # -- the window ---------------------------------------------------------
@@ -322,11 +343,11 @@ def measure(args, man, cell, config, mix, served, dev, n_devices,
         load = served.setup_seconds()
         loaded = sum(load.get(k, 0) for k in ("weights", "engines", "warmup"))
         say("set-up s: imports+backend %.2f, weights %.2f, engine placement %.2f, "
-            "LoadModel AOT warm-up %.2f, server+rest %.2f, warm traffic %.2f; "
-            "setup_s %.2f"
+            "LoadModel AOT warm-up %.2f, server+rest %.2f, warm traffic %.2f "
+            "(heap trims in it %.2f + %.2f); setup_s %.2f"
             % (t_backend - T_START, load.get("weights", 0), load.get("engines", 0),
                load.get("warmup", 0), t_loaded - t_backend - loaded,
-               setup_s - (t_loaded - T_START), setup_s))
+               setup_s - (t_loaded - T_START), trimmed[0], trimmed[1], setup_s))
 
     # -- per layer ----------------------------------------------------------
     planes = None
@@ -334,7 +355,7 @@ def measure(args, man, cell, config, mix, served, dev, n_devices,
         planes = xplane.load(xplane.find(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
     ctx = readers.Context(
-        dims=dims, mix=mix, slots=int(config["assumed"]["slots"]),
+        arch=arch, dims=dims, mix=mix, slots=int(config["assumed"]["slots"]),
         records=records, w0=w0, w1=w1, before=before, after=after,
         samples=sampler.samples, timelines=list(served.timelines),
         peaks=peaks, peak_bytes=peak_bytes, planes=planes,
@@ -364,8 +385,9 @@ def measure(args, man, cell, config, mix, served, dev, n_devices,
     # one padded length for the whole sample: the schedule is fixed, so the
     # longest greedy request of the window, and with it the compiled block,
     # is the same in every run of a cell
+    control = (check.get("control") or arch.CONTROL) if args.control else ""
     ref = compare_with_reference(
-        served, dims, args.seed, sample, bool(args.control),
+        served, arch, dims, args.seed, sample, control,
         reference.bucket(max(r.turn.prompt_tokens + r.turn.answer_tokens for r in sample)),
     ) if sample else None
     t_checked = time.time()
@@ -386,6 +408,12 @@ def measure(args, man, cell, config, mix, served, dev, n_devices,
                    and compiles == 0
                    and counts["failed"] == 0 and counts["attempted"] > 0
                    and not gen.error and (on_chip(dev) or rehearsal))
+    # every number compared, beside its limit: last on standard error and last
+    # in the result's line, which is all the driver's record keeps of a run
+    compared = {f"gap_p{q:g}": [gap, limit], "compiles_in_window": [compiles, 0],
+                "failed": [counts["failed"], 0]}
+    if bulk_q:
+        compared[f"bulk_gap_p{float(bulk_q):g}"] = [bulk, float(bulk_limit)]
     say(f"correct: served-token logit gap p{q:g} {gap} limit {limit} over {len(kept)} of "
         f"{len(ref['gaps']) if ref else 0} greedy tokens of {len(sample)} requests "
         f"(those with router margin >= {eps:g}; rows {ref['rows'] if ref else None}; "
@@ -399,7 +427,7 @@ def measure(args, man, cell, config, mix, served, dev, n_devices,
         say_by_margin(ref, "gaps", "served")
         if ref["control_gaps"]:
             low = metrics.percentile([ref["control_gaps"][i] for i in kept], q)
-            say(f"control (the int4 reference's first token in the served token's place): "
+            say(f"control (the {control} reference's first token in the served token's place): "
                 f"gap p{q:g} {low} over the same {len(kept)} positions; has to lie above {limit}")
             say_control(ref)
     # -- the line -----------------------------------------------------------
@@ -426,6 +454,9 @@ def measure(args, man, cell, config, mix, served, dev, n_devices,
     if rehearsal:
         line["rehearsal"] = True
     line["device"] = device
+    line["compared"] = {k: {"value": v, "limit": lim} for k, (v, lim) in compared.items()}
+    for k, (v, lim) in compared.items():
+        print(f"compared: {k} {v} limit {lim}", file=sys.stderr, flush=True)
     say(json.dumps(line))
     return 0
 

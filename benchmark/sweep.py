@@ -46,13 +46,14 @@ def main() -> int:
     if jax.devices()[0].platform != "tpu":
         print("needs the chip")
         return 2
-    served = Served(config, args.seed)
+    served = Served(man.arch(config), config, args.seed)
     print("| rate_rps | attempted | failed | ttft_p50_ms | ttft_p90_ms | tpot_p50_ms "
           "| queue_wait_ms 1st half | 2nd half | waiting at close |", flush=True)
     try:
         for k, rate in enumerate(float(r) for r in args.rates.split(",")):
             gen = LoadGenerator({**mix, "rate_rps": rate}, args.seed + 1 + k,
-                                served.template_overhead(), served.stream, served.name)
+                                served.template_overhead(), served.stream, served.name,
+                                served.tokenizer.width)
             gen.start()
             w0 = gen.t0 + float(mix["warm_s"])
             w1 = w0 + args.seconds

@@ -13,11 +13,14 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, REPO)
 
 from benchmark.harness import reference  # noqa: E402
-from benchmark.harness import weights as W  # noqa: E402
+from benchmark.harness.manifest import load_file  # noqa: E402
 
-DENSE = W.Dims(layers=4, hidden=256, ffn=512, heads=4, kv_heads=2, head_dim=64,
+# the sizes are the architecture's own: its file is found as a run finds it
+A = load_file(os.path.join(REPO, "benchmark", "archs", "mistral.py"), "benchmark_arch")
+
+DENSE = A.Dims(layers=4, hidden=256, ffn=512, heads=4, kv_heads=2, head_dim=64,
                vocab=2048, experts=0, top_k=0, rope_theta=1e4, eps=1e-5, window=None)
-MOE = W.Dims(layers=3, hidden=256, ffn=256, heads=4, kv_heads=2, head_dim=64,
+MOE = A.Dims(layers=3, hidden=256, ffn=256, heads=4, kv_heads=2, head_dim=64,
              vocab=2048, experts=4, top_k=2, rope_theta=1e6, eps=1e-5, window=64)
 LIMIT = 0.01  # test size: a sound float32 run reads 0.0, the control below
 
@@ -32,9 +35,9 @@ def _sequences(seed):
 def test_the_int4_control_comes_out_not_correct(dims, seed):
     seqs = _sequences(seed)
     keep = [len(s) - 65 for s in seqs]
-    out = reference.logits_for(dims, seed, seqs, keep, ("float32", "int4"))
+    out = reference.logits_for(A, dims, seed, seqs, keep, ("float32", A.CONTROL))
     worst = 0.0
-    for ref, low, seq, k in zip(out["float32"], out["int4"], seqs, keep):
+    for ref, low, seq, k in zip(out["float32"], out[A.CONTROL], seqs, keep):
         assert ref.shape == (len(seq) - k, dims.vocab)
         # the reference's own first tokens have gap 0: the comparison is sound
         assert reference.served_gaps(ref[:-1], ref[:-1].argmax(-1)).max() == 0.0
@@ -53,8 +56,8 @@ def test_the_router_margin_is_read_layer_by_layer():
     import dataclasses
 
     seq = list(np.random.RandomState(5).randint(0, 2048, 300))
-    deep = reference.logits_for(MOE, 5, [seq], [100])["router_margin"][0]
-    one = reference.logits_for(dataclasses.replace(MOE, layers=1), 5, [seq],
+    deep = reference.logits_for(A, MOE, 5, [seq], [100])["router_margin"][0]
+    one = reference.logits_for(A, dataclasses.replace(MOE, layers=1), 5, [seq],
                                [100])["router_margin"][0]
     assert deep.shape == (200, 3) and one.shape == (200, 1)
     np.testing.assert_allclose(deep[:, 0], one[:, 0], rtol=1e-5)
@@ -66,16 +69,16 @@ def test_the_router_margin_is_read_layer_by_layer():
 def test_the_reference_builds_one_layer_at_a_time_from_the_seed_alone():
     import jax
 
-    full = W.build_params(DENSE, 2 ** 31 + 5)
+    full = A.build_params(DENSE, 2 ** 31 + 5)
     for layer in (0, 3):
-        one = W.build_layer(DENSE, 2 ** 31 + 5, layer)
+        one = A.build_layer(DENSE, 2 ** 31 + 5, layer)
         same = jax.tree.map(lambda a, b: bool((a[layer] == b).all()),
                             full["layers"], one)
         assert all(jax.tree.leaves(same))
-    top = W.build_top(DENSE, 2 ** 31 + 5)
+    top = A.build_top(DENSE, 2 ** 31 + 5)
     assert bool((top["lm_head"]["q"] == full["lm_head"]["q"]).all())
-    other = W.build_layer(DENSE, 2 ** 31 + 6, 0)
-    assert not bool((other["wo"]["q"] == W.build_layer(DENSE, 2 ** 31 + 5, 0)["wo"]["q"]).all())
+    other = A.build_layer(DENSE, 2 ** 31 + 6, 0)
+    assert not bool((other["wo"]["q"] == A.build_layer(DENSE, 2 ** 31 + 5, 0)["wo"]["q"]).all())
     # int8 matrices in the fused layout the configurations state
     assert full["layers"]["w_qkv"]["q"].shape == (4, 256, 256 + 2 * 128)
     assert str(full["layers"]["w_qkv"]["q"].dtype) == "int8"
@@ -83,7 +86,7 @@ def test_the_reference_builds_one_layer_at_a_time_from_the_seed_alone():
 
 def test_padding_to_a_bucket_does_not_change_a_causal_model_s_logits():
     seq = list(np.random.RandomState(3).randint(0, 2048, 450))
-    a = reference.logits_for(DENSE, 3, [seq], [400])["float32"][0]
-    b = reference.logits_for(DENSE, 3, [seq + [7] * 150], [400])["float32"][0][:50]
+    a = reference.logits_for(A, DENSE, 3, [seq], [400])["float32"][0]
+    b = reference.logits_for(A, DENSE, 3, [seq + [7] * 150], [400])["float32"][0][:50]
     assert reference.bucket(len(seq)) != reference.bucket(len(seq) + 150)
     np.testing.assert_allclose(a, b, atol=1e-5)

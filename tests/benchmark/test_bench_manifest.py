@@ -11,7 +11,9 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import published  # noqa: E402
 from benchmark.harness import manifest  # noqa: E402
 
 
@@ -53,24 +55,16 @@ def test_every_cell_finds_its_files_by_name_and_its_metrics_by_traffic_kind():
 
 
 def test_a_configuration_lists_every_key_it_changed_and_never_a_width():
+    """The rule is `published.check`; what each source says is a file beside
+    the test data, one per configuration, so a new one brings its own."""
     m = manifest.Manifest(REPO)
-    widths = ("hidden_size", "intermediate_size", "head_dim", "num_experts_per_tok")
-    published = {
-        "mistral-7b-int8": dict(num_hidden_layers=32, sliding_window=4096,
-                                rope_theta=10000.0),
-        "mixtral-8x7b-int8-d6": dict(num_local_experts=8, num_experts_per_tok=2,
-                                     rope_theta=1000000.0),
-    }
     for entry in m.doc["configs"]:
-        config = m.config(entry["name"])
-        assert sorted(entry["reduced"]) == sorted(config["reduced"])
-        assert not set(entry["reduced"]) & set(widths)
-        assert (config["hidden_size"], config["intermediate_size"],
-                config["num_attention_heads"], config["num_key_value_heads"],
-                config["vocab_size"]) == (4096, 14336, 32, 8, 32000)
-        for k, v in published[entry["name"]].items():
-            assert config[k] == v
-        assert config["check"]["logit_gap_limit"] > 0
+        doc = published.check(m, entry["name"])
+        assert {"hidden_size", "intermediate_size"} <= set(doc["widths"])
+    listed = copy.deepcopy(m)  # the same, with a width listed as cut
+    listed.doc["configs"][0]["reduced"].append("intermediate_size")
+    with pytest.raises(AssertionError, match="intermediate_size is a width"):
+        published.check(listed, listed.doc["configs"][0]["name"])
 
 
 def _mutate(tmp_path, change):

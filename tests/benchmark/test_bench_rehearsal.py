@@ -32,10 +32,42 @@ def root(tmp_path_factory):
     return rehearsal_root.build(str(tmp_path_factory.mktemp("bench")))
 
 
-@pytest.mark.parametrize("cell,trace", [("tiny-agents", 1), ("tiny-moe-arrivals", 0)])
+def test_adding_cells_and_an_architecture_edits_no_file_that_is_there(root):
+    """Every committed file under benchmark/ is in the temporary root byte for
+    byte; what the root has besides is what `rehearsal_root` says it added."""
+    import filecmp
+
+    committed, there = set(), set()
+    for base, found in ((os.path.join(REPO, "benchmark"), committed),
+                        (os.path.join(root, "benchmark"), there)):
+        for folder, _, files in os.walk(base):
+            if "__pycache__" not in folder:
+                found.update(os.path.relpath(os.path.join(folder, f), os.path.dirname(base))
+                             for f in files if not f.endswith(".pyc"))
+    assert committed <= there and there - committed == rehearsal_root.added()
+    assert len(committed) > 40
+    for rel in sorted(committed):
+        assert filecmp.cmp(os.path.join(REPO, rel), os.path.join(root, rel), shallow=False), rel
+    # the third configuration is of another architecture by its `arch` key alone,
+    # and keeps to the rule on what a configuration may change by its own file
+    sys.path.insert(0, HERE)
+    import published
+    from benchmark.harness import manifest
+
+    man = manifest.Manifest(root)
+    manifest.check(man)
+    assert man.config("tiny-other")["arch"] == "otherfamily"
+    assert published.check(man, "tiny-other")["widths"][0] == "n_embd"
+    assert not os.path.exists(os.path.join(REPO, "benchmark", "archs", "otherfamily.py"))
+
+
+@pytest.mark.parametrize("cell,trace", [("tiny-agents", 1), ("tiny-moe-arrivals", 0),
+                                        ("tiny-other-agents", 1)])
 def test_rehearsal_prints_the_contract_s_last_line_and_no_device_metric(root, cell, trace):
+    other = cell == "tiny-other-agents"  # of another architecture: its control is read too
     done = _run(["--root", root, "--workload", cell, "--seed", "3000000001",
-                 "--seconds", "3", "--trace", str(trace), "--rehearsal-cpu"])
+                 "--seconds", "3", "--trace", str(trace), "--rehearsal-cpu"]
+                + (["--control", "1"] if other else []))
     assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
     lines = done.stdout.strip().splitlines()
     line = json.loads(lines[-1])
@@ -60,6 +92,18 @@ def test_rehearsal_prints_the_contract_s_last_line_and_no_device_metric(root, ce
     assert any(l.startswith("requests: attempted") and "failed 0" in l for l in lines)
     assert any(l.startswith("correct: served-token logit gap p") for l in lines)
     assert "no time is printed" in done.stdout
+    # every number compared stands beside its limit: last in the line, last on stderr
+    assert list(line)[-1] == "compared" and line["compared"]["failed"] == {"value": 0, "limit": 0}
+    gap = next(v for k, v in line["compared"].items() if k.startswith("gap_p"))
+    assert 0 <= gap["value"] <= gap["limit"]
+    said = [l for l in done.stderr.splitlines() if l.startswith("compared: ")]
+    assert len(said) == len(line["compared"]) and done.stderr.strip().endswith(said[-1])
+    if other:
+        # the control is the one the configuration's `check` names (the file of
+        # its architecture states none); that it FAILS is shown at a size that
+        # can carry it (test_bench_control.py): 30 tokens of a 64-wide model cannot
+        low = next(l for l in lines if l.startswith("control (the int4 reference"))
+        assert float(low.split("gap p100 ")[1].split(" ")[0]) >= 0.0, low
 
 
 def test_without_a_chip_it_exits_non_zero_and_prints_no_result():
@@ -139,3 +183,20 @@ def test_the_rule_of_the_configurations_with_a_router_sees_what_the_old_one_let_
     gap = float(said.split("gap p95 ")[1].split(" ")[0])
     assert gap > 0.05, said
     print(said)
+
+
+def test_the_heap_trim_of_set_up_takes_time_and_never_stops_a_run(monkeypatch):
+    """`run.trim_heap` hands freed pages back before the window opens (PERF.md
+    section 7: left to glibc it stalled every stream for 0.7-0.9 s inside the
+    window of a checkout's first run). With another libc it does nothing."""
+    import ctypes
+
+    from benchmark import run as bench_run
+
+    assert 0.0 <= bench_run.trim_heap() < 60.0
+
+    def no_glibc(name):
+        raise OSError(name)
+
+    monkeypatch.setattr(ctypes, "CDLL", no_glibc)
+    assert 0.0 <= bench_run.trim_heap() < 1.0

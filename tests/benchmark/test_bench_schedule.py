@@ -24,7 +24,7 @@ def _mix(path):
 
 
 def _gen(mix, seed):
-    return loadgen.LoadGenerator(mix, seed, OVERHEAD, lambda f, deadline_s: iter(()), "m")
+    return loadgen.LoadGenerator(mix, seed, OVERHEAD, lambda f, deadline_s: iter(()), "m", 4)
 
 
 @pytest.mark.parametrize("path", MIXES, ids=os.path.basename)
@@ -78,13 +78,13 @@ def test_the_seed_chooses_bytes_and_the_rendered_prompt_has_the_scheduled_length
     mix = _mix(path)
     tok = FixedWidthTokenizer(32000)
     for turn in loadgen.build_schedule(mix)[0][:6]:
-        s1, p1 = loadgen.fill(turn, 1, OVERHEAD)
-        s2, p2 = loadgen.fill(turn, 2, OVERHEAD)
+        s1, p1 = loadgen.fill(turn, 1, OVERHEAD, tok.width)
+        s2, p2 = loadgen.fill(turn, 2, OVERHEAD, tok.width)
         assert p1 != p2 and len(p1) == len(p2) and len(s1) == len(s2)
         text = f"[INST] {s1}\n\n{p1} [/INST]" if s1 else f"[INST] {p1} [/INST]"
         assert len(tok.encode(text)) == turn.prompt_tokens
         # one agent's system prompt is the same in every turn of a run
-        again, _ = loadgen.fill(turn, 1, OVERHEAD)
+        again, _ = loadgen.fill(turn, 1, OVERHEAD, tok.width)
         assert again == s1
 
 
@@ -95,8 +95,26 @@ def test_tokenizer_is_total_and_reads_its_own_text_back():
     assert len(text) == 4 * len(ids) and tok.encode(text) == ids
     assert tok.eos_id is None and tok.bos_id is None
     assert all(0 <= i < 32000 for i in tok.encode("[INST] zzzz ffff [/INST]"))
+    assert tok.width == FixedWidthTokenizer(65536).width == 4
     with pytest.raises(ValueError):
-        FixedWidthTokenizer(70000)
+        FixedWidthTokenizer(16 ** 5 + 1)
+
+
+def test_the_tokenizer_s_width_follows_the_vocabulary():
+    """Three of the four architectures drawn for the next configuration have
+    over 65,536 rows: ids then take 5 hex digits, and the load generator fills
+    prompts by the width of the tokenizer in use."""
+    tok = FixedWidthTokenizer(153600)
+    assert tok.width == 5 and tok.vocab_size == 153600
+    ids = [0, 255, 65535, 65536, 153599]
+    text = tok.decode(ids)
+    assert len(text) == 5 * len(ids) and tok.encode(text) == ids
+    assert all(0 <= i < 153600 for i in tok.encode("[INST] zzzzz fffff [/INST]"))
+    with open(os.path.join(REPO, "benchmark", "traffic", "longprompt-m7.json")) as fh:
+        mix = json.load(fh)
+    for turn in loadgen.build_schedule(mix)[0][:4]:
+        _, prompt = loadgen.fill(turn, 1, OVERHEAD, tok.width)
+        assert len(tok.encode(f"[INST] {prompt} [/INST]")) == turn.prompt_tokens
 
 
 def test_a_mix_with_a_missing_parameter_or_an_unknown_kind_is_refused():
@@ -133,7 +151,7 @@ def test_a_stream_that_never_ends_is_a_failed_request_not_a_crash():
     with open(os.path.join(tiny, "tiny-arrivals.json")) as fh:
         mix = {**json.load(fh), "rate_rps": 50.0}
     assert "deadline_s" not in mix  # no committed mix states a deadline: none is sent
-    gen = loadgen.LoadGenerator(mix, 1, OVERHEAD, stream, "m")
+    gen = loadgen.LoadGenerator(mix, 1, OVERHEAD, stream, "m", 4)
     gen.start()
     time.sleep(0.4)
     gen.stop_and_drain(timeout_s=0.5)
@@ -145,5 +163,5 @@ def test_a_stream_that_never_ends_is_a_failed_request_not_a_crash():
     assert counts["failed"] == 1 and counts["attempted"] >= 5
     assert counts["ttft"] == counts["attempted"] - 1
     assert set(seen) == {None}
-    timed = loadgen.LoadGenerator({**mix, "deadline_s": 30}, 1, OVERHEAD, stream, "m")
+    timed = loadgen.LoadGenerator({**mix, "deadline_s": 30}, 1, OVERHEAD, stream, "m", 4)
     assert timed.deadline_s == 30.0

@@ -18,6 +18,7 @@ import make_trace_extract  # noqa: E402
 from benchmark.harness import xplane  # noqa: E402
 
 LAYERS = 6
+KERNEL = "paged_decode_attention"  # what archs/mistral.py names as its decode step's marker
 
 
 @pytest.fixture(scope="module")
@@ -35,7 +36,7 @@ def test_programs_are_told_apart_by_name(planes):
 
 
 def test_steps_of_a_decode_program_come_from_its_attention_kernel_events(planes):
-    runs = xplane.decode_steps(planes, LAYERS)
+    runs = xplane.decode_steps(planes, KERNEL, LAYERS)
     assert [n for _, n in runs] == [2, 16]  # an admission tick, then a full one
     for seconds, n in runs:
         assert 1e3 * seconds / n == pytest.approx(14.85, abs=0.05)
@@ -104,12 +105,12 @@ def test_a_recorded_xplane_file_loads_with_jax_alone(tmp_path):
     # a CPU trace has no device plane: nothing is read as device time
     assert xplane.device_planes(loaded) == {}
     assert xplane.busy_and_window_seconds(loaded) == (0.0, 0.0)
-    assert xplane.decode_steps(loaded, LAYERS) == [] and xplane.modules(loaded) == []
+    assert xplane.decode_steps(loaded, KERNEL, LAYERS) == [] and xplane.modules(loaded) == []
     with pytest.raises(FileNotFoundError):
         xplane.find(str(tmp_path / "nothing"))
 
 
 def test_extract_and_from_extract_round_trip(planes):
     again = xplane.from_extract(make_trace_extract.extract(planes, before_s=0.08, after_s=0.32))
-    assert xplane.decode_steps(again, LAYERS)[-1][1] == 16
+    assert xplane.decode_steps(again, KERNEL, LAYERS)[-1][1] == 16
     assert xplane.prefill_seconds(again) == pytest.approx(0.029739425)
