@@ -36,6 +36,37 @@ class ModelConfig:
     num_experts_per_tok: int = 2
     moe_intermediate_size: Optional[int] = None
     norm_topk_prob: bool = True
+    # ``num_experts`` is the ROUTER's width. A chip that holds a share of a
+    # layer's experts (wide expert parallelism: the layer is divided over
+    # several chips) holds ``experts_held`` of them from ``first_expert``
+    # on; 0 = all. The router still ranks all ``num_experts``; the layer
+    # adds what ITS experts give for the tokens routed to them.
+    experts_held: int = 0
+    first_expert: int = 0
+    # router scoring: "softmax" (Mixtral/Qwen3-MoE) or "sigmoid" (the
+    # DeepSeek-V3 family: independent scores, the top-k renormalized, then
+    # scaled by routed_scaling_factor)
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # experts every token runs beside the routed ones (width
+    # n_shared_experts * moe_intermediate_size)
+    n_shared_experts: int = 0
+    # the first first_k_dense layers keep a dense FFN (width
+    # intermediate_size); the layers after them are expert layers
+    first_k_dense: int = 0
+    # Multi-head latent attention (kv_lora_rank > 0): queries through a
+    # q_lora_rank bottleneck, keys/values through one kv_lora_rank latent a
+    # row plus qk_rope_head_dim rotary values shared by all heads — the
+    # cache holds those, not per-head K/V. A head is qk_nope_head_dim +
+    # qk_rope_head_dim wide for scores and v_head_dim for values.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # a post-norm on each sub-layer's output besides the pre-norm:
+    # x + norm(attn(norm(x))), x + norm(mlp(norm(x)))
+    sandwich_norm: bool = False
     # serving replicas per managed model (aios_tpu/serving/): N independent
     # engine+batcher replicas behind one cache-aware router. 1 = the
     # single-engine layout; AIOS_TPU_REPLICAS overrides at load time.
@@ -123,9 +154,64 @@ class ModelConfig:
     # paged pool. AIOS_TPU_SEQ_PREFILL_MIN overrides.
     seq_prefill_min: int = 0
 
+    def __post_init__(self) -> None:
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"{self.name}: unknown moe_scoring {self.moe_scoring!r}")
+        if self.experts_held and not (
+            0 <= self.first_expert
+            and self.first_expert + self.experts_held <= self.num_experts
+        ):
+            raise ValueError(
+                f"{self.name}: experts [{self.first_expert}, "
+                f"{self.first_expert + self.experts_held}) are not among the "
+                f"router's {self.num_experts}"
+            )
+        if (self.first_k_dense or self.sandwich_norm) and not self.mla:
+            raise ValueError(
+                f"{self.name}: leading dense layers and sandwich norms are "
+                "built for the latent-attention block only "
+                "(engine/latent.py); the grouped-query block has neither"
+            )
+        if self.mla and not (
+            self.q_lora_rank and self.qk_nope_head_dim
+            and self.qk_rope_head_dim and self.v_head_dim
+        ):
+            raise ValueError(
+                f"{self.name}: latent attention needs q_lora_rank and the "
+                "three head dims beside kv_lora_rank"
+            )
+
     @property
     def moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights live here (the leading axis of we_*)."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """True when this chip holds a proper share of each layer's experts."""
+        return self.moe and self.held_experts < self.num_experts
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def kv_row_dims(self) -> tuple:
+        """Stored widths of one cache row in the two pool arrays
+        (engine/paged.py header): per-head K and V merged for grouped-query
+        attention; for latent attention the compressed latent, and the
+        rotary part padded to a whole 128-lane tile."""
+        if self.mla:
+            return (self.kv_lora_rank, -(-self.qk_rope_head_dim // 128) * 128)
+        return (self.kv_dim, self.kv_dim)
 
     @property
     def expert_dim(self) -> int:

@@ -254,6 +254,23 @@ def _env_int(name: str) -> Optional[int]:
         return None
 
 
+def refuse_for_latent_pool(cfg, **asked) -> None:
+    """Raise, naming the model and the feature, for a serving feature that
+    cannot take a latent (MLA) page pool yet. No quiet fallback to another
+    path: ``LoadModel`` fails with this error. ``asked`` maps a feature's
+    description to whether the load asks for it."""
+    if not cfg.mla:
+        return
+    wanted = [what for what, on in asked.items() if on]
+    if wanted:
+        raise ValueError(
+            f"{cfg.name}: latent attention (MLA) serves from the bf16 "
+            f"paged latent pool only; this load asks for "
+            f"{' and '.join(w.replace('_', ' ') for w in wanted)}, which "
+            "cannot take a latent pool yet"
+        )
+
+
 # Device-resident decode state, threaded through the jitted cores as one
 # donated pytree: {k, v, lengths, last_tokens, temps, top_ps, key}
 DecodeState = Dict[str, jnp.ndarray]
@@ -380,6 +397,16 @@ class TPUEngine:
         # int8 KV cache: half the cache footprint/traffic; scales ride along
         # in the decode state and rows quantize on write inside the graph
         self.quant_cache = cache_dtype == jnp.int8
+        refuse_for_latent_pool(
+            cfg,
+            the_dense_slot_cache=paged_pool_rows is None,
+            an_int8_KV_pool=self.quant_cache,
+            a_sharding_plan_and_its_replicated_or_sharded_pool_twins=(
+                shardings is not None
+            ),
+            a_context_sharded_cache=bool(seq_sharded_cache),
+            a_draft_model=draft is not None,
+        )
         # Pallas kernels are per-device programs; under a sharding plan the
         # global-array paths must stay pure XLA (GSPMD partitions those) —
         # EXCEPT decode attention, which is head/slot-local and runs the
@@ -396,9 +423,18 @@ class TPUEngine:
         # under EP the expert axis is sharded and the dense path's psum is
         # the right collective. Decode/verify dispatches only — prefill
         # token counts saturate the experts.
-        self._moe_impl: Optional[str] = None
+        # AIOS_TPU_MOE_IMPL ("dense" | "gather" | "dispatch") is the
+        # operator's escape hatch: resolved HERE, once, with the engine's
+        # other load-time overrides, it beats every static choice below and
+        # goes to every graph (prefill, chunk, decode, verify); model.ffn
+        # reads no environment.
+        self._moe_override: Optional[str] = (
+            os.environ.get("AIOS_TPU_MOE_IMPL") or None
+        )
+        self._moe_impl: Optional[str] = self._moe_override
         if (
-            cfg.moe
+            self._moe_override is None
+            and cfg.moe
             and shardings is None
             and num_slots * cfg.num_experts_per_tok < cfg.num_experts
             and os.environ.get("AIOS_TPU_MOE_GATHER", "").lower()
@@ -597,12 +633,14 @@ class TPUEngine:
                 num_pages, page_size, num_slots, max_blocks, replicas=R
             )
             # THE stored layout (paged.py's header): a row's kv heads
-            # merged on the last axis
-            shape = (
-                cfg.num_layers, num_pages, page_size,
-                cfg.num_kv_heads * cfg.head_dim,
+            # merged on the last axis; for latent attention the latents
+            # and the padded rotary parts (ModelConfig.kv_row_dims)
+            k, v = (
+                jnp.zeros(
+                    (cfg.num_layers, num_pages, page_size, width), cache_dtype
+                )
+                for width in cfg.kv_row_dims
             )
-            k, v = jnp.zeros(shape, cache_dtype), jnp.zeros(shape, cache_dtype)
             if R > 1:
                 # dp-replicated pool: page ops must run per device under
                 # shard_map (table ids are replica-local; a GSPMD gather
@@ -697,6 +735,9 @@ class TPUEngine:
         ), 1)
         self.kv_compress_armed = False
         self._sink_rows = 0
+        refuse_for_latent_pool(
+            cfg, window_and_sink_KV_compression=self.kv_compress_after > 0
+        )
         if self.kv_compress_after > 0:
             if not self.paged or self.pool_replicas > 1:
                 log.warning(
@@ -741,6 +782,9 @@ class TPUEngine:
         self.seq_prefill_min = knob(
             seq_prefill_min, "AIOS_TPU_SEQ_PREFILL_MIN",
             getattr(cfg, "seq_prefill_min", 0),
+        )
+        refuse_for_latent_pool(
+            cfg, sequence_sharded_prefill=self.seq_prefill_min > 0
         )
         # Device-resident multi-tick decode megagraph (_mega_impl): up to
         # mega_ticks decode ticks per dispatch in one lax.while_loop with
@@ -815,6 +859,15 @@ class TPUEngine:
             "history": spec.init_history(num_slots, self.max_context),
             "key": jax.random.PRNGKey(seed),
         }
+        # a model that holds a share of its experts counts its router's
+        # picks on the device (moe.pick_stats): every graph adds to this,
+        # and a decode dispatch hands the sum back with its tokens
+        self.counts_picks = cfg.mla and cfg.expert_share
+        self.moe_picks_total = 0
+        self.moe_picks_local = 0
+        self.moe_expert_rows = 0
+        if self.counts_picks:
+            self.state["moe_stats"] = jnp.zeros((3,), jnp.int32)
         if self.quant_cache:
             if self.paged:
                 # per-(page, row, kv-head) scales alongside the int8 pool
@@ -940,6 +993,9 @@ class TPUEngine:
         # device_put can lose to recompute).
         if prefix_host_bytes is None:
             prefix_host_bytes = getattr(cfg, "prefix_host_bytes", 0)
+        refuse_for_latent_pool(
+            cfg, the_host_spill_tier=int(prefix_host_bytes or 0) > 0
+        )
         self.host_store: Optional[paged.HostPageStore] = None
         self.host_restore_min_pages = max(int(host_restore_min_pages or 1), 1)
         self.host_restore_seconds = 0.0
@@ -1292,7 +1348,7 @@ class TPUEngine:
             if self.quant_cache:
                 logits, k, v, (k_s, v_s) = out
             else:
-                logits, k, v = out
+                logits, k, v, *picks = out
         elif self.quant_cache:
             logits, k, v, (k_s, v_s) = model.decode_step(
                 params,
@@ -1321,6 +1377,7 @@ class TPUEngine:
                 moe_impl=self._moe_impl,
                 qmm=self._qmm_impl,
             )
+        moe_stats = st.get("moe_stats")
         with jax.named_scope("sampling"):
             if mask is not None:
                 logits = logits + mask
@@ -1356,6 +1413,8 @@ class TPUEngine:
         if self.quant_cache:
             st["k_s"] = k_s
             st["v_s"] = v_s
+        if self.counts_picks:
+            st["moe_stats"] = moe_stats + picks[0]
         return st, next_tokens
 
     def _step_impl(self, params, state: DecodeState, n_steps: int, tables=None,
@@ -1374,7 +1433,17 @@ class TPUEngine:
         keys = jax.random.split(state["key"], n_steps + 1)
         state = dict(state, key=keys[0])
         state, tokens = jax.lax.scan(one, state, keys[1:])
-        return state, tokens  # tokens [n_steps, S]
+        if self.counts_picks:
+            # the counters ride back with the tokens, in the one readback
+            # there is: three rows below them, counter i in every column
+            # of row n_steps + i (readers slice [:n_steps]); the device's
+            # sums start again from zero
+            rows = jnp.broadcast_to(
+                state["moe_stats"][:, None], (3, tokens.shape[1])
+            )
+            tokens = jnp.concatenate([tokens, rows], axis=0)
+            state = dict(state, moe_stats=jnp.zeros((3,), jnp.int32))
+        return state, tokens  # tokens [n_steps (+ 3), S]
 
     def _unified_impl(self, params, state: DecodeState, n, max_steps: int,
                       tables=None):
@@ -1479,7 +1548,7 @@ class TPUEngine:
         this engine runs — the shared dispatch body of ``_spec_impl``,
         ``_jump_impl`` and ``_draft_spec_impl``. ``feed`` is [S, W]
         ([last_token, draft/forced tokens...]); returns
-        (logits [S, W, V], k, v, scales-or-None)."""
+        (logits [S, W, V], k, v, scales-or-None, expert counters-or-None)."""
         scales = (st["k_s"], st["v_s"]) if self.quant_cache else None
         moe_impl = self._verify_moe_impl(feed.shape[1])
         if self.paged:
@@ -1499,9 +1568,9 @@ class TPUEngine:
             )
         if self.quant_cache:
             logits, k, v, (k_s, v_s) = out
-            return logits, k, v, (k_s, v_s)
-        logits, k, v = out
-        return logits, k, v, None
+            return logits, k, v, (k_s, v_s), None
+        logits, k, v, *picks = out
+        return logits, k, v, None, (picks[0] if picks else None)
 
     def _spec_impl(
         self, params, state: DecodeState, n_rounds: int, draft_len: int,
@@ -1532,7 +1601,7 @@ class TPUEngine:
             feed = jnp.concatenate(
                 [st["last_tokens"][:, None], drafts], axis=1
             )  # [S, K+1]
-            logits, k, v, new_scales = self._verify_feed(
+            logits, k, v, new_scales, _ = self._verify_feed(
                 params, st, feed, tables
             )
             if self.quant_cache:
@@ -1710,7 +1779,7 @@ class TPUEngine:
             feed = jnp.concatenate(
                 [st["last_tokens"][:, None], drafts], axis=1
             )  # [S, K+1]
-            logits, k, v, new_scales = self._verify_feed(
+            logits, k, v, new_scales, _ = self._verify_feed(
                 params, st, feed, tables
             )
             g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, K+1]
@@ -1781,7 +1850,7 @@ class TPUEngine:
         slots = jnp.arange(S)
         st = state
         feed = jnp.concatenate([st["last_tokens"][:, None], forced], axis=1)
-        _logits, k, v, new_scales = self._verify_feed(params, st, feed,
+        _logits, k, v, new_scales, picks = self._verify_feed(params, st, feed,
                                                       tables)
         if self.quant_cache:
             k_s, v_s = new_scales
@@ -1818,6 +1887,8 @@ class TPUEngine:
         if self.quant_cache:
             new["k_s"] = k_s
             new["v_s"] = v_s
+        if self.counts_picks:
+            new["moe_stats"] = st["moe_stats"] + picks
         return new
 
     def _prefill_impl_paged(
@@ -1832,9 +1903,10 @@ class TPUEngine:
         ring/Ulysses adapter so a huge prompt's forward spreads over the
         mesh's sp axis while the scatter/sample/activate tail stays
         byte-for-byte the normal admission path."""
-        logits, ks, vs = model.prefill(
+        logits, ks, vs, *picks = model.prefill(
             params, self.cfg, tokens, kernels=self._kernels,
             qmm=self._qmm_gspmd, attn_fn=attn_fn,
+            moe_impl=self._moe_override,
         )
         # ks/vs [L, 1, T, KH, D] -> the pool's rows [L, T, KH*D], written
         # from row 0 of the slot's first page, by whole pages
@@ -1878,6 +1950,8 @@ class TPUEngine:
         }
         if self.quant_cache:
             out["k_s"], out["v_s"] = scales
+        if self.counts_picks:
+            out["moe_stats"] = state["moe_stats"] + picks[0]
         return out, first
 
     def _prefill_impl(
@@ -1885,7 +1959,7 @@ class TPUEngine:
     ):
         logits, ks, vs = model.prefill(
             params, self.cfg, tokens, kernels=self._kernels,
-            qmm=self._qmm_gspmd,
+            qmm=self._qmm_gspmd, moe_impl=self._moe_override,
         )
         # ks/vs [L, B=1, T, KH, D] -> cache layout [L, slot, T, KH, D]
         start = (0, slot, 0, 0, 0)
@@ -1943,16 +2017,20 @@ class TPUEngine:
                 params, self.cfg, tokens, start, state["k"], state["v"],
                 table_row, cache_scales=scales, qmm=self._qmm_gspmd,
                 win_start=win_start, sink_rows=self._sink_rows,
+                moe_impl=self._moe_override,
             )
             if self.quant_cache:
                 logits, upd["k"], upd["v"], (upd["k_s"], upd["v_s"]) = out
             else:
-                logits, upd["k"], upd["v"] = out
+                logits, upd["k"], upd["v"], *picks = out
+                if self.counts_picks:
+                    upd["moe_stats"] = state["moe_stats"] + picks[0]
         else:
             scales = (state["k_s"], state["v_s"]) if self.quant_cache else None
             out = model.prefill_chunk(
                 params, self.cfg, tokens, slot, start, state["k"], state["v"],
                 cache_scales=scales, qmm=self._qmm_gspmd,
+                moe_impl=self._moe_override,
             )
             if self.quant_cache:
                 logits, upd["k"], upd["v"], (upd["k_s"], upd["v_s"]) = out
@@ -3046,6 +3124,11 @@ class TPUEngine:
         not token ids. Same return shape and lock discipline."""
         if self.prefix_index is None or not hashes:
             return []
+        if self.cfg.mla:
+            raise paged.LatentEntryUnsupported(
+                f"{self.cfg.name}: latent (MLA) pages have no KVX entry "
+                "kind yet"
+            )
         with self._lock:
             snap = self.prefix_index.snapshot()
             chain = []
@@ -3340,7 +3423,10 @@ class TPUEngine:
                 )
                 lengths = self._host_lengths.copy()
             with ph.phase("engine.readback"):
-                host_tokens = np.asarray(tokens)[:n_steps]
+                host_all = np.asarray(tokens)
+                host_tokens = host_all[:n_steps]
+            if not self.unified_step:
+                self._take_picks(host_all, n_steps)
             # the readback above already blocked until the tokens
             # materialized, so the sample is the graph-call -> ready
             # delta at zero extra synchronization
@@ -3516,9 +3602,20 @@ class TPUEngine:
         # readback OUTSIDE the lock (like _step_dispatch): concurrent
         # engine calls — force_pending_token, release, overlap probes that
         # do take the lock — need not wait for this dispatch to finish
-        host_tokens = np.asarray(tokens)
+        host_all = np.asarray(tokens)
+        self._take_picks(host_all, 1)
         self._devprof_sample(dtok)
-        return host_tokens
+        return host_all[:1]
+
+    def _take_picks(self, host_all: np.ndarray, n_steps: int) -> None:
+        """Add the expert counters a ``_step_impl`` graph appended below
+        its ``n_steps`` token rows (a model that counts none appends
+        none)."""
+        if self.counts_picks:
+            total, local, rows = host_all[n_steps:n_steps + 3, 0]
+            self.moe_picks_total += int(total)
+            self.moe_picks_local += int(local)
+            self.moe_expert_rows += int(rows)
 
     def jump_step(self, forced: np.ndarray, counts: np.ndarray) -> None:
         """Append grammar-FORCED token runs in ONE multi-token dispatch
@@ -3852,6 +3949,15 @@ class TPUEngine:
         if self.allocator is not None:
             out["kv_pages_in_use"] = self.allocator.pages_in_use()
             out["kv_pages_free"] = self.allocator.free_pages
+            # one cache row of one layer, as STORED (both pool arrays)
+            out["kv_row_bytes"] = sum(self.cfg.kv_row_dims) * (
+                self.state["k"].dtype.itemsize if self.state else 0
+            )
+        if self.counts_picks:
+            # summed on the device, read back with the decode tokens
+            out["moe_picks_total"] = self.moe_picks_total
+            out["moe_picks_local"] = self.moe_picks_local
+            out["moe_expert_rows"] = self.moe_expert_rows
         if self.kv_compress_armed:
             out["kv_compress_slots"] = self.kv_compress_slots
             out["kv_compress_pages_pruned"] = self.kv_pages_pruned

@@ -1,0 +1,463 @@
+"""Multi-head latent attention (MLA) on the paged serving path.
+
+The block of the DeepSeek-V3 family (openPangu-Ultra-MoE among it), as
+``engine/model.py`` dispatches to it for ``cfg.mla`` models. One row of the
+residual ``x``, ``h = rms(x)``:
+
+    cq = rms(h W_dq)                      q = cq W_uq -> heads of [nope | rope]
+    [c | k_r] = h W_dkv                   c = rms(c); k_r, q_rope rotated
+    k_nope_i = c W_uk_i, v_i = c W_uv_i   per head i; k_r is shared by all heads
+    s_ij = (q_nope_i . k_nope_j + q_rope_i . k_r_j) / sqrt(nope + rope)
+    o = concat_i(softmax(s_i) v) W_o
+
+The cache holds ``[c | k_r]`` a row a layer, not per-head keys and values
+(engine/paged.py header: two pool arrays, ``[.., kv_lora_rank]`` and the
+rotary part padded to 128 lanes). Serving leaves: ``w_dqkv`` is
+[W_dq | W_dkv] along columns, ``w_uk`` / ``w_uv`` are the two column groups
+of the published W_ukv, each ``[kv_lora_rank, heads * dim]``.
+
+Two forms of the same attention:
+
+* EXPANDED (prefill of new rows, over cached latent rows too): keys and
+  values are made from the latents a block of rows at a time and attended
+  with an online softmax, so neither the scores nor the expanded cache of a
+  long prefix ever exist whole; the loop runs only over the blocks the
+  newest row can see.
+* ABSORBED (decode, one row a slot): ``q_lat_i = q_nope_i W_uk_i^T`` scores
+  against ``c`` directly and ``o_i = (sum_j p_ij c_j) W_uv_i``, so a step
+  reads the latent pages once for all heads
+  (ops/paged_mla_attention.py) and expands nothing.
+
+With ``cfg.sandwich_norm`` each sub-layer's output gets its own norm before
+the residual add. The FFN is ``model.ffn``: a leading dense layer and an
+expert layer differ only in their trees, and the stack is scanned segment by
+segment (``model.layer_segments``) with the pools carried through.
+
+Entry points mirror ``model``'s and return the same tuples, with the pools
+in the places of K and V; a model that holds a share of its experts
+(``cfg.expert_share``) returns ``moe.pick_stats`` summed over its layers as
+one more value.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from .. import ops
+from . import model
+from .config import ModelConfig
+
+NEG_INF = -1e30
+Q_TILE = 512  # query rows attended at a time, and the kv block beside them
+
+
+def sm_scale(cfg: ModelConfig) -> float:
+    return float(cfg.qk_head_dim) ** -0.5
+
+
+def _einsum32(spec: str, a, b):
+    """einsum of bfloat16 (or int8) values accumulated in float32. The
+    operands are widened first: the same values, the product XLA's TPU
+    backend runs in one bfloat16 pass either way, and the CPU backend's dot
+    thunk refuses bf16 x bf16 -> f32 at some shapes."""
+    return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32))
+
+
+def _project(h, lp, cfg: ModelConfig, positions, qmm=None):
+    """Normed rows h [B, T, E] -> (q_nope [B,T,H,nope], q_rope [B,T,H,rope]
+    rotated, c [B,T,kv_lora_rank] normed, k_r [B,T,rope] rotated)."""
+    B, T, _ = h.shape
+    ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    eps = cfg.rms_norm_eps
+    cos, sin = model.rope_tables(positions, dr, cfg.rope_theta)
+    with jax.named_scope("mla_q"):
+        down = model.matmul(h, lp["w_dqkv"], qmm)
+        cq = model.rms_norm(down[..., :ql], lp["q_a_norm"], eps)
+        q = model.matmul(cq, lp["w_uq"], qmm).reshape(
+            B, T, cfg.num_heads, dn + dr
+        )
+        q_nope = q[..., :dn]
+        q_rope = model.apply_rope(q[..., dn:], cos, sin)
+    c = model.rms_norm(down[..., ql:ql + kl], lp["kv_a_norm"], eps)
+    k_r = model.apply_rope(down[..., ql + kl:][:, :, None, :], cos, sin)
+    return q_nope, q_rope, c, k_r[:, :, 0]
+
+
+def _pad_rope(x, cfg: ModelConfig):
+    """Rotary parts [..., rope] as the pool stores them: one whole lane
+    tile, zeros above the rotary dims."""
+    return jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, cfg.kv_row_dims[1] - x.shape[-1])]
+    )
+
+
+def _per_head(w, heads: int):
+    """A column-grouped leaf [K, heads * d] as (values [K, heads, d], scales
+    [heads, d] or None)."""
+    if isinstance(w, dict):
+        q = w["q"]
+        return q.reshape(q.shape[0], heads, -1), w["s"].reshape(heads, -1)
+    return w.reshape(w.shape[0], heads, -1), None
+
+
+def _absorb_q(q_nope, lp, cfg: ModelConfig):
+    """q_lat_i = q_nope_i W_uk_i^T: [..., H, nope] -> [..., H, kv_lora_rank].
+    An int8 leaf's per-column scales lie on the contracted axis here, so
+    they go onto the query first."""
+    w, s = _per_head(lp["w_uk"], cfg.num_heads)
+    q = q_nope.astype(jnp.float32)
+    if s is not None:
+        q = q * s
+    return _einsum32("...hd,chd->...hc", q, w).astype(q_nope.dtype)
+
+
+def _unabsorb_o(o_lat, lp, cfg: ModelConfig):
+    """o_i = o_lat_i W_uv_i: [..., H, kv_lora_rank] -> [..., H * v_head_dim]."""
+    w, s = _per_head(lp["w_uv"], cfg.num_heads)
+    o = _einsum32("...hc,chd->...hd", o_lat, w)
+    if s is not None:
+        o = o * s
+    return o.astype(o_lat.dtype).reshape(*o_lat.shape[:-2], -1)
+
+
+def _expand(c_rows, lp, cfg: ModelConfig, qmm=None):
+    """Latent rows [S, kv_lora_rank] -> (k_nope [S, H, nope], v [S, H, v])."""
+    S, H = c_rows.shape[0], cfg.num_heads
+    return (
+        model.matmul(c_rows, lp["w_uk"], qmm).reshape(S, H, -1),
+        model.matmul(c_rows, lp["w_uv"], qmm).reshape(S, H, -1),
+    )
+
+
+def _attend_expanded(q_nope, q_rope, q_pos, kv_block, n_blocks, blk: int,
+                     scale: float, v_dim: int):
+    """Expanded attention of ONE sequence's query rows (q_nope [T, H, nope],
+    q_rope [T, H, rope], at absolute positions q_pos [T]) over kv blocks
+    0 .. n_blocks-1 of ``blk`` rows: an online softmax in float32, so the
+    [T, S] scores never exist whole. ``kv_block(j)`` gives block j's
+    (k_nope [blk, H, nope], k_r [blk, rope], v [blk, H, v]); ``n_blocks``
+    may be traced (the loop then runs only as far as the newest row sees).
+    Row i sees column j iff j <= q_pos[i]; block 0 always holds column 0, so
+    no row's denominator is zero. Returns [T, H, v]."""
+    T, H, _ = q_nope.shape
+    f32 = jnp.float32
+
+    def fold(j, carry):
+        m, l, acc = carry
+        k_nope, k_r, v = kv_block(j)
+        s = (_einsum32("thd,shd->hts", q_nope, k_nope)
+             + _einsum32("thr,sr->hts", q_rope, k_r)) * scale
+        cols = j * blk + jnp.arange(blk)
+        s = jnp.where((cols[None, :] <= q_pos[:, None])[None], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + _einsum32(
+            "hts,shd->htd", p.astype(v.dtype), v
+        )
+        return m_new, l, acc
+
+    init = (
+        jnp.full((H, T), NEG_INF, f32),
+        jnp.zeros((H, T), f32),
+        jnp.zeros((H, T, v_dim), f32),
+    )
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, fold, init)
+    return (acc / l[..., None]).transpose(1, 0, 2).astype(q_nope.dtype)
+
+
+def _attend_own_rows(q_nope, q_rope, c, k_r, lp, cfg: ModelConfig, qmm=None):
+    """Causal expanded attention of one sequence over its own T rows (the
+    whole-prompt prefill): keys and values are expanded once, queries go a
+    Q_TILE at a time and each tile stops at its own diagonal block."""
+    T = q_nope.shape[0]
+    k_nope, v = _expand(c, lp, cfg, qmm)
+    tiled = T > Q_TILE and T % Q_TILE == 0
+    blk = Q_TILE if tiled else T
+
+    def kv_block(j):
+        at = j * blk
+        return (
+            jax.lax.dynamic_slice_in_dim(k_nope, at, blk),
+            jax.lax.dynamic_slice_in_dim(k_r, at, blk),
+            jax.lax.dynamic_slice_in_dim(v, at, blk),
+        )
+
+    def tile(i, qn, qr):
+        return _attend_expanded(
+            qn, qr, i * blk + jnp.arange(blk), kv_block, i + 1, blk,
+            sm_scale(cfg), cfg.v_head_dim,
+        )
+
+    if not tiled:
+        return tile(0, q_nope, q_rope)
+    n = T // blk
+    out = jax.lax.map(
+        lambda a: tile(*a),
+        (jnp.arange(n), q_nope.reshape(n, blk, *q_nope.shape[1:]),
+         q_rope.reshape(n, blk, *q_rope.shape[1:])),
+    )
+    return out.reshape(T, *out.shape[2:])
+
+
+def _finish_block(x, attn_flat, lp, cfg: ModelConfig, moe_impl, qmm,
+                  allow_dispatch: bool = False):
+    """Output projection and FFN of one block, with the sandwich norms;
+    returns (x', moe_aux, stats-or-None)."""
+    eps = cfg.rms_norm_eps
+    with jax.named_scope("mla_out"):
+        a = model.matmul(attn_flat, lp["wo"], qmm, "row")
+        if cfg.sandwich_norm:
+            a = model.rms_norm(a, lp["post_attn_norm"], eps)
+        x = x + a
+    h = model.rms_norm(x, lp["ffn_norm"], eps)
+    m, aux, stats = model.ffn(h, lp, cfg, allow_dispatch, moe_impl, qmm)
+    if cfg.sandwich_norm:
+        m = model.rms_norm(m, lp["post_ffn_norm"], eps)
+    return x + m, aux, stats
+
+
+def _zero_stats(cfg: ModelConfig):
+    return (jnp.zeros((3,), jnp.int32),) if cfg.expert_share else ()
+
+
+def _add_stats(stats, new):
+    """The carried counters plus one layer's (a dense layer has none)."""
+    if not stats or new is None:
+        return stats
+    return (stats[0] + new,)
+
+
+def forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None,
+                    with_aux: bool = False, qmm=None,
+                    moe_impl: Optional[str] = None):
+    """``model._forward_with_kv`` for a latent-attention model: (logits
+    [B, T, V], latents [L, B, T, 1, kv_lora_rank], padded rotary parts
+    [L, B, T, 1, 128][, mean moe aux][, stats])."""
+    if attn_fn is not None:
+        raise ValueError(
+            f"{cfg.name}: latent attention has no sequence-sharded prefill"
+        )
+    B, T = tokens.shape
+    x = params["embed"][tokens]
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+
+    def block(carry, layer):
+        x, *stats = carry
+        lp, _ = layer
+        h = model.rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q_nope, q_rope, c, k_r = _project(h, lp, cfg, positions, qmm)
+        with jax.named_scope("attention"):
+            attn = jax.vmap(
+                lambda qn, qr, c1, kr1: _attend_own_rows(
+                    qn, qr, c1, kr1, lp, cfg, qmm
+                )
+            )(q_nope, q_rope, c, k_r)
+        x, aux, new = _finish_block(
+            x, attn.reshape(B, T, -1), lp, cfg, moe_impl, qmm, with_aux
+        )
+        rows = (c[:, :, None, :], _pad_rope(k_r, cfg)[:, :, None, :], aux)
+        return (x, *_add_stats(stats, new)), rows
+
+    (x, *stats), (cs, rs, auxs) = model.scan_segments(
+        block, (x, *_zero_stats(cfg)), model.layer_segments(params)
+    )
+    logits = model._final_logits(x, params, cfg, qmm)
+    out = (logits, cs, rs)
+    if with_aux:
+        out += (jnp.mean(auxs),)
+    return out + tuple(stats)
+
+
+def _write_chunk(pool, l, rows, pages, off):
+    """A chunk's rows [T, W] into layer ``l`` of the pool: whole pages
+    through ``ops.write_rows``, and a chunk of at most ONE page as one slice
+    update. A one-page chunk (T == P) must not go the scatter's way: with a
+    single page index the TPU compiler relays the whole pool out and back
+    around it (two pool-sized copies and 3.5 GB of temporaries in the
+    compiled final-128 graph; chipless compile, PR 27)."""
+    P = pool.shape[2]
+    if rows.shape[0] > P:
+        return ops.write_rows(pool, l, rows, pages, off)
+    return jax.lax.dynamic_update_slice(
+        pool, rows.astype(pool.dtype)[None, None], (l, pages[0], off, 0)
+    )
+
+
+def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, c_pool,
+                        r_pool, table_row, qmm=None,
+                        moe_impl: Optional[str] = None):
+    """``model.prefill_chunk_paged`` over the latent pool: the chunk's
+    latent rows are written by whole pages (or inside one), then each new
+    row attends, in the EXPANDED form, over the slot's cached latent rows
+    and the chunk's own — a turn's task behind its cached system prompt.
+    Returns (logits [1, Tc, V], c_pool', r_pool'[, stats])."""
+    B, Tc = tokens.shape
+    MB = table_row.shape[0]
+    P = c_pool.shape[2]
+    C_log = MB * P
+    dr = cfg.qk_rope_head_dim
+    x = params["embed"][tokens]
+    positions = start + jnp.arange(Tc)[None, :]
+    pages, off = model.chunk_pages(table_row, start, Tc, P)
+    blk = Q_TILE if C_log % Q_TILE == 0 else P
+    n_blocks = (start + Tc + blk - 1) // blk  # as far as the newest row sees
+
+    def block(carry, layer):
+        x, c_pool, r_pool, *stats = carry
+        lp, l = layer
+        h = model.rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q_nope, q_rope, c, k_r = _project(h, lp, cfg, positions, qmm)
+        with jax.named_scope("mla_kv_write"):
+            c_pool = _write_chunk(c_pool, l, c[0], pages, off)
+            r_pool = _write_chunk(r_pool, l, _pad_rope(k_r[0], cfg), pages, off)
+        with jax.named_scope("attention"):
+            # the slot's logical view (a copy of its pages; the decode
+            # kernel reads them in place)
+            c_all = c_pool[l, table_row].reshape(C_log, -1)
+            r_all = r_pool[l, table_row].reshape(C_log, -1)
+
+            def kv_block(j):
+                c_blk = jax.lax.dynamic_slice_in_dim(c_all, j * blk, blk)
+                r_blk = jax.lax.dynamic_slice_in_dim(r_all, j * blk, blk)
+                k_nope, v = _expand(c_blk.astype(h.dtype), lp, cfg, qmm)
+                return k_nope, r_blk[:, :dr].astype(h.dtype), v
+
+            attn = _attend_expanded(
+                q_nope[0], q_rope[0], positions[0], kv_block, n_blocks, blk,
+                sm_scale(cfg), cfg.v_head_dim,
+            )
+        x, _, new = _finish_block(
+            x, attn.reshape(B, Tc, -1), lp, cfg, moe_impl, qmm
+        )
+        return (x, c_pool, r_pool, *_add_stats(stats, new)), None
+
+    (x, c_pool, r_pool, *stats), _ = model.scan_segments(
+        block, (x, c_pool, r_pool, *_zero_stats(cfg)),
+        model.layer_segments(params),
+    )
+    logits = model._final_logits(x, params, cfg, qmm)
+    return (logits, c_pool, r_pool, *stats)
+
+
+def _write_targets(tables, rows, active, P: int):
+    """(pages, offsets) of logical rows [B] or [B, T] through the tables;
+    inactive slots write the sacrificial page 0's last row."""
+    act = active.reshape(active.shape + (1,) * (rows.ndim - 1))
+    pages = jnp.take_along_axis(
+        tables, (rows // P).reshape(rows.shape[0], -1), axis=1
+    ).reshape(rows.shape)
+    return jnp.where(act, pages, 0), jnp.where(act, rows % P, P - 1)
+
+
+def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
+                      r_pool, tables, kernels: Optional[bool] = None,
+                      active=None, moe_impl: Optional[str] = None, qmm=None):
+    """``model.decode_step_paged`` over the latent pool, in the ABSORBED
+    form: each slot's new latent row is scattered to its page, and the
+    kernel scores every head against the latent pages where they lie in the
+    carried pool. Returns (logits [B, V], c_pool', r_pool'[, stats])."""
+    B = tokens.shape[0]
+    P = c_pool.shape[2]
+    if active is None:
+        active = jnp.ones((B,), jnp.bool_)
+    rows = jnp.where(active, lengths, 0)
+    pages, offs = _write_targets(tables, rows, active, P)
+    use_kernel = model._use_kernels(kernels)
+    attend = (
+        ops.paged_mla_decode_attention if use_kernel
+        else ops.paged_mla_decode_attention_reference
+    )
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens][:, None, :]  # [B, 1, E]
+
+    def block(carry, layer):
+        x, c_pool, r_pool, *stats = carry
+        lp, l = layer
+        h = model.rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q_nope, q_rope, c, k_r = _project(h, lp, cfg, lengths[:, None], qmm)
+        with jax.named_scope("mla_q"):
+            q_lat = _absorb_q(q_nope[:, 0], lp, cfg)
+        with jax.named_scope("mla_kv_write"):
+            c_pool = c_pool.at[l, pages, offs].set(c[:, 0].astype(c_pool.dtype))
+            r_pool = r_pool.at[l, pages, offs].set(
+                _pad_rope(k_r[:, 0], cfg).astype(r_pool.dtype)
+            )
+        with jax.named_scope("attention"):
+            o_lat = attend(
+                q_lat, _pad_rope(q_rope[:, 0], cfg), c_pool, r_pool, l,
+                tables, rows, sm_scale=sm_scale(cfg),
+            )
+        with jax.named_scope("mla_out"):
+            attn = _unabsorb_o(o_lat, lp, cfg)[:, None]
+        x, _, new = _finish_block(x, attn, lp, cfg, moe_impl, qmm)
+        return (x, c_pool, r_pool, *_add_stats(stats, new)), None
+
+    (x, c_pool, r_pool, *stats), _ = model.scan_segments(
+        block, (x, c_pool, r_pool, *_zero_stats(cfg)),
+        model.layer_segments(params),
+    )
+    with jax.named_scope("final_logits"):
+        logits = model._final_logits(x[:, 0], params, cfg, qmm)
+    return (logits, c_pool, r_pool, *stats)
+
+
+def verify_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
+                      r_pool, tables, active=None,
+                      moe_impl: Optional[str] = None, qmm=None):
+    """``model.verify_step_paged`` over the latent pool (the constrained
+    decoder's jump-ahead append): the T in-flight rows of every slot are
+    scattered through the tables, and each attends, absorbed, over its
+    slot's gathered latent view up to itself, one slot at a time. Returns
+    (logits [B, T, V], c_pool', r_pool'[, stats])."""
+    B, T = tokens.shape
+    MB, P = tables.shape[1], c_pool.shape[2]
+    C = MB * P
+    if active is None:
+        active = jnp.ones((B,), jnp.bool_)
+    positions = lengths[:, None] + jnp.arange(T)[None, :]  # [B, T]
+    pages, offs = _write_targets(tables, jnp.minimum(positions, C - 1), active, P)
+    qpos = jnp.where(active[:, None], positions, 0)
+    x = params["embed"][tokens]
+    scale = sm_scale(cfg)
+
+    def slot_attend(args):
+        q_lat, q_rope, c, r, pos = args  # [T,H,Dc] [T,H,Dr] [C,Dc] [C,Dr] [T]
+        s = (
+            _einsum32("thc,sc->hts", q_lat, c)
+            + _einsum32("thr,sr->hts", q_rope, r)
+        ) * scale
+        s = jnp.where((jnp.arange(C)[None, :] <= pos[:, None])[None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(c.dtype)
+        return _einsum32("hts,sc->thc", p, c).astype(q_lat.dtype)
+
+    def block(carry, layer):
+        x, c_pool, r_pool, *stats = carry
+        lp, l = layer
+        h = model.rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q_nope, q_rope, c, k_r = _project(h, lp, cfg, positions, qmm)
+        c_pool = c_pool.at[l, pages, offs].set(c.astype(c_pool.dtype))
+        r_pool = r_pool.at[l, pages, offs].set(
+            _pad_rope(k_r, cfg).astype(r_pool.dtype)
+        )
+        o_lat = jax.lax.map(slot_attend, (
+            _absorb_q(q_nope, lp, cfg), _pad_rope(q_rope, cfg),
+            c_pool[l, tables].reshape(B, C, -1).astype(h.dtype),
+            r_pool[l, tables].reshape(B, C, -1).astype(h.dtype), qpos,
+        ))
+        x, _, new = _finish_block(
+            x, _unabsorb_o(o_lat, lp, cfg), lp, cfg, moe_impl, qmm
+        )
+        return (x, c_pool, r_pool, *_add_stats(stats, new)), None
+
+    (x, c_pool, r_pool, *stats), _ = model.scan_segments(
+        block, (x, c_pool, r_pool, *_zero_stats(cfg)),
+        model.layer_segments(params),
+    )
+    logits = model._final_logits(x, params, cfg, qmm)
+    return (logits, c_pool, r_pool, *stats)

@@ -30,6 +30,7 @@ F=intermediate, L=layers, V=vocab, D=head_dim):
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -390,7 +391,8 @@ def _project_qkv(x, lp, cfg: ModelConfig, cos, sin, qmm=None):
 
 
 def apply_block(x, lp, cfg: ModelConfig, cos, sin, mask, attention=None,
-                with_aux: bool = False, qmm=None):
+                with_aux: bool = False, qmm=None,
+                moe_impl: Optional[str] = None):
     """One transformer block on [B, T, E]; returns (x', (k, v)) — or
     (x', (k, v, moe_aux)) when ``with_aux``.
 
@@ -403,7 +405,8 @@ def apply_block(x, lp, cfg: ModelConfig, cos, sin, mask, attention=None,
     q, k, v = _project_qkv(x, lp, cfg, cos, sin, qmm)
     attn = attention(q, k, v, mask)
     x = x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row")
-    mlp_out, aux = _mlp_aux(x, lp, cfg, allow_dispatch=with_aux, qmm=qmm)
+    mlp_out, aux = _mlp_aux(x, lp, cfg, allow_dispatch=with_aux,
+                            moe_impl=moe_impl, qmm=qmm)
     x = x + mlp_out
     if with_aux:
         return x, (k, v, aux)
@@ -424,45 +427,90 @@ def _mlp_aux(
 ):
     """FFN sublayer; returns (out, moe_aux) — aux is the router
     load-balancing term (0.0 for dense models), consumed only by the
-    training forward (forward_full with_aux=True).
-
-    ``moe_impl`` — explicit MoE path ("dense" | "gather" | "dispatch"),
-    normally chosen statically by the engine (TPUEngine picks "gather" for
-    unsharded decode when slots*k < num_experts); None falls back to the
-    AIOS_TPU_MOE_IMPL env var, then auto.
-    """
+    training forward (forward_full with_aux=True)."""
     h = rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
-    if "w_router" in lp:  # mixture-of-experts FFN (engine/moe.py)
-        import os
+    return ffn(h, lp, cfg, allow_dispatch, moe_impl, qmm)[:2]
 
-        from . import moe as moe_mod
 
-        # the env var stays the operator's escape hatch: it overrides the
-        # engine's static choice (e.g. AIOS_TPU_MOE_IMPL=dense to A/B or
-        # disable the gathered decode path)
-        impl = os.environ.get("AIOS_TPU_MOE_IMPL") or moe_impl or "auto"
-        n_tok = h.shape[0] * h.shape[1]
-        if impl == "dispatch":
-            return moe_mod.moe_ffn_dispatch(h, lp, cfg)
-        if impl == "gather":
-            return moe_mod.moe_ffn_gather(h, lp, cfg)
-        if impl == "auto" and allow_dispatch and n_tok >= 1024:
+def _swiglu(h, lp, prefix: str, width: int, qmm=None):
+    """One SwiGLU FFN over normed rows from the leaves ``<prefix>gateup``
+    (the fused serving layout [gate | up], quantize_params) or
+    ``<prefix>gate`` / ``<prefix>up``, and ``<prefix>down``."""
+    if prefix + "gateup" in lp:
+        gu = matmul(h, lp[prefix + "gateup"], qmm)
+        gate_pre, u = gu[..., :width], gu[..., width:]
+    else:
+        gate_pre = matmul(h, lp[prefix + "gate"], qmm)
+        u = matmul(h, lp[prefix + "up"], qmm)
+    g = jax.nn.silu(gate_pre.astype(jnp.float32)).astype(h.dtype)
+    return matmul(g * u, lp[prefix + "down"], qmm, "row")
+
+
+def ffn(
+    h,  # [B, T, E] normed hidden states
+    lp,
+    cfg: ModelConfig,
+    allow_dispatch: bool = False,
+    moe_impl: Optional[str] = None,
+    qmm=None,
+):
+    """The FFN of one layer over NORMED rows; returns (out, moe_aux,
+    stats). Which FFN is the layer's own tree's to say (a dense layer has
+    ``w_gateup``/``w_gate``, an expert layer ``w_router``, and beside it
+    ``ws_*`` where every token also runs a shared expert), so leading dense
+    layers and expert layers go through one function.
+
+    The expert path is chosen from STATIC shapes and the config:
+    ``moe_impl`` ("dense" | "gather" | "dispatch") is the caller's explicit
+    choice — the engine resolves the operator's AIOS_TPU_MOE_IMPL override
+    and its own gathered-decode opt-in once, at load time — and otherwise
+    the exact dense-over-held path serves, except that large token counts
+    take the exact grouped path where it pays (moe.grouped_pays) and the
+    training forward (``allow_dispatch``) the capacity dispatch.
+
+    ``stats`` is moe.pick_stats (int32 [3]) for a layer that holds a SHARE
+    of its experts, else None: only such a model's graphs carry counters.
+    """
+    if "w_router" not in lp:
+        out = _swiglu(h, lp, "w_", cfg.intermediate_size, qmm)
+        return out, jnp.float32(0.0), None
+    from . import moe as moe_mod
+
+    impl = moe_impl or "auto"
+    n_tok = h.shape[0] * h.shape[1]
+    share = cfg.expert_share
+    stats = None
+    # a scope renumbers a compiled graph's instructions: only the graphs of
+    # a model that holds a share (new with the scope) get this one
+    with jax.named_scope("moe_routed") if share else contextlib.nullcontext():
+        if impl == "dispatch" or (
+            impl == "auto" and allow_dispatch and n_tok >= 1024
+        ):
             # The capacity-based dispatch path may DROP overflow picks, so
             # auto only selects it on the training forward
             # (``allow_dispatch``, i.e. with_aux) at large token counts —
             # every serving path (decode, chunked/bucketed prefill) stays
-            # on an exact path unless the env explicitly forces dispatch.
-            return moe_mod.moe_ffn_dispatch(h, lp, cfg)
-        return moe_mod.moe_ffn_dense(h, lp, cfg)
-    if "w_gateup" in lp:  # fused serving layout (quantize_params)
-        F = cfg.intermediate_size
-        gu = matmul(h, lp["w_gateup"], qmm)
-        gate_pre, up = gu[..., :F], gu[..., F:]
-    else:
-        gate_pre = matmul(h, lp["w_gate"], qmm)
-        up = matmul(h, lp["w_up"], qmm)
-    gate = jax.nn.silu(gate_pre.astype(jnp.float32)).astype(h.dtype)
-    return matmul(gate * up, lp["w_down"], qmm, "row"), jnp.float32(0.0)
+            # on an exact path unless the operator explicitly forces
+            # dispatch.
+            out, aux = moe_mod.moe_ffn_dispatch(h, lp, cfg)
+        elif impl == "gather":
+            out, aux = moe_mod.moe_ffn_gather(h, lp, cfg)
+        elif impl == "auto" and moe_mod.grouped_pays(n_tok, cfg):
+            out, aux, stats = moe_mod.moe_ffn_grouped(h, lp, cfg)
+        elif share:
+            out, aux, stats = moe_mod.moe_ffn_dense(
+                h, lp, cfg, with_stats=True
+            )
+        else:
+            out, aux = moe_mod.moe_ffn_dense(h, lp, cfg)
+    if "ws_gateup" in lp or "ws_gate" in lp:
+        with jax.named_scope("moe_shared"):
+            out = out + _swiglu(
+                h, lp, "ws_", cfg.n_shared_experts * cfg.expert_dim, qmm
+            )
+    if share and stats is None:  # a forced path: picks counted, rows unknown
+        stats = jnp.zeros((3,), jnp.int32)
+    return out, aux, stats if share else None
 
 
 # ---------------------------------------------------------------------------
@@ -488,20 +536,21 @@ def forward_full(
     ``with_aux`` additionally returns the mean per-layer MoE
     load-balancing loss (0.0 for dense models): (logits, aux).
     """
-    if with_aux:
-        logits, _, _, aux = _forward_with_kv(
-            params, cfg, tokens, attn_fn, kernels, with_aux=True
-        )
-        return logits, aux
-    logits, _, _ = _forward_with_kv(params, cfg, tokens, attn_fn, kernels)
-    return logits
+    out = _forward_with_kv(
+        params, cfg, tokens, attn_fn, kernels, with_aux=with_aux
+    )
+    return (out[0], out[3]) if with_aux else out[0]
 
 
 def prefill(
     params: Params, cfg: ModelConfig, tokens: jnp.ndarray, kernels=None,
-    qmm=None, attn_fn=None,
+    qmm=None, attn_fn=None, moe_impl: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Causal forward returning (logits [B,T,V], k [L,B,T,KH,D], v [...]).
+    A latent-attention model returns its cache rows in the same places, as
+    one "head" each: the latents [L,B,T,1,kv_lora_rank] and the padded
+    rotary parts [L,B,T,1,128] (engine/paged.py header), and a fourth value
+    where the model carries expert counters (engine/latent.py).
 
     The engine copies the returned K/V into the request's cache slot.
     ``attn_fn`` swaps the attention implementation — the sequence-sharded
@@ -509,7 +558,8 @@ def prefill(
     prompt's forward spreads over the mesh's sp axis.
     """
     return _forward_with_kv(
-        params, cfg, tokens, attn_fn=attn_fn, kernels=kernels, qmm=qmm
+        params, cfg, tokens, attn_fn=attn_fn, kernels=kernels, qmm=qmm,
+        moe_impl=moe_impl,
     )
 
 
@@ -586,7 +636,15 @@ def _use_ragged_kernel(
 
 
 def _forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None, kernels=None,
-                     with_aux: bool = False, qmm=None):
+                     with_aux: bool = False, qmm=None,
+                     moe_impl: Optional[str] = None):
+    if cfg.mla:
+        from . import latent
+
+        return latent.forward_with_kv(
+            params, cfg, tokens, attn_fn=attn_fn, with_aux=with_aux,
+            qmm=qmm, moe_impl=moe_impl,
+        )
     B, T = tokens.shape
     x = params["embed"][tokens]
     positions = jnp.broadcast_to(jnp.arange(T), (B, T))
@@ -607,7 +665,7 @@ def _forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None, kernels=Non
 
     def block(x, lp):
         return apply_block(x, lp, cfg, cos, sin, mask, attention, with_aux,
-                           qmm=qmm)
+                           qmm=qmm, moe_impl=moe_impl)
 
     if with_aux:
         x, (ks, vs, auxs) = jax.lax.scan(block, x, params["layers"])
@@ -628,6 +686,7 @@ def prefill_chunk(
     v_cache: jnp.ndarray,  # [L, S, C, KH, D]
     cache_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
+    moe_impl: Optional[str] = None,
 ):
     """One chunk of an incremental prefill against the slot cache.
 
@@ -710,7 +769,7 @@ def prefill_chunk(
             v_all = jax.lax.dynamic_slice_in_dim(v_l, slot, 1, axis=0)
         attn = attend(q, k_all.astype(q.dtype), v_all.astype(q.dtype))
         x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
-        x = x + _mlp(x, lp, cfg, qmm=qmm)
+        x = x + _mlp(x, lp, cfg, moe_impl, qmm)
         if quant_cache:
             return x, (k_l, v_l, k_s, v_s)
         return x, (k_l, v_l)
@@ -876,6 +935,35 @@ def decode_step(
     return logits, k_cache, v_cache
 
 
+def layer_segments(params: Params) -> Tuple[dict, ...]:
+    """The layer stack as homogeneous segments, each a tree stacked on a
+    leading layer axis: the leading dense layers (``lead_layers``, where a
+    model has them: their tree differs from an expert layer's), then the
+    periodic stack (``layers``). One segment is every other model's case."""
+    if "lead_layers" in params:
+        return (params["lead_layers"], params["layers"])
+    return (params["layers"],)
+
+
+def scan_segments(block, carry, segments):
+    """Run ``block(carry, (layer_params, l))`` over every layer of every
+    segment in turn, one ``lax.scan`` a segment, the carry (the residual,
+    the page pools) going through all of them and the layer index ``l``
+    running on across segments. Returns (carry, what the blocks emitted,
+    stacked over all layers; None where they emit nothing)."""
+    first, emitted = 0, []
+    for seg in segments:
+        n = jax.tree.leaves(seg)[0].shape[0]
+        carry, ys = jax.lax.scan(
+            block, carry, (seg, jnp.arange(first, first + n))
+        )
+        emitted.append(ys)
+        first += n
+    if emitted[0] is None:
+        return carry, None
+    return carry, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *emitted)
+
+
 def _scan_layers_over_pool(block, x, layers, k_pool, v_pool, cache_scales):
     """Run ``block`` over the layer stack with the page pools (and int8
     scales) as the scan CARRY: layer ``l`` reads and writes
@@ -894,6 +982,26 @@ def _scan_layers_over_pool(block, x, layers, k_pool, v_pool, cache_scales):
     return x, k_pool, v_pool, tuple(scales) or None
 
 
+def chunk_pages(table_row, start, Tc: int, P: int):
+    """Where a chunk's rows [start, start+Tc) land (ops.write_rows: whole
+    pages, or inside one): the pages, and the row of the first page it
+    starts on."""
+    if Tc >= P:  # page-aligned chunk spanning Tc/P whole pages
+        nb = Tc // P
+        # pad with sacrificial entries so a final bucket whose padding
+        # overruns max_context (possible when a prefix match de-aligns
+        # chunk starts) slices cleanly: overflow rows land on page 0
+        # instead of dynamic_slice clamping the start a block early and
+        # corrupting the previous chunk's rows
+        table_ext = jnp.concatenate(
+            [table_row, jnp.zeros((nb,), table_row.dtype)]
+        )
+        pages = jax.lax.dynamic_slice(table_ext, (start // P,), (nb,))
+    else:  # chunk inside one page
+        pages = jax.lax.dynamic_slice(table_row, (start // P,), (1,))
+    return pages, start % P
+
+
 def prefill_chunk_paged(
     params: Params,
     cfg: ModelConfig,
@@ -906,6 +1014,7 @@ def prefill_chunk_paged(
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
     win_start: Optional[jnp.ndarray] = None,  # scalar: live window start
     sink_rows: int = 0,  # static sink rows (window+sink KV compression)
+    moe_impl: Optional[str] = None,
 ):
     """One chunk of an incremental prefill against the PAGED cache.
 
@@ -926,6 +1035,13 @@ def prefill_chunk_paged(
     gathered view dequantizes). Returns (logits [1, Tc, V] fp32, k_pool',
     v_pool'[, scales']).
     """
+    if cfg.mla:
+        from . import latent
+
+        return latent.prefill_chunk_paged(
+            params, cfg, tokens, start, k_pool, v_pool, table_row,
+            qmm=qmm, moe_impl=moe_impl,
+        )
     B, Tc = tokens.shape
     MB = table_row.shape[0]
     P = k_pool.shape[2]
@@ -935,22 +1051,7 @@ def prefill_chunk_paged(
     positions = start + jnp.arange(Tc)[None, :]  # [1, Tc]
     cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
-    # where the chunk's rows land (ops.write_rows: whole pages, or inside
-    # one): the pages, and the row of the first page it starts on
-    if Tc >= P:  # page-aligned chunk spanning Tc/P whole pages
-        nb = Tc // P
-        # pad with sacrificial entries so a final bucket whose padding
-        # overruns max_context (possible when a prefix match de-aligns
-        # chunk starts) slices cleanly: overflow rows land on page 0
-        # instead of dynamic_slice clamping the start a block early and
-        # corrupting the previous chunk's rows
-        table_ext = jnp.concatenate(
-            [table_row, jnp.zeros((nb,), table_row.dtype)]
-        )
-        pages = jax.lax.dynamic_slice(table_ext, (start // P,), (nb,))
-    else:  # chunk inside one page
-        pages = jax.lax.dynamic_slice(table_row, (start // P,), (1,))
-    off = start % P
+    pages, off = chunk_pages(table_row, start, Tc, P)
 
     t = min(512, C_log)
     kv_tile = t if C_log % t == 0 else P
@@ -990,7 +1091,7 @@ def prefill_chunk_paged(
             sink=sink_rows,
         )
         x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
-        x = x + _mlp(x, lp, cfg, qmm=qmm)
+        x = x + _mlp(x, lp, cfg, moe_impl, qmm)
         return (x, k_pool, v_pool, *scales), None
 
     x, k_pool, v_pool, scales = _scan_layers_over_pool(
@@ -1050,6 +1151,13 @@ def decode_step_paged(
     Unsupported with ``pool_impl`` (the dp-replicated shard_map twin —
     the engine never arms compression there).
     """
+    if cfg.mla:
+        from . import latent
+
+        return latent.decode_step_paged(
+            params, cfg, tokens, lengths, k_pool, v_pool, tables,
+            kernels=kernels, active=active, moe_impl=moe_impl, qmm=qmm,
+        )
     B = tokens.shape[0]
     P = k_pool.shape[2]
     quant_pool = cache_scales is not None
@@ -1197,6 +1305,13 @@ def verify_step_paged(
 
     Returns (logits [B, T, V] fp32, k_pool', v_pool'[, scales']).
     """
+    if cfg.mla:
+        from . import latent
+
+        return latent.verify_step_paged(
+            params, cfg, tokens, lengths, k_pool, v_pool, tables,
+            active=active, moe_impl=moe_impl, qmm=qmm,
+        )
     B, T = tokens.shape
     MB = tables.shape[1]
     P = k_pool.shape[2]

@@ -21,6 +21,16 @@ Two implementations of the same math (top-k routed SwiGLU experts):
     expert is zero), the standard training trade; with generous capacity
     the result is bit-identical to the dense path (tested).
 
+A chip may hold a SHARE of a layer's experts (``cfg.experts_held`` of the
+router's ``cfg.num_experts``, from ``cfg.first_expert`` on: wide expert
+parallelism, each layer divided over several chips). The router keeps its
+published width and top-k; every FFN here computes the part of the result
+that the HELD experts give for the tokens routed to them (``local_picks``),
+and what the absent ones would add is left out — on one chip the layer runs
+without its exchange. For such a layer at prefill token counts
+``moe_ffn_grouped`` is the exact dropless path: picks sorted by expert, each
+held expert run over its own rows only.
+
 Replaces: nothing in the reference — its only MoE access is the cloud
 qwen3:30b endpoint behind the api-gateway (api-gateway/src/main.rs:70-88).
 Serving the Qwen3-30B-A3B tier locally is a TPU-build extension.
@@ -63,19 +73,57 @@ def route(
     w_router,  # [E, X]
     cfg: ModelConfig,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Top-k routing. Returns (probs [N, X] fp32, weights [N, k] fp32,
+    """Top-k routing over ALL ``cfg.num_experts`` experts, whatever share
+    of them is held here. Returns (probs [N, X] fp32, weights [N, k] fp32,
     idx [N, k] int32). ``probs`` is the full softmax (for the
     load-balancing aux loss); ``weights`` are the selected gates,
     renormalized over the top-k set when cfg.norm_topk_prob (the
-    Mixtral/Qwen3-MoE convention)."""
+    Mixtral/Qwen3-MoE convention). With ``cfg.moe_scoring == "sigmoid"``
+    (the DeepSeek-V3 family) the scores are independent sigmoids, the top-k
+    of them renormalized and then scaled by ``cfg.routed_scaling_factor``."""
     if isinstance(w_router, dict):  # never quantized, but be safe
         w_router = w_router["q"].astype(jnp.float32) * w_router["s"]
     logits = (h.astype(jnp.float32) @ w_router.astype(jnp.float32))
+    if cfg.moe_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        weights, idx = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+        if cfg.norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights * jnp.float32(cfg.routed_scaling_factor)
+        return scores, weights, idx.astype(jnp.int32)
+    if cfg.moe_scoring != "softmax":
+        raise ValueError(f"unknown moe_scoring {cfg.moe_scoring!r}")
     probs = jax.nn.softmax(logits, axis=-1)
     weights, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
     if cfg.norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return probs, weights, idx.astype(jnp.int32)
+
+
+def local_picks(weights: jnp.ndarray, idx: jnp.ndarray, cfg: ModelConfig):
+    """The router's picks as the HELD experts see them: (weights with the
+    picks of absent experts zeroed, idx relative to ``cfg.first_expert`` and
+    clipped into the held range, which picks are held here). With every
+    expert held this is the identity, traces nothing, and the third is
+    None."""
+    if not cfg.expert_share:
+        return weights, idx, None
+    rel = idx - cfg.first_expert
+    here = (rel >= 0) & (rel < cfg.held_experts)
+    return (
+        jnp.where(here, weights, 0.0),
+        jnp.clip(rel, 0, cfg.held_experts - 1),
+        here,
+    )
+
+
+def pick_stats(here: jnp.ndarray, expert_rows) -> jnp.ndarray:
+    """int32 [3]: (picks the router made, picks that fell on an expert held
+    here, rows the expert matmuls computed) for one layer's call."""
+    return jnp.stack([
+        jnp.int32(here.size), jnp.sum(here, dtype=jnp.int32),
+        jnp.asarray(expert_rows, jnp.int32),
+    ])
 
 
 def gate_matrix(
@@ -106,12 +154,16 @@ def moe_ffn_dense(
     h: jnp.ndarray,  # [B, T, E] normalized hidden states
     lp,  # layer params holding w_router / we_gate / we_up / we_down
     cfg: ModelConfig,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Exact dropless MoE FFN; returns (out [B, T, E], aux scalar fp32)."""
+    with_stats: bool = False,
+) -> Tuple[jnp.ndarray, ...]:
+    """Exact dropless MoE FFN over the experts held here; returns (out
+    [B, T, E], aux scalar fp32), and with ``with_stats`` ``pick_stats``
+    third (every held expert runs over every token)."""
     B, T, E = h.shape
     flat = h.reshape(B * T, E)
     probs, weights, idx = route(flat, lp["w_router"], cfg)
-    gates = gate_matrix(weights, idx, cfg.num_experts).astype(h.dtype)  # [N,X]
+    weights, idx_here, here = local_picks(weights, idx, cfg)
+    gates = gate_matrix(weights, idx_here, cfg.held_experts).astype(h.dtype)
 
     if "we_gateup" in lp:  # fused serving layout (model.quantize_params)
         F = cfg.expert_dim
@@ -131,6 +183,12 @@ def moe_ffn_dense(
     else:
         out = jnp.einsum("xnf,xfe->ne", z, lp["we_down"])
     aux = load_balance_aux(probs, idx, cfg.num_experts)
+    if with_stats:
+        if here is None:
+            here = jnp.ones(idx.shape, jnp.bool_)
+        return out.reshape(B, T, E), aux, pick_stats(
+            here, B * T * cfg.held_experts
+        )
     return out.reshape(B, T, E), aux
 
 
@@ -158,7 +216,9 @@ def moe_ffn_gather(
     k = cfg.num_experts_per_tok
     flat = h.reshape(N, E)
     probs, weights, idx = route(flat, lp["w_router"], cfg)
-    picks = idx.reshape(N * k)  # [P] expert id per pick
+    # a pick of an absent expert gathers a held one's block at weight zero
+    weights, idx_here, _ = local_picks(weights, idx, cfg)
+    picks = idx_here.reshape(N * k)  # [P] held-expert id per pick
     x_pick = jnp.repeat(flat, k, axis=0)  # [P, E] token repeated per pick
 
     def pick_einsum(x, w):  # x [P, E or F], w [X, in, out] -> [P, out]
@@ -206,9 +266,13 @@ def moe_ffn_dispatch(
     """
     B, T, E = h.shape
     N = B * T
-    X, k = cfg.num_experts, cfg.num_experts_per_tok
+    X, k = cfg.held_experts, cfg.num_experts_per_tok
     flat = h.reshape(N, E)
-    probs, weights, idx = route(flat, lp["w_router"], cfg)
+    probs, weights, idx_all = route(flat, lp["w_router"], cfg)
+    weights, idx, here = local_picks(weights, idx_all, cfg)
+    if here is not None:
+        # picks of absent experts one-hot off the end: they queue nowhere
+        idx = jnp.where(here, idx, X)
 
     if capacity is None:
         capacity = max(8, int(-(-N * k * capacity_factor // X)))
@@ -244,5 +308,100 @@ def moe_ffn_dispatch(
     z = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * u
     ye = _expert_einsum("xcf,xfe->xce", z, lp["we_down"])
     out = jnp.einsum("nxc,xce->ne", combine, ye)
-    aux = load_balance_aux(probs, idx, X)
+    aux = load_balance_aux(probs, idx_all, cfg.num_experts)
     return out.reshape(B, T, E), aux
+
+
+GROUP_TILE = 128  # rows of one expert's tile: the MXU's height
+
+
+def grouped_pays(n_tok: int, cfg: ModelConfig) -> bool:
+    """Whether ``moe_ffn_grouped`` does less expert work than
+    dense-over-held for ``n_tok`` tokens: an expert's rows come in tiles of
+    GROUP_TILE, so it needs more tokens than a tile, and a token's picks
+    have to be a small share of the router's width (at Mixtral's 2 of 8 a
+    tile is nearly as full as the dense path's; that model keeps its path)."""
+    return (
+        n_tok > GROUP_TILE
+        and cfg.num_experts_per_tok * 8 <= cfg.num_experts
+    )
+
+
+def moe_ffn_grouped(
+    h: jnp.ndarray,  # [B, T, E] normalized hidden states
+    lp,
+    cfg: ModelConfig,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Exact dropless MoE FFN for LARGE token counts; returns (out, aux,
+    ``pick_stats``).
+
+    The picks that fall on a held expert are sorted by expert; each held
+    expert then runs over ITS rows only, a GROUP_TILE of them at a time (a
+    loop whose trip count is the expert's own row count, so no capacity is
+    fixed and no pick is dropped), and adds its gated result at the rows'
+    tokens. Work follows the picks that landed here — 1/32 of
+    dense-over-held at 8 of 256 picked and 16 held — where the dense path
+    runs every held expert over every token. The expert's weights stream
+    once, as int8, whatever its row count."""
+    B, T, E = h.shape
+    N, k, X, TM = B * T, cfg.num_experts_per_tok, cfg.held_experts, GROUP_TILE
+    F = cfg.expert_dim
+    flat = h.reshape(N, E)
+    probs, weights, idx = route(flat, lp["w_router"], cfg)
+    weights, idx_here, here = local_picks(weights, idx, cfg)
+    if here is None:
+        here = jnp.ones(idx.shape, jnp.bool_)
+    key = jnp.where(here, idx_here, X).reshape(N * k)  # absent experts last
+    order = jnp.argsort(key, stable=True)
+    pad = jnp.zeros((TM,), jnp.int32)  # a tile's slice never clamps
+    tok = jnp.concatenate([(order // k).astype(jnp.int32), pad])
+    gate = jnp.concatenate(
+        [weights.reshape(N * k)[order], pad.astype(weights.dtype)]
+    )
+    counts = jnp.sum(jax.nn.one_hot(key, X, dtype=jnp.int32), axis=0)  # [X]
+    starts = jnp.cumsum(counts) - counts
+    lane = jnp.arange(TM)
+
+    def qdot(x, w):  # [TM, in] @ one expert's [in, out] (int8 leaf or dense)
+        if isinstance(w, dict):
+            y = jnp.einsum(
+                "ni,io->no", x, w["q"], preferred_element_type=jnp.float32
+            )
+            return (y * w["s"][0]).astype(x.dtype)
+        return x @ w
+
+    fused = "we_gateup" in lp
+    xs = (
+        (lp["we_gateup"],) if fused else (lp["we_gate"], lp["we_up"])
+    ) + (lp["we_down"], starts, counts)
+
+    def expert(out, xs):
+        *w_in, w_down, start, count = xs
+
+        def tile(t, out):
+            at = start + t * TM
+            rows = jax.lax.dynamic_slice(tok, (at,), (TM,))
+            g = jnp.where(
+                t * TM + lane < count,
+                jax.lax.dynamic_slice(gate, (at,), (TM,)), 0.0,
+            )
+            x = flat[rows]  # [TM, E]
+            if fused:
+                gu = qdot(x, w_in[0])
+                a, u = gu[:, :F], gu[:, F:]
+            else:
+                a, u = qdot(x, w_in[0]), qdot(x, w_in[1])
+            z = jax.nn.silu(a.astype(jnp.float32)).astype(x.dtype) * u
+            y = qdot(z, w_down).astype(jnp.float32)
+            # a token picks an expert at most once: live rows never collide
+            return out.at[rows].add(y * g[:, None])
+
+        n_tiles = (count + TM - 1) // TM
+        return jax.lax.fori_loop(0, n_tiles, tile, out), n_tiles
+
+    out, tiles = jax.lax.scan(expert, jnp.zeros((N, E), jnp.float32), xs)
+    aux = load_balance_aux(probs, idx, cfg.num_experts)
+    return (
+        out.astype(h.dtype).reshape(B, T, E), aux,
+        pick_stats(here, jnp.sum(tiles, dtype=jnp.int32) * TM),
+    )

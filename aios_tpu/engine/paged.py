@@ -4,7 +4,16 @@ The device holds a fixed page pool and reads it through per-slot page
 tables; THIS module owns the mapping. The pool has ONE stored layout,
 [L, N, P, KH*D] per k/v (layers, physical pages, rows of a page, and a
 row's kv heads side by side: head h is [h*D, (h+1)*D) of the last axis, for
-any head size). The paged decode kernel takes that array whole with the
+any head size). A latent-attention (MLA) model's pool is the same pair of
+arrays holding LATENT rows, not keys and values: in k's place the compressed
+latents [L, N, P, kv_lora_rank] (512), in v's the rotary key parts
+[L, N, P, 128] with a row's qk_rope_head_dim (64) values in lanes [0, 64) and
+zeros above — two arrays and not one padded 576 -> 640 row, because a page is
+then two whole-tile DMAs (a 64-lane row is a lane-padded tile in HBM either
+way and Mosaic refuses a page DMA narrower than a tile) and the latent part
+feeds the value product without a lane slice. 640 values a row are stored
+for the published 576 (ModelConfig.kv_row_dims); allocator, tables, prefix
+index and page accounting are the same, a page being P rows of either. The paged decode kernel takes that array whole with the
 layer's index and DMAs the pages it needs from where they lie
 (ops/paged_attention.py); every other reader and writer indexes it by
 (layer, page, row) and reshapes only what is small — new rows on the way
@@ -365,9 +374,29 @@ def chain_hashes(
 _WIRE_MAGIC = b"KVX1"
 
 
+class LatentEntryUnsupported(ValueError):
+    """A page of a latent (MLA) pool has no entry kind yet: the host spill
+    tier and the fleet transfer plane carry K/V pages only."""
+
+
+def refuse_latent_entry(entry: Dict[str, np.ndarray]) -> None:
+    """Raise for a page entry cut from a latent pool. A K/V page's two
+    arrays have one shape; a latent page's (latents, padded rotary parts)
+    do not, and a receiver would scatter them as keys and values."""
+    k, v = entry.get("k"), entry.get("v")
+    if k is not None and v is not None and k.shape != v.shape:
+        raise LatentEntryUnsupported(
+            f"page entry with k {k.shape} and v {v.shape} is a latent "
+            "(MLA) page: the host spill tier and KVX have no entry kind "
+            "for it yet"
+        )
+
+
 def pack_entry(entry: Dict[str, np.ndarray]) -> bytes:
     """Serialize one page-KV entry for the transfer plane (sorted keys,
-    so the byte stream — like the crc — is order-independent)."""
+    so the byte stream — like the crc — is order-independent). Refuses a
+    latent page (``LatentEntryUnsupported``)."""
+    refuse_latent_entry(entry)
     parts = [_WIRE_MAGIC, struct.pack("<B", len(entry))]
     for key in sorted(entry):
         a = np.ascontiguousarray(entry[key])
@@ -498,7 +527,9 @@ class HostPageStore:
         """Insert a spilled page (the newest entry; LRU evicts past the
         byte budget). An entry bigger than the whole budget is dropped.
         The crc32 is computed OUTSIDE the lock (spill-worker thread CPU
-        time; the engine's restore probe shares this lock)."""
+        time; the engine's restore probe shares this lock). Refuses a
+        latent page (``LatentEntryUnsupported``)."""
+        refuse_latent_entry(entry)
         nb = self._entry_bytes(entry)
         if nb > self.max_bytes:
             return

@@ -175,7 +175,14 @@ class KvxService(services.KvTransferServicer):
         budget = int(request.budget_bytes) or fetch_budget()
         triples: List[Tuple[bytes, int, bytes]] = []
         total = 0
-        hbm = engine.export_hashes(hashes)
+        try:
+            hbm = engine.export_hashes(hashes)
+        except paged.LatentEntryUnsupported as exc:
+            # a latent (MLA) pool's pages have no entry kind: say so by
+            # name; the puller falls back to local prefill
+            import grpc
+
+            context.abort(grpc.StatusCode.FAILED_PRECONDITION, str(exc))
         for h, entry in hbm:
             payload = paged.pack_entry(entry)
             crc = paged.HostPageStore._entry_crc(entry)

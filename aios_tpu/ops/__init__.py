@@ -44,6 +44,10 @@ from .paged_attention import (
     split_heads,
     write_rows,
 )
+from .paged_mla_attention import (
+    paged_mla_decode_attention,
+    paged_mla_decode_attention_reference,
+)
 from .int4_matmul import (
     dequantize_int4,
     int4_matmul,
@@ -74,6 +78,8 @@ __all__ = [
     "paged_decode_attention_int8",
     "paged_decode_attention_int8_reference",
     "paged_decode_attention_reference",
+    "paged_mla_decode_attention",
+    "paged_mla_decode_attention_reference",
     "gather_pages",
     "merge_heads",
     "split_heads",

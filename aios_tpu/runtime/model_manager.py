@@ -345,7 +345,7 @@ class ModelManager:
     def _kv_row_bytes(cfg, cache_dtype) -> float:
         """Bytes one KV row (both k and v, all layers) occupies."""
         item = 1 if cache_dtype == jnp.int8 else 2
-        return 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim * item
+        return cfg.num_layers * sum(cfg.kv_row_dims) * item
 
     def _kv_bytes_per_chip(self, cfg, ctx, cache_dtype, kw) -> float:
         """Estimated per-chip HBM the KV cache will pin under the current
@@ -488,6 +488,11 @@ class ModelManager:
                         + self._kv_row_bytes(draft.cfg, jnp.bfloat16)
                         * self.num_slots * ctx * repl_factor
                     )
+            from ..engine.engine import refuse_for_latent_pool
+
+            refuse_for_latent_pool(
+                cfg, speculative_decoding_with_verify_step_paged=spec_on
+            )
             kw = {}
             pool_rows = self.paged_pool_rows
             if pool_rows == "auto":

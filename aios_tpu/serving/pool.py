@@ -574,6 +574,9 @@ class ReplicaPool:
                 if k == "batch_occupancy":
                     occ.append(v)
                     continue
+                if k == "kv_row_bytes":  # a size, the same in every replica
+                    out[k] = v
+                    continue
                 out[k] = out.get(k, 0) + v
             out["waiting"] = out.get("waiting", 0) + r.queue_depth()
             out["completed"] = out.get("completed", 0) + r.batcher.completed

@@ -257,12 +257,22 @@ def test_verify_gather_gating(monkeypatch):
 
 
 def test_env_var_overrides_engine_gather(monkeypatch):
-    """AIOS_TPU_MOE_IMPL is the operator's escape hatch: it beats the
-    engine's static 'gather' choice at trace time."""
-    from aios_tpu.engine import moe as moe_mod_check  # noqa: F401
+    """AIOS_TPU_MOE_IMPL is the operator's escape hatch: the engine resolves
+    it once, at load time, beside its other overrides, and it beats the
+    engine's static 'gather' choice in every graph; model.ffn reads no
+    environment."""
+    from aios_tpu.engine.engine import TPUEngine
 
     cfg = TINY_MOE
     params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    monkeypatch.setenv("AIOS_TPU_MOE_GATHER", "1")
+    monkeypatch.setenv("AIOS_TPU_MOE_IMPL", "dense")
+    eng = TPUEngine(cfg, params, num_slots=1, max_context=64,
+                    cache_dtype=jnp.float32)
+    assert eng._moe_override == "dense" and eng._moe_impl == "dense"
+    assert eng._verify_moe_impl(4) == "dense"
+    eng.close()
+
     h = jax.random.normal(jax.random.PRNGKey(7), (1, 1, cfg.hidden_size))
     lp = _layer0(params)
     called = {}
@@ -273,8 +283,9 @@ def test_env_var_overrides_engine_gather(monkeypatch):
         return real(*a, **k)
 
     monkeypatch.setattr(moe_mod, "moe_ffn_dense", spy)
-    monkeypatch.setenv("AIOS_TPU_MOE_IMPL", "dense")
-    M._mlp(h, {**lp, "ffn_norm": lp["ffn_norm"]}, cfg, moe_impl="gather")
+    # the explicit choice is the caller's; the environment no longer beats it
+    monkeypatch.setenv("AIOS_TPU_MOE_IMPL", "gather")
+    M._mlp(h, lp, cfg, moe_impl="dense")
     assert called.get("dense")
 
 

@@ -406,3 +406,76 @@ def test_aot_mistral_serving_graphs_compile_and_fit(
         live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
         assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"
+
+
+# --- the latent-attention configuration of the benchmark (PR 27) -----------
+
+
+def test_aot_latent_serving_graphs_compile_and_fit(rep_sharding, monkeypatch):
+    """openPangu-Ultra-MoE as the benchmark cuts it (published widths, 5
+    layers, 16 of 256 experts held, 32 slots x 16,384 rows): the latent decode
+    kernel alone, then the composed decode step, a mid chunk, the one-page and
+    a sub-page final chunk and a whole-prompt prefill, traced as on the chip.
+    Each must fit beside the 5.1 GB of weights and the 3.5 GB latent pool,
+    and none may copy a whole pool array (the one-page chunk did, through
+    the scatter's way: engine/latent.py `_write_chunk`)."""
+    import json
+
+    from aios_tpu import backend, ops
+    from aios_tpu.engine import model as M
+    from aios_tpu.engine.config import ModelConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    import sys
+
+    sys.path.insert(0, root)
+    from benchmark.harness.manifest import load_file
+
+    arch = load_file(os.path.join(root, "benchmark", "archs", "pangu_ultra_moe.py"),
+                     "benchmark_arch")
+    with open(os.path.join(root, "benchmark", "configs",
+                           "openpangu-ultra-moe-int8-ep16-d5.json")) as fh:
+        config = json.load(fh)
+    d = arch.dims_of(config)
+    cfg = ModelConfig(**arch.model_fields(config, 16384))
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    rep = rep_sharding
+    params = jax.tree.map(lambda a: sds(rep, a.shape, a.dtype),
+                          jax.eval_shape(lambda: arch.build_params(d, 1)))
+    slots, blocks, pages = 32, 128, 33 * 128 + 1
+    pools = tuple(sds(rep, (cfg.num_layers, pages, 128, w), jnp.bfloat16)
+                  for w in cfg.kv_row_dims)
+    pool_elems = {cfg.num_layers * pages * 128 * w for w in cfg.kv_row_dims}
+    i32 = lambda *shape: sds(rep, shape, jnp.int32)  # noqa: E731
+
+    aot_compile(
+        rep, ops.paged_mla_decode_attention,
+        sds(rep, (slots, cfg.num_heads, 512), jnp.bfloat16),
+        sds(rep, (slots, cfg.num_heads, 128), jnp.bfloat16), *pools,
+        i32(), i32(slots, blocks), i32(slots), sm_scale=192 ** -0.5,
+    )
+
+    def chunk(p, c, r, toks, start, row):
+        return M.prefill_chunk_paged(p, cfg, toks, start, c, r, row)
+
+    def step(p, c, r, toks, lens, tables):
+        return M.decode_step_paged(p, cfg, toks, lens, c, r, tables, kernels=True)
+
+    graphs = {"decode-step": (step, (params, *pools, i32(slots), i32(slots),
+                                     i32(slots, blocks)), (1, 2)),
+              "prefill-512": (lambda p, t: M.prefill(p, cfg, t, kernels=True),
+                              (params, i32(1, 512)), ())}
+    for t in (512, 128, 64):
+        graphs[f"chunk-{t}"] = (chunk, (params, *pools, i32(1, t), i32(),
+                                        i32(blocks)), (1, 2))
+    for name, (fn, args, donate) in graphs.items():
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        copied = [res for op, res in _hlo_results(compiled.as_text())
+                  if op == "copy" and pool_elems & set(res)]
+        assert copied == [], f"{name}: copies a whole pool array"
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 1.6e9, (
+            f"{name}: {mem.temp_size_in_bytes / 1e9:.2f} GB of temporaries")
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"
