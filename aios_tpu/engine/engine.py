@@ -412,6 +412,12 @@ class TPUEngine:
         # EXCEPT decode attention, which is head/slot-local and runs the
         # ragged kernel per device under shard_map (see _attn_impl below).
         self._kernels: Optional[bool] = False if shardings is not None else None
+        # MoE prefill: with nothing forced, a chunk or bucket of enough
+        # tokens (moe.grouped_pays, from static shapes) runs each expert
+        # over the rows routed to it only, reading the stacked int8 expert
+        # weights where they lie (moe.moe_ffn_grouped; the layer scans hand
+        # it the stacks whole); fewer tokens, and every decode step, run
+        # every held expert over every token (moe.moe_ffn_dense).
         # MoE decode: the gathered path streams only the routed experts'
         # weights (moe.moe_ffn_gather) when every slot's picks together
         # touch fewer experts than exist. Measured on v5e (2.3B geometry,
@@ -422,7 +428,7 @@ class TPUEngine:
         # X/(slots*k) ratios may still favor it). Single-device only:
         # under EP the expert axis is sharded and the dense path's psum is
         # the right collective. Decode/verify dispatches only — prefill
-        # token counts saturate the experts.
+        # has the grouped path above.
         # AIOS_TPU_MOE_IMPL ("dense" | "gather" | "dispatch") is the
         # operator's escape hatch: resolved HERE, once, with the engine's
         # other load-time overrides, it beats every static choice below and
@@ -441,6 +447,12 @@ class TPUEngine:
             in ("1", "true", "on")
         ):
             self._moe_impl = "gather"
+        # under a sharding plan the expert axis may be sharded over ep,
+        # where the dense path's contraction is one psum and the grouped
+        # path's per-expert reads would cross chips: every graph stays dense
+        self._prefill_moe_impl: Optional[str] = self._moe_override
+        if self._moe_override is None and cfg.moe and shardings is not None:
+            self._moe_impl = self._prefill_moe_impl = "dense"
 
         if shardings is not None:
             if _is_prequantized(params):
@@ -859,10 +871,10 @@ class TPUEngine:
             "history": spec.init_history(num_slots, self.max_context),
             "key": jax.random.PRNGKey(seed),
         }
-        # a model that holds a share of its experts counts its router's
-        # picks on the device (moe.pick_stats): every graph adds to this,
-        # and a decode dispatch hands the sum back with its tokens
-        self.counts_picks = cfg.mla and cfg.expert_share
+        # a model with a router counts its picks and the rows its expert
+        # matmuls computed on the device (moe.pick_stats): every graph adds
+        # to this, and a decode dispatch hands the sum back with its tokens
+        self.counts_picks = cfg.moe
         self.moe_picks_total = 0
         self.moe_picks_local = 0
         self.moe_expert_rows = 0
@@ -1346,11 +1358,11 @@ class TPUEngine:
                 sink_rows=self._sink_rows,
             )
             if self.quant_cache:
-                logits, k, v, (k_s, v_s) = out
+                logits, k, v, (k_s, v_s), *picks = out
             else:
                 logits, k, v, *picks = out
         elif self.quant_cache:
-            logits, k, v, (k_s, v_s) = model.decode_step(
+            logits, k, v, (k_s, v_s), *picks = model.decode_step(
                 params,
                 self.cfg,
                 st["last_tokens"],
@@ -1364,7 +1376,7 @@ class TPUEngine:
                 qmm=self._qmm_impl,
             )
         else:
-            logits, k, v = model.decode_step(
+            logits, k, v, *picks = model.decode_step(
                 params,
                 self.cfg,
                 st["last_tokens"],
@@ -1540,7 +1552,7 @@ class TPUEngine:
             and self.num_slots * feed_width * self.cfg.num_experts_per_tok
             >= self.cfg.num_experts
         ):
-            return None
+            return self._prefill_moe_impl
         return self._moe_impl
 
     def _verify_feed(self, params, st: DecodeState, feed, tables=None):
@@ -1566,11 +1578,9 @@ class TPUEngine:
                 active=st["active"], moe_impl=moe_impl,
                 qmm=self._qmm_gspmd,
             )
-        if self.quant_cache:
-            logits, k, v, (k_s, v_s) = out
-            return logits, k, v, (k_s, v_s), None
-        logits, k, v, *picks = out
-        return logits, k, v, None, (picks[0] if picks else None)
+        logits, k, v, *rest = out
+        scales = rest.pop(0) if self.quant_cache else None
+        return logits, k, v, scales, (rest[0] if rest else None)
 
     def _spec_impl(
         self, params, state: DecodeState, n_rounds: int, draft_len: int,
@@ -1601,11 +1611,12 @@ class TPUEngine:
             feed = jnp.concatenate(
                 [st["last_tokens"][:, None], drafts], axis=1
             )  # [S, K+1]
-            logits, k, v, new_scales, _ = self._verify_feed(
+            logits, k, v, new_scales, picks = self._verify_feed(
                 params, st, feed, tables
             )
             if self.quant_cache:
                 k_s, v_s = new_scales
+            moe_stats = st.get("moe_stats")
             g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, K+1]
             a = spec.accept_counts(drafts, g)  # [S] in [0, K]
             key, sub = jax.random.split(st["key"])
@@ -1641,6 +1652,8 @@ class TPUEngine:
             if self.quant_cache:
                 st["k_s"] = k_s
                 st["v_s"] = v_s
+            if self.counts_picks:
+                st["moe_stats"] = moe_stats + picks
             return st, (out_tokens, counts)
 
         state, (tokens, counts) = jax.lax.scan(one, state, None, length=n_rounds)
@@ -1678,7 +1691,7 @@ class TPUEngine:
         _logits, k, v = model.verify_step(
             dparams, dcfg, feed, d_len, dstate["k"], dstate["v"],
             kernels=self._kernels, active=ing,
-        )
+        )[:3]  # a draft with a router: its counters are not kept
         new_len = d_len + jnp.where(ing, jnp.minimum(gap, width), 0)
         return {"k": k, "v": v, "lengths": new_len}
 
@@ -1710,7 +1723,7 @@ class TPUEngine:
             logits, k, v = model.decode_step(
                 dparams, dcfg, cur_tok, cur_len, k, v,
                 kernels=self._kernels, active=ok,
-            )
+            )[:3]  # a draft with a router: its counters are not kept
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             new_len = jnp.where(ok, jnp.minimum(cur_len + 1, C - 1), cur_len)
             return (k, v, new_len, nxt), nxt
@@ -1779,9 +1792,10 @@ class TPUEngine:
             feed = jnp.concatenate(
                 [st["last_tokens"][:, None], drafts], axis=1
             )  # [S, K+1]
-            logits, k, v, new_scales, _ = self._verify_feed(
+            logits, k, v, new_scales, picks = self._verify_feed(
                 params, st, feed, tables
             )
+            moe_stats = st.get("moe_stats")
             g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, K+1]
             a = spec.accept_counts(drafts, g)  # [S] in [0, K]
             key, sub = jax.random.split(st["key"])
@@ -1814,6 +1828,8 @@ class TPUEngine:
             }
             if self.quant_cache:
                 st["k_s"], st["v_s"] = new_scales
+            if self.counts_picks:
+                st["moe_stats"] = moe_stats + picks
             # draft sync: rows for accepted tokens are already correct
             # (the draft wrote them while proposing); everything past the
             # verified length — rejected drafts, or the bonus token's
@@ -1906,7 +1922,7 @@ class TPUEngine:
         logits, ks, vs, *picks = model.prefill(
             params, self.cfg, tokens, kernels=self._kernels,
             qmm=self._qmm_gspmd, attn_fn=attn_fn,
-            moe_impl=self._moe_override,
+            moe_impl=self._prefill_moe_impl,
         )
         # ks/vs [L, 1, T, KH, D] -> the pool's rows [L, T, KH*D], written
         # from row 0 of the slot's first page, by whole pages
@@ -1957,9 +1973,9 @@ class TPUEngine:
     def _prefill_impl(
         self, params, state: DecodeState, tokens, slot, true_len, temp, top_p
     ):
-        logits, ks, vs = model.prefill(
+        logits, ks, vs, *picks = model.prefill(
             params, self.cfg, tokens, kernels=self._kernels,
-            qmm=self._qmm_gspmd, moe_impl=self._moe_override,
+            qmm=self._qmm_gspmd, moe_impl=self._prefill_moe_impl,
         )
         # ks/vs [L, B=1, T, KH, D] -> cache layout [L, slot, T, KH, D]
         start = (0, slot, 0, 0, 0)
@@ -2001,6 +2017,8 @@ class TPUEngine:
         if self.quant_cache:
             out["k_s"] = k_s
             out["v_s"] = v_s
+        if self.counts_picks:
+            out["moe_stats"] = state["moe_stats"] + picks[0]
         return out, first
 
     def _chunk_forward(self, params, state: DecodeState, tokens, slot, start,
@@ -2017,25 +2035,21 @@ class TPUEngine:
                 params, self.cfg, tokens, start, state["k"], state["v"],
                 table_row, cache_scales=scales, qmm=self._qmm_gspmd,
                 win_start=win_start, sink_rows=self._sink_rows,
-                moe_impl=self._moe_override,
+                moe_impl=self._prefill_moe_impl,
             )
-            if self.quant_cache:
-                logits, upd["k"], upd["v"], (upd["k_s"], upd["v_s"]) = out
-            else:
-                logits, upd["k"], upd["v"], *picks = out
-                if self.counts_picks:
-                    upd["moe_stats"] = state["moe_stats"] + picks[0]
         else:
             scales = (state["k_s"], state["v_s"]) if self.quant_cache else None
             out = model.prefill_chunk(
                 params, self.cfg, tokens, slot, start, state["k"], state["v"],
                 cache_scales=scales, qmm=self._qmm_gspmd,
-                moe_impl=self._moe_override,
+                moe_impl=self._prefill_moe_impl,
             )
-            if self.quant_cache:
-                logits, upd["k"], upd["v"], (upd["k_s"], upd["v_s"]) = out
-            else:
-                logits, upd["k"], upd["v"] = out
+        if self.quant_cache:
+            logits, upd["k"], upd["v"], (upd["k_s"], upd["v_s"]), *picks = out
+        else:
+            logits, upd["k"], upd["v"], *picks = out
+        if self.counts_picks:
+            upd["moe_stats"] = state["moe_stats"] + picks[0]
         return logits, upd
 
     def _prefill_chunk_impl(

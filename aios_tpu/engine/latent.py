@@ -34,9 +34,8 @@ expert layer differ only in their trees, and the stack is scanned segment by
 segment (``model.layer_segments``) with the pools carried through.
 
 Entry points mirror ``model``'s and return the same tuples, with the pools
-in the places of K and V; a model that holds a share of its experts
-(``cfg.expert_share``) returns ``moe.pick_stats`` summed over its layers as
-one more value.
+in the places of K and V, and like them ``moe.pick_stats`` summed over the
+layers as one more value where the model has a router.
 """
 
 from __future__ import annotations
@@ -47,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import ops
-from . import model
+from . import model, moe
 from .config import ModelConfig
 
 NEG_INF = -1e30
@@ -222,17 +221,6 @@ def _finish_block(x, attn_flat, lp, cfg: ModelConfig, moe_impl, qmm,
     return x + m, aux, stats
 
 
-def _zero_stats(cfg: ModelConfig):
-    return (jnp.zeros((3,), jnp.int32),) if cfg.expert_share else ()
-
-
-def _add_stats(stats, new):
-    """The carried counters plus one layer's (a dense layer has none)."""
-    if not stats or new is None:
-        return stats
-    return (stats[0] + new,)
-
-
 def forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None,
                     with_aux: bool = False, qmm=None,
                     moe_impl: Optional[str] = None):
@@ -262,10 +250,11 @@ def forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None,
             x, attn.reshape(B, T, -1), lp, cfg, moe_impl, qmm, with_aux
         )
         rows = (c[:, :, None, :], _pad_rope(k_r, cfg)[:, :, None, :], aux)
-        return (x, *_add_stats(stats, new)), rows
+        return (x, *model.add_stats(stats, new)), rows
 
     (x, *stats), (cs, rs, auxs) = model.scan_segments(
-        block, (x, *_zero_stats(cfg)), model.layer_segments(params)
+        block, (x, *model.zero_stats(cfg)), model.layer_segments(params),
+        moe.grouped_serves(B * T, cfg, moe_impl, with_aux),
     )
     logits = model._final_logits(x, params, cfg, qmm)
     out = (logits, cs, rs)
@@ -335,11 +324,12 @@ def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, c_pool,
         x, _, new = _finish_block(
             x, attn.reshape(B, Tc, -1), lp, cfg, moe_impl, qmm
         )
-        return (x, c_pool, r_pool, *_add_stats(stats, new)), None
+        return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
 
     (x, c_pool, r_pool, *stats), _ = model.scan_segments(
-        block, (x, c_pool, r_pool, *_zero_stats(cfg)),
+        block, (x, c_pool, r_pool, *model.zero_stats(cfg)),
         model.layer_segments(params),
+        moe.grouped_serves(B * Tc, cfg, moe_impl),
     )
     logits = model._final_logits(x, params, cfg, qmm)
     return (logits, c_pool, r_pool, *stats)
@@ -396,11 +386,12 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
         with jax.named_scope("mla_out"):
             attn = _unabsorb_o(o_lat, lp, cfg)[:, None]
         x, _, new = _finish_block(x, attn, lp, cfg, moe_impl, qmm)
-        return (x, c_pool, r_pool, *_add_stats(stats, new)), None
+        return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
 
     (x, c_pool, r_pool, *stats), _ = model.scan_segments(
-        block, (x, c_pool, r_pool, *_zero_stats(cfg)),
+        block, (x, c_pool, r_pool, *model.zero_stats(cfg)),
         model.layer_segments(params),
+        moe.grouped_serves(B, cfg, moe_impl),
     )
     with jax.named_scope("final_logits"):
         logits = model._final_logits(x[:, 0], params, cfg, qmm)
@@ -453,11 +444,12 @@ def verify_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
         x, _, new = _finish_block(
             x, _unabsorb_o(o_lat, lp, cfg), lp, cfg, moe_impl, qmm
         )
-        return (x, c_pool, r_pool, *_add_stats(stats, new)), None
+        return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
 
     (x, c_pool, r_pool, *stats), _ = model.scan_segments(
-        block, (x, c_pool, r_pool, *_zero_stats(cfg)),
+        block, (x, c_pool, r_pool, *model.zero_stats(cfg)),
         model.layer_segments(params),
+        moe.grouped_serves(B * T, cfg, moe_impl),
     )
     logits = model._final_logits(x, params, cfg, qmm)
     return (logits, c_pool, r_pool, *stats)

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import ops
+from . import moe as moe_mod
 from .config import ModelConfig
 
 Params = Dict[str, jnp.ndarray]
@@ -400,17 +401,22 @@ def apply_block(x, lp, cfg: ModelConfig, cos, sin, mask, attention=None,
     forward, the decode step, and the pipeline-parallel stage all build on
     it (pipeline.py discards the returned k/v).
     """
-    attention = attention or gqa_attention
-    B, T = x.shape[0], x.shape[1]
-    q, k, v = _project_qkv(x, lp, cfg, cos, sin, qmm)
-    attn = attention(q, k, v, mask)
-    x = x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row")
+    x, k, v = _attend(x, lp, cfg, cos, sin, mask, attention, qmm)
     mlp_out, aux = _mlp_aux(x, lp, cfg, allow_dispatch=with_aux,
                             moe_impl=moe_impl, qmm=qmm)
     x = x + mlp_out
     if with_aux:
         return x, (k, v, aux)
     return x, (k, v)
+
+
+def _attend(x, lp, cfg: ModelConfig, cos, sin, mask, attention=None, qmm=None):
+    """The attention sublayer of a block over its own rows; (x', k, v)."""
+    attention = attention or gqa_attention
+    B, T = x.shape[0], x.shape[1]
+    q, k, v = _project_qkv(x, lp, cfg, cos, sin, qmm)
+    attn = attention(q, k, v, mask)
+    return x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row"), k, v
 
 
 def _mlp(x, lp, cfg: ModelConfig, moe_impl: Optional[str] = None, qmm=None):
@@ -430,6 +436,28 @@ def _mlp_aux(
     training forward (forward_full with_aux=True)."""
     h = rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
     return ffn(h, lp, cfg, allow_dispatch, moe_impl, qmm)[:2]
+
+
+def zero_stats(cfg: ModelConfig):
+    """What a serving graph of a model with a router carries through its
+    layers beside the residual: the expert counters (moe.pick_stats), from
+    zero. Nothing for a model without one."""
+    return (jnp.zeros((3,), jnp.int32),) if cfg.moe else ()
+
+
+def add_stats(stats, new):
+    """The carried counters plus one layer's (a dense layer has none)."""
+    if not stats or new is None:
+        return stats
+    return (stats[0] + new,)
+
+
+def _add_mlp(x, stats, lp, cfg: ModelConfig, moe_impl, qmm):
+    """A serving block's FFN sublayer: (x plus it, the carried expert
+    counters plus the layer's)."""
+    h = rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
+    out, _, new = ffn(h, lp, cfg, False, moe_impl, qmm)
+    return x + out, add_stats(stats, new)
 
 
 def _swiglu(h, lp, prefix: str, width: int, qmm=None):
@@ -462,27 +490,28 @@ def ffn(
 
     The expert path is chosen from STATIC shapes and the config:
     ``moe_impl`` ("dense" | "gather" | "dispatch") is the caller's explicit
-    choice — the engine resolves the operator's AIOS_TPU_MOE_IMPL override
-    and its own gathered-decode opt-in once, at load time — and otherwise
-    the exact dense-over-held path serves, except that large token counts
-    take the exact grouped path where it pays (moe.grouped_pays) and the
-    training forward (``allow_dispatch``) the capacity dispatch.
+    choice — the engine resolves the operator's AIOS_TPU_MOE_IMPL override,
+    its own gathered-decode opt-in and a sharding plan's dense path once, at
+    load time — and otherwise token counts at which it computes fewer rows
+    take the exact grouped path (moe.grouped_serves: a prefill chunk or
+    bucket; there ``lp``'s expert leaves may be the whole stacks, read in
+    place at ``lp["expert_layer"]``), the training forward
+    (``allow_dispatch``) the capacity dispatch at large token counts, and
+    everything else — a decode step — the exact dense-over-held path.
 
-    ``stats`` is moe.pick_stats (int32 [3]) for a layer that holds a SHARE
-    of its experts, else None: only such a model's graphs carry counters.
+    ``stats`` is moe.pick_stats (int32 [3]) for a layer with a router, else
+    None: every serving graph of such a model carries the counters.
     """
     if "w_router" not in lp:
         out = _swiglu(h, lp, "w_", cfg.intermediate_size, qmm)
         return out, jnp.float32(0.0), None
-    from . import moe as moe_mod
-
     impl = moe_impl or "auto"
     n_tok = h.shape[0] * h.shape[1]
-    share = cfg.expert_share
     stats = None
     # a scope renumbers a compiled graph's instructions: only the graphs of
     # a model that holds a share (new with the scope) get this one
-    with jax.named_scope("moe_routed") if share else contextlib.nullcontext():
+    with (jax.named_scope("moe_routed") if cfg.expert_share
+          else contextlib.nullcontext()):
         if impl == "dispatch" or (
             impl == "auto" and allow_dispatch and n_tok >= 1024
         ):
@@ -495,22 +524,20 @@ def ffn(
             out, aux = moe_mod.moe_ffn_dispatch(h, lp, cfg)
         elif impl == "gather":
             out, aux = moe_mod.moe_ffn_gather(h, lp, cfg)
-        elif impl == "auto" and moe_mod.grouped_pays(n_tok, cfg):
+        elif moe_mod.grouped_serves(n_tok, cfg, moe_impl, allow_dispatch):
             out, aux, stats = moe_mod.moe_ffn_grouped(h, lp, cfg)
-        elif share:
+        else:
             out, aux, stats = moe_mod.moe_ffn_dense(
                 h, lp, cfg, with_stats=True
             )
-        else:
-            out, aux = moe_mod.moe_ffn_dense(h, lp, cfg)
     if "ws_gateup" in lp or "ws_gate" in lp:
         with jax.named_scope("moe_shared"):
             out = out + _swiglu(
                 h, lp, "ws_", cfg.n_shared_experts * cfg.expert_dim, qmm
             )
-    if share and stats is None:  # a forced path: picks counted, rows unknown
+    if stats is None:  # a forced path counts nothing
         stats = jnp.zeros((3,), jnp.int32)
-    return out, aux, stats if share else None
+    return out, aux, stats
 
 
 # ---------------------------------------------------------------------------
@@ -549,8 +576,10 @@ def prefill(
     """Causal forward returning (logits [B,T,V], k [L,B,T,KH,D], v [...]).
     A latent-attention model returns its cache rows in the same places, as
     one "head" each: the latents [L,B,T,1,kv_lora_rank] and the padded
-    rotary parts [L,B,T,1,128] (engine/paged.py header), and a fourth value
-    where the model carries expert counters (engine/latent.py).
+    rotary parts [L,B,T,1,128] (engine/paged.py header). A model with a
+    router returns its expert counters (moe.pick_stats summed over the
+    layers, int32 [3]) as one more value, here and from every serving graph
+    below.
 
     The engine copies the returned K/V into the request's cache slot.
     ``attn_fn`` swaps the attention implementation — the sequence-sharded
@@ -663,17 +692,27 @@ def _forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None, kernels=Non
         attention = attn_fn or gqa_attention
     mask = causal_mask(T, cfg.sliding_window)
 
-    def block(x, lp):
-        return apply_block(x, lp, cfg, cos, sin, mask, attention, with_aux,
-                           qmm=qmm, moe_impl=moe_impl)
-
     if with_aux:
+        def block(x, lp):
+            return apply_block(x, lp, cfg, cos, sin, mask, attention, True,
+                               qmm=qmm, moe_impl=moe_impl)
+
         x, (ks, vs, auxs) = jax.lax.scan(block, x, params["layers"])
         logits = _final_logits(x, params, cfg, qmm)
         return logits, ks, vs, jnp.mean(auxs)
-    x, (ks, vs) = jax.lax.scan(block, x, params["layers"])
+
+    def block(carry, layer):
+        x, stats = carry
+        x, k, v = _attend(x, layer[0], cfg, cos, sin, mask, attention, qmm)
+        x, stats = _add_mlp(x, stats, layer[0], cfg, moe_impl, qmm)
+        return (x, stats), (k, v)
+
+    (x, stats), (ks, vs) = scan_segments(
+        block, (x, zero_stats(cfg)), layer_segments(params),
+        moe_mod.grouped_serves(B * T, cfg, moe_impl),
+    )
     logits = _final_logits(x, params, cfg, qmm)
-    return logits, ks, vs
+    return (logits, ks, vs, *stats)
 
 
 def prefill_chunk(
@@ -699,7 +738,7 @@ def prefill_chunk(
     block the reference inherits from llama-server's serial queue,
     SURVEY.md section 7 hard-part #1).
 
-    Returns (logits [1, Tc, V] fp32, k_cache', v_cache'[, scales']).
+    Returns (logits [1, Tc, V] fp32, k_cache', v_cache'[, scales'][, stats]).
     Rows past ``start+Tc`` are garbage and masked; the caller samples from
     the logits row of the prompt's true last token on the final chunk.
     """
@@ -732,12 +771,10 @@ def prefill_chunk(
 
     write_at = (slot, start, jnp.int32(0), jnp.int32(0))
 
-    def block(x, layer):
-        if quant_cache:
-            lp, k_l, v_l, k_s, v_s = layer
-        else:
-            lp, k_l, v_l = layer
-            k_s = v_s = None
+    def block(carry, layer):
+        x, stats = carry
+        lp, k_l, v_l, *scales_l = layer
+        k_s, v_s = scales_l or (None, None)
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
         # k_new/v_new [1, Tc, KH, D] drop straight into the slot-cache layout
         # [S, C, KH, D] at (slot, start, 0, 0)
@@ -769,24 +806,17 @@ def prefill_chunk(
             v_all = jax.lax.dynamic_slice_in_dim(v_l, slot, 1, axis=0)
         attn = attend(q, k_all.astype(q.dtype), v_all.astype(q.dtype))
         x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
-        x = x + _mlp(x, lp, cfg, moe_impl, qmm)
-        if quant_cache:
-            return x, (k_l, v_l, k_s, v_s)
-        return x, (k_l, v_l)
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        return (x, stats), (k_l, v_l, *((k_s, v_s) if quant_cache else ()))
 
-    if quant_cache:
-        k_scales, v_scales = cache_scales
-        x, (k_cache, v_cache, k_scales, v_scales) = jax.lax.scan(
-            block, x, (params["layers"], k_cache, v_cache, k_scales, v_scales)
-        )
-    else:
-        x, (k_cache, v_cache) = jax.lax.scan(
-            block, x, (params["layers"], k_cache, v_cache)
-        )
+    x, k_cache, v_cache, scales, stats = _scan_layers_over_cache(
+        block, x, params, k_cache, v_cache, cache_scales, cfg,
+        moe_mod.grouped_serves(B * Tc, cfg, moe_impl),
+    )
     logits = _final_logits(x, params, cfg, qmm)
     if quant_cache:
-        return logits, k_cache, v_cache, (k_scales, v_scales)
-    return logits, k_cache, v_cache
+        return (logits, k_cache, v_cache, scales, *stats)
+    return (logits, k_cache, v_cache, *stats)
 
 
 def decode_step(
@@ -807,7 +837,7 @@ def decode_step(
 
     Writes the new K/V at row ``lengths[b]`` of each slot, attends over all
     valid rows (with sliding window if configured), and returns
-    (logits [B, V] fp32, k_cache', v_cache'[, (k_scales', v_scales')]).
+    (logits [B, V] fp32, k_cache', v_cache'[, (k_scales', v_scales')][, stats]).
     Intended to be jitted with the caches donated so XLA updates them in
     place. Besides the single-dispatch scan, this is the body the
     multi-tick decode megagraph (TPUEngine._mega_impl) iterates under
@@ -877,12 +907,10 @@ def decode_step(
             mask = mask & (cols > (read_lengths[:, None] - cfg.sliding_window))
         mask = mask[:, None, :]  # [B, 1, C]
 
-    def block(x, layer):
-        if quant_cache:
-            lp, k_l, v_l, k_s, v_s = layer
-        else:
-            lp, k_l, v_l = layer
-            k_s = v_s = None
+    def block(carry, layer):
+        x, stats = carry
+        lp, k_l, v_l, *scales_l = layer
+        k_s, v_s = scales_l or (None, None)
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
         if quant_cache:
             kq, ks_new = quantize_kv(k_new[:, 0])
@@ -915,24 +943,17 @@ def decode_step(
             else:
                 attn = gqa_attention(q, k_l, v_l, mask)
         x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
-        x = x + _mlp(x, lp, cfg, moe_impl, qmm)
-        if quant_cache:
-            return x, (k_l, v_l, k_s, v_s)
-        return x, (k_l, v_l)
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        return (x, stats), (k_l, v_l, *((k_s, v_s) if quant_cache else ()))
 
-    if quant_cache:
-        k_scales, v_scales = cache_scales
-        x, (k_cache, v_cache, k_scales, v_scales) = jax.lax.scan(
-            block, x, (params["layers"], k_cache, v_cache, k_scales, v_scales)
-        )
-    else:
-        x, (k_cache, v_cache) = jax.lax.scan(
-            block, x, (params["layers"], k_cache, v_cache)
-        )
+    x, k_cache, v_cache, scales, stats = _scan_layers_over_cache(
+        block, x, params, k_cache, v_cache, cache_scales, cfg,
+        moe_mod.grouped_serves(B, cfg, moe_impl),
+    )
     logits = _final_logits(x[:, 0], params, cfg, qmm)
     if quant_cache:
-        return logits, k_cache, v_cache, (k_scales, v_scales)
-    return logits, k_cache, v_cache
+        return (logits, k_cache, v_cache, scales, *stats)
+    return (logits, k_cache, v_cache, *stats)
 
 
 def layer_segments(params: Params) -> Tuple[dict, ...]:
@@ -945,26 +966,81 @@ def layer_segments(params: Params) -> Tuple[dict, ...]:
     return (params["layers"],)
 
 
-def scan_segments(block, carry, segments):
+def _experts_apart(layers, apart: bool):
+    """A stacked layer tree as (the part a layer scan slices, the expert
+    stacks it keeps whole: none unless ``apart``)."""
+    whole = {
+        name: layers[name] for name in moe_mod.EXPERT_LEAVES
+        if apart and name in layers
+    }
+    return {k: v for k, v in layers.items() if k not in whole}, whole
+
+
+def _with_experts(lp, whole, l):
+    """A scanned layer's tree with the whole expert stacks beside it and
+    ``expert_layer``, its index into them (moe.moe_ffn_grouped reads it)."""
+    return {**lp, **whole, "expert_layer": l} if whole else lp
+
+
+def scan_segments(block, carry, segments, experts_whole: bool = False):
     """Run ``block(carry, (layer_params, l))`` over every layer of every
     segment in turn, one ``lax.scan`` a segment, the carry (the residual,
-    the page pools) going through all of them and the layer index ``l``
-    running on across segments. Returns (carry, what the blocks emitted,
-    stacked over all layers; None where they emit nothing)."""
+    the page pools, the expert counters) going through all of them and the
+    layer index ``l`` running on across segments. Returns (carry, what the
+    blocks emitted, stacked over all layers; None where they emit nothing).
+
+    ``experts_whole`` (a graph whose token count takes the grouped expert
+    path, moe.grouped_serves) keeps a segment's expert stacks OUT of the
+    scanned operands: the block gets them whole, ``[L, X, in, out]``, with
+    ``expert_layer``, the layer's index into them, and the grouped loop
+    reads ``w[l, e]`` where it lies. A scanned slice of them would be that
+    loop's operand, and so a copy of the layer's experts each layer."""
     first, emitted = 0, []
     for seg in segments:
         n = jax.tree.leaves(seg)[0].shape[0]
+        scanned, whole = _experts_apart(seg, experts_whole)
+
+        def layer_block(carry, layer, whole=whole, first=first):
+            lp, l = layer
+            return block(carry, (_with_experts(lp, whole, l - first), l))
+
         carry, ys = jax.lax.scan(
-            block, carry, (seg, jnp.arange(first, first + n))
+            layer_block, carry, (scanned, jnp.arange(first, first + n))
         )
         emitted.append(ys)
         first += n
     if emitted[0] is None:
         return carry, None
+    if len(emitted) == 1:
+        return carry, emitted[0]
     return carry, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *emitted)
 
 
-def _scan_layers_over_pool(block, x, layers, k_pool, v_pool, cache_scales):
+def _scan_layers_over_cache(block, x, params, k_cache, v_cache, cache_scales,
+                            cfg: ModelConfig, experts_whole: bool = False):
+    """The dense-cache graphs' layer scan: layer ``l``'s slices of the two
+    caches (and of an int8 cache's scales) are scanned operands and results,
+    the residual and the expert counters the carry.
+
+    ``block((x, stats), (layer_params, k_l, v_l, *scales_l))`` returns
+    ((x, stats), (k_l, v_l, *scales_l)). Returns (x, k_cache, v_cache,
+    scales-or-None, stats)."""
+    layers, whole = _experts_apart(params["layers"], experts_whole)
+
+    def layer_block(carry, layer):
+        lp, l, *caches = layer
+        return block(carry, (_with_experts(lp, whole, l), *caches))
+
+    xs = (layers, jnp.arange(k_cache.shape[0]), k_cache, v_cache,
+          *(cache_scales or ()))
+    (x, stats), (k_cache, v_cache, *scales) = jax.lax.scan(
+        layer_block, (x, zero_stats(cfg)), xs
+    )
+    return x, k_cache, v_cache, tuple(scales) or None, stats
+
+
+def _scan_layers_over_pool(block, x, params, k_pool, v_pool, cache_scales,
+                           cfg: ModelConfig, experts_whole: bool = False):
     """Run ``block`` over the layer stack with the page pools (and int8
     scales) as the scan CARRY: layer ``l`` reads and writes
     ``pool[l, page, row]`` in place. Scanning the pools as xs -> ys instead
@@ -972,14 +1048,15 @@ def _scan_layers_over_pool(block, x, layers, k_pool, v_pool, cache_scales):
     buffer cannot share the donated input's) — on a 16 GB chip that is the
     difference between Mistral-7B's 4096-row context fitting and not.
 
-    ``block((x, k_pool, v_pool, *scales), (layer_params, l))`` returns the
-    same carry. Returns (x, k_pool, v_pool, scales-or-None)."""
-    L = k_pool.shape[0]
-    carry = (x, k_pool, v_pool) + tuple(cache_scales or ())
-    (x, k_pool, v_pool, *scales), _ = jax.lax.scan(
-        block, carry, (layers, jnp.arange(L))
+    ``block((x, k_pool, v_pool, scales, stats), (layer_params, l))``
+    returns the same carry: ``scales`` the pair of an int8 pool or (),
+    ``stats`` from ``zero_stats(cfg)``. Returns (x, k_pool, v_pool,
+    scales-or-None, stats)."""
+    carry = (x, k_pool, v_pool, tuple(cache_scales or ()), zero_stats(cfg))
+    (x, k_pool, v_pool, scales, stats), _ = scan_segments(
+        block, carry, layer_segments(params), experts_whole
     )
-    return x, k_pool, v_pool, tuple(scales) or None
+    return x, k_pool, v_pool, scales or None, stats
 
 
 def chunk_pages(table_row, start, Tc: int, P: int):
@@ -1033,7 +1110,7 @@ def prefill_chunk_paged(
 
     ``cache_scales`` marks an int8 pool (rows quantize on write, the
     gathered view dequantizes). Returns (logits [1, Tc, V] fp32, k_pool',
-    v_pool'[, scales']).
+    v_pool'[, scales'][, stats]).
     """
     if cfg.mla:
         from . import latent
@@ -1057,7 +1134,7 @@ def prefill_chunk_paged(
     kv_tile = t if C_log % t == 0 else P
 
     def block(carry, layer):
-        x, k_pool, v_pool, *scales = carry
+        x, k_pool, v_pool, scales, stats = carry
         lp, l = layer
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
         if quant_pool:
@@ -1091,16 +1168,17 @@ def prefill_chunk_paged(
             sink=sink_rows,
         )
         x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
-        x = x + _mlp(x, lp, cfg, moe_impl, qmm)
-        return (x, k_pool, v_pool, *scales), None
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        return (x, k_pool, v_pool, tuple(scales), stats), None
 
-    x, k_pool, v_pool, scales = _scan_layers_over_pool(
-        block, x, params["layers"], k_pool, v_pool, cache_scales
+    x, k_pool, v_pool, scales, stats = _scan_layers_over_pool(
+        block, x, params, k_pool, v_pool, cache_scales, cfg,
+        moe_mod.grouped_serves(B * Tc, cfg, moe_impl),
     )
     logits = _final_logits(x, params, cfg, qmm)
     if quant_pool:
-        return logits, k_pool, v_pool, scales
-    return logits, k_pool, v_pool
+        return (logits, k_pool, v_pool, scales, *stats)
+    return (logits, k_pool, v_pool, *stats)
 
 
 def decode_step_paged(
@@ -1141,7 +1219,7 @@ def decode_step_paged(
     pages through the paged kernel with scales folded into the dots
     (AIOS_TPU_INT8_RAGGED=1, ops.paged_decode_attention_int8) or
     dequantizes a gathered per-slot view on the XLA path. Returns
-    (logits [B, V] fp32, k_pool', v_pool'[, (k_scales', v_scales')]).
+    (logits [B, V] fp32, k_pool', v_pool'[, (k_scales', v_scales')][, stats]).
 
     ``win_starts``/``sink_rows`` (window+sink KV compression,
     docs/ENGINE_PERF.md "Long-context tier"): slot b attends only rows
@@ -1198,7 +1276,7 @@ def decode_step_paged(
     ffn_scope = "moe" if cfg.num_experts else "ffn"
 
     def block(carry, layer):
-        x, k_pool, v_pool, *scales = carry
+        x, k_pool, v_pool, scales, stats = carry
         lp, l = layer
         # no scope of its own: one around the projection renumbers the
         # compiled graph's instructions, and the benchmark's records name
@@ -1266,17 +1344,18 @@ def decode_step_paged(
         with jax.named_scope("attn_out"):
             x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
         with jax.named_scope(ffn_scope):
-            x = x + _mlp(x, lp, cfg, moe_impl, qmm)
-        return (x, k_pool, v_pool, *scales), None
+            x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        return (x, k_pool, v_pool, tuple(scales), stats), None
 
-    x, k_pool, v_pool, scales = _scan_layers_over_pool(
-        block, x, params["layers"], k_pool, v_pool, cache_scales
+    x, k_pool, v_pool, scales, stats = _scan_layers_over_pool(
+        block, x, params, k_pool, v_pool, cache_scales, cfg,
+        moe_mod.grouped_serves(B, cfg, moe_impl),
     )
     with jax.named_scope("final_logits"):
         logits = _final_logits(x[:, 0], params, cfg, qmm)
     if quant_pool:
-        return logits, k_pool, v_pool, scales
-    return logits, k_pool, v_pool
+        return (logits, k_pool, v_pool, scales, *stats)
+    return (logits, k_pool, v_pool, *stats)
 
 
 def verify_step_paged(
@@ -1303,7 +1382,7 @@ def verify_step_paged(
     ``lengths[b] .. lengths[b]+T-1`` for every active slot.
     ``cache_scales`` marks an int8 pool.
 
-    Returns (logits [B, T, V] fp32, k_pool', v_pool'[, scales']).
+    Returns (logits [B, T, V] fp32, k_pool', v_pool'[, scales'][, stats]).
     """
     if cfg.mla:
         from . import latent
@@ -1344,7 +1423,7 @@ def verify_step_paged(
     cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
     def block(carry, layer):
-        x, k_pool, v_pool, *scales = carry
+        x, k_pool, v_pool, scales, stats = carry
         lp, l = layer
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
         if quant_pool:
@@ -1367,16 +1446,17 @@ def verify_step_paged(
             v_all = ops.gather_pages(v_pool, l, tables, cfg.head_dim)
         attn = gqa_attention(q, k_all, v_all, mask)
         x = x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row")
-        x = x + _mlp(x, lp, cfg, moe_impl, qmm)
-        return (x, k_pool, v_pool, *scales), None
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        return (x, k_pool, v_pool, tuple(scales), stats), None
 
-    x, k_pool, v_pool, scales = _scan_layers_over_pool(
-        block, x, params["layers"], k_pool, v_pool, cache_scales
+    x, k_pool, v_pool, scales, stats = _scan_layers_over_pool(
+        block, x, params, k_pool, v_pool, cache_scales, cfg,
+        moe_mod.grouped_serves(B * T, cfg, moe_impl),
     )
     logits = _final_logits(x, params, cfg, qmm)
     if quant_pool:
-        return logits, k_pool, v_pool, scales
-    return logits, k_pool, v_pool
+        return (logits, k_pool, v_pool, scales, *stats)
+    return (logits, k_pool, v_pool, *stats)
 
 
 def verify_step(
@@ -1419,7 +1499,7 @@ def verify_step(
     indeterminate: callers must not consume tokens from saturated slots
     (the batcher retires them at the cache end; ``generate`` stops
     consuming mid-dispatch). Returns (logits [B, T, V] fp32, k_cache',
-    v_cache'[, scales']).
+    v_cache'[, scales'][, stats]).
     """
     B, T = tokens.shape
     C = k_cache.shape[2]
@@ -1464,12 +1544,10 @@ def verify_step(
     cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     batch_idx = jnp.arange(B)[:, None]  # [B, 1] pairs with write_rows [B, T]
 
-    def block(x, layer):
-        if quant_cache:
-            lp, k_l, v_l, k_s, v_s = layer
-        else:
-            lp, k_l, v_l = layer
-            k_s = v_s = None
+    def block(carry, layer):
+        x, stats = carry
+        lp, k_l, v_l, *scales_l = layer
+        k_s, v_s = scales_l or (None, None)
         q, k_new, v_new = _project_qkv(x, lp, cfg, cos, sin, qmm)
         if quant_cache:
             kq, ks_new = quantize_kv(k_new)  # [B, T, KH, D], [B, T, KH]
@@ -1501,24 +1579,17 @@ def verify_step(
             else:
                 attn = gqa_attention(q, k_l, v_l, mask)
         x = x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row")
-        x = x + _mlp(x, lp, cfg, moe_impl, qmm)
-        if quant_cache:
-            return x, (k_l, v_l, k_s, v_s)
-        return x, (k_l, v_l)
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        return (x, stats), (k_l, v_l, *((k_s, v_s) if quant_cache else ()))
 
-    if quant_cache:
-        k_scales, v_scales = cache_scales
-        x, (k_cache, v_cache, k_scales, v_scales) = jax.lax.scan(
-            block, x, (params["layers"], k_cache, v_cache, k_scales, v_scales)
-        )
-    else:
-        x, (k_cache, v_cache) = jax.lax.scan(
-            block, x, (params["layers"], k_cache, v_cache)
-        )
+    x, k_cache, v_cache, scales, stats = _scan_layers_over_cache(
+        block, x, params, k_cache, v_cache, cache_scales, cfg,
+        moe_mod.grouped_serves(B * T, cfg, moe_impl),
+    )
     logits = _final_logits(x, params, cfg, qmm)
     if quant_cache:
-        return logits, k_cache, v_cache, (k_scales, v_scales)
-    return logits, k_cache, v_cache
+        return (logits, k_cache, v_cache, scales, *stats)
+    return (logits, k_cache, v_cache, *stats)
 
 
 # ---------------------------------------------------------------------------

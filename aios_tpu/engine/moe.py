@@ -1,22 +1,34 @@
 """Mixture-of-experts FFN: router + expert computation, TPU-first.
 
-Two implementations of the same math (top-k routed SwiGLU experts):
+What serves (top-k routed SwiGLU experts, one math, chosen from static
+shapes by ``grouped_serves``):
 
+  * ``moe_ffn_grouped`` — PREFILL token counts (a chunk or a bucket of
+    enough tokens, ``grouped_pays``): each held expert runs over the rows
+    routed to it and no others, a GROUP_TILE of them at a time in one flat
+    loop over (expert, tile) pairs, reading the stacked int8 expert weights
+    where they lie at (layer, expert). Exact and dropless. At 2 of 8 and 512
+    tokens that is 1,024-1,536 expert rows where every expert over every
+    token is 4,096.
   * ``moe_ffn_dense`` — every expert processes every token; per-token gate
     weights (zero for unselected experts) scale the outputs. Exact and
     dropless. Decode steps are weight-bandwidth-bound, and at serving batch
     sizes the routed set spans most experts anyway, so streaming all expert
-    weights is the honest cost — this is the serving path. The einsum
-    contracts over the expert axis, so under expert parallelism (experts
-    sharded on the mesh's ``ep`` axis) each device computes its local
-    experts and XLA inserts one psum over ``ep`` — no hand-written
-    collectives, same GSPMD recipe as the Megatron TP rules
-    (parallel/sharding.py).
+    weights is the honest cost — this is the DECODE path, the path of token
+    counts too small for a tile an expert, and every graph's under a
+    sharding plan. The einsum contracts over the expert axis, so under
+    expert parallelism (experts sharded on the mesh's ``ep`` axis) each
+    device computes its local experts and XLA inserts one psum over ``ep``
+    — no hand-written collectives, same GSPMD recipe as the Megatron TP
+    rules (parallel/sharding.py).
+  * ``moe_ffn_gather`` — the operator's opt-in for decode at few slots
+    (engine/engine.py resolves it): one gathered weight block a pick
+    instead of every expert's stream.
   * ``moe_ffn_dispatch`` — GShard-style capacity-based dispatch/combine
     one-hot einsums: tokens route to per-expert queues of ``capacity``
     slots, experts run a batched SwiGLU over their queues, outputs combine
     back weighted by the gates. FLOPs scale with k/num_experts instead of
-    num_experts — the training/prefill path at large token counts. Tokens
+    num_experts — the TRAINING forward's path at large token counts. Tokens
     beyond an expert's capacity are dropped (their contribution from that
     expert is zero), the standard training trade; with generous capacity
     the result is bit-identical to the dense path (tested).
@@ -27,9 +39,8 @@ parallelism, each layer divided over several chips). The router keeps its
 published width and top-k; every FFN here computes the part of the result
 that the HELD experts give for the tokens routed to them (``local_picks``),
 and what the absent ones would add is left out — on one chip the layer runs
-without its exchange. For such a layer at prefill token counts
-``moe_ffn_grouped`` is the exact dropless path: picks sorted by expert, each
-held expert run over its own rows only.
+without its exchange; ``moe_ffn_grouped`` lays out only the picks that
+landed here.
 
 Replaces: nothing in the reference — its only MoE access is the cloud
 qwen3:30b endpoint behind the api-gateway (api-gateway/src/main.rs:70-88).
@@ -313,17 +324,37 @@ def moe_ffn_dispatch(
 
 
 GROUP_TILE = 128  # rows of one expert's tile: the MXU's height
+# the leaves of an expert layer that hold one matrix an expert
+EXPERT_LEAVES = ("we_gateup", "we_gate", "we_up", "we_down")
 
 
 def grouped_pays(n_tok: int, cfg: ModelConfig) -> bool:
-    """Whether ``moe_ffn_grouped`` does less expert work than
-    dense-over-held for ``n_tok`` tokens: an expert's rows come in tiles of
-    GROUP_TILE, so it needs more tokens than a tile, and a token's picks
-    have to be a small share of the router's width (at Mixtral's 2 of 8 a
-    tile is nearly as full as the dense path's; that model keeps its path)."""
+    """Whether ``moe_ffn_grouped`` computes fewer expert rows than
+    dense-over-held for ``n_tok`` tokens, reckoned from static shapes: the
+    picks expected to land on a held expert, plus at most one part-filled
+    GROUP_TILE a held expert, against every held expert over every token.
+    At 2 of 8 with all held that is 2,048 against 4,096 rows for 512 tokens
+    and 1,280 against 1,024 for 128; at 8 of 256 with 16 held, 2,176 against
+    4,096 for 256 tokens and 2,112 against 2,048 for 128. A decode step's
+    few rows never pay."""
+    held = cfg.held_experts
+    picks = -(-n_tok * cfg.num_experts_per_tok * held // cfg.num_experts)
+    return picks + held * GROUP_TILE < held * n_tok
+
+
+def grouped_serves(
+    n_tok: int, cfg: ModelConfig, moe_impl: Optional[str] = None,
+    allow_dispatch: bool = False,
+) -> bool:
+    """Whether a graph over ``n_tok`` tokens runs its expert layers through
+    ``moe_ffn_grouped``: no path forced, not the training forward (its loop
+    runs a data-dependent number of tiles, which reverse-mode
+    differentiation cannot unroll), and the path pays. model.ffn and the
+    layer scans that hand it the expert stacks whole ask the same
+    question."""
     return (
-        n_tok > GROUP_TILE
-        and cfg.num_experts_per_tok * 8 <= cfg.num_experts
+        cfg.moe and not moe_impl and not allow_dispatch
+        and grouped_pays(n_tok, cfg)
     )
 
 
@@ -335,14 +366,19 @@ def moe_ffn_grouped(
     """Exact dropless MoE FFN for LARGE token counts; returns (out, aux,
     ``pick_stats``).
 
-    The picks that fall on a held expert are sorted by expert; each held
-    expert then runs over ITS rows only, a GROUP_TILE of them at a time (a
-    loop whose trip count is the expert's own row count, so no capacity is
-    fixed and no pick is dropped), and adds its gated result at the rows'
-    tokens. Work follows the picks that landed here — 1/32 of
-    dense-over-held at 8 of 256 picked and 16 held — where the dense path
-    runs every held expert over every token. The expert's weights stream
-    once, as int8, whatever its row count."""
+    The picks that fall on a held expert are laid out by expert, each
+    expert's rows padded to whole GROUP_TILEs; ONE flat loop then runs over
+    the tiles that hold a pick (a trip count read from the picks, so no
+    capacity is fixed and no pick is dropped): tile ``i`` belongs to the
+    expert whose tiles it falls among, and its three matrix products read
+    that expert's int8 weights WHERE THEY LIE. ``lp``'s expert leaves may be
+    one layer's ``[X, in, out]`` or, with ``lp["expert_layer"]`` the layer's
+    index into them, the whole stacks ``[L, X, in, out]`` (the layer scans
+    hand them so: a layer's slice taken by the scan would become the loop's
+    operand, and a copy); the tile's dot indexes ``w[l, e]`` itself. Each
+    token then adds up its picks' rows, gated, in float32. Work follows the
+    picks that landed here where the dense path runs every held expert over
+    every token; an expert's weights stream once a tile."""
     B, T, E = h.shape
     N, k, X, TM = B * T, cfg.num_experts_per_tok, cfg.held_experts, GROUP_TILE
     F = cfg.expert_dim
@@ -351,57 +387,68 @@ def moe_ffn_grouped(
     weights, idx_here, here = local_picks(weights, idx, cfg)
     if here is None:
         here = jnp.ones(idx.shape, jnp.bool_)
-    key = jnp.where(here, idx_here, X).reshape(N * k)  # absent experts last
-    order = jnp.argsort(key, stable=True)
-    pad = jnp.zeros((TM,), jnp.int32)  # a tile's slice never clamps
-    tok = jnp.concatenate([(order // k).astype(jnp.int32), pad])
-    gate = jnp.concatenate(
-        [weights.reshape(N * k)[order], pad.astype(weights.dtype)]
+    key = jnp.where(here, idx_here, X).reshape(N * k)  # absent: no expert
+    onehot = jax.nn.one_hot(key, X, dtype=jnp.int32)  # [N*k, X]
+    counts = jnp.sum(onehot, axis=0)
+    # a pick's place among its expert's rows, in token order
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=-1)
+    tiles = (counts + TM - 1) // TM
+    tile_end = jnp.cumsum(tiles)  # [X] tiles up to and with each expert's
+    n_tiles = tile_end[-1]
+    first_row = (tile_end - tiles) * TM
+    # every pick a row of its own; the picks of absent experts off the end
+    M = -(-N * k // TM) * TM + X * TM
+    pos = jnp.where(
+        key < X, first_row[jnp.minimum(key, X - 1)] + rank, M
+    ).astype(jnp.int32)
+    src = jnp.full((M,), N, jnp.int32).at[pos].set(
+        jnp.arange(N * k, dtype=jnp.int32) // k, mode="drop"
     )
-    counts = jnp.sum(jax.nn.one_hot(key, X, dtype=jnp.int32), axis=0)  # [X]
-    starts = jnp.cumsum(counts) - counts
-    lane = jnp.arange(TM)
+    x_rows = jnp.concatenate([flat, jnp.zeros((1, E), flat.dtype)])[src]
 
-    def qdot(x, w):  # [TM, in] @ one expert's [in, out] (int8 leaf or dense)
+    whole = "expert_layer" in lp
+    l = lp["expert_layer"] if whole else 0
+
+    def at(a, e):  # expert e's matrix (or scales) where the stack holds it
+        stack = a if whole else a[None]
+        return jax.lax.dynamic_slice(
+            stack, (l, e, 0, 0), (1, 1) + stack.shape[2:]
+        )[0, 0]
+
+    def qdot(x, w, e):  # [TM, in] @ expert e's [in, out]; float32 out
         if isinstance(w, dict):
             y = jnp.einsum(
-                "ni,io->no", x, w["q"], preferred_element_type=jnp.float32
+                "ni,io->no", x, at(w["q"], e),
+                preferred_element_type=jnp.float32,
             )
-            return (y * w["s"][0]).astype(x.dtype)
-        return x @ w
+            return y * at(w["s"], e)[0]
+        return jnp.einsum(
+            "ni,io->no", x, at(w, e), preferred_element_type=jnp.float32
+        )
 
-    fused = "we_gateup" in lp
-    xs = (
-        (lp["we_gateup"],) if fused else (lp["we_gate"], lp["we_up"])
-    ) + (lp["we_down"], starts, counts)
+    def tile(i, y_rows):
+        e = jnp.sum(i >= tile_end, dtype=jnp.int32)  # the tile's expert
+        x = jax.lax.dynamic_slice(x_rows, (i * TM, 0), (TM, E))
+        if "we_gateup" in lp:  # fused serving layout (quantize_params)
+            gu = qdot(x, lp["we_gateup"], e).astype(x.dtype)
+            a, u = gu[:, :F], gu[:, F:]
+        else:
+            a = qdot(x, lp["we_gate"], e).astype(x.dtype)
+            u = qdot(x, lp["we_up"], e).astype(x.dtype)
+        z = jax.nn.silu(a.astype(jnp.float32)).astype(x.dtype) * u
+        return jax.lax.dynamic_update_slice(
+            y_rows, qdot(z, lp["we_down"], e), (i * TM, 0)
+        )
 
-    def expert(out, xs):
-        *w_in, w_down, start, count = xs
-
-        def tile(t, out):
-            at = start + t * TM
-            rows = jax.lax.dynamic_slice(tok, (at,), (TM,))
-            g = jnp.where(
-                t * TM + lane < count,
-                jax.lax.dynamic_slice(gate, (at,), (TM,)), 0.0,
-            )
-            x = flat[rows]  # [TM, E]
-            if fused:
-                gu = qdot(x, w_in[0])
-                a, u = gu[:, :F], gu[:, F:]
-            else:
-                a, u = qdot(x, w_in[0]), qdot(x, w_in[1])
-            z = jax.nn.silu(a.astype(jnp.float32)).astype(x.dtype) * u
-            y = qdot(z, w_down).astype(jnp.float32)
-            # a token picks an expert at most once: live rows never collide
-            return out.at[rows].add(y * g[:, None])
-
-        n_tiles = (count + TM - 1) // TM
-        return jax.lax.fori_loop(0, n_tiles, tile, out), n_tiles
-
-    out, tiles = jax.lax.scan(expert, jnp.zeros((N, E), jnp.float32), xs)
+    y_rows = jax.lax.fori_loop(
+        0, n_tiles, tile, jnp.zeros((M, E), jnp.float32)
+    )
+    # a token's result: its picks' rows, gated in float32 (a pick of an
+    # absent expert reads nothing at weight zero)
+    picked = y_rows.at[pos].get(mode="fill", fill_value=0).reshape(N, k, E)
+    out = jnp.einsum("nk,nke->ne", weights.astype(jnp.float32), picked)
     aux = load_balance_aux(probs, idx, cfg.num_experts)
     return (
         out.astype(h.dtype).reshape(B, T, E), aux,
-        pick_stats(here, jnp.sum(tiles, dtype=jnp.int32) * TM),
+        pick_stats(here, n_tiles * TM),
     )

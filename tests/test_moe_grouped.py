@@ -1,0 +1,201 @@
+"""The grouped expert path (moe.moe_ffn_grouped) at Mixtral's shape of
+routing: softmax top-2 of 8, every expert held. Prefill token counts run each
+expert over the rows routed to it only, reading the stacked expert weights in
+place at (layer, expert); the dense path (every expert over every token) is
+the reference, and which of the two serves follows the static shapes alone
+(moe.grouped_pays).
+
+These live beside tests/test_moe.py and not in it because that module is the
+slow tier as a whole (its ``pytestmark``): the cases here are small and run in
+the commit gate.
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from aios_tpu.engine import model as M
+from aios_tpu.engine import moe
+from aios_tpu.engine.config import ModelConfig
+from aios_tpu.engine.engine import TPUEngine
+
+CFG = ModelConfig(
+    name="tiny-top2of8", vocab_size=512, hidden_size=64, intermediate_size=128,
+    num_layers=3, num_heads=4, num_kv_heads=2, head_dim=16, max_context=512,
+    num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+)
+TOL = 0.02  # of the largest output: tests/test_latent.py's, 5 x bf16's 2^-8
+TAKES_ALL, TAKES_NONE = 3, 5
+
+
+@functools.lru_cache(maxsize=None)
+def _layers(leaves: str):
+    """The three layers' stacked trees in one of the serving layouts."""
+    params = M.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.bfloat16)
+    if leaves != "bf16":
+        params = M.quantize_params(params, fuse=leaves == "int8-fused")
+    layers = params["layers"]
+    assert ("we_gateup" in layers) == (leaves == "int8-fused")
+    assert isinstance(layers["we_down"], dict) == (leaves != "bf16")
+    return layers
+
+
+def _forced(layers):
+    """The same layers with every token's first pick forced onto one expert
+    and another never picked (the rows' first value is a constant 4)."""
+    w = layers["w_router"].astype(jnp.float32)
+    w = w.at[:, 0, TAKES_ALL].set(50.0).at[:, 0, TAKES_NONE].set(-50.0)
+    return {**layers, "w_router": w.astype(layers["w_router"].dtype)}
+
+
+@jax.jit
+def _dense(h, layers, l):
+    lp = jax.tree.map(lambda a: a[l], layers)
+    out, _, stats = moe.moe_ffn_dense(h, lp, CFG, with_stats=True)
+    _, _, idx = moe.route(h[0], lp["w_router"], CFG)
+    return out, stats, idx
+
+
+@jax.jit
+def _grouped(h, layers, l):
+    """As the layer scans hand it over: the layer's own leaves sliced, the
+    expert stacks whole with the layer's index beside them."""
+    scanned, whole = M._experts_apart(layers, True)
+    lp = M._with_experts(jax.tree.map(lambda a: a[l], scanned), whole, l)
+    out, _, stats = moe.moe_ffn_grouped(h, lp, CFG)
+    return out, stats
+
+
+def _one_pick(h_row, lp, e, gate):
+    """What expert ``e`` adds for one normed row at weight ``gate``, from the
+    dequantized weights in float32."""
+    def w(name):
+        leaf = jax.tree.map(lambda a: a[e], lp[name])
+        if isinstance(leaf, dict):
+            return leaf["q"].astype(jnp.float32) * leaf["s"]
+        return leaf.astype(jnp.float32)
+
+    x = h_row.astype(jnp.float32)
+    if "we_gateup" in lp:
+        gu = x @ w("we_gateup")
+        g, u = gu[:CFG.expert_dim], gu[CFG.expert_dim:]
+    else:
+        g, u = x @ w("we_gate"), x @ w("we_up")
+    return gate * ((jax.nn.silu(g) * u) @ w("we_down"))
+
+
+@pytest.mark.parametrize("routing", ["free", "one-takes-all"])
+@pytest.mark.parametrize("n_tok", [130, 256, 300, 512])
+@pytest.mark.parametrize("leaves", ["bf16", "int8-fused", "int8"])
+def test_grouped_matches_dense_at_top2_of_8(leaves, n_tok, routing):
+    """Grouped against dense on each of three stacked layers read at
+    ``expert_layer`` 0, 1, 2: the same picks and weights, float32
+    accumulation in both, a different order of adding the experts' parts.
+    130 and 300 tokens leave a tile part-filled; forced routing gives one
+    expert every token (more than one tile of rows) and another none."""
+    layers = _layers(leaves)
+    if routing == "one-takes-all":
+        layers = _forced(layers)
+    h = jax.random.normal(jax.random.PRNGKey(n_tok), (1, n_tok, 64), jnp.bfloat16)
+    h = h.at[..., 0].set(4.0)
+    for l in range(CFG.num_layers):
+        want, s_dense, idx = _dense(h, layers, l)
+        got, s_grouped = _grouped(h, layers, l)
+        want = np.asarray(want, np.float32)
+        tol = TOL * np.abs(want).max()
+        assert np.abs(np.asarray(got, np.float32) - want).max() < tol, l
+        total, local, rows = s_grouped.tolist()
+        assert total == local == 2 * n_tok == s_dense.tolist()[0]
+        assert s_dense.tolist()[2] == 8 * n_tok
+        counts = np.bincount(np.asarray(idx).ravel(), minlength=8)
+        assert rows == int(np.sum(-(-counts // moe.GROUP_TILE)) * moe.GROUP_TILE)
+        if routing == "one-takes-all":
+            assert counts[TAKES_ALL] == n_tok and counts[TAKES_NONE] == 0
+    # the control: ONE pick of one token dropped is a fault this tolerance sees
+    lp = jax.tree.map(lambda a: a[l], layers)
+    _, weights, idx = moe.route(h[0], lp["w_router"], CFG)
+    dropped = np.asarray(_one_pick(h[0, 7], lp, idx[7, 0], weights[7, 0]))
+    assert np.abs(dropped).max() > tol
+
+
+def test_layers_are_read_at_their_own_index():
+    """The in-place index is the layer's: the three layers' results differ,
+    and each equals the grouped path over that layer's slice alone."""
+    layers = _layers("int8-fused")
+    h = jax.random.normal(jax.random.PRNGKey(1), (1, 256, 64), jnp.bfloat16)
+    outs = [np.asarray(_grouped(h, layers, l)[0], np.float32) for l in range(3)]
+    assert np.abs(outs[0] - outs[1]).max() > 0.1 * np.abs(outs[0]).max()
+    assert np.abs(outs[1] - outs[2]).max() > 0.1 * np.abs(outs[1]).max()
+    for l in range(3):
+        alone, _, _ = moe.moe_ffn_grouped(
+            h, jax.tree.map(lambda a: a[l], layers), CFG)
+        np.testing.assert_array_equal(np.asarray(alone, np.float32), outs[l])
+
+
+MIXTRAL = dataclasses.replace(
+    CFG, name="mixtral-widths", hidden_size=4096, moe_intermediate_size=14336)
+PANGU = dataclasses.replace(
+    CFG, name="pangu-share", hidden_size=7680, moe_intermediate_size=2048,
+    num_experts=256, num_experts_per_tok=8, experts_held=16,
+    moe_scoring="sigmoid")
+
+
+@pytest.mark.parametrize("cfg, n_tok, grouped", [
+    (MIXTRAL, 512, True),   # 1,024 picks + 8 part tiles against 4,096 rows
+    (MIXTRAL, 256, True),   # 512 + 1,024 against 2,048
+    (MIXTRAL, 128, False),  # 256 + 1,024 against 1,024
+    (MIXTRAL, 8, False),    # a decode step
+    (PANGU, 512, True),     # 256 + 2,048 against 8,192
+    (PANGU, 256, True),     # 128 + 2,048 against 4,096
+    (PANGU, 128, False),    # 64 + 2,048 against 2,048
+    (PANGU, 32, False),     # a decode step
+], ids=lambda v: getattr(v, "name", str(v)))
+def test_grouped_pays_follows_the_static_shapes(cfg, n_tok, grouped):
+    assert moe.grouped_pays(n_tok, cfg) is grouped
+    assert moe.grouped_serves(n_tok, cfg) is grouped
+    # a forced path (the engine names "dense" under a sharding plan, where
+    # the expert axis may be sharded over ep) and the training forward keep
+    # theirs, whatever the shapes
+    assert not moe.grouped_serves(n_tok, cfg, "dense")
+    assert not moe.grouped_serves(n_tok, cfg, None, allow_dispatch=True)
+
+
+def _greedy(engine, prompt, chunk):
+    pc = engine.start_chunked_prefill(0, prompt, temperature=0.0, chunk=chunk)
+    first = None
+    while first is None:
+        first = pc.step()
+    return [first] + [int(t) for t in engine.step(8)[:, 0]]
+
+
+def test_chunked_prefill_through_grouped_yields_the_dense_tokens(monkeypatch):
+    """A 300-token prompt admitted in a 256-token chunk (grouped: 512 picks
+    and 8 part tiles against 2,048 rows) and a final 64-token bucket (dense),
+    then greedy decode: the tokens of an engine forced onto the dense path.
+    The counters say which path ran: dense-over-all computes exactly 4 rows
+    a pick (8 experts over every token, 2 picks a token)."""
+    cfg = dataclasses.replace(CFG, num_layers=2)
+    params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    prompt = (np.arange(1, 301) * 7 % 500 + 1).tolist()
+    kw = dict(num_slots=2, max_context=512, cache_dtype=jnp.float32,
+              paged_pool_rows=3 * 512)
+    auto = TPUEngine(cfg, params, **kw)
+    assert auto._prefill_moe_impl is None and auto.counts_picks
+    got = _greedy(auto, prompt, 256)
+    picks, local, rows = (auto.moe_picks_total, auto.moe_picks_local,
+                          auto.moe_expert_rows)
+    auto.close()
+    # chunk 256 + final 64 + 8 steps of 2 slots, 2 picks a token, 2 layers
+    assert picks == local == 2 * 2 * (256 + 64 + 8 * 2)
+    assert picks < rows < 4 * picks
+    monkeypatch.setenv("AIOS_TPU_MOE_IMPL", "dense")
+    dense = TPUEngine(cfg, params, **kw)
+    assert dense._prefill_moe_impl == "dense"
+    want = _greedy(dense, prompt, 256)
+    assert dense.moe_expert_rows == 4 * dense.moe_picks_total == 4 * picks
+    dense.close()
+    assert got == want

@@ -408,6 +408,27 @@ def test_aot_mistral_serving_graphs_compile_and_fit(
         assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"
 
 
+def _bench_model(config_name, arch_file, context):
+    """(program ModelConfig, shapes of the benchmark's serving tree) of a
+    committed configuration, built as `benchmark/harness/manager.py` does."""
+    import json
+    import sys
+
+    from aios_tpu.engine.config import ModelConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.harness.manifest import load_file
+
+    arch = load_file(os.path.join(root, "benchmark", "archs", arch_file),
+                     "benchmark_arch")
+    with open(os.path.join(root, "benchmark", "configs", config_name)) as fh:
+        config = json.load(fh)
+    cfg = ModelConfig(**arch.model_fields(config, context))
+    return cfg, jax.eval_shape(lambda: arch.build_params(arch.dims_of(config), 1))
+
+
 # --- the latent-attention configuration of the benchmark (PR 27) -----------
 
 
@@ -419,29 +440,14 @@ def test_aot_latent_serving_graphs_compile_and_fit(rep_sharding, monkeypatch):
     Each must fit beside the 5.1 GB of weights and the 3.5 GB latent pool,
     and none may copy a whole pool array (the one-page chunk did, through
     the scatter's way: engine/latent.py `_write_chunk`)."""
-    import json
-
     from aios_tpu import backend, ops
     from aios_tpu.engine import model as M
-    from aios_tpu.engine.config import ModelConfig
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    import sys
-
-    sys.path.insert(0, root)
-    from benchmark.harness.manifest import load_file
-
-    arch = load_file(os.path.join(root, "benchmark", "archs", "pangu_ultra_moe.py"),
-                     "benchmark_arch")
-    with open(os.path.join(root, "benchmark", "configs",
-                           "openpangu-ultra-moe-int8-ep16-d5.json")) as fh:
-        config = json.load(fh)
-    d = arch.dims_of(config)
-    cfg = ModelConfig(**arch.model_fields(config, 16384))
+    cfg, shapes = _bench_model("openpangu-ultra-moe-int8-ep16-d5.json",
+                               "pangu_ultra_moe.py", 16384)
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     rep = rep_sharding
-    params = jax.tree.map(lambda a: sds(rep, a.shape, a.dtype),
-                          jax.eval_shape(lambda: arch.build_params(d, 1)))
+    params = jax.tree.map(lambda a: sds(rep, a.shape, a.dtype), shapes)
     slots, blocks, pages = 32, 128, 33 * 128 + 1
     pools = tuple(sds(rep, (cfg.num_layers, pages, 128, w), jnp.bfloat16)
                   for w in cfg.kv_row_dims)
@@ -479,3 +485,62 @@ def test_aot_latent_serving_graphs_compile_and_fit(rep_sharding, monkeypatch):
         live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
         assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"
+
+
+# --- the grouped expert path reads the stacked experts in place (PR 28) -----
+
+
+@pytest.mark.parametrize("config_name, arch_file, context, pages, chunk", [
+    # the 512-token chunk of `mixtral-d6-longprompt`: (8 + 1) x 4096 rows
+    ("mixtral-8x7b-int8-d6.json", "mistral.py", 4096, 9 * 32 + 1, 512),
+    # the bucket-256 tail prefill of `pangu-ultra-ep16-agents32`
+    ("openpangu-ultra-moe-int8-ep16-d5.json", "pangu_ultra_moe.py", 16384,
+     33 * 128 + 1, 256),
+])
+def test_aot_grouped_experts_are_read_in_place(
+    rep_sharding, monkeypatch, config_name, arch_file, context, pages, chunk
+):
+    """A prefill chunk at a token count the grouped expert path serves
+    (moe.grouped_pays), at the benchmark's widths: the graph compiles for the
+    v5e, fits beside the weights, and makes nothing as large as ONE layer's
+    expert stack (1.41 GB at Mixtral's widths, 755 MB at the Pangu share's):
+    not in its temporaries, and no operation's result has the element count
+    of a layer's gate-up or down stack (the copy a scanned slice of them was,
+    `_dynamic-slice_bitcast_fusion s8[16,7680,4096]` in PR 27's trace), nor is
+    a whole stack copied."""
+    from aios_tpu import backend
+    from aios_tpu.engine import model as M
+    from aios_tpu.engine import moe
+
+    cfg, shapes = _bench_model(config_name, arch_file, context)
+    assert moe.grouped_pays(chunk, cfg)
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    rep = rep_sharding
+    params = jax.tree.map(lambda a: sds(rep, a.shape, a.dtype), shapes)
+    pools = tuple(sds(rep, (cfg.num_layers, pages, 128, w), jnp.bfloat16)
+                  for w in cfg.kv_row_dims)
+    i32 = lambda *shape: sds(rep, shape, jnp.int32)  # noqa: E731
+
+    def chunk_graph(p, k, v, toks, start, row):
+        return M.prefill_chunk_paged(p, cfg, toks, start, k, v, row)
+
+    compiled = jax.jit(chunk_graph, donate_argnums=(1, 2)).lower(
+        params, *pools, i32(1, chunk), i32(), i32(context // 128)
+    ).compile()
+    stacks = [params["layers"][n]["q"] for n in moe.EXPERT_LEAVES
+              if n in params["layers"]]
+    layer_bytes = sum(int(np.prod(a.shape[1:])) for a in stacks)
+    layer_elems = {int(np.prod(a.shape[1:])) for a in stacks}
+    stack_elems = {int(np.prod(a.shape)) for a in stacks}
+    made = [
+        (op, res) for op, res in _hlo_results(compiled.as_text())
+        if layer_elems & set(res) or (op == "copy" and stack_elems & set(res))
+    ]
+    assert made == [], f"results as large as a layer's experts: {made[:4]}"
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes, (
+        f"{mem.temp_size_in_bytes / 1e9:.2f} GB of temporaries against "
+        f"{layer_bytes / 1e9:.2f} GB of experts a layer")
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert live < 15.75e9, f"{live / 1e9:.2f} GB live"
