@@ -75,16 +75,9 @@ def _default_sections() -> Dict[str, Dict[str, Any]]:
             "draft_model": "",
             "spec_reprobe_secs": "",
             # pipelined decode loop: dispatch N+1 enqueues while dispatch
-            # N's tokens are emitted/detokenized (docs/ENGINE_PERF.md);
-            # unified_step folds every decode chunk size into ONE
-            # dynamic-n XLA graph (greedy-identical; opt-in). "" = off.
+            # N's tokens are emitted/detokenized (docs/ENGINE_PERF.md).
+            # "" = off.
             "decode_pipeline": "",
-            "unified_step": "",
-            # device-resident multi-tick decode megagraph: up to this
-            # many decode ticks per dispatch with on-device sampling,
-            # stop detection and budget/cap checks (early exit when no
-            # slot needs another tick; docs/ENGINE_PERF.md). "" = off.
-            "mega_ticks": "",
             # grammar jump-ahead for constrained/structured decoding
             # (multi-token forced runs in one dispatch; default ON) and
             # the radix-tree prefix index (default ON) — tri-state
@@ -243,10 +236,9 @@ def serving_env(cfg: "AiosConfig") -> Dict[str, str]:
         put("AIOS_TPU_DRAFT_MODEL", str(m["draft_model"]))
     # tri-state decode-loop knobs: "" = unset (config/engine defaults
     # apply); an explicit false forwards too, so config can turn OFF a
-    # ModelConfig.decode_pipeline/unified_step default
+    # ModelConfig.decode_pipeline default
     for cfg_key, env_key in (
         ("decode_pipeline", "AIOS_TPU_DECODE_PIPELINE"),
-        ("unified_step", "AIOS_TPU_UNIFIED_STEP"),
         ("jump_ahead", "AIOS_TPU_JUMP_AHEAD"),
         ("prefix_radix", "AIOS_TPU_PREFIX_RADIX"),
     ):
@@ -291,9 +283,6 @@ def serving_env(cfg: "AiosConfig") -> Dict[str, str]:
         ("kv_sink_pages", "AIOS_TPU_KV_SINK_PAGES", False),
         ("kv_window_pages", "AIOS_TPU_KV_WINDOW_PAGES", False),
         ("seq_prefill_min", "AIOS_TPU_SEQ_PREFILL_MIN", True),
-        # an explicit 0 forwards (megagraph OFF, overriding a
-        # ModelConfig.mega_ticks default)
-        ("mega_ticks", "AIOS_TPU_MEGA_TICKS", True),
         # SLO autoscaler policy (serving/autoscale.py; only meaningful
         # with autoscale = true above)
         ("autoscale_max_replicas", "AIOS_TPU_AUTOSCALE_MAX_REPLICAS",

@@ -32,8 +32,8 @@ import numpy as np
 
 from ..analysis.locks import make_lock
 from .engine import (
-    JUMP_BUCKETS, MEGA_STOP_SLOTS, ChunkedPrefill, PendingDecode,
-    TPUEngine, _env_flag,
+    ADMIT_DECODE_STEPS, DECODE_STEPS, JUMP_BUCKETS, ChunkedPrefill,
+    PendingDecode, TPUEngine, _env_flag,
 )
 from .paged import PoolExhausted
 from .sampling import GREEDY_EPS
@@ -239,10 +239,11 @@ class ContinuousBatcher:
     def __init__(
         self,
         engine: TPUEngine,
-        chunk_steps: int = 16,  # ~70ms/dispatch TinyLlama, ~300ms Mistral on
-        # v5e; bigger chunks amortize dispatch overhead (+15% measured), and
-        # the admit_chunk_steps fallback keeps admission latency low
-        admit_chunk_steps: int = 2,
+        # ~70ms/dispatch TinyLlama, ~300ms Mistral on v5e; bigger chunks
+        # amortize dispatch overhead (+15% measured), and the
+        # admit_chunk_steps fallback keeps admission latency low
+        chunk_steps: int = DECODE_STEPS,
+        admit_chunk_steps: int = ADMIT_DECODE_STEPS,
         prefill_chunk: Optional[int] = None,  # None -> the engine's default
         speculative: bool = False,  # n-gram speculative decode dispatches
         spec_draft_len: int = 7,
@@ -402,8 +403,8 @@ class ContinuousBatcher:
         # verify dispatch instead of one masked dispatch each. Greedy
         # streams are token-identical to the per-step path (forced
         # tokens of sampled streams too; the sampled remainder draws a
-        # shifted key chain, the unified_step caveat). Unsupported —
-        # like speculative verify — on a dp-replicated page pool.
+        # shifted key chain). Unsupported — like speculative verify — on
+        # a dp-replicated page pool.
         if jump_ahead is None:
             jump_ahead = _env_flag("AIOS_TPU_JUMP_AHEAD")
         if jump_ahead is None:
@@ -457,13 +458,10 @@ class ContinuousBatcher:
         # a non-default chunk_steps would otherwise compile for seconds on
         # the scheduler thread at first dispatch, stalling live requests).
         # AOT — compile_step_fn lowers without dispatching, so attaching a
-        # batcher never perturbs engine state. Largest size first keeps
-        # unified_step engines on ONE dynamic-n graph. A never-warmed
-        # engine (tests, lazy callers) is left lazy.
+        # batcher never perturbs engine state. A never-warmed engine
+        # (tests, lazy callers) is left lazy.
         if engine._step_fns:
-            for n in sorted(
-                {self.admit_chunk_steps, self.chunk_steps}, reverse=True
-            ):
+            for n in {self.admit_chunk_steps, self.chunk_steps}:
                 engine.compile_step_fn(n)
             if self.speculative:
                 for n in {self.admit_chunk_steps, self.chunk_steps}:
@@ -476,15 +474,6 @@ class ContinuousBatcher:
                     engine.compile_draft_spec_fn(n, self.spec_draft_len)
                 if engine.draft is not None:
                     engine.compile_draft_ingest_fns()
-            if engine.mega_ticks:
-                # the megagraph windows this batcher can dispatch: each
-                # step size capped by the armed K, bucketed to its power
-                # of two (warmup already covered 1..mega_bucket(K), so
-                # these are no-ops unless the batcher's sizes diverge)
-                for n in {self.admit_chunk_steps, self.chunk_steps}:
-                    engine.compile_mega_fn(
-                        engine.mega_bucket(min(n, engine.mega_ticks))
-                    )
             if self.jump_ahead and "masked" in engine._step_fns:
                 # constrained serving was declared at warmup (the masked
                 # graph is the same signal json-mode deployments use):
@@ -1061,24 +1050,6 @@ class ContinuousBatcher:
             for ev in tick.evs:
                 ev["dev_us"] = round(dev * 1e6, 1)
         lengths = tick.pending.lengths
-        if getattr(lengths, "ndim", 1) == 2:
-            # megagraph dispatch: per-tick length snapshots [k, S] and
-            # k REAL ticks of tokens — each row retires against the
-            # lengths AS OF its own tick (a context-cap finish must fire
-            # on the tick that hit the cap, not the window's last), and
-            # the flight-recorder events' n joins late with the real k
-            # (never the requested K when the device loop exited early)
-            for ev in tick.evs:
-                ev["n"] = tick.pending.ticks
-            for row, lrow in zip(tokens, lengths):
-                for slot, live in tick.lives.items():
-                    if live.done:
-                        continue
-                    self._emit(
-                        live, int(row[slot]), slot_len=int(lrow[slot])
-                    )
-            self._progressed(tick.lives.values())
-            return
         for row in tokens:
             for slot, live in tick.lives.items():
                 if live.done:
@@ -1092,27 +1063,6 @@ class ContinuousBatcher:
         now = time.monotonic()
         for live in lives:
             live.progress_at = now
-
-    def _mega_operands(
-        self, slots: Dict[int, "_Live"]
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """Device operands for one megagraph window: per-slot stop ids
-        ``[S, MEGA_STOP_SLOTS]`` (pad -1 — BEST-EFFORT, the device
-        checks only the first MEGA_STOP_SLOTS ids while ``_emit`` stays
-        authoritative over the full set) and remaining token budgets
-        ``[S]`` (0 for slots with no live request, so an empty column
-        can never hold the device loop open)."""
-        eng = self.engine
-        stops = np.full((eng.num_slots, MEGA_STOP_SLOTS), -1, np.int32)
-        budgets = np.zeros((eng.num_slots,), np.int32)
-        for slot, live in slots.items():
-            if live.done:
-                continue
-            ids = tuple(live.req.stop_ids)[:MEGA_STOP_SLOTS]
-            if ids:
-                stops[slot, : len(ids)] = ids
-            budgets[slot] = max(live.req.max_tokens - live.produced, 0)
-        return stops, budgets
 
     def _flush_pending(self, cause: str) -> None:
         """Consume the in-flight pipelined dispatch NOW. Called whenever
@@ -1945,9 +1895,6 @@ class ContinuousBatcher:
                 self._spec_emit(slots, proposer, tokens, counts, proposed,
                                 gap, t0)
             return
-        if self.engine.mega_ticks:
-            self._mega_tick(slots, n)
-            return
         if self.pipeline:
             # depth-2 double buffer: hand dispatch N+1 to the engine's
             # dispatch worker, THEN consume dispatch N — the host's
@@ -2042,81 +1989,3 @@ class ContinuousBatcher:
                 rec.device_us += est_us
         self._progressed(slots[s_] for s_ in consumed)
         self._spec_measure(proposer, counts, consumed, proposed)
-
-    def _mega_tick(self, slots: Dict[int, _Live], n: int) -> None:
-        """Device-resident multi-tick window: ONE megagraph dispatch
-        runs up to min(n, mega_ticks) decode ticks with sampling,
-        stop/budget/cap checks on device and early exit the moment no
-        live slot needs another tick — the host round-trip (readback,
-        emit, recorder) amortizes over the k real ticks. Constrained and
-        speculative batches never reach here (their branches in _tick
-        return first): a constrained tick's mask depends on every
-        emitted token, so "a constrained tick is due" is realized as
-        routing, not as a device predicate. The window size equals the
-        plain loop's dispatch size, so the key fanout (split(key, K+1))
-        — and with it every sampled stream — matches the off arm
-        key-for-key."""
-        phase = self.phases.phase
-        window = min(n, self.engine.mega_ticks)
-        cap = self.engine.max_context - 1
-        stuck = [
-            live for slot, live in slots.items()
-            if not live.done and self.engine.slot_length(slot) >= cap
-        ]
-        if stuck:
-            # a slot already AT the context cap can never run a device
-            # tick (the loop's live predicate excludes it) — finish it
-            # here or a 0-tick dispatch would emit nothing and the
-            # scheduler would spin on it forever
-            with phase("batcher.emit"):
-                for live in stuck:
-                    self._finish(live)
-            slots = {s: l for s, l in slots.items() if not l.done}
-            if not slots:
-                return
-        stops, budgets = self._mega_operands(slots)
-        if self.pipeline:
-            prev = self._pending
-            with phase("batcher.dispatch"):
-                gap = self._note_dispatch()
-                handle = self.engine.mega_step_async(window, stops, budgets)
-                self._gap_mark = time.monotonic()
-            with phase("batcher.emit"):
-                # recorded with the REQUESTED window; _consume late-joins
-                # the real k (early exit) onto these events
-                evs = self._rec_dispatch(
-                    slots.values(), "decode", window, gap, pipelined=True,
-                    join_sample=False, graph="mega",
-                )
-                self._pending = _PendingTick(handle, slots, tuple(evs))
-                if prev is not None:
-                    self._consume(prev)
-            return
-        try:
-            with phase("batcher.dispatch"):
-                gap = self._note_dispatch()
-                t0 = time.monotonic()
-                tokens, lengths, k = self.engine.mega_step(
-                    window, stops, budgets
-                )
-                self._gap_mark = time.monotonic()
-        except PoolExhausted as e:
-            self._evict_longest(e.replica)
-            return
-        # judged against windows that ran as many REAL ticks
-        self._dispatched = ("mega", k)
-        with phase("batcher.emit"):
-            # k REAL ticks — never the requested window when the device
-            # loop exited early (the SLO/TPOT accounting contract)
-            self._rec_dispatch(
-                slots.values(), "decode", k, gap, self._gap_mark - t0,
-                graph="mega",
-            )
-            for row, lrow in zip(tokens, lengths):
-                for slot, live in list(slots.items()):
-                    if live.done:
-                        continue
-                    self._emit(
-                        live, int(row[slot]), slot_len=int(lrow[slot])
-                    )
-            self._progressed(slots.values())

@@ -81,20 +81,6 @@ class ModelConfig:
     # host phase overlaps device execution instead of idling it.
     # AIOS_TPU_DECODE_PIPELINE overrides at load time (docs/ENGINE_PERF.md).
     decode_pipeline: bool = False
-    # unified dynamic-step decode graph (engine/engine.py _unified_impl):
-    # one compiled fori_loop serves every decode chunk size instead of one
-    # scan graph per size. Greedy-identical; sampled sequences draw from a
-    # different key fanout. AIOS_TPU_UNIFIED_STEP overrides at load time.
-    unified_step: bool = False
-    # device-resident multi-tick decode megagraph (engine/engine.py
-    # _mega_impl): up to this many decode ticks run per dispatch inside
-    # one lax.while_loop — sampling, EOS/stop detection, per-slot budget
-    # and context-cap checks all on device — with early exit the moment
-    # no slot needs another tick, so host work (readback, detokenize,
-    # flight recorder, SLO sampling) amortizes K-fold. 0 = off (the
-    # per-dispatch scan graphs serve). AIOS_TPU_MEGA_TICKS overrides at
-    # load time (docs/ENGINE_PERF.md "Device-resident multi-tick decode").
-    mega_ticks: int = 0
     # grammar jump-ahead for constrained decoding (engine/batching.py
     # _jump_tick): chains of grammar-FORCED tokens (singleton masks —
     # schema key literals, '":', '",', closers) emit host-side and append

@@ -56,14 +56,14 @@ DEFAULT_BUCKETS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192)
 JUMP_BUCKETS = (4, 16)
 assert JUMP_BUCKETS[-1] <= spec.HISTORY_PAD - 2
 
-# Device-side stop-id slots per batch slot in the mega decode loop
-# (_mega_impl): each slot's first MEGA_STOP_SLOTS stop ids ride the
-# dispatch as a fixed-shape [S, MEGA_STOP_SLOTS] operand (pad -1) so
-# EOS/stop detection runs on device. BEST-EFFORT by design: the host
-# emit loop stays authoritative for stream truncation (it checks the
-# FULL stop set), so an overflowing stop set only costs an early-exit
-# opportunity, never correctness.
-MEGA_STOP_SLOTS = 4
+# Decode steps per dispatch of the continuous batcher's loop: the long
+# dispatch while nothing waits for a slot, the short one while a request
+# does (it is admitted between dispatches, so a short one keeps its wait
+# low). warmup's default step sizes and the batcher's defaults and
+# attach-time compile all read these two, so the graphs compiled behind
+# the readiness gate are the graphs the loop dispatches.
+DECODE_STEPS = 16
+ADMIT_DECODE_STEPS = 2
 
 # Width buckets for the standalone draft-KV bulk-ingest graphs: a freshly
 # admitted (or failed-over) slot's draft cache trails the serving state by
@@ -302,7 +302,7 @@ class PendingDecode:
     submit time."""
 
     __slots__ = ("_fut", "_started", "n_steps", "tokens", "lengths",
-                 "ticks", "device_s")
+                 "device_s")
 
     def __init__(self, fut, n_steps: int, started: threading.Event) -> None:
         self._fut = fut
@@ -310,10 +310,6 @@ class PendingDecode:
         self.n_steps = int(n_steps)
         self.tokens: Optional[np.ndarray] = None
         self.lengths: Optional[np.ndarray] = None
-        # REAL ticks the dispatch ran: n_steps for the scan graphs, the
-        # device loop's k <= n_steps for a megagraph dispatch that
-        # early-exited (mega_step_async); set at wait()
-        self.ticks = int(n_steps)
         self.device_s: Optional[float] = None
 
     def wait_started(self) -> None:
@@ -323,11 +319,7 @@ class PendingDecode:
 
     def wait(self) -> np.ndarray:
         if self.tokens is None:
-            res = self._fut.result()
-            if len(res) == 4:  # mega: (tokens, lengths, k, device_s)
-                self.tokens, self.lengths, self.ticks, self.device_s = res
-            else:
-                self.tokens, self.lengths, self.device_s = res
+            self.tokens, self.lengths, self.device_s = self._fut.result()
         return self.tokens
 
 
@@ -353,14 +345,12 @@ class TPUEngine:
         host_restore_min_pages: Optional[int] = None,  # restore floor
         seq_sharded_cache: bool = False,  # shard KV context axis over sp
         track_history: bool = True,  # device-side token history (spec.py)
-        unified_step: Optional[bool] = None,  # one dynamic-n decode graph
         prefix_radix: Optional[bool] = None,  # radix-tree prefix index
         draft: Optional["spec.DraftModel"] = None,  # draft-model proposer
         kv_compress_after: Optional[int] = None,  # window+sink threshold rows
         kv_sink_pages: Optional[int] = None,  # live leading (sink) pages
         kv_window_pages: Optional[int] = None,  # live trailing window pages
         seq_prefill_min: Optional[int] = None,  # sp-sharded prefill floor rows
-        mega_ticks: Optional[int] = None,  # multi-tick decode megagraph cap
     ) -> None:
         self.cfg = cfg
         self.num_slots = num_slots
@@ -412,47 +402,16 @@ class TPUEngine:
         # EXCEPT decode attention, which is head/slot-local and runs the
         # ragged kernel per device under shard_map (see _attn_impl below).
         self._kernels: Optional[bool] = False if shardings is not None else None
-        # MoE prefill: with nothing forced, a chunk or bucket of enough
+        # MoE: a prefill chunk or bucket, or a verify feed, of enough
         # tokens (moe.grouped_pays, from static shapes) runs each expert
         # over the rows routed to it only, reading the stacked int8 expert
         # weights where they lie (moe.moe_ffn_grouped; the layer scans hand
         # it the stacks whole); fewer tokens, and every decode step, run
         # every held expert over every token (moe.moe_ffn_dense).
-        # MoE decode: the gathered path streams only the routed experts'
-        # weights (moe.moe_ffn_gather) when every slot's picks together
-        # touch fewer experts than exist. Measured on v5e (2.3B geometry,
-        # 32 experts top-4, single request): gather 126.5 tok/s vs dense
-        # 216.4 — the expert-weight gather costs more than the skipped
-        # streaming saves at small expert sizes, so DENSE is the default
-        # and AIOS_TPU_MOE_GATHER=1 opts in (bigger experts / higher
-        # X/(slots*k) ratios may still favor it). Single-device only:
-        # under EP the expert axis is sharded and the dense path's psum is
-        # the right collective. Decode/verify dispatches only — prefill
-        # has the grouped path above.
-        # AIOS_TPU_MOE_IMPL ("dense" | "gather" | "dispatch") is the
-        # operator's escape hatch: resolved HERE, once, with the engine's
-        # other load-time overrides, it beats every static choice below and
-        # goes to every graph (prefill, chunk, decode, verify); model.ffn
-        # reads no environment.
-        self._moe_override: Optional[str] = (
-            os.environ.get("AIOS_TPU_MOE_IMPL") or None
-        )
-        self._moe_impl: Optional[str] = self._moe_override
-        if (
-            self._moe_override is None
-            and cfg.moe
-            and shardings is None
-            and num_slots * cfg.num_experts_per_tok < cfg.num_experts
-            and os.environ.get("AIOS_TPU_MOE_GATHER", "").lower()
-            in ("1", "true", "on")
-        ):
-            self._moe_impl = "gather"
-        # under a sharding plan the expert axis may be sharded over ep,
+        # Under a sharding plan the expert axis may be sharded over ep,
         # where the dense path's contraction is one psum and the grouped
-        # path's per-expert reads would cross chips: every graph stays dense
-        self._prefill_moe_impl: Optional[str] = self._moe_override
-        if self._moe_override is None and cfg.moe and shardings is not None:
-            self._moe_impl = self._prefill_moe_impl = "dense"
+        # path's per-expert reads would cross chips: every graph stays dense.
+        self._moe_dense = shardings is not None
 
         if shardings is not None:
             if _is_prequantized(params):
@@ -727,7 +686,7 @@ class TPUEngine:
         # threshold streams are token-exact.
         def knob(explicit, env, default):
             # explicit constructor arg > env > ModelConfig default — the
-            # unified_step/prefix_radix resolution convention
+            # prefix_radix resolution convention
             if explicit is not None:
                 return int(explicit)
             v = _env_int(env)
@@ -798,17 +757,6 @@ class TPUEngine:
         refuse_for_latent_pool(
             cfg, sequence_sharded_prefill=self.seq_prefill_min > 0
         )
-        # Device-resident multi-tick decode megagraph (_mega_impl): up to
-        # mega_ticks decode ticks per dispatch in one lax.while_loop with
-        # sampling, stop detection and budget/cap checks on device, early
-        # exit the moment no slot needs another tick. 0 = off (default).
-        # The loop's key fanout is split(key, K+1) — identical to the
-        # per-size scan graph of the same K, so a full-window mega
-        # dispatch is key-for-key the _step_impl(K) dispatch.
-        self.mega_ticks = max(knob(
-            mega_ticks, "AIOS_TPU_MEGA_TICKS",
-            getattr(cfg, "mega_ticks", 0),
-        ), 0)
         self._seq_attn = None
         self._seq_prefill_fns: Dict[int, object] = {}
         self.prefill_seq_sharded = 0
@@ -972,21 +920,6 @@ class TPUEngine:
         self._spec_fns: Dict[Tuple[int, int, int], object] = {}
         self._restore_fns: Dict[int, object] = {}
         self._jump_fns: Dict[int, object] = {}  # run-length-bucketed
-        self._mega_fns: Dict[int, object] = {}  # pow2 K-bucketed megagraphs
-        # Unified decode graph: ONE compiled fori_loop over a static
-        # max-steps bound with the actual step count as a DYNAMIC operand,
-        # so every chunk size the batcher dispatches shares a single XLA
-        # graph instead of compiling per size (warmup compiles 1 graph,
-        # not len(step_sizes)). Greedy output is identical to the per-size
-        # scan graphs; sampling draws from a different (fixed-fanout) key
-        # split, so the knob is opt-in (AIOS_TPU_UNIFIED_STEP /
-        # ModelConfig.unified_step) rather than the default.
-        if unified_step is None:
-            unified_step = _env_flag("AIOS_TPU_UNIFIED_STEP")
-        if unified_step is None:
-            unified_step = bool(getattr(cfg, "unified_step", False))
-        self.unified_step = bool(unified_step)
-        self._unified_max = 0
         # single-thread dispatch worker behind step_async (built lazily:
         # only pipelined batchers use it); FIFO order is the dispatch
         # ordering contract
@@ -1067,12 +1000,6 @@ class TPUEngine:
         # jump_tokens/jump_dispatches masked single-token dispatches
         self.jump_dispatches = 0
         self.jump_tokens = 0
-        # multi-tick megagraph accounting (mega_step): dispatches and the
-        # REAL ticks they ran (k <= K when the device loop early-exited);
-        # dispatches * K - mega_tick_total = ticks the early-exit contract
-        # saved. Distinct attribute names from the mega_ticks knob above.
-        self.mega_dispatches = 0
-        self.mega_tick_total = 0
         # XLA compile-event accounting: every new jit graph counts once
         # and its FIRST dispatch's wall time — jax compiles synchronously
         # inside that call — is recorded as the compile stall. stats(),
@@ -1133,12 +1060,6 @@ class TPUEngine:
         )
         obs.ENGINE_JUMP_TOKENS.labels(model=name).set_function(
             engines_sum("jump_tokens")
-        )
-        obs.ENGINE_MEGA_DISPATCHES.labels(model=name).set_function(
-            engines_sum("mega_dispatches")
-        )
-        obs.ENGINE_MEGA_TICKS.labels(model=name).set_function(
-            engines_sum("mega_tick_total")
         )
         # long-context tier: compression + sequence-sharded prefill
         # counters (same WeakSet-summed monotonic-engine-counter pattern)
@@ -1329,8 +1250,7 @@ class TPUEngine:
     def _decode_body(self, params, st: DecodeState, sub, tables=None,
                      mask=None):
         """ONE decode step against whichever cache layout this engine runs
-        — the shared body of the per-size scan graphs (``_step_impl``) and
-        the unified dynamic-n loop graph (``_unified_impl``). Only the
+        — the body of the per-size scan graphs (``_step_impl``). Only the
         model call differs between the dense, int8-KV and paged layouts;
         sampling, history gating and the state rebuild are shared.
         ``mask`` [S, V] fp32 adds to the logits before sampling — the
@@ -1351,7 +1271,7 @@ class TPUEngine:
                 kernels=self._kernels,
                 cache_scales=scales,
                 active=st["active"],
-                moe_impl=self._moe_impl,
+                moe_dense=self._moe_dense,
                 qmm=self._qmm_impl,
                 pool_impl=self._pool_impl,
                 win_starts=win_starts,
@@ -1372,7 +1292,7 @@ class TPUEngine:
                 kernels=self._kernels,
                 cache_scales=(st["k_s"], st["v_s"]),
                 active=st["active"],
-                moe_impl=self._moe_impl,
+                moe_dense=self._moe_dense,
                 qmm=self._qmm_impl,
             )
         else:
@@ -1386,7 +1306,7 @@ class TPUEngine:
                 kernels=self._kernels,
                 active=st["active"],
                 attn_impl=self._attn_impl,
-                moe_impl=self._moe_impl,
+                moe_dense=self._moe_dense,
                 qmm=self._qmm_impl,
             )
         moe_stats = st.get("moe_stats")
@@ -1457,104 +1377,6 @@ class TPUEngine:
             state = dict(state, moe_stats=jnp.zeros((3,), jnp.int32))
         return state, tokens  # tokens [n_steps (+ 3), S]
 
-    def _unified_impl(self, params, state: DecodeState, n, max_steps: int,
-                      tables=None):
-        """Dynamic-step decode loop: run ``n`` (a traced operand, n <=
-        max_steps) steps of ``_decode_body`` under one fori_loop, emitting
-        into a fixed [max_steps, S] token buffer — ONE compiled graph
-        serves every chunk size the batcher dispatches. Rows past n stay
-        zero and are sliced off on the host (PendingDecode.wait). The key
-        fanout is max_steps+1 regardless of n, so sampled sequences differ
-        from the per-size scan graphs (greedy output is identical)."""
-        keys = jax.random.split(state["key"], max_steps + 1)
-        state = dict(state, key=keys[0])
-
-        def body(i, carry):
-            st, out = carry
-            st, tok = self._decode_body(params, st, keys[i + 1], tables)
-            return st, out.at[i].set(tok)
-
-        out0 = jnp.zeros((max_steps, self.num_slots), jnp.int32)
-        state, tokens = jax.lax.fori_loop(
-            0, jnp.minimum(n, max_steps), body, (state, out0)
-        )
-        return state, tokens  # tokens [max_steps, S]; rows [n:] are zeros
-
-    def _mega_impl(self, params, state: DecodeState, n, stops, budgets,
-                   abort_after, max_ticks: int, tables=None):
-        """Device-resident multi-tick decode megagraph: up to ``n`` (a
-        traced operand, n <= max_ticks) applications of ``_decode_body``
-        under one ``lax.while_loop``, emitting into a fixed
-        [max_ticks, S] token buffer — sampling, EOS/stop-sequence
-        detection, per-slot token-budget and context-cap checks all run
-        ON DEVICE, and the loop EXITS EARLY the moment no slot needs
-        another tick, returning the real tick count ``k`` in the
-        readback (the early-exit contract; the batcher's flush causes
-        become loop-exit conditions instead of pipeline flushes).
-
-        Per-slot live flags: a slot stays live while it is active, has
-        not sampled one of its ``stops`` ids ([S, MEGA_STOP_SLOTS]
-        int32, pad -1 — best-effort, the host emit loop stays
-        authoritative), still has token budget (``budgets`` [S] int32,
-        remaining = max_tokens - produced) and is below the context cap.
-        ``abort_after`` (int32, normally n) is the injectable
-        host-attention override: ``pool.megatick_abort`` caps the loop
-        mid-window through it, exercising the early-exit path
-        deterministically.
-
-        The key fanout is ``split(key, max_ticks + 1)`` — the SAME
-        fanout as ``_step_impl(max_ticks)`` — so a full-window mega
-        dispatch is key-for-key identical to the per-size scan graph of
-        the same size; early exits only ever skip ticks whose tokens the
-        host would have discarded (every live slot done). Composes with
-        the shard_map ragged-attention twin (``self._attn_impl``) and
-        the paged pool exactly like the scan graphs: ``_decode_body`` is
-        the shared body, so dp/tp-sharded plans serve the megagraph
-        natively instead of silently falling back."""
-        keys = jax.random.split(state["key"], max_ticks + 1)
-        state = dict(state, key=keys[0])
-        cap = jnp.minimum(jnp.minimum(n, abort_after), max_ticks)
-        ctx_cap = self.max_context - 1
-
-        def live(st, done, rem):
-            return st["active"] & ~done & (rem > 0) & (st["lengths"] < ctx_cap)
-
-        def cond(carry):
-            i, st, _, done, rem = carry
-            return (i < cap) & jnp.any(live(st, done, rem))
-
-        def body(carry):
-            i, st, out, done, rem = carry
-            st, tok = self._decode_body(params, st, keys[i + 1], tables)
-            out = out.at[i].set(tok)
-            done = done | jnp.any(tok[:, None] == stops, axis=1)
-            return i + 1, st, out, done, rem - 1
-
-        out0 = jnp.zeros((max_ticks, self.num_slots), jnp.int32)
-        done0 = jnp.zeros((self.num_slots,), jnp.bool_)
-        k, state, tokens, _, _ = jax.lax.while_loop(
-            cond, body,
-            (jnp.int32(0), state, out0, done0,
-             jnp.asarray(budgets, jnp.int32)),
-        )
-        # tokens [max_ticks, S]; rows [k:] are zeros and never read back
-        return state, tokens, k
-
-    def _verify_moe_impl(self, feed_width: int):
-        """The gathered-MoE crossover gate shared by every verify-shaped
-        dispatch (spec rounds, jump-ahead, draft verify): feeding W
-        tokens per slot shifts the gather-vs-dense traffic crossover by
-        that factor — gathering S*W*k expert blocks (with duplicates
-        re-streamed) must still undercut the dense path's X blocks, or
-        the verify falls back to dense."""
-        if (
-            self._moe_impl == "gather"
-            and self.num_slots * feed_width * self.cfg.num_experts_per_tok
-            >= self.cfg.num_experts
-        ):
-            return self._prefill_moe_impl
-        return self._moe_impl
-
     def _verify_feed(self, params, st: DecodeState, feed, tables=None):
         """One multi-token verify forward against whichever cache layout
         this engine runs — the shared dispatch body of ``_spec_impl``,
@@ -1562,20 +1384,19 @@ class TPUEngine:
         ([last_token, draft/forced tokens...]); returns
         (logits [S, W, V], k, v, scales-or-None, expert counters-or-None)."""
         scales = (st["k_s"], st["v_s"]) if self.quant_cache else None
-        moe_impl = self._verify_moe_impl(feed.shape[1])
         if self.paged:
             tables, win_starts = self._split_tables(tables)
             out = model.verify_step_paged(
                 params, self.cfg, feed, st["lengths"], st["k"], st["v"],
                 tables, cache_scales=scales, active=st["active"],
-                moe_impl=moe_impl, qmm=self._qmm_gspmd,
+                moe_dense=self._moe_dense, qmm=self._qmm_gspmd,
                 win_starts=win_starts, sink_rows=self._sink_rows,
             )
         else:
             out = model.verify_step(
                 params, self.cfg, feed, st["lengths"], st["k"], st["v"],
                 kernels=self._kernels, cache_scales=scales,
-                active=st["active"], moe_impl=moe_impl,
+                active=st["active"], moe_dense=self._moe_dense,
                 qmm=self._qmm_gspmd,
             )
         logits, k, v, *rest = out
@@ -1922,7 +1743,7 @@ class TPUEngine:
         logits, ks, vs, *picks = model.prefill(
             params, self.cfg, tokens, kernels=self._kernels,
             qmm=self._qmm_gspmd, attn_fn=attn_fn,
-            moe_impl=self._prefill_moe_impl,
+            moe_dense=self._moe_dense,
         )
         # ks/vs [L, 1, T, KH, D] -> the pool's rows [L, T, KH*D], written
         # from row 0 of the slot's first page, by whole pages
@@ -1975,7 +1796,7 @@ class TPUEngine:
     ):
         logits, ks, vs, *picks = model.prefill(
             params, self.cfg, tokens, kernels=self._kernels,
-            qmm=self._qmm_gspmd, moe_impl=self._prefill_moe_impl,
+            qmm=self._qmm_gspmd, moe_dense=self._moe_dense,
         )
         # ks/vs [L, B=1, T, KH, D] -> cache layout [L, slot, T, KH, D]
         start = (0, slot, 0, 0, 0)
@@ -2035,14 +1856,14 @@ class TPUEngine:
                 params, self.cfg, tokens, start, state["k"], state["v"],
                 table_row, cache_scales=scales, qmm=self._qmm_gspmd,
                 win_start=win_start, sink_rows=self._sink_rows,
-                moe_impl=self._prefill_moe_impl,
+                moe_dense=self._moe_dense,
             )
         else:
             scales = (state["k_s"], state["v_s"]) if self.quant_cache else None
             out = model.prefill_chunk(
                 params, self.cfg, tokens, slot, start, state["k"], state["v"],
                 cache_scales=scales, qmm=self._qmm_gspmd,
-                moe_impl=self._prefill_moe_impl,
+                moe_dense=self._moe_dense,
             )
         if self.quant_cache:
             logits, upd["k"], upd["v"], (upd["k_s"], upd["v_s"]), *picks = out
@@ -2201,17 +2022,6 @@ class TPUEngine:
             donate_argnums=(1,),
         )
 
-    def _make_unified_jit(self, max_steps: int):
-        if self.paged:
-            return jax.jit(
-                lambda p, s, t, n: self._unified_impl(p, s, n, max_steps, t),
-                donate_argnums=(1,),
-            )
-        return jax.jit(
-            lambda p, s, n: self._unified_impl(p, s, n, max_steps),
-            donate_argnums=(1,),
-        )
-
     def _make_masked_jit(self):
         if self.paged:
             return jax.jit(
@@ -2231,21 +2041,6 @@ class TPUEngine:
             )
         return jax.jit(
             lambda p, s, f, c: self._jump_impl(p, s, f, c),
-            donate_argnums=(1,),
-        )
-
-    def _make_mega_jit(self, max_ticks: int):
-        if self.paged:
-            return jax.jit(
-                lambda p, s, t, n, st_, b, a: self._mega_impl(
-                    p, s, n, st_, b, a, max_ticks, t
-                ),
-                donate_argnums=(1,),
-            )
-        return jax.jit(
-            lambda p, s, n, st_, b, a: self._mega_impl(
-                p, s, n, st_, b, a, max_ticks
-            ),
             donate_argnums=(1,),
         )
 
@@ -2367,9 +2162,7 @@ class TPUEngine:
         """Ensure the ``n_steps`` decode graph exists WITHOUT dispatching
         (the batcher calls this for its chunk sizes when it attaches to a
         warmed engine; warmup calls it for every serving step size)."""
-        if self.unified_step:
-            self._unified_fn(n_steps, aot=True)
-        elif n_steps not in self._step_fns:
+        if n_steps not in self._step_fns:
             self._compile_aot(
                 "step", self._step_fns, n_steps,
                 self._make_step_jit(n_steps), self._step_example(),
@@ -2452,37 +2245,6 @@ class TPUEngine:
         self._compile_aot(
             "jump", self._jump_fns, k_bucket, self._make_jump_jit(),
             tuple(args),
-        )
-
-    def mega_bucket(self, n: int) -> int:
-        """The power-of-two megagraph bucket serving an ``n``-tick
-        window (the smallest compiled K >= n; the dispatch passes the
-        true n as a dynamic operand)."""
-        m = 1
-        while m < n:
-            m *= 2
-        return m
-
-    def compile_mega_fn(self, k_bucket: int) -> None:
-        """Ensure the ``k_bucket``-tick megagraph exists WITHOUT
-        dispatching (warmup compiles every power-of-two bucket up to
-        ``mega_ticks``; the batcher attach calls this for its own
-        window sizes — the flat-compile-counters invariant). No-op when
-        the megagraph is disarmed (``mega_ticks`` = 0)."""
-        if k_bucket in self._mega_fns or not self.mega_ticks:
-            return
-        args = [self.params, self.state]
-        if self.paged:
-            args.append(self._tables_operand())
-        args += [
-            jnp.int32(k_bucket),
-            jnp.full((self.num_slots, MEGA_STOP_SLOTS), -1, jnp.int32),
-            jnp.zeros((self.num_slots,), jnp.int32),
-            jnp.int32(k_bucket),
-        ]
-        self._compile_aot(
-            "mega", self._mega_fns, k_bucket,
-            self._make_mega_jit(k_bucket), tuple(args),
         )
 
     def compile_prefill_fn(self, bucket: int) -> None:
@@ -2575,42 +2337,6 @@ class TPUEngine:
             fn = self._instrument_compile(self._make_step_jit(n_steps), "step")
             self._step_fns[n_steps] = fn
         return fn
-
-    def _unified_fn(self, n_steps: int, aot: bool = False):
-        """The dynamic-n decode graph serving ``n_steps`` (unified_step
-        mode): one graph per power-of-two max-steps bound, grown on
-        demand. Returns (fn, max_steps)."""
-        m = self._unified_max
-        if m < n_steps:
-            m = 1
-            while m < n_steps:
-                m *= 2
-        key = ("uni", m)
-        fn = self._step_fns.get(key)
-        if fn is None:
-            jitfn = self._make_unified_jit(m)
-            if aot:
-                self._compile_aot(
-                    "step", self._step_fns, key, jitfn,
-                    self._step_example() + (jnp.int32(1),),
-                )
-                fn = self._step_fns[key]
-            else:
-                fn = self._instrument_compile(jitfn, "step")
-                self._step_fns[key] = fn
-            self._unified_max = m
-        return fn, m
-
-    def _mega_fn(self, n_ticks: int):
-        """The megagraph serving an ``n_ticks`` window: the power-of-two
-        bucket >= n_ticks, compiled lazily on an unwarmed engine.
-        Returns (fn, bucket)."""
-        m = self.mega_bucket(n_ticks)
-        fn = self._mega_fns.get(m)
-        if fn is None:
-            fn = self._instrument_compile(self._make_mega_jit(m), "mega")
-            self._mega_fns[m] = fn
-        return fn, m
 
     def _masked_step_fn(self):
         """1-step decode with an additive per-slot logits mask (grammar-
@@ -3408,28 +3134,14 @@ class TPUEngine:
                     if self.paged:
                         self._back_active_slots(n_steps)
                         tables = (self._tables_operand(),)
-                    if self.unified_step:
-                        fn, m = self._unified_fn(n_steps)
-                        # worker dispatches sample only with
-                        # double-buffer slack (nothing queued behind this
-                        # one), so a measurement never delays the next
-                        # submission
-                        dtok = self._devprof_note(
-                            "step", ("uni", m),
-                            need_slack=started is not None,
-                        )
-                        self.state, tokens = fn(
-                            self.params, self.state, *tables,
-                            jnp.int32(n_steps),
-                        )
-                    else:
-                        fn = self._step_fn(n_steps)
-                        dtok = self._devprof_note(
-                            "step", n_steps, need_slack=started is not None
-                        )
-                        self.state, tokens = fn(
-                            self.params, self.state, *tables
-                        )
+                    fn = self._step_fn(n_steps)
+                    # worker dispatches sample only with double-buffer
+                    # slack (nothing queued behind this one), so a
+                    # measurement never delays the next submission
+                    dtok = self._devprof_note(
+                        "step", n_steps, need_slack=started is not None
+                    )
+                    self.state, tokens = fn(self.params, self.state, *tables)
                 self.decode_steps += n_steps
                 self._obs_decode_steps.inc(n_steps)
                 self._host_lengths = np.minimum(
@@ -3439,8 +3151,7 @@ class TPUEngine:
             with ph.phase("engine.readback"):
                 host_all = np.asarray(tokens)
                 host_tokens = host_all[:n_steps]
-            if not self.unified_step:
-                self._take_picks(host_all, n_steps)
+            self._take_picks(host_all, n_steps)
             # the readback above already blocked until the tokens
             # materialized, so the sample is the graph-call -> ready
             # delta at zero extra synchronization
@@ -3481,115 +3192,6 @@ class TPUEngine:
             self._step_dispatch, n_steps, started
         )
         return PendingDecode(fut, n_steps, started)
-
-    def _mega_dispatch(
-        self, n_ticks: int, stops: np.ndarray, budgets: np.ndarray,
-        started: Optional[threading.Event] = None,
-    ) -> Tuple[np.ndarray, np.ndarray, int, Optional[float]]:
-        """The megagraph dispatch body: lock, while-loop graph call
-        (donated state swap), the k readback, host-length advance by the
-        REAL tick count, then the token-block readback outside the lock.
-        Returns (tokens [k, S], per-tick length snapshots [k, S], k,
-        sample_s). Unlike ``_step_dispatch``, the scalar k readback
-        blocks UNDER the engine lock — the host-length advance depends
-        on it, and the CPU backend already executes the graph inline
-        under the lock in ``_step_dispatch``; on TPU this serializes
-        admissions behind the window's device execution (the documented
-        K>1 tradeoff, docs/ENGINE_PERF.md)."""
-        ph = self.phases
-        try:
-            lock_wait = ph.begin("engine.lock_wait")
-            with self._lock:
-                ph.end(lock_wait)
-                if started is not None:
-                    started.set()
-                with ph.phase("engine.enqueue", n=n_ticks,
-                              occ=int(self.active.sum())):
-                    tables = ()
-                    if self.paged:
-                        self._back_active_slots(n_ticks)
-                        tables = (self._tables_operand(),)
-                    abort_after = n_ticks
-                    act = faults.point("pool.megatick_abort", self.cfg.name)
-                    if act is not None and n_ticks > 1:
-                        # injected host-attention demand: cap the device loop
-                        # mid-window (ticks param, default half the window) —
-                        # the early-exit path fires with slots still live
-                        abort_after = min(
-                            max(act.ticks or n_ticks // 2, 1), n_ticks - 1
-                        )
-                    fn, m = self._mega_fn(n_ticks)
-                    dtok = self._devprof_note(
-                        "mega", m, need_slack=started is not None
-                    )
-                    self.state, tokens, k_dev = fn(
-                        self.params, self.state, *tables, jnp.int32(n_ticks),
-                        jnp.asarray(stops, jnp.int32),
-                        jnp.asarray(budgets, jnp.int32),
-                        jnp.int32(abort_after),
-                    )
-                with ph.phase("engine.readback"):
-                    k = int(k_dev)
-                self.mega_dispatches += 1
-                self.mega_tick_total += k
-                self.decode_steps += k
-                self._obs_decode_steps.inc(k)
-                base = self._host_lengths.copy()
-                self._host_lengths = np.minimum(
-                    base + k, self.max_context - 1
-                )
-            # per-tick length snapshots: row j holds every slot's length
-            # AS OF tick j, so retirement anchors on the dispatch tick
-            # that produced each token (the K=1 loop's post-dispatch
-            # snapshot, per tick) — never on the window's requested n
-            lengths = np.minimum(
-                base[None, :] + np.arange(1, k + 1, dtype=np.int64)[:, None],
-                self.max_context - 1,
-            )
-            with ph.phase("engine.readback"):
-                host_tokens = np.asarray(tokens)[:k]
-            sample_s = self._devprof_sample(dtok)
-            return host_tokens, lengths, k, sample_s
-        finally:
-            if started is not None and self._devprof is not None:
-                self._devprof.dequeue()
-
-    def mega_step(
-        self, n_ticks: int, stops: np.ndarray, budgets: np.ndarray,
-    ) -> Tuple[np.ndarray, np.ndarray, int]:
-        """Run up to ``n_ticks`` decode ticks in ONE device-resident
-        while-loop dispatch (the multi-tick megagraph, ``_mega_impl``)
-        with early exit the moment no slot needs another tick.
-
-        ``stops`` [num_slots, MEGA_STOP_SLOTS] int32 carries each slot's
-        stop ids (pad -1); ``budgets`` [num_slots] int32 the remaining
-        token budget per slot. Returns (tokens [k, num_slots], per-tick
-        length snapshots [k, num_slots], k) where k <= n_ticks is the
-        REAL tick count the loop ran."""
-        tokens, lengths, k, _ = self._mega_dispatch(n_ticks, stops, budgets)
-        return tokens, lengths, k
-
-    def mega_step_async(
-        self, n_ticks: int, stops: np.ndarray, budgets: np.ndarray,
-    ) -> PendingDecode:
-        """``mega_step`` on the engine's dispatch worker thread —
-        the same depth-2 pipelined contract as ``step_async``; the
-        returned handle's ``ticks`` holds the real k after ``wait()``
-        and ``lengths`` the per-tick [k, S] snapshots."""
-        if self._dispatch_pool is None:
-            from concurrent.futures import ThreadPoolExecutor
-
-            self._dispatch_pool = ThreadPoolExecutor(
-                max_workers=1,
-                thread_name_prefix=f"decode-dispatch-{self.cfg.name}",
-            )
-        started = threading.Event()
-        if self._devprof is not None:
-            self._devprof.enqueue()
-        fut = self._dispatch_pool.submit(
-            self._mega_dispatch, n_ticks, stops, budgets, started
-        )
-        return PendingDecode(fut, n_ticks, started)
 
     def step_masked(self, mask: np.ndarray) -> np.ndarray:
         """One batched decode step with a per-slot ADDITIVE logits mask
@@ -3955,11 +3557,6 @@ class TPUEngine:
         if self.jump_dispatches:
             out["jump_dispatches"] = self.jump_dispatches
             out["jump_tokens"] = self.jump_tokens
-        if self.mega_dispatches:
-            out["mega_dispatches"] = self.mega_dispatches
-            # REAL ticks run (k per dispatch, <= K on early exit);
-            # mega_ticks * dispatches - this = the early-exit savings
-            out["mega_ticks"] = self.mega_tick_total
         if self.allocator is not None:
             out["kv_pages_in_use"] = self.allocator.pages_in_use()
             out["kv_pages_free"] = self.allocator.free_pages
@@ -4036,7 +3633,6 @@ class TPUEngine:
             self._spec_fns.clear()
             self._restore_fns.clear()
             self._jump_fns.clear()
-            self._mega_fns.clear()
             self._draft_fns.clear()
             self._seq_prefill_fns.clear()
             self._seq_attn = None
@@ -4054,12 +3650,11 @@ class TPUEngine:
 
     def warmup(
         self,
-        # must cover every step size the continuous batcher dispatches
-        # (admit_chunk_steps=2, chunk_steps=16) — a size missing here
-        # compiles for multiple seconds ON the scheduler thread at first
-        # use, stalling every live request (measured: ~2 s added to all 8
-        # agents' TTFT)
-        step_sizes: Tuple[int, ...] = (1, 2, 8, 16),
+        # must cover every step size the continuous batcher dispatches —
+        # a size missing here compiles for multiple seconds ON the
+        # scheduler thread at first use, stalling every live request
+        # (measured: ~2 s added to all 8 agents' TTFT)
+        step_sizes: Tuple[int, ...] = (ADMIT_DECODE_STEPS, DECODE_STEPS),
         prefill_chunk: Optional[int] = None,  # None -> prefill_chunk_default
         masked_step: bool = False,  # also compile the grammar-masked step
         spec_sizes: Tuple[int, ...] = (),  # speculative round counts
@@ -4086,11 +3681,9 @@ class TPUEngine:
         ``prefill_chunk``; pass the batcher's size if it overrides the
         shared default, 0 to skip), the prefix-HIT graphs (history
         backfill per bucket + the prefix-chunk tail graphs), every
-        ``step_sizes`` decode graph (ONE dynamic-n graph in unified_step
-        mode), the grammar-masked step when ``masked_step``, speculative
-        round graphs for ``spec_sizes``, every power-of-two multi-tick
-        megagraph bucket when ``mega_ticks`` is armed, and the host-tier
-        restore scatter buckets.
+        ``step_sizes`` decode graph, the grammar-masked step when
+        ``masked_step``, speculative round graphs for ``spec_sizes``, and
+        the host-tier restore scatter buckets.
         """
         t0 = time.perf_counter()
         before = self.compile_events
@@ -4128,10 +3721,7 @@ class TPUEngine:
                     if b > pc:
                         break
                     self.compile_chunk_fn(b, final=True)
-        # largest first: in unified_step mode the first compile sets
-        # _unified_max, so ONE dynamic-n graph serves every smaller size
-        # (ascending order would compile one graph per power of two)
-        for n in sorted(step_sizes, reverse=True):
+        for n in step_sizes:
             self.compile_step_fn(n)
         if masked_step:  # json-mode deployments dispatch step_masked
             self.compile_masked_fn()
@@ -4146,18 +3736,6 @@ class TPUEngine:
             jump_sizes = JUMP_BUCKETS if (masked_step and enabled) else ()
         for k in jump_sizes:
             self.compile_jump_fn(k)
-        if self.mega_ticks:
-            # every power-of-two megagraph bucket up to the armed cap:
-            # the batcher's window is min(chunk, mega_ticks) so the top
-            # bucket covers it, and short tails (budget remainders,
-            # admission windows) bucket downward — a size missing here
-            # would compile on the scheduler thread mid-serving, exactly
-            # the stall the flat-compile-counters gate exists to catch
-            m = 1
-            top = self.mega_bucket(self.mega_ticks)
-            while m <= top:
-                self.compile_mega_fn(m)
-                m *= 2
         for n in spec_sizes:
             self.compile_spec_fn(n, spec_draft_len, spec_ngram)
             # the draft proposer serves the same round sizes; its n-gram

@@ -15,13 +15,7 @@ Design notes (TPU-first):
     additive fp32 mask per constrained step. The jitted graph is unchanged
     in shape, so no recompiles — constrained slots simply ride a 1-step
     dispatch cadence (the batcher's choice) while unconstrained slots in
-    the same batch decode unmasked. The multi-tick decode megagraph
-    (AIOS_TPU_MEGA_TICKS) keeps this split: the mask for tick t+1 depends
-    on the token the automaton consumed at tick t, so constrained slots
-    route through the same 1-step masked dispatches while mega windows
-    only ever carry unconstrained slots — "constrained-mask selection on
-    device" means the ROUTE is selected per slot on the host, not that
-    the automaton was traced into the device loop.
+    the same batch decode unmasked.
   * masks are cached per automaton state. Generations revisit a small set
     of states (in-string, after-comma, ...), so the vocab walk
     (~vocab x token-length byte transitions, pure numpy/python) amortizes

@@ -204,7 +204,7 @@ def _attend_own_rows(q_nope, q_rope, c, k_r, lp, cfg: ModelConfig, qmm=None):
     return out.reshape(T, *out.shape[2:])
 
 
-def _finish_block(x, attn_flat, lp, cfg: ModelConfig, moe_impl, qmm,
+def _finish_block(x, attn_flat, lp, cfg: ModelConfig, moe_dense, qmm,
                   allow_dispatch: bool = False):
     """Output projection and FFN of one block, with the sandwich norms;
     returns (x', moe_aux, stats-or-None)."""
@@ -215,7 +215,7 @@ def _finish_block(x, attn_flat, lp, cfg: ModelConfig, moe_impl, qmm,
             a = model.rms_norm(a, lp["post_attn_norm"], eps)
         x = x + a
     h = model.rms_norm(x, lp["ffn_norm"], eps)
-    m, aux, stats = model.ffn(h, lp, cfg, allow_dispatch, moe_impl, qmm)
+    m, aux, stats = model.ffn(h, lp, cfg, allow_dispatch, moe_dense, qmm)
     if cfg.sandwich_norm:
         m = model.rms_norm(m, lp["post_ffn_norm"], eps)
     return x + m, aux, stats
@@ -223,7 +223,7 @@ def _finish_block(x, attn_flat, lp, cfg: ModelConfig, moe_impl, qmm,
 
 def forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None,
                     with_aux: bool = False, qmm=None,
-                    moe_impl: Optional[str] = None):
+                    moe_dense: bool = False):
     """``model._forward_with_kv`` for a latent-attention model: (logits
     [B, T, V], latents [L, B, T, 1, kv_lora_rank], padded rotary parts
     [L, B, T, 1, 128][, mean moe aux][, stats])."""
@@ -247,14 +247,14 @@ def forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None,
                 )
             )(q_nope, q_rope, c, k_r)
         x, aux, new = _finish_block(
-            x, attn.reshape(B, T, -1), lp, cfg, moe_impl, qmm, with_aux
+            x, attn.reshape(B, T, -1), lp, cfg, moe_dense, qmm, with_aux
         )
         rows = (c[:, :, None, :], _pad_rope(k_r, cfg)[:, :, None, :], aux)
         return (x, *model.add_stats(stats, new)), rows
 
     (x, *stats), (cs, rs, auxs) = model.scan_segments(
         block, (x, *model.zero_stats(cfg)), model.layer_segments(params),
-        moe.grouped_serves(B * T, cfg, moe_impl, with_aux),
+        moe.grouped_serves(B * T, cfg, moe_dense, with_aux),
     )
     logits = model._final_logits(x, params, cfg, qmm)
     out = (logits, cs, rs)
@@ -280,7 +280,7 @@ def _write_chunk(pool, l, rows, pages, off):
 
 def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, c_pool,
                         r_pool, table_row, qmm=None,
-                        moe_impl: Optional[str] = None):
+                        moe_dense: bool = False):
     """``model.prefill_chunk_paged`` over the latent pool: the chunk's
     latent rows are written by whole pages (or inside one), then each new
     row attends, in the EXPANDED form, over the slot's cached latent rows
@@ -322,14 +322,14 @@ def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, c_pool,
                 sm_scale(cfg), cfg.v_head_dim,
             )
         x, _, new = _finish_block(
-            x, attn.reshape(B, Tc, -1), lp, cfg, moe_impl, qmm
+            x, attn.reshape(B, Tc, -1), lp, cfg, moe_dense, qmm
         )
         return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
 
     (x, c_pool, r_pool, *stats), _ = model.scan_segments(
         block, (x, c_pool, r_pool, *model.zero_stats(cfg)),
         model.layer_segments(params),
-        moe.grouped_serves(B * Tc, cfg, moe_impl),
+        moe.grouped_serves(B * Tc, cfg, moe_dense),
     )
     logits = model._final_logits(x, params, cfg, qmm)
     return (logits, c_pool, r_pool, *stats)
@@ -347,7 +347,7 @@ def _write_targets(tables, rows, active, P: int):
 
 def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
                       r_pool, tables, kernels: Optional[bool] = None,
-                      active=None, moe_impl: Optional[str] = None, qmm=None):
+                      active=None, moe_dense: bool = False, qmm=None):
     """``model.decode_step_paged`` over the latent pool, in the ABSORBED
     form: each slot's new latent row is scattered to its page, and the
     kernel scores every head against the latent pages where they lie in the
@@ -385,13 +385,13 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
             )
         with jax.named_scope("mla_out"):
             attn = _unabsorb_o(o_lat, lp, cfg)[:, None]
-        x, _, new = _finish_block(x, attn, lp, cfg, moe_impl, qmm)
+        x, _, new = _finish_block(x, attn, lp, cfg, moe_dense, qmm)
         return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
 
     (x, c_pool, r_pool, *stats), _ = model.scan_segments(
         block, (x, c_pool, r_pool, *model.zero_stats(cfg)),
         model.layer_segments(params),
-        moe.grouped_serves(B, cfg, moe_impl),
+        moe.grouped_serves(B, cfg, moe_dense),
     )
     with jax.named_scope("final_logits"):
         logits = model._final_logits(x[:, 0], params, cfg, qmm)
@@ -400,7 +400,7 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
 
 def verify_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
                       r_pool, tables, active=None,
-                      moe_impl: Optional[str] = None, qmm=None):
+                      moe_dense: bool = False, qmm=None):
     """``model.verify_step_paged`` over the latent pool (the constrained
     decoder's jump-ahead append): the T in-flight rows of every slot are
     scattered through the tables, and each attends, absorbed, over its
@@ -442,14 +442,14 @@ def verify_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
             r_pool[l, tables].reshape(B, C, -1).astype(h.dtype), qpos,
         ))
         x, _, new = _finish_block(
-            x, _unabsorb_o(o_lat, lp, cfg), lp, cfg, moe_impl, qmm
+            x, _unabsorb_o(o_lat, lp, cfg), lp, cfg, moe_dense, qmm
         )
         return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
 
     (x, c_pool, r_pool, *stats), _ = model.scan_segments(
         block, (x, c_pool, r_pool, *model.zero_stats(cfg)),
         model.layer_segments(params),
-        moe.grouped_serves(B * T, cfg, moe_impl),
+        moe.grouped_serves(B * T, cfg, moe_dense),
     )
     logits = model._final_logits(x, params, cfg, qmm)
     return (logits, c_pool, r_pool, *stats)

@@ -178,8 +178,8 @@ def quantize_params(
     "s4": [G, 1, N] scales} instead (ops/int4_matmul.py) — half the int8
     bytes, matching the reference's Q4-class GGUF serving precision.
     Leaves whose dims don't fit the int4 layout, and the expert-stacked
-    MoE leaves (their gathered-decode path is int8-specialized), fall
-    back to int8.
+    MoE leaves (moe._expert_einsum reads int8 leaves only), fall back to
+    int8.
     """
     if mode not in ("int8", "int4"):
         raise ValueError(f"unknown weight quantization mode {mode!r}")
@@ -393,7 +393,7 @@ def _project_qkv(x, lp, cfg: ModelConfig, cos, sin, qmm=None):
 
 def apply_block(x, lp, cfg: ModelConfig, cos, sin, mask, attention=None,
                 with_aux: bool = False, qmm=None,
-                moe_impl: Optional[str] = None):
+                moe_dense: bool = False):
     """One transformer block on [B, T, E]; returns (x', (k, v)) — or
     (x', (k, v, moe_aux)) when ``with_aux``.
 
@@ -403,7 +403,7 @@ def apply_block(x, lp, cfg: ModelConfig, cos, sin, mask, attention=None,
     """
     x, k, v = _attend(x, lp, cfg, cos, sin, mask, attention, qmm)
     mlp_out, aux = _mlp_aux(x, lp, cfg, allow_dispatch=with_aux,
-                            moe_impl=moe_impl, qmm=qmm)
+                            moe_dense=moe_dense, qmm=qmm)
     x = x + mlp_out
     if with_aux:
         return x, (k, v, aux)
@@ -419,23 +419,19 @@ def _attend(x, lp, cfg: ModelConfig, cos, sin, mask, attention=None, qmm=None):
     return x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row"), k, v
 
 
-def _mlp(x, lp, cfg: ModelConfig, moe_impl: Optional[str] = None, qmm=None):
-    return _mlp_aux(x, lp, cfg, moe_impl=moe_impl, qmm=qmm)[0]
-
-
 def _mlp_aux(
     x,
     lp,
     cfg: ModelConfig,
     allow_dispatch: bool = False,
-    moe_impl: Optional[str] = None,
+    moe_dense: bool = False,
     qmm=None,
 ):
     """FFN sublayer; returns (out, moe_aux) — aux is the router
     load-balancing term (0.0 for dense models), consumed only by the
     training forward (forward_full with_aux=True)."""
     h = rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
-    return ffn(h, lp, cfg, allow_dispatch, moe_impl, qmm)[:2]
+    return ffn(h, lp, cfg, allow_dispatch, moe_dense, qmm)[:2]
 
 
 def zero_stats(cfg: ModelConfig):
@@ -452,11 +448,11 @@ def add_stats(stats, new):
     return (stats[0] + new,)
 
 
-def _add_mlp(x, stats, lp, cfg: ModelConfig, moe_impl, qmm):
+def _add_mlp(x, stats, lp, cfg: ModelConfig, moe_dense, qmm):
     """A serving block's FFN sublayer: (x plus it, the carried expert
     counters plus the layer's)."""
     h = rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
-    out, _, new = ffn(h, lp, cfg, False, moe_impl, qmm)
+    out, _, new = ffn(h, lp, cfg, False, moe_dense, qmm)
     return x + out, add_stats(stats, new)
 
 
@@ -479,7 +475,7 @@ def ffn(
     lp,
     cfg: ModelConfig,
     allow_dispatch: bool = False,
-    moe_impl: Optional[str] = None,
+    moe_dense: bool = False,
     qmm=None,
 ):
     """The FFN of one layer over NORMED rows; returns (out, moe_aux,
@@ -488,16 +484,14 @@ def ffn(
     ``ws_*`` where every token also runs a shared expert), so leading dense
     layers and expert layers go through one function.
 
-    The expert path is chosen from STATIC shapes and the config:
-    ``moe_impl`` ("dense" | "gather" | "dispatch") is the caller's explicit
-    choice — the engine resolves the operator's AIOS_TPU_MOE_IMPL override,
-    its own gathered-decode opt-in and a sharding plan's dense path once, at
-    load time — and otherwise token counts at which it computes fewer rows
-    take the exact grouped path (moe.grouped_serves: a prefill chunk or
-    bucket; there ``lp``'s expert leaves may be the whole stacks, read in
-    place at ``lp["expert_layer"]``), the training forward
-    (``allow_dispatch``) the capacity dispatch at large token counts, and
-    everything else — a decode step — the exact dense-over-held path.
+    The expert path is chosen from STATIC shapes and the config: token
+    counts at which it computes fewer rows take the exact grouped path
+    (moe.grouped_serves: a prefill chunk or bucket; there ``lp``'s expert
+    leaves may be the whole stacks, read in place at
+    ``lp["expert_layer"]``), the training forward (``allow_dispatch``) the
+    capacity dispatch at large token counts, and everything else — a
+    decode step, and every graph of an engine under a sharding plan
+    (``moe_dense``) — the exact dense-over-held path.
 
     ``stats`` is moe.pick_stats (int32 [3]) for a layer with a router, else
     None: every serving graph of such a model carries the counters.
@@ -505,26 +499,19 @@ def ffn(
     if "w_router" not in lp:
         out = _swiglu(h, lp, "w_", cfg.intermediate_size, qmm)
         return out, jnp.float32(0.0), None
-    impl = moe_impl or "auto"
     n_tok = h.shape[0] * h.shape[1]
     stats = None
     # a scope renumbers a compiled graph's instructions: only the graphs of
     # a model that holds a share (new with the scope) get this one
     with (jax.named_scope("moe_routed") if cfg.expert_share
           else contextlib.nullcontext()):
-        if impl == "dispatch" or (
-            impl == "auto" and allow_dispatch and n_tok >= 1024
-        ):
+        if allow_dispatch and n_tok >= 1024:
             # The capacity-based dispatch path may DROP overflow picks, so
-            # auto only selects it on the training forward
-            # (``allow_dispatch``, i.e. with_aux) at large token counts —
-            # every serving path (decode, chunked/bucketed prefill) stays
-            # on an exact path unless the operator explicitly forces
-            # dispatch.
+            # only the training forward (``allow_dispatch``, i.e. with_aux)
+            # takes it, at large token counts — every serving path (decode,
+            # chunked/bucketed prefill) stays on an exact path.
             out, aux = moe_mod.moe_ffn_dispatch(h, lp, cfg)
-        elif impl == "gather":
-            out, aux = moe_mod.moe_ffn_gather(h, lp, cfg)
-        elif moe_mod.grouped_serves(n_tok, cfg, moe_impl, allow_dispatch):
+        elif moe_mod.grouped_serves(n_tok, cfg, moe_dense, allow_dispatch):
             out, aux, stats = moe_mod.moe_ffn_grouped(h, lp, cfg)
         else:
             out, aux, stats = moe_mod.moe_ffn_dense(
@@ -535,7 +522,7 @@ def ffn(
             out = out + _swiglu(
                 h, lp, "ws_", cfg.n_shared_experts * cfg.expert_dim, qmm
             )
-    if stats is None:  # a forced path counts nothing
+    if stats is None:  # the training forward's dispatch counts nothing
         stats = jnp.zeros((3,), jnp.int32)
     return out, aux, stats
 
@@ -571,7 +558,7 @@ def forward_full(
 
 def prefill(
     params: Params, cfg: ModelConfig, tokens: jnp.ndarray, kernels=None,
-    qmm=None, attn_fn=None, moe_impl: Optional[str] = None,
+    qmm=None, attn_fn=None, moe_dense: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Causal forward returning (logits [B,T,V], k [L,B,T,KH,D], v [...]).
     A latent-attention model returns its cache rows in the same places, as
@@ -588,7 +575,7 @@ def prefill(
     """
     return _forward_with_kv(
         params, cfg, tokens, attn_fn=attn_fn, kernels=kernels, qmm=qmm,
-        moe_impl=moe_impl,
+        moe_dense=moe_dense,
     )
 
 
@@ -666,13 +653,13 @@ def _use_ragged_kernel(
 
 def _forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None, kernels=None,
                      with_aux: bool = False, qmm=None,
-                     moe_impl: Optional[str] = None):
+                     moe_dense: bool = False):
     if cfg.mla:
         from . import latent
 
         return latent.forward_with_kv(
             params, cfg, tokens, attn_fn=attn_fn, with_aux=with_aux,
-            qmm=qmm, moe_impl=moe_impl,
+            qmm=qmm, moe_dense=moe_dense,
         )
     B, T = tokens.shape
     x = params["embed"][tokens]
@@ -695,7 +682,7 @@ def _forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None, kernels=Non
     if with_aux:
         def block(x, lp):
             return apply_block(x, lp, cfg, cos, sin, mask, attention, True,
-                               qmm=qmm, moe_impl=moe_impl)
+                               qmm=qmm, moe_dense=moe_dense)
 
         x, (ks, vs, auxs) = jax.lax.scan(block, x, params["layers"])
         logits = _final_logits(x, params, cfg, qmm)
@@ -704,12 +691,12 @@ def _forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None, kernels=Non
     def block(carry, layer):
         x, stats = carry
         x, k, v = _attend(x, layer[0], cfg, cos, sin, mask, attention, qmm)
-        x, stats = _add_mlp(x, stats, layer[0], cfg, moe_impl, qmm)
+        x, stats = _add_mlp(x, stats, layer[0], cfg, moe_dense, qmm)
         return (x, stats), (k, v)
 
     (x, stats), (ks, vs) = scan_segments(
         block, (x, zero_stats(cfg)), layer_segments(params),
-        moe_mod.grouped_serves(B * T, cfg, moe_impl),
+        moe_mod.grouped_serves(B * T, cfg, moe_dense),
     )
     logits = _final_logits(x, params, cfg, qmm)
     return (logits, ks, vs, *stats)
@@ -725,7 +712,7 @@ def prefill_chunk(
     v_cache: jnp.ndarray,  # [L, S, C, KH, D]
     cache_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
-    moe_impl: Optional[str] = None,
+    moe_dense: bool = False,
 ):
     """One chunk of an incremental prefill against the slot cache.
 
@@ -806,12 +793,12 @@ def prefill_chunk(
             v_all = jax.lax.dynamic_slice_in_dim(v_l, slot, 1, axis=0)
         attn = attend(q, k_all.astype(q.dtype), v_all.astype(q.dtype))
         x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
-        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
         return (x, stats), (k_l, v_l, *((k_s, v_s) if quant_cache else ()))
 
     x, k_cache, v_cache, scales, stats = _scan_layers_over_cache(
         block, x, params, k_cache, v_cache, cache_scales, cfg,
-        moe_mod.grouped_serves(B * Tc, cfg, moe_impl),
+        moe_mod.grouped_serves(B * Tc, cfg, moe_dense),
     )
     logits = _final_logits(x, params, cfg, qmm)
     if quant_cache:
@@ -830,7 +817,7 @@ def decode_step(
     cache_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     active: Optional[jnp.ndarray] = None,  # [B] bool
     attn_impl=None,  # (q [B,H,D], k_l, v_l, lengths) -> [B,H,D]
-    moe_impl: Optional[str] = None,
+    moe_dense: bool = False,
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
 ):
     """One batched decode step over the slot cache.
@@ -839,11 +826,7 @@ def decode_step(
     valid rows (with sliding window if configured), and returns
     (logits [B, V] fp32, k_cache', v_cache'[, (k_scales', v_scales')][, stats]).
     Intended to be jitted with the caches donated so XLA updates them in
-    place. Besides the single-dispatch scan, this is the body the
-    multi-tick decode megagraph (TPUEngine._mega_impl) iterates under
-    lax.while_loop — keep it free of host callbacks and shape-dependent
-    Python branching on traced values, or the K-tick window stops
-    lowering to one device program.
+    place.
 
     ``active`` — slots marked False write their (ignored) K/V to the
     sacrificial last cache row and attend over zero rows, so an inactive or
@@ -943,12 +926,12 @@ def decode_step(
             else:
                 attn = gqa_attention(q, k_l, v_l, mask)
         x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
-        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
         return (x, stats), (k_l, v_l, *((k_s, v_s) if quant_cache else ()))
 
     x, k_cache, v_cache, scales, stats = _scan_layers_over_cache(
         block, x, params, k_cache, v_cache, cache_scales, cfg,
-        moe_mod.grouped_serves(B, cfg, moe_impl),
+        moe_mod.grouped_serves(B, cfg, moe_dense),
     )
     logits = _final_logits(x[:, 0], params, cfg, qmm)
     if quant_cache:
@@ -1091,7 +1074,7 @@ def prefill_chunk_paged(
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
     win_start: Optional[jnp.ndarray] = None,  # scalar: live window start
     sink_rows: int = 0,  # static sink rows (window+sink KV compression)
-    moe_impl: Optional[str] = None,
+    moe_dense: bool = False,
 ):
     """One chunk of an incremental prefill against the PAGED cache.
 
@@ -1117,7 +1100,7 @@ def prefill_chunk_paged(
 
         return latent.prefill_chunk_paged(
             params, cfg, tokens, start, k_pool, v_pool, table_row,
-            qmm=qmm, moe_impl=moe_impl,
+            qmm=qmm, moe_dense=moe_dense,
         )
     B, Tc = tokens.shape
     MB = table_row.shape[0]
@@ -1168,12 +1151,12 @@ def prefill_chunk_paged(
             sink=sink_rows,
         )
         x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
-        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
         return (x, k_pool, v_pool, tuple(scales), stats), None
 
     x, k_pool, v_pool, scales, stats = _scan_layers_over_pool(
         block, x, params, k_pool, v_pool, cache_scales, cfg,
-        moe_mod.grouped_serves(B * Tc, cfg, moe_impl),
+        moe_mod.grouped_serves(B * Tc, cfg, moe_dense),
     )
     logits = _final_logits(x, params, cfg, qmm)
     if quant_pool:
@@ -1192,7 +1175,7 @@ def decode_step_paged(
     kernels: Optional[bool] = None,
     cache_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     active: Optional[jnp.ndarray] = None,  # [B] bool
-    moe_impl: Optional[str] = None,
+    moe_dense: bool = False,
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
     pool_impl=None,  # per-device pool write+attend; see ShardingPlan
     win_starts: Optional[jnp.ndarray] = None,  # [B] int32 live-window start
@@ -1234,7 +1217,7 @@ def decode_step_paged(
 
         return latent.decode_step_paged(
             params, cfg, tokens, lengths, k_pool, v_pool, tables,
-            kernels=kernels, active=active, moe_impl=moe_impl, qmm=qmm,
+            kernels=kernels, active=active, moe_dense=moe_dense, qmm=qmm,
         )
     B = tokens.shape[0]
     P = k_pool.shape[2]
@@ -1344,12 +1327,12 @@ def decode_step_paged(
         with jax.named_scope("attn_out"):
             x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
         with jax.named_scope(ffn_scope):
-            x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+            x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
         return (x, k_pool, v_pool, tuple(scales), stats), None
 
     x, k_pool, v_pool, scales, stats = _scan_layers_over_pool(
         block, x, params, k_pool, v_pool, cache_scales, cfg,
-        moe_mod.grouped_serves(B, cfg, moe_impl),
+        moe_mod.grouped_serves(B, cfg, moe_dense),
     )
     with jax.named_scope("final_logits"):
         logits = _final_logits(x[:, 0], params, cfg, qmm)
@@ -1368,7 +1351,7 @@ def verify_step_paged(
     tables: jnp.ndarray,  # [B, MB] int32
     cache_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     active: Optional[jnp.ndarray] = None,  # [B] bool
-    moe_impl: Optional[str] = None,
+    moe_dense: bool = False,
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
     win_starts: Optional[jnp.ndarray] = None,  # [B] int32 live-window start
     sink_rows: int = 0,  # static sink rows (window+sink KV compression)
@@ -1389,7 +1372,7 @@ def verify_step_paged(
 
         return latent.verify_step_paged(
             params, cfg, tokens, lengths, k_pool, v_pool, tables,
-            active=active, moe_impl=moe_impl, qmm=qmm,
+            active=active, moe_dense=moe_dense, qmm=qmm,
         )
     B, T = tokens.shape
     MB = tables.shape[1]
@@ -1446,12 +1429,12 @@ def verify_step_paged(
             v_all = ops.gather_pages(v_pool, l, tables, cfg.head_dim)
         attn = gqa_attention(q, k_all, v_all, mask)
         x = x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row")
-        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
         return (x, k_pool, v_pool, tuple(scales), stats), None
 
     x, k_pool, v_pool, scales, stats = _scan_layers_over_pool(
         block, x, params, k_pool, v_pool, cache_scales, cfg,
-        moe_mod.grouped_serves(B * T, cfg, moe_impl),
+        moe_mod.grouped_serves(B * T, cfg, moe_dense),
     )
     logits = _final_logits(x, params, cfg, qmm)
     if quant_pool:
@@ -1469,7 +1452,7 @@ def verify_step(
     kernels: Optional[bool] = None,
     cache_scales: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     active: Optional[jnp.ndarray] = None,  # [B] bool
-    moe_impl: Optional[str] = None,
+    moe_dense: bool = False,
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
 ):
     """Batched multi-token decode for speculative verification.
@@ -1579,12 +1562,12 @@ def verify_step(
             else:
                 attn = gqa_attention(q, k_l, v_l, mask)
         x = x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row")
-        x, stats = _add_mlp(x, stats, lp, cfg, moe_impl, qmm)
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
         return (x, stats), (k_l, v_l, *((k_s, v_s) if quant_cache else ()))
 
     x, k_cache, v_cache, scales, stats = _scan_layers_over_cache(
         block, x, params, k_cache, v_cache, cache_scales, cfg,
-        moe_mod.grouped_serves(B * T, cfg, moe_impl),
+        moe_mod.grouped_serves(B * T, cfg, moe_dense),
     )
     logits = _final_logits(x, params, cfg, qmm)
     if quant_cache:
@@ -1740,8 +1723,8 @@ def init_quantized_params(
     if cfg.moe:
         X, Fm = cfg.num_experts, cfg.expert_dim
         layers["w_router"] = normal("layers/w_router", (L, E, X))
-        # expert leaves stay int8 in int4 mode (the gathered-expert decode
-        # path is int8-specialized, matching quantize_params)
+        # expert leaves stay int8 in int4 mode (moe._expert_einsum reads
+        # int8 leaves only, matching quantize_params)
         if fuse:
             layers["we_gateup"] = qleaf(
                 "layers/we_gateup", (L, X, E, 2 * Fm), force_int8=True

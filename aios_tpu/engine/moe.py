@@ -21,9 +21,6 @@ shapes by ``grouped_serves``):
     device computes its local experts and XLA inserts one psum over ``ep``
     — no hand-written collectives, same GSPMD recipe as the Megatron TP
     rules (parallel/sharding.py).
-  * ``moe_ffn_gather`` — the operator's opt-in for decode at few slots
-    (engine/engine.py resolves it): one gathered weight block a pick
-    instead of every expert's stream.
   * ``moe_ffn_dispatch`` — GShard-style capacity-based dispatch/combine
     one-hot einsums: tokens route to per-expert queues of ``capacity``
     slots, experts run a batched SwiGLU over their queues, outputs combine
@@ -203,65 +200,6 @@ def moe_ffn_dense(
     return out.reshape(B, T, E), aux
 
 
-def moe_ffn_gather(
-    h: jnp.ndarray,  # [B, T, E] normalized hidden states
-    lp,
-    cfg: ModelConfig,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Gathered-expert MoE FFN for SMALL token counts; returns (out, aux).
-
-    Decode is weight-bandwidth-bound, and with N*k picks below the expert
-    count most experts are idle — so instead of streaming every expert's
-    weights (moe_ffn_dense), gather exactly the N*k routed experts' weight
-    blocks and run one batched per-pick SwiGLU. HBM traffic drops from
-    X * 3EF bytes to N*k * 3EF bytes per layer: ~16x less FFN traffic for
-    a single request on a top-8-of-128 model (qwen3-30b-a3b), ~2x at batch
-    8. Exact and dropless — identical math to the dense path, reordered.
-
-    Single-device layouts only: the weight gather indexes the expert axis,
-    which under expert parallelism is sharded (an ep-sharded gather would
-    bounce picks across chips; the dense path's psum handles that case).
-    """
-    B, T, E = h.shape
-    N = B * T
-    k = cfg.num_experts_per_tok
-    flat = h.reshape(N, E)
-    probs, weights, idx = route(flat, lp["w_router"], cfg)
-    # a pick of an absent expert gathers a held one's block at weight zero
-    weights, idx_here, _ = local_picks(weights, idx, cfg)
-    picks = idx_here.reshape(N * k)  # [P] held-expert id per pick
-    x_pick = jnp.repeat(flat, k, axis=0)  # [P, E] token repeated per pick
-
-    def pick_einsum(x, w):  # x [P, E or F], w [X, in, out] -> [P, out]
-        if isinstance(w, dict):
-            w_q, s = w["q"], w["s"]  # s [X, 1, out]
-            y = jnp.einsum(
-                "pi,pio->po",
-                x,
-                w_q[picks],
-                preferred_element_type=jnp.float32,
-            )
-            return (y * s[picks, 0, :]).astype(x.dtype)
-        return jnp.einsum("pi,pio->po", x, w[picks])
-
-    if "we_gateup" in lp:  # fused serving layout (quantize_params)
-        F = cfg.expert_dim
-        gu = pick_einsum(x_pick, lp["we_gateup"])
-        g, u = gu[..., :F], gu[..., F:]
-    else:
-        g = pick_einsum(x_pick, lp["we_gate"])
-        u = pick_einsum(x_pick, lp["we_up"])
-    z = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * u  # [P, F]
-    y_pick = pick_einsum(z, lp["we_down"])  # [P, E]
-    out = jnp.sum(
-        y_pick.reshape(N, k, E).astype(jnp.float32)
-        * weights[..., None],
-        axis=1,
-    ).astype(h.dtype)
-    aux = load_balance_aux(probs, idx, cfg.num_experts)
-    return out.reshape(B, T, E), aux
-
-
 def moe_ffn_dispatch(
     h: jnp.ndarray,  # [B, T, E] normalized hidden states
     lp,
@@ -343,17 +281,17 @@ def grouped_pays(n_tok: int, cfg: ModelConfig) -> bool:
 
 
 def grouped_serves(
-    n_tok: int, cfg: ModelConfig, moe_impl: Optional[str] = None,
+    n_tok: int, cfg: ModelConfig, moe_dense: bool = False,
     allow_dispatch: bool = False,
 ) -> bool:
     """Whether a graph over ``n_tok`` tokens runs its expert layers through
-    ``moe_ffn_grouped``: no path forced, not the training forward (its loop
-    runs a data-dependent number of tiles, which reverse-mode
-    differentiation cannot unroll), and the path pays. model.ffn and the
-    layer scans that hand it the expert stacks whole ask the same
-    question."""
+    ``moe_ffn_grouped``: not an engine's under a sharding plan
+    (``moe_dense``), not the training forward (its loop runs a
+    data-dependent number of tiles, which reverse-mode differentiation
+    cannot unroll), and the path pays. model.ffn and the layer scans that
+    hand it the expert stacks whole ask the same question."""
     return (
-        cfg.moe and not moe_impl and not allow_dispatch
+        cfg.moe and not moe_dense and not allow_dispatch
         and grouped_pays(n_tok, cfg)
     )
 

@@ -88,13 +88,6 @@ def sample(
     truncated — even at top_p=1.0 — matching llama-server, whose default
     chain applies top-k 40 before top-p. Raise AIOS_TPU_SAMPLE_POOL if a
     deployment needs a wider nucleus.
-
-    This is also the per-tick sampler inside the multi-tick decode
-    megagraph (TPUEngine._mega_impl): each while_loop iteration calls it
-    with one key from the same fixed ``split(key, K + 1)`` fanout the
-    single-dispatch scan uses, so a K-tick device window draws exactly
-    the random stream K chained host dispatches would — the byte-identity
-    contract for sampled slots rests on this function being cadence-blind.
     """
     B, V = logits.shape
     K = min(topk_cap(), V)

@@ -98,11 +98,6 @@ POINTS = (
     "net.partition_oneway",   # src->dst dropped, reverse clean
     "net.delay",              # per-edge latency (delay_ms)
     "net.drop_after",         # stream severed after after_msgs messages
-    # multi-tick decode megagraph (engine.py _mega_dispatch): caps the
-    # device while-loop's abort_after operand mid-window (ticks param)
-    # so the early-exit path fires with slots still live — the chaos
-    # storm's proof that a k<K readback retires/streams correctly
-    "pool.megatick_abort",
 )
 
 MODES = ("nth", "prob", "after")
@@ -120,7 +115,6 @@ _PARAM_DEFAULTS: Dict[str, Dict[str, float]] = {
     "rpc.unavailable": {"retry_after_ms": 1000.0},
     "net.delay": {"delay_ms": 50.0},
     "net.drop_after": {"after_msgs": 3.0},
-    "pool.megatick_abort": {"ticks": 1.0},
 }
 
 # param keys whose values are strings, not floats — the per-edge scoping
@@ -154,11 +148,6 @@ class FaultAction:
     # net.drop_after only: how many stream messages flow before the
     # sever (the mid-transfer cut the resume ladder must survive)
     after_msgs: int = 3
-    # pool.megatick_abort only: cap the megagraph's abort_after operand
-    # at this many ticks (0 = the call site's half-window default) —
-    # the injected "host attention needed" demand that forces the
-    # device loop's early-exit branch mid-window
-    ticks: int = 0
 
 
 @dataclass
@@ -245,7 +234,6 @@ class FaultPlan:
                 retry_after_ms=int(spec.params.get("retry_after_ms", 1000)),
                 exit=bool(spec.params.get("exit", 0.0)),
                 after_msgs=int(spec.params.get("after_msgs", 3.0)),
-                ticks=int(spec.params.get("ticks", 0.0)),
             )
             entry = {"point": name, "mode": spec.mode, "hit": hit,
                      "model": model}

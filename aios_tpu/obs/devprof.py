@@ -78,7 +78,7 @@ __all__ = [
 # else, so a new graph family is a reviewed enum change, not a stray
 # string growing the ``graph`` label set.
 GRAPH_KINDS = (
-    "step",          # plain/unified decode (the dispatch-worker path)
+    "step",          # plain decode (the dispatch-worker path)
     "masked",        # grammar-masked 1-step decode
     "prefill",       # whole-prompt prefill buckets
     "seq_prefill",   # sequence-sharded (sp-axis) prefill twins
@@ -87,7 +87,6 @@ GRAPH_KINDS = (
     "draft_spec",    # fused draft-model propose+verify rounds
     "draft_ingest",  # bulk draft-KV catch-up writes
     "jump",          # grammar jump-ahead multi-token verify
-    "mega",          # multi-tick decode megagraph (K ticks per dispatch)
     "restore",       # host-tier KV restore scatters
     "hist",          # prefix-hit history backfill
 )

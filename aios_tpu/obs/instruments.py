@@ -170,23 +170,6 @@ ENGINE_JUMP_TOKENS = Gauge(
     ("model",),
 )
 
-# -- multi-tick decode megagraph (engine.mega_step) — same WeakSet-summed
-# monotonic engine counters as the jump family. dispatches * K - ticks
-# is the early-exit savings; ticks / dispatches the realized window.
-
-ENGINE_MEGA_DISPATCHES = Gauge(
-    "aios_tpu_engine_mega_dispatches_total",
-    "Multi-tick decode megagraph dispatches (each replaced up to K "
-    "single-tick dispatches; monotonic, summed over replica engines)",
-    ("model",),
-)
-ENGINE_MEGA_TICKS = Gauge(
-    "aios_tpu_engine_mega_ticks_total",
-    "REAL decode ticks run inside megagraph dispatches (k per dispatch, "
-    "k <= K on early exit; monotonic, summed over replica engines)",
-    ("model",),
-)
-
 # -- speculative decoding (engine.spec_step / spec_step_draft) -------------
 # Rounds/accepted are engine counters (WeakSet-summed like the jump
 # family); the acceptance ratio is the per-batcher EWMA driving the

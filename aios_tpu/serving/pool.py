@@ -598,15 +598,6 @@ class ReplicaPool:
             out[f"replica{r.idx}_occupancy"] = round(r.occupancy(), 3)
         if occ:
             out["batch_occupancy"] = round(sum(occ) / len(occ), 3)
-        # the armed megagraph window (PR 19): engines emit the summed
-        # mega_dispatches/mega_ticks counters; K itself is config, so
-        # surface it here — dispatches * K - ticks is the early-exit
-        # savings fleetctl top renders fleet-wide
-        mega_k = max(
-            (r.engine.mega_ticks for r in self.replicas), default=0
-        )
-        if mega_k:
-            out["mega_k"] = mega_k
         with self._lock:
             for reason, n in self._routed.items():
                 out[f"routed_{reason}"] = n
@@ -623,7 +614,6 @@ class ReplicaPool:
             k: s[k]
             for k in ("replicas", "replica_restarts", "degrade_level",
                       "batch_occupancy", "waiting", "completed",
-                      "num_slots", "mega_dispatches", "mega_ticks",
-                      "mega_k")
+                      "num_slots")
             if k in s
         }

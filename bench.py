@@ -890,16 +890,11 @@ def bench_chaos(seed: int = 42) -> int:
         (prob-mode delay faults shape load and are excluded: their hit
         counts ride thread timing by design).
 
-    The storm runs FOUR ARMS, each twice: the plain pool; a DRAFT-MODE
+    The storm runs THREE ARMS, each twice: the plain pool; a DRAFT-MODE
     pool (ISSUE 11 — draft-model speculation attached, speculative
     batchers) so the determinism contract is pinned for the draft
-    proposer's fused dispatches and failover-time draft-KV rebuilds; a
-    LONGCTX pool (ISSUE 13 — paged KV compression); and a MEGA pool
-    (ISSUE 19 — mega_ticks=8 device-resident decode windows) whose
-    schedule adds pool.megatick_abort so a seeded device early-exit
-    fires mid-window ON TOP of the crash/delay storm — and whose greedy
-    streams must still match the plain arm token for token (greedy
-    streams are dispatch-shape invariant).
+    proposer's fused dispatches and failover-time draft-KV rebuilds; and
+    a LONGCTX pool (ISSUE 13 — paged KV compression).
 
     docs/TESTING.md wires scripts/chaos.sh (this scenario) next to
     scripts/analyze.sh as the pre-merge robustness gate."""
@@ -925,15 +920,8 @@ def bench_chaos(seed: int = 42) -> int:
                                    dtype=jnp.float32)
     draft_model = spec_mod.DraftModel(cfg, params, quantize=None)
 
-    def run_once(with_draft: bool, longctx: bool = False,
-                 mega: bool = False):
-        # the mega arm layers a seeded mid-window device abort on top of
-        # the shared storm (passed per-arm so the other arms' schedules
-        # — and their nth fingerprints — stay byte-identical)
-        plan = faults.activate(
-            schedule + ";pool.megatick_abort=nth:2,ticks=1" if mega
-            else schedule
-        )
+    def run_once(with_draft: bool, longctx: bool = False):
+        plan = faults.activate(schedule)
         # the longctx arm serves a paged pool with window+sink KV
         # compression armed and prompts LONG enough to cross the
         # threshold mid-storm: pruning + masked decode + failover
@@ -944,9 +932,6 @@ def bench_chaos(seed: int = 42) -> int:
             eng_kw = dict(paged_pool_rows=512, page_size=16,
                           prefix_cache=False, kv_compress_after=96,
                           kv_sink_pages=1, kv_window_pages=4)
-        if mega:
-            eng_kw = dict(mega_ticks=8)
-        chunk = 8 if mega else 2
         engines = [
             TPUEngine(cfg, params, num_slots=2, max_context=256,
                       cache_dtype=jnp.float32,
@@ -956,8 +941,8 @@ def bench_chaos(seed: int = 42) -> int:
         ]
         pool = ReplicaPool(
             "chaos", engines,
-            lambda e: ContinuousBatcher(e, chunk_steps=chunk,
-                                        admit_chunk_steps=chunk,
+            lambda e: ContinuousBatcher(e, chunk_steps=2,
+                                        admit_chunk_steps=2,
                                         speculative=with_draft,
                                         spec_draft_len=3),
             ServingConfig(replicas=2, failover_retries=3),
@@ -1006,12 +991,12 @@ def bench_chaos(seed: int = 42) -> int:
         }
 
     arms = {}
-    for arm, with_draft, longctx, mega in (
-        ("plain", False, False, False), ("draft", True, False, False),
-        ("longctx", False, True, False), ("mega", False, False, True),
+    for arm, with_draft, longctx in (
+        ("plain", False, False), ("draft", True, False),
+        ("longctx", False, True),
     ):
-        a = run_once(with_draft, longctx, mega)
-        b = run_once(with_draft, longctx, mega)
+        a = run_once(with_draft, longctx)
+        b = run_once(with_draft, longctx)
         complete = all(
             s is not None and len(s) == max_tokens for s in a["streams"]
         )
@@ -1048,43 +1033,21 @@ def bench_chaos(seed: int = 42) -> int:
     spec_identical = (
         arms["draft"]["a"]["streams"] == arms["plain"]["a"]["streams"]
     )
-    # same contract for the megagraph arm: K-tick device windows (with
-    # a seeded mid-window abort forcing the early-exit path) may change
-    # dispatch counts, never tokens — greedy streams are dispatch-shape
-    # invariant, so chunk-8 mega output must equal the chunk-2 plain arm
-    mega_identical = (
-        arms["mega"]["a"]["streams"] == arms["plain"]["a"]["streams"]
-    )
-    # the abort must actually have FIRED in the mega arm (nth-mode, so
-    # it is part of the determinism fingerprint too)
-    mega_abort_fired = any(
-        p == "pool.megatick_abort"
-        for p, _ in arms["mega"]["a"]["nth_faults"]
-    )
-    if not mega_abort_fired:
-        log("[chaos] pool.megatick_abort never fired in the mega arm — "
-            "the early-exit path went unexercised")
     ok = (stuck == 0 and aborted == 0 and complete and deterministic
-          and spec_identical and mega_identical and mega_abort_fired
-          and armed)
+          and spec_identical and armed)
     pa, da = arms["plain"]["a"], arms["draft"]["a"]
     la = arms["longctx"]["a"]
-    ma = arms["mega"]["a"]
     log(f"[chaos] seed={seed} restarts plain="
         f"{pa['restarts']}/{arms['plain']['b']['restarts']} draft="
         f"{da['restarts']}/{arms['draft']['b']['restarts']} longctx="
-        f"{la['restarts']}/{arms['longctx']['b']['restarts']} mega="
-        f"{ma['restarts']}/{arms['mega']['b']['restarts']} "
+        f"{la['restarts']}/{arms['longctx']['b']['restarts']} "
         f"stuck={stuck} aborted={aborted} deterministic={deterministic} "
         f"draft_streams_match={spec_identical} "
-        f"mega_streams_match={mega_identical} "
-        f"mega_abort_fired={mega_abort_fired} "
         f"verdict={'PASS' if ok else 'FAIL'}")
     emit({
         "metric": "chaos storm (seeded crash + dispatch delay, "
                   "2-replica pool, plain + draft-speculation + "
-                  "longctx-compression + megagraph-decode arms, each "
-                  "run twice)",
+                  "longctx-compression arms, each run twice)",
         "value": 1.0 if ok else 0.0,
         "unit": "verdict (1 = pass)",
         "vs_baseline": 1.0 if ok else 0.0,
@@ -1106,11 +1069,8 @@ def bench_chaos(seed: int = 42) -> int:
         },
         "nth_fault_sequence": pa["nth_faults"],
         "nth_fault_sequence_draft": da["nth_faults"],
-        "nth_fault_sequence_mega": ma["nth_faults"],
         "deterministic": deterministic,
         "draft_streams_match_plain": spec_identical,
-        "mega_streams_match_plain": mega_identical,
-        "mega_abort_fired": mega_abort_fired,
         "streams_complete": complete,
         "faults_armed": armed,
     })
@@ -1539,134 +1499,6 @@ def bench_dispatch():
         # The mechanism (identical streams, dispatch worker overlap) is
         # what this probe regression-guards; absolute gains need the TPU
         # (device compute does not contend with the host there).
-        "cpu_cores": os.cpu_count(),
-    }
-
-
-def bench_mega():
-    """Multi-tick decode megagraph A/B (AIOS_TPU_MEGA_TICKS, ISSUE 19):
-    8 concurrent greedy requests per wave through the production
-    batcher, K=1 single-tick dispatches vs K=8 device-resident windows,
-    identical token streams asserted across arms.
-
-    Same pairing discipline as bench_dispatch (both arms resident, waves
-    order-alternated, median of per-pair tok/s ratios) because this
-    container's CPU availability swings ~2x on a seconds timescale. The
-    DETERMINISTIC headline is the decode-dispatch reduction: the K=1 arm
-    pays one host round-trip per tick while the K=8 arm retires up to 8
-    ticks per dispatch — a count, not a timing, so it holds on any
-    backend. Wall-clock on CPU understates the win (XLA executes inline
-    in the dispatching thread, so the readback it amortizes is cheap
-    here); the host gap per dispatch is reported for both arms."""
-    import statistics
-
-    import jax
-    import jax.numpy as jnp
-
-    from aios_tpu.engine import model as model_mod
-    from aios_tpu.engine.batching import ContinuousBatcher, Request
-    from aios_tpu.engine.config import TINY_TEST
-    from aios_tpu.engine.engine import TPUEngine
-
-    cfg = TINY_TEST.scaled(
-        name="micro-mega", num_layers=1, hidden_size=32,
-        intermediate_size=64, num_heads=2, num_kv_heads=1, head_dim=16,
-        vocab_size=256, max_context=512,
-    )
-    params = model_mod.init_params(cfg, jax.random.PRNGKey(0),
-                                   dtype=jnp.float32)
-    K, max_tokens, slots, pairs = 8, 256, 8, 9
-
-    def wave(batcher):
-        eng = batcher.engine
-        d0 = eng.mega_dispatches if eng.mega_ticks else eng.decode_steps
-        handles = [
-            batcher.submit(Request(prompt_ids=[3 + i, 17, 91],
-                                   max_tokens=max_tokens, temperature=0.0))
-            for i in range(slots)
-        ]
-        t0 = time.time()
-        out = [h.tokens() for h in handles]
-        dt = time.time() - t0
-        d1 = eng.mega_dispatches if eng.mega_ticks else eng.decode_steps
-        return sum(len(t) for t in out) / dt, out, d1 - d0
-
-    arms = []  # (engine, batcher) for K=1, K=8
-    try:
-        for mega in (0, K):
-            eng = TPUEngine(cfg, params, num_slots=slots, max_context=512,
-                            cache_dtype=jnp.float32, mega_ticks=mega)
-            # the K=1 arm dispatches 1-tick scan graphs (chunk_steps=1:
-            # one host round-trip per token — the loop mega replaces);
-            # the K=8 arm dispatches K-tick device windows
-            eng.warmup(step_sizes=(1, K) if not mega else (K,),
-                       prefill_chunk=0)
-            batcher = ContinuousBatcher(
-                eng, chunk_steps=1 if not mega else K,
-                admit_chunk_steps=1 if not mega else K, pipeline=True,
-            )
-            wave(batcher)  # steady state before any measured pair
-            arms.append((eng, batcher))
-        ratios, identical = [], True
-        tps = {0: [], 1: []}
-        disp = {0: 0, 1: 0}
-        for pair in range(pairs):
-            order = (0, 1) if pair % 2 == 0 else (1, 0)
-            got = {}
-            for idx in order:
-                got[idx] = wave(arms[idx][1])
-            identical = identical and got[0][1] == got[1][1]
-            ratios.append(got[1][0] / max(got[0][0], 1e-9))
-            for idx in (0, 1):
-                tps[idx].append(got[idx][0])
-                disp[idx] += got[idx][2]
-        gaps = {
-            idx: b.host_gap_seconds / max(b.decode_dispatches, 1) * 1e3
-            for idx, (_, b) in enumerate(arms)
-        }
-        mega_ticks_run = arms[1][0].mega_tick_total
-    finally:
-        for eng, batcher in arms:
-            batcher.shutdown()
-            eng.close()
-    # deterministic headline: decode dispatches the K=8 windows replaced
-    # (greedy wave, fixed budgets — identical on every backend)
-    reduction = disp[0] / max(disp[1], 1)
-    ratios_sorted = sorted(ratios)
-    speedup = statistics.median(ratios)
-    q25 = ratios_sorted[len(ratios) // 4]
-    q75 = ratios_sorted[-1 - len(ratios) // 4]
-    log(f"[mega] K=1 med {statistics.median(tps[0]):.0f} tok/s "
-        f"(gap {gaps[0]:.2f} ms, {disp[0]} dispatches) -> K={K} med "
-        f"{statistics.median(tps[1]):.0f} tok/s (gap {gaps[1]:.2f} ms, "
-        f"{disp[1]} dispatches, {mega_ticks_run} ticks); dispatch "
-        f"reduction {reduction:.1f}x, wall median {speedup:.2f}x "
-        f"(IQR {q25:.2f}-{q75:.2f}), identical={identical}")
-    return {
-        "metric": "multi-tick decode megagraph A/B, continuous batcher "
-                  f"(batch {slots}, K=1 vs K={K}, {pairs} "
-                  "order-alternated paired waves, micro geometry)",
-        "value": round(reduction, 3),
-        "unit": f"x decode-dispatch reduction (K={K} vs K=1, "
-                "greedy wave)",
-        "vs_baseline": round(reduction, 3),
-        "wallclock_ratio_median": round(speedup, 3),
-        "pair_ratios": [round(r, 3) for r in ratios],
-        "ratio_iqr": [round(q25, 3), round(q75, 3)],
-        "tps_k1": round(statistics.median(tps[0]), 1),
-        "tps_k8": round(statistics.median(tps[1]), 1),
-        "dispatches_k1": int(disp[0]),
-        "dispatches_k8": int(disp[1]),
-        "mega_ticks_run": int(mega_ticks_run),
-        "host_gap_ms_k1": round(gaps[0], 3),
-        "host_gap_ms_k8": round(gaps[1], 3),
-        "tokens_identical": bool(identical),
-        "slo": slo_block("micro-mega"),
-        # CPU-bench caveat (docs/ENGINE_PERF.md): XLA executes inline in
-        # the dispatching thread here, so the amortized host round-trip
-        # is a small slice of each dispatch — the dispatch-count
-        # reduction is the backend-independent signal; the wall-clock
-        # delta needs the TPU.
         "cpu_cores": os.cpu_count(),
     }
 
@@ -2189,69 +2021,6 @@ def bench_draft():
     }
 
 
-def bench_moe_gather():
-    """Gathered-expert MoE decode A/B on the real chip: a ~2.3B-param
-    MoE geometry (32 experts, top-4 — qwen3-moe-style, scaled to fit one
-    chip's HBM comfortably) decoded single-request with the gathered path
-    (streams only the routed experts' weights; AIOS_TPU_MOE_GATHER opt-in)
-    vs the dense-all-experts path. Measured r3: gather 126.5 vs dense
-    216.4 tok/s — the expert gather costs more than the skipped streaming
-    saves at this geometry, which is why dense is the engine default;
-    qwen3-30b-a3b itself needs a multi-chip slice (--virtual-ep)."""
-    import jax
-    import jax.numpy as jnp
-
-    from aios_tpu.engine import model as model_mod
-    from aios_tpu.engine.config import QWEN3_30B_A3B
-    from aios_tpu.engine.engine import TPUEngine
-
-    cfg = QWEN3_30B_A3B.scaled(
-        name="qwen3-moe-2b-geometry",
-        vocab_size=32000,
-        hidden_size=1024,
-        intermediate_size=2816,
-        moe_intermediate_size=1408,
-        num_layers=16,
-        num_heads=16,
-        num_kv_heads=4,
-        head_dim=64,
-        num_experts=32,
-        num_experts_per_tok=4,
-        max_context=1024,
-    )
-    params = model_mod.init_quantized_params(cfg, jax.random.PRNGKey(0))
-    weight_bytes = model_mod.serving_weight_bytes(params)
-    chunk, rounds = 64, 2
-    results = {}
-    for impl in ("gather", "dense"):
-        eng = TPUEngine(cfg, params, num_slots=1, max_context=1024,
-                        cache_dtype=jnp.bfloat16)
-        # force each arm explicitly (the engine default is dense; gather
-        # is the AIOS_TPU_MOE_GATHER opt-in, sparse-eligible here: 1*4<32)
-        eng._moe_impl = "gather" if impl == "gather" else None
-        eng.prefill(0, list(range(1, 65)), temperature=0.7, top_p=0.95)
-        eng.step(chunk)  # compile
-        eng.step(chunk)  # warm
-        t0 = time.time()
-        for _ in range(rounds):
-            eng.step(chunk)
-        dt = time.time() - t0
-        eng.close()
-        results[impl] = chunk * rounds / dt
-        log(f"[moe-gather] {impl}: {results[impl]:.1f} tok/s")
-    speedup = results["gather"] / max(results["dense"], 1e-9)
-    return {
-        "metric": "moe gathered-expert single-request decode "
-                  "(2.3B geometry, 32 experts top-4, int8)",
-        "value": round(results["gather"], 1),
-        "unit": "tokens/sec/chip",
-        "vs_baseline": round(results["gather"] / BASELINE_CPU_TPS, 1),
-        "dense_all_experts_tok_per_s": round(results["dense"], 1),
-        "gather_speedup": round(speedup, 2),
-        "weights_gb": round(weight_bytes / 1e9, 2),
-    }
-
-
 def bench_int8_kv_ragged_ab():
     """A/B the env-gated int8-KV ragged kernel (AIOS_TPU_INT8_RAGGED) on a
     long-context int8-KV TinyLlama: flag OFF = the dequantizing XLA
@@ -2754,9 +2523,8 @@ def main() -> int:
     extra = [] if args.skip_mistral else [bench_mixed_tier, bench_spec_decode]
     extra.extend([
         bench_paged_kv, bench_host_tier, bench_longctx, bench_dispatch,
-        bench_mega, bench_tsdb, bench_devprof, bench_structured, bench_draft,
-        bench_agent_ttft, bench_moe_gather, bench_int8_kv_ragged_ab,
-        bench_orchestrator_e2e,
+        bench_tsdb, bench_devprof, bench_structured, bench_draft,
+        bench_agent_ttft, bench_int8_kv_ragged_ab, bench_orchestrator_e2e,
     ])
     if args.fast:
         extra = []

@@ -5,23 +5,18 @@
 #
 # Runs bench.py --chaos: the SAME seeded fault schedule (replica
 # scheduler crash + probabilistic dispatch delays) against fresh pools
-# under a concurrent greedy wave — FOUR ARMS (a plain pool; a
+# under a concurrent greedy wave — THREE ARMS (a plain pool; a
 # draft-speculation pool with a paired DraftModel + speculative
-# batchers; a longctx pool with window+sink KV compression armed
-# and prompts long enough to prune mid-storm; and a megagraph pool
-# serving mega_ticks=8 device-resident decode windows with
-# pool.megatick_abort layered on so a seeded device early-exit fires
-# mid-window on top of the crash), each run twice. Exit is
+# batchers; and a longctx pool with window+sink KV compression armed
+# and prompts long enough to prune mid-storm), each run twice. Exit is
 # NON-ZERO on any stuck request, any aborted stream (transparent
 # failover must complete every greedy request), a nondeterministic
 # re-run (token streams, terminal states, and the nth-mode
 # injected-fault sequence must be identical — including the compressed
-# arm's pruned streams), a draft-arm stream that diverges from the
+# arm's pruned streams), or a draft-arm stream that diverges from the
 # plain arm's (speculation may change dispatch counts, never tokens —
 # even across a mid-storm crash and the failover-time draft-KV
-# rebuild), a mega-arm stream that diverges from the plain arm's
-# (K-tick windows and forced early exits may change dispatch counts,
-# never tokens), or a mega arm whose seeded abort never fired.
+# rebuild).
 #
 # Usage:
 #   scripts/chaos.sh                 # default seed (42)

@@ -22,9 +22,8 @@ symmetric, so any member renders the whole fleet.
                       scriptable health probe), 2 when the target is
                       unreachable.
   * ``top``         — per-host load: pool occupancy / waiting / degrade
-                      rung, devprof MFU and device-seconds, SLO worst
-                      burn, and the megagraph early-exit savings
-                      (dispatches x K - ticks) — sorted worst-burn-first
+                      rung, devprof MFU and device-seconds, and SLO
+                      worst burn — sorted worst-burn-first
                       so the sick host is the top row; the worst few
                       tenants by TTFT burn fleet-wide render below the
                       table. Exit codes as ``status``.
@@ -104,23 +103,6 @@ def _pool_load(member: dict) -> tuple:
                         float(stats.get("batch_occupancy", 0.0) or 0.0))
         degrade = max(degrade, int(stats.get("degrade_level", 0) or 0))
     return waiting, occupancy, degrade
-
-
-def _mega_savings(member: dict) -> Optional[int]:
-    """Megagraph early-exit savings summed across the member's pools:
-    dispatches x K - ticks (the decode ticks the early exit never ran).
-    None when no pool runs the megagraph."""
-    savings = None
-    for name, stats in (member.get("pools") or {}).items():
-        if name == "_error" or not isinstance(stats, dict):
-            continue
-        k = int(stats.get("mega_k", 0) or 0)
-        dispatches = int(stats.get("mega_dispatches", 0) or 0)
-        if not k or not dispatches:
-            continue
-        ticks = int(stats.get("mega_ticks", 0) or 0)
-        savings = (savings or 0) + dispatches * k - ticks
-    return savings
 
 
 def _worst_tenants(members: List[dict], limit: int = 5) -> List[dict]:
@@ -212,7 +194,6 @@ def cmd_top(data: dict, as_json: bool = False) -> int:
                 "worst_burn": b, "occupancy": occupancy,
                 "waiting": waiting, "degrade_level": degrade,
                 "mfu": mfu, "device_seconds": secs,
-                "mega_savings": _mega_savings(m),
             })
         print(json.dumps({
             "cmd": "top", "pass": not not_up, "members": out,
@@ -224,17 +205,15 @@ def cmd_top(data: dict, as_json: bool = False) -> int:
         waiting, occupancy, degrade = _pool_load(m)
         mfu, secs = _mfu_secs(m)
         b = (m.get("slo") or {}).get("worst_burn")
-        save = _mega_savings(m)
         rows.append([
             m["host"], m["state"],
             f"{b:.2f}" if b is not None else "-",
             f"{occupancy:.2f}", waiting, degrade,
             f"{mfu:.3f}" if mfu is not None else "-",
             f"{secs:.2f}",
-            save if save is not None else "-",
         ])
     _table(rows, ["HOST", "STATE", "BURN", "OCCUP", "WAIT", "DEGRADE",
-                  "MFU", "DEV_SECS", "MEGA_SAVE"])
+                  "MFU", "DEV_SECS"])
     if tenants:
         log("")
         log("worst tenants by TTFT burn:")

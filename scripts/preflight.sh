@@ -11,10 +11,8 @@
 #   3. bench.py --chaos          — the seeded chaos storm, run twice,
 #                                  deterministic or fail (scripts/chaos.sh
 #                                  semantics, docs/FAULTS.md); arms cover
-#                                  plain, draft-speculation, longctx
-#                                  compression, and megagraph decode
-#                                  (mega_ticks=8 + a seeded mid-window
-#                                  pool.megatick_abort early exit);
+#                                  plain, draft-speculation and
+#                                  longctx compression;
 #   4. the devprof sentinel      — bench.py --devprof captured fresh and
 #                                  diffed against the committed
 #                                  BASELINE_DEVPROF.json by
@@ -103,7 +101,7 @@ scripts/analyze.sh
 echo "[preflight 2/9] obs-lint subset (tests/test_obs_lint.py)" >&2
 python -m pytest tests/test_obs_lint.py -q -p no:cacheprovider
 
-echo "[preflight 3/9] seeded chaos storm (bench.py --chaos; plain/draft/longctx/mega arms)" >&2
+echo "[preflight 3/9] seeded chaos storm (bench.py --chaos; plain/draft/longctx arms)" >&2
 python bench.py --chaos > "$workdir/chaos.json"
 
 echo "[preflight 4/9] devprof sentinel (bench.py --devprof vs" \

@@ -1,6 +1,6 @@
 """Pipelined decode loop + dispatch-free AOT warmup (ISSUE 6).
 
-Three guarantees under test:
+Two guarantees under test:
   * token identity: the depth-2 pipelined batcher (AIOS_TPU_DECODE_PIPELINE)
     emits byte-for-byte the streams the sync loop emits — greedy AND
     sampled with a fixed seed — including across retirement boundaries,
@@ -10,10 +10,7 @@ Three guarantees under test:
     the serving path can hit, so a post-warmup sweep across every prefill
     bucket, both chunked-admission paths, every decode chunk size, the
     masked step, and the prefix-hit path moves ``engine.stats()``'s
-    compile counters by exactly zero;
-  * the unified dynamic-step graph (AIOS_TPU_UNIFIED_STEP) is greedy-
-    identical to the per-size scan graphs and serves unwarmed chunk sizes
-    without compiling.
+    compile counters by exactly zero.
 """
 
 import json
@@ -26,7 +23,9 @@ import pytest
 from aios_tpu.engine import model as M
 from aios_tpu.engine.batching import ContinuousBatcher, Request
 from aios_tpu.engine.config import TINY_TEST
-from aios_tpu.engine.engine import TPUEngine
+from aios_tpu.engine.engine import (
+    ADMIT_DECODE_STEPS, DECODE_STEPS, JUMP_BUCKETS, TPUEngine,
+)
 from aios_tpu.engine.tokenizer import ByteTokenizer
 
 
@@ -266,28 +265,80 @@ def test_warmup_covers_host_tier_restore(params):
         eng.close()
 
 
-def test_unified_step_greedy_identical_one_graph(params):
-    """AIOS_TPU_UNIFIED_STEP mode: one dynamic-n graph serves every chunk
-    size (warmed or not) with zero extra compiles, and greedy output
-    matches the per-size scan graphs token-for-token."""
-    uni = make_engine(params, unified_step=True)
-    ref = make_engine(params)
+@pytest.mark.parametrize(
+    "mode", ["plain", "json_forced", "speculative", "plain_pipelined"])
+def test_warmup_and_attach_compile_exactly_what_the_loop_dispatches(
+        params, mode):
+    """After ``warmup()`` as the model manager calls it and a default
+    batcher's attach, the step registry holds the loop's two sizes (and
+    the masked step where json mode is forced) and nothing else, the
+    speculative and jump registries the same two sizes and the jump
+    buckets, and a serving wave with more requests than slots (so both
+    sizes dispatch) compiles nothing."""
+    tok = ByteTokenizer()
+    forced, spec = mode == "json_forced", mode == "speculative"
+    eng = make_engine(params, num_slots=2)
+    eng.warmup(masked_step=forced)
+    b = ContinuousBatcher(eng, speculative=spec, tokenizer=tok,
+                          pipeline=mode == "plain_pipelined")
     try:
-        uni.warmup(step_sizes=(1, 2, 8, 16), prefill_chunk=0)
-        step_graphs = [k for k in uni._step_fns if isinstance(k, tuple)]
-        assert step_graphs == [("uni", 16)]
-        before = uni.stats()["xla_compiles"]
-        prompt = [3, 17, 91, 4, 55, 8]
-        g_uni = [uni.prefill(0, prompt, temperature=0.0)]
-        g_ref = [ref.prefill(0, prompt, temperature=0.0)]
-        for n in (1, 2, 8, 5, 16, 3):  # 5 and 3 were never warmed
-            g_uni += [int(t) for t in uni.step(n)[:, 0]]
-            g_ref += [int(t) for t in ref.step(n)[:, 0]]
-        assert g_uni == g_ref
-        assert uni.stats()["xla_compiles"] == before
+        assert (b.admit_chunk_steps, b.chunk_steps) == (
+            ADMIT_DECODE_STEPS, DECODE_STEPS)
+        sizes = {ADMIT_DECODE_STEPS, DECODE_STEPS}
+        assert set(eng._step_fns) == sizes | ({"masked"} if forced else set())
+        assert {k[0] for k in eng._spec_fns} == (sizes if spec else set())
+        assert set(eng._jump_fns) == (set(JUMP_BUCKETS) if forced else set())
+        before = eng.stats()["xla_compiles"]
+        handles = [
+            b.submit(Request(prompt_ids=tok.encode("ab" * (3 + i)),
+                             max_tokens=24 + 7 * i, temperature=0.0,
+                             json_mode=forced and i == 1))
+            for i in range(5)
+        ]
+        outs = [h.tokens() for h in handles]
+        assert all(outs) and not any(h.aborted for h in handles)
+        assert eng.stats()["xla_compiles"] == before
     finally:
-        uni.close()
-        ref.close()
+        b.shutdown()
+        eng.close()
+
+
+@pytest.mark.parametrize(
+    "why", ["budget", "stop", "context_cap", "context_cap_pipelined"])
+def test_a_slot_that_cannot_take_another_row_finishes_and_frees(params, why):
+    """A request whose budget runs out, whose stop token comes, or whose
+    slot reaches the last row of its context in the middle of a dispatch
+    (2 steps each: a request waits) ends there, without an abort, and hands
+    its slot (the engine has one) to the request waiting behind it, which
+    runs to its end. The context cap is judged on the slot's length after
+    the dispatch, so the stream ends within a dispatch of the last row,
+    never beyond it."""
+    ctx = 32 if why.startswith("context_cap") else 128
+    free = run_batch(params, False, [dict(
+        prompt_ids=[3, 17, 91, 4], max_tokens=40, temperature=0.0)],
+        engine_kw=dict(num_slots=1), warm=False)[0][0]
+    first = dict(prompt_ids=[3, 17, 91, 4], max_tokens=40, temperature=0.0)
+    if why == "budget":
+        first["max_tokens"], want = 6, free[:6]
+    elif why == "stop":
+        stop = free[5]
+        first["stop_ids"], want = (stop,), free[:free.index(stop) + 1]
+    else:
+        want = None
+    second = dict(prompt_ids=[9, 8, 7], max_tokens=6, temperature=0.0)
+    outs, stats = run_batch(
+        params, why.endswith("pipelined"), [first, second],
+        engine_kw=dict(num_slots=1, max_context=ctx),
+        batcher_kw=dict(chunk_steps=DECODE_STEPS,
+                        admit_chunk_steps=ADMIT_DECODE_STEPS),
+        warm=False)
+    if want is None:
+        # 4 prompt rows of 32: at most 28 tokens, one of them the prefill's
+        assert ctx - 4 - DECODE_STEPS <= len(outs[0]) <= ctx - 4
+        want = free[:len(outs[0])]
+    assert outs[0] == want and 0 < len(want) < 40
+    assert len(outs[1]) == 6
+    assert stats["aborted"] == ["", ""]
 
 
 def test_batcher_attach_compiles_missing_sizes_without_dispatch(params):

@@ -172,133 +172,16 @@ def test_dispatch_drops_only_overflow_tokens():
     assert np.isfinite(float(aux))
 
 
-def test_gather_matches_dense():
-    """The gathered-expert path (stream only routed experts' weights) is
-    exact — identical math to dense, reordered."""
-    cfg = TINY_MOE
-    params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    lp = _layer0(params)
-    h = jax.random.normal(jax.random.PRNGKey(5), (2, 3, cfg.hidden_size))
-    dense, aux_d = moe_mod.moe_ffn_dense(h, lp, cfg)
-    gath, aux_g = moe_mod.moe_ffn_gather(h, lp, cfg)
-    np.testing.assert_allclose(gath, dense, atol=1e-6, rtol=1e-6)
-    np.testing.assert_allclose(aux_g, aux_d, atol=1e-6, rtol=1e-6)
-
-
-def test_gather_matches_dense_quantized():
-    cfg = TINY_MOE
-    params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    h = jax.random.normal(jax.random.PRNGKey(6), (1, 2, cfg.hidden_size))
-    for fuse in (True, False):
-        qp = M.quantize_params(params, fuse=fuse)
-        lp = {
-            k: (v[0] if not isinstance(v, dict)
-                else {"q": v["q"][0], "s": v["s"][0]})
-            for k, v in qp["layers"].items()
-        }
-        dense, _ = moe_mod.moe_ffn_dense(h, lp, cfg)
-        gath, _ = moe_mod.moe_ffn_gather(h, lp, cfg)
-        np.testing.assert_allclose(gath, dense, atol=1e-6, rtol=1e-6)
-
-
-def test_engine_auto_selects_gather_only_when_sparse(monkeypatch):
-    """AIOS_TPU_MOE_GATHER=1 + slots*k < X -> gathered decode (streams only
-    routed experts); otherwise dense (chip-measured default: dense wins at
-    small expert sizes). Sharded engines never gather (ep psum instead)."""
-    from aios_tpu.engine.engine import TPUEngine
-    from aios_tpu.parallel.sharding import ShardingPlan, build_mesh
-
-    monkeypatch.setenv("AIOS_TPU_MOE_GATHER", "1")
-    cfg = TINY_MOE  # X=4, k=2
-    params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    e1 = TPUEngine(cfg, params, num_slots=1, max_context=64,
-                   cache_dtype=jnp.float32)
-    assert e1._moe_impl == "gather"
-    out_gather = e1.generate([1, 2, 3, 4, 5], max_new_tokens=16,
-                             temperature=0.0)
-    e1.close()
-    e2 = TPUEngine(cfg, params, num_slots=4, max_context=64,
-                   cache_dtype=jnp.float32)
-    assert e2._moe_impl is None  # 4*2 >= 4 experts: dense
-    out_dense = e2.generate([1, 2, 3, 4, 5], max_new_tokens=16,
-                            temperature=0.0)
-    e2.close()
-    assert out_gather == out_dense
-    e3 = TPUEngine(cfg, params, num_slots=2, max_context=64,
-                   cache_dtype=jnp.float32,
-                   shardings=ShardingPlan(build_mesh(8, dp=2, ep=2, tp=2)))
-    # a sharding plan names its path: the expert axis may be sharded over ep
-    assert e3._moe_impl == e3._prefill_moe_impl == "dense"
-    e3.close()
-
-
-def test_verify_gather_gating(monkeypatch):
-    """Verify feeds K+1 tokens per slot, so spec rounds fall back to dense
-    when S*(K+1)*k reaches the expert count; decode keeps gathering."""
+def test_spec_decode_on_a_router_model():
+    """Speculative rounds on a model with a router (its verify feed of 4
+    tokens runs every expert over every token, like decode) emit the
+    tokens plain greedy decode emits."""
     from aios_tpu.engine.engine import TPUEngine
 
-    monkeypatch.setenv("AIOS_TPU_MOE_GATHER", "1")
-    cfg = TINY_MOE  # X=4, k=2
-    params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    seen = {}
-    real_verify = M.verify_step
-
-    def spy(*args, **kw):
-        seen["verify_moe_impl"] = kw.get("moe_impl")
-        return real_verify(*args, **kw)
-
-    monkeypatch.setattr(M, "verify_step", spy)
-    eng = TPUEngine(cfg, params, num_slots=1, max_context=64,
-                    cache_dtype=jnp.float32)
-    assert eng._moe_impl == "gather"
-    eng.prefill(0, [1, 2, 3, 4], temperature=0.0)
-    eng.spec_step(1, draft_len=3)  # 1*(3+1)*2 = 8 >= 4 experts -> dense
-    eng.close()
-    assert seen["verify_moe_impl"] is None
-
-
-def test_env_var_overrides_engine_gather(monkeypatch):
-    """AIOS_TPU_MOE_IMPL is the operator's escape hatch: the engine resolves
-    it once, at load time, beside its other overrides, and it beats the
-    engine's static 'gather' choice in every graph; model.ffn reads no
-    environment."""
-    from aios_tpu.engine.engine import TPUEngine
-
-    cfg = TINY_MOE
-    params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    monkeypatch.setenv("AIOS_TPU_MOE_GATHER", "1")
-    monkeypatch.setenv("AIOS_TPU_MOE_IMPL", "dense")
-    eng = TPUEngine(cfg, params, num_slots=1, max_context=64,
-                    cache_dtype=jnp.float32)
-    assert eng._moe_override == "dense" and eng._moe_impl == "dense"
-    assert eng._verify_moe_impl(4) == "dense"
-    eng.close()
-
-    h = jax.random.normal(jax.random.PRNGKey(7), (1, 1, cfg.hidden_size))
-    lp = _layer0(params)
-    called = {}
-    real = moe_mod.moe_ffn_dense
-
-    def spy(*a, **k):
-        called["dense"] = True
-        return real(*a, **k)
-
-    monkeypatch.setattr(moe_mod, "moe_ffn_dense", spy)
-    # the explicit choice is the caller's; the environment no longer beats it
-    monkeypatch.setenv("AIOS_TPU_MOE_IMPL", "gather")
-    M._mlp(h, lp, cfg, moe_impl="dense")
-    assert called.get("dense")
-
-
-def test_spec_decode_under_gather(monkeypatch):
-    from aios_tpu.engine.engine import TPUEngine
-
-    monkeypatch.setenv("AIOS_TPU_MOE_GATHER", "1")
     cfg = TINY_MOE
     params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     eng = TPUEngine(cfg, params, num_slots=1, max_context=64,
                     cache_dtype=jnp.float32)
-    assert eng._moe_impl == "gather"
     ref = eng.generate([1, 2, 3, 4] * 3, max_new_tokens=16, temperature=0.0)
     eng.release(0)
     first = eng.prefill(0, [1, 2, 3, 4] * 3, temperature=0.0)
@@ -318,18 +201,25 @@ def test_load_balance_aux_perfectly_balanced():
     np.testing.assert_allclose(float(aux), 1.0, atol=1e-6)
 
 
-def test_serving_forward_never_auto_dispatches(monkeypatch):
-    """The serving forward (no with_aux) must stay on the exact dense path
-    even at >=1024 tokens — auto-dispatch is training-only (it drops
-    overflow picks and would skew prefill logits)."""
-    monkeypatch.delenv("AIOS_TPU_MOE_IMPL", raising=False)
+def test_serving_forward_never_dispatches(monkeypatch):
+    """The serving forward (no with_aux) stays on an exact path even at
+    >=1024 tokens — the capacity dispatch is the training forward's alone
+    (it drops overflow picks and would skew prefill logits)."""
+    called = []
+    real = moe_mod.moe_ffn_dispatch
+
+    def spy(*a, **kw):
+        called.append(True)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(moe_mod, "moe_ffn_dispatch", spy)
     cfg = TINY_MOE
     params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     tokens = _tokens(cfg, batch=1, seq=1024, seed=21)
-    auto = np.asarray(M.forward_full(params, cfg, tokens, kernels=False))
-    monkeypatch.setenv("AIOS_TPU_MOE_IMPL", "dense")
-    dense = np.asarray(M.forward_full(params, cfg, tokens, kernels=False))
-    np.testing.assert_array_equal(auto, dense)
+    M.forward_full(params, cfg, tokens, kernels=False)
+    assert not called
+    M.forward_full(params, cfg, tokens, kernels=False, with_aux=True)
+    assert called
 
 
 def test_pp_train_step_moe_aux(cpu_devices):

@@ -157,11 +157,10 @@ PANGU = dataclasses.replace(
 def test_grouped_pays_follows_the_static_shapes(cfg, n_tok, grouped):
     assert moe.grouped_pays(n_tok, cfg) is grouped
     assert moe.grouped_serves(n_tok, cfg) is grouped
-    # a forced path (the engine names "dense" under a sharding plan, where
-    # the expert axis may be sharded over ep) and the training forward keep
-    # theirs, whatever the shapes
-    assert not moe.grouped_serves(n_tok, cfg, "dense")
-    assert not moe.grouped_serves(n_tok, cfg, None, allow_dispatch=True)
+    # an engine under a sharding plan (the expert axis may be sharded over
+    # ep) and the training forward keep theirs, whatever the shapes
+    assert not moe.grouped_serves(n_tok, cfg, moe_dense=True)
+    assert not moe.grouped_serves(n_tok, cfg, allow_dispatch=True)
 
 
 def _greedy(engine, prompt, chunk):
@@ -175,7 +174,8 @@ def _greedy(engine, prompt, chunk):
 def test_chunked_prefill_through_grouped_yields_the_dense_tokens(monkeypatch):
     """A 300-token prompt admitted in a 256-token chunk (grouped: 512 picks
     and 8 part tiles against 2,048 rows) and a final 64-token bucket (dense),
-    then greedy decode: the tokens of an engine forced onto the dense path.
+    then greedy decode: the tokens of an engine for which the grouped path
+    never pays, so that every graph of it runs dense.
     The counters say which path ran: dense-over-all computes exactly 4 rows
     a pick (8 experts over every token, 2 picks a token)."""
     cfg = dataclasses.replace(CFG, num_layers=2)
@@ -184,7 +184,7 @@ def test_chunked_prefill_through_grouped_yields_the_dense_tokens(monkeypatch):
     kw = dict(num_slots=2, max_context=512, cache_dtype=jnp.float32,
               paged_pool_rows=3 * 512)
     auto = TPUEngine(cfg, params, **kw)
-    assert auto._prefill_moe_impl is None and auto.counts_picks
+    assert not auto._moe_dense and auto.counts_picks
     got = _greedy(auto, prompt, 256)
     picks, local, rows = (auto.moe_picks_total, auto.moe_picks_local,
                           auto.moe_expert_rows)
@@ -192,10 +192,151 @@ def test_chunked_prefill_through_grouped_yields_the_dense_tokens(monkeypatch):
     # chunk 256 + final 64 + 8 steps of 2 slots, 2 picks a token, 2 layers
     assert picks == local == 2 * 2 * (256 + 64 + 8 * 2)
     assert picks < rows < 4 * picks
-    monkeypatch.setenv("AIOS_TPU_MOE_IMPL", "dense")
+    monkeypatch.setattr(moe, "grouped_pays", lambda n_tok, cfg: False)
     dense = TPUEngine(cfg, params, **kw)
-    assert dense._prefill_moe_impl == "dense"
     want = _greedy(dense, prompt, 256)
     assert dense.moe_expert_rows == 4 * dense.moe_picks_total == 4 * picks
     dense.close()
     assert got == want
+
+
+# -- which path a graph takes, and what every graph counts ------------------
+
+PATHS = ("moe_ffn_dense", "moe_ffn_grouped", "moe_ffn_dispatch")
+
+
+@pytest.fixture
+def traced(monkeypatch):
+    """The expert paths traced since the last ``clear()``, by name."""
+    seen = []
+    for name in PATHS:
+        real = getattr(moe, name)
+
+        def spy(*a, _real=real, _name=name, **kw):
+            seen.append(_name)
+            return _real(*a, **kw)
+
+        monkeypatch.setattr(moe, name, spy)
+    return seen
+
+
+@functools.lru_cache(maxsize=None)
+def _two_layers():
+    cfg = dataclasses.replace(CFG, num_layers=2)
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+
+
+def _small_engine(num_slots=2, **kw):
+    cfg, params = _two_layers()
+    kw.setdefault("paged_pool_rows", num_slots * 512)
+    return TPUEngine(cfg, params, num_slots=num_slots, max_context=512,
+                     cache_dtype=jnp.float32, **kw)
+
+
+def _trace_chunk(eng, n_tok):
+    """Lower the mid-prompt chunk graph of ``n_tok`` tokens."""
+    args = [eng.params, eng.state, jnp.zeros((1, n_tok), jnp.int32),
+            jnp.int32(0), jnp.int32(0)]
+    if eng.paged:
+        args.append(jnp.asarray(eng.allocator.tables[0]))
+    eng._make_chunk_jit(False).lower(*args)
+
+
+@pytest.mark.parametrize("case", [
+    "decode", "chunk512", "chunk512_under_plan", "train2048"])
+def test_the_expert_path_follows_token_count_plan_and_training_alone(
+        case, traced):
+    """What is left of the choice: a decode dispatch runs every held expert
+    over every token, a 512-token chunk each expert over its own rows, the
+    same chunk of an engine under a sharding plan dense again (the engine's
+    one bit), and the training forward at 2,048 tokens the capacity
+    dispatch. No string and no environment name selects a path."""
+    from aios_tpu.engine.engine import DECODE_STEPS
+    from aios_tpu.parallel.sharding import ShardingPlan, build_mesh
+
+    if case == "train2048":
+        cfg, params = _two_layers()
+        jax.eval_shape(
+            lambda p, t: M.forward_full(p, cfg, t, kernels=False, with_aux=True),
+            params, jnp.zeros((4, 512), jnp.int32))
+        assert set(traced) == {"moe_ffn_dispatch"}
+        return
+    plan = None
+    if case == "chunk512_under_plan":
+        plan = ShardingPlan(build_mesh(8, dp=2, ep=2, tp=2))
+    eng = _small_engine(shardings=plan,
+                        paged_pool_rows=None if plan else 2 * 512)
+    try:
+        assert eng._moe_dense is (plan is not None)
+        if case == "decode":
+            eng._make_step_jit(DECODE_STEPS).lower(*eng._step_example())
+        else:
+            _trace_chunk(eng, 512)
+    finally:
+        eng.close()
+    want = "moe_ffn_grouped" if case == "chunk512" else "moe_ffn_dense"
+    assert set(traced) == {want}
+
+
+@pytest.mark.parametrize("num_slots, grouped", [(8, False), (16, True)],
+                         ids=["below_the_edge", "above_the_edge"])
+def test_a_verify_feed_takes_the_path_a_prefill_of_its_token_count_takes(
+        num_slots, grouped, traced):
+    """A jump run of 16 tokens a slot feeds 17 rows a slot (the pending
+    token leads): 136 at 8 slots and 272 at 16; at top-2 of 8 the grouped
+    path pays from 171 rows on. The verify graph asks the question the
+    chunk graph of that many tokens asks."""
+    from aios_tpu.engine.engine import JUMP_BUCKETS
+
+    n_tok = num_slots * (JUMP_BUCKETS[-1] + 1)
+    # pages of 8 rows: a chunk is whole pages, and 136 and 272 are
+    eng = _small_engine(num_slots, paged_pool_rows=4 * 512, page_size=8)
+    try:
+        assert moe.grouped_pays(n_tok, eng.cfg) is grouped
+        _trace_chunk(eng, n_tok)
+        as_prefill = set(traced)
+        traced.clear()
+        eng._make_jump_jit().lower(
+            eng.params, eng.state, eng._tables_operand(),
+            jnp.zeros((num_slots, JUMP_BUCKETS[-1]), jnp.int32),
+            jnp.zeros((num_slots,), jnp.int32))
+    finally:
+        eng.close()
+    assert set(traced) == as_prefill
+    assert as_prefill == {"moe_ffn_grouped" if grouped else "moe_ffn_dense"}
+
+
+@pytest.mark.parametrize("graph", ["step", "masked", "spec", "jump"])
+def test_router_counters_come_back_from_every_decode_graph_by_the_next_scan_dispatch(
+        graph):
+    """``moe_picks_total`` ends at rows x top-k x expert layers whichever
+    graph fed the rows (every slot's, live or not: the graphs are of fixed
+    shape). Only the scan graphs (step, masked) append the device's sums to
+    their token readback; a speculative round and a jump run add into the
+    sums as a prefill does, and the next scan dispatch brings them back."""
+    eng = _small_engine()
+    S, per_row = eng.num_slots, 2 * 2  # top-2, two expert layers
+    try:
+        eng.prefill(0, [5, 9, 5, 9, 5, 9, 5, 9], temperature=0.0)
+        eng.step(1)  # the prefill's counts come back here
+        before = eng.moe_picks_total
+        if graph == "step":
+            eng.step(2)
+            rows = 2 * S
+        elif graph == "masked":
+            eng.step_masked(np.zeros((S, eng.cfg.vocab_size), np.float32))
+            rows = S
+        else:
+            if graph == "spec":
+                eng.spec_step(1, draft_len=3)
+            else:
+                eng.jump_step(np.full((S, 4), 7, np.int32),
+                              np.asarray([4, 0], np.int32))
+            assert eng.moe_picks_total == before  # held on the device
+            eng.step(1)
+            # the pending token leads a feed: 3 drafts, or a 4-token run
+            rows = (5 if graph == "jump" else 4) * S + S
+        assert eng.moe_picks_total == before + rows * per_row
+        assert eng.moe_picks_local == eng.moe_picks_total
+    finally:
+        eng.close()

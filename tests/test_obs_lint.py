@@ -241,46 +241,6 @@ def test_engine_jump_ahead_gauges_aggregate_over_engine_weakset():
         assert name in used, f"{name} not registered over the WeakSet"
 
 
-# -- the multi-tick megagraph family (engine.mega_step, ISSUE 19) ----------
-
-ENGINE_MEGA_EXPECTED = {
-    "aios_tpu_engine_mega_dispatches_total": "gauge",
-    "aios_tpu_engine_mega_ticks_total": "gauge",
-}
-
-
-def test_engine_mega_family_complete_and_typed():
-    """The megagraph instruments the ISSUE 19 catalog promises exist,
-    with the promised kinds — and any NEW aios_tpu_engine_mega_* metric
-    must be added here (and to docs/ENGINE_PERF.md + OBSERVABILITY.md)
-    so the family stays reviewed. Like the jump family they are
-    monotonic engine counters summed at scrape time over the per-model
-    WeakSet of replica engines."""
-    family = {
-        m.name: m.kind for m in _catalog()
-        if m.name.startswith("aios_tpu_engine_mega_")
-    }
-    assert family == ENGINE_MEGA_EXPECTED
-    for m in _catalog():
-        if m.name.startswith("aios_tpu_engine_mega_"):
-            assert tuple(m.labelnames) == ("model",), (
-                f"{m.name}: megagraph metrics carry exactly the model "
-                f"label (replicas aggregate through the engine WeakSet)"
-            )
-
-
-def test_engine_mega_gauges_aggregate_over_engine_weakset():
-    """Same WeakSet-sum contract as the jump family, on the AST."""
-    from aios_tpu.analysis.core import module_info_for, names_used_in
-    from aios_tpu.engine import engine as engine_mod
-
-    mi = module_info_for(engine_mod)
-    fn = mi.functions["TPUEngine._register_gauges"]
-    used = names_used_in(fn.node)
-    for name in ("ENGINE_MEGA_DISPATCHES", "ENGINE_MEGA_TICKS"):
-        assert name in used, f"{name} not registered over the WeakSet"
-
-
 # -- the speculative-decode family (engine.spec_step + batcher EWMA) -------
 
 SPEC_EXPECTED = {

@@ -223,7 +223,7 @@ def test_jump_ahead_greedy_identity_and_dispatch_reduction(params):
 def test_jump_ahead_sampled_schema_still_conforms(params):
     """Sampled constrained streams under jump-ahead stay schema-exact
     (forced tokens are sampler-independent; the sampled remainder draws
-    a shifted key chain — the documented unified_step-style caveat)."""
+    a shifted key chain — the documented caveat)."""
     reqs = [_schema_req(0, temperature=0.9, top_p=0.9)]
     on, s_on = _run_constrained(params, True, reqs)
     tok = ByteTokenizer()
