@@ -76,7 +76,7 @@ def _default_sections() -> Dict[str, Dict[str, Any]]:
             "spec_reprobe_secs": "",
             # pipelined decode loop: dispatch N+1 enqueues while dispatch
             # N's tokens are emitted/detokenized (docs/ENGINE_PERF.md).
-            # "" = off.
+            # "" = the default (on); false = the synchronous loop.
             "decode_pipeline": "",
             # grammar jump-ahead for constrained/structured decoding
             # (multi-token forced runs in one dispatch; default ON) and

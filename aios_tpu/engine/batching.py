@@ -9,9 +9,10 @@ caller through a per-request queue as dispatches complete.
 
 Scheduling policy (single background thread, dispatch-level granularity):
   * admit waiting requests whenever slots are free (prefill immediately);
-  * decode in chunks of `chunk_steps` tokens per dispatch (amortizes
-    host<->device round trips); a smaller chunk is used when requests are
-    waiting so admission latency stays low;
+  * decode in short dispatches of `chunk_steps` tokens, each issued
+    before the last one's tokens are consumed (the pipelined loop): a
+    finished slot and an arrival wait out a few steps, and the host's
+    time per dispatch runs while the device works on the next;
   * requests retire on EOS/stop token, max_tokens, or a full cache slot.
 """
 
@@ -32,8 +33,8 @@ import numpy as np
 
 from ..analysis.locks import make_lock
 from .engine import (
-    ADMIT_DECODE_STEPS, DECODE_STEPS, JUMP_BUCKETS, ChunkedPrefill,
-    PendingDecode, TPUEngine, _env_flag,
+    DECODE_STEPS, JUMP_BUCKETS, ChunkedPrefill, PendingDecode, TPUEngine,
+    _env_flag,
 )
 from .paged import PoolExhausted
 from .sampling import GREEDY_EPS
@@ -174,6 +175,7 @@ class _PendingTick:
     pending: PendingDecode
     lives: Dict[int, "_Live"]
     evs: tuple = ()
+    key: object = None  # the running median it is judged against
 
 
 class RequestHandle:
@@ -239,11 +241,11 @@ class ContinuousBatcher:
     def __init__(
         self,
         engine: TPUEngine,
-        # ~70ms/dispatch TinyLlama, ~300ms Mistral on v5e; bigger chunks
-        # amortize dispatch overhead (+15% measured), and the
-        # admit_chunk_steps fallback keeps admission latency low
+        # steps a dispatch, and steps a dispatch while a request waits
+        # for a slot: one size serves both (engine.DECODE_STEPS says how
+        # it was chosen)
         chunk_steps: int = DECODE_STEPS,
-        admit_chunk_steps: int = ADMIT_DECODE_STEPS,
+        admit_chunk_steps: int = DECODE_STEPS,
         prefill_chunk: Optional[int] = None,  # None -> the engine's default
         speculative: bool = False,  # n-gram speculative decode dispatches
         spec_draft_len: int = 7,
@@ -255,8 +257,9 @@ class ContinuousBatcher:
         spec_reprobe_secs: Optional[float] = None,  # reprobe window
     ) -> None:
         self.engine = engine
-        # Pipelined decode (AIOS_TPU_DECODE_PIPELINE /
-        # ModelConfig.decode_pipeline): dispatch N+1 is enqueued BEFORE
+        # Pipelined decode, the default loop (AIOS_TPU_DECODE_PIPELINE=0
+        # / ModelConfig.decode_pipeline=False is the way back to the
+        # synchronous one): dispatch N+1 is enqueued BEFORE
         # dispatch N's tokens are consumed, so the host's emit/detokenize/
         # retire phase overlaps device execution instead of idling it — a
         # depth-2 double buffer over the plain decode path, with explicit
@@ -271,7 +274,7 @@ class ContinuousBatcher:
         if pipeline is None:
             pipeline = _env_flag("AIOS_TPU_DECODE_PIPELINE")
         if pipeline is None:
-            pipeline = bool(getattr(engine.cfg, "decode_pipeline", False))
+            pipeline = bool(getattr(engine.cfg, "decode_pipeline", True))
         self.pipeline = bool(pipeline)
         self._pending: Optional[_PendingTick] = None
         self.flushes = 0
@@ -288,8 +291,13 @@ class ContinuousBatcher:
         self.loop_stalls = 0
         self.loop_stall_seconds = 0.0
         # the size of the dispatch this tick completed (the key of the
-        # running median it is judged against), None when it made none
+        # running median it is judged against), None when it made none;
+        # and the pipelined dispatch it consumed, issued a tick before
         self._dispatched: Optional[object] = None
+        self._consumed: Optional[_PendingTick] = None
+        # a prompt chunk was enqueued and not waited for: the next decode
+        # dispatch has that chunk's device time in front of its own
+        self._chunk_ahead = False
         self._last_dispatch_s = 0.0
         self._dispatch_hist: Dict[object, deque] = {}
         self._progress_checked = 0.0
@@ -802,6 +810,7 @@ class ContinuousBatcher:
                     live.out_q.put(_END)
                     return
         self._prefill_chunks += 1
+        self._chunk_ahead = first is None
         live.progress_at = time.monotonic()
         # tokens = rows actually consumed this chunk (the FINAL chunk is
         # usually partial — recording the nominal chunk size would
@@ -971,7 +980,7 @@ class ContinuousBatcher:
             self._rec_prefill(live, len(ids), t0, reused0, restored0)
             if live.constraint is not None:
                 first = self._constrained_first(live, first)
-            live.first_token_at = time.monotonic()
+            live.first_token_at = live.progress_at = time.monotonic()
             self._obs_ttft.observe(live.first_token_at - live.submitted_at)
             with self._lock:
                 self._live[slot] = live
@@ -1013,7 +1022,9 @@ class ContinuousBatcher:
     # -- pipelined decode (depth-2 double buffer) ---------------------------
 
     def _consume(self, tick: _PendingTick) -> None:
-        """Emit one finished dispatch's tokens to whoever is still live.
+        """Emit one finished dispatch's tokens to whoever is still live:
+        the wait for them (batcher.consume, a wait on the device), then
+        the emission (batcher.emit, the host's own time).
 
         A PoolExhausted surfacing from the dispatch worker (the ensure()
         failed; engine state untouched) retires a victim here instead —
@@ -1021,7 +1032,7 @@ class ContinuousBatcher:
         loop's dispatch-site handling."""
         t0 = time.monotonic()
         try:
-            with self.phases.phase("engine.readback"):
+            with self.phases.phase("batcher.consume"):
                 tokens = tick.pending.wait()
         except PoolExhausted as e:
             self._gap_wait += time.monotonic() - t0
@@ -1041,21 +1052,25 @@ class ContinuousBatcher:
             self._evict_longest(e.replica)
             return
         self._gap_wait += time.monotonic() - t0
-        dev = tick.pending.device_s
-        if dev is not None:
-            # late devprof join: the sampled device-µs of the dispatch
-            # the worker just finished, onto the events recorded at its
-            # submit (scheduler-thread-only mutation of LIVE timelines —
-            # readers copy finished rings, never these)
-            for ev in tick.evs:
-                ev["dev_us"] = round(dev * 1e6, 1)
-        lengths = tick.pending.lengths
-        for row in tokens:
-            for slot, live in tick.lives.items():
-                if live.done:
-                    continue
-                self._emit(live, int(row[slot]), slot_len=int(lengths[slot]))
-        self._progressed(tick.lives.values())
+        self._consumed = tick
+        with self.phases.phase("batcher.emit"):
+            dev = tick.pending.device_s
+            if dev is not None:
+                # late devprof join: the sampled device-µs of the dispatch
+                # the worker just finished, onto the events recorded at
+                # its submit (scheduler-thread-only mutation of LIVE
+                # timelines — readers copy finished rings, never these)
+                for ev in tick.evs:
+                    ev["dev_us"] = round(dev * 1e6, 1)
+            lengths = tick.pending.lengths
+            for row in tokens:
+                for slot, live in tick.lives.items():
+                    if live.done:
+                        continue
+                    self._emit(
+                        live, int(row[slot]), slot_len=int(lengths[slot])
+                    )
+            self._progressed(tick.lives.values())
 
     @staticmethod
     def _progressed(lives) -> None:
@@ -1081,8 +1096,7 @@ class ContinuousBatcher:
         obs.ENGINE_DISPATCH_FLUSHES.labels(
             model=self.engine.cfg.name, cause=cause
         ).inc()
-        with self.phases.phase("batcher.emit"):
-            self._consume(tick)
+        self._consume(tick)
 
     def _note_dispatch(self) -> Optional[float]:
         """Record and return the host gap since the previous decode
@@ -1109,6 +1123,14 @@ class ContinuousBatcher:
             self._obs_gap.observe(gap)
         self._gap_wait = 0.0
         return gap
+
+    def _dispatch_key(self, n: int) -> object:
+        """The running median a plain dispatch of ``n`` steps is judged
+        against. One issued behind a prompt chunk that is still on the
+        device carries that chunk's time, as long as a short dispatch's
+        own: it has a median of its own."""
+        behind, self._chunk_ahead = self._chunk_ahead, False
+        return ("behind_chunk", n) if behind else n
 
     # -- flight-recorder hooks (obs/flightrec.py) ---------------------------
     # One event per DISPATCH per live request — never per token — and
@@ -1406,12 +1428,20 @@ class ContinuousBatcher:
         last decode dispatch — the device starved for more than a whole
         dispatch — or whose dispatch took more than STALL_DISPATCH_FACTOR
         times the running median for its step count. It is counted and
-        leaves one model event naming the phase that took longest. The
-        pipelined loop issues its dispatches without waiting, so it has
-        no dispatch time to judge by and is not judged."""
+        leaves one model event naming the phase that took longest. A
+        pipelined tick hands its dispatch to the worker and consumes the
+        one before: what lay under its batcher.dispatch is host time (no
+        wait on the device is in it), and the dispatch it is judged by is
+        the one it consumed, by the seconds the worker spent on it."""
         dispatched, self._dispatched = self._dispatched, None
-        if not self.pipeline:
-            self._judge_stall(host, under_dispatch, dispatched)
+        consumed, self._consumed = self._consumed, None
+        if dispatched is None:
+            host = {**host, **under_dispatch}
+            under_dispatch = {}
+            if consumed is not None:
+                dispatched = consumed.key
+                under_dispatch = consumed.pending.spans
+        self._judge_stall(host, under_dispatch, dispatched)
         now = time.monotonic()
         if now - self._progress_checked >= NO_PROGRESS_CHECK_SECS:
             self._progress_checked = now
@@ -1522,6 +1552,10 @@ class ContinuousBatcher:
         out = self.phases.stats()
         out["loop_stalls"] = self.loop_stalls
         out["loop_stall_seconds"] = round(self.loop_stall_seconds, 6)
+        # how often the pipeline engages: a flush is a dispatch consumed
+        # before the next could be issued ahead of it
+        out["decode_dispatches"] = self.decode_dispatches
+        out["dispatch_flushes"] = self.flushes
         out["oldest_no_progress_s"] = round(self.oldest_no_progress_s(), 3)
         return out
 
@@ -1847,7 +1881,8 @@ class ContinuousBatcher:
             return
         # keep admission latency low when someone is waiting (constrained
         # ticks above ignore chunking — they are always 1 step). n is
-        # always one of exactly TWO values — each step size is its own XLA
+        # always one of the batcher's two sizes (one and the same by
+        # default) — each step size is its own XLA
         # graph, so clamping n to a data-dependent remaining-budget (as an
         # earlier version did) triggers fresh multi-second compiles on this
         # thread mid-serving; overshooting a request's max_tokens just
@@ -1911,16 +1946,17 @@ class ContinuousBatcher:
                 gap = self._note_dispatch()
                 handle = self.engine.step_async(n)
                 self._gap_mark = time.monotonic()
-            with phase("batcher.emit"):
                 # the worker's timing sample (if this dispatch drew one)
                 # joins these events at consume time — see _consume
                 evs = self._rec_dispatch(
                     slots.values(), "decode", n, gap, pipelined=True,
                     join_sample=False,
                 )
-                self._pending = _PendingTick(handle, slots, tuple(evs))
-                if prev is not None:
-                    self._consume(prev)
+                self._pending = _PendingTick(
+                    handle, slots, tuple(evs), self._dispatch_key(n)
+                )
+            if prev is not None:
+                self._consume(prev)
             return
         try:
             with phase("batcher.dispatch"):
@@ -1933,7 +1969,7 @@ class ContinuousBatcher:
             # failed ensure() left all engine state untouched
             self._evict_longest(e.replica)
             return
-        self._dispatched = n
+        self._dispatched = self._dispatch_key(n)
         with phase("batcher.emit"):
             self._rec_dispatch(
                 slots.values(), "decode", n, gap, self._gap_mark - t0

@@ -78,9 +78,10 @@ class ModelConfig:
     prefix_host_bytes: int = 0
     # pipelined decode loop (engine/batching.py): decode dispatch N+1 is
     # enqueued before dispatch N's tokens are emitted/detokenized, so the
-    # host phase overlaps device execution instead of idling it.
-    # AIOS_TPU_DECODE_PIPELINE overrides at load time (docs/ENGINE_PERF.md).
-    decode_pipeline: bool = False
+    # host phase overlaps device execution instead of idling it. The
+    # default loop; False (or AIOS_TPU_DECODE_PIPELINE=0 at load time) is
+    # the synchronous one (docs/ENGINE_PERF.md).
+    decode_pipeline: bool = True
     # grammar jump-ahead for constrained decoding (engine/batching.py
     # _jump_tick): chains of grammar-FORCED tokens (singleton masks —
     # schema key literals, '":', '",', closers) emit host-side and append

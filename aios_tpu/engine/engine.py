@@ -56,14 +56,18 @@ DEFAULT_BUCKETS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192)
 JUMP_BUCKETS = (4, 16)
 assert JUMP_BUCKETS[-1] <= spec.HISTORY_PAD - 2
 
-# Decode steps per dispatch of the continuous batcher's loop: the long
-# dispatch while nothing waits for a slot, the short one while a request
-# does (it is admitted between dispatches, so a short one keeps its wait
-# low). warmup's default step sizes and the batcher's defaults and
-# attach-time compile all read these two, so the graphs compiled behind
-# the readiness gate are the graphs the loop dispatches.
-DECODE_STEPS = 16
-ADMIT_DECODE_STEPS = 2
+# Decode steps per dispatch of the continuous batcher's loop, whether or
+# not a request waits for a slot: a finished slot and an arrival wait
+# out a dispatch or two, and a prompt admitted in chunks gets one chunk
+# between two dispatches, so the dispatch is short, and the pipelined
+# loop hides the host's time per dispatch where a long one amortised it.
+# Chosen on the chip (PERF.md section 6, PR 30): the shortest of 2, 4, 8
+# and 16 that holds tpot_p50_ms in the benchmark's cells (steps of
+# 10-12 ms; a model with a much shorter step may want more). warmup's
+# default step size and the batcher's defaults and attach-time compile
+# all read this, so the graph compiled behind the readiness gate is the
+# graph the loop dispatches.
+DECODE_STEPS = 2
 
 # Width buckets for the standalone draft-KV bulk-ingest graphs: a freshly
 # admitted (or failed-over) slot's draft cache trails the serving state by
@@ -277,8 +281,8 @@ DecodeState = Dict[str, jnp.ndarray]
 
 
 class PendingDecode:
-    """Handle for a decode dispatch running on the engine's dispatch
-    worker (engine.step_async).
+    """Handle for a decode dispatch running on one of the engine's
+    dispatch workers (engine.step_async).
 
     The worker thread performs the whole dispatch — lock, graph call,
     device->host token readback — so the CALLER's thread overlaps its own
@@ -299,10 +303,16 @@ class PendingDecode:
     (valid after ``wait()``) carries the dispatch's sampled device-time
     measurement when devprof took one (obs/devprof.py), None otherwise —
     the batcher joins it onto the flight-recorder event it recorded at
-    submit time."""
+    submit time. ``spans`` (valid after ``wait()``) holds the seconds the
+    worker spent in each phase of this dispatch (engine.lock_wait,
+    engine.enqueue, engine.readback; for one issued while the dispatch
+    before it was still on the device, the read-back alone, from that
+    one's tokens to its own): what the batcher's stall judge takes for
+    the dispatch's time, since no phase of the scheduler's own thread
+    covers it."""
 
     __slots__ = ("_fut", "_started", "n_steps", "tokens", "lengths",
-                 "device_s")
+                 "device_s", "spans")
 
     def __init__(self, fut, n_steps: int, started: threading.Event) -> None:
         self._fut = fut
@@ -311,6 +321,7 @@ class PendingDecode:
         self.tokens: Optional[np.ndarray] = None
         self.lengths: Optional[np.ndarray] = None
         self.device_s: Optional[float] = None
+        self.spans: Dict[str, float] = {}
 
     def wait_started(self) -> None:
         if self.tokens is not None or self._fut.done():
@@ -319,7 +330,8 @@ class PendingDecode:
 
     def wait(self) -> np.ndarray:
         if self.tokens is None:
-            self.tokens, self.lengths, self.device_s = self._fut.result()
+            (self.tokens, self.lengths, self.device_s,
+             self.spans) = self._fut.result()
         return self.tokens
 
 
@@ -920,10 +932,14 @@ class TPUEngine:
         self._spec_fns: Dict[Tuple[int, int, int], object] = {}
         self._restore_fns: Dict[int, object] = {}
         self._jump_fns: Dict[int, object] = {}  # run-length-bucketed
-        # single-thread dispatch worker behind step_async (built lazily:
-        # only pipelined batchers use it); FIFO order is the dispatch
-        # ordering contract
+        # the dispatch workers behind step_async (built lazily: only
+        # pipelined batchers use them); FIFO order is the dispatch
+        # ordering contract, kept by each dispatch waiting for the one
+        # before it to hold the engine lock (_dispatch_started)
         self._dispatch_pool = None
+        self._dispatch_started: Optional[threading.Event] = None
+        # when the last decode dispatch's tokens reached the host
+        self._tokens_ready = 0.0
         self.decode_steps = 0
         self.prefix_rows_reused = 0
         self.prefix_rows_restored = 0
@@ -3109,27 +3125,32 @@ class TPUEngine:
         ``self.active`` are meaningful. Lengths advance for every slot
         (fixed-shape graph), clamped at the cache end.
         """
-        tokens, _, _ = self._step_dispatch(n_steps)
-        return tokens
+        return self._step_dispatch(n_steps)[0]
 
     def _step_dispatch(
         self, n_steps: int, started: Optional[threading.Event] = None,
-    ) -> Tuple[np.ndarray, np.ndarray]:
+        after: Optional[threading.Event] = None,
+    ) -> Tuple[np.ndarray, np.ndarray, Optional[float], Dict[str, float]]:
         """The decode dispatch body: lock, graph call (donated state
         swap), host-length advance, then the blocking device->host token
         readback OUTSIDE the lock. Returns (tokens [n_steps, S] host
-        array, post-dispatch host lengths). ``started`` (the step_async
-        worker path) is set the moment the engine lock is held, so a
-        caller can fence later engine calls behind this dispatch."""
+        array, post-dispatch host lengths, devprof's sample if it took
+        one, seconds per phase of this dispatch). ``started`` (the
+        step_async worker path) is set the moment the engine lock is
+        held, so a caller can fence later engine calls behind this
+        dispatch; ``after`` is the ``started`` of the dispatch issued
+        before this one, which takes the lock first."""
         ph = self.phases
         try:
             lock_wait = ph.begin("engine.lock_wait")
+            if after is not None:
+                after.wait()
             with self._lock:
                 ph.end(lock_wait)
                 if started is not None:
                     started.set()
                 with ph.phase("engine.enqueue", n=n_steps,
-                              occ=int(self.active.sum())):
+                              occ=int(self.active.sum())) as enqueue:
                     tables = ()
                     if self.paged:
                         self._back_active_slots(n_steps)
@@ -3148,7 +3169,7 @@ class TPUEngine:
                     self._host_lengths + n_steps, self.max_context - 1
                 )
                 lengths = self._host_lengths.copy()
-            with ph.phase("engine.readback"):
+            with ph.phase("engine.readback") as readback:
                 host_all = np.asarray(tokens)
                 host_tokens = host_all[:n_steps]
             self._take_picks(host_all, n_steps)
@@ -3156,7 +3177,19 @@ class TPUEngine:
             # materialized, so the sample is the graph-call -> ready
             # delta at zero extra synchronization
             sample_s = self._devprof_sample(dtok)
-            return host_tokens, lengths, sample_s
+            ready = readback.t0 + readback.dt
+            before, self._tokens_ready = self._tokens_ready, ready
+            if before > readback.t0:
+                # issued ahead: the dispatch before this one was still on
+                # the device, so this one's own time runs from that one's
+                # tokens to its own, and its lock and enqueue were hidden
+                spans = {"engine.readback": ready - before}
+            else:
+                spans = {
+                    span.name: span.dt
+                    for span in (lock_wait, enqueue, readback)
+                }
+            return host_tokens, lengths, sample_s, spans
         finally:
             if started is not None and self._devprof is not None:
                 self._devprof.dequeue()
@@ -3172,24 +3205,29 @@ class TPUEngine:
         from backing the slots surfaces at ``wait()`` with engine state
         untouched, exactly like the sync path.
 
-        Dispatches are FIFO (single worker) and serialize with every
-        other engine call through the engine lock; use
-        ``wait_started()`` before issuing engine calls that must order
-        AFTER this dispatch."""
+        Two workers, so that the graph call of dispatch N+1 is made
+        while N is still on the device and the device goes from one to
+        the next without waiting for the host (a worker is blocked in
+        N's read-back until N ends; measured: PERF.md section 6, PR 30).
+        Dispatches stay FIFO (each takes the engine lock only after the
+        one before it has) and serialize with every other engine call
+        through the engine lock; use ``wait_started()`` before issuing
+        engine calls that must order AFTER this dispatch."""
         if self._dispatch_pool is None:
             from concurrent.futures import ThreadPoolExecutor
 
             self._dispatch_pool = ThreadPoolExecutor(
-                max_workers=1,
+                max_workers=2,
                 thread_name_prefix=f"decode-dispatch-{self.cfg.name}",
             )
         started = threading.Event()
+        after, self._dispatch_started = self._dispatch_started, started
         if self._devprof is not None:
             # backlog accounting for the sampling slack check: the
             # worker only times a dispatch with nothing queued behind it
             self._devprof.enqueue()
         fut = self._dispatch_pool.submit(
-            self._step_dispatch, n_steps, started
+            self._step_dispatch, n_steps, started, after
         )
         return PendingDecode(fut, n_steps, started)
 
@@ -3654,7 +3692,7 @@ class TPUEngine:
         # a size missing here compiles for multiple seconds ON the
         # scheduler thread at first use, stalling every live request
         # (measured: ~2 s added to all 8 agents' TTFT)
-        step_sizes: Tuple[int, ...] = (ADMIT_DECODE_STEPS, DECODE_STEPS),
+        step_sizes: Tuple[int, ...] = (DECODE_STEPS,),
         prefill_chunk: Optional[int] = None,  # None -> prefill_chunk_default
         masked_step: bool = False,  # also compile the grammar-masked step
         spec_sizes: Tuple[int, ...] = (),  # speculative round counts

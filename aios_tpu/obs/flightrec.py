@@ -153,21 +153,23 @@ SNAPSHOT_CAUSES = ("shed_spike", "crash_respawn", "slo_breach", "abort",
 # gauge, the no-progress check), prefill (_advance_prefill, in the ticks
 # that have one), admit (_admit, likewise), idle (the 50 ms wake
 # wait), dispatch (_note_dispatch through the engine call's return),
-# emit (the token loops, _consume, _finish, _rec_close), evict
-# (_evict_longest). engine.* wrap the dispatch bodies: lock_wait (until
+# emit (the token loops, _finish, _rec_close), consume (the pipelined
+# tick's wait for the tokens of the dispatch it consumes: a name of its
+# own, so that engine.readback stays one span a dispatch, on the thread
+# that made it), evict (_evict_longest). engine.* wrap the dispatch bodies: lock_wait (until
 # the engine lock is held), enqueue (lock held until the graph call
 # returns), readback (the blocking device->host copy of the tokens),
 # prefill (one prefill / chunk dispatch, lock to first token), compile
 # (the first call of a lazily compiled graph).
 PHASES = (
     "batcher.fence", "batcher.reap", "batcher.prefill", "batcher.admit",
-    "batcher.idle", "batcher.dispatch", "batcher.emit", "batcher.evict",
-    "engine.lock_wait", "engine.enqueue", "engine.readback",
+    "batcher.idle", "batcher.dispatch", "batcher.emit", "batcher.consume",
+    "batcher.evict", "engine.lock_wait", "engine.enqueue", "engine.readback",
     "engine.prefill", "engine.compile",
 )
 # phases that wait on the device, not on the host: a tick's host time
 # leaves them out wherever they nest
-DEVICE_WAIT_PHASES = ("engine.prefill", "engine.readback")
+DEVICE_WAIT_PHASES = ("engine.prefill", "engine.readback", "batcher.consume")
 
 
 def abort_cause(reason: str) -> str:
@@ -185,8 +187,9 @@ def abort_cause(reason: str) -> str:
 
 # -- bounds -----------------------------------------------------------------
 
-# Events per timeline: a decode event lands once per DISPATCH (~chunk_steps
-# tokens), so 512 events cover a ~8k-token generation with default chunks;
+# Events per timeline: a decode event lands once per DISPATCH (chunk_steps
+# tokens), so 512 events cover a ~1k-token generation at the default 2
+# steps a dispatch (admission, prefill and first token come first);
 # past the cap events drop and are counted (the record stays bounded no
 # matter how long the stream runs).
 MAX_EVENTS = 512
@@ -638,9 +641,9 @@ class FlightRecorder:
 
 class _Span:
     """One open phase. ``Phases.begin`` returns it; leaving a ``with``
-    block or ``Phases.end`` closes it."""
+    block or ``Phases.end`` closes it and leaves its seconds in ``dt``."""
 
-    __slots__ = ("owner", "name", "t0", "ann", "inner", "root")
+    __slots__ = ("owner", "name", "t0", "dt", "ann", "inner", "root")
 
     def __init__(self, owner: "Phases", name: str) -> None:
         self.owner = owner
@@ -710,7 +713,7 @@ class Phases:
 
     def end(self, span: _Span) -> None:
         t1 = time.monotonic()
-        dt = t1 - span.t0
+        dt = span.dt = t1 - span.t0
         name = span.name
         with self._sums:
             self.seconds[name] += dt  # KeyError: not one of PHASES

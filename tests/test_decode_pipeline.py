@@ -23,9 +23,7 @@ import pytest
 from aios_tpu.engine import model as M
 from aios_tpu.engine.batching import ContinuousBatcher, Request
 from aios_tpu.engine.config import TINY_TEST
-from aios_tpu.engine.engine import (
-    ADMIT_DECODE_STEPS, DECODE_STEPS, JUMP_BUCKETS, TPUEngine,
-)
+from aios_tpu.engine.engine import DECODE_STEPS, JUMP_BUCKETS, TPUEngine
 from aios_tpu.engine.tokenizer import ByteTokenizer
 
 
@@ -266,25 +264,35 @@ def test_warmup_covers_host_tier_restore(params):
 
 
 @pytest.mark.parametrize(
-    "mode", ["plain", "json_forced", "speculative", "plain_pipelined"])
+    "mode", ["default", "plain", "json_forced", "speculative",
+             "plain_pipelined"])
 def test_warmup_and_attach_compile_exactly_what_the_loop_dispatches(
         params, mode):
     """After ``warmup()`` as the model manager calls it and a default
-    batcher's attach, the step registry holds the loop's two sizes (and
+    batcher's attach, the step registry holds the loop's sizes (and
     the masked step where json mode is forced) and nothing else, the
-    speculative and jump registries the same two sizes and the jump
-    buckets, and a serving wave with more requests than slots (so both
-    sizes dispatch) compiles nothing."""
+    speculative and jump registries the same sizes and the jump
+    buckets, and a serving wave with more requests than slots (so every
+    size dispatches) compiles nothing and dispatches no size but those.
+    A batcher made with nothing said runs the pipelined loop."""
     tok = ByteTokenizer()
     forced, spec = mode == "json_forced", mode == "speculative"
     eng = make_engine(params, num_slots=2)
     eng.warmup(masked_step=forced)
-    b = ContinuousBatcher(eng, speculative=spec, tokenizer=tok,
-                          pipeline=mode == "plain_pipelined")
+    warmed = {n for n in eng._step_fns if n != "masked"}
+    dispatched = set()
+    for name in ("step", "step_async"):
+        def counted(n=1, _fn=getattr(eng, name)):
+            dispatched.add(n)
+            return _fn(n)
+        setattr(eng, name, counted)
+    how = {} if mode == "default" else {"pipeline": mode == "plain_pipelined"}
+    b = ContinuousBatcher(eng, speculative=spec, tokenizer=tok, **how)
     try:
+        assert b.pipeline == (mode in ("default", "plain_pipelined"))
         assert (b.admit_chunk_steps, b.chunk_steps) == (
-            ADMIT_DECODE_STEPS, DECODE_STEPS)
-        sizes = {ADMIT_DECODE_STEPS, DECODE_STEPS}
+            DECODE_STEPS, DECODE_STEPS)
+        sizes = {DECODE_STEPS}
         assert set(eng._step_fns) == sizes | ({"masked"} if forced else set())
         assert {k[0] for k in eng._spec_fns} == (sizes if spec else set())
         assert set(eng._jump_fns) == (set(JUMP_BUCKETS) if forced else set())
@@ -298,9 +306,70 @@ def test_warmup_and_attach_compile_exactly_what_the_loop_dispatches(
         outs = [h.tokens() for h in handles]
         assert all(outs) and not any(h.aborted for h in handles)
         assert eng.stats()["xla_compiles"] == before
+        assert dispatched <= warmed and (dispatched or spec)
     finally:
         b.shutdown()
         eng.close()
+
+
+def test_an_arrival_waits_at_most_three_dispatches_of_the_other_stream(params):
+    """A request submitted while a run of dispatches is in flight gets its
+    first token after at most 3 x DECODE_STEPS further tokens of the stream
+    beside it: the rest of the running dispatch, the one already issued
+    behind it, the one issued beside the admission. Counted in the order
+    the scheduler emits, never in time."""
+    eng = make_engine(params, num_slots=2)
+    b = ContinuousBatcher(eng)
+    order = []
+    emit = b._emit
+
+    def noted(live, token, slot_len=None):
+        order.append(live.req.request_id)
+        emit(live, token, slot_len=slot_len)
+
+    b._emit = noted
+    try:
+        first = b.submit(Request(prompt_ids=[3, 17, 91], max_tokens=120,
+                                 temperature=0.0, request_id="running"))
+        it = iter(first)
+        for _ in range(3 * DECODE_STEPS + 1):  # dispatches follow one another
+            next(it)
+        order.append("submitted")
+        second = b.submit(Request(prompt_ids=[9, 8, 7], max_tokens=4,
+                                  temperature=0.0, request_id="arrival"))
+        assert len(second.tokens()) == 4 and len(list(it)) > 0
+    finally:
+        b.shutdown()
+        eng.close()
+    after = order[order.index("submitted") + 1:]
+    waited = after[:after.index("arrival")]
+    assert set(waited) <= {"running"}
+    assert len(waited) <= 3 * DECODE_STEPS
+
+
+def test_a_stream_that_ends_frees_its_slot_within_two_dispatches(params):
+    """A stream whose last token is decoded by step t of its slot hands
+    the slot back with at most 2 x DECODE_STEPS steps decoded past t: the
+    rest of that dispatch and the one already issued behind it."""
+    eng = make_engine(params, num_slots=1)
+    b = ContinuousBatcher(eng)
+    released = []
+    release = eng.release
+
+    def noted(slot):
+        released.append(eng.decode_steps)
+        release(slot)
+
+    eng.release = noted
+    tokens = 4 * DECODE_STEPS + 2  # the prefill's, then one a step
+    try:
+        out = b.submit(Request(prompt_ids=[3, 17, 91, 4], max_tokens=tokens,
+                               temperature=0.0)).tokens()
+    finally:
+        b.shutdown()
+        eng.close()
+    assert len(out) == tokens
+    assert 0 <= released[0] - (tokens - 1) <= 2 * DECODE_STEPS
 
 
 @pytest.mark.parametrize(
@@ -330,7 +399,7 @@ def test_a_slot_that_cannot_take_another_row_finishes_and_frees(params, why):
         params, why.endswith("pipelined"), [first, second],
         engine_kw=dict(num_slots=1, max_context=ctx),
         batcher_kw=dict(chunk_steps=DECODE_STEPS,
-                        admit_chunk_steps=ADMIT_DECODE_STEPS),
+                        admit_chunk_steps=DECODE_STEPS),
         warm=False)
     if want is None:
         # 4 prompt rows of 32: at most 28 tokens, one of them the prefill's
