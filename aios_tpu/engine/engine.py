@@ -839,15 +839,17 @@ class TPUEngine:
             "history": spec.init_history(num_slots, self.max_context),
             "key": jax.random.PRNGKey(seed),
         }
-        # a model with a router counts its picks and the rows its expert
-        # matmuls computed on the device (moe.pick_stats): every graph adds
-        # to this, and a decode dispatch hands the sum back with its tokens
+        # a model with a router counts its picks, the rows its expert
+        # matmuls computed and the experts they read on the device
+        # (moe.pick_stats): every graph adds to this, and a decode dispatch
+        # hands the sum back with its tokens
         self.counts_picks = cfg.moe
         self.moe_picks_total = 0
         self.moe_picks_local = 0
         self.moe_expert_rows = 0
+        self.moe_experts_visited = 0
         if self.counts_picks:
-            self.state["moe_stats"] = jnp.zeros((3,), jnp.int32)
+            self.state["moe_stats"] = model.zero_stats(cfg)[0]
         # rows x sub-layers whose residual was mixed (engine/residual.py),
         # from each dispatched program's static shapes: host-side, no
         # device work (`_devprof_note` sees every dispatch)
@@ -1395,15 +1397,16 @@ class TPUEngine:
         state, tokens = jax.lax.scan(one, state, keys[1:])
         if self.counts_picks:
             # the counters ride back with the tokens, in the one readback
-            # there is: three rows below them, counter i in every column
+            # there is: four rows below them, counter i in every column
             # of row n_steps + i (readers slice [:n_steps]); the device's
             # sums start again from zero
+            stats = state["moe_stats"]
             rows = jnp.broadcast_to(
-                state["moe_stats"][:, None], (3, tokens.shape[1])
+                stats[:, None], (stats.shape[0], tokens.shape[1])
             )
             tokens = jnp.concatenate([tokens, rows], axis=0)
-            state = dict(state, moe_stats=jnp.zeros((3,), jnp.int32))
-        return state, tokens  # tokens [n_steps (+ 3), S]
+            state = dict(state, moe_stats=jnp.zeros_like(stats))
+        return state, tokens  # tokens [n_steps (+ 4), S]
 
     def _verify_feed(self, params, st: DecodeState, feed, tables=None):
         """One multi-token verify forward against whichever cache layout
@@ -3303,10 +3306,11 @@ class TPUEngine:
         its ``n_steps`` token rows (a model that counts none appends
         none)."""
         if self.counts_picks:
-            total, local, rows = host_all[n_steps:n_steps + 3, 0]
+            total, local, rows, visited = host_all[n_steps:, 0]
             self.moe_picks_total += int(total)
             self.moe_picks_local += int(local)
             self.moe_expert_rows += int(rows)
+            self.moe_experts_visited += int(visited)
 
     def jump_step(self, forced: np.ndarray, counts: np.ndarray) -> None:
         """Append grammar-FORCED token runs in ONE multi-token dispatch
@@ -3644,6 +3648,7 @@ class TPUEngine:
             out["moe_picks_total"] = self.moe_picks_total
             out["moe_picks_local"] = self.moe_picks_local
             out["moe_expert_rows"] = self.moe_expert_rows
+            out["moe_experts_visited"] = self.moe_experts_visited
         if self.cfg.hc:
             out["hc_mix_rows"] = self.hc_mix_rows
         if self.kv_compress_armed:

@@ -255,9 +255,10 @@ def _attn_input(x, lp, cfg: ModelConfig):
 
 
 def _finish_block(x, mix, attn_flat, lp, cfg: ModelConfig, moe_dense, qmm,
-                  allow_dispatch: bool = False):
+                  allow_dispatch: bool = False, live=None):
     """Output projection and FFN of one block, with the sandwich norms;
-    returns (x', moe_aux, stats-or-None)."""
+    returns (x', moe_aux, stats-or-None). ``live``: a decode step's slot
+    mask (model.ffn)."""
     eps = cfg.rms_norm_eps
     with jax.named_scope("mla_out"):
         a = model.matmul(attn_flat, lp["wo"], qmm, "row")
@@ -266,7 +267,7 @@ def _finish_block(x, mix, attn_flat, lp, cfg: ModelConfig, moe_dense, qmm,
         x = residual.post(x, a, mix, cfg)
     u, mix = residual.pre(x, lp, "ffn", cfg)
     h = model.rms_norm(u, lp["ffn_norm"], eps)
-    m, aux, stats = model.ffn(h, lp, cfg, allow_dispatch, moe_dense, qmm)
+    m, aux, stats = model.ffn(h, lp, cfg, allow_dispatch, moe_dense, qmm, live)
     if cfg.sandwich_norm:
         m = model.rms_norm(m, lp["post_ffn_norm"], eps)
     return residual.post(x, m, mix, cfg), aux, stats
@@ -442,13 +443,15 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
             )
         with jax.named_scope("mla_out"):
             attn = _unabsorb_o(o_lat, lp, cfg)[:, None]
-        x, _, new = _finish_block(x, mix, attn, lp, cfg, moe_dense, qmm)
+        x, _, new = _finish_block(
+            x, mix, attn, lp, cfg, moe_dense, qmm, live=active
+        )
         return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
 
     (x, c_pool, r_pool, *stats), _ = model.scan_segments(
         block, (x, c_pool, r_pool, *model.zero_stats(cfg)),
         model.layer_segments(params),
-        moe.grouped_serves(B, cfg, moe_dense),
+        moe.visit_serves(cfg, moe_dense),
     )
     with jax.named_scope("final_logits"):
         logits = model._final_logits(

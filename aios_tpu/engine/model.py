@@ -438,7 +438,7 @@ def zero_stats(cfg: ModelConfig):
     """What a serving graph of a model with a router carries through its
     layers beside the residual: the expert counters (moe.pick_stats), from
     zero. Nothing for a model without one."""
-    return (jnp.zeros((3,), jnp.int32),) if cfg.moe else ()
+    return (jnp.zeros((moe_mod.PICK_STATS,), jnp.int32),) if cfg.moe else ()
 
 
 def add_stats(stats, new):
@@ -448,11 +448,11 @@ def add_stats(stats, new):
     return (stats[0] + new,)
 
 
-def _add_mlp(x, stats, lp, cfg: ModelConfig, moe_dense, qmm):
+def _add_mlp(x, stats, lp, cfg: ModelConfig, moe_dense, qmm, live=None):
     """A serving block's FFN sublayer: (x plus it, the carried expert
-    counters plus the layer's)."""
+    counters plus the layer's). ``live``: a decode step's slot mask."""
     h = rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
-    out, _, new = ffn(h, lp, cfg, False, moe_dense, qmm)
+    out, _, new = ffn(h, lp, cfg, False, moe_dense, qmm, live)
     return x + out, add_stats(stats, new)
 
 
@@ -477,6 +477,7 @@ def ffn(
     allow_dispatch: bool = False,
     moe_dense: bool = False,
     qmm=None,
+    live=None,  # [B] bool: the slots a DECODE step decodes; its graphs alone
 ):
     """The FFN of one layer over NORMED rows; returns (out, moe_aux,
     stats). Which FFN is the layer's own tree's to say (a dense layer has
@@ -484,16 +485,18 @@ def ffn(
     ``ws_*`` where every token also runs a shared expert), so leading dense
     layers and expert layers go through one function.
 
-    The expert path is chosen from STATIC shapes and the config: token
-    counts at which it computes fewer rows take the exact grouped path
-    (moe.grouped_serves: a prefill chunk or bucket; there ``lp``'s expert
+    The expert path is chosen by what the graph is, from STATIC shapes and
+    the config: a decode step (the one graph that hands ``live``) visits
+    the experts its live rows picked (moe.visit_serves), token counts at
+    which it computes fewer rows take the exact grouped path
+    (moe.grouped_serves: a prefill chunk or bucket; on both, ``lp``'s expert
     leaves may be the whole stacks, read in place at
     ``lp["expert_layer"]``), the training forward (``allow_dispatch``) the
-    capacity dispatch at large token counts, and everything else — a
-    decode step, and every graph of an engine under a sharding plan
-    (``moe_dense``) — the exact dense-over-held path.
+    capacity dispatch at large token counts, and everything else — the
+    small prefill buckets, and every graph of an engine under a sharding
+    plan (``moe_dense``) — the exact dense-over-held path.
 
-    ``stats`` is moe.pick_stats (int32 [3]) for a layer with a router, else
+    ``stats`` is moe.pick_stats (int32 [4]) for a layer with a router, else
     None: every serving graph of such a model carries the counters.
     """
     if "w_router" not in lp:
@@ -511,6 +514,8 @@ def ffn(
             # takes it, at large token counts — every serving path (decode,
             # chunked/bucketed prefill) stays on an exact path.
             out, aux = moe_mod.moe_ffn_dispatch(h, lp, cfg)
+        elif live is not None and moe_mod.visit_serves(cfg, moe_dense):
+            out, aux, stats = moe_mod.moe_ffn_visit(h, lp, cfg, live)
         elif moe_mod.grouped_serves(n_tok, cfg, moe_dense, allow_dispatch):
             out, aux, stats = moe_mod.moe_ffn_grouped(h, lp, cfg)
         else:
@@ -523,7 +528,7 @@ def ffn(
                 h, lp, "ws_", cfg.n_shared_experts * cfg.expert_dim, qmm
             )
     if stats is None:  # the training forward's dispatch counts nothing
-        stats = jnp.zeros((3,), jnp.int32)
+        stats = jnp.zeros((moe_mod.PICK_STATS,), jnp.int32)
     return out, aux, stats
 
 
@@ -565,7 +570,7 @@ def prefill(
     one "head" each: the latents [L,B,T,1,kv_lora_rank] and the padded
     rotary parts [L,B,T,1,128] (engine/paged.py header). A model with a
     router returns its expert counters (moe.pick_stats summed over the
-    layers, int32 [3]) as one more value, here and from every serving graph
+    layers, int32 [4]) as one more value, here and from every serving graph
     below.
 
     The engine copies the returned K/V into the request's cache slot.
@@ -836,8 +841,9 @@ def decode_step(
     sacrificial last cache row and attend over zero rows, so an inactive or
     mid-chunked-prefill slot costs no cache bandwidth and cannot corrupt
     rows an incremental admission has already written. The fixed-shape
-    graph still computes every slot's matmuls; only the cache traffic and
-    writes are gated. None means all slots active.
+    graph still computes every slot's matmuls; the cache traffic and
+    writes are gated, and in a model with a router an inactive slot's row
+    picks no expert (moe.moe_ffn_visit). None means all slots active.
 
     ``kernels`` — None picks the Pallas ragged-attention kernel on TPU
     (reads only rows [0, length] per slot from HBM); False forces the naive
@@ -873,6 +879,7 @@ def decode_step(
     if active is None:
         write_rows = lengths
         read_lengths = lengths
+        active = jnp.ones((B,), jnp.bool_)
     else:
         write_rows = jnp.where(active, lengths, C - 1)
         # read length -1 would be ideal; 0 exposes one (overwritten-before-
@@ -930,12 +937,12 @@ def decode_step(
             else:
                 attn = gqa_attention(q, k_l, v_l, mask)
         x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
-        x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm, active)
         return (x, stats), (k_l, v_l, *((k_s, v_s) if quant_cache else ()))
 
     x, k_cache, v_cache, scales, stats = _scan_layers_over_cache(
         block, x, params, k_cache, v_cache, cache_scales, cfg,
-        moe_mod.grouped_serves(B, cfg, moe_dense),
+        moe_mod.visit_serves(cfg, moe_dense),
     )
     logits = _final_logits(x[:, 0], params, cfg, qmm)
     if quant_cache:
@@ -965,7 +972,8 @@ def _experts_apart(layers, apart: bool):
 
 def _with_experts(lp, whole, l):
     """A scanned layer's tree with the whole expert stacks beside it and
-    ``expert_layer``, its index into them (moe.moe_ffn_grouped reads it)."""
+    ``expert_layer``, its index into them (moe._experts_in_place and
+    moe.moe_ffn_visit read it)."""
     return {**lp, **whole, "expert_layer": l} if whole else lp
 
 
@@ -977,11 +985,12 @@ def scan_segments(block, carry, segments, experts_whole: bool = False):
     blocks emitted, stacked over all layers; None where they emit nothing).
 
     ``experts_whole`` (a graph whose token count takes the grouped expert
-    path, moe.grouped_serves) keeps a segment's expert stacks OUT of the
-    scanned operands: the block gets them whole, ``[L, X, in, out]``, with
-    ``expert_layer``, the layer's index into them, and the grouped loop
-    reads ``w[l, e]`` where it lies. A scanned slice of them would be that
-    loop's operand, and so a copy of the layer's experts each layer."""
+    path, moe.grouped_serves, or a decode step, moe.visit_serves) keeps a
+    segment's expert stacks OUT of the scanned operands: the block gets
+    them whole, ``[L, X, in, out]``, with ``expert_layer``, the layer's
+    index into them, and the loop or kernel reads ``w[l, e]`` where it
+    lies. A scanned slice of them would be its operand, and so a copy of
+    the layer's experts each layer."""
     first, emitted = 0, []
     for seg in segments:
         n = jax.tree.leaves(seg)[0].shape[0]
@@ -1331,12 +1340,12 @@ def decode_step_paged(
         with jax.named_scope("attn_out"):
             x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
         with jax.named_scope(ffn_scope):
-            x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
+            x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm, act)
         return (x, k_pool, v_pool, tuple(scales), stats), None
 
     x, k_pool, v_pool, scales, stats = _scan_layers_over_pool(
         block, x, params, k_pool, v_pool, cache_scales, cfg,
-        moe_mod.grouped_serves(B, cfg, moe_dense),
+        moe_mod.visit_serves(cfg, moe_dense),
     )
     with jax.named_scope("final_logits"):
         logits = _final_logits(x[:, 0], params, cfg, qmm)

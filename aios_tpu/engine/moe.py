@@ -1,8 +1,21 @@
 """Mixture-of-experts FFN: router + expert computation, TPU-first.
 
-What serves (top-k routed SwiGLU experts, one math, chosen from static
-shapes by ``grouped_serves``):
+What serves (top-k routed SwiGLU experts, one math; which of the three a
+graph takes follows from what the graph IS and from static shapes, never
+from an option: ``visit_serves``, ``grouped_serves``):
 
+  * ``moe_ffn_visit`` — a DECODE step (the graph that hands the FFN its
+    live-slot mask), not under a sharding plan: the step is bound by the
+    expert weights' BYTES, so only the held experts that a LIVE row picked
+    are read, each where it lies in the stacks at (layer, expert), in
+    ascending order, a trip count read on the device from the step's own
+    picks. A visited expert runs over all the step's rows (8-32: nothing is
+    gathered), gated per row. Exact and dropless. A row of an inactive slot
+    picks no expert. With few live rows against many held experts (a layer
+    call touches about 4 of 64, 2.4 of 8 and 4-5 of 16 in the benchmark's
+    open-loop and half-used cells, 7.1 of 8 with eight live slots: PERF.md,
+    PR 32) that is a sixteenth to a third of the bytes; with every expert
+    touched it is every expert's bytes once, as the dense path reads them.
   * ``moe_ffn_grouped`` — PREFILL token counts (a chunk or a bucket of
     enough tokens, ``grouped_pays``): each held expert runs over the rows
     routed to it and no others, a GROUP_TILE of them at a time in one flat
@@ -12,11 +25,11 @@ shapes by ``grouped_serves``):
     token is 4,096.
   * ``moe_ffn_dense`` — every expert processes every token; per-token gate
     weights (zero for unselected experts) scale the outputs. Exact and
-    dropless. Decode steps are weight-bandwidth-bound, and at serving batch
-    sizes the routed set spans most experts anyway, so streaming all expert
-    weights is the honest cost — this is the DECODE path, the path of token
-    counts too small for a tile an expert, and every graph's under a
-    sharding plan. The einsum contracts over the expert axis, so under
+    dropless. Every held expert's weights are streamed whatever was picked:
+    the path of every graph under a sharding plan (decode steps too), of the
+    training forward's small token counts, and of the prefill buckets too
+    small for a tile an expert (``grouped_pays``; 128 tokens touch every
+    expert anyway). The einsum contracts over the expert axis, so under
     expert parallelism (experts sharded on the mesh's ``ep`` axis) each
     device computes its local experts and XLA inserts one psum over ``ep``
     — no hand-written collectives, same GSPMD recipe as the Megatron TP
@@ -37,7 +50,7 @@ published width and top-k; every FFN here computes the part of the result
 that the HELD experts give for the tokens routed to them (``local_picks``),
 and what the absent ones would add is left out — on one chip the layer runs
 without its exchange; ``moe_ffn_grouped`` lays out only the picks that
-landed here.
+landed here, and ``moe_ffn_visit`` visits only the held experts among them.
 
 Replaces: nothing in the reference — its only MoE access is the cloud
 qwen3:30b endpoint behind the api-gateway (api-gateway/src/main.rs:70-88).
@@ -51,6 +64,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import ops
+from ..ops import expert_visit
 from .config import ModelConfig
 
 
@@ -141,12 +156,20 @@ def local_picks(weights: jnp.ndarray, idx: jnp.ndarray, cfg: ModelConfig):
     )
 
 
-def pick_stats(here: jnp.ndarray, expert_rows) -> jnp.ndarray:
-    """int32 [3]: (picks the router made, picks that fell on an expert held
-    here, rows the expert matmuls computed) for one layer's call."""
+PICK_STATS = 4  # the numbers of ``pick_stats``
+
+
+def pick_stats(here: jnp.ndarray, expert_rows, visited, picks=None) -> jnp.ndarray:
+    """int32 [PICK_STATS]: (picks the router made, picks that fell on an
+    expert held here, rows the expert matmuls computed, experts whose
+    weights were read) for one layer's call. ``here`` [N, k] marks the
+    counted picks that landed here; ``picks`` is how many were counted where
+    that is not every row's (a decode step counts its live rows' alone)."""
     return jnp.stack([
-        jnp.int32(here.size), jnp.sum(here, dtype=jnp.int32),
+        jnp.asarray(here.size if picks is None else picks, jnp.int32),
+        jnp.sum(here, dtype=jnp.int32),
         jnp.asarray(expert_rows, jnp.int32),
+        jnp.asarray(visited, jnp.int32),
     ])
 
 
@@ -211,7 +234,7 @@ def moe_ffn_dense(
         if here is None:
             here = jnp.ones(idx.shape, jnp.bool_)
         return out.reshape(B, T, E), aux, pick_stats(
-            here, B * T * cfg.held_experts
+            here, B * T * cfg.held_experts, cfg.held_experts
         )
     return out.reshape(B, T, E), aux
 
@@ -289,8 +312,10 @@ def grouped_pays(n_tok: int, cfg: ModelConfig) -> bool:
     GROUP_TILE a held expert, against every held expert over every token.
     At 2 of 8 with all held that is 2,048 against 4,096 rows for 512 tokens
     and 1,280 against 1,024 for 128; at 8 of 256 with 16 held, 2,176 against
-    4,096 for 256 tokens and 2,112 against 2,048 for 128. A decode step's
-    few rows never pay."""
+    4,096 for 256 tokens and 2,112 against 2,048 for 128. This reckons ROWS,
+    the measure of a prefill's compute; a decode step, which is bound by the
+    experts' bytes, is never asked (``visit_serves``), and under a sharding
+    plan, where it is, its few rows do not pay."""
     held = cfg.held_experts
     picks = -(-n_tok * cfg.num_experts_per_tok * held // cfg.num_experts)
     return picks + held * GROUP_TILE < held * n_tok
@@ -312,6 +337,114 @@ def grouped_serves(
     )
 
 
+def visit_serves(cfg: ModelConfig, moe_dense: bool = False) -> bool:
+    """Whether a DECODE step's graph runs its expert layers through
+    ``moe_ffn_visit``: a model with a router, not under a sharding plan.
+    That the graph is a decode step is its own to say: it hands model.ffn
+    its live-slot mask, and its layer scan hands the expert stacks whole."""
+    return cfg.moe and not moe_dense
+
+
+def _experts_in_place(lp, F: int):
+    """(swiglu(x, e), down(z, e)) of one layer's experts read WHERE THEY
+    LIE: expert ``e``'s SwiGLU over rows ``x`` (in x's dtype) and its down
+    product (float32), each matrix product indexing ``w[l, e]`` itself.
+    ``lp``'s expert leaves may be one layer's ``[X, in, out]`` or, with
+    ``lp["expert_layer"]`` the layer's index into them, the whole stacks
+    ``[L, X, in, out]``."""
+    whole = "expert_layer" in lp
+    l = lp["expert_layer"] if whole else 0
+
+    def at(a, e):  # expert e's matrix (or scales) where the stack holds it
+        stack = a if whole else a[None]
+        return jax.lax.dynamic_slice(
+            stack, (l, e, 0, 0), (1, 1) + stack.shape[2:]
+        )[0, 0]
+
+    def qdot(x, w, e):  # [rows, in] @ expert e's [in, out]; float32 out
+        if isinstance(w, dict):
+            y = jnp.einsum(
+                "ni,io->no", x, at(w["q"], e),
+                preferred_element_type=jnp.float32,
+            )
+            return y * at(w["s"], e)[0]
+        return jnp.einsum(
+            "ni,io->no", x, at(w, e), preferred_element_type=jnp.float32
+        )
+
+    def swiglu(x, e):
+        if "we_gateup" in lp:  # fused serving layout (quantize_params)
+            gu = qdot(x, lp["we_gateup"], e).astype(x.dtype)
+            a, u = gu[:, :F], gu[:, F:]
+        else:
+            a = qdot(x, lp["we_gate"], e).astype(x.dtype)
+            u = qdot(x, lp["we_up"], e).astype(x.dtype)
+        return jax.nn.silu(a.astype(jnp.float32)).astype(x.dtype) * u
+
+    return swiglu, lambda z, e: qdot(z, lp["we_down"], e)
+
+
+def moe_ffn_visit(
+    h: jnp.ndarray,  # [B, T, E] normalized hidden states
+    lp,
+    cfg: ModelConfig,
+    live: jnp.ndarray,  # [B] bool — the slots that decode in this step
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Exact dropless MoE FFN of a DECODE step; returns (out, aux,
+    ``pick_stats``).
+
+    The rows of slots that are not ``live`` pick no expert (their result is
+    zero and read by nobody). From the live rows' picks that fall on a held
+    expert: which held experts are touched, in ascending order, and how many
+    (``expert_visit.visit_list``: on the device, no readback). Each touched
+    expert then runs over ALL the rows, gated per row by the router's weight
+    (zero for a row that did not pick it), and the visits add up in float32:
+    ``moe_ffn_dense`` restricted to the experts whose gate column is not all
+    zero, reading nothing of the others. The trip count is the touched
+    count. ``lp``'s expert leaves as for ``moe_ffn_grouped``: the decode
+    steps' layer scans hand the stacks whole. On the chip the serving
+    layout's int8 leaves go through ONE kernel a layer
+    (ops/expert_visit.py); anything else, and the CPU, through a loop of the
+    same products."""
+    B, T, E = h.shape
+    N, k, X, F = B * T, cfg.num_experts_per_tok, cfg.held_experts, cfg.expert_dim
+    flat = h.reshape(N, E)
+    probs, weights, idx = route(flat, lp["w_router"], cfg, lp.get("router_bias"))
+    weights, idx_here, here = local_picks(weights, idx, cfg)
+    rows = jnp.broadcast_to(live[:, None], (B, T)).reshape(N, 1)
+    here = jnp.broadcast_to(rows, idx.shape) if here is None else here & rows
+    gates = gate_matrix(jnp.where(here, weights, 0.0), idx_here, X)
+    touched = jnp.any(
+        jax.nn.one_hot(jnp.where(here, idx_here, X), X, dtype=jnp.bool_),
+        axis=(0, 1),
+    )
+    visit, n = expert_visit.visit_list(touched)
+
+    gu, dn = lp.get("we_gateup"), lp["we_down"]
+    if (ops.use_pallas() and isinstance(gu, dict) and isinstance(dn, dict)
+            and expert_visit.supports_pallas(E, F)):
+        stacks = (gu["q"], gu["s"], dn["q"], dn["s"])
+        if "expert_layer" not in lp:
+            stacks = tuple(a[None] for a in stacks)
+        out = expert_visit.expert_visit(
+            flat, gates, visit, n, lp.get("expert_layer", 0), *stacks
+        )
+    else:
+        swiglu, down = _experts_in_place(lp, F)
+
+        def one(i, acc):
+            e = visit[i]
+            gate = jax.lax.dynamic_slice_in_dim(gates, e, 1, axis=1)
+            return acc + down(swiglu(flat, e) * gate.astype(h.dtype), e)
+
+        out = jax.lax.fori_loop(0, n, one, jnp.zeros((N, E), jnp.float32))
+    aux = load_balance_aux(probs, idx, cfg.num_experts)
+    return (
+        out.astype(h.dtype).reshape(B, T, E), aux,
+        pick_stats(here, n * N, n, picks=jnp.sum(rows, dtype=jnp.int32) * k),
+    )
+
+
 def moe_ffn_grouped(
     h: jnp.ndarray,  # [B, T, E] normalized hidden states
     lp,
@@ -325,11 +458,9 @@ def moe_ffn_grouped(
     the tiles that hold a pick (a trip count read from the picks, so no
     capacity is fixed and no pick is dropped): tile ``i`` belongs to the
     expert whose tiles it falls among, and its three matrix products read
-    that expert's int8 weights WHERE THEY LIE. ``lp``'s expert leaves may be
-    one layer's ``[X, in, out]`` or, with ``lp["expert_layer"]`` the layer's
-    index into them, the whole stacks ``[L, X, in, out]`` (the layer scans
-    hand them so: a layer's slice taken by the scan would become the loop's
-    operand, and a copy); the tile's dot indexes ``w[l, e]`` itself. Each
+    that expert's int8 weights WHERE THEY LIE (``_experts_in_place``: the
+    layer scans hand the stacks whole, because a layer's slice taken by the
+    scan would become the loop's operand, and a copy). Each
     token then adds up its picks' rows, gated, in float32. Work follows the
     picks that landed here where the dense path runs every held expert over
     every token; an expert's weights stream once a tile."""
@@ -360,38 +491,13 @@ def moe_ffn_grouped(
     )
     x_rows = jnp.concatenate([flat, jnp.zeros((1, E), flat.dtype)])[src]
 
-    whole = "expert_layer" in lp
-    l = lp["expert_layer"] if whole else 0
-
-    def at(a, e):  # expert e's matrix (or scales) where the stack holds it
-        stack = a if whole else a[None]
-        return jax.lax.dynamic_slice(
-            stack, (l, e, 0, 0), (1, 1) + stack.shape[2:]
-        )[0, 0]
-
-    def qdot(x, w, e):  # [TM, in] @ expert e's [in, out]; float32 out
-        if isinstance(w, dict):
-            y = jnp.einsum(
-                "ni,io->no", x, at(w["q"], e),
-                preferred_element_type=jnp.float32,
-            )
-            return y * at(w["s"], e)[0]
-        return jnp.einsum(
-            "ni,io->no", x, at(w, e), preferred_element_type=jnp.float32
-        )
+    swiglu, down = _experts_in_place(lp, F)
 
     def tile(i, y_rows):
         e = jnp.sum(i >= tile_end, dtype=jnp.int32)  # the tile's expert
         x = jax.lax.dynamic_slice(x_rows, (i * TM, 0), (TM, E))
-        if "we_gateup" in lp:  # fused serving layout (quantize_params)
-            gu = qdot(x, lp["we_gateup"], e).astype(x.dtype)
-            a, u = gu[:, :F], gu[:, F:]
-        else:
-            a = qdot(x, lp["we_gate"], e).astype(x.dtype)
-            u = qdot(x, lp["we_up"], e).astype(x.dtype)
-        z = jax.nn.silu(a.astype(jnp.float32)).astype(x.dtype) * u
         return jax.lax.dynamic_update_slice(
-            y_rows, qdot(z, lp["we_down"], e), (i * TM, 0)
+            y_rows, down(swiglu(x, e), e), (i * TM, 0)
         )
 
     y_rows = jax.lax.fori_loop(
@@ -404,5 +510,5 @@ def moe_ffn_grouped(
     aux = load_balance_aux(probs, idx, cfg.num_experts)
     return (
         out.astype(h.dtype).reshape(B, T, E), aux,
-        pick_stats(here, n_tiles * TM),
+        pick_stats(here, n_tiles * TM, jnp.sum(tiles > 0)),
     )

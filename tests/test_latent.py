@@ -155,8 +155,11 @@ def test_chunked_prefill_then_decode_through_the_latent_pool(params):
         lg, c_pool, r_pool, picks = latent.decode_step_paged(
             params, CFG, toks, jnp.asarray(lengths), c_pool, r_pool, tables,
             kernels=False)
-        assert picks.tolist()[0] == 2 * CFG.num_experts_per_tok * 2
-        assert picks.tolist()[2] == 2 * CFG.held_experts * 2  # dense-over-held rows
+        total, local, expert_rows, visited = picks.tolist()
+        assert total == 2 * CFG.num_experts_per_tok * 2
+        # the held experts a row picked, each over both rows, in 2 layers
+        assert local // 2 <= visited <= local
+        assert expert_rows == 2 * visited <= 2 * CFG.held_experts * 2
         rows[0].append(np.asarray(lg)[0])
         rows[1].append(np.asarray(lg)[1])
         lengths += 1
@@ -265,9 +268,10 @@ def test_grouped_experts_match_dense_over_held(params):
     grouped, _, s_grouped = moe.moe_ffn_grouped(h, lp, CFG)
     want = np.asarray(dense, np.float32)
     assert np.abs(np.asarray(grouped, np.float32) - want).max() < 0.02 * np.abs(want).max()
-    total, local, rows = s_grouped.tolist()
+    total, local, rows, visited = s_grouped.tolist()
     assert (total, local) == tuple(s_dense.tolist()[:2]) == (256 * 4, local)
-    assert 0 < local < total and s_dense.tolist()[2] == 256 * 8
+    assert 0 < local < total and s_dense.tolist()[2:] == [256 * 8, 8]
+    assert visited * moe.GROUP_TILE <= rows and 0 < visited <= 8
     # a tile of 128 rows an expert that was picked at all; never a dropped pick
     assert local <= rows <= 8 * moe.GROUP_TILE * 2 and rows % moe.GROUP_TILE == 0
     # and through model.ffn the static token count alone chooses between them

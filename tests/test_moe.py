@@ -410,7 +410,10 @@ def test_ep_sharded_engine_decode_matches_single_device(cpu_devices):
         f0 = ref.prefill(0, [1, 2, 3, 4], temperature=0.0)
         t0 = ref.step(3)
         assert first == f0
-        assert toks.tolist() == t0.tolist()
+        # slot 0 alone decodes: a row of an inactive slot picks no expert on
+        # the single device's path and every one under the plan's, so what
+        # it samples (read by nobody) differs
+        assert toks[:, 0].tolist() == t0[:, 0].tolist()
     finally:
         eng.close()
         ref.close()

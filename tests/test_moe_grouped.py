@@ -108,11 +108,12 @@ def test_grouped_matches_dense_at_top2_of_8(leaves, n_tok, routing):
         want = np.asarray(want, np.float32)
         tol = TOL * np.abs(want).max()
         assert np.abs(np.asarray(got, np.float32) - want).max() < tol, l
-        total, local, rows = s_grouped.tolist()
+        total, local, rows, visited = s_grouped.tolist()
         assert total == local == 2 * n_tok == s_dense.tolist()[0]
-        assert s_dense.tolist()[2] == 8 * n_tok
+        assert s_dense.tolist()[2:] == [8 * n_tok, 8]
         counts = np.bincount(np.asarray(idx).ravel(), minlength=8)
         assert rows == int(np.sum(-(-counts // moe.GROUP_TILE)) * moe.GROUP_TILE)
+        assert visited == np.count_nonzero(counts)
         if routing == "one-takes-all":
             assert counts[TAKES_ALL] == n_tok and counts[TAKES_NONE] == 0
     # the control: ONE pick of one token dropped is a fault this tolerance sees
@@ -174,10 +175,12 @@ def _greedy(engine, prompt, chunk):
 def test_chunked_prefill_through_grouped_yields_the_dense_tokens(monkeypatch):
     """A 300-token prompt admitted in a 256-token chunk (grouped: 512 picks
     and 8 part tiles against 2,048 rows) and a final 64-token bucket (dense),
-    then greedy decode: the tokens of an engine for which the grouped path
-    never pays, so that every graph of it runs dense.
+    then greedy decode (the visit path: the one live slot's two experts a
+    layer over both rows): the tokens of an engine for which the grouped path
+    never pays, so that every prefill graph of it runs dense.
     The counters say which path ran: dense-over-all computes exactly 4 rows
-    a pick (8 experts over every token, 2 picks a token)."""
+    a pick (8 experts over every token, 2 picks a token) and reads 8 experts
+    a layer call."""
     cfg = dataclasses.replace(CFG, num_layers=2)
     params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     prompt = (np.arange(1, 301) * 7 % 500 + 1).tolist()
@@ -189,20 +192,27 @@ def test_chunked_prefill_through_grouped_yields_the_dense_tokens(monkeypatch):
     picks, local, rows = (auto.moe_picks_total, auto.moe_picks_local,
                           auto.moe_expert_rows)
     auto.close()
-    # chunk 256 + final 64 + 8 steps of 2 slots, 2 picks a token, 2 layers
-    assert picks == local == 2 * 2 * (256 + 64 + 8 * 2)
+    # chunk 256 + final 64 + 8 steps of the ONE live slot, 2 picks a token,
+    # 2 layers
+    assert picks == local == 2 * 2 * (256 + 64 + 8)
     assert picks < rows < 4 * picks
     monkeypatch.setattr(moe, "grouped_pays", lambda n_tok, cfg: False)
     dense = TPUEngine(cfg, params, **kw)
     want = _greedy(dense, prompt, 256)
-    assert dense.moe_expert_rows == 4 * dense.moe_picks_total == 4 * picks
+    assert dense.moe_picks_total == picks
+    # two prefill graphs x 2 layers x all 8; 8 steps x 2 layers x the 2 picked
+    assert dense.moe_experts_visited == 2 * 2 * 8 + 8 * 2 * 2
+    # 8 experts over every prompt row; a visit runs both slots' rows
+    assert dense.moe_expert_rows == 2 * 8 * (256 + 64) + 2 * (8 * 2 * 2)
+    assert auto.moe_experts_visited <= dense.moe_experts_visited
     dense.close()
     assert got == want
 
 
 # -- which path a graph takes, and what every graph counts ------------------
 
-PATHS = ("moe_ffn_dense", "moe_ffn_grouped", "moe_ffn_dispatch")
+PATHS = ("moe_ffn_dense", "moe_ffn_grouped", "moe_ffn_dispatch",
+         "moe_ffn_visit")
 
 
 @pytest.fixture
@@ -243,14 +253,16 @@ def _trace_chunk(eng, n_tok):
 
 
 @pytest.mark.parametrize("case", [
-    "decode", "chunk512", "chunk512_under_plan", "train2048"])
+    "decode", "decode_under_plan", "chunk512", "chunk512_under_plan",
+    "train2048"])
 def test_the_expert_path_follows_token_count_plan_and_training_alone(
         case, traced):
-    """What is left of the choice: a decode dispatch runs every held expert
-    over every token, a 512-token chunk each expert over its own rows, the
-    same chunk of an engine under a sharding plan dense again (the engine's
-    one bit), and the training forward at 2,048 tokens the capacity
-    dispatch. No string and no environment name selects a path."""
+    """What is left of the choice: a decode dispatch visits the experts its
+    live rows picked, a 512-token chunk runs each expert over its own rows,
+    the same two graphs of an engine under a sharding plan run every held
+    expert over every token (the engine's one bit), and the training forward
+    at 2,048 tokens the capacity dispatch. No string and no environment name
+    selects a path."""
     from aios_tpu.engine.engine import DECODE_STEPS
     from aios_tpu.parallel.sharding import ShardingPlan, build_mesh
 
@@ -262,20 +274,20 @@ def test_the_expert_path_follows_token_count_plan_and_training_alone(
         assert set(traced) == {"moe_ffn_dispatch"}
         return
     plan = None
-    if case == "chunk512_under_plan":
+    if case.endswith("_under_plan"):
         plan = ShardingPlan(build_mesh(8, dp=2, ep=2, tp=2))
     eng = _small_engine(shardings=plan,
                         paged_pool_rows=None if plan else 2 * 512)
     try:
         assert eng._moe_dense is (plan is not None)
-        if case == "decode":
+        if case.startswith("decode"):
             eng._make_step_jit(DECODE_STEPS).lower(*eng._step_example())
         else:
             _trace_chunk(eng, 512)
     finally:
         eng.close()
-    want = "moe_ffn_grouped" if case == "chunk512" else "moe_ffn_dense"
-    assert set(traced) == {want}
+    want = {"decode": "moe_ffn_visit", "chunk512": "moe_ffn_grouped"}
+    assert set(traced) == {want.get(case, "moe_ffn_dense")}
 
 
 @pytest.mark.parametrize("num_slots, grouped", [(8, False), (16, True)],
@@ -310,22 +322,25 @@ def test_a_verify_feed_takes_the_path_a_prefill_of_its_token_count_takes(
 def test_router_counters_come_back_from_every_decode_graph_by_the_next_scan_dispatch(
         graph):
     """``moe_picks_total`` ends at rows x top-k x expert layers whichever
-    graph fed the rows (every slot's, live or not: the graphs are of fixed
-    shape). Only the scan graphs (step, masked) append the device's sums to
-    their token readback; a speculative round and a jump run add into the
-    sums as a prefill does, and the next scan dispatch brings them back."""
+    graph fed the rows: a decode step's LIVE rows (the one slot that was
+    prefilled), and every slot's in a verify feed, live or not (those graphs
+    are of fixed shape and hand no mask). Only the scan graphs (step, masked)
+    append the device's sums to their token readback; a speculative round
+    and a jump run add into the sums as a prefill does, and the next scan
+    dispatch brings them back."""
     eng = _small_engine()
     S, per_row = eng.num_slots, 2 * 2  # top-2, two expert layers
+    live = 1
     try:
         eng.prefill(0, [5, 9, 5, 9, 5, 9, 5, 9], temperature=0.0)
         eng.step(1)  # the prefill's counts come back here
         before = eng.moe_picks_total
         if graph == "step":
             eng.step(2)
-            rows = 2 * S
+            rows = 2 * live
         elif graph == "masked":
             eng.step_masked(np.zeros((S, eng.cfg.vocab_size), np.float32))
-            rows = S
+            rows = live
         else:
             if graph == "spec":
                 eng.spec_step(1, draft_len=3)
@@ -335,7 +350,7 @@ def test_router_counters_come_back_from_every_decode_graph_by_the_next_scan_disp
             assert eng.moe_picks_total == before  # held on the device
             eng.step(1)
             # the pending token leads a feed: 3 drafts, or a 4-token run
-            rows = (5 if graph == "jump" else 4) * S + S
+            rows = (5 if graph == "jump" else 4) * S + live
         assert eng.moe_picks_total == before + rows * per_row
         assert eng.moe_picks_local == eng.moe_picks_total
     finally:

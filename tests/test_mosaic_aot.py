@@ -546,6 +546,88 @@ def test_aot_grouped_experts_are_read_in_place(
     assert live < 15.75e9, f"{live / 1e9:.2f} GB live"
 
 
+# --- a decode step visits the experts its live rows picked (PR 32) ----------
+
+
+@pytest.mark.parametrize("config_name, arch_file, context, slots, pages", [
+    ("mixtral-8x7b-int8-d6.json", "mistral.py", 4096, 8, 9 * 32 + 1),
+    ("openpangu-ultra-moe-int8-ep16-d5.json", "pangu_ultra_moe.py", 16384, 32,
+     33 * 128 + 1),
+    ("xing4-29b-a4b-int8-d13.json", "xing4.py", 8192, 16, 17 * 64 + 1),
+])
+def test_aot_decode_steps_visit_their_experts_in_place(
+    rep_sharding, monkeypatch, config_name, arch_file, context, slots, pages
+):
+    """The visit kernel alone at each MoE configuration's widths and rows
+    (ops/expert_visit.py: a traced grid bound, the stacks indexed at
+    ``[l, visit[i]]`` by the block specs), then the composed decode step as
+    the benchmark serves it: it compiles for the v5e, fits beside the
+    weights, holds one visit kernel a layer scan, and makes nothing as large
+    as ONE layer's expert stack (the dense path's ``bf16[64,16,2048]``-sized
+    results are a layer's experts over every row; a scanned slice of the
+    stacks would be a copy of them: PR 27), nor copies a whole stack."""
+    from aios_tpu import backend
+    from aios_tpu.engine import model as M
+    from aios_tpu.engine import moe
+    from aios_tpu.ops import expert_visit as ev
+
+    cfg, shapes = _bench_model(config_name, arch_file, context)
+    assert moe.visit_serves(cfg)
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    rep = rep_sharding
+    params = jax.tree.map(lambda a: sds(rep, a.shape, a.dtype), shapes)
+    layers = params["layers"]
+    E, F, X = cfg.hidden_size, cfg.expert_dim, cfg.held_experts
+    assert ev.supports_pallas(E, F)
+    i32 = lambda *shape: sds(rep, shape, jnp.int32)  # noqa: E731
+    aot_compile(
+        rep, ev.expert_visit, sds(rep, (slots, E), jnp.bfloat16),
+        sds(rep, (slots, X), jnp.float32), i32(X), i32(), i32(),
+        layers["we_gateup"]["q"], layers["we_gateup"]["s"],
+        layers["we_down"]["q"], layers["we_down"]["s"],
+    )
+    pools = tuple(
+        sds(rep, (cfg.num_layers, pages, 128, w), jnp.bfloat16)
+        for w in (cfg.kv_row_dims if cfg.mla
+                  else (cfg.num_kv_heads * cfg.head_dim,) * 2))
+
+    def step(p, k, v, toks, lens, tables, active):
+        return M.decode_step_paged(p, cfg, toks, lens, k, v, tables,
+                                   kernels=True, active=active)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, *pools, i32(slots), i32(slots), i32(slots, context // 128),
+        sds(rep, (slots,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%expert_visit[.\d]* = ", text)) == 1
+    stacks = [layers[n]["q"] for n in ("we_gateup", "we_down")]
+    layer_bytes = sum(int(np.prod(a.shape[1:])) for a in stacks)
+    # by the dimensions' text: xing4's output head has a layer's gate-up
+    # stack's element count (131,072 x 3,584 = 64 x 3,584 x 2,048)
+    dims = lambda shape: "[" + ",".join(map(str, shape)) + "]"  # noqa: E731
+    layer_dims = [dims(a.shape[1:]) for a in stacks]
+    # every expert over every row: the dense path's results
+    layer_dims += [dims((X, slots, 2 * F)), dims((X, slots, E))]
+    stack_dims = [dims(a.shape) for a in stacks]
+    made = []
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None or m["op"] in _HLO_VIEWS:
+            continue
+        if any(d in m["type"] for d in layer_dims) or (
+                m["op"] == "copy" and any(d in m["type"] for d in stack_dims)):
+            made.append(line.strip()[:160])
+    assert made == [], f"results as large as a layer's experts: {made[:4]}"
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes, (
+        f"{mem.temp_size_in_bytes / 1e9:.2f} GB of temporaries against "
+        f"{layer_bytes / 1e9:.2f} GB of experts a layer")
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert live < 15.75e9, f"{live / 1e9:.2f} GB live"
+
+
 # --- the residual of several mixed streams (PR 31) ---------------------------
 
 

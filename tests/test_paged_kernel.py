@@ -273,3 +273,147 @@ def test_decode_step_paged_moves_no_pool_slice():
     find(body)
     assert len(calls) == 1
     assert sum(v.aval.shape == pool.shape for v in calls[0].invars) == 2
+
+
+# -- the decode step's experts (PR 32) ----------------------------------------
+
+
+def _vars_outside_the_visit(jaxpr):
+    """Every variable's shape in ``jaxpr`` and below, but inside the visit:
+    its loop (``while``) on the CPU, its kernel call on the chip."""
+    shapes = {tuple(v.aval.shape) for v in (*jaxpr.invars, *jaxpr.constvars)
+              if hasattr(v.aval, "shape")}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("while", "pallas_call"):
+            continue
+        shapes |= {tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars)
+                   if hasattr(v, "aval") and hasattr(v.aval, "shape")}
+        for sub in _sub_jaxprs(eqn):
+            shapes |= _vars_outside_the_visit(sub)
+    return shapes
+
+
+def _moe_step_layer_body(moe_dense):
+    from aios_tpu.engine.config import TINY_MOE
+
+    cfg = dataclasses.replace(TINY_MOE, num_layers=3)
+    params = model.quantize_params(
+        model.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+    pool = jnp.zeros((cfg.num_layers, 9, 16, cfg.num_kv_heads * cfg.head_dim),
+                     jnp.bfloat16)
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+
+    def step(params, k, v, toks, lens, active):
+        return model.decode_step_paged(
+            params, cfg, toks, lens, k, v, tables, kernels=False,
+            active=active, moe_dense=moe_dense)
+
+    jaxpr = jax.make_jaxpr(step)(
+        params, pool, pool, jnp.asarray([1, 2], jnp.int32),
+        jnp.asarray([5, 11], jnp.int32), jnp.asarray([True, False]),
+    ).jaxpr
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"
+             and any(getattr(v.aval, "shape", None) == pool.shape for v in e.invars)]
+    assert len(scans) == 1, "the layer scan that carries the pool"
+    X, E, F = cfg.num_experts, cfg.hidden_size, cfg.expert_dim
+    return scans[0].params["jaxpr"].jaxpr, {(X, E, 2 * F), (X, F, E)}, cfg
+
+
+def test_decode_step_reads_its_experts_where_they_lie():
+    """The layer scan of a MoE decode step has no variable of a layer's
+    experts' shape ``[X, in, out]`` outside the visit: the stacks reach the
+    body whole (``[L, X, in, out]``, not scanned) and only the visit indexes
+    them. Under a sharding plan's dense path the scanned slices are there
+    (the control: this test sees them)."""
+    body, layer_shapes, cfg = _moe_step_layer_body(moe_dense=False)
+    seen = _vars_outside_the_visit(body)
+    assert not layer_shapes & seen, layer_shapes & seen
+    whole = {(cfg.num_layers, *s) for s in layer_shapes}
+    assert whole <= seen  # handed whole, as the scan's constants
+    dense_body, _, _ = _moe_step_layer_body(moe_dense=True)
+    assert layer_shapes <= _vars_outside_the_visit(dense_body)
+
+
+def test_the_live_mask_reaches_only_the_graphs_that_use_it(monkeypatch):
+    """A Mistral-shaped decode step (no router) and a Pangu-shaped CHUNK (a
+    prefill graph: it hands no mask) lower, text for text, to what they are
+    with the mask dropped on its way to the FFN, which is how the parent
+    called it; the MoE decode steps trace the visit (the control)."""
+    import os
+    import sys
+
+    from aios_tpu.engine import latent
+    from aios_tpu.engine.config import TINY_MOE, ModelConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.harness.manifest import load_file
+
+    arch = load_file(os.path.join(root, "benchmark", "archs", "pangu_ultra_moe.py"),
+                     "benchmark_arch")
+    tiny = dict(
+        num_hidden_layers=3, first_k_dense_replace=1, hidden_size=64,
+        intermediate_size=128, moe_intermediate_size=32, num_attention_heads=4,
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, vocab_size=512, n_routed_experts=8, router_n_experts=32,
+        first_routed_expert=8, num_experts_per_tok=4, n_shared_experts=1,
+        routed_scaling_factor=2.5, norm_topk_prob=True, rope_theta=25600000.0,
+        rms_norm_eps=1e-5, max_position_embeddings=128,
+        assumed={"served_name": "tiny-pangu"})
+    pangu = ModelConfig(**arch.model_fields(tiny, 128))
+    pangu_shapes = jax.eval_shape(lambda: arch.build_params(arch.dims_of(tiny), 1))
+    mistral = dataclasses.replace(TINY_TEST, num_layers=3)
+    mixtral = dataclasses.replace(TINY_MOE, num_layers=3)
+
+    def quantized(cfg):
+        return jax.eval_shape(lambda: model.quantize_params(
+            model.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+
+    def pools(cfg):
+        return tuple(jax.ShapeDtypeStruct((cfg.num_layers, 9, 16, w), jnp.bfloat16)
+                     for w in (cfg.kv_row_dims if cfg.mla else
+                               (cfg.num_kv_heads * cfg.head_dim,) * 2))
+
+    def step_text(cfg, shapes):
+        def step(p, k, v, toks, lens, tables, active):
+            return model.decode_step_paged(p, cfg, toks, lens, k, v, tables,
+                                           kernels=False, active=active)
+        return jax.jit(step).lower(
+            shapes, *pools(cfg), i32(2), i32(2), i32(2, 8),
+            jax.ShapeDtypeStruct((2,), jnp.bool_)).as_text()
+
+    def chunk_text(cfg, shapes):
+        def chunk(p, k, v, toks, start, row):
+            return model.prefill_chunk_paged(p, cfg, toks, start, k, v, row)
+        return jax.jit(chunk).lower(
+            shapes, *pools(cfg), i32(1, 16), i32(), i32(8)).as_text()
+
+    from aios_tpu.engine import moe
+
+    visits = []
+    real_visit = moe.moe_ffn_visit
+    monkeypatch.setattr(
+        moe, "moe_ffn_visit",
+        lambda *a, **kw: (visits.append(1), real_visit(*a, **kw))[1])
+
+    def texts():
+        return (step_text(mistral, quantized(mistral)),
+                chunk_text(pangu, pangu_shapes))
+
+    with_mask = texts()
+    assert visits == []
+    step_text(mixtral, quantized(mixtral))
+    step_text(pangu, pangu_shapes)
+    assert len(visits) == 2  # the control: one traced layer body a MoE step
+    real = model.ffn
+    monkeypatch.setattr(
+        model, "ffn",
+        lambda h, lp, cfg, allow_dispatch=False, moe_dense=False, qmm=None,
+        live=None: real(h, lp, cfg, allow_dispatch, moe_dense, qmm))
+    assert latent.model is model  # the latent block calls through the module
+    dropped = texts()
+    assert with_mask[0] == dropped[0], "a Mistral-shaped decode step"
+    assert with_mask[1] == dropped[1], "a Pangu-shaped chunk"
