@@ -796,7 +796,8 @@ class ContinuousBatcher:
                 # HIGHER-priority streams hold the pool, keep the partial
                 # admission and retry next tick (they drain eventually).
                 outcome = self._evict_longest(
-                    e.replica, requester_priority=live.req.priority
+                    e.replica, requester_priority=live.req.priority,
+                    kind=e.kind,
                 )
                 if outcome == "blocked":
                     return
@@ -882,7 +883,7 @@ class ContinuousBatcher:
                 slot = free[0]
             live.slot = slot
             ids = live.req.prompt_ids
-            need_rows = min(len(ids), self.engine.max_context - 1)
+            need_rows = all_rows = min(len(ids), self.engine.max_context - 1)
             window = self.engine.cfg.sliding_window
             if (
                 alloc is not None
@@ -913,9 +914,12 @@ class ContinuousBatcher:
                     max(self.engine.kv_compress_after, comp_rows)
                     + self.prefill_chunk + 2 * alloc.page_size,
                 )
-            if alloc is not None and alloc.blocks_for(
-                need_rows
-            ) > alloc.capacity_blocks():
+            # pages by kind: the full kind budgets the prompt's every row, the
+            # window kind what the bound above leaves of it
+            never_fits = alloc is not None and not alloc.can_hold(
+                all_rows, need_rows
+            )
+            if never_fits:
                 # the prompt can NEVER fit the pool — fail it up front;
                 # evicting live requests one per tick would truncate every
                 # co-resident stream before reaching the same conclusion
@@ -962,7 +966,8 @@ class ContinuousBatcher:
                 with self._qlock:
                     self._waiting.appendleft(live)  # keep FIFO order
                 outcome = self._evict_longest(
-                    e.replica, requester_priority=live.req.priority
+                    e.replica, requester_priority=live.req.priority,
+                    kind=e.kind,
                 )
                 if outcome == "empty":
                     # nothing to evict: the prompt is bigger than the whole
@@ -1049,7 +1054,7 @@ class ContinuousBatcher:
                     pass  # state untouched; the post-evict tick retries
                 else:
                     self._pending = nxt  # it ran after all: deliver it
-            self._evict_longest(e.replica)
+            self._evict_longest(e.replica, kind=e.kind)
             return
         self._gap_wait += time.monotonic() - t0
         self._consumed = tick
@@ -1209,6 +1214,13 @@ class ContinuousBatcher:
             fields["restored_rows"] = int(restored)
         if chunk is not None:
             fields["chunk"] = chunk
+        if self.engine.cfg.kinds and live.slot >= 0:
+            # pages by kind: what this admission left the slot holding
+            alloc = self.engine.allocator
+            fields["pages_full"] = alloc.slot_pages_resident(live.slot, "full")
+            fields["pages_window"] = alloc.slot_pages_resident(
+                live.slot, "window"
+            )
         rec.event("prefill", **fields)
 
     def _rec_close(self, live: _Live) -> None:
@@ -1316,9 +1328,12 @@ class ContinuousBatcher:
     def _evict_longest(
         self, replica: Optional[int] = None,
         requester_priority: Optional[int] = None,
+        kind: str = "",
     ) -> str:
         """Retire the lowest-priority live request, longest first within a
-        priority level (frees the most pages), so a pool-exhausted
+        priority level (frees the most pages; where the pool has pages by
+        kind, the request that holds most of the ``kind`` that ran short:
+        PoolExhausted.kind), so a pool-exhausted
         dispatch can make progress without sacrificing strategic work to
         keep bulk traffic alive. ``replica`` restricts the hunt to the
         starved replica of a dp-partitioned pool — evicting elsewhere
@@ -1334,6 +1349,13 @@ class ContinuousBatcher:
             # during the flush may itself free the pages this hunt is after
             self._flush_pending("evict")
             alloc = self.engine.allocator
+
+            def holds(l):
+                # pages by kind: the kind that ran short picks the victim
+                if kind:
+                    return alloc.slot_pages_resident(l.slot, kind)
+                return self.engine.slot_length(l.slot)
+
             with self._lock:
                 candidates = [
                     l for l in self._live.values()
@@ -1342,10 +1364,7 @@ class ContinuousBatcher:
                 if not candidates:
                     return "empty"
                 victim = min(
-                    candidates,
-                    key=lambda l: (
-                        l.req.priority, -self.engine.slot_length(l.slot)
-                    ),
+                    candidates, key=lambda l: (l.req.priority, -holds(l)),
                 )
             if (
                 requester_priority is not None
@@ -1708,7 +1727,7 @@ class ContinuousBatcher:
                 self.engine.jump_step(forced, counts)
                 self._gap_mark = time.monotonic()
         except PoolExhausted as e:
-            self._evict_longest(e.replica)  # retry next tick
+            self._evict_longest(e.replica, kind=e.kind)  # retry next tick
             return True
         self._dispatched = ("jump", k)
         with self.phases.phase("batcher.emit"):
@@ -1862,7 +1881,7 @@ class ContinuousBatcher:
                     tokens = self.engine.step_masked(mask)
                     self._gap_mark = time.monotonic()
             except PoolExhausted as e:
-                self._evict_longest(e.replica)
+                self._evict_longest(e.replica, kind=e.kind)
                 return
             self._dispatched = 1
             with phase("batcher.emit"):
@@ -1923,7 +1942,7 @@ class ContinuousBatcher:
                         )
                     self._gap_mark = time.monotonic()
             except PoolExhausted as e:
-                self._evict_longest(e.replica)  # retry next tick
+                self._evict_longest(e.replica, kind=e.kind)  # retry next tick
                 return
             self._dispatched = ("spec", proposer, n)
             with phase("batcher.emit"):
@@ -1967,7 +1986,7 @@ class ContinuousBatcher:
         except PoolExhausted as e:
             # retire the longest request and retry on the next tick; the
             # failed ensure() left all engine state untouched
-            self._evict_longest(e.replica)
+            self._evict_longest(e.replica, kind=e.kind)
             return
         self._dispatched = self._dispatch_key(n)
         with phase("batcher.emit"):

@@ -9,8 +9,30 @@ built from presets, GGUF metadata, or HF config dicts.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from typing import Any, Dict, Optional
+
+
+@dataclass(frozen=True)
+class RopeParams:
+    """The rotary table of one kind of layer: plain where ``factor`` <= 1,
+    else YaRN (model.yarn_inv_freq has the blend). ``attention_factor``
+    multiplies cos and sin, so scores carry its square (the grouped-query
+    block's convention; the latent block scales its softmax instead)."""
+
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_context: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+# the kinds of attention layer a grouped-query stack can mix
+# (ModelConfig.layer_types): ``window`` sees itself and the
+# sliding_window - 1 rows before it, ``full`` every row
+LAYER_KINDS = ("full", "window")
 
 
 @dataclass(frozen=True)
@@ -79,17 +101,31 @@ class ModelConfig:
     hc_eps: float = 1e-6
     hc_res_clamp: tuple = (-30.0, 30.0)
     # YaRN scaling of the rotary embedding (rope_factor > 1; the latent
-    # block's rotary part only): frequencies blended between 1/theta^(2i/d)
+    # block's rotary part, or every layer of a grouped-query stack that
+    # rope_by_kind does not name): frequencies blended between 1/theta^(2i/d)
     # and that over rope_factor, by a linear ramp between the dimensions
     # that turn rope_beta_fast and rope_beta_slow times in
     # rope_original_context positions. The softmax scale is multiplied by
     # (0.1 rope_mscale_all_dim ln(rope_factor) + 1)^2; cos and sin stay
-    # unscaled (the published mscale equals mscale_all_dim).
+    # unscaled (the published mscale equals mscale_all_dim). That is the
+    # latent block; the grouped-query block multiplies cos and sin by
+    # 0.1 ln(rope_factor) + 1 instead (rope_of).
     rope_factor: float = 1.0
     rope_original_context: int = 0
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # A kind per layer, ``window`` or ``full`` (LAYER_KINDS), for a stack
+    # that mixes the two; () = every layer alike, windowed iff
+    # sliding_window. The layer scan's body is one period of the pattern
+    # (model.scan_segments; ``period``).
+    # Each kind has its own pages and residency rule (engine/paged.py
+    # KindPageAllocator) and its own rotary table: ``rope_by_kind`` pairs a
+    # kind with its RopeParams; a kind it does not name takes the fields
+    # above. The grouped-query block only, and only where the stack mixes
+    # kinds: a stack of one kind has the one table of the fields above.
+    layer_types: tuple = ()
+    rope_by_kind: tuple = ()
     # serving replicas per managed model (aios_tpu/serving/): N independent
     # engine+batcher replicas behind one cache-aware router. 1 = the
     # single-engine layout; AIOS_TPU_REPLICAS overrides at load time.
@@ -165,6 +201,14 @@ class ModelConfig:
     seq_prefill_min: int = 0
 
     def __post_init__(self) -> None:
+        # plain data (a list; a kind's table as pairs of RopeParams' fields)
+        # is taken too: the benchmark's files import nothing of the program
+        if not isinstance(self.layer_types, tuple):
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "rope_by_kind", tuple(
+            (kind, rope if isinstance(rope, RopeParams) else RopeParams(**dict(rope)))
+            for kind, rope in self.rope_by_kind
+        ))
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"{self.name}: unknown moe_scoring {self.moe_scoring!r}")
         if self.experts_held and not (
@@ -182,19 +226,20 @@ class ModelConfig:
                 "built for the latent-attention block only "
                 "(engine/latent.py); the grouped-query block has neither"
             )
-        if (self.hc or self.rope_factor > 1.0) and not self.mla:
+        if self.hc and not self.mla:
             raise ValueError(
                 f"{self.name}: a residual of several mixed streams "
-                "(hc_mult > 1) and YaRN rotary scaling are built for the "
-                "latent-attention block only (engine/latent.py, "
-                "engine/residual.py); the grouped-query block writes "
-                "x + F(norm(x)) and has one rope_theta"
+                "(hc_mult > 1) is built for the latent-attention block only "
+                "(engine/latent.py, engine/residual.py); the grouped-query "
+                "block writes x + F(norm(x))"
             )
-        if self.rope_factor > 1.0 and self.rope_original_context <= 0:
-            raise ValueError(
-                f"{self.name}: YaRN (rope_factor {self.rope_factor}) needs "
-                "rope_original_context"
-            )
+        for rope in (self.rope_of(None), *(r for _, r in self.rope_by_kind)):
+            if rope.factor > 1.0 and rope.original_context <= 0:
+                raise ValueError(
+                    f"{self.name}: YaRN (rope_factor {rope.factor}) needs "
+                    "rope_original_context"
+                )
+        self._check_layer_types()
         if self.mla and not (
             self.q_lora_rank and self.qk_nope_head_dim
             and self.qk_rope_head_dim and self.v_head_dim
@@ -203,6 +248,81 @@ class ModelConfig:
                 f"{self.name}: latent attention needs q_lora_rank and the "
                 "three head dims beside kv_lora_rank"
             )
+
+    def _check_layer_types(self) -> None:
+        types = self.layer_types
+        if not types and not self.rope_by_kind:
+            return
+        unknown = (set(types) | {k for k, _ in self.rope_by_kind}) - set(LAYER_KINDS)
+        if unknown or (types and len(types) != self.num_layers):
+            raise ValueError(
+                f"{self.name}: layer_types names one of {LAYER_KINDS} for "
+                f"each of the {self.num_layers} layers, and rope_by_kind "
+                f"pairs those kinds with RopeParams; got {len(types)} "
+                f"entries and the unknown kinds {sorted(unknown)}"
+            )
+        if self.mla:
+            raise ValueError(
+                f"{self.name}: layers of two kinds are built for the "
+                "grouped-query block only (the latent pool has one table "
+                "a slot)"
+            )
+        if self.rope_by_kind and not self.kinds:
+            raise ValueError(
+                f"{self.name}: rope_by_kind is for a stack that mixes window "
+                "and full layers; a stack of one kind has one rotary table, "
+                "that of rope_theta and the rope_* fields"
+            )
+        if ("window" in types) != (self.sliding_window is not None):
+            raise ValueError(
+                f"{self.name}: sliding_window is the window of the window "
+                "kind: set it iff layer_types has a window layer"
+            )
+
+    @property
+    def kinds(self) -> bool:
+        """True when the stack mixes window and full attention layers."""
+        return len(set(self.layer_types)) > 1
+
+    @property
+    def period(self) -> int:
+        """Layers in one repeat of ``layer_types`` (1 for a stack of one
+        kind): the least divisor of the depth the pattern repeats with, the
+        depth itself where it never does (the scan's body is then the stack)."""
+        types = self.layer_types
+        if not self.kinds:
+            return 1
+        return next(
+            p for p in range(1, len(types) + 1)
+            if len(types) % p == 0
+            and all(types[i] == types[i % p] for i in range(len(types)))
+        )
+
+    @property
+    def period_kinds(self) -> tuple:
+        """The kind of each layer of one period; () for a stack of one."""
+        return tuple(self.layer_types[: self.period]) if self.kinds else ()
+
+    def window_of(self, kind: Optional[str]) -> Optional[int]:
+        """The window of a layer of ``kind`` (None: a stack of one kind)."""
+        return None if kind == "full" else self.sliding_window
+
+    def rope_of(self, kind: Optional[str]) -> RopeParams:
+        """The rotary parameters of a layer of ``kind``: ``rope_by_kind``'s
+        where it names the kind, else the model's own fields."""
+        for k, rope in self.rope_by_kind:
+            if k == kind:
+                return rope
+        factor = float(self.rope_factor)
+        scale = 1.0
+        if factor > 1.0 and not self.mla:
+            scale = 0.1 * math.log(factor) + 1.0
+        return RopeParams(
+            theta=float(self.rope_theta), factor=factor,
+            original_context=int(self.rope_original_context),
+            beta_fast=float(self.rope_beta_fast),
+            beta_slow=float(self.rope_beta_slow), attention_factor=scale,
+        )
 
     @property
     def moe(self) -> bool:

@@ -283,6 +283,36 @@ def refuse_for_latent_pool(cfg, **asked) -> None:
         )
 
 
+def window_rows_a_slot(cfg, page_size: int, max_context: int,
+                       chunk: int = 512) -> int:
+    """Rows ONE slot's window kind holds at most (a stack of window and full
+    layers), in whole pages: the window, one admission chunk in flight (at
+    least two pages: a decode dispatch grows a slot across a page boundary)
+    and the page the window's first row straddles. ``chunk`` defaults to
+    ``TPUEngine.prefill_chunk_default``."""
+    chunk = min(chunk or 1, max_context)
+    blocks = -(-cfg.sliding_window // page_size) + max(-(-chunk // page_size), 2)
+    return min(blocks * page_size, max_context)
+
+
+def refuse_for_two_kinds(cfg, **asked) -> None:
+    """Raise, naming the model and the feature, for a serving feature that
+    cannot take the pool of a stack of window and full attention layers yet
+    (pages, tables and residency by kind: engine/paged.py header). No quiet
+    fallback: ``LoadModel`` fails with this error, as
+    ``refuse_for_latent_pool`` does for the latent pool."""
+    if not cfg.kinds:
+        return
+    wanted = [what for what, on in asked.items() if on]
+    if wanted:
+        raise ValueError(
+            f"{cfg.name}: window and full attention layers in one stack "
+            f"serve from the bf16 page pool by kind only; this load asks "
+            f"for {' and '.join(w.replace('_', ' ') for w in wanted)}, "
+            f"which cannot take pages of two kinds yet"
+        )
+
+
 # Device-resident decode state, threaded through the jitted cores as one
 # donated pytree: {k, v, lengths, last_tokens, temps, top_ps, key}
 DecodeState = Dict[str, jnp.ndarray]
@@ -416,6 +446,16 @@ class TPUEngine:
             ),
             a_context_sharded_cache=bool(seq_sharded_cache),
             a_draft_model=draft is not None,
+        )
+        refuse_for_two_kinds(
+            cfg,
+            the_dense_slot_cache=paged_pool_rows is None,
+            an_int8_KV_pool=self.quant_cache,
+            a_sharding_plan_or_the_dp_replicated_pool_twin=(
+                shardings is not None
+            ),
+            a_context_sharded_cache=bool(seq_sharded_cache),
+            a_draft_model_and_its_speculation=draft is not None,
         )
         # Pallas kernels are per-device programs; under a sharding plan the
         # global-array paths must stay pure XLA (GSPMD partitions those) —
@@ -595,6 +635,13 @@ class TPUEngine:
         self._pool_impl = None
         self._paged_scatter = None
         self.pool_replicas = 1
+        # a stack of window and full layers: where each kind's pages lie
+        # (paged.PoolLayout, a constant of the paged graphs), the window
+        # kind's side of prefix sharing, and the hits it cut short
+        self._layout = None
+        self._whole_prompt_rows: Optional[int] = None
+        self.window_prefix: Optional[paged.WindowPrefixPages] = None
+        self.prefix_hits_refused_window = 0
         if self.paged:
             # sp in the mesh: the pool (like any non-seq-sharded cache)
             # REPLICATES over the sp axis — its shard_map specs name only
@@ -620,16 +667,41 @@ class TPUEngine:
                 1, -(-int(paged_pool_rows) // (page_size * R))
             )
             num_pages = R * local_pages
-            self.allocator = paged.PageAllocator(
-                num_pages, page_size, num_slots, max_blocks, replicas=R
-            )
+            if cfg.kinds:
+                # pages by kind (paged.py's header): the full kind as any
+                # model's; the window kind what the same count of contexts
+                # (paged_pool_rows / max_context) can hold of a window and
+                # an admission chunk in flight
+                slot_rows = window_rows_a_slot(
+                    cfg, page_size, self.max_context,
+                    self.prefill_chunk_default,
+                )
+                window_pool_rows = -(
+                    -int(paged_pool_rows) // self.max_context
+                ) * slot_rows
+                self.allocator = paged.KindPageAllocator(
+                    local_pages, 1 + window_pool_rows // page_size,
+                    page_size, num_slots, max_blocks, cfg.period_kinds,
+                )
+                self._layout = self.allocator.layout
+                # a whole-prompt prefill leaves every row in the window
+                # kind until the first decode trims: it serves prompts
+                # within ONE slot's share, longer ones admit in chunks
+                self._whole_prompt_rows = min(
+                    slot_rows,
+                    self.allocator.window.capacity_blocks() * page_size,
+                )
+                pool_shape = (cfg.num_layers // cfg.period, self._layout.pages)
+            else:
+                self.allocator = paged.PageAllocator(
+                    num_pages, page_size, num_slots, max_blocks, replicas=R
+                )
+                pool_shape = (cfg.num_layers, num_pages)
             # THE stored layout (paged.py's header): a row's kv heads
             # merged on the last axis; for latent attention the latents
             # and the padded rotary parts (ModelConfig.kv_row_dims)
             k, v = (
-                jnp.zeros(
-                    (cfg.num_layers, num_pages, page_size, width), cache_dtype
-                )
+                jnp.zeros((*pool_shape, page_size, width), cache_dtype)
                 for width in cfg.kv_row_dims
             )
             if R > 1:
@@ -681,9 +753,19 @@ class TPUEngine:
                     paged.RadixPrefixIndex if prefix_radix
                     else paged.PrefixIndex
                 )
-                self.prefix_index = index_cls(
-                    self.allocator, max_pages=num_pages
-                )
+                if cfg.kinds:
+                    # the index holds the full kind's pages; the window
+                    # kind's ride beside it (paged.py's header)
+                    self.prefix_index = index_cls(
+                        self.allocator.full, max_pages=local_pages
+                    )
+                    self.window_prefix = paged.WindowPrefixPages(
+                        self.allocator.window
+                    )
+                else:
+                    self.prefix_index = index_cls(
+                        self.allocator, max_pages=num_pages
+                    )
         else:
             prefix_host_bytes = 0
             k, v = model.init_kv_cache(
@@ -727,6 +809,9 @@ class TPUEngine:
         self.kv_compress_armed = False
         self._sink_rows = 0
         refuse_for_latent_pool(
+            cfg, window_and_sink_KV_compression=self.kv_compress_after > 0
+        )
+        refuse_for_two_kinds(
             cfg, window_and_sink_KV_compression=self.kv_compress_after > 0
         )
         if self.kv_compress_after > 0:
@@ -775,6 +860,9 @@ class TPUEngine:
             getattr(cfg, "seq_prefill_min", 0),
         )
         refuse_for_latent_pool(
+            cfg, sequence_sharded_prefill=self.seq_prefill_min > 0
+        )
+        refuse_for_two_kinds(
             cfg, sequence_sharded_prefill=self.seq_prefill_min > 0
         )
         self._seq_attn = None
@@ -970,6 +1058,12 @@ class TPUEngine:
             prefix_host_bytes = getattr(cfg, "prefix_host_bytes", 0)
         refuse_for_latent_pool(
             cfg, the_host_spill_tier=int(prefix_host_bytes or 0) > 0
+        )
+        refuse_for_two_kinds(
+            cfg,
+            the_host_spill_tier_and_its_KVX_entries=(
+                int(prefix_host_bytes or 0) > 0
+            ),
         )
         self.host_store: Optional[paged.HostPageStore] = None
         self.host_restore_min_pages = max(int(host_restore_min_pages or 1), 1)
@@ -1306,6 +1400,7 @@ class TPUEngine:
                 pool_impl=self._pool_impl,
                 win_starts=win_starts,
                 sink_rows=self._sink_rows,
+                layout=self._layout,
             )
             if self.quant_cache:
                 logits, k, v, (k_s, v_s), *picks = out
@@ -1422,6 +1517,7 @@ class TPUEngine:
                 tables, cache_scales=scales, active=st["active"],
                 moe_dense=self._moe_dense, qmm=self._qmm_gspmd,
                 win_starts=win_starts, sink_rows=self._sink_rows,
+                layout=self._layout,
             )
         else:
             out = model.verify_step(
@@ -1801,9 +1897,8 @@ class TPUEngine:
                 *pools, *rows, table_row, self.allocator.replica_of(slot)
             )
         else:
-            k, v, *scales = (
-                ops.write_rows(p, None, r, table_row)
-                for p, r in zip(pools, rows)
+            k, v, *scales = model.write_prompt_rows(
+                pools, rows, table_row, self._layout
             )
         key, sub = jax.random.split(state["key"])
         last = logits[0, 0 if one_row else true_len - 1][None, :]  # [1, V]
@@ -1893,7 +1988,7 @@ class TPUEngine:
                 params, self.cfg, tokens, start, state["k"], state["v"],
                 table_row, cache_scales=scales, qmm=self._qmm_gspmd,
                 win_start=win_start, sink_rows=self._sink_rows,
-                moe_dense=self._moe_dense,
+                moe_dense=self._moe_dense, layout=self._layout,
             )
         else:
             scales = (state["k_s"], state["v_s"]) if self.quant_cache else None
@@ -2811,7 +2906,23 @@ class TPUEngine:
                 entries = []  # below the floor: recompute beats device_put
         if not pages and not entries:
             return 0, hashes
-        if pages:
+        if pages and self.window_prefix is not None:
+            # two kinds: served where the window kind's rows are held too
+            # (paged.py's header), cut to the longest hit that is
+            served, held = self.window_prefix.servable(
+                hashes[: len(pages)], self.cfg.sliding_window
+            )
+            if served < len(pages):
+                self.prefix_hits_refused_window += 1
+            pages = pages[:served]
+            if not pages:
+                return 0, hashes
+            self.allocator.full.map_shared(slot, pages)
+            self.allocator.window.map_shared(
+                slot, held, first=served - len(held)
+            )
+            self.prefix_rows_reused += served * P
+        elif pages:
             # map the HBM hits FIRST: their index references alone are
             # reclaimable (refcount 1), so taking the slot reference
             # before the restore's alloc_pages keeps a pressure-reclaim
@@ -2838,6 +2949,17 @@ class TPUEngine:
         prompt blocks to the index so the NEXT prompt with this prefix
         skips their prefill. Caller holds the engine lock."""
         if self.prefix_index is None or not hashes:
+            return
+        if self.window_prefix is not None:
+            # two kinds: every block's full-kind page, and the window-kind
+            # pages this slot still holds (its last window: paged.py header)
+            tables = self.allocator.tables[slot]
+            n, MB = len(hashes), self.allocator.max_blocks
+            self.prefix_index.put(hashes, [int(p) for p in tables[:n]])
+            held = min(int(self.allocator.window._trimmed[slot]), n)
+            self.window_prefix.put(
+                hashes[held:], [int(p) for p in tables[MB + held : MB + n]]
+            )
             return
         if int(self.allocator._trimmed[slot]):
             # sliding-window trimming released leading blocks during this
@@ -2925,6 +3047,7 @@ class TPUEngine:
                 f"{self.cfg.name}: latent (MLA) pages have no KVX entry "
                 "kind yet"
             )
+        refuse_for_two_kinds(self.cfg, KVX_entries=True)
         with self._lock:
             snap = self.prefix_index.snapshot()
             chain = []
@@ -3006,10 +3129,16 @@ class TPUEngine:
         if self.prefix_index is not None:
             with self._lock:
                 matched, hashes = self._match_prefix(slot, token_ids)
-        if matched:
+        if matched or (
+            self._whole_prompt_rows is not None
+            and true_len > self._whole_prompt_rows
+            and self._prefix_chunk
+        ):
             # tail-only admission through the chunked path, which attends
             # over the mapped prefix; release on failure so the shared
-            # pages don't leak into the batcher's retry
+            # pages don't leak into the batcher's retry. (Pages by kind:
+            # also a prompt past one slot's share of the window kind, which
+            # chunks trim as they go.)
             pc = ChunkedPrefill(
                 self, slot, token_ids, temperature, top_p,
                 self._prefix_chunk, start_pos=matched, hashes=hashes,
@@ -3637,8 +3766,14 @@ class TPUEngine:
             out["jump_dispatches"] = self.jump_dispatches
             out["jump_tokens"] = self.jump_tokens
         if self.allocator is not None:
+            # the sum over kinds where the pool has two
             out["kv_pages_in_use"] = self.allocator.pages_in_use()
             out["kv_pages_free"] = self.allocator.free_pages
+            if self._layout is not None:
+                out.update(self.allocator.stats())
+                out["prefix_hits_refused_window"] = (
+                    self.prefix_hits_refused_window
+                )
             # one cache row of one layer, as STORED (both pool arrays)
             out["kv_row_bytes"] = sum(self.cfg.kv_row_dims) * (
                 self.state["k"].dtype.itemsize if self.state else 0
@@ -3774,6 +3909,8 @@ class TPUEngine:
                 bucket // 2 + 1
             ) > self.allocator.capacity_blocks():
                 continue  # pool can't back prompts of this bucket anyway
+            if self._whole_prompt_rows and bucket // 2 + 1 > self._whole_prompt_rows:
+                continue  # pages by kind: such a prompt admits in chunks
             self.compile_prefill_fn(bucket)
             if (
                 self._seq_attn is not None

@@ -71,22 +71,9 @@ def sm_scale(cfg: ModelConfig) -> float:
 
 
 def yarn_inv_freq(cfg: ModelConfig) -> np.ndarray:
-    """The rotary frequencies [rope / 2] under YaRN: dimension i keeps
-    1 / theta^(2i/d) where it turns more than ``rope_beta_fast`` times in
-    ``rope_original_context`` positions, takes that over ``rope_factor``
-    where it turns fewer than ``rope_beta_slow`` times, and a linear blend
-    between the two dimensions those counts correspond to."""
-    d, base = cfg.qk_rope_head_dim, float(cfg.rope_theta)
-    plain = 1.0 / base ** (np.arange(0, d, 2, dtype=np.float64) / d)
-
-    def dim_of(turns: float) -> float:
-        return d * math.log(cfg.rope_original_context / (turns * 2 * math.pi)) / (
-            2 * math.log(base))
-
-    low = max(math.floor(dim_of(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(dim_of(cfg.rope_beta_slow)), d - 1)
-    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
-    return (plain / cfg.rope_factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+    """The rotary part's frequencies [rope / 2] under YaRN
+    (``model.yarn_inv_freq`` has the blend)."""
+    return model.yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_of(None))
 
 
 def rope_tables(positions, cfg: ModelConfig):

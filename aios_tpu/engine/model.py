@@ -31,6 +31,7 @@ F=intermediate, L=layers, V=vocab, D=head_dim):
 from __future__ import annotations
 
 import contextlib
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -39,7 +40,7 @@ import numpy as np
 
 from .. import ops
 from . import moe as moe_mod
-from .config import ModelConfig
+from .config import ModelConfig, RopeParams
 
 Params = Dict[str, jnp.ndarray]
 
@@ -254,6 +255,55 @@ def rope_tables(
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def yarn_inv_freq(dim: int, rope: RopeParams) -> np.ndarray:
+    """The rotary frequencies [dim / 2] under YaRN: dimension i keeps
+    1 / theta^(2i/dim) where it turns more than ``beta_fast`` times in
+    ``original_context`` positions, takes that over ``factor`` where it
+    turns fewer than ``beta_slow`` times, and a linear blend between the
+    two dimensions those counts correspond to. The one blend: the latent
+    block's rotary part and a grouped-query layer both come here."""
+    d, base = dim, float(rope.theta)
+    plain = 1.0 / base ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def dim_of(turns: float) -> float:
+        return d * math.log(rope.original_context / (turns * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(dim_of(rope.beta_fast)), 0)
+    high = min(math.ceil(dim_of(rope.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (plain / rope.factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_tables_of(
+    positions: jnp.ndarray, head_dim: int, rope: RopeParams
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """cos/sin tables of one kind of layer: ``rope_tables`` where there is
+    no scaling (the same trace), else YaRN's frequencies with cos and sin
+    both times ``attention_factor``."""
+    if rope.factor <= 1.0 and rope.attention_factor == 1.0:
+        return rope_tables(positions, head_dim, rope.theta)
+    angles = positions.astype(jnp.float32)[..., None] * yarn_inv_freq(head_dim, rope)
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    scale = jnp.float32(rope.attention_factor)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def kind_of(lp) -> Optional[str]:
+    """The kind of the layer whose tree this is, where its stack mixes two
+    (scan_segments writes it beside the leaves); None in a stack of one."""
+    return lp.get("layer_kind")
+
+
+def rope_by_kind(positions: jnp.ndarray, cfg: ModelConfig):
+    """{kind: (cos, sin)} at ``positions`` for every kind of layer the stack
+    has; a stack of one kind has the one key None."""
+    return {
+        kind: rope_tables_of(positions, cfg.head_dim, cfg.rope_of(kind))
+        for kind in (set(cfg.period_kinds) or {None})
+    }
+
+
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
     """Rotate q or k. x: [B, T, H, D]; cos/sin: [B, T, D]."""
     half = x.shape[-1] // 2
@@ -296,6 +346,7 @@ def blockwise_cache_attention(
     block: int = 512,
     live_from: Optional[jnp.ndarray] = None,  # scalar: live window start
     sink: int = 0,  # static sink rows (window+sink KV compression)
+    col0: Optional[jnp.ndarray] = None,  # scalar: position of k[:, 0]
 ) -> jnp.ndarray:
     """Chunk-vs-cache attention via an online softmax over KV blocks.
 
@@ -317,6 +368,10 @@ def blockwise_cache_attention(
     kb = k[0].astype(jnp.float32).reshape(nb, block, KH, D)
     vb = v[0].astype(jnp.float32).reshape(nb, block, KH, D)
     colsb = jnp.arange(C).reshape(nb, block)
+    if col0 is not None:
+        # k and v are the rows from position col0 on (a window layer's
+        # chunk gathers from its window's first page, not from row 0)
+        colsb = colsb + col0
 
     def fold(carry, xs):
         m, l, acc = carry
@@ -491,7 +546,10 @@ def ffn(
     which it computes fewer rows take the exact grouped path
     (moe.grouped_serves: a prefill chunk or bucket; on both, ``lp``'s expert
     leaves may be the whole stacks, read in place at
-    ``lp["expert_layer"]``), the training forward (``allow_dispatch``) the
+    ``lp["expert_layer"]``; a layer scan that hands the stacks whole at a
+    lower count, _scan_periods, gets the grouped path there too: the dense
+    product cannot read them in place), the training forward
+    (``allow_dispatch``) the
     capacity dispatch at large token counts, and everything else — the
     small prefill buckets, and every graph of an engine under a sharding
     plan (``moe_dense``) — the exact dense-over-held path.
@@ -516,7 +574,9 @@ def ffn(
             out, aux = moe_mod.moe_ffn_dispatch(h, lp, cfg)
         elif live is not None and moe_mod.visit_serves(cfg, moe_dense):
             out, aux, stats = moe_mod.moe_ffn_visit(h, lp, cfg, live)
-        elif moe_mod.grouped_serves(n_tok, cfg, moe_dense, allow_dispatch):
+        elif "expert_layer" in lp or moe_mod.grouped_serves(
+            n_tok, cfg, moe_dense, allow_dispatch
+        ):
             out, aux, stats = moe_mod.moe_ffn_grouped(h, lp, cfg)
         else:
             out, aux, stats = moe_mod.moe_ffn_dense(
@@ -671,22 +731,31 @@ def _forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None, kernels=Non
     B, T = tokens.shape
     x = params["embed"][tokens]
     positions = jnp.broadcast_to(jnp.arange(T), (B, T))
-    cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    ropes = rope_by_kind(positions, cfg)
 
     # Attention implementation ladder: explicit attn_fn (ring attention for
     # sequence parallelism) > Pallas flash kernel (TPU, block-aligned T) >
     # naive masked GQA. Flash is what keeps 8k-token prefills inside HBM —
-    # it never materializes the [T, T] score matrix.
-    if attn_fn is None and _use_kernels(kernels) and T >= 128 and T % 128 == 0:
-        def attention(q, k, v, mask):
-            return ops.flash_attention(
-                q, k, v, causal=True, window=cfg.sliding_window
-            )
-    else:
-        attention = attn_fn or gqa_attention
-    mask = causal_mask(T, cfg.sliding_window)
+    # it never materializes the [T, T] score matrix. A stack of two kinds
+    # has the ladder once a kind: each with its window.
+    def ladder(window):
+        if attn_fn is None and _use_kernels(kernels) and T >= 128 and T % 128 == 0:
+            def attention(q, k, v, mask):
+                return ops.flash_attention(q, k, v, causal=True, window=window)
+        else:
+            attention = attn_fn or gqa_attention
+        return attention, causal_mask(T, window)
+
+    views = {kind: (*ropes[kind], *ladder(cfg.window_of(kind))) for kind in ropes}
 
     if with_aux:
+        if cfg.kinds:
+            raise ValueError(
+                f"{cfg.name}: the training forward (with_aux) scans layers "
+                "of one kind; a stack of window and full layers serves only"
+            )
+        cos, sin, attention, mask = views[None]
+
         def block(x, lp):
             return apply_block(x, lp, cfg, cos, sin, mask, attention, True,
                                qmm=qmm, moe_dense=moe_dense)
@@ -697,13 +766,14 @@ def _forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None, kernels=Non
 
     def block(carry, layer):
         x, stats = carry
+        cos, sin, attention, mask = views[kind_of(layer[0])]
         x, k, v = _attend(x, layer[0], cfg, cos, sin, mask, attention, qmm)
         x, stats = _add_mlp(x, stats, layer[0], cfg, moe_dense, qmm)
         return (x, stats), (k, v)
 
     (x, stats), (ks, vs) = scan_segments(
         block, (x, zero_stats(cfg)), layer_segments(params),
-        moe_mod.grouped_serves(B * T, cfg, moe_dense),
+        moe_mod.grouped_serves(B * T, cfg, moe_dense), cfg.period_kinds,
     )
     if logit_row is not None:
         x = jax.lax.dynamic_slice_in_dim(x, logit_row, 1, axis=1)
@@ -743,7 +813,7 @@ def prefill_chunk(
     quant_cache = cache_scales is not None
     x = params["embed"][tokens]  # [1, Tc, E]
     positions = start + jnp.arange(Tc)[None, :]  # [1, Tc]
-    cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_tables_of(positions, cfg.head_dim, cfg.rope_of(None))
 
     kv_tile = min(512, C)  # NB: local `block` below would shadow this
     if C % kv_tile == 0:
@@ -887,7 +957,7 @@ def decode_step(
         # keeps the mask/kernel contract "row `length` was just written"
         read_lengths = jnp.where(active, lengths, 0)
     x = params["embed"][tokens][:, None, :]  # [B, 1, E]
-    cos, sin = rope_tables(lengths[:, None], cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_tables_of(lengths[:, None], cfg.head_dim, cfg.rope_of(None))
 
     batch_idx = jnp.arange(B)
     if use_kernel or use_int8_kernel or attn_impl is not None:
@@ -977,7 +1047,8 @@ def _with_experts(lp, whole, l):
     return {**lp, **whole, "expert_layer": l} if whole else lp
 
 
-def scan_segments(block, carry, segments, experts_whole: bool = False):
+def scan_segments(block, carry, segments, experts_whole: bool = False,
+                  kinds: Tuple[str, ...] = ()):
     """Run ``block(carry, (layer_params, l))`` over every layer of every
     segment in turn, one ``lax.scan`` a segment, the carry (the residual,
     the page pools, the expert counters) going through all of them and the
@@ -990,7 +1061,17 @@ def scan_segments(block, carry, segments, experts_whole: bool = False):
     them whole, ``[L, X, in, out]``, with ``expert_layer``, the layer's
     index into them, and the loop or kernel reads ``w[l, e]`` where it
     lies. A scanned slice of them would be its operand, and so a copy of
-    the layer's experts each layer."""
+    the layer's experts each layer.
+
+    ``kinds`` (ModelConfig.period_kinds: a stack that mixes window and full
+    attention layers) makes the scan's body one PERIOD, its layers unrolled
+    in it: the window, the rotary table and the pages a layer reads are
+    constants of its place in the period, so ONE scan serves the whole
+    stack where a scan a run of like layers would be ten. The block finds
+    its layer's kind and place in the tree (``layer_kind``,
+    ``layer_in_period``; ``kind_of``)."""
+    if kinds:
+        return _scan_periods(block, carry, segments, kinds)
     first, emitted = 0, []
     for seg in segments:
         n = jax.tree.leaves(seg)[0].shape[0]
@@ -1010,6 +1091,50 @@ def scan_segments(block, carry, segments, experts_whole: bool = False):
     if len(emitted) == 1:
         return carry, emitted[0]
     return carry, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *emitted)
+
+
+def _scan_periods(block, carry, segments, kinds):
+    """``scan_segments`` for a stack of two kinds: one scan over the periods
+    of its one segment, the period's layers unrolled in the body.
+
+    The expert stacks stay whole at EVERY token count, so model.ffn reads
+    them in place (the visit path in a decode step, the grouped path
+    elsewhere, below moe.grouped_pays too): the body slices the stacked
+    leaves itself, and there the dense product's layout change of a sliced
+    expert stack is hoisted by the TPU compiler to a copy of the WHOLE stack
+    (4.9 GB at 64 experts of 2304 x 1792 over 20 layers:
+    tests/test_mosaic_aot.py -k two_kinds). Below ``grouped_pays`` the
+    grouped path computes up to a tile an expert where the dense path
+    computes a few rows, and reads the same bytes."""
+    (seg,) = segments  # a model of two kinds has no leading dense layers
+    period = len(kinds)
+    n = jax.tree.leaves(seg)[0].shape[0]
+    scanned, whole = _experts_apart(seg, True)
+
+    def period_block(carry, l0):
+        ys = []
+        for i, kind in enumerate(kinds):
+            # layer l0 + i of the stacked leaves, sliced where the block
+            # runs: scanning them as [L / period, period, ..] operands made
+            # the compiler copy the whole stack into another layout (4.9 GB
+            # of expert weights at the benchmark's widths)
+            lp = jax.tree.map(
+                lambda a, i=i: jax.lax.dynamic_index_in_dim(
+                    a, l0 + i, 0, keepdims=False
+                ), scanned,
+            )
+            lp = {**_with_experts(lp, whole, l0 + i),
+                  "layer_kind": kind, "layer_in_period": i}
+            carry, y = block(carry, (lp, l0 + i))
+            ys.append(y)
+        if ys[0] is None:
+            return carry, None
+        return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+    carry, ys = jax.lax.scan(period_block, carry, jnp.arange(0, n, period))
+    if ys is None:
+        return carry, None
+    return carry, jax.tree.map(lambda a: a.reshape(n, *a.shape[2:]), ys)
 
 
 def _scan_layers_over_cache(block, x, params, k_cache, v_cache, cache_scales,
@@ -1050,7 +1175,7 @@ def _scan_layers_over_pool(block, x, params, k_pool, v_pool, cache_scales,
     scales-or-None, stats)."""
     carry = (x, k_pool, v_pool, tuple(cache_scales or ()), zero_stats(cfg))
     (x, k_pool, v_pool, scales, stats), _ = scan_segments(
-        block, carry, layer_segments(params), experts_whole
+        block, carry, layer_segments(params), experts_whole, cfg.period_kinds
     )
     return x, k_pool, v_pool, scales or None, stats
 
@@ -1088,6 +1213,7 @@ def prefill_chunk_paged(
     win_start: Optional[jnp.ndarray] = None,  # scalar: live window start
     sink_rows: int = 0,  # static sink rows (window+sink KV compression)
     moe_dense: bool = False,
+    layout=None,  # paged.PoolLayout: the pool of a stack of two kinds
 ):
     """One chunk of an incremental prefill against the PAGED cache.
 
@@ -1107,6 +1233,12 @@ def prefill_chunk_paged(
     ``cache_scales`` marks an int8 pool (rows quantize on write, the
     gathered view dequantizes). Returns (logits [1, Tc, V] fp32, k_pool',
     v_pool'[, scales'][, stats]).
+
+    ``layout`` (a stack of window and full layers; ``table_row`` is then the
+    slot's two tables side by side): a layer writes and reads its kind's
+    pages of its period (engine/paged.py header), a full layer every page
+    of the slot, a window layer the pages from its window's first block to
+    the chunk's end alone.
     """
     if cfg.mla:
         from . import latent
@@ -1116,18 +1248,22 @@ def prefill_chunk_paged(
             qmm=qmm, moe_dense=moe_dense,
         )
     B, Tc = tokens.shape
-    MB = table_row.shape[0]
     P = k_pool.shape[2]
-    C_log = MB * P
     quant_pool = cache_scales is not None
     x = params["embed"][tokens]  # [1, Tc, E]
     positions = start + jnp.arange(Tc)[None, :]  # [1, Tc]
-    cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    if layout is not None:
+        return _prefill_chunk_kinds(
+            params, cfg, x, positions, start, k_pool, v_pool, table_row,
+            qmm, moe_dense, layout,
+        )
+    MB = table_row.shape[0]
+    C_log = MB * P
+    cos, sin = rope_tables_of(positions, cfg.head_dim, cfg.rope_of(None))
 
     pages, off = chunk_pages(table_row, start, Tc, P)
 
-    t = min(512, C_log)
-    kv_tile = t if C_log % t == 0 else P
+    kv_tile = _kv_tile(C_log, P)
 
     def block(carry, layer):
         x, k_pool, v_pool, scales, stats = carry
@@ -1177,6 +1313,100 @@ def prefill_chunk_paged(
     return (logits, k_pool, v_pool, *stats)
 
 
+def _kv_tile(rows: int, P: int) -> int:
+    """The key tile of a chunk's blockwise attention over ``rows`` rows."""
+    t = min(512, rows)
+    return t if rows % t == 0 else P
+
+
+def window_chunk_blocks(window: int, Tc: int, P: int) -> int:
+    """Pages a window layer's chunk attention gathers: those that hold a
+    row of [start - window + 1, start + Tc), for a page-aligned ``start``."""
+    return -(-(window - 1) // P) + -(-Tc // P)
+
+
+def _prefill_chunk_kinds(params, cfg: ModelConfig, x, positions, start,
+                         k_pool, v_pool, table_row, qmm, moe_dense, layout):
+    """``prefill_chunk_paged`` for a stack of window and full layers (bf16
+    pool): the same block, each layer with its kind's table, page range,
+    window and rotary table."""
+    B, Tc = x.shape[:2]
+    P = k_pool.shape[2]
+    period = len(layout.kinds)
+    ropes = rope_by_kind(positions, cfg)
+    tables = {k: layout.table_of(table_row, k) for k in ropes}
+    W = cfg.sliding_window
+    # the window kind reads from its window's first block: the first row a
+    # query of this chunk sees is start - W + 1
+    nbw = window_chunk_blocks(W, Tc, P)
+    first_blk = jnp.maximum(start - W + 1, 0) // P
+    reads = {
+        "full": (tables["full"], None, _kv_tile(layout.max_blocks * P, P)),
+        "window": (
+            jax.lax.dynamic_slice(
+                jnp.concatenate(
+                    [tables["window"], jnp.zeros((nbw,), table_row.dtype)]
+                ), (first_blk,), (nbw,),
+            ),
+            first_blk * P, _kv_tile(nbw * P, P),
+        ),
+    }
+    writes = {k: chunk_pages(tables[k], start, Tc, P) for k in ropes}
+
+    def block(carry, layer):
+        x, k_pool, v_pool, scales, stats = carry
+        lp, l = layer
+        kind, base = kind_of(lp), layout.bases[lp["layer_in_period"]]
+        at = l // period  # the period whose pages this layer's are
+        q, k_new, v_new = _project_qkv(x, lp, cfg, *ropes[kind], qmm)
+        pages, off = writes[kind]
+        k_pool = ops.write_rows(
+            k_pool, at, ops.merge_heads(k_new[0]), pages + base, off
+        )
+        v_pool = ops.write_rows(
+            v_pool, at, ops.merge_heads(v_new[0]), pages + base, off
+        )
+        read, col0, tile = reads[kind]
+        k_all = ops.gather_pages(k_pool, at, read + base, cfg.head_dim)[None]
+        v_all = ops.gather_pages(v_pool, at, read + base, cfg.head_dim)[None]
+        with jax.named_scope(f"attention_{kind}"):
+            attn = blockwise_cache_attention(
+                q, k_all.astype(q.dtype), v_all.astype(q.dtype),
+                positions[0], cfg.window_of(kind), tile, col0=col0,
+            )
+        x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
+        return (x, k_pool, v_pool, scales, stats), None
+
+    x, k_pool, v_pool, _, stats = _scan_layers_over_pool(
+        block, x, params, k_pool, v_pool, None, cfg,
+        moe_mod.grouped_serves(B * Tc, cfg, moe_dense),
+    )
+    return (_final_logits(x, params, cfg, qmm), k_pool, v_pool, *stats)
+
+
+def write_prompt_rows(pools, rows, table_row, layout=None):
+    """A whole prompt's rows ``[L, T, W]`` (one array a pool) into the pools
+    through the slot's table(s), by whole pages from row 0 of its first
+    page. A pool by kind (``layout``) takes each layer's rows at its kind's
+    pages of its period."""
+    if layout is None:
+        return tuple(
+            ops.write_rows(p, None, r, table_row) for p, r in zip(pools, rows)
+        )
+    period = len(layout.kinds)
+    out = []
+    for pool, r in zip(pools, rows):
+        r = r.reshape(r.shape[0] // period, period, *r.shape[1:])
+        for i, kind in enumerate(layout.kinds):
+            pool = ops.write_rows(
+                pool, None, r[:, i],
+                layout.table_of(table_row, kind) + layout.bases[i],
+            )
+        out.append(pool)
+    return tuple(out)
+
+
 def decode_step_paged(
     params: Params,
     cfg: ModelConfig,
@@ -1193,6 +1423,7 @@ def decode_step_paged(
     pool_impl=None,  # per-device pool write+attend; see ShardingPlan
     win_starts: Optional[jnp.ndarray] = None,  # [B] int32 live-window start
     sink_rows: int = 0,  # static sink rows (window+sink KV compression)
+    layout=None,  # paged.PoolLayout: the pool of a stack of two kinds
 ):
     """One batched decode step over the PAGED slot cache.
 
@@ -1224,6 +1455,11 @@ def decode_step_paged(
     sacrificial page. win_starts[b] = 0 makes the mask a no-op.
     Unsupported with ``pool_impl`` (the dp-replicated shard_map twin —
     the engine never arms compression there).
+
+    ``layout`` (a stack of window and full layers; ``tables`` holds the two
+    tables a slot side by side): a layer writes its row to, and reads, its
+    kind's pages of its period (engine/paged.py header), a window layer
+    through ``ops.window_decode_attention``. bf16 pool, no ``pool_impl``.
     """
     if cfg.mla:
         from . import latent
@@ -1231,6 +1467,11 @@ def decode_step_paged(
         return latent.decode_step_paged(
             params, cfg, tokens, lengths, k_pool, v_pool, tables,
             kernels=kernels, active=active, moe_dense=moe_dense, qmm=qmm,
+        )
+    if layout is not None:
+        return _decode_step_kinds(
+            params, cfg, tokens, lengths, k_pool, v_pool, tables, kernels,
+            active, moe_dense, qmm, layout,
         )
     B = tokens.shape[0]
     P = k_pool.shape[2]
@@ -1268,7 +1509,7 @@ def decode_step_paged(
     # is renamed by it
     with jax.named_scope("embed"):
         x = params["embed"][tokens][:, None, :]  # [B, 1, E]
-        cos, sin = rope_tables(lengths[:, None], cfg.head_dim, cfg.rope_theta)
+        cos, sin = rope_tables_of(lengths[:, None], cfg.head_dim, cfg.rope_of(None))
     ffn_scope = "moe" if cfg.num_experts else "ffn"
 
     def block(carry, layer):
@@ -1354,6 +1595,75 @@ def decode_step_paged(
     return (logits, k_pool, v_pool, *stats)
 
 
+def _decode_step_kinds(params, cfg: ModelConfig, tokens, lengths, k_pool,
+                       v_pool, tables, kernels, active, moe_dense, qmm, layout):
+    """``decode_step_paged`` for a stack of window and full layers (bf16
+    pool): the same step, each layer with its kind's table, page range,
+    window and rotary table."""
+    B = tokens.shape[0]
+    P = k_pool.shape[2]
+    period = len(layout.kinds)
+    use_kernel = _use_kernels(kernels)
+    act = jnp.ones((B,), jnp.bool_) if active is None else active
+    at_rows = jnp.where(act, lengths, 0)  # an inactive slot reads no row
+    offs = jnp.where(act, at_rows % P, P - 1)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens][:, None, :]  # [B, 1, E]
+        ropes = rope_by_kind(lengths[:, None], cfg)
+    by_kind = {k: layout.table_of(tables, k) for k in ropes}
+    # the page of its kind each slot's new row goes to; an inactive slot's
+    # goes to the range's sacrificial page (+ base below)
+    pages = {
+        k: jnp.where(
+            act, jnp.take_along_axis(t, (at_rows // P)[:, None], axis=1)[:, 0], 0
+        ) for k, t in by_kind.items()
+    }
+
+    def attend(kind, q, k_pool, v_pool, at, tbl):
+        # the window kind's kernel is the full kind's under a jitted name of
+        # its own (ops.window_decode_attention): a device trace tells the
+        # two kinds' attention apart by it
+        window = {"window": cfg.sliding_window} if kind == "window" else {}
+        if not use_kernel:
+            fn = ops.paged_decode_attention_reference
+        elif window:
+            fn = ops.window_decode_attention
+        else:
+            fn = ops.paged_decode_attention
+        return fn(q, k_pool, v_pool, at, tbl, at_rows, **window)
+
+    def block(carry, layer):
+        x, k_pool, v_pool, scales, stats = carry
+        lp, l = layer
+        kind, base = kind_of(lp), layout.bases[lp["layer_in_period"]]
+        at = l // period  # the period whose pages this layer's are
+        q, k_new, v_new = _project_qkv(x, lp, cfg, *ropes[kind], qmm)
+        with jax.named_scope("kv_write"):
+            k_pool = k_pool.at[at, pages[kind] + base, offs].set(
+                ops.merge_heads(k_new[:, 0]).astype(k_pool.dtype)
+            )
+            v_pool = v_pool.at[at, pages[kind] + base, offs].set(
+                ops.merge_heads(v_new[:, 0]).astype(v_pool.dtype)
+            )
+        with jax.named_scope(f"attention_{kind}"):
+            attn = attend(
+                kind, q[:, 0], k_pool, v_pool, at, by_kind[kind] + base
+            )[:, None]
+        with jax.named_scope("attn_out"):
+            x = x + matmul(attn.reshape(B, 1, -1), lp["wo"], qmm, "row")
+        with jax.named_scope("moe" if cfg.num_experts else "ffn"):
+            x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm, act)
+        return (x, k_pool, v_pool, scales, stats), None
+
+    x, k_pool, v_pool, _, stats = _scan_layers_over_pool(
+        block, x, params, k_pool, v_pool, None, cfg,
+        moe_mod.visit_serves(cfg, moe_dense),
+    )
+    with jax.named_scope("final_logits"):
+        logits = _final_logits(x[:, 0], params, cfg, qmm)
+    return (logits, k_pool, v_pool, *stats)
+
+
 def verify_step_paged(
     params: Params,
     cfg: ModelConfig,
@@ -1368,6 +1678,7 @@ def verify_step_paged(
     qmm=None,  # int4 matmul impl (x, leaf, kind) -> y; see matmul()
     win_starts: Optional[jnp.ndarray] = None,  # [B] int32 live-window start
     sink_rows: int = 0,  # static sink rows (window+sink KV compression)
+    layout=None,  # paged.PoolLayout: the pool of a stack of two kinds
 ):
     """``verify_step`` over the PAGED cache: the T in-flight rows scatter
     through the page tables (inactive slots -> sacrificial page 0), and
@@ -1388,12 +1699,17 @@ def verify_step_paged(
             active=active, moe_dense=moe_dense, qmm=qmm,
         )
     B, T = tokens.shape
-    MB = tables.shape[1]
+    MB = tables.shape[1] if layout is None else layout.max_blocks
     P = k_pool.shape[2]
     C = MB * P
     quant_pool = cache_scales is not None
     if active is None:
         active = jnp.ones((B,), jnp.bool_)
+    if layout is not None:
+        return _verify_step_kinds(
+            params, cfg, tokens, lengths, k_pool, v_pool, tables, active,
+            moe_dense, qmm, layout,
+        )
     offs_t = jnp.arange(T)[None, :]
     positions = lengths[:, None] + offs_t  # [B, T]
     rows = jnp.minimum(positions, C - 1)
@@ -1416,7 +1732,7 @@ def verify_step_paged(
         )
 
     x = params["embed"][tokens]  # [B, T, E]
-    cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_tables_of(positions, cfg.head_dim, cfg.rope_of(None))
 
     def block(carry, layer):
         x, k_pool, v_pool, scales, stats = carry
@@ -1453,6 +1769,61 @@ def verify_step_paged(
     if quant_pool:
         return (logits, k_pool, v_pool, scales, *stats)
     return (logits, k_pool, v_pool, *stats)
+
+
+def _verify_step_kinds(params, cfg: ModelConfig, tokens, lengths, k_pool,
+                       v_pool, tables, active, moe_dense, qmm, layout):
+    """``verify_step_paged`` for a stack of window and full layers (bf16
+    pool; what the grammar's jump-ahead dispatches): each layer scatters to
+    and gathers its kind's pages of its period, under its kind's mask and
+    rotary table."""
+    B, T = tokens.shape
+    P = k_pool.shape[2]
+    C = layout.max_blocks * P
+    period = len(layout.kinds)
+    positions = lengths[:, None] + jnp.arange(T)[None, :]  # [B, T]
+    rows = jnp.minimum(positions, C - 1)
+    offs = jnp.where(active[:, None], rows % P, P - 1)
+    qpos = jnp.where(active[:, None], positions, 0)
+    cols = jnp.arange(C)[None, None, :]
+    causal = cols <= qpos[..., None]  # [B, T, C]
+    ropes = rope_by_kind(positions, cfg)
+    by_kind = {k: layout.table_of(tables, k) for k in ropes}
+    pages = {
+        k: jnp.where(
+            active[:, None], jnp.take_along_axis(t, rows // P, axis=1), 0
+        ) for k, t in by_kind.items()
+    }
+    masks = {
+        "full": causal,
+        "window": causal & (cols > (qpos[..., None] - cfg.sliding_window)),
+    }
+    x = params["embed"][tokens]  # [B, T, E]
+
+    def block(carry, layer):
+        x, k_pool, v_pool, scales, stats = carry
+        lp, l = layer
+        kind, base = kind_of(lp), layout.bases[lp["layer_in_period"]]
+        at = l // period
+        q, k_new, v_new = _project_qkv(x, lp, cfg, *ropes[kind], qmm)
+        k_pool = k_pool.at[at, pages[kind] + base, offs].set(
+            ops.merge_heads(k_new).astype(k_pool.dtype)
+        )
+        v_pool = v_pool.at[at, pages[kind] + base, offs].set(
+            ops.merge_heads(v_new).astype(v_pool.dtype)
+        )
+        k_all = ops.gather_pages(k_pool, at, by_kind[kind] + base, cfg.head_dim)
+        v_all = ops.gather_pages(v_pool, at, by_kind[kind] + base, cfg.head_dim)
+        attn = gqa_attention(q, k_all, v_all, masks[kind])
+        x = x + matmul(attn.reshape(B, T, -1), lp["wo"], qmm, "row")
+        x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
+        return (x, k_pool, v_pool, scales, stats), None
+
+    x, k_pool, v_pool, _, stats = _scan_layers_over_pool(
+        block, x, params, k_pool, v_pool, None, cfg,
+        moe_mod.grouped_serves(B * T, cfg, moe_dense),
+    )
+    return (_final_logits(x, params, cfg, qmm), k_pool, v_pool, *stats)
 
 
 def verify_step(
@@ -1537,7 +1908,7 @@ def verify_step(
             mask = mask & (cols > (qpos[..., None] - cfg.sliding_window))
 
     x = params["embed"][tokens]  # [B, T, E]
-    cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_tables_of(positions, cfg.head_dim, cfg.rope_of(None))
     batch_idx = jnp.arange(B)[:, None]  # [B, 1] pairs with write_rows [B, T]
 
     def block(carry, layer):

@@ -22,6 +22,33 @@ ops.write_rows), a slot's gathered pages on the way out
 (ops.gather_pages). Nothing slices, reshapes or copies a layer of it. An
 int8 pool keeps its per-(row, kv head) scales beside it as [L, N, P, KH].
 
+A stack that mixes WINDOW and FULL attention layers (ModelConfig.layer_types)
+has pages by kind, in ONE array pair with two page ranges a period:
+[L / period, N_period, P, KH*D], a period's pages being the full kind's range
+for each of its full layers and then the window kind's range for each of its
+window layers (PoolLayout.bases; each range's first page is that range's
+sacrificial page). A slot has a table a kind, side by side in one
+[S, 2 * MAX_BLOCKS] int32 array (full, then window), and each kind its free
+list, refcounts and residency rule (KindPageAllocator): the full kind holds a
+slot's every row, the window kind the rows its window can still reach, the
+pages below it released as the slot advances and reused by any slot. A window
+layer's trimmed table entries keep their stale page ids (trim_below_window's
+rule): the decode kernel and a chunk's gather start at the window's first
+block, which no trim has passed, and the two readers that gather a slot's
+whole table (the CPU reference, the verify step) mask every row below the
+window, so a stale id is at most a masked read of a page another slot now
+owns. (Rewriting a dead entry to the sacrificial page instead would race a
+dispatch still in flight: on the CPU backend the device's table IS the host
+array.) A layer reads page table[kind] + base of
+period l // period, so nothing a graph makes is as large as a layer of the pool
+and no layer holds pages it cannot read. PREFIX SHARING there: the index
+holds the full kind's pages as for any model; beside it WindowPrefixPages
+keeps, for each registered block, the window-kind page the registering slot
+still held (its last window and what straddles it), and a hit at m rows is
+served only where every window-kind block that holds a row of (m - window, m)
+is held too: else the longest shorter hit that is, else none
+(``prefix_hits_refused_window`` counts the hits cut short or refused).
+
 Allocation is a free-list pop, release a push — O(1), no compaction, no
 device traffic beyond the [S, MAX_BLOCKS] int32 table that rides along with
 each dispatch (a few hundred bytes). The scheduler's admission/retire cycle calls
@@ -45,12 +72,13 @@ import logging
 import struct
 import zlib
 from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .. import faults
 from ..analysis.locks import make_lock
+from .config import LAYER_KINDS as KINDS  # the two tables' order in a slot's row
 
 log = logging.getLogger("aios.paged")
 
@@ -70,14 +98,18 @@ class PoolExhausted(RuntimeError):
     (0 for the unreplicated pool) so the batcher can evict a request that
     actually frees pages there."""
 
-    def __init__(self, needed: int, free: int, replica: int = 0):
+    def __init__(self, needed: int, free: int, replica: int = 0,
+                 kind: str = ""):
         super().__init__(
             f"KV page pool exhausted: need {needed} page(s), {free} free "
-            f"(replica {replica})"
+            f"(replica {replica})" + (f" of the {kind} kind" if kind else "")
         )
         self.needed = needed
         self.free = free
         self.replica = replica
+        # which kind's pages ran out, in a pool by kind ("" otherwise): the
+        # batcher's victim is the slot that holds most pages of THAT kind
+        self.kind = kind
 
 
 class PageAllocator:
@@ -93,7 +125,8 @@ class PageAllocator:
     contiguous blocks — the same split GSPMD applies to the slot axis."""
 
     def __init__(self, num_pages: int, page_size: int, num_slots: int,
-                 max_blocks: int, replicas: int = 1) -> None:
+                 max_blocks: int, replicas: int = 1, kind: str = "") -> None:
+        self.kind = kind  # named by PoolExhausted in a pool by kind
         if replicas < 1 or num_pages % replicas:
             raise ValueError(
                 f"num_pages {num_pages} must divide into {replicas} replicas"
@@ -134,6 +167,10 @@ class PageAllocator:
         # called with the shortfall when the free list runs dry; returns
         # how many pages it reclaimed (PrefixIndex.reclaim plugs in here)
         self.reclaimer: Optional[Callable[[int], int]] = None
+        # pages ``ensure`` ever handed out, and pages trim_below_window
+        # released: what kv.window_trim_share_pct divides (pool.stats())
+        self.pages_allocated = 0
+        self.pages_trimmed = 0
 
     def replica_of(self, slot: int) -> int:
         return slot * self.replicas // self.num_slots
@@ -159,18 +196,26 @@ class PageAllocator:
     def blocks_for(self, rows: int) -> int:
         return -(-rows // self.page_size)  # ceil
 
+    def can_hold(self, rows: int, window_rows: int) -> bool:
+        """Whether a slot of ``rows`` rows, ``window_rows`` of them within
+        reach of its window at once (all of them without one), can ever be
+        backed: one kind of page holds what the window reaches."""
+        return self.blocks_for(window_rows) <= self.capacity_blocks()
+
     def _take(self, grow: int, replica: int = 0) -> None:
         act = faults.point("allocator.pressure")
         if act is not None:
             # chaos: synthetic pool pressure — rides the real
             # PoolExhausted recovery (victim eviction at decode grow /
             # prefill, restore fallback at alloc_pages)
-            raise PoolExhausted(grow, len(self._free[replica]), replica)
+            raise PoolExhausted(
+                grow, len(self._free[replica]), replica, self.kind
+            )
         free = self._free[replica]
         if grow > len(free) and self.reclaimer is not None:
             self.reclaimer(grow - len(free))
         if grow > len(free):
-            raise PoolExhausted(grow, len(free), replica)
+            raise PoolExhausted(grow, len(free), replica, self.kind)
 
     def ensure(self, slot: int, rows: int) -> bool:
         """Back slot ``slot`` for ``rows`` logical rows; allocates any
@@ -184,6 +229,7 @@ class PageAllocator:
             return False
         r = self.replica_of(slot)
         self._take(need - have, r)
+        self.pages_allocated += need - have
         for b in range(have, need):
             page = self._free[r].pop()
             self._rc[r, page] = 1
@@ -191,16 +237,19 @@ class PageAllocator:
         self._blocks_used[slot] = need
         return True
 
-    def map_shared(self, slot: int, pages: Sequence[int]) -> None:
+    def map_shared(self, slot: int, pages: Sequence[int],
+                   first: int = 0) -> None:
         """Map already-resident pages (a matched prefix) as slot ``slot``'s
-        leading blocks, taking a reference on each. The slot must be empty
-        (fresh admission)."""
+        blocks from ``first`` on, taking a reference on each. The slot must
+        be empty (fresh admission). ``first`` > 0 is the window kind's case:
+        the blocks below it count as trimmed (never held, never read)."""
         assert int(self._blocks_used[slot]) == 0, "slot must be empty"
         r = self.replica_of(slot)
-        for b, page in enumerate(pages):
+        for b, page in enumerate(pages, start=first):
             self._rc[r, page] += 1
             self.tables[slot, b] = page
-        self._blocks_used[slot] = len(pages)
+        self._blocks_used[slot] = first + len(pages)
+        self._trimmed[slot] = first
 
     def alloc_pages(self, n: int, replica: int = 0) -> List[int]:
         """Pop ``n`` fresh pages (refcount 1 each) WITHOUT mapping them to
@@ -288,6 +337,7 @@ class PageAllocator:
             freed += 1
         if dead > self._trimmed[slot]:
             self._trimmed[slot] = dead
+        self.pages_trimmed += freed
         return freed
 
     def prune_range(self, slot: int, lo: int, hi: int) -> int:
@@ -336,6 +386,198 @@ class PageAllocator:
 
     def slot_rows_backed(self, slot: int) -> int:
         return int(self._blocks_used[slot]) * self.page_size
+
+
+class PoolLayout(NamedTuple):
+    """Where the pages of a pool by kind lie (paged.py header): constants
+    of a graph. ``kinds[i]`` / ``bases[i]`` are the kind of layer i of a
+    period and the first page of its range among the period's ``pages``."""
+
+    max_blocks: int
+    kinds: Tuple[str, ...]
+    bases: Tuple[int, ...]
+    pages: int
+
+    def table_of(self, tables, kind: str):
+        """The ``kind`` table(s) of the side-by-side array [.., 2 * MB]."""
+        at = KINDS.index(kind) * self.max_blocks
+        return tables[..., at : at + self.max_blocks]
+
+
+class KindPageAllocator:
+    """The pages of a stack of window and full layers: a PageAllocator a
+    kind (``full``, ``window``) behind the interface the engine and the
+    batcher use, their tables two views of one [S, 2 * MAX_BLOCKS] array.
+
+    ``ensure`` backs a slot's rows in both kinds or in neither;
+    ``trim_below_window`` releases the window kind's pages only (the full
+    kind reads every row); ``free_slot`` frees both. ``window_rows`` is what
+    ONE slot's window kind holds at most: the window, one chunk in flight
+    and a page of straddle, in whole pages."""
+
+    replicas = 1
+
+    def __init__(self, full_pages: int, window_pages: int, page_size: int,
+                 num_slots: int, max_blocks: int,
+                 period_kinds: Sequence[str]) -> None:
+        self.page_size = page_size
+        self.num_slots = num_slots
+        self.max_blocks = max_blocks
+        self.full = PageAllocator(
+            full_pages, page_size, num_slots, max_blocks, kind="full"
+        )
+        self.window = PageAllocator(
+            window_pages, page_size, num_slots, max_blocks, kind="window"
+        )
+        self.by_kind = {"full": self.full, "window": self.window}
+        self.tables = np.full(
+            (num_slots, 2 * max_blocks), SACRIFICIAL_PAGE, dtype=np.int32
+        )
+        for i, kind in enumerate(KINDS):
+            self.by_kind[kind].tables = self.tables[
+                :, i * max_blocks : (i + 1) * max_blocks
+            ]
+        bases, at = {}, 0
+        for kind in KINDS:
+            for i, k in enumerate(period_kinds):
+                if k == kind:
+                    bases[i] = at
+                    at += self.by_kind[kind].num_pages
+        self.layout = PoolLayout(
+            max_blocks, tuple(period_kinds),
+            tuple(bases[i] for i in range(len(period_kinds))), at,
+        )
+
+    # -- what the engine and the batcher ask of any allocator ---------------
+
+    def replica_of(self, slot: int) -> int:
+        return 0
+
+    @property
+    def free_pages(self) -> int:
+        return self.full.free_pages + self.window.free_pages
+
+    def free_pages_for(self, slot: int) -> int:
+        return self.full.free_pages_for(slot)
+
+    def pages_in_use(self) -> int:
+        return self.full.pages_in_use() + self.window.pages_in_use()
+
+    def capacity_blocks(self) -> int:
+        """Most blocks one slot can ever hold: the FULL kind's (a slot's
+        window kind holds ``can_hold``'s bounded rows)."""
+        return self.full.capacity_blocks()
+
+    def blocks_for(self, rows: int) -> int:
+        return self.full.blocks_for(rows)
+
+    def can_hold(self, rows: int, window_rows: int) -> bool:
+        """Whether a slot of ``rows`` rows, ``window_rows`` of them within
+        reach of a window layer at once, can ever be backed."""
+        return (
+            self.full.blocks_for(rows) <= self.full.capacity_blocks()
+            and self.window.blocks_for(window_rows)
+            <= self.window.capacity_blocks()
+        )
+
+    def ensure(self, slot: int, rows: int) -> bool:
+        """Back ``rows`` logical rows of ``slot`` in BOTH kinds, or raise
+        PoolExhausted (naming the kind) with neither grown."""
+        need = min(self.blocks_for(rows), self.max_blocks)
+        for alloc in (self.window, self.full):
+            grow = need - int(alloc._blocks_used[slot])
+            if grow > 0:
+                alloc._take(grow)  # reclaims, or raises before any pop
+        changed = self.full.ensure(slot, rows)
+        return self.window.ensure(slot, rows) or changed
+
+    def trim_below_window(self, slot: int, length: int, window: int) -> int:
+        return self.window.trim_below_window(slot, length, window)
+
+    def free_slot(self, slot: int) -> None:
+        self.full.free_slot(slot)
+        self.window.free_slot(slot)
+
+    def slot_pages_resident(self, slot: int, kind: str = "") -> int:
+        if kind:
+            return self.by_kind[kind].slot_pages_resident(slot)
+        return sum(a.slot_pages_resident(slot) for a in self.by_kind.values())
+
+    def slot_rows_backed(self, slot: int) -> int:
+        return self.full.slot_rows_backed(slot)
+
+    def stats(self) -> Dict[str, int]:
+        """The counters by kind (pool.stats()). ``in_use`` counts what the
+        prefix index keeps of finished requests too (it stands near the pool
+        whenever sharing is on); ``kv_full_pages_live`` is the demand: the
+        full-kind pages the slots map now, a page two slots share once a
+        slot."""
+        return {
+            "kv_full_pages_in_use": self.full.pages_in_use(),
+            "kv_full_pages_live": sum(
+                self.full.slot_pages_resident(s)
+                for s in range(self.tables.shape[0])
+            ),
+            "kv_full_pages": self.full.num_pages - 1,
+            "kv_window_pages_in_use": self.window.pages_in_use(),
+            "kv_window_pages": self.window.num_pages - 1,
+            "kv_window_pages_allocated": self.window.pages_allocated,
+            "kv_window_pages_trimmed": self.window.pages_trimmed,
+        }
+
+
+class WindowPrefixPages:
+    """The window kind's side of prefix sharing (paged.py header): for a
+    registered block's chain hash, the window-kind page the registering slot
+    still held, one reference each. LRU; the window allocator's reclaimer
+    drops the coldest entries no slot shares when its free list runs dry."""
+
+    def __init__(self, allocator: PageAllocator) -> None:
+        self.alloc = allocator
+        self._pages: "OrderedDict[bytes, int]" = OrderedDict()
+        allocator.reclaimer = self.reclaim
+
+    def __len__(self) -> int:
+        return len(self._pages)
+
+    def put(self, hashes: Sequence[bytes], pages: Sequence[int]) -> None:
+        for h, page in zip(hashes, pages):
+            if h in self._pages:
+                self._pages.move_to_end(h)
+                continue
+            self.alloc.incref(page)
+            self._pages[h] = page
+
+    def servable(self, hashes: Sequence[bytes], window: int) -> Tuple[int, List[int]]:
+        """The longest n <= len(hashes) such that a hit at n blocks has every
+        window-kind block that holds a row of (n * P - window, n * P): (n,
+        those blocks' pages, the first of them being block n - len(pages))."""
+        P = self.alloc.page_size
+        for n in range(len(hashes), 0, -1):
+            first = max(n * P - window + 1, 0) // P
+            pages = [self._pages.get(h) for h in hashes[first:n]]
+            if None not in pages:
+                for h in hashes[first:n]:
+                    self._pages.move_to_end(h)
+                return n, pages
+        return 0, []
+
+    def reclaim(self, n: int) -> int:
+        dropped = 0
+        for h in list(self._pages):
+            if dropped >= n:
+                break
+            page = self._pages[h]
+            if self.alloc.refcount(page) == 1:
+                del self._pages[h]
+                self.alloc.decref(page)
+                dropped += 1
+        return dropped
+
+    def clear(self) -> None:
+        while self._pages:
+            _, page = self._pages.popitem(last=False)
+            self.alloc.decref(page)
 
 
 def chain_hashes(

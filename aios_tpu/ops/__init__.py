@@ -42,6 +42,7 @@ from .paged_attention import (
     paged_decode_attention_reference,
     merge_heads,
     split_heads,
+    window_decode_attention,
     write_rows,
 )
 from .paged_mla_attention import (
@@ -78,6 +79,7 @@ __all__ = [
     "paged_decode_attention_int8",
     "paged_decode_attention_int8_reference",
     "paged_decode_attention_reference",
+    "window_decode_attention",
     "paged_mla_decode_attention",
     "paged_mla_decode_attention_reference",
     "gather_pages",

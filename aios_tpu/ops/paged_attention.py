@@ -291,6 +291,28 @@ def paged_decode_attention(
     )
 
 
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def window_decode_attention(
+    q: jnp.ndarray,  # [B, H, D]
+    k_pool: jnp.ndarray,  # [L, N, P, KH*D] bf16
+    v_pool: jnp.ndarray,
+    layer: jnp.ndarray,
+    tables: jnp.ndarray,  # [B, MAX_BLOCKS] int32: the window kind's pages
+    lengths: jnp.ndarray,  # [B] int32
+    *,
+    window: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``paged_decode_attention`` for the WINDOW layers of a stack that also
+    has full ones (engine/paged.py header): the same kernel under a jitted
+    name of its own, because a device trace names a kernel by the jitted
+    function it lies in and the two kinds' attention are told apart there."""
+    return _paged_call(
+        q, k_pool, v_pool, layer, tables, lengths, None,
+        window=window, win_starts=None, sink=None, interpret=interpret,
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("window", "sink", "interpret"))
 def paged_decode_attention_int8(
     q: jnp.ndarray,  # [B, H, D]

@@ -102,7 +102,7 @@ def make_pp_train_step(
         returns (x', stage aux sum over local layers)."""
         mb, T, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(T), (mb, T))
-        cos, sin = model.rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+        cos, sin = model.rope_tables_of(positions, cfg.head_dim, cfg.rope_of(None))
         mask = model.causal_mask(T, cfg.sliding_window)
 
         def blk(x, lp):

@@ -358,6 +358,20 @@ class ModelManager:
         if self.plan is not None:
             dp, tp = self.plan.dp, self.plan.tp
         rows = kw.get("paged_pool_rows") or self.num_slots * ctx
+        if cfg.kinds and kw.get("paged_pool_rows"):
+            # pages by kind (engine/paged.py header): the full layers hold
+            # the pool's rows, the window layers what the same share of
+            # slots holds of a window
+            from ..engine.engine import window_rows_a_slot
+
+            per_layer = row / cfg.num_layers
+            window = sum(k == "window" for k in cfg.layer_types)
+            held = -(-rows // ctx) * window_rows_a_slot(
+                cfg, kw.get("page_size", 128), ctx
+            )
+            return per_layer * (
+                (cfg.num_layers - window) * rows + window * held
+            )
         return row * rows / (dp * tp)
 
     # -- loading ------------------------------------------------------------
@@ -488,10 +502,16 @@ class ModelManager:
                         + self._kv_row_bytes(draft.cfg, jnp.bfloat16)
                         * self.num_slots * ctx * repl_factor
                     )
-            from ..engine.engine import refuse_for_latent_pool
+            from ..engine.engine import (
+                refuse_for_latent_pool,
+                refuse_for_two_kinds,
+            )
 
             refuse_for_latent_pool(
                 cfg, speculative_decoding_with_verify_step_paged=spec_on
+            )
+            refuse_for_two_kinds(
+                cfg, speculative_decoding_and_its_rollback=spec_on
             )
             kw = {}
             pool_rows = self.paged_pool_rows

@@ -700,3 +700,86 @@ def test_aot_stream_mixes_compile_and_fit(rep_sharding, monkeypatch):
         live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
         assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"
+
+
+# --- window and full attention layers in one stack (PR 35) -------------------
+
+
+def test_aot_two_kinds_compile_and_fit(rep_sharding, monkeypatch):
+    """Mellum 2 as the benchmark cuts it (published widths, 20 layers = 5
+    periods of 3 window + 1 full, all 64 experts, the whole 98k vocabulary, 16
+    slots x 16,384 rows): the composed decode step, a mid and a final chunk
+    and the largest whole-prompt prefill (2,048: a longer prompt admits in
+    chunks, engine.prefill), each beside 9.1 GB of weights and
+    the 3.65 GB pool by kind. ONE layer scan a graph (its body a period); a
+    decode step runs the window kind's kernel under its own jitted name three
+    times a period and the full kind's once; no graph copies a pool array."""
+    from aios_tpu import backend
+    from aios_tpu.engine import model as M
+    from aios_tpu.engine import paged
+
+    cfg, shapes = _bench_model("mellum2-12b-a2.5b-int8-d20.json", "mellum.py", 16384)
+    assert cfg.kinds and cfg.period_kinds == ("window",) * 3 + ("full",)
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    rep = rep_sharding
+    params = jax.tree.map(lambda a: sds(rep, a.shape, a.dtype), shapes)
+    slots, blocks = 16, 128
+    alloc = paged.KindPageAllocator(17 * 128 + 1, 17 * 12 + 1, 128, slots, blocks,
+                                    cfg.period_kinds)
+    layout = alloc.layout
+    assert layout.pages == 2177 + 3 * 205 and layout.bases == (2177, 2382, 2587, 0)
+    pools = tuple(sds(rep, (5, layout.pages, 128, w), jnp.bfloat16)
+                  for w in cfg.kv_row_dims)
+    pool_elems = {5 * layout.pages * 128 * w for w in cfg.kv_row_dims}
+    i32 = lambda *shape: sds(rep, shape, jnp.int32)  # noqa: E731
+
+    def step(p, c, r, toks, lens, tables):
+        return M.decode_step_paged(p, cfg, toks, lens, c, r, tables, kernels=True,
+                                   layout=layout)
+
+    def mid(p, c, r, toks, start, row):
+        return M.prefill_chunk_paged(p, cfg, toks, start, c, r, row, layout=layout)[1:]
+
+    def final(p, c, r, toks, start, row, n):
+        logits, *rest = M.prefill_chunk_paged(p, cfg, toks, start, c, r, row,
+                                              layout=layout)
+        return (logits[0, n - 1], *rest)
+
+    def prefill(p, c, r, toks, row, n):
+        logits, ks, vs, picks = M.prefill(p, cfg, toks, kernels=True, logit_row=n - 1)
+        from aios_tpu import ops
+
+        rows = (ops.merge_heads(ks[:, 0]), ops.merge_heads(vs[:, 0]))
+        return (logits[0, 0], *M.write_prompt_rows((c, r), rows, row, layout), picks)
+
+    chunk_args = (params, *pools, i32(1, 512), i32(), i32(2 * blocks))
+    graphs = {
+        "decode-step": (step, (params, *pools, i32(slots), i32(slots),
+                               i32(slots, 2 * blocks))),
+        "chunk-512": (mid, chunk_args),
+        "final-chunk-512": (final, chunk_args + (i32(),)),
+        "prefill-2048": (prefill, (params, *pools, i32(1, 2048), i32(2 * blocks), i32())),
+        # token counts the dense expert path serves: the layer scan slices
+        # one layer of the stacks, and never copies them whole
+        "prefill-128": (prefill, (params, *pools, i32(1, 128), i32(2 * blocks), i32())),
+        "final-chunk-64": (final, (params, *pools, i32(1, 64), i32(), i32(2 * blocks), i32())),
+    }
+    for name, (fn, args) in graphs.items():
+        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+        text = compiled.as_text()
+        if os.environ.get("AOT_DUMP"):
+            with open(os.path.join(os.environ["AOT_DUMP"], f"{name}.hlo"), "w") as fh:
+                fh.write(text)
+        stack_elems = {20 * 64 * 2304 * 1792, 20 * 64 * 896 * 2304}
+        copied = [res for op, res in _hlo_results(text)
+                  if op == "copy" and (pool_elems | stack_elems) & set(res)]
+        assert copied == [], f"{name}: copies a whole pool array or expert stack"
+        if name == "decode-step":
+            assert len(re.findall(r"%window_decode_attention[.\d]* = ", text)) == 3
+            assert len(re.findall(r"%paged_decode_attention[.\d]* = ", text)) == 1
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"
+        print(f"{name}: {live / 1e9:.2f} GB live, temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB")

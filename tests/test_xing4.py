@@ -401,8 +401,12 @@ def test_what_cannot_carry_the_streams_refuses_by_name():
                          num_layers=1, num_heads=2, num_kv_heads=1, head_dim=4)
     with pytest.raises(ValueError, match="several mixed streams.*latent-attention block only"):
         ModelConfig(**grouped_query, hc_mult=4)
-    with pytest.raises(ValueError, match="YaRN rotary scaling"):
+    # YaRN serves the grouped-query block too (PR 35): what is refused is a
+    # factor without the length it scales from
+    with pytest.raises(ValueError, match="needs rope_original_context"):
         ModelConfig(**grouped_query, rope_factor=8.0)
+    assert ModelConfig(**grouped_query, rope_factor=8.0,
+                       rope_original_context=64).rope_of(None).attention_factor > 1.0
     with pytest.raises(ValueError, match="needs rope_original_context"):
         dataclasses.replace(CFG, rope_original_context=0)
     from aios_tpu.engine.engine import refuse_for_latent_pool
