@@ -427,22 +427,16 @@ class ContinuousBatcher:
         # prefill_chunk_default — the same size warmup pre-compiles — and
         # falls back to monolithic prefill when the engine's bucket grid
         # can't honour the chunk size.
-        if prefill_chunk is None:
-            prefill_chunk = engine.prefill_chunk_default
-        self.prefill_chunk: Optional[int] = prefill_chunk or None
-        if self.prefill_chunk is not None and (
-            self.prefill_chunk not in engine.buckets
-            or engine.max_context % self.prefill_chunk
+        self.prefill_chunk: Optional[int] = (
+            engine.admission_chunk(prefill_chunk) or None
+        )
+        if prefill_chunk and self.prefill_chunk is None and (
+            engine.pool_replicas > 1
         ):
-            self.prefill_chunk = None
-        if self.prefill_chunk is not None and getattr(
-            engine, "pool_replicas", 1
-        ) > 1:
             log.warning(
                 "chunked admission disabled: unsupported on a "
                 "dp-replicated page pool (whole-prompt prefill instead)"
             )
-            self.prefill_chunk = None
         # paged engines can run out of physical KV pages mid-stream; the
         # policy is to retire the LONGEST request (it has produced the most
         # and frees the most pages) and retry — counted for observability

@@ -3865,6 +3865,18 @@ class TPUEngine:
     # production graphs and the readiness gate can't drift apart.
     prefill_chunk_default = 512
 
+    def admission_chunk(self, prefill_chunk: Optional[int] = None) -> int:
+        """Rows of one chunk of chunked admission, 0 where every prompt
+        prefills whole: ``prefill_chunk`` (None -> prefill_chunk_default)
+        where it is a prefill bucket that divides ``max_context`` and the
+        pool is not dp-partitioned (chunks read the pool during admission).
+        The batcher admits by it and ``warmup`` compiles by it."""
+        ck = self.prefill_chunk_default if prefill_chunk is None else prefill_chunk
+        if (ck and ck in self.buckets and self.max_context % ck == 0
+                and self.pool_replicas == 1):
+            return ck
+        return 0
+
     def warmup(
         self,
         # must cover every step size the continuous batcher dispatches —
@@ -3893,17 +3905,27 @@ class TPUEngine:
         counters stay FLAT afterwards (the no-compile-after-warmup
         regression gate in tests/test_decode_pipeline.py).
 
-        Coverage: every power-of-two prefill bucket the pool can back, the
-        chunked-admission graphs (mid chunk + every final bucket <=
-        ``prefill_chunk``; pass the batcher's size if it overrides the
-        shared default, 0 to skip), the prefix-HIT graphs (history
-        backfill per bucket + the prefix-chunk tail graphs), every
-        ``step_sizes`` decode graph, the grammar-masked step when
-        ``masked_step``, speculative round graphs for ``spec_sizes``, and
-        the host-tier restore scatter buckets.
+        Coverage: the whole-prompt prefill graph of every power-of-two
+        bucket the pool can back AND the batcher prefills whole: with
+        chunked admission on (``admission_chunk``, the rule
+        ``ContinuousBatcher`` reads too) a longer prompt admits in chunks,
+        so the buckets above the chunk size are left out; with it off (0,
+        or a dp-partitioned pool) every bucket. Then the chunked-admission
+        graphs (mid chunk + every final bucket <= ``prefill_chunk``; pass
+        the batcher's size if it overrides the shared default, 0 to skip),
+        the prefix-HIT graphs (history backfill per bucket + the
+        prefix-chunk tail graphs), every ``step_sizes`` decode graph, the
+        grammar-masked step when ``masked_step``, speculative round graphs
+        for ``spec_sizes``, and the host-tier restore scatter buckets.
+
+        A caller outside the batcher (``generate``, the single-shot CLI)
+        that prefills a prompt longer than the chunk size whole compiles
+        that bucket's graph on first use, as any decode size outside
+        ``step_sizes`` does.
         """
         t0 = time.perf_counter()
         before = self.compile_events
+        ck = self.admission_chunk(prefill_chunk)
         for bucket in self.buckets:
             if self.paged and self.allocator.blocks_for(
                 bucket // 2 + 1
@@ -3911,7 +3933,8 @@ class TPUEngine:
                 continue  # pool can't back prompts of this bucket anyway
             if self._whole_prompt_rows and bucket // 2 + 1 > self._whole_prompt_rows:
                 continue  # pages by kind: such a prompt admits in chunks
-            self.compile_prefill_fn(bucket)
+            if not ck or bucket <= ck:
+                self.compile_prefill_fn(bucket)
             if (
                 self._seq_attn is not None
                 and bucket >= self.bucket_for(self.seq_prefill_min)
@@ -3920,8 +3943,7 @@ class TPUEngine:
                 # sp-sharded twin, so a huge admission never compiles
                 # on the scheduler thread
                 self.compile_seq_prefill_fn(bucket)
-        ck = self.prefill_chunk_default if prefill_chunk is None else prefill_chunk
-        if ck and ck in self.buckets and self.max_context % ck == 0:
+        if ck:
             self.compile_chunk_fn(ck, final=False)
             for b in self.buckets:
                 if b > ck:

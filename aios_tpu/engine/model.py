@@ -298,9 +298,12 @@ def kind_of(lp) -> Optional[str]:
 def rope_by_kind(positions: jnp.ndarray, cfg: ModelConfig):
     """{kind: (cos, sin)} at ``positions`` for every kind of layer the stack
     has; a stack of one kind has the one key None."""
+    # in the period's own order: a set's order follows the process's hash
+    # seed, the graph's text with it, and a compile cache keyed by that text
+    # then misses every other start
     return {
         kind: rope_tables_of(positions, cfg.head_dim, cfg.rope_of(kind))
-        for kind in (set(cfg.period_kinds) or {None})
+        for kind in (dict.fromkeys(cfg.period_kinds) or {None: None})
     }
 
 

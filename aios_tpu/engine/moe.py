@@ -18,18 +18,22 @@ from an option: ``visit_serves``, ``grouped_serves``):
     touched it is every expert's bytes once, as the dense path reads them.
   * ``moe_ffn_grouped`` — PREFILL token counts (a chunk or a bucket of
     enough tokens, ``grouped_pays``): each held expert runs over the rows
-    routed to it and no others, a GROUP_TILE of them at a time in one flat
-    loop over (expert, tile) pairs, reading the stacked int8 expert weights
-    where they lie at (layer, expert). Exact and dropless. At 2 of 8 and 512
-    tokens that is 1,024-1,536 expert rows where every expert over every
-    token is 4,096.
+    routed to it and no others. The picks are laid out by expert, each
+    expert's segment rounded up to a ROW_BLOCK of 32 rows, and the unit of
+    work is an expert's SEGMENT: on the chip one kernel a layer call
+    (ops/expert_group.py) streams each touched expert's int8 weights ONCE,
+    where they lie in the stacks at (layer, expert), over that expert's own
+    rows; off the chip a loop of the same products over the same layout.
+    Exact and dropless. At 2 of 8 and 512 tokens that is 1,024-1,280 expert
+    rows where every expert over every token is 4,096, and eight streams of
+    an expert where a tile of 128 rows streamed it twelve times (PR 37).
   * ``moe_ffn_dense`` — every expert processes every token; per-token gate
     weights (zero for unselected experts) scale the outputs. Exact and
     dropless. Every held expert's weights are streamed whatever was picked:
     the path of every graph under a sharding plan (decode steps too), of the
     training forward's small token counts, and of the prefill buckets too
-    small for a tile an expert (``grouped_pays``; 128 tokens touch every
-    expert anyway). The einsum contracts over the expert axis, so under
+    small for a pass of the MXU an expert (``grouped_pays``). The einsum
+    contracts over the expert axis, so under
     expert parallelism (experts sharded on the mesh's ``ep`` axis) each
     device computes its local experts and XLA inserts one psum over ``ep``
     — no hand-written collectives, same GSPMD recipe as the Megatron TP
@@ -65,7 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import ops
-from ..ops import expert_visit
+from ..ops import expert_group, expert_visit
 from .config import ModelConfig
 
 
@@ -300,25 +304,31 @@ def moe_ffn_dispatch(
     return out.reshape(B, T, E), aux
 
 
-GROUP_TILE = 128  # rows of one expert's tile: the MXU's height
 # the leaves of an expert layer that hold one matrix an expert
 EXPERT_LEAVES = ("we_gateup", "we_gate", "we_up", "we_down")
 
 
 def grouped_pays(n_tok: int, cfg: ModelConfig) -> bool:
-    """Whether ``moe_ffn_grouped`` computes fewer expert rows than
-    dense-over-held for ``n_tok`` tokens, reckoned from static shapes: the
-    picks expected to land on a held expert, plus at most one part-filled
-    GROUP_TILE a held expert, against every held expert over every token.
-    At 2 of 8 with all held that is 2,048 against 4,096 rows for 512 tokens
-    and 1,280 against 1,024 for 128; at 8 of 256 with 16 held, 2,176 against
-    4,096 for 256 tokens and 2,112 against 2,048 for 128. This reckons ROWS,
-    the measure of a prefill's compute; a decode step, which is bound by the
-    experts' bytes, is never asked (``visit_serves``), and under a sharding
-    plan, where it is, its few rows do not pay."""
+    """Whether ``moe_ffn_grouped`` does less MXU work than dense-over-held
+    for ``n_tok`` tokens, reckoned from static shapes. Either path streams a
+    held expert's weights once at most, and a weight tile pushed through the
+    MXU costs a PASS of 128 rows whatever fewer follow it (ops/expert_group.py),
+    so the measure is rows in whole passes: the picks expected to land on a
+    held expert plus at most one part-filled PASS a held expert, against
+    every held expert over every token. At 2 of 8 with all held that is
+    2,048 against 4,096 rows for 512 tokens and 1,280 against 1,024 for 128;
+    at 8 of 256 with 16 held, 2,176 against 4,096 for 256 tokens and 2,112
+    against 2,048 for 128. Timed alone on the v5e both ways (PERF.md, PR 37)
+    the buckets of 64 and 128 tokens that a reckoning in ROW_BLOCKs would
+    hand over run dense in 1.06-1.45 ms a layer against 1.31-1.55 grouped at
+    64 experts and at the held sixteenth (every expert is touched anyway and
+    the layout is extra); Mixtral's bucket 128 alone reads the other way
+    (2.21 against 2.60) and stays where it was. A decode step, which is
+    bound by the experts' bytes, is never asked (``visit_serves``), and
+    under a sharding plan, where it is, its few rows do not pay."""
     held = cfg.held_experts
     picks = -(-n_tok * cfg.num_experts_per_tok * held // cfg.num_experts)
-    return picks + held * GROUP_TILE < held * n_tok
+    return picks + held * expert_group.PASS < held * n_tok
 
 
 def grouped_serves(
@@ -328,7 +338,7 @@ def grouped_serves(
     """Whether a graph over ``n_tok`` tokens runs its expert layers through
     ``moe_ffn_grouped``: not an engine's under a sharding plan
     (``moe_dense``), not the training forward (its loop runs a
-    data-dependent number of tiles, which reverse-mode differentiation
+    data-dependent number of trips, which reverse-mode differentiation
     cannot unroll), and the path pays. model.ffn and the layer scans that
     hand it the expert stacks whole ask the same question."""
     return (
@@ -445,44 +455,67 @@ def moe_ffn_visit(
     )
 
 
+def _ranks(key: jnp.ndarray, X: int):
+    """(rank [P], counts [X]) int32 of ``key`` [P] (an expert below ``X`` or
+    ``X`` for none): how many earlier picks fell on the same expert, and how
+    many fell on each. A running count over picks in two levels, blocks of
+    128 picks through a triangular product (zeros and ones, summed in
+    float32: exact) and the blocks' totals through a short cumsum: the
+    cumsum over all the picks is a reduce-window of 100 us at 2,048 picks
+    on the v5e (PERF.md, PR 37)."""
+    P, B = key.shape[0], 128
+    G = -(-P // B)
+    hot = jax.nn.one_hot(
+        jnp.pad(key, (0, G * B - P), constant_values=X), X, dtype=jnp.bfloat16
+    ).reshape(G, B, X)
+    before = jnp.tril(jnp.ones((B, B), jnp.bfloat16), -1)
+    within = jnp.einsum(
+        "rc,gcx->grx", before, hot, preferred_element_type=jnp.float32
+    )
+    totals = jnp.sum(hot, axis=1, dtype=jnp.float32)  # [G, X]
+    upto = jnp.cumsum(totals, axis=0)
+    rank = jnp.sum(
+        (within + (upto - totals)[:, None, :]) * hot.astype(jnp.float32), axis=-1
+    )
+    return rank.reshape(G * B)[:P].astype(jnp.int32), upto[-1].astype(jnp.int32)
+
+
 def moe_ffn_grouped(
     h: jnp.ndarray,  # [B, T, E] normalized hidden states
     lp,
     cfg: ModelConfig,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Exact dropless MoE FFN for LARGE token counts; returns (out, aux,
+    """Exact dropless MoE FFN for PREFILL token counts; returns (out, aux,
     ``pick_stats``).
 
     The picks that fall on a held expert are laid out by expert, each
-    expert's rows padded to whole GROUP_TILEs; ONE flat loop then runs over
-    the tiles that hold a pick (a trip count read from the picks, so no
-    capacity is fixed and no pick is dropped): tile ``i`` belongs to the
-    expert whose tiles it falls among, and its three matrix products read
-    that expert's int8 weights WHERE THEY LIE (``_experts_in_place``: the
-    layer scans hand the stacks whole, because a layer's slice taken by the
-    scan would become the loop's operand, and a copy). Each
-    token then adds up its picks' rows, gated, in float32. Work follows the
-    picks that landed here where the dense path runs every held expert over
-    every token; an expert's weights stream once a tile."""
+    expert's segment of rows rounded up to whole ROW_BLOCKs
+    (``expert_group.segments``; no capacity is fixed and no pick is
+    dropped). The unit of work is an expert's SEGMENT: on the chip the
+    serving layout's int8 leaves go through ONE kernel a layer call
+    (ops/expert_group.py), which streams each touched expert's weights once,
+    WHERE THEY LIE in the stacks (the layer scans hand them whole, because a
+    layer's slice taken by the scan would become an operand, and a copy),
+    and runs them over that expert's own rows; anything else, and the CPU,
+    through a loop of the same products over the same layout, a ROW_BLOCK a
+    trip (``_experts_in_place``). Each token then adds up its picks' rows,
+    gated, in float32. ``pick_stats`` counts the rows of the segments'
+    blocks (every pick's row and the rounding) and the experts that have
+    one."""
     B, T, E = h.shape
-    N, k, X, TM = B * T, cfg.num_experts_per_tok, cfg.held_experts, GROUP_TILE
-    F = cfg.expert_dim
+    N, k, X, F = B * T, cfg.num_experts_per_tok, cfg.held_experts, cfg.expert_dim
+    RB = expert_group.ROW_BLOCK
     flat = h.reshape(N, E)
     probs, weights, idx = route(flat, lp["w_router"], cfg, lp.get("router_bias"))
     weights, idx_here, here = local_picks(weights, idx, cfg)
     if here is None:
         here = jnp.ones(idx.shape, jnp.bool_)
     key = jnp.where(here, idx_here, X).reshape(N * k)  # absent: no expert
-    onehot = jax.nn.one_hot(key, X, dtype=jnp.int32)  # [N*k, X]
-    counts = jnp.sum(onehot, axis=0)
-    # a pick's place among its expert's rows, in token order
-    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=-1)
-    tiles = (counts + TM - 1) // TM
-    tile_end = jnp.cumsum(tiles)  # [X] tiles up to and with each expert's
-    n_tiles = tile_end[-1]
-    first_row = (tile_end - tiles) * TM
+    rank, counts = _ranks(key, X)
+    blocks, first_row = expert_group.segments(counts)
+    n_blocks = jnp.sum(blocks)
     # every pick a row of its own; the picks of absent experts off the end
-    M = -(-N * k // TM) * TM + X * TM
+    M = expert_group.buffer_rows(N * k, X)
     pos = jnp.where(
         key < X, first_row[jnp.minimum(key, X - 1)] + rank, M
     ).astype(jnp.int32)
@@ -491,18 +524,31 @@ def moe_ffn_grouped(
     )
     x_rows = jnp.concatenate([flat, jnp.zeros((1, E), flat.dtype)])[src]
 
-    swiglu, down = _experts_in_place(lp, F)
-
-    def tile(i, y_rows):
-        e = jnp.sum(i >= tile_end, dtype=jnp.int32)  # the tile's expert
-        x = jax.lax.dynamic_slice(x_rows, (i * TM, 0), (TM, E))
-        return jax.lax.dynamic_update_slice(
-            y_rows, down(swiglu(x, e), e), (i * TM, 0)
+    gu, dn = lp.get("we_gateup"), lp["we_down"]
+    if (ops.use_pallas() and isinstance(gu, dict) and isinstance(dn, dict)
+            and expert_group.supports_pallas(E, F)):
+        stacks = (gu["q"], gu["s"], dn["q"], dn["s"])
+        if "expert_layer" not in lp:
+            stacks = tuple(a[None] for a in stacks)
+        cap = expert_group.row_cap(E, F, flat.dtype.itemsize)
+        y_rows = expert_group.expert_group(
+            x_rows, *expert_group.unit_list(blocks, cap, N * k),
+            lp.get("expert_layer", 0), *stacks, cap=cap,
         )
+    else:
+        swiglu, down = _experts_in_place(lp, F)
+        block_end = jnp.cumsum(blocks)
 
-    y_rows = jax.lax.fori_loop(
-        0, n_tiles, tile, jnp.zeros((M, E), jnp.float32)
-    )
+        def block(i, y_rows):
+            e = jnp.sum(i >= block_end, dtype=jnp.int32)  # the block's expert
+            x = jax.lax.dynamic_slice(x_rows, (i * RB, 0), (RB, E))
+            return jax.lax.dynamic_update_slice(
+                y_rows, down(swiglu(x, e), e), (i * RB, 0)
+            )
+
+        y_rows = jax.lax.fori_loop(
+            0, n_blocks, block, jnp.zeros((M, E), jnp.float32)
+        )
     # a token's result: its picks' rows, gated in float32 (a pick of an
     # absent expert reads nothing at weight zero)
     picked = y_rows.at[pos].get(mode="fill", fill_value=0).reshape(N, k, E)
@@ -510,5 +556,5 @@ def moe_ffn_grouped(
     aux = load_balance_aux(probs, idx, cfg.num_experts)
     return (
         out.astype(h.dtype).reshape(B, T, E), aux,
-        pick_stats(here, n_tiles * TM, jnp.sum(tiles > 0)),
+        pick_stats(here, n_blocks * RB, jnp.sum(blocks > 0)),
     )

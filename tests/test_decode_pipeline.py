@@ -190,30 +190,51 @@ def test_pipeline_pool_eviction_flush(params):
     assert survivor and len(survivor[0]) > 0
 
 
-def test_no_compile_after_warmup_serving_sweep(params):
-    """The AOT readiness gate covers the WHOLE serving surface: walking
-    every prefill bucket, the chunked-admission path, the prefix-hit
-    path, every warmed decode chunk size, and the grammar-masked step
-    moves the engine's compile counters by exactly zero."""
+@pytest.mark.parametrize("admission", ["chunked", "whole"])
+def test_no_compile_after_warmup_serving_sweep(params, admission):
+    """The AOT readiness gate covers the WHOLE serving surface, and what the
+    batcher cannot dispatch stays outside it. With chunked admission on (a
+    chunk of 128 rows under buckets to 512) ``warmup`` compiles no
+    whole-prompt graph above the chunk size; with it off every bucket the
+    pool can back. Either way prompts of every bucket's length THROUGH THE
+    BATCHER, a prefix hit behind them, then the engine's own walk of the
+    whole-prompt buckets it compiled, the prefix chunk's path, every warmed
+    decode size and the grammar-masked step move the compile counters by
+    exactly zero."""
+    chunk = 128 if admission == "chunked" else 0
     eng = TPUEngine(
         TINY_TEST.scaled(max_context=512), params, num_slots=2,
         max_context=512, cache_dtype=jnp.float32,
         paged_pool_rows=512, page_size=32, prefix_host_bytes=32 << 20,
     )
     try:
-        eng.warmup(step_sizes=(1, 2, 8, 16), masked_step=True)
+        eng.warmup(step_sizes=(1, 2, 8, 16), masked_step=True,
+                   prefill_chunk=chunk)
+        whole = [b for b in eng.buckets if not chunk or b <= chunk]
+        assert sorted(b for b in eng._prefill_fns if isinstance(b, int)) == whole
+        assert whole[-1] == (128 if chunk else 512)
+        b = ContinuousBatcher(eng, prefill_chunk=chunk)
         before = eng.stats()["xla_compiles"]
         rng = np.random.default_rng(7)
-        # every monolithic prefill bucket the pool can back
-        for b in eng.buckets:
-            n = b // 2 + 1
-            if eng.allocator.blocks_for(n) > eng.allocator.capacity_blocks():
-                continue
-            prompt = [int(t) for t in rng.integers(1, 500, n)]
+        prompts = [[int(t) for t in rng.integers(1, 500, n)]
+                   for n in [bk // 2 + 1 for bk in eng.buckets] + [128, 420]]
+        try:
+            assert b.prefill_chunk == (chunk or None)
+            # the last resubmitted with one more token: a prefix HIT
+            for prompt in prompts + [prompts[-1] + [5]]:
+                h = b.submit(Request(prompt_ids=prompt, max_tokens=3,
+                                     temperature=0.0))
+                assert len(h.tokens()) == 3 and not h.aborted
+            assert eng.stats()["prefix_rows_reused"] > 0
+        finally:
+            b.shutdown()
+        # the engine's own surface: every whole-prompt graph it compiled
+        for bk in whole:
+            prompt = [int(t) for t in rng.integers(1, 500, bk // 2 + 1)]
             eng.prefill(0, prompt, temperature=0.0)
             eng.step(1)
             eng.release(0)
-        # chunked admission (mid + final chunk graphs)
+        # chunked admission at the prefix chunk (mid + final chunk graphs)
         long_prompt = [int(t) for t in rng.integers(1, 500, 420)]
         pc = eng.start_chunked_prefill(0, long_prompt, chunk=eng._prefix_chunk)
         while pc.step() is None:

@@ -89,21 +89,28 @@ def test_chunked_prefill_rejects_non_bucket_chunk(tiny_engine):
         tiny_engine.start_chunked_prefill(0, [1, 2, 3], chunk=48)
 
 
-def test_warmup_compiles_every_bucket_and_step_size():
-    """The readiness gate must leave NO graph uncompiled: a missing prefill
-    bucket or decode step size compiles for seconds on the scheduler thread
-    at first use (the regression behind the 2s agent TTFT: warmup's old
-    4-token prompt bucketed to 16 every iteration, so larger buckets were
-    never compiled)."""
+@pytest.mark.parametrize("chunk", [32, 0])
+def test_warmup_compiles_every_bucket_and_step_size(chunk):
+    """The readiness gate must leave NO graph the batcher dispatches
+    uncompiled: a missing prefill bucket or decode step size compiles for
+    seconds on the scheduler thread at first use (the regression behind the
+    2s agent TTFT: warmup's old 4-token prompt bucketed to 16 every
+    iteration, so larger buckets were never compiled). With chunked
+    admission on, a prompt above the chunk size admits in chunks, so the
+    whole-prompt graphs stop at the chunk size; with it off they are every
+    bucket."""
     params = M.init_params(TINY_TEST, jax.random.PRNGKey(0), dtype=jnp.float32)
     engine = TPUEngine(
         TINY_TEST, params, num_slots=2, max_context=128, cache_dtype=jnp.float32
     )
-    engine.warmup(step_sizes=(1, 2), prefill_chunk=32)
-    assert set(engine._prefill_fns) == set(engine.buckets)
+    engine.warmup(step_sizes=(1, 2), prefill_chunk=chunk)
+    assert engine.admission_chunk(chunk) == chunk
+    assert set(engine._prefill_fns) == {
+        b for b in engine.buckets if not chunk or b <= chunk}
     assert set(engine._step_fns) == {1, 2}
     # chunked-admission graphs: the mid chunk and every final bucket <= 32
-    assert set(engine._chunk_fns) == {(32, False), (16, True), (32, True)}
+    assert set(engine._chunk_fns) == (
+        {(32, False), (16, True), (32, True)} if chunk else set())
 
 
 def test_close_releases_state():

@@ -26,6 +26,7 @@ sys.path.insert(0, REPO)
 from aios_tpu import ops  # noqa: E402
 from aios_tpu.engine import latent, model, moe, paged  # noqa: E402
 from aios_tpu.engine.config import ModelConfig  # noqa: E402
+from aios_tpu.ops import expert_group  # noqa: E402
 from benchmark.harness import reference  # noqa: E402
 from benchmark.harness.manifest import load_file  # noqa: E402
 
@@ -260,7 +261,7 @@ def test_grouped_experts_match_dense_over_held(params):
     picks, the same int8 weights, float32 accumulation in both; they differ
     in the ORDER the experts' parts are added (float32 against bfloat16
     partial sums), 2^-8 of the result: 0.004 of the largest value read. Rows
-    follow the picks that landed here, a tile at a time."""
+    follow the picks that landed here, a row block at a time."""
     lp = jax.tree.map(lambda a: a[1], params["layers"])
     h = jax.random.normal(jax.random.PRNGKey(9), (1, 256, CFG.hidden_size), jnp.bfloat16)
     assert moe.grouped_pays(256, CFG) and not moe.grouped_pays(128, CFG)
@@ -271,9 +272,10 @@ def test_grouped_experts_match_dense_over_held(params):
     total, local, rows, visited = s_grouped.tolist()
     assert (total, local) == tuple(s_dense.tolist()[:2]) == (256 * 4, local)
     assert 0 < local < total and s_dense.tolist()[2:] == [256 * 8, 8]
-    assert visited * moe.GROUP_TILE <= rows and 0 < visited <= 8
-    # a tile of 128 rows an expert that was picked at all; never a dropped pick
-    assert local <= rows <= 8 * moe.GROUP_TILE * 2 and rows % moe.GROUP_TILE == 0
+    RB = expert_group.ROW_BLOCK
+    assert visited * RB <= rows and 0 < visited <= 8
+    # an expert's segment rounded up to a row block; never a dropped pick
+    assert local <= rows < local + 8 * RB and rows % RB == 0
     # and through model.ffn the static token count alone chooses between them
     out, _, stats = model.ffn(h, lp, CFG)
     assert stats.tolist() == s_grouped.tolist()

@@ -7,6 +7,10 @@ held to."""
 
 import dataclasses
 import hashlib
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -536,3 +540,43 @@ def test_a_model_of_one_kind_lowers_to_the_graphs_it_had(name, cfg):
     texts = _lowered(cfg)
     assert [hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts] == PARENT[name]
     assert all("attention_window" not in t and "layer_kind" not in t for t in texts)
+
+
+# -- two processes lower the same text ---------------------------------------------
+
+_LOWER_EVERY_SERVING_GRAPH = """
+import hashlib, json, sys
+sys.path.insert(0, {tests!r})
+import jax
+import test_mellum2 as t
+eng = t._engine(t.model.init_params(t.CFG, jax.random.PRNGKey(0), dtype=t.jnp.float32))
+texts = {{}}
+def lower_only(kind, store, key, jitfn, args):
+    texts[f"{{kind}} {{key}}"] = hashlib.sha256(
+        jitfn.lower(*args).as_text().encode()).hexdigest()[:16]
+eng._compile_aot = lower_only
+eng.warmup()
+print(json.dumps({{"set_order": list(set(t.CFG.period_kinds)), "texts": texts}}))
+"""
+
+
+def test_a_stack_of_two_kinds_lowers_to_the_same_text_whatever_the_hash_seed():
+    """Every graph ``warmup`` compiles for the tiny stack of two kinds, lowered
+    in two processes whose hash seeds put a SET of the two kinds in opposite
+    orders: the same text, so a compile cache keyed by it hits on every start
+    (``model.rope_by_kind`` walks the period's own order)."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    runs = []
+    for seed in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join(
+                       [os.path.dirname(here), os.environ.get("PYTHONPATH", "")]))
+        out = subprocess.run(
+            [sys.executable, "-c", _LOWER_EVERY_SERVING_GRAPH.format(tests=here)],
+            env=env, capture_output=True, text=True, timeout=600)
+        assert out.returncode == 0, out.stderr[-2000:]
+        runs.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    one, two = runs
+    assert one["set_order"] != two["set_order"], "the seeds no longer tell"
+    assert {name.split()[0] for name in one["texts"]} >= {"prefill", "chunk", "step"}
+    assert one["texts"] == two["texts"]

@@ -3,7 +3,9 @@ routing: softmax top-2 of 8, every expert held. Prefill token counts run each
 expert over the rows routed to it only, reading the stacked expert weights in
 place at (layer, expert); the dense path (every expert over every token) is
 the reference, and which of the two serves follows the static shapes alone
-(moe.grouped_pays).
+(moe.grouped_pays). Below, the chip's form of the same path (ONE kernel a
+layer call, ops/expert_group.py) runs interpreted against the loop and the
+dense path at the four MoE configurations' shapes of routing.
 
 These live beside tests/test_moe.py and not in it because that module is the
 slow tier as a whole (its ``pytestmark``): the cases here are small and run in
@@ -18,10 +20,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from aios_tpu import ops
 from aios_tpu.engine import model as M
 from aios_tpu.engine import moe
 from aios_tpu.engine.config import ModelConfig
 from aios_tpu.engine.engine import TPUEngine
+from aios_tpu.ops import expert_group
 
 CFG = ModelConfig(
     name="tiny-top2of8", vocab_size=512, hidden_size=64, intermediate_size=128,
@@ -95,8 +99,8 @@ def test_grouped_matches_dense_at_top2_of_8(leaves, n_tok, routing):
     """Grouped against dense on each of three stacked layers read at
     ``expert_layer`` 0, 1, 2: the same picks and weights, float32
     accumulation in both, a different order of adding the experts' parts.
-    130 and 300 tokens leave a tile part-filled; forced routing gives one
-    expert every token (more than one tile of rows) and another none."""
+    130 and 300 tokens leave a row block part-filled; forced routing gives
+    one expert every token and another none."""
     layers = _layers(leaves)
     if routing == "one-takes-all":
         layers = _forced(layers)
@@ -112,7 +116,8 @@ def test_grouped_matches_dense_at_top2_of_8(leaves, n_tok, routing):
         assert total == local == 2 * n_tok == s_dense.tolist()[0]
         assert s_dense.tolist()[2:] == [8 * n_tok, 8]
         counts = np.bincount(np.asarray(idx).ravel(), minlength=8)
-        assert rows == int(np.sum(-(-counts // moe.GROUP_TILE)) * moe.GROUP_TILE)
+        RB = expert_group.ROW_BLOCK
+        assert rows == int(np.sum(-(-counts // RB)) * RB)
         assert visited == np.count_nonzero(counts)
         if routing == "one-takes-all":
             assert counts[TAKES_ALL] == n_tok and counts[TAKES_NONE] == 0
@@ -146,7 +151,7 @@ PANGU = dataclasses.replace(
 
 
 @pytest.mark.parametrize("cfg, n_tok, grouped", [
-    (MIXTRAL, 512, True),   # 1,024 picks + 8 part tiles against 4,096 rows
+    (MIXTRAL, 512, True),   # 1,024 picks + 8 part passes against 4,096 rows
     (MIXTRAL, 256, True),   # 512 + 1,024 against 2,048
     (MIXTRAL, 128, False),  # 256 + 1,024 against 1,024
     (MIXTRAL, 8, False),    # a decode step
@@ -174,7 +179,7 @@ def _greedy(engine, prompt, chunk):
 
 def test_chunked_prefill_through_grouped_yields_the_dense_tokens(monkeypatch):
     """A 300-token prompt admitted in a 256-token chunk (grouped: 512 picks
-    and 8 part tiles against 2,048 rows) and a final 64-token bucket (dense),
+    and 8 part passes against 2,048 rows) and a final 64-token bucket (dense),
     then greedy decode (the visit path: the one live slot's two experts a
     layer over both rows): the tokens of an engine for which the grouped path
     never pays, so that every prefill graph of it runs dense.
@@ -355,3 +360,213 @@ def test_router_counters_come_back_from_every_decode_graph_by_the_next_scan_disp
         assert eng.moe_picks_local == eng.moe_picks_total
     finally:
         eng.close()
+
+
+# -- the chip's form: one kernel a layer call (ops/expert_group.py) ---------
+
+WIDE = dataclasses.replace(  # lane-aligned widths: the kernel's condition
+    CFG, name="wide-top2of8", hidden_size=128, moe_intermediate_size=128,
+    num_layers=2, head_dim=32)
+KINDS = {
+    # Mixtral: softmax top-2 of 8, every expert held
+    "top2of8": WIDE,
+    # the Pangu share: sigmoid x 2.5 top-8 of 256, a sixteenth held from 48 on
+    "16of256-from48": dataclasses.replace(
+        WIDE, name="wide-16of256", num_experts=256, experts_held=16,
+        first_expert=48, num_experts_per_tok=8, moe_scoring="sigmoid",
+        routed_scaling_factor=2.5),
+    # xing4: sigmoid top-4 of 64 under a selection bias
+    "top4of64-bias": dataclasses.replace(
+        WIDE, name="wide-top4of64", num_experts=64, num_experts_per_tok=4,
+        moe_scoring="sigmoid", routed_scaling_factor=2.0),
+    # mellum2: softmax top-8 of 64, the stacks handed whole (`expert_layer`)
+    "top8of64-whole": dataclasses.replace(
+        WIDE, name="wide-top8of64", num_experts=64, num_experts_per_tok=8),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_layer(kind: str, forced: bool):
+    """One expert layer in the fused int8 serving layout (for the "whole"
+    kind two layers stacked, read at ``expert_layer`` 1). ``forced``: held
+    expert TAKES_ALL gets a pick of every token and TAKES_NONE none (the
+    rows' first value is a constant 4)."""
+    cfg = KINDS[kind]
+    E, F, X, Xr = cfg.hidden_size, cfg.expert_dim, cfg.held_experts, cfg.num_experts
+    ks = jax.random.split(jax.random.PRNGKey(len(kind)), 5)
+    router = jax.random.normal(ks[0], (E, Xr), jnp.float32) * 0.05
+    if forced:
+        # logits of +-12 beside the others' unit spread: decided, and the
+        # other picks still differ by token
+        router = router.at[0, cfg.first_expert + TAKES_ALL].set(3.0)
+        router = router.at[0, cfg.first_expert + TAKES_NONE].set(-3.0)
+    whole = kind.endswith("whole")
+    L = 2 if whole else 1
+
+    def w(k, *shape):
+        a = (jax.random.normal(k, (L,) + shape, jnp.float32) * 0.08)
+        q, s = ops.quantize_int8(a.astype(jnp.bfloat16), axis=-2)
+        return {"q": q, "s": s}
+
+    lp = {"w_router": router, "we_gateup": w(ks[1], X, E, 2 * F),
+          "we_down": w(ks[2], X, F, E)}
+    if kind == "top4of64-bias":
+        lp["router_bias"] = jax.random.normal(ks[3], (Xr,), jnp.float32) * 0.02
+    if whole:
+        return {**lp, "expert_layer": jnp.int32(1)}
+    return {k: (jax.tree.map(lambda a: a[0], v) if k.startswith("we_") else v)
+            for k, v in lp.items()}
+
+
+def _one_layer(lp):
+    """The tree the dense path reads: one layer's ``[X, in, out]`` leaves."""
+    if "expert_layer" not in lp:
+        return lp
+    return {k: (jax.tree.map(lambda a: a[1], v) if k.startswith("we_") else v)
+            for k, v in lp.items() if k != "expert_layer"}
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The chip's choice of form on the CPU: the kernel, interpreted."""
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(
+        expert_group, "expert_group",
+        functools.partial(expert_group.expert_group, interpret=True))
+
+
+@pytest.mark.parametrize("routing", ["free", "one-takes-all"])
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_the_kernel_equals_the_loop_and_the_dense_path(
+        kind, routing, request, monkeypatch):
+    """300 tokens (no expert's count a multiple of the row block but by
+    chance) through the loop, the interpreted kernel and the dense path:
+    the same picks, the same layout, the same counters, which equal a count
+    by hand from the router alone. Forced routing gives one held expert all
+    300 tokens, more rows than one unit holds (``row_cap`` held to 256
+    here: two products of 128 rows a weight block, then a second unit, a
+    second stream of that expert), and another none (no unit, nothing
+    read)."""
+    monkeypatch.setattr(expert_group, "ROW_CAP_MAX", 256)
+    cfg, forced = KINDS[kind], routing == "one-takes-all"
+    lp = _wide_layer(kind, forced)
+    n_tok, RB = 300, expert_group.ROW_BLOCK
+    h = jax.random.normal(jax.random.PRNGKey(n_tok), (1, n_tok, 128), jnp.bfloat16)
+    h = h.at[..., 0].set(4.0)
+    run = lambda: jax.jit(  # noqa: E731 - traced anew under the fixture
+        lambda h, lp: moe.moe_ffn_grouped(h, lp, cfg))(h, lp)
+    loop, _, s_loop = run()
+    request.getfixturevalue("interpreted")
+    kern, _, s_kern = run()
+    want, _, s_dense = moe.moe_ffn_dense(h, _one_layer(lp), cfg, with_stats=True)
+    loop, kern, want = (np.asarray(a, np.float32) for a in (loop, kern, want))
+    tol = TOL * np.abs(want).max()
+    assert np.abs(loop - want).max() < tol
+    assert np.abs(kern - want).max() < tol
+    assert np.abs(kern - loop).max() < tol / 2  # cast for cast the same
+    # the counters by hand
+    _, _, idx = moe.route(h[0], lp["w_router"], cfg, lp.get("router_bias"))
+    rel = np.asarray(idx).ravel() - cfg.first_expert
+    counts = np.bincount(rel[(rel >= 0) & (rel < cfg.held_experts)],
+                         minlength=cfg.held_experts)
+    by_hand = [n_tok * cfg.num_experts_per_tok, int(counts.sum()),
+               int(np.sum(-(-counts // RB)) * RB), np.count_nonzero(counts)]
+    assert s_loop.tolist() == s_kern.tolist() == by_hand
+    assert s_dense.tolist()[:2] == by_hand[:2]
+    assert (counts % RB != 0).any()
+    cap = expert_group.row_cap(128, 128)
+    units = expert_group.unit_list(
+        jnp.asarray(-(-counts // RB), jnp.int32), cap, by_hand[0])
+    if forced:
+        assert counts[TAKES_ALL] == n_tok > cap and counts[TAKES_NONE] == 0
+        assert int(units[3]) == by_hand[3] + 1  # one expert takes two units
+        both = np.asarray(units[0])[:int(units[3])] == TAKES_ALL
+        assert np.asarray(units[2])[:int(units[3])][both].tolist() == [
+            cap // RB, -(-(n_tok - cap) // RB)]
+    else:
+        assert int(units[3]) == by_hand[3]
+
+
+def test_units_are_an_experts_segment_cut_at_the_row_cap():
+    """Blocks (3, 0, 9, 1) at a cap of 4 blocks: expert 0 one unit, expert 1
+    none, expert 2 three (4 + 4 + 1 blocks, each starting where the last
+    ended), expert 3 one; then the last unit repeated. No block at all: no
+    unit. The cap follows from the widths: 256 rows wherever half the
+    kernel's VMEM holds them (all four configurations' widths)."""
+    RB = expert_group.ROW_BLOCK
+    e, first, nb, n = expert_group.unit_list(
+        jnp.asarray([3, 0, 9, 1], jnp.int32), 4 * RB, 13 * RB)
+    assert int(n) == 5
+    assert e.tolist()[:6] == [0, 2, 2, 2, 3, 3]
+    assert first.tolist()[:6] == [0, 3, 7, 11, 12, 12]
+    assert nb.tolist()[:6] == [3, 4, 4, 1, 1, 1]
+    e, first, nb, n = expert_group.unit_list(
+        jnp.zeros((4,), jnp.int32), 4 * RB, 13 * RB)
+    assert int(n) == 0 and nb.tolist() == [0] * len(nb)
+    blocks, first_row = expert_group.segments(jnp.asarray([1, 0, 33, 64]))
+    assert blocks.tolist() == [1, 0, 2, 2]
+    assert first_row.tolist() == [0, RB, RB, 3 * RB]
+    for E, F in ((7680, 2048), (3584, 1024), (2304, 896)):
+        assert expert_group.row_cap(E, F) == 512
+    assert expert_group.row_cap(4096, 14336) == 256
+    assert expert_group.row_cap(4096, 8 * 14336) == 128  # one product at least
+
+
+@pytest.mark.parametrize("rests", ["1-to-64", "65-to-127"])
+def test_a_segments_rest_below_a_pass_has_its_own_product_or_one_more_pass(rests):
+    """The kernel alone, interpreted, over segments whose rows beyond the
+    whole passes of 128 number 32 and 64 (a product of their own, TAIL) or
+    96 (one more pass), with and without a whole pass before the rest, an
+    expert with no pick between them and the last expert of the stack: row
+    for row the loop's products over the same layout."""
+    E = F = 128
+    X, RB = 6, expert_group.ROW_BLOCK
+    counts = {"1-to-64": [20, 0, 64, 129, 190, 1],    # rests 32, -, 64, 32, 64, 32
+              "65-to-127": [70, 0, 96, 200, 353, 65]}[rests]  # 96, -, 96, 96, 96, 96
+    blocks, first_row = expert_group.segments(jnp.asarray(counts, jnp.int32))
+    rest_rows = (np.asarray(blocks) * RB) % expert_group.PASS
+    want_rests = {0, 32, 64} if rests == "1-to-64" else {0, 96}
+    assert set(rest_rows.tolist()) == want_rests
+    picks = int(sum(counts))
+    M_rows = expert_group.buffer_rows(picks, X)
+    ks = jax.random.split(jax.random.PRNGKey(sum(counts)), 3)
+    x = jax.random.normal(ks[0], (M_rows, E), jnp.bfloat16)
+
+    def w(k, *shape):
+        a = jax.random.normal(k, (2,) + shape, jnp.float32) * 0.08
+        q, s = ops.quantize_int8(a.astype(jnp.bfloat16), axis=-2)
+        return {"q": q, "s": s}
+
+    lp = {"we_gateup": w(ks[1], X, E, 2 * F), "we_down": w(ks[2], X, F, E),
+          "expert_layer": jnp.int32(1)}
+    cap = 256
+    got = expert_group.expert_group(
+        x, *expert_group.unit_list(blocks, cap, picks), lp["expert_layer"],
+        lp["we_gateup"]["q"], lp["we_gateup"]["s"], lp["we_down"]["q"],
+        lp["we_down"]["s"], cap=cap, interpret=True)
+    swiglu, down = moe._experts_in_place(lp, F)
+    got = np.asarray(got, np.float32)
+    for e, (n, r0) in enumerate(zip(counts, np.asarray(first_row).tolist())):
+        if not n:
+            continue
+        rows = slice(r0, r0 + n)
+        want = np.asarray(down(swiglu(x[rows], e), e), np.float32)
+        assert np.abs(got[rows] - want).max() < TOL / 2 * np.abs(want).max(), e
+    if rests == "65-to-127":  # 353 rows: a second unit of that expert
+        assert int(expert_group.unit_list(blocks, cap, picks)[3]) == 6
+
+
+@pytest.mark.parametrize("picks, X", [(2048, 64), (300, 8), (4097, 16), (7, 3)])
+def test_the_two_level_rank_equals_a_cumsum_over_all_the_picks(picks, X):
+    """``moe._ranks`` on random keys, some of them ``X`` (a pick on an expert
+    held elsewhere): each pick's count of earlier picks on its own expert and
+    each expert's total, as a one-hot cumsum over all the picks gives them;
+    a pick on no held expert ranks 0."""
+    key = jax.random.randint(jax.random.PRNGKey(picks), (picks,), 0, X + 1)
+    rank, counts = jax.jit(moe._ranks, static_argnums=1)(key, X)
+    onehot = np.eye(X + 1, dtype=np.int64)[np.asarray(key)][:, :X]
+    want = ((np.cumsum(onehot, axis=0) - onehot) * onehot).sum(-1)
+    assert rank.dtype == counts.dtype == jnp.int32
+    assert np.asarray(rank).tolist() == want.tolist()
+    assert np.asarray(counts).tolist() == onehot.sum(0).tolist()
+    assert (np.asarray(key) == X).any() or picks < 16

@@ -537,6 +537,11 @@ def test_aot_grouped_experts_are_read_in_place(
         if layer_elems & set(res) or (op == "copy" and stack_elems & set(res))
     ]
     assert made == [], f"results as large as a layer's experts: {made[:4]}"
+    # ONE kernel a layer call (the layer scan's body holds it once), and no
+    # tile of 128 rows streams an expert any more
+    text = compiled.as_text()
+    assert len(re.findall(r"%expert_group[.\d]* = ", text)) == 1
+    assert f"bf16[128,{2 * cfg.expert_dim}]" not in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < layer_bytes, (
         f"{mem.temp_size_in_bytes / 1e9:.2f} GB of temporaries against "
@@ -544,6 +549,61 @@ def test_aot_grouped_experts_are_read_in_place(
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert live < 15.75e9, f"{live / 1e9:.2f} GB live"
+
+
+# --- a prefill chunk streams each touched expert once (PR 37) ---------------
+
+
+@pytest.mark.parametrize("config_name, arch_file, context", [
+    ("mixtral-8x7b-int8-d6.json", "mistral.py", 4096),  # 4096 x 14336
+    ("openpangu-ultra-moe-int8-ep16-d5.json", "pangu_ultra_moe.py", 16384),
+    ("xing4-29b-a4b-int8-d13.json", "xing4.py", 8192),  # 3584 x 1024
+    ("mellum2-12b-a2.5b-int8-d20.json", "mellum.py", 16384),  # 2304 x 896
+])
+def test_aot_prefill_chunks_stream_each_touched_expert_once(
+    rep_sharding, config_name, arch_file, context
+):
+    """The grouped kernel alone (ops/expert_group.py: a traced grid bound,
+    the stacks indexed at ``[l, expert[i]]`` by the block specs, the rows
+    copied in and out by the kernel itself) at each MoE configuration's
+    widths, for the picks of a 512-token chunk and of a 256-token bucket:
+    it compiles for the v5e in seconds (one rolled product of 128 rows, no
+    variant by row count), is ONE custom call, and makes nothing the size of
+    a layer's experts: its only operands of that size are the stacks
+    themselves, handed whole. The composed chunk graphs are
+    ``-k grouped_experts`` (Mixtral, the Pangu share), ``-k stream_mixes``
+    (xing4) and ``-k two_kinds`` (mellum2, four layers a period)."""
+    import time
+
+    from aios_tpu.ops import expert_group as eg
+
+    cfg, shapes = _bench_model(config_name, arch_file, context)
+    rep = rep_sharding
+    layers = jax.tree.map(lambda a: sds(rep, a.shape, a.dtype), shapes)["layers"]
+    E, F, X, k = (cfg.hidden_size, cfg.expert_dim, cfg.held_experts,
+                  cfg.num_experts_per_tok)
+    assert eg.supports_pallas(E, F)
+    cap = eg.row_cap(E, F)
+    assert cap % eg.PASS == 0 and eg.PASS % eg.ROW_BLOCK == 0
+    stacks = [layers[n][m] for n in ("we_gateup", "we_down") for m in ("q", "s")]
+    layer_elems = {int(np.prod(a.shape[1:])) for a in stacks[::2]}
+    i32 = lambda *shape: sds(rep, shape, jnp.int32)  # noqa: E731
+    for n_tok in (512, 256):
+        M = eg.buffer_rows(n_tok * k, X)
+        U = X + M // cap
+        t0 = time.monotonic()
+        compiled = jax.jit(functools.partial(eg.expert_group, cap=cap)).lower(
+            sds(rep, (M, E), jnp.bfloat16), i32(U), i32(U), i32(U), i32(), i32(),
+            *stacks).compile()
+        assert time.monotonic() - t0 < 30, "the kernel's compile is a set-up cost"
+        text = compiled.as_text()
+        assert len(re.findall(r"%expert_group[.\d]* = ", text)) == 1
+        made = [(op, res) for op, res in _hlo_results(text)
+                if layer_elems & set(res)]
+        assert made == [], f"results as large as a layer's experts: {made[:4]}"
+        mem = compiled.memory_analysis()
+        # the rows go in and the result comes out as they are: no copy
+        assert mem.temp_size_in_bytes < 1 << 20
 
 
 # --- a decode step visits the experts its live rows picked (PR 32) ----------
@@ -696,6 +756,9 @@ def test_aot_stream_mixes_compile_and_fit(rep_sharding, monkeypatch):
         text = compiled.as_text()
         assert len(re.findall(r"%hc_pre[.\d]* = ", text)) == pre_calls, name
         assert len(re.findall(r"%hc_post[.\d]* = ", text)) == 4, name
+        # the expert layers' scan: one grouped kernel a prefill graph (PR 37)
+        assert len(re.findall(r"%expert_group[.\d]* = ", text)) == (
+            0 if name == "decode-step" else 1), name
         mem = compiled.memory_analysis()
         live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
@@ -777,6 +840,9 @@ def test_aot_two_kinds_compile_and_fit(rep_sharding, monkeypatch):
         if name == "decode-step":
             assert len(re.findall(r"%window_decode_attention[.\d]* = ", text)) == 3
             assert len(re.findall(r"%paged_decode_attention[.\d]* = ", text)) == 1
+        else:  # the period's four expert layers: a grouped kernel each (PR 37)
+            assert len(re.findall(r"%expert_group[.\d]* = ", text)) == 4, name
+            assert "bf16[128,1792]" not in text, name
         mem = compiled.memory_analysis()
         live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
