@@ -401,6 +401,7 @@ class TPUEngine:
         kv_sink_pages: Optional[int] = None,  # live leading (sink) pages
         kv_window_pages: Optional[int] = None,  # live trailing window pages
         seq_prefill_min: Optional[int] = None,  # sp-sharded prefill floor rows
+        phases: Optional[flightrec.Phases] = None,  # LoadModel's; None -> own
     ) -> None:
         self.cfg = cfg
         self.num_slots = num_slots
@@ -415,8 +416,9 @@ class TPUEngine:
         ) or (self.max_context,)
         self._lock = make_lock("engine")
         # the dispatch bodies' phases (flightrec.PHASES); the batcher
-        # that drives this engine adds its own to the same object
-        self.phases = flightrec.Phases(cfg.name)
+        # that drives this engine adds its own to the same object, and
+        # LoadModel, which made it, the set-up's (flightrec.SETUP_PHASES)
+        self.phases = phases if phases is not None else flightrec.Phases(cfg.name)
         self.plan = shardings
         # normalize the quantize knob to a mode: True -> int8 (the measured
         # single-chip default), "int4" -> packed-nibble group-wise int4
@@ -1132,6 +1134,7 @@ class TPUEngine:
         # exists to prevent) is visible instead of a mystery latency spike.
         self.compile_events = 0
         self.compile_seconds = 0.0
+        self.warmup_trace_cpu_seconds = 0.0
         # Device-time attribution (obs/devprof.py): per-graph cost
         # ledger + sampled dispatch timing, OFF by default — the hot
         # paths pay one attribute None-check, the faults/ pattern. Read
@@ -2265,20 +2268,35 @@ class TPUEngine:
                      example_args) -> None:
         """AOT-compile one graph against the live avals of
         ``example_args`` and store the compiled executable where the
-        dispatch path looks it up. lower()+compile() traces but never
+        dispatch path looks it up. trace().lower().compile() never
         executes — no device state moves, nothing donates — so the whole
         serving surface can warm behind the readiness gate in compile
-        time alone. Counts the same compile-event accounting a lazy
-        first dispatch would. On the TPU a graph that cannot lower or
-        compile (Mosaic rejection, HBM overflow) raises, so LoadModel fails
-        instead of reporting ``ready`` for a model whose first request
-        would die; an intended CPU run keeps the lazy instrumented wrapper
-        (the first real dispatch then compiles, visibly)."""
+        time alone. Each stage is a span of its own (flightrec
+        ``warmup.trace`` / ``.lower`` / ``.compile``), their sum is the
+        graph's ``xla_compile_s``, and one ``compile`` event on the model
+        lane says which graph it was. Counts the same compile-event
+        accounting a lazy first dispatch would. On the TPU a graph that
+        cannot lower or compile (Mosaic rejection, HBM overflow) raises, so
+        LoadModel fails instead of reporting ``ready`` for a model whose
+        first request would die; an intended CPU run keeps the lazy
+        instrumented wrapper (the first real dispatch then compiles,
+        visibly)."""
         if key in store:
             return
-        t0 = time.perf_counter()
+        ph, label = self.phases, str(key)
+        args = {"kind": kind, "key": label}  # ride on the trace annotations
+        requests0, hits0 = flightrec.compile_cache()
         try:
-            fn = jitfn.lower(*example_args).compile()
+            # jitfn.lower(*args) is trace(*args).lower() (jax 0.9.0
+            # pjit.jit_lower): the same text, so the same cache entry
+            cpu0 = time.thread_time()
+            with ph.phase("warmup.trace", **args) as traced:
+                staged = jitfn.trace(*example_args)
+            with ph.phase("warmup.lower", **args) as lowered:
+                staged = staged.lower()
+            cpu = time.thread_time() - cpu0
+            with ph.phase("warmup.compile", **args) as compiled:
+                fn = staged.compile()
         except Exception:  # noqa: BLE001 - lazy compile still serves on CPU
             if backend.on_tpu():
                 log.error("AOT compile failed for %s graph %r", kind, key)
@@ -2289,13 +2307,26 @@ class TPUEngine:
             )
             store[key] = self._instrument_compile(jitfn, kind)
             return
-        dt = time.perf_counter() - t0
+        dt = traced.dt + lowered.dt + compiled.dt
         obs.ENGINE_XLA_COMPILES.labels(model=self.cfg.name, kind=kind).inc()
         self.compile_events += 1
         self.compile_seconds += dt
+        self.warmup_trace_cpu_seconds += cpu
         obs.ENGINE_XLA_COMPILE_SECONDS.labels(
             model=self.cfg.name, kind=kind
         ).observe(dt)
+        requests, hits = flightrec.compile_cache()
+        requests, hits = requests - requests0, hits - hits0
+        flightrec.RECORDER.model_event(
+            # "graph": an event's own kind is "compile"
+            self.cfg.name, "compile", graph=kind, key=label,
+            trace_ms=round(traced.dt * 1e3, 3),
+            lower_ms=round(lowered.dt * 1e3, 3),
+            compile_ms=round(compiled.dt * 1e3, 3),
+            cpu_ms=round(cpu * 1e3, 3),
+            # the persistent cache served every compile this graph asked of it
+            cache_hit=bool(requests) and hits == requests,
+        )
         if self._devprof is not None:
             # ledger registration: the compiled executable's static
             # cost_analysis (FLOPs + bytes per dispatch) + compile time,
@@ -3737,7 +3768,16 @@ class TPUEngine:
             ) if self.num_slots else 0.0,
             "xla_compiles": self.compile_events,
             "xla_compile_s": round(self.compile_seconds, 2),
+            # thread CPU seconds inside the warmup.trace and warmup.lower
+            # spans: their wall seconds less this is what the compiling
+            # thread stood off the processor (the GIL, I/O)
+            "warmup_trace_cpu_seconds": self.warmup_trace_cpu_seconds,
         }
+        # JAX's persistent compile cache, process-wide (the pool reports
+        # them once, not summed over replicas): misses = requests - hits
+        out["compile_cache_requests"], out["compile_cache_hits"] = (
+            flightrec.compile_cache()
+        )
         if self.spec_rounds:
             out["spec_rounds"] = self.spec_rounds
             # mean tokens emitted per slot per verify round (1.0 = nothing

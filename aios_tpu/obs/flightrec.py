@@ -30,7 +30,10 @@ The scheduler loop has a record of its own beside the requests':
 ``Phases`` closes each phase of a batcher tick and of an engine dispatch
 body (the closed list ``PHASES``) into a ``jax.profiler.TraceAnnotation``,
 always-on counters and a bounded per-model ring — the "scheduler" track
-of ``/debug/trace`` — all on ``time.monotonic()``.
+of ``/debug/trace`` — all on ``time.monotonic()``. The same object closes
+the model's set-up before the loop runs (``SETUP_PHASES``: ``LoadModel``
+from its entry to ready, and the trace, lower and compile stages of each
+graph compiled behind the readiness gate).
 
 Export surfaces (obs/http.py): ``/debug/requests`` (recent timelines as
 JSON), ``/debug/trace`` (Chrome trace-event / Perfetto JSON),
@@ -103,6 +106,10 @@ EVENT_KINDS = (
     # a whole decode dispatch, or a dispatch over twice its running
     # median (model lane; engine/batching.py _tick_done)
     "stall",
+    # "compile": one graph compiled ahead of time behind the readiness
+    # gate (model lane; engine/engine.py _compile_aot): which graph, and
+    # the milliseconds of its trace, lower and compile stages
+    "compile",
 )
 
 # Shed causes — THE closed enum; serving/admission.py raises with these
@@ -170,6 +177,58 @@ PHASES = (
 # phases that wait on the device, not on the host: a tick's host time
 # leaves them out wherever they nest
 DEVICE_WAIT_PHASES = ("engine.prefill", "engine.readback", "batcher.consume")
+
+# Set-up phases — the second closed list, closed by the same ``Phases``
+# object before the loop runs. load.* wrap runtime/model_manager.py
+# ``load_model``: model (its whole, entry to STATE_READY, parent of the
+# rest), weights (reading or generating, quantizing and placing the
+# parameters), engine (``TPUEngine(...)`` until params and state are on
+# the device, once a replica), warmup (``engine.warmup``, once a
+# replica), attach (``ReplicaPool(...)``: the batchers attach and compile
+# their own chunk and round sizes). warmup.* wrap the three stages of
+# ``engine._compile_aot``, one triple a graph: trace (Python to a jaxpr),
+# lower (jaxpr to StableHLO), compile (XLA / Mosaic, or the persistent
+# cache's fetch).
+SETUP_PHASES = (
+    "load.model", "load.weights", "load.engine", "load.warmup", "load.attach",
+    "warmup.trace", "warmup.lower", "warmup.compile",
+)
+# every name a ``Phases`` object closes
+_ALL_PHASES = PHASES + SETUP_PHASES
+
+# JAX's own events of its persistent compilation cache (jax 0.9.0
+# jax/_src/compiler.py): every compile that may use the cache, and those
+# of them the cache served; each with its place in _cache_counts
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": 0,
+    "/jax/compilation_cache/cache_hits": 1,
+}
+# process-wide [requests, hits], counted by one listener (compile_cache())
+_cache_counts = [0, 0]
+_cache_lock = threading.Lock()
+_cache_listening = False
+
+
+def _on_jax_event(event: str, **kwargs) -> None:
+    at = _CACHE_EVENTS.get(event)
+    if at is not None:
+        with _cache_lock:
+            _cache_counts[at] += 1
+
+
+def compile_cache() -> Tuple[int, int]:
+    """(requests, hits) of JAX's persistent compilation cache in this
+    process since the first call, which registers the one listener: misses
+    are requests less hits."""
+    global _cache_listening
+    with _cache_lock:
+        if not _cache_listening:
+            _cache_listening = True
+            # imported here: the other services import obs and stay JAX-free
+            from jax import monitoring
+
+            monitoring.register_event_listener(_on_jax_event)
+        return _cache_counts[0], _cache_counts[1]
 
 
 def abort_cause(reason: str) -> str:
@@ -643,7 +702,7 @@ class _Span:
     """One open phase. ``Phases.begin`` returns it; leaving a ``with``
     block or ``Phases.end`` closes it and leaves its seconds in ``dt``."""
 
-    __slots__ = ("owner", "name", "t0", "dt", "ann", "inner", "root")
+    __slots__ = ("owner", "name", "t0", "dt", "ann", "ann_dt", "inner", "root")
 
     def __init__(self, owner: "Phases", name: str) -> None:
         self.owner = owner
@@ -660,16 +719,19 @@ class _Span:
 
 
 class Phases:
-    """What the scheduler loop of one model replica is doing, by phase.
+    """What one model replica is doing, by phase: its set-up (the closed
+    list ``SETUP_PHASES``) and then its scheduler loop (``PHASES``).
 
-    The engine makes one and its batcher shares it. Closing a phase
-    writes to three places: a ``jax.profiler.TraceAnnotation`` (so the
-    phase lies in the host plane of the profiler's trace, on the same
-    clock as the device's programs; a flag test when no profile is being
-    taken), the always-on ``seconds`` / ``counts`` per phase (summed into
-    ``ServingPool.stats()``), and — recorder enabled — the model's
-    bounded ring of (name, t0, t1) on ``time.monotonic()`` that
-    ``chrome_trace`` renders as the "scheduler" track.
+    ``ModelManager.load_model`` makes one a replica and hands it to the
+    engine (an engine given none makes its own), and the engine's batcher
+    shares it. Closing a phase writes to three places: a
+    ``jax.profiler.TraceAnnotation`` (so the phase lies in the host plane
+    of the profiler's trace, on the same clock as the device's programs; a
+    flag test when no profile is being taken), the always-on ``seconds`` /
+    ``counts`` per phase (summed into ``ServingPool.stats()``), and —
+    recorder enabled — the model's bounded ring of (name, t0, t1) on
+    ``time.monotonic()`` that ``chrome_trace`` renders as the "scheduler"
+    track.
 
     On the scheduler thread (``tick_thread``) phases also nest, and
     each closes with its OWN seconds, its children's left out, into one
@@ -679,29 +741,43 @@ class Phases:
     ``batcher.dispatch``. The batcher reads and resets both once a tick
     (``take_tick``)."""
 
-    def __init__(self, model: str) -> None:
+    def __init__(self, model: str = "") -> None:
         # imported here: the other services import obs and stay JAX-free
         from jax.profiler import TraceAnnotation
 
         self._annotation = TraceAnnotation
-        self._ring = RECORDER.phase_ring(model)
+        # LoadModel opens its first span before the weights have said what
+        # the model calls itself: no ring until ``named``
+        self._ring = RECORDER.phase_ring(model) if model else None
         # the scheduler thread, the pipelined dispatch worker and direct
         # engine callers all close phases: the two sums share one lock
         self._sums = threading.Lock()
-        self.seconds: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
-        self.counts: Dict[str, int] = dict.fromkeys(PHASES, 0)
+        self.seconds: Dict[str, float] = dict.fromkeys(_ALL_PHASES, 0.0)
+        self.counts: Dict[str, int] = dict.fromkeys(_ALL_PHASES, 0)
+        # seconds inside the profiler's own calls (a TraceAnnotation's
+        # __enter__ and __exit__), which lie outside every span: what the
+        # spans cost while a profile is being taken
+        self.annotation_seconds = 0.0
         self.tick_thread = 0
         self.tick_host: Dict[str, float] = {}
         self.tick_dispatch: Dict[str, float] = {}
         self._stack: List[_Span] = []
 
+    def named(self, model: str) -> None:
+        """Write the ring of ``model`` from here on: the key of its model
+        lane and request timelines (``cfg.name``), so that
+        ``/debug/trace?model=`` finds one model under one name."""
+        self._ring = RECORDER.phase_ring(model)
+
     def begin(self, name: str, **args) -> _Span:
-        """Open phase ``name`` (one of PHASES). ``args`` ride on the
-        trace annotation only."""
+        """Open phase ``name`` (one of PHASES or SETUP_PHASES). ``args``
+        ride on the trace annotation only."""
         span = _Span(self, name)
         if self._annotation.is_enabled():
+            t = time.monotonic()
             span.ann = self._annotation(name, **args)
             span.ann.__enter__()
+            span.ann_dt = time.monotonic() - t
         if threading.get_ident() == self.tick_thread:
             stack = self._stack
             span.root = stack[0].name if stack else name
@@ -716,11 +792,14 @@ class Phases:
         dt = span.dt = t1 - span.t0
         name = span.name
         with self._sums:
-            self.seconds[name] += dt  # KeyError: not one of PHASES
+            self.seconds[name] += dt  # KeyError: in neither closed list
             self.counts[name] += 1
         if span.ann is not None:
+            t = time.monotonic()
             span.ann.__exit__(None, None, None)
-        if RECORDER.enabled:
+            with self._sums:
+                self.annotation_seconds += span.ann_dt + time.monotonic() - t
+        if RECORDER.enabled and self._ring is not None:
             self._ring.append((name, span.t0, t1))
         if span.root is not None:
             stack = self._stack
@@ -750,14 +829,15 @@ class Phases:
         return [
             {"name": name, "ago_ms": round((now - t0) * 1e3, 3),
              "dur_ms": round((t1 - t0) * 1e3, 3)}
-            for name, t0, t1 in list(self._ring)[-n:]
+            for name, t0, t1 in list(self._ring or ())[-n:]
         ]
 
     def stats(self) -> Dict[str, float]:
         out: Dict[str, float] = {}
-        for name in PHASES:
+        for name in _ALL_PHASES:
             out[f"phase_{name}_seconds"] = self.seconds[name]
             out[f"phase_{name}_count"] = self.counts[name]
+        out["trace_annotation_seconds"] = self.annotation_seconds
         return out
 
 

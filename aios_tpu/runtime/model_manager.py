@@ -52,6 +52,7 @@ from ..engine.tokenizer import (
     HFTokenizer,
     gguf_tokenizer,
 )
+from ..obs import flightrec
 from ..serving import ReplicaPool, ServingConfig
 
 log = logging.getLogger("aios.runtime.models")
@@ -446,11 +447,20 @@ class ModelManager:
             )
 
         t0 = time.time()
+        # the model's set-up, named (flightrec.SETUP_PHASES): replica 0's
+        # phases hold load.model and what is done once a model, each
+        # replica's its own engine and warm-up, and pool.stats() sums them
+        phases = [flightrec.Phases()]
+        flightrec.compile_cache()  # counts from here: the weights' compiles too
+        whole = phases[0].begin("load.model")
         try:
-            cfg, params, tokenizer = self._load_weights(name, path, context_length)
-            jax.block_until_ready(params)  # weight builds dispatch async
-            setup = {"weights": time.time() - t0, "engines": 0.0,
-                     "warmup": 0.0}
+            with phases[0].phase("load.weights"):
+                cfg, params, tokenizer = self._load_weights(
+                    name, path, context_length
+                )
+                # the ring of the model's lane and its requests' timelines
+                phases[0].named(cfg.name)
+                jax.block_until_ready(params)  # weight builds dispatch async
             serving_cfg = ServingConfig.from_env(
                 cfg.replicas,
                 draft_model_default=getattr(cfg, "draft_model", ""),
@@ -691,28 +701,32 @@ class ModelManager:
                         if devices[i] is not None
                         else contextlib.nullcontext()
                     )
+                    if i:
+                        phases.append(flightrec.Phases(cfg.name))
+                    ph = phases[i]
                     with scope:
-                        t_engine = time.time()
-                        engine = TPUEngine(
-                            cfg,
-                            params,
-                            num_slots=self.num_slots,
-                            max_context=ctx,
-                            shardings=plans[i],
-                            quantize=quantize,
-                            cache_dtype=cache_dtype,
-                            # the per-step history scatter serves only
-                            # the speculative proposers — skip it (and
-                            # its serial scan dependency) when
-                            # speculative serving is off
-                            track_history=spec_on,
-                            draft=draft,
-                            **kw,
-                        )
-                        engines.append(engine)
-                        jax.block_until_ready((engine.params, engine.state))
-                        t_warm = time.time()
-                        setup["engines"] += t_warm - t_engine
+                        with ph.phase("load.engine"):
+                            engine = TPUEngine(
+                                cfg,
+                                params,
+                                num_slots=self.num_slots,
+                                max_context=ctx,
+                                shardings=plans[i],
+                                quantize=quantize,
+                                cache_dtype=cache_dtype,
+                                # the per-step history scatter serves only
+                                # the speculative proposers — skip it (and
+                                # its serial scan dependency) when
+                                # speculative serving is off
+                                track_history=spec_on,
+                                draft=draft,
+                                phases=ph,
+                                **kw,
+                            )
+                            engines.append(engine)
+                            jax.block_until_ready(
+                                (engine.params, engine.state)
+                            )
                         if self.warm_compile:
                             # json-mode deployments dispatch the
                             # grammar-masked step; compile it behind the
@@ -723,8 +737,10 @@ class ModelManager:
                             # chunk sizes, still before STATE_READY
                             from .service import json_mode_forced
 
-                            engine.warmup(masked_step=json_mode_forced())
-                            setup["warmup"] += time.time() - t_warm
+                            with ph.phase("load.warmup"):
+                                engine.warmup(
+                                    masked_step=json_mode_forced()
+                                )
             except BaseException:
                 # a failed replica build must not strand its siblings'
                 # HBM until a gc pass
@@ -764,9 +780,10 @@ class ModelManager:
                 )
 
             try:
-                pool = ReplicaPool(
-                    name, engines, batcher_factory, serving_cfg
-                )
+                with phases[0].phase("load.attach"):
+                    pool = ReplicaPool(
+                        name, engines, batcher_factory, serving_cfg
+                    )
             except BaseException:
                 # the pool shuts its partial batchers down itself; the
                 # engines are still ours to free
@@ -793,7 +810,14 @@ class ModelManager:
                 pool=pool,
                 model_path=path,
                 context_length=context_length or 0,
-                setup_seconds={k: round(v, 2) for k, v in setup.items()},
+                # the three keys the benchmark's set-up line reads, from
+                # the spans' own seconds
+                setup_seconds={
+                    key: round(sum(p.seconds[span] for p in phases), 2)
+                    for key, span in (("weights", "load.weights"),
+                                      ("engines", "load.engine"),
+                                      ("warmup", "load.warmup"))
+                },
             )
             # keep the replica-0 snapshot fresh across crash-respawns
             # (the pool swaps Replica.batcher; the ManagedModel field
@@ -884,6 +908,10 @@ class ModelManager:
             else:
                 log.error("model %s failed to load: %s", name, exc)
             raise
+        finally:
+            # a failed load's seconds count too, and no trace annotation
+            # stays entered
+            phases[0].end(whole)
 
     def _build_draft(self, source: str, cfg: ModelConfig, ctx: int,
                      tokenizer: BaseTokenizer):

@@ -574,7 +574,10 @@ class ReplicaPool:
                 if k == "batch_occupancy":
                     occ.append(v)
                     continue
-                if k == "kv_row_bytes":  # a size, the same in every replica
+                # a size, and the process's compile cache: the same in
+                # every replica
+                if k in ("kv_row_bytes", "compile_cache_requests",
+                         "compile_cache_hits"):
                     out[k] = v
                     continue
                 out[k] = out.get(k, 0) + v

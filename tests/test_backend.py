@@ -99,8 +99,11 @@ def test_failed_aot_compile_fails_the_load_on_tpu(monkeypatch):
     params = model_mod.init_params(TINY_TEST, jax.random.PRNGKey(0))
     engine = TPUEngine(TINY_TEST, params, num_slots=2, max_context=64)
 
-    class Unlowerable:
-        def lower(self, *args):
+    class Unlowerable:  # traces, and fails where the kernels lower
+        def trace(self, *args):
+            return self
+
+        def lower(self):
             raise RuntimeError("Mosaic failed to compile TPU kernel")
 
     try:

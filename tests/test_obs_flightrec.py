@@ -524,9 +524,12 @@ def test_healthz_returns_503_when_health_fn_raises():
 
 def test_recorder_no_compile_and_dispatch_identical(monkeypatch):
     """With the recorder ON, compile counters stay FLAT after warmup and
-    dispatch counts + token streams are identical to recorder OFF —
-    single-request waves so the dispatch count is deterministic (no
-    admission-timing variance in the chunk-size choice)."""
+    the token streams are identical to recorder OFF. The dispatched steps
+    are equal to within one dispatch a request: the default loop issues
+    dispatch N+1 before it consumes N (PR 30), so whether a dispatch of
+    nothing follows a request's last token is timing, one ``chunk_steps``
+    apart, recorder or no recorder. Single-request waves, so nothing else
+    varies (no admission-timing variance in the chunk-size choice)."""
     params = M.init_params(TINY_TEST, jax.random.PRNGKey(0),
                            dtype=jnp.float32)
 
@@ -547,7 +550,8 @@ def test_recorder_no_compile_and_dispatch_identical(monkeypatch):
             return {
                 "outs": outs,
                 # decode_steps counts every dispatched step at the engine
-                # — deterministic for sequential single-request waves.
+                # — for sequential single-request waves deterministic but
+                # for the dispatch issued ahead of each request's last.
                 # (batcher.decode_dispatches is NOT compared: that
                 # counter skips the first dispatch after an idle gap,
                 # and whether an idle tick lands between sequential
@@ -565,7 +569,7 @@ def test_recorder_no_compile_and_dispatch_identical(monkeypatch):
         "recorder ON compiled post-warmup — it must be host-side only"
     )
     assert off["compile_delta"] == 0
-    assert on["decode_steps"] == off["decode_steps"]
+    assert abs(on["decode_steps"] - off["decode_steps"]) <= 3 * 4  # requests x chunk_steps
     assert on["outs"] == off["outs"]
     # and the ON wave actually recorded: 3 retired timelines with decode
     # ticks, the OFF wave recorded nothing new for those ids
