@@ -373,6 +373,35 @@ class PendingDecode:
         return self.tokens
 
 
+class PendingFirstToken:
+    """An admission's first token, sampled by the prefill program that was
+    just issued and still on the device (engine.prefill_async,
+    ChunkedPrefill.step_async).
+
+    The program has already written the token into the state the next
+    decode step reads, and the slot's host length and ``active`` flag are
+    set, so a decode dispatch may be issued behind the prefill without
+    it: the host needs the number only to send it to the client.
+    ``wait()`` blocks until the prefill program has finished and returns
+    it. It is called outside the engine lock, and lands the devprof
+    sample the dispatch was due (graph call -> token on the host)."""
+
+    __slots__ = ("_engine", "_first", "_dtok", "token")
+
+    def __init__(self, engine: "TPUEngine", first, dtok) -> None:
+        self._engine = engine
+        self._first = first
+        self._dtok = dtok
+        self.token: Optional[int] = None
+
+    def wait(self) -> int:
+        if self.token is None:
+            self.token = int(self._first)
+            self._first = None
+            self._engine._devprof_sample(self._dtok)
+        return self.token
+
+
 class TPUEngine:
     """Single-model decode engine over a fixed set of batch slots."""
 
@@ -3149,6 +3178,19 @@ class TPUEngine:
         top_p: float = 1.0,
     ) -> int:
         """Fill ``slot`` with a prompt; returns the first generated token."""
+        return self.prefill_async(slot, token_ids, temperature, top_p).wait()
+
+    def prefill_async(
+        self,
+        slot: int,
+        token_ids: List[int],
+        temperature: float = 0.0,
+        top_p: float = 1.0,
+    ) -> PendingFirstToken:
+        """Fill ``slot`` with a prompt and return as soon as its last
+        prefill program is issued: the slot is active and a decode
+        dispatch may follow at once; ``wait()`` of what comes back yields
+        the first generated token."""
         if not 0 <= slot < self.num_slots:
             raise ValueError(f"slot {slot} out of range")
         token_ids = list(token_ids)[-(self.max_context - 1) :]
@@ -3175,9 +3217,9 @@ class TPUEngine:
                 self._prefix_chunk, start_pos=matched, hashes=hashes,
             )
             try:
-                first = pc.step()
+                first = pc.step_async()
                 while first is None:
-                    first = pc.step()
+                    first = pc.step_async()
             except BaseException:
                 self.release(slot)
                 raise
@@ -3214,11 +3256,7 @@ class TPUEngine:
             self._host_greedy[slot] = temperature < sampling.GREEDY_EPS
             self._host_lengths[slot] = true_len
             self._register_prefix(slot, token_ids, hashes)
-            first_token = int(first)
-        # int(first) above blocked through completion, so the sample is
-        # the dispatch->ready delta (landed outside the lock)
-        self._devprof_sample(dtok)
-        return first_token
+        return PendingFirstToken(self, first, dtok)
 
     def _seq_route_ok(self, true_len: int) -> bool:
         """Whether a prompt of ``true_len`` rows routes through the
@@ -3234,7 +3272,7 @@ class TPUEngine:
         )
 
     def _seq_prefill(self, slot: int, ids: List[int], temperature: float,
-                     top_p: float, hashes) -> int:
+                     top_p: float, hashes) -> PendingFirstToken:
         """Whole-prompt prefill in ONE dispatch with the sequence sharded
         over the mesh's sp axis (parallel/ring_attention.py or
         ulysses.py): every chip works a T/sp slice of the prompt instead
@@ -3270,9 +3308,7 @@ class TPUEngine:
             )
             self._maybe_compress(slot)
             self._register_prefix(slot, ids, hashes)
-            first_token = int(first)
-        self._devprof_sample(dtok)
-        return first_token
+        return PendingFirstToken(self, first, dtok)
 
     def start_chunked_prefill(
         self,
@@ -4118,7 +4154,9 @@ class ChunkedPrefill:
     Each ``step()`` call processes one chunk (holding the engine lock only
     for that chunk's dispatch); between calls the owner may run
     ``engine.step`` for the other slots. The final chunk samples the first
-    token, activates the slot, and is returned from ``step()``.
+    token and activates the slot: ``step_async()`` hands the token back
+    still on the device (a decode dispatch may be issued behind the chunk
+    before it is read), ``step()`` reads it.
 
     While chunks are in flight the slot's device-side ``active`` flag stays
     False, so interleaved decode dispatches write this slot's (ignored) K/V
@@ -4151,17 +4189,23 @@ class ChunkedPrefill:
         self.chunk = int(chunk)
         self.pos = int(start_pos)
         self.hashes = hashes
-        self.first_token: Optional[int] = None
+        self.first: Optional[PendingFirstToken] = None
 
     @property
     def done(self) -> bool:
-        return self.first_token is not None
+        return self.first is not None
 
     def step(self) -> Optional[int]:
         """Process the next chunk; returns the first sampled token when the
         prompt is fully admitted, else None."""
+        first = self.step_async()
+        return None if first is None else first.wait()
+
+    def step_async(self) -> Optional[PendingFirstToken]:
+        """Issue the next chunk; returns the pending first token once the
+        final chunk is issued, else None."""
         if self.done:
-            return self.first_token
+            return self.first
         eng = self.engine
         remaining = len(self.ids) - self.pos
         final = remaining <= self.chunk
@@ -4213,7 +4257,7 @@ class ChunkedPrefill:
                 )
                 eng._host_lengths[self.slot] = len(self.ids)
                 eng._register_prefix(self.slot, self.ids, self.hashes)
-                self.first_token = int(first)
+                self.first = PendingFirstToken(eng, first, dtok)
             else:
                 eng.state = eng._chunk_fn(bucket, False)(
                     eng.params,
@@ -4223,11 +4267,13 @@ class ChunkedPrefill:
                     jnp.int32(self.pos),
                     *extra,
                 )
-        # final chunks blocked on int(first) above; mid-chunk samples
-        # are submit-side (their writes overlap the next chunk's staging)
-        eng._devprof_sample(dtok)
+        if not final:
+            # mid-chunk samples are submit-side (their writes overlap the
+            # next chunk's staging); a final chunk's lands where its
+            # token is read
+            eng._devprof_sample(dtok)
         self.pos += n
-        return self.first_token
+        return self.first
 
 
 class _SeqShardedPrefill:
@@ -4248,17 +4294,20 @@ class _SeqShardedPrefill:
         self.top_p = float(top_p)
         self.hashes = hashes
         self.pos = 0
-        self.first_token: Optional[int] = None
+        self.first: Optional[PendingFirstToken] = None
 
     @property
     def done(self) -> bool:
-        return self.first_token is not None
+        return self.first is not None
 
     def step(self) -> Optional[int]:
-        if self.done:
-            return self.first_token
-        self.first_token = self.engine._seq_prefill(
-            self.slot, self.ids, self.temperature, self.top_p, self.hashes
-        )
-        self.pos = len(self.ids)
-        return self.first_token
+        return self.step_async().wait()
+
+    def step_async(self) -> PendingFirstToken:
+        if not self.done:
+            self.first = self.engine._seq_prefill(
+                self.slot, self.ids, self.temperature, self.top_p,
+                self.hashes,
+            )
+            self.pos = len(self.ids)
+        return self.first

@@ -163,20 +163,25 @@ SNAPSHOT_CAUSES = ("shed_spike", "crash_respawn", "slo_breach", "abort",
 # emit (the token loops, _finish, _rec_close), consume (the pipelined
 # tick's wait for the tokens of the dispatch it consumes: a name of its
 # own, so that engine.readback stays one span a dispatch, on the thread
-# that made it), evict (_evict_longest). engine.* wrap the dispatch bodies: lock_wait (until
+# that made it), first_token (the wait for the first tokens of the tick's
+# admissions, whose prefill programs were issued and not waited for: in
+# the pipelined loop after the decode dispatch behind them is issued),
+# evict (_evict_longest). engine.* wrap the dispatch bodies: lock_wait (until
 # the engine lock is held), enqueue (lock held until the graph call
 # returns), readback (the blocking device->host copy of the tokens),
-# prefill (one prefill / chunk dispatch, lock to first token), compile
-# (the first call of a lazily compiled graph).
+# prefill (one prefill / chunk dispatch, lock held until the graph call
+# returns: the first token is read outside it), compile (the first call
+# of a lazily compiled graph).
 PHASES = (
     "batcher.fence", "batcher.reap", "batcher.prefill", "batcher.admit",
     "batcher.idle", "batcher.dispatch", "batcher.emit", "batcher.consume",
     "batcher.evict", "engine.lock_wait", "engine.enqueue", "engine.readback",
-    "engine.prefill", "engine.compile",
+    "engine.prefill", "engine.compile", "batcher.first_token",
 )
 # phases that wait on the device, not on the host: a tick's host time
 # leaves them out wherever they nest
-DEVICE_WAIT_PHASES = ("engine.prefill", "engine.readback", "batcher.consume")
+DEVICE_WAIT_PHASES = ("engine.prefill", "engine.readback", "batcher.consume",
+                      "batcher.first_token")
 
 # Set-up phases — the second closed list, closed by the same ``Phases``
 # object before the loop runs. load.* wrap runtime/model_manager.py

@@ -410,7 +410,7 @@ def test_gateway_disconnect_while_queued_cancels_without_slot(monkeypatch):
 
         eng = mgr.models["tiny"].engine
         eos = mgr.models["tiny"].tokenizer.eos_id
-        real_step, real_prefill = eng.step, eng.prefill
+        real_step, real_prefill = eng.step, eng.prefill_async
 
         def never_stopping_step(n=1):
             time.sleep(0.2)
@@ -420,13 +420,13 @@ def test_gateway_disconnect_while_queued_cancels_without_slot(monkeypatch):
 
         def never_stopping_prefill(slot, ids, temperature=0.0, top_p=1.0):
             first = real_prefill(slot, ids, temperature, top_p)
-            if first == eos:
+            if first.wait() == eos:
                 eng.force_pending_token(slot, 7)
-                first = 7
+                first.token = 7
             return first
 
         monkeypatch.setattr(eng, "step", never_stopping_step)
-        monkeypatch.setattr(eng, "prefill", never_stopping_prefill)
+        monkeypatch.setattr(eng, "prefill_async", never_stopping_prefill)
         batcher0 = mgr.models["tiny"].batcher
         monkeypatch.setattr(batcher0, "tokens_per_second", lambda: 500.0)
         rt_server, _, rt_port = serve_runtime(
@@ -567,13 +567,13 @@ def test_priority_admission_order():
     )
     b = ContinuousBatcher(engine, chunk_steps=2, admit_chunk_steps=2)
     order = []
-    orig_prefill = engine.prefill
+    orig_prefill = engine.prefill_async
 
     def recording_prefill(slot, ids, **kw):
         order.append(tuple(ids[:2]))
         return orig_prefill(slot, ids, **kw)
 
-    engine.prefill = recording_prefill
+    engine.prefill_async = recording_prefill
     try:
         import time
 
@@ -611,13 +611,13 @@ def test_priority_aging_prevents_starvation():
     )
     b = ContinuousBatcher(engine, chunk_steps=2, admit_chunk_steps=2)
     order = []
-    orig_prefill = engine.prefill
+    orig_prefill = engine.prefill_async
 
     def recording_prefill(slot, ids, **kw):
         order.append(tuple(ids[:2]))
         return orig_prefill(slot, ids, **kw)
 
-    engine.prefill = recording_prefill
+    engine.prefill_async = recording_prefill
     try:
         hog = b.submit(Request(prompt_ids=[9, 9], max_tokens=24,
                                temperature=0.0))

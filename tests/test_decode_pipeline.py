@@ -14,6 +14,7 @@ Two guarantees under test:
 """
 
 import json
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +24,9 @@ import pytest
 from aios_tpu.engine import model as M
 from aios_tpu.engine.batching import ContinuousBatcher, Request
 from aios_tpu.engine.config import TINY_TEST
-from aios_tpu.engine.engine import DECODE_STEPS, JUMP_BUCKETS, TPUEngine
+from aios_tpu.engine.engine import (
+    DECODE_STEPS, JUMP_BUCKETS, PendingFirstToken, TPUEngine,
+)
 from aios_tpu.engine.tokenizer import ByteTokenizer
 
 
@@ -466,3 +469,264 @@ def test_pending_decode_lengths_snapshot(params):
         p2.wait_started()
     finally:
         eng.close()
+
+
+# -- an admission's first token is read behind the next decode dispatch ------
+
+PAGED = dict(num_slots=3, paged_pool_rows=3 * 128, page_size=32)
+SHARED = (np.arange(1, 41) % 250 + 1).tolist()  # a page and a quarter
+LATE = {
+    "whole": dict(prompt_ids=[9, 8, 7, 6]),
+    # its first page is what the running stream's prompt registered
+    "prefix_hit": dict(prompt_ids=SHARED[:36] + [5, 4]),
+    # three chunks of 32, the last one partial
+    "chunked": dict(prompt_ids=(np.arange(3, 83) % 250 + 1).tolist()),
+}
+
+
+def serve_with_arrival(params, pipeline, running, late, at=3, engine_kw=None,
+                       batcher_kw=None, calls=None, active=None):
+    """``running`` are submitted up front; ``late`` is submitted from the
+    scheduler's own thread as it makes its ``at``-th decode dispatch, so that
+    it is admitted by the tick after it in either loop (an arrival from
+    another thread lands on whatever tick the race picks, and a sampled
+    stream then draws from another place of the key chain). ``calls`` takes
+    the order of the engine calls of the late request's admission, and
+    ``active`` the number of active slots as each later dispatch is made."""
+    eng = make_engine(params, **(engine_kw or PAGED))
+    kw = dict(chunk_steps=2, admit_chunk_steps=2, prefill_chunk=32,
+              pipeline=pipeline)
+    kw.update(batcher_kw or {})
+    b = ContinuousBatcher(eng, **kw)
+    handles, dispatches = [], []
+    note = calls.append if calls is not None else (lambda what: None)
+
+    def arrive(what):
+        dispatches.append(what)
+        if len(dispatches) == at:
+            handles.append(b.submit(Request(**late)))
+        elif handles:
+            note(what)
+            if active is not None:
+                active.append(int(eng.active.sum()))
+
+    for name in ("step", "step_async", "step_masked"):
+        def counted(*a, _real=getattr(eng, name), _name=name):
+            arrive(_name)
+            return _real(*a)
+        setattr(eng, name, counted)
+    force = eng.force_pending_token
+
+    def forced(slot, token):
+        note("force_pending_token")
+        force(slot, token)
+
+    eng.force_pending_token = forced
+    try:
+        first = [b.submit(Request(**r)) for r in running]
+        outs = [h.tokens() for h in first]
+        assert handles, "the streams ended before the arrival"
+        outs.append(handles[0].tokens())
+        stats = dict(eng.stats(), **b.stats())
+        stats["pool_evictions"] = b.pool_evictions
+        stats["aborted"] = [h.abort_reason for h in first + handles]
+        return outs, stats
+    finally:
+        b.shutdown()
+        eng.close()
+        assert eng.prefix_index is not None \
+            or eng.stats().get("kv_pages_in_use", 0) == 0
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.85], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("admission", sorted(LATE))
+def test_an_admission_beside_running_streams_serves_the_sync_loops_tokens(
+        params, admission, temperature):
+    """A request admitted while two streams decode (a whole prompt, a prefix
+    hit's tail, a chunked admission's final chunk): the pipelined loop issues
+    the decode dispatch behind its last prefill program and reads its first
+    token after; every stream, the arrival's and the running ones' before and
+    after it, is token for token what the synchronous loop serves, which
+    reads the token where it admits."""
+    sample = dict(temperature=temperature, top_p=0.9)
+    running = [dict(prompt_ids=SHARED, max_tokens=40, **sample),
+               dict(prompt_ids=[41, 2, 77], max_tokens=33, **sample)]
+    late = dict(LATE[admission], max_tokens=12, **sample)
+    off, s_off = serve_with_arrival(params, False, running, late)
+    on, s_on = serve_with_arrival(params, True, running, late)
+    assert on == off
+    assert [len(o) for o in on] == [40, 33, 12]
+    assert s_on["aborted"] == ["", "", ""]
+    if temperature:
+        assert any(len(set(o)) > 2 for o in on)
+    if admission == "prefix_hit":
+        assert s_on["prefix_rows_reused"] >= 32
+    # the mechanism engaged at every admission of the pipelined loop, and
+    # never in the other
+    assert s_on["admissions"] == s_off["admissions"] == 3
+    assert s_on["admissions_read_after_dispatch"] == 3
+    assert s_off["admissions_read_after_dispatch"] == 0
+    assert s_on["phase_batcher.first_token_count"] == 3
+
+
+@pytest.mark.parametrize("constrained", [False, True],
+                         ids=["plain", "constrained"])
+def test_the_dispatch_behind_an_admission_is_issued_before_its_first_token_is_read(
+        params, monkeypatch, constrained):
+    """The order of the engine calls on an admission tick of the pipelined
+    loop: prefill program, decode dispatch, THEN the read of the first token.
+    A request with a constraint reads at once, overwrites the token with its
+    forced opener, and only then rides a (masked) dispatch: the counter does
+    not count it."""
+    calls = []
+    wait = PendingFirstToken.wait
+
+    def read(self):
+        if self.token is None:
+            calls.append("first_token.wait")
+        return wait(self)
+
+    monkeypatch.setattr(PendingFirstToken, "wait", read)
+    tok = ByteTokenizer()
+    late = dict(prompt_ids=tok.encode("emit json"), max_tokens=16,
+                temperature=0.0, stop_ids=(tok.eos_id,), json_mode=constrained)
+    running = [dict(prompt_ids=[3, 17, 91], max_tokens=30, temperature=0.0)]
+    outs, stats = serve_with_arrival(
+        params, True, running, late, calls=calls,
+        engine_kw=dict(num_slots=2), batcher_kw=dict(tokenizer=tok))
+    assert len(outs[0]) == 30 and outs[1]
+    # the running stream's own read, then what follows the arrival: the
+    # engine calls of the tick that admitted it
+    reads = [i for i, c in enumerate(calls) if c == "first_token.wait"]
+    assert len(reads) == 2 and reads[0] == 0
+    late_read = reads[1]
+    if constrained:
+        assert late_read == 1
+        assert calls[2] == "force_pending_token"
+        assert calls[3] == "step_masked"
+        assert isinstance(json.loads(tok.decode(outs[1])), dict)
+    else:
+        assert calls[1:3] == ["step_async", "first_token.wait"]
+        assert "force_pending_token" not in calls
+    assert stats["admissions"] == 2
+    # the running stream's own admission found nothing live and was read
+    # behind the dispatch it started
+    assert stats["admissions_read_after_dispatch"] == (1 if constrained else 2)
+
+
+@pytest.mark.parametrize("why", ["budget", "stop"])
+def test_a_first_token_that_is_the_last_retires_its_stream_and_frees_its_slot(
+        params, why):
+    """``max_tokens`` 1, or a stop token sampled by the prefill: the stream
+    ends where its first token is read, without an abort; the dispatch
+    already issued for its slot decodes that column into nothing, the slot
+    is free again within two dispatches of its admission, and the stream
+    beside it is what it is alone."""
+    alone = run_batch(params, True, [dict(
+        prompt_ids=[3, 17, 91], max_tokens=30, temperature=0.0)],
+        engine_kw=PAGED, warm=False)[0][0]
+    free = run_batch(params, True, [dict(
+        prompt_ids=[9, 8, 7, 6], max_tokens=4, temperature=0.0)],
+        engine_kw=PAGED, warm=False)[0][0]
+    late = dict(prompt_ids=[9, 8, 7, 6], temperature=0.0)
+    late.update(dict(max_tokens=1) if why == "budget"
+                else dict(max_tokens=9, stop_ids=(free[0],)))
+    running = [dict(prompt_ids=[3, 17, 91], max_tokens=30, temperature=0.0)]
+    active = []
+    outs, stats = serve_with_arrival(
+        params, True, running, late, active=active,
+        engine_kw=dict(PAGED, prefix_cache=False))
+    assert outs == [alone, free[:1]]
+    assert stats["aborted"] == ["", ""]
+    assert stats["admissions_read_after_dispatch"] == 2
+    # the dispatch behind its prefill ran with its slot, the next without
+    assert active[:2] == [2, 1] and set(active[2:]) <= {1}
+    assert stats["kv_pages_in_use"] == 0
+
+
+def _hold_first_token(monkeypatch, nth):
+    """Make the ``nth`` read of a first token block until the test lets it
+    go: (it is being waited for, let it go)."""
+    reading, go = threading.Event(), threading.Event()
+    wait = PendingFirstToken.wait
+    reads = []
+
+    def held(self):
+        if self.token is None:
+            reads.append(self)
+            if len(reads) == nth:
+                reading.set()
+                assert go.wait(timeout=60)
+        return wait(self)
+
+    monkeypatch.setattr(PendingFirstToken, "wait", held)
+    return reading, go
+
+
+@pytest.mark.parametrize("what", ["cancel", "shutdown"])
+def test_a_stream_ended_from_outside_while_its_first_token_is_pending(
+        params, monkeypatch, what):
+    """The client cancels, or the model is unloaded, while the scheduler
+    waits for an admission's first token behind the dispatch it issued: the
+    token is dropped (cancel) or delivered and the stream then ended as
+    unloaded (shutdown), nobody hangs, and every page comes back."""
+    eng = make_engine(params, **dict(PAGED, prefix_cache=False))
+    b = ContinuousBatcher(eng)
+    reading, go = _hold_first_token(monkeypatch, 2)  # the late request's
+    try:
+        running = b.submit(Request(prompt_ids=[3, 17, 91], max_tokens=400,
+                                   temperature=0.0))
+        it = iter(running)
+        next(it)
+        late = b.submit(Request(prompt_ids=[9, 8, 7, 6, 5], max_tokens=50,
+                                temperature=0.0))
+        assert reading.wait(timeout=60)
+        assert late._live.first_token_at == 0.0
+        if what == "cancel":
+            late.cancel()
+            go.set()
+            assert late.tokens() == [] and not late.aborted
+            running.cancel()
+            list(it)
+            b.shutdown()
+            assert b.cancellations == 2
+        else:
+            closer = threading.Thread(target=b.shutdown)
+            closer.start()
+            go.set()
+            closer.join(timeout=60)
+            assert not closer.is_alive()
+            got = late.tokens()
+            assert len(got) >= 1 and late.abort_reason == "model unloading"
+            assert running.abort_reason == "model unloading"
+            list(it)
+        assert b._firsts == []
+        assert eng.stats()["kv_pages_in_use"] == 0
+        assert len(eng.free_slots()) == eng.num_slots
+    finally:
+        go.set()
+        b.shutdown()
+        eng.close()
+
+
+def test_the_dispatch_behind_an_admission_runs_out_of_pages(params):
+    """Three pages: the running stream holds two, the arrival's prompt of 32
+    rows takes the third, and the dispatch issued behind its prefill cannot
+    back row 33. The arrival's first token, pending when that dispatch was
+    issued, is delivered; the failure surfaces at the next tick's consume
+    and evicts it (the lower priority); the other stream runs to its end and
+    no page is lost."""
+    running = [dict(prompt_ids=list(range(1, 31)), max_tokens=30,
+                    temperature=0.0, priority=1)]
+    late = dict(prompt_ids=list(range(40, 72)), max_tokens=20, temperature=0.0)
+    outs, stats = serve_with_arrival(
+        params, True, running, late, at=4,
+        engine_kw=dict(num_slots=2, paged_pool_rows=96, page_size=32,
+                       prefix_cache=False),
+        batcher_kw=dict(prefill_chunk=0))
+    assert stats["aborted"][0] == "" and len(outs[0]) == 30
+    assert "evicted" in stats["aborted"][1]
+    assert len(outs[1]) >= 1
+    assert stats["pool_evictions"] == 1
+    assert stats["admissions_read_after_dispatch"] == 2
+    assert stats["kv_pages_in_use"] == 0

@@ -15,6 +15,7 @@ Four layers under test:
 """
 
 import json
+import threading
 import time
 import urllib.request
 
@@ -93,9 +94,15 @@ def test_e2e_timeline_through_live_grpc(flight_server):
     assert tl.prompt_tokens > 0
     kinds = [k for _, k, _ in tl.events]
     # ordering: first occurrence of each lifecycle stage is monotonic
-    order = ["route", "admit", "queue", "prefill", "decode", "retire"]
+    order = ["route", "admit", "queue", "decode", "retire"]
     positions = [kinds.index(k) for k in order]
     assert positions == sorted(positions), (order, kinds)
+    # the prefill's event is stamped where its first token was read, which
+    # the pipelined loop does after it has issued the decode dispatch behind
+    # the prefill: the prefill BEGAN (stamp - dur_ms) before that dispatch
+    assert kinds.index("queue") < kinds.index("prefill") < kinds.index("retire")
+    t_prefill, _, prefill = tl.events[kinds.index("prefill")]
+    assert t_prefill - prefill["dur_ms"] / 1e3 < tl.events[kinds.index("decode")][0]
     assert kinds.count("retire") == 1
     # per-dispatch decode ticks carry occupancy + step count
     decode = [f for _, k, f in tl.events if k == "decode"]
@@ -219,6 +226,9 @@ def test_abort_records_closed_cause_and_snapshots():
     eng = TPUEngine(TINY_TEST, params, num_slots=2, max_context=128,
                     cache_dtype=jnp.float32)
     b = ContinuousBatcher(eng, chunk_steps=4, admit_chunk_steps=2)
+    # an abort of this model by an earlier test file of the same worker, less
+    # than the cooldown ago, would claim the one snapshot this abort is due
+    flightrec.RECORDER._snapshot_at.pop((TINY_TEST.name, "abort"), None)
     try:
         h = b.submit(Request(prompt_ids=[3, 5, 7], max_tokens=512,
                              temperature=0.0, request_id="flight-abort-1"))
@@ -252,6 +262,34 @@ def test_abort_records_closed_cause_and_snapshots():
     assert snaps, (
         "abort must freeze an anomaly snapshot holding this request"
     )
+
+
+def test_the_wait_for_first_tokens_is_a_phase_a_ticks_host_time_leaves_out():
+    """``batcher.first_token`` is in the closed list, a wait on the device:
+    it closes into its own seconds and count, and the tick's host time
+    (what a stall is judged by) has neither it nor, where the synchronous
+    loop reads inside the admission, its share of the phase around it."""
+    name = "batcher.first_token"
+    assert name in flightrec.PHASES and name in flightrec.DEVICE_WAIT_PHASES
+    ph = flightrec.Phases("phases-first-token")
+    ph.tick_thread = threading.get_ident()
+    with ph.phase("batcher.admit") as admit:
+        with ph.phase(name) as inside:  # the synchronous loop
+            time.sleep(0.01)
+    with ph.phase("batcher.dispatch"):
+        pass
+    with ph.phase(name) as behind:  # the pipelined loop, of the tick's own
+        time.sleep(0.01)
+    host, under_dispatch = ph.take_tick()
+    assert set(host) == {"batcher.admit"}
+    assert host["batcher.admit"] == pytest.approx(admit.dt - inside.dt)
+    assert set(under_dispatch) == {"batcher.dispatch"}
+    stats = ph.stats()
+    assert stats[f"phase_{name}_count"] == 2
+    assert stats[f"phase_{name}_seconds"] == pytest.approx(inside.dt + behind.dt)
+    assert stats[f"phase_{name}_seconds"] >= 0.02
+    assert [n for _, n, _, _ in flightrec.RECORDER.phases("phases-first-token")] == [
+        name, "batcher.admit", "batcher.dispatch", name]
 
 
 def test_shed_spike_triggers_snapshot_with_cooldown():

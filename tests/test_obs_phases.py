@@ -86,9 +86,15 @@ def test_every_phase_of_the_closed_list_is_counted_and_kept_nested_as_the_code_n
     assert all(_inside(s, by["engine.enqueue"] + by["engine.prefill"])
                for s in by["engine.compile"])
     assert len(by["engine.prefill"]) >= 4  # three chunks and a whole prompt
+    # this loop reads an admission's first token where it admits: one wait a
+    # request, inside the phase that issued its last prefill program
+    assert len(by["batcher.first_token"]) == 2
+    assert all(_inside(s, by["batcher.prefill"] + by["batcher.admit"])
+               for s in by["batcher.first_token"])
     # and in the order of a tick: the loop's own phases follow one another,
     # none overlaps the next, and a dispatch is followed by its emit
-    loop = [s for s in spans if s[0].startswith("batcher.")]
+    loop = [s for s in spans
+            if s[0].startswith("batcher.") and s[0] != "batcher.first_token"]
     assert all(a[2] <= b_[1] for a, b_ in zip(loop, loop[1:]))
     names = [s[0] for s in loop]
     for i, name in enumerate(names[:-1]):
@@ -110,6 +116,17 @@ def test_every_phase_of_the_closed_list_is_counted_and_kept_nested_as_the_code_n
     assert b.stats()["phase_batcher.fence_count"] >= 1
     assert b.stats()["phase_batcher.consume_count"] >= 1
     assert {"batcher.fence", "batcher.consume"} <= {n for n, _, _ in _spans("phases-pipe")}
+    # and reads first tokens behind the dispatch it issued after their
+    # prefill: a phase of the tick's own, after every admit and dispatch of it
+    pipe = [s for s in _spans("phases-pipe") if s[0].startswith("batcher.")]
+    firsts = [s for s in pipe if s[0] == "batcher.first_token"]
+    assert len(firsts) == 2 == b.stats()["admissions"]
+    assert not any(_inside(s, [o for o in pipe if o[0] != "batcher.first_token"])
+                   for s in firsts)
+    for f in firsts:
+        before = [s[0] for s in pipe if s[2] <= f[1]]
+        last = len(before) - 1 - before[::-1].index("batcher.reap")
+        assert "batcher.dispatch" in before[last:]
     assert b._last_dispatch_s > 0
     # a dispatch behind a prompt chunk still on the device has that chunk's
     # time in front of its own: a running median of its own
