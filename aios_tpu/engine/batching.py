@@ -34,7 +34,7 @@ import numpy as np
 from ..analysis.locks import make_lock
 from .engine import (
     DECODE_STEPS, JUMP_BUCKETS, ChunkedPrefill, PendingDecode,
-    PendingFirstToken, TPUEngine, _env_flag,
+    PendingFirstToken, TPUEngine, _env_flag, refuse_for_state_kind,
 )
 from .paged import PoolExhausted
 from .sampling import GREEDY_EPS
@@ -339,6 +339,9 @@ class ContinuousBatcher:
         # tokens per slot per round — greedy requests decode the identical
         # sequence in fewer dispatches (engine/spec.py); sampling requests
         # transparently take their usual one token per round.
+        refuse_for_state_kind(
+            engine.cfg, speculative_decoding_and_its_rollback=bool(speculative)
+        )
         if speculative and not getattr(engine, "spec_supported", True):
             log.warning(
                 "speculative decoding disabled: unsupported on this "
@@ -438,6 +441,12 @@ class ContinuousBatcher:
         # a dp-replicated page pool.
         if jump_ahead is None:
             jump_ahead = _env_flag("AIOS_TPU_JUMP_AHEAD")
+        # asked for by argument or environment over a recurrent state:
+        # refused by name (the default's ON falls to the masked step below)
+        refuse_for_state_kind(
+            engine.cfg,
+            the_grammar_jump_ahead_and_its_verify_graph=bool(jump_ahead),
+        )
         if jump_ahead is None:
             jump_ahead = bool(getattr(engine.cfg, "jump_ahead", True))
         self.jump_ahead = bool(jump_ahead) and getattr(
@@ -1254,6 +1263,10 @@ class ContinuousBatcher:
             fields["restored_rows"] = int(restored)
         if chunk is not None:
             fields["chunk"] = chunk
+        states = getattr(self.engine, "slot_states", None)
+        if states is not None:
+            # the state kind: what the slot holds beside its latent rows
+            fields["state_bytes"] = states.slot_bytes
         if self.engine.cfg.kinds and live.slot >= 0:
             # pages by kind: what this admission left the slot holding
             alloc = self.engine.allocator

@@ -33,6 +33,10 @@ class RopeParams:
 # (ModelConfig.layer_types): ``window`` sees itself and the
 # sliding_window - 1 rows before it, ``full`` every row
 LAYER_KINDS = ("full", "window")
+# and those of a latent-attention stack: ``mla`` keeps latent rows in pages,
+# ``kda`` (Kimi Delta Attention, engine/kda.py) one fixed-size recurrent
+# state a slot and no rows (engine/paged.py header: the state kind)
+STATE_KINDS = ("kda", "mla")
 
 
 @dataclass(frozen=True)
@@ -81,6 +85,7 @@ class ModelConfig:
     # row plus qk_rope_head_dim rotary values shared by all heads — the
     # cache holds those, not per-head K/V. A head is qk_nope_head_dim +
     # qk_rope_head_dim wide for scores and v_head_dim for values.
+    # q_lora_rank 0 = one direct query projection (no bottleneck, no norm).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -126,6 +131,30 @@ class ModelConfig:
     # kinds: a stack of one kind has the one table of the fields above.
     layer_types: tuple = ()
     rope_by_kind: tuple = ()
+    # A latent-attention stack may mix ``mla`` layers with ``kda`` layers
+    # (STATE_KINDS in layer_types; engine/kda.py has the equations): kda_heads
+    # heads of kda_key_dim key and kda_value_dim value channels, a depthwise
+    # causal convolution of kda_conv taps before them, a decay a channel of
+    # log alpha in (kda_lower_bound, 0), and a float32 state
+    # [kda_heads, kda_key_dim, kda_value_dim] a slot a layer in place of rows.
+    kda_heads: int = 0
+    kda_key_dim: int = 0
+    kda_value_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
+    # the latent block's variations, each off where a model has none:
+    # learned RMSNorms on each query head and on the shared rotary key
+    # before rotation; rotary pairs (2i, 2i+1) in place of (i, i + half);
+    # each head's attention output times sigmoid(h W_z)_head
+    latent_qk_norm: bool = False
+    rope_interleave: bool = False
+    attn_head_gate: bool = False
+    # group-limited routing (sigmoid scoring): the router's experts are
+    # n_group groups of consecutive experts, a group scores the sum of its
+    # two largest biased scores, and the top-k is taken among the topk_group
+    # best groups' experts. 0 = no groups.
+    n_group: int = 0
+    topk_group: int = 0
     # serving replicas per managed model (aios_tpu/serving/): N independent
     # engine+batcher replicas behind one cache-aware router. 1 = the
     # single-engine layout; AIOS_TPU_REPLICAS overrides at load time.
@@ -241,18 +270,43 @@ class ModelConfig:
                 )
         self._check_layer_types()
         if self.mla and not (
-            self.q_lora_rank and self.qk_nope_head_dim
-            and self.qk_rope_head_dim and self.v_head_dim
+            self.qk_nope_head_dim and self.qk_rope_head_dim and self.v_head_dim
         ):
             raise ValueError(
-                f"{self.name}: latent attention needs q_lora_rank and the "
-                "three head dims beside kv_lora_rank"
+                f"{self.name}: latent attention needs the three head dims "
+                "beside kv_lora_rank (q_lora_rank 0 is a direct query "
+                "projection)"
             )
+        if (self.latent_qk_norm or self.rope_interleave
+                or self.attn_head_gate) and not self.mla:
+            raise ValueError(
+                f"{self.name}: latent_qk_norm, rope_interleave and "
+                "attn_head_gate are the latent-attention block's "
+                "(engine/latent.py)"
+            )
+        if self.n_group or self.topk_group:
+            if not (
+                self.moe_scoring == "sigmoid" and self.n_group > 0
+                and self.num_experts % self.n_group == 0
+                and 0 < self.topk_group <= self.n_group
+                and self.num_experts // self.n_group >= 2
+                and self.topk_group * (self.num_experts // self.n_group)
+                >= self.num_experts_per_tok
+            ):
+                raise ValueError(
+                    f"{self.name}: group-limited routing needs sigmoid "
+                    f"scoring and n_group ({self.n_group}) groups of at "
+                    f"least two of the router's {self.num_experts} experts, "
+                    f"of which topk_group ({self.topk_group}) hold the "
+                    f"top {self.num_experts_per_tok}"
+                )
 
     def _check_layer_types(self) -> None:
         types = self.layer_types
         if not types and not self.rope_by_kind:
             return
+        if "kda" in types:
+            return self._check_state_kinds()
         unknown = (set(types) | {k for k, _ in self.rope_by_kind}) - set(LAYER_KINDS)
         if unknown or (types and len(types) != self.num_layers):
             raise ValueError(
@@ -279,19 +333,69 @@ class ModelConfig:
                 "kind: set it iff layer_types has a window layer"
             )
 
+    def _check_state_kinds(self) -> None:
+        types = self.layer_types
+        unknown = set(types) - set(STATE_KINDS)
+        if unknown or len(types) != self.num_layers or self.rope_by_kind:
+            raise ValueError(
+                f"{self.name}: a stack with kda layers names one of "
+                f"{STATE_KINDS} for each of the {self.num_layers} layers and "
+                f"has one rotary table; got {len(types)} entries, the "
+                f"unknown kinds {sorted(unknown)} and rope_by_kind "
+                f"{self.rope_by_kind!r}"
+            )
+        if not (self.mla and self.kda_heads and self.kda_key_dim
+                and self.kda_value_dim and self.kda_conv >= 2
+                and self.kda_lower_bound < 0):
+            raise ValueError(
+                f"{self.name}: kda layers stand in a latent-attention stack "
+                "(kv_lora_rank > 0) and need kda_heads, kda_key_dim, "
+                "kda_value_dim, kda_conv >= 2 taps and kda_lower_bound < 0"
+            )
+        if self.hc or self.sandwich_norm or self.sliding_window is not None:
+            raise ValueError(
+                f"{self.name}: kda layers are built beside the plain latent "
+                "block: no mixed streams, sandwich norms or window"
+            )
+
+    @property
+    def state_kinds(self) -> bool:
+        """True when some layers keep a recurrent state a slot (``kda``) in
+        place of cache rows: engine/paged.py's state kind."""
+        return "kda" in self.layer_types
+
     @property
     def kinds(self) -> bool:
         """True when the stack mixes window and full attention layers."""
-        return len(set(self.layer_types)) > 1
+        return len(set(self.layer_types)) > 1 and not self.state_kinds
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers of ``kind`` the stack has."""
+        return sum(k == kind for k in self.layer_types)
+
+    @property
+    def row_layers(self) -> int:
+        """Layers that keep cache ROWS in pages: all but the ``kda`` ones."""
+        return self.num_layers - self.layers_of("kda")
+
+    @property
+    def kda_state_shapes(self) -> tuple:
+        """One slot's state kind, a kda layer: the float32 state
+        [heads, key, value] and the bfloat16 convolution tail (the last
+        kda_conv - 1 rows of the q | k | v projections)."""
+        h, k, v = self.kda_heads, self.kda_key_dim, self.kda_value_dim
+        return (h, k, v), (self.kda_conv - 1, h * (2 * k + v))
 
     @property
     def period(self) -> int:
         """Layers in one repeat of ``layer_types`` (1 for a stack of one
         kind): the least divisor of the depth the pattern repeats with, the
-        depth itself where it never does (the scan's body is then the stack)."""
-        types = self.layer_types
-        if not self.kinds:
+        depth itself where it never does (the scan's body is then the stack).
+        The leading dense layers of a stack with kda layers stand before
+        the pattern (their tree differs: model.layer_segments)."""
+        if not (self.kinds or self.state_kinds):
             return 1
+        types = self.layer_types[len(self.lead_kinds):]
         return next(
             p for p in range(1, len(types) + 1)
             if len(types) % p == 0
@@ -301,7 +405,18 @@ class ModelConfig:
     @property
     def period_kinds(self) -> tuple:
         """The kind of each layer of one period; () for a stack of one."""
-        return tuple(self.layer_types[: self.period]) if self.kinds else ()
+        if not (self.kinds or self.state_kinds):
+            return ()
+        lead = len(self.lead_kinds)
+        return tuple(self.layer_types[lead: lead + self.period])
+
+    @property
+    def lead_kinds(self) -> tuple:
+        """The kinds of the leading dense layers that stand before the
+        pattern (a stack with kda layers; () otherwise)."""
+        if not self.state_kinds:
+            return ()
+        return tuple(self.layer_types[: self.first_k_dense])
 
     def window_of(self, kind: Optional[str]) -> Optional[int]:
         """The window of a layer of ``kind`` (None: a stack of one kind)."""

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import model, paged, sampling, spec
+from . import kda, model, paged, sampling, spec
 from .config import ModelConfig
 from .. import backend, faults, ops
 from ..analysis.locks import make_lock
@@ -313,6 +313,23 @@ def refuse_for_two_kinds(cfg, **asked) -> None:
         )
 
 
+def refuse_for_state_kind(cfg, **asked) -> None:
+    """Raise, naming the model and the feature, for a serving feature that
+    cannot take a recurrent state a slot (a stack with kda layers: the state
+    kind, engine/paged.py header). No quiet fallback: ``LoadModel`` fails
+    with this error, as its two siblings' do."""
+    if not getattr(cfg, "state_kinds", False):  # (a test's stub has none)
+        return
+    wanted = [what for what, on in asked.items() if on]
+    if wanted:
+        raise ValueError(
+            f"{cfg.name}: linear-attention (kda) layers keep one recurrent "
+            f"state a slot beside the bf16 latent pool; this load asks for "
+            f"{' and '.join(w.replace('_', ' ') for w in wanted)}, which "
+            f"cannot take a state yet"
+        )
+
+
 # Device-resident decode state, threaded through the jitted cores as one
 # donated pytree: {k, v, lengths, last_tokens, temps, top_ps, key}
 DecodeState = Dict[str, jnp.ndarray]
@@ -468,6 +485,14 @@ class TPUEngine:
         # int8 KV cache: half the cache footprint/traffic; scales ride along
         # in the decode state and rows quantize on write inside the graph
         self.quant_cache = cache_dtype == jnp.int8
+        refuse_for_state_kind(
+            cfg,
+            the_dense_slot_cache=paged_pool_rows is None,
+            an_int8_KV_pool=self.quant_cache,
+            a_sharding_plan=shardings is not None,
+            a_context_sharded_cache=bool(seq_sharded_cache),
+            a_draft_model_and_the_verify_graph=draft is not None,
+        )
         refuse_for_latent_pool(
             cfg,
             the_dense_slot_cache=paged_pool_rows is None,
@@ -488,6 +513,8 @@ class TPUEngine:
             a_context_sharded_cache=bool(seq_sharded_cache),
             a_draft_model_and_its_speculation=draft is not None,
         )
+        if cfg.state_kinds:
+            kda.check(cfg)
         # Pallas kernels are per-device programs; under a sharding plan the
         # global-array paths must stay pure XLA (GSPMD partitions those) —
         # EXCEPT decode attention, which is head/slot-local and runs the
@@ -673,6 +700,16 @@ class TPUEngine:
         self._whole_prompt_rows: Optional[int] = None
         self.window_prefix: Optional[paged.WindowPrefixPages] = None
         self.prefix_hits_refused_window = 0
+        # the state kind (a stack with kda layers: paged.py's header): its
+        # host-side account, the hits refused for want of a state and the
+        # rows they would have served, and the rows through kda layers by
+        # graph kind (rows x kda layers, padding included)
+        self.slot_states: Optional[paged.SlotStates] = None
+        self.refused_prefixes: Optional[paged.SeenPrefixes] = None
+        self.prefix_hits_refused_state = 0
+        self.prefix_rows_refused_state = 0
+        self.kda_rows_prefill = 0
+        self.kda_rows_decode = 0
         if self.paged:
             # sp in the mesh: the pool (like any non-seq-sharded cache)
             # REPLICATES over the sp axis — its shard_map specs name only
@@ -727,7 +764,15 @@ class TPUEngine:
                 self.allocator = paged.PageAllocator(
                     num_pages, page_size, num_slots, max_blocks, replicas=R
                 )
-                pool_shape = (cfg.num_layers, num_pages)
+                # (a stack with kda layers: its mla layers alone hold rows)
+                pool_shape = (cfg.row_layers, num_pages)
+            if cfg.state_kinds:
+                self.slot_states = paged.SlotStates(
+                    cfg.layers_of("kda"), num_slots, *cfg.kda_state_shapes
+                )
+                # every prompt admits in chunks: a chunk graph takes the
+                # slot and the count of real rows, which the state needs
+                self._whole_prompt_rows = 0
             # THE stored layout (paged.py's header): a row's kv heads
             # merged on the last axis; for latent attention the latents
             # and the padded rotary parts (ModelConfig.kv_row_dims)
@@ -768,6 +813,13 @@ class TPUEngine:
                  and self.max_context % b == 0),
                 default=None,
             )
+            if cfg.state_kinds and self._prefix_chunk is None:
+                raise ValueError(
+                    f"{cfg.name}: a stack with kda layers admits every "
+                    f"prompt in chunks, and no prefill bucket up to "
+                    f"{self.prefill_chunk_default} divides the context "
+                    f"{self.max_context}"
+                )
             if prefix_cache is None:
                 prefix_cache = True
             if prefix_cache and self._prefix_chunk is not None:
@@ -784,7 +836,12 @@ class TPUEngine:
                     paged.RadixPrefixIndex if prefix_radix
                     else paged.PrefixIndex
                 )
-                if cfg.kinds:
+                if cfg.state_kinds:
+                    # no hit can be served without the state at its end
+                    # (paged.py's header): the hashes alone are kept, to
+                    # count the hits refused
+                    self.refused_prefixes = paged.SeenPrefixes(num_pages)
+                elif cfg.kinds:
                     # the index holds the full kind's pages; the window
                     # kind's ride beside it (paged.py's header)
                     self.prefix_index = index_cls(
@@ -805,7 +862,11 @@ class TPUEngine:
         # speculative verify does global pool scatters; under a
         # dp-partitioned pool those need a shard_map twin that does not
         # exist yet — refuse rather than corrupt replica-local pages
-        self.spec_supported = not (self.paged and self.pool_replicas > 1)
+        # (and a rejected token could not be rolled back out of a recurrent
+        # state: no verify graph for a stack with kda layers either)
+        self.spec_supported = not (
+            (self.paged and self.pool_replicas > 1) or cfg.state_kinds
+        )
 
         # -- Long-context tier (docs/ENGINE_PERF.md "Long-context tier") --
         # (1) Window+sink KV compression: past kv_compress_after rows a
@@ -839,6 +900,9 @@ class TPUEngine:
         ), 1)
         self.kv_compress_armed = False
         self._sink_rows = 0
+        refuse_for_state_kind(
+            cfg, window_and_sink_KV_compression=self.kv_compress_after > 0
+        )
         refuse_for_latent_pool(
             cfg, window_and_sink_KV_compression=self.kv_compress_after > 0
         )
@@ -889,6 +953,9 @@ class TPUEngine:
         self.seq_prefill_min = knob(
             seq_prefill_min, "AIOS_TPU_SEQ_PREFILL_MIN",
             getattr(cfg, "seq_prefill_min", 0),
+        )
+        refuse_for_state_kind(
+            cfg, sequence_sharded_prefill=self.seq_prefill_min > 0
         )
         refuse_for_latent_pool(
             cfg, sequence_sharded_prefill=self.seq_prefill_min > 0
@@ -941,6 +1008,13 @@ class TPUEngine:
         elif shardings is not None:
             k = shardings.put_cache(k, seq_shard=self.seq_sharded)
             v = shardings.put_cache(v, seq_shard=self.seq_sharded)
+        states = ()
+        if self.slot_states is not None:
+            with self.phases.phase("load.states"):
+                states = jax.block_until_ready((
+                    jnp.zeros(self.slot_states.state_shape, jnp.float32),
+                    jnp.zeros(self.slot_states.tail_shape, cache_dtype),
+                ))
         self.state: DecodeState = {
             "k": k,
             "v": v,
@@ -969,6 +1043,8 @@ class TPUEngine:
         self.moe_experts_visited = 0
         if self.counts_picks:
             self.state["moe_stats"] = model.zero_stats(cfg)[0]
+        if states:
+            self.state["kda_s"], self.state["kda_tail"] = states
         # rows x sub-layers whose residual was mixed (engine/residual.py),
         # from each dispatched program's static shapes: host-side, no
         # device work (`_devprof_note` sees every dispatch)
@@ -1087,6 +1163,12 @@ class TPUEngine:
         # device_put can lose to recompute).
         if prefix_host_bytes is None:
             prefix_host_bytes = getattr(cfg, "prefix_host_bytes", 0)
+        refuse_for_state_kind(
+            cfg,
+            the_host_spill_tier_and_its_KVX_entries=(
+                int(prefix_host_bytes or 0) > 0
+            ),
+        )
         refuse_for_latent_pool(
             cfg, the_host_spill_tier=int(prefix_host_bytes or 0) > 0
         )
@@ -1394,6 +1476,13 @@ class TPUEngine:
             return (t, jnp.asarray(self._win_starts))
         return t
 
+    def _states_of(self, st: DecodeState) -> tuple:
+        """The state kind's arrays of a decode state, () where the model has
+        no such kind."""
+        if self.slot_states is None:
+            return ()
+        return (st["kda_s"], st["kda_tail"])
+
     @staticmethod
     def _split_tables(tables):
         """Unpack a ``_tables_operand`` value into (tables, win_starts);
@@ -1433,11 +1522,14 @@ class TPUEngine:
                 win_starts=win_starts,
                 sink_rows=self._sink_rows,
                 layout=self._layout,
+                states=self._states_of(st),
             )
             if self.quant_cache:
                 logits, k, v, (k_s, v_s), *picks = out
             else:
                 logits, k, v, *picks = out
+            if self.slot_states is not None:
+                kda_s, kda_tail, *picks = picks
         elif self.quant_cache:
             logits, k, v, (k_s, v_s), *picks = model.decode_step(
                 params,
@@ -1504,6 +1596,8 @@ class TPUEngine:
             st["v_s"] = v_s
         if self.counts_picks:
             st["moe_stats"] = moe_stats + picks[0]
+        if self.slot_states is not None:
+            st["kda_s"], st["kda_tail"] = kda_s, kda_tail
         return st, next_tokens
 
     def _step_impl(self, params, state: DecodeState, n_steps: int, tables=None,
@@ -2007,12 +2101,13 @@ class TPUEngine:
         return out, first
 
     def _chunk_forward(self, params, state: DecodeState, tokens, slot, start,
-                       table_row, win_start=None):
+                       table_row, win_start=None, n_valid=None):
         """One prefill chunk against whichever cache layout this engine
         runs (paged / int8 KV / dense); returns (logits, kv-state updates).
         The single place the layout dispatch lives — both chunk impls
         build on it. ``win_start`` (armed engines only) masks the pruned
-        middle of a mid-admission compressed slot."""
+        middle of a mid-admission compressed slot. ``n_valid`` (a final
+        chunk's count of real rows) is what the state kind advances by."""
         upd: Dict[str, jnp.ndarray] = {}
         if self.paged:
             scales = (state["k_s"], state["v_s"]) if self.quant_cache else None
@@ -2021,6 +2116,7 @@ class TPUEngine:
                 table_row, cache_scales=scales, qmm=self._qmm_gspmd,
                 win_start=win_start, sink_rows=self._sink_rows,
                 moe_dense=self._moe_dense, layout=self._layout,
+                states=self._states_of(state), slot=slot, n_valid=n_valid,
             )
         else:
             scales = (state["k_s"], state["v_s"]) if self.quant_cache else None
@@ -2033,6 +2129,8 @@ class TPUEngine:
             logits, upd["k"], upd["v"], (upd["k_s"], upd["v_s"]), *picks = out
         else:
             logits, upd["k"], upd["v"], *picks = out
+        if self.slot_states is not None:
+            upd["kda_s"], upd["kda_tail"], *picks = picks
         if self.counts_picks:
             upd["moe_stats"] = state["moe_stats"] + picks[0]
         return logits, upd
@@ -2069,7 +2167,7 @@ class TPUEngine:
         """Last chunk: write K/V, then sample the first token from the
         logits row of the prompt's true last token and activate the slot."""
         logits, upd = self._chunk_forward(params, state, tokens, slot, start,
-                                          table_row, win_start)
+                                          table_row, win_start, n_valid)
         new = dict(state)
         new.update(upd)
         key, sub = jax.random.split(state["key"])
@@ -2126,7 +2224,16 @@ class TPUEngine:
 
     def _devprof_note(self, kind: str, key=None, need_slack: bool = False):
         if self.cfg.hc:
-            self.hc_mix_rows += self._mixed_rows(kind, key)
+            # through two sub-layers a layer
+            self.hc_mix_rows += (
+                self._program_rows(kind, key) * 2 * self.cfg.num_layers
+            )
+        if self.slot_states is not None:
+            rows = self._program_rows(kind, key) * self.slot_states.layers
+            if kind in ("step", "masked"):
+                self.kda_rows_decode += rows
+            else:
+                self.kda_rows_prefill += rows
         dp = self._devprof
         if dp is None:
             return None
@@ -2137,9 +2244,8 @@ class TPUEngine:
             due = False
         return (kind, key, time.perf_counter()) if due else None
 
-    def _mixed_rows(self, kind: str, key) -> int:
-        """Rows x sub-layers the program of this dispatch mixes: its token
-        rows, padding included, through two sub-layers a layer."""
+    def _program_rows(self, kind: str, key) -> int:
+        """Token rows of the program of this dispatch, padding included."""
         if kind == "step":
             rows = int(key) * self.num_slots
         elif kind == "masked":
@@ -2152,7 +2258,7 @@ class TPUEngine:
             rows = int(key[0])
         else:  # no forward pass (history, restore), or refused at load
             return 0
-        return rows * 2 * self.cfg.num_layers
+        return rows
 
     def _devprof_sample(self, tok) -> Optional[float]:
         if tok is None:
@@ -2951,13 +3057,20 @@ class TPUEngine:
         chunk starts inherit the misalignment, which the chunk writers are
         built for (prefill_chunk_paged's sacrificial-page slice padding,
         _chunk_history's clamped scatter)."""
-        if self.prefix_index is None:
+        if self.prefix_index is None and self.refused_prefixes is None:
             return 0, []
         P = self.allocator.page_size
         full = (len(ids) - 1) // P  # cap: at least one tail row remains
         if full <= 0:
             return 0, []
         hashes = paged.chain_hashes(ids, P, full)
+        if self.refused_prefixes is not None:
+            # the state kind: the hit is refused and counted, not served
+            seen = self.refused_prefixes.match(hashes)
+            if seen:
+                self.prefix_hits_refused_state += 1
+                self.prefix_rows_refused_state += seen * P
+            return 0, hashes
         pages = self.prefix_index.match(hashes)
         entries = []
         if self.host_store is not None and len(pages) < full:
@@ -3008,6 +3121,9 @@ class TPUEngine:
         """After a successful admission, publish the slot's fully-covered
         prompt blocks to the index so the NEXT prompt with this prefix
         skips their prefill. Caller holds the engine lock."""
+        if self.refused_prefixes is not None:
+            self.refused_prefixes.put(hashes)
+            return
         if self.prefix_index is None or not hashes:
             return
         if self.window_prefix is not None:
@@ -3199,7 +3315,7 @@ class TPUEngine:
             raise ValueError("empty prompt")
 
         matched, hashes = 0, []
-        if self.prefix_index is not None:
+        if self.prefix_index is not None or self.refused_prefixes is not None:
             with self._lock:
                 matched, hashes = self._match_prefix(slot, token_ids)
         if matched or (
@@ -3338,7 +3454,7 @@ class TPUEngine:
             )
         ids = list(token_ids)[-(self.max_context - 1) :]
         matched, hashes = 0, []
-        if self.prefix_index is not None:
+        if self.prefix_index is not None or self.refused_prefixes is not None:
             with self._lock:
                 matched, hashes = self._match_prefix(slot, ids)
         if not matched and self._seq_route_ok(len(ids)):
@@ -3521,6 +3637,9 @@ class TPUEngine:
         ``slot_length + counts[s] <= max_context - 2`` (the verify-write
         contract) and emits the run tokens itself — the forced tokens
         ARE the dispatch's output by construction."""
+        refuse_for_state_kind(
+            self.cfg, the_grammar_jump_ahead_and_its_verify_graph=True
+        )
         if not self.spec_supported:
             raise ValueError(
                 "jump-ahead dispatches are unsupported with a "
@@ -3766,6 +3885,10 @@ class TPUEngine:
         with self._lock:
             if self.allocator is not None:
                 self.allocator.free_slot(slot)  # pages recycle instantly
+            if self.slot_states is not None:
+                # nothing to hand back or to zero: the next tenant's first
+                # chunk starts from zeros (paged.py's header)
+                self.slot_states.free_slot(slot)
             self.state["lengths"] = self.state["lengths"].at[slot].set(0)
             self.state["active"] = self.state["active"].at[slot].set(False)
             if self.draft_state is not None:
@@ -3854,6 +3977,12 @@ class TPUEngine:
             out["kv_row_bytes"] = sum(self.cfg.kv_row_dims) * (
                 self.state["k"].dtype.itemsize if self.state else 0
             )
+        if self.slot_states is not None:
+            out.update(self.slot_states.stats())
+            out["kda_rows_prefill"] = self.kda_rows_prefill
+            out["kda_rows_decode"] = self.kda_rows_decode
+            out["prefix_hits_refused_state"] = self.prefix_hits_refused_state
+            out["prefix_rows_refused_state"] = self.prefix_rows_refused_state
         if self.counts_picks:
             # summed on the device, read back with the decode tokens
             out["moe_picks_total"] = self.moe_picks_total
@@ -4007,8 +4136,9 @@ class TPUEngine:
                 bucket // 2 + 1
             ) > self.allocator.capacity_blocks():
                 continue  # pool can't back prompts of this bucket anyway
-            if self._whole_prompt_rows and bucket // 2 + 1 > self._whole_prompt_rows:
-                continue  # pages by kind: such a prompt admits in chunks
+            if (self._whole_prompt_rows is not None
+                    and bucket // 2 + 1 > self._whole_prompt_rows):
+                continue  # such a prompt admits in chunks (prefill_async)
             if not ck or bucket <= ck:
                 self.compile_prefill_fn(bucket)
             if (
@@ -4232,6 +4362,8 @@ class ChunkedPrefill:
                     )
                 eng._maybe_compress(self.slot, length=self.pos)
                 eng.allocator.ensure(self.slot, self.pos + n)
+                if eng.slot_states is not None:
+                    eng.slot_states.take(self.slot)
                 extra = (jnp.asarray(eng.allocator.tables[self.slot]),)
                 if eng.kv_compress_armed:
                     extra += (

@@ -37,6 +37,25 @@ below goes through its ``expand`` / ``pre`` / ``post`` / ``collapse``. With
 expert layer differ only in their trees, and the stack is scanned segment by
 segment (``model.layer_segments``) with the pools carried through.
 
+Four variations of the block, each traced away where its field is at its
+default: ``cfg.q_lora_rank`` 0 is one direct query projection (``w_dqkv`` is
+then [W_q | W_dkv], and there is no ``w_uq`` or ``q_a_norm``);
+``cfg.latent_qk_norm`` norms each query head (``q_head_norm``) and the shared
+rotary key (``k_rope_norm``) before rotation: both key-side norms act on what
+is CACHED, so the absorbed form holds; ``cfg.rope_interleave`` rotates pairs
+(2i, 2i + 1) and keeps the rotated values in [evens | odds] order, in the
+queries and in the cache alike (a score is a sum over pairs: their order does
+not enter); ``cfg.attn_head_gate`` multiplies each head's output by
+``sigmoid(h w_hgate)`` of its own row.
+
+A stack may mix these layers with ``kda`` layers (``cfg.state_kinds``;
+engine/kda.py has their mixer), five to one in the one model that does: the
+scan's body is then one period whose layers' trees differ
+(``model.scan_segments``), only the ``mla`` layers have a layer of the latent
+pool (``kind_index`` of a layer's tree), and the graphs carry the state
+kind's two arrays beside the pools (engine/paged.py header): they take them
+as ``states`` and hand them back after the pools.
+
 Entry points mirror ``model``'s and return the same tuples, with the pools
 in the places of K and V, and like them ``moe.pick_stats`` summed over the
 layers as one more value where the model has a router.
@@ -52,7 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import ops
-from . import model, moe, residual
+from . import kda, model, moe, residual
 from .config import ModelConfig
 
 NEG_INF = -1e30
@@ -95,6 +114,20 @@ def _einsum32(spec: str, a, b):
     return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32))
 
 
+def _rope_pairs(x, cos, sin):
+    """The rotary embedding over pairs (2i, 2i + 1) of x [B, T, H, D], the
+    result in [evens | odds] order (cos / sin [B, T, D] as
+    ``model.apply_rope`` takes them: pair i turns by column i)."""
+    half = x.shape[-1] // 2
+    x1 = x[..., 0::2].astype(jnp.float32)
+    x2 = x[..., 1::2].astype(jnp.float32)
+    cos = cos[..., None, :half]
+    sin = sin[..., None, :half]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
 def _project(h, lp, cfg: ModelConfig, positions, qmm=None):
     """Normed rows h [B, T, E] -> (q_nope [B,T,H,nope], q_rope [B,T,H,rope]
     rotated, c [B,T,kv_lora_rank] normed, k_r [B,T,rope] rotated)."""
@@ -103,16 +136,25 @@ def _project(h, lp, cfg: ModelConfig, positions, qmm=None):
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     eps = cfg.rms_norm_eps
     cos, sin = rope_tables(positions, cfg)
+    rotate = _rope_pairs if cfg.rope_interleave else model.apply_rope
     with jax.named_scope("mla_q"):
         down = model.matmul(h, lp["w_dqkv"], qmm)
-        cq = model.rms_norm(down[..., :ql], lp["q_a_norm"], eps)
-        q = model.matmul(cq, lp["w_uq"], qmm).reshape(
-            B, T, cfg.num_heads, dn + dr
-        )
+        if ql:
+            cq = model.rms_norm(down[..., :ql], lp["q_a_norm"], eps)
+            q = model.matmul(cq, lp["w_uq"], qmm)
+        else:  # a direct query projection: w_dqkv is [W_q | W_dkv]
+            ql = cfg.num_heads * (dn + dr)
+            q = down[..., :ql]
+        q = q.reshape(B, T, cfg.num_heads, dn + dr)
+        if cfg.latent_qk_norm:
+            q = model.rms_norm(q, lp["q_head_norm"], eps)
         q_nope = q[..., :dn]
-        q_rope = model.apply_rope(q[..., dn:], cos, sin)
+        q_rope = rotate(q[..., dn:], cos, sin)
     c = model.rms_norm(down[..., ql:ql + kl], lp["kv_a_norm"], eps)
-    k_r = model.apply_rope(down[..., ql + kl:][:, :, None, :], cos, sin)
+    k_r = down[..., ql + kl:]
+    if cfg.latent_qk_norm:
+        k_r = model.rms_norm(k_r, lp["k_rope_norm"], eps)
+    k_r = rotate(k_r[:, :, None, :], cos, sin)
     return q_nope, q_rope, c, k_r[:, :, 0]
 
 
@@ -241,17 +283,34 @@ def _attn_input(x, lp, cfg: ModelConfig):
     return model.rms_norm(u, lp["attn_norm"], cfg.rms_norm_eps), mix
 
 
+def _head_gate(attn_flat, h, lp, cfg: ModelConfig):
+    """Each head's output [.., H * v] times sigmoid(h w_hgate) of its row."""
+    gate = jax.nn.sigmoid(
+        h.astype(jnp.float32) @ lp["w_hgate"].astype(jnp.float32)
+    )
+    heads = attn_flat.reshape(*attn_flat.shape[:-1], cfg.num_heads, -1)
+    return (heads * gate[..., None].astype(heads.dtype)).reshape(attn_flat.shape)
+
+
 def _finish_block(x, mix, attn_flat, lp, cfg: ModelConfig, moe_dense, qmm,
-                  allow_dispatch: bool = False, live=None):
+                  allow_dispatch: bool = False, live=None, gate_rows=None,
+                  mixed=None):
     """Output projection and FFN of one block, with the sandwich norms;
     returns (x', moe_aux, stats-or-None). ``live``: a decode step's slot
-    mask (model.ffn)."""
+    mask (model.ffn). ``gate_rows``: the normed rows the attention read,
+    where ``cfg.attn_head_gate`` gates its heads by them. ``mixed``: a kda
+    layer's mixer output, already through its own output projection."""
     eps = cfg.rms_norm_eps
-    with jax.named_scope("mla_out"):
-        a = model.matmul(attn_flat, lp["wo"], qmm, "row")
-        if cfg.sandwich_norm:
-            a = model.rms_norm(a, lp["post_attn_norm"], eps)
-        x = residual.post(x, a, mix, cfg)
+    if mixed is not None:
+        x = residual.post(x, mixed, mix, cfg)
+    else:
+        with jax.named_scope("mla_out"):
+            if cfg.attn_head_gate:
+                attn_flat = _head_gate(attn_flat, gate_rows, lp, cfg)
+            a = model.matmul(attn_flat, lp["wo"], qmm, "row")
+            if cfg.sandwich_norm:
+                a = model.rms_norm(a, lp["post_attn_norm"], eps)
+            x = residual.post(x, a, mix, cfg)
     u, mix = residual.pre(x, lp, "ffn", cfg)
     h = model.rms_norm(u, lp["ffn_norm"], eps)
     m, aux, stats = model.ffn(h, lp, cfg, allow_dispatch, moe_dense, qmm, live)
@@ -260,15 +319,36 @@ def _finish_block(x, mix, attn_flat, lp, cfg: ModelConfig, moe_dense, qmm,
     return residual.post(x, m, mix, cfg), aux, stats
 
 
+def _scan(block, carry, params, cfg: ModelConfig, experts_whole: bool):
+    """``model.scan_segments`` over this model's stack: segment by segment,
+    or period by period where kda and mla layers mix."""
+    return model.scan_segments(
+        block, carry, model.layer_segments(params), experts_whole,
+        cfg.period_kinds, cfg.lead_kinds,
+    )
+
+
+def _pool_layer(lp, l):
+    """The latent pool's layer of this block: its place among the mla
+    layers where the stack has others, else its index."""
+    return lp.get("kind_index", l)
+
+
 def forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None,
                     with_aux: bool = False, qmm=None,
                     moe_dense: bool = False, logit_row=None):
     """``model._forward_with_kv`` for a latent-attention model: (logits
     [B, T, V], latents [L, B, T, 1, kv_lora_rank], padded rotary parts
-    [L, B, T, 1, 128][, mean moe aux][, stats])."""
+    [L, B, T, 1, 128][, mean moe aux][, stats]). A stack with kda layers
+    returns None for both: it admits in chunks alone (the engine's rule),
+    and this whole-prompt forward is its parity path."""
     if attn_fn is not None:
         raise ValueError(
             f"{cfg.name}: latent attention has no sequence-sharded prefill"
+        )
+    if cfg.state_kinds and with_aux:
+        raise ValueError(
+            f"{cfg.name}: the training forward (with_aux) has no kda layers"
         )
     B, T = tokens.shape
     x = residual.expand(params["embed"][tokens], cfg)
@@ -278,6 +358,12 @@ def forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None,
         x, *stats = carry
         lp, _ = layer
         h, mix = _attn_input(x, lp, cfg)
+        if model.kind_of(lp) == "kda":
+            y, _, _ = kda.mix_prompt(h, lp, cfg, T, qmm)
+            x, _, new = _finish_block(
+                x, mix, None, lp, cfg, moe_dense, qmm, mixed=y
+            )
+            return (x, *model.add_stats(stats, new)), None
         q_nope, q_rope, c, k_r = _project(h, lp, cfg, positions, qmm)
         with jax.named_scope("attention"):
             attn = jax.vmap(
@@ -286,15 +372,19 @@ def forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None,
                 )
             )(q_nope, q_rope, c, k_r)
         x, aux, new = _finish_block(
-            x, mix, attn.reshape(B, T, -1), lp, cfg, moe_dense, qmm, with_aux
+            x, mix, attn.reshape(B, T, -1), lp, cfg, moe_dense, qmm, with_aux,
+            gate_rows=h,
         )
+        if cfg.state_kinds:
+            return (x, *model.add_stats(stats, new)), None
         rows = (c[:, :, None, :], _pad_rope(k_r, cfg)[:, :, None, :], aux)
         return (x, *model.add_stats(stats, new)), rows
 
-    (x, *stats), (cs, rs, auxs) = model.scan_segments(
-        block, (x, *model.zero_stats(cfg)), model.layer_segments(params),
+    (x, *stats), rows = _scan(
+        block, (x, *model.zero_stats(cfg)), params, cfg,
         moe.grouped_serves(B * T, cfg, moe_dense, with_aux),
     )
+    cs, rs, auxs = rows or (None, None, None)
     if logit_row is not None:  # the one row a prefill samples from
         x = jax.lax.dynamic_slice_in_dim(x, logit_row, 1, axis=1)
     logits = model._final_logits(
@@ -323,12 +413,18 @@ def _write_chunk(pool, l, rows, pages, off):
 
 def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, c_pool,
                         r_pool, table_row, qmm=None,
-                        moe_dense: bool = False):
+                        moe_dense: bool = False, states=(), slot=None,
+                        n_valid=None):
     """``model.prefill_chunk_paged`` over the latent pool: the chunk's
     latent rows are written by whole pages (or inside one), then each new
     row attends, in the EXPANDED form, over the slot's cached latent rows
     and the chunk's own — a turn's task behind its cached system prompt.
-    Returns (logits [1, Tc, V], c_pool', r_pool'[, stats])."""
+    Returns (logits [1, Tc, V], c_pool', r_pool'[, stats]).
+
+    ``states`` (a stack with kda layers: the state kind's two arrays,
+    engine/paged.py header) is carried beside the pools and handed back
+    after them; ``slot`` is whose state the chunk advances and ``n_valid``
+    how many of its rows are real (None: all)."""
     B, Tc = tokens.shape
     MB = table_row.shape[0]
     P = c_pool.shape[2]
@@ -339,11 +435,24 @@ def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, c_pool,
     pages, off = model.chunk_pages(table_row, start, Tc, P)
     blk = Q_TILE if C_log % Q_TILE == 0 else P
     n_blocks = (start + Tc + blk - 1) // blk  # as far as the newest row sees
+    if states:  # the scan carries the slot's own tails, not every slot's
+        states, tails = (states[0], kda.slot_tails(states[1], slot)), states[1]
 
     def block(carry, layer):
-        x, c_pool, r_pool, *stats = carry
+        x, c_pool, r_pool, states, *stats = carry
         lp, l = layer
         h, mix = _attn_input(x, lp, cfg)
+        if model.kind_of(lp) == "kda":
+            y, *states = kda.mix_chunk(
+                h, lp, cfg, *states, lp["kind_index"], slot, start,
+                Tc if n_valid is None else n_valid, qmm,
+            )
+            x, _, new = _finish_block(
+                x, mix, None, lp, cfg, moe_dense, qmm, mixed=y
+            )
+            return (x, c_pool, r_pool, tuple(states),
+                    *model.add_stats(stats, new)), None
+        l = _pool_layer(lp, l)
         q_nope, q_rope, c, k_r = _project(h, lp, cfg, positions, qmm)
         with jax.named_scope("mla_kv_write"):
             c_pool = _write_chunk(c_pool, l, c[0], pages, off)
@@ -365,19 +474,21 @@ def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, c_pool,
                 sm_scale(cfg), cfg.v_head_dim,
             )
         x, _, new = _finish_block(
-            x, mix, attn.reshape(B, Tc, -1), lp, cfg, moe_dense, qmm
+            x, mix, attn.reshape(B, Tc, -1), lp, cfg, moe_dense, qmm,
+            gate_rows=h,
         )
-        return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
+        return (x, c_pool, r_pool, states, *model.add_stats(stats, new)), None
 
-    (x, c_pool, r_pool, *stats), _ = model.scan_segments(
-        block, (x, c_pool, r_pool, *model.zero_stats(cfg)),
-        model.layer_segments(params),
-        moe.grouped_serves(B * Tc, cfg, moe_dense),
+    (x, c_pool, r_pool, states, *stats), _ = _scan(
+        block, (x, c_pool, r_pool, tuple(states), *model.zero_stats(cfg)),
+        params, cfg, moe.grouped_serves(B * Tc, cfg, moe_dense),
     )
+    if states:
+        states = (states[0], kda.put_slot_tails(tails, states[1], slot))
     logits = model._final_logits(
         residual.collapse(x, params, cfg), params, cfg, qmm
     )
-    return (logits, c_pool, r_pool, *stats)
+    return (logits, c_pool, r_pool, *states, *stats)
 
 
 def _write_targets(tables, rows, active, P: int):
@@ -392,11 +503,14 @@ def _write_targets(tables, rows, active, P: int):
 
 def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
                       r_pool, tables, kernels: Optional[bool] = None,
-                      active=None, moe_dense: bool = False, qmm=None):
+                      active=None, moe_dense: bool = False, qmm=None,
+                      states=()):
     """``model.decode_step_paged`` over the latent pool, in the ABSORBED
     form: each slot's new latent row is scattered to its page, and the
     kernel scores every head against the latent pages where they lie in the
-    carried pool. Returns (logits [B, V], c_pool', r_pool'[, stats])."""
+    carried pool. Returns (logits [B, V], c_pool', r_pool'[, stats]); with
+    ``states`` (prefill_chunk_paged) the state kind's arrays after the
+    pools, each kda layer's states updated in place."""
     B = tokens.shape[0]
     P = c_pool.shape[2]
     if active is None:
@@ -412,9 +526,19 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
         x = residual.expand(params["embed"][tokens][:, None, :], cfg)
 
     def block(carry, layer):
-        x, c_pool, r_pool, *stats = carry
+        x, c_pool, r_pool, states, *stats = carry
         lp, l = layer
         h, mix = _attn_input(x, lp, cfg)
+        if model.kind_of(lp) == "kda":
+            y, *states = kda.mix_step(
+                h, lp, cfg, *states, lp["kind_index"], active, use_kernel, qmm
+            )
+            x, _, new = _finish_block(
+                x, mix, None, lp, cfg, moe_dense, qmm, live=active, mixed=y
+            )
+            return (x, c_pool, r_pool, tuple(states),
+                    *model.add_stats(stats, new)), None
+        l = _pool_layer(lp, l)
         q_nope, q_rope, c, k_r = _project(h, lp, cfg, lengths[:, None], qmm)
         with jax.named_scope("mla_q"):
             q_lat = _absorb_q(q_nope[:, 0], lp, cfg)
@@ -431,20 +555,19 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
         with jax.named_scope("mla_out"):
             attn = _unabsorb_o(o_lat, lp, cfg)[:, None]
         x, _, new = _finish_block(
-            x, mix, attn, lp, cfg, moe_dense, qmm, live=active
+            x, mix, attn, lp, cfg, moe_dense, qmm, live=active, gate_rows=h
         )
-        return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
+        return (x, c_pool, r_pool, states, *model.add_stats(stats, new)), None
 
-    (x, c_pool, r_pool, *stats), _ = model.scan_segments(
-        block, (x, c_pool, r_pool, *model.zero_stats(cfg)),
-        model.layer_segments(params),
-        moe.visit_serves(cfg, moe_dense),
+    (x, c_pool, r_pool, states, *stats), _ = _scan(
+        block, (x, c_pool, r_pool, tuple(states), *model.zero_stats(cfg)),
+        params, cfg, moe.visit_serves(cfg, moe_dense),
     )
     with jax.named_scope("final_logits"):
         logits = model._final_logits(
             residual.collapse(x[:, 0], params, cfg), params, cfg, qmm
         )
-    return (logits, c_pool, r_pool, *stats)
+    return (logits, c_pool, r_pool, *states, *stats)
 
 
 def verify_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
@@ -455,6 +578,11 @@ def verify_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
     scattered through the tables, and each attends, absorbed, over its
     slot's gathered latent view up to itself, one slot at a time. Returns
     (logits [B, T, V], c_pool', r_pool'[, stats])."""
+    if cfg.state_kinds:
+        raise ValueError(
+            f"{cfg.name}: a verify step over kda layers would have to roll a "
+            "rejected token back out of the recurrent state; no graph does"
+        )
     B, T = tokens.shape
     MB, P = tables.shape[1], c_pool.shape[2]
     C = MB * P
@@ -491,7 +619,8 @@ def verify_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
             r_pool[l, tables].reshape(B, C, -1).astype(h.dtype), qpos,
         ))
         x, _, new = _finish_block(
-            x, mix, _unabsorb_o(o_lat, lp, cfg), lp, cfg, moe_dense, qmm
+            x, mix, _unabsorb_o(o_lat, lp, cfg), lp, cfg, moe_dense, qmm,
+            gate_rows=h,
         )
         return (x, c_pool, r_pool, *model.add_stats(stats, new)), None
 

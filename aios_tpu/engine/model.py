@@ -1051,7 +1051,7 @@ def _with_experts(lp, whole, l):
 
 
 def scan_segments(block, carry, segments, experts_whole: bool = False,
-                  kinds: Tuple[str, ...] = ()):
+                  kinds: Tuple[str, ...] = (), lead_kinds: Tuple[str, ...] = ()):
     """Run ``block(carry, (layer_params, l))`` over every layer of every
     segment in turn, one ``lax.scan`` a segment, the carry (the residual,
     the page pools, the expert counters) going through all of them and the
@@ -1066,15 +1066,16 @@ def scan_segments(block, carry, segments, experts_whole: bool = False,
     lies. A scanned slice of them would be its operand, and so a copy of
     the layer's experts each layer.
 
-    ``kinds`` (ModelConfig.period_kinds: a stack that mixes window and full
-    attention layers) makes the scan's body one PERIOD, its layers unrolled
-    in it: the window, the rotary table and the pages a layer reads are
-    constants of its place in the period, so ONE scan serves the whole
-    stack where a scan a run of like layers would be ten. The block finds
-    its layer's kind and place in the tree (``layer_kind``,
-    ``layer_in_period``; ``kind_of``)."""
+    ``kinds`` (ModelConfig.period_kinds: a stack that mixes kinds of layer)
+    makes the scan's body one PERIOD, its layers unrolled in it: the window,
+    the rotary table and the pages a layer reads are constants of its place
+    in the period, so ONE scan serves the whole stack where a scan a run of
+    like layers would be ten. The block finds its layer's kind and place in
+    the tree (``layer_kind``, ``layer_in_period``; ``kind_of``).
+    ``lead_kinds`` are the kinds of the leading dense layers before the
+    pattern (ModelConfig.lead_kinds), where the stack has them."""
     if kinds:
-        return _scan_periods(block, carry, segments, kinds)
+        return _scan_periods(block, carry, segments, kinds, lead_kinds)
     first, emitted = 0, []
     for seg in segments:
         n = jax.tree.leaves(seg)[0].shape[0]
@@ -1089,16 +1090,30 @@ def scan_segments(block, carry, segments, experts_whole: bool = False,
         )
         emitted.append(ys)
         first += n
+    return carry, _join_emitted(emitted)
+
+
+def _join_emitted(emitted):
+    """What the segments' scans emitted, as one stack over all layers."""
     if emitted[0] is None:
-        return carry, None
+        return None
     if len(emitted) == 1:
-        return carry, emitted[0]
-    return carry, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *emitted)
+        return emitted[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *emitted)
 
 
-def _scan_periods(block, carry, segments, kinds):
-    """``scan_segments`` for a stack of two kinds: one scan over the periods
-    of its one segment, the period's layers unrolled in the body.
+def _scan_periods(block, carry, segments, kinds, lead_kinds=()):
+    """``scan_segments`` for a stack that mixes kinds of layer: one scan over
+    the periods of its periodic segment, the period's layers unrolled in the
+    body; a segment of leading dense layers before it (``lead_kinds``, all of
+    one kind) is a scan of periods of one.
+
+    The layers of a period may differ in their TREES too (a ``kda`` and an
+    ``mla`` mixer): what every layer has alike (norms, the FFN) is stacked
+    over all of the segment's layers, and a kind's own leaves over that
+    kind's layers alone, under ``by_kind``; the block's tree is the two
+    together, with ``kind_index``, the layer's place among the stack's
+    layers of its kind (the index of its state or of its pages' layer).
 
     The expert stacks stay whole at EVERY token count, so model.ffn reads
     them in place (the visit path in a decode step, the grouped path
@@ -1109,10 +1124,33 @@ def _scan_periods(block, carry, segments, kinds):
     tests/test_mosaic_aot.py -k two_kinds). Below ``grouped_pays`` the
     grouped path computes up to a tile an expert where the dense path
     computes a few rows, and reads the same bytes."""
-    (seg,) = segments  # a model of two kinds has no leading dense layers
+    *lead, seg = segments
+    first, emitted = 0, []
+    for lead_seg in lead:
+        (kind,) = set(lead_kinds)
+        n = jax.tree.leaves(lead_seg)[0].shape[0]
+
+        def lead_block(carry, layer, kind=kind):
+            lp, l = layer
+            return block(carry, (
+                {**lp, "layer_kind": kind, "layer_in_period": 0,
+                 "kind_index": l}, l,
+            ))
+
+        carry, ys = jax.lax.scan(
+            lead_block, carry, (lead_seg, jnp.arange(first, first + n))
+        )
+        emitted.append(ys)
+        first += n
     period = len(kinds)
-    n = jax.tree.leaves(seg)[0].shape[0]
-    scanned, whole = _experts_apart(seg, True)
+    by_kind = seg.get("by_kind", {})
+    common = {k: v for k, v in seg.items() if k != "by_kind"}
+    n = jax.tree.leaves(common)[0].shape[0]
+    scanned, whole = _experts_apart(common, True)
+    # a layer's place among its period's layers of its kind, and how many
+    # layers of each kind stand before the pattern
+    place = [kinds[:i].count(kind) for i, kind in enumerate(kinds)]
+    before = {kind: lead_kinds.count(kind) for kind in set(kinds)}
 
     def period_block(carry, l0):
         ys = []
@@ -1128,16 +1166,24 @@ def _scan_periods(block, carry, segments, kinds):
             )
             lp = {**_with_experts(lp, whole, l0 + i),
                   "layer_kind": kind, "layer_in_period": i}
-            carry, y = block(carry, (lp, l0 + i))
+            if by_kind:
+                own = l0 // period * kinds.count(kind) + place[i]
+                lp.update(jax.tree.map(
+                    lambda a, own=own: jax.lax.dynamic_index_in_dim(
+                        a, own, 0, keepdims=False
+                    ), by_kind[kind],
+                ), kind_index=before[kind] + own)
+            carry, y = block(carry, (lp, first + l0 + i if first else l0 + i))
             ys.append(y)
         if ys[0] is None:
             return carry, None
         return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
     carry, ys = jax.lax.scan(period_block, carry, jnp.arange(0, n, period))
-    if ys is None:
-        return carry, None
-    return carry, jax.tree.map(lambda a: a.reshape(n, *a.shape[2:]), ys)
+    if ys is not None:
+        ys = jax.tree.map(lambda a: a.reshape(n, *a.shape[2:]), ys)
+    emitted.append(ys)
+    return carry, _join_emitted(emitted)
 
 
 def _scan_layers_over_cache(block, x, params, k_cache, v_cache, cache_scales,
@@ -1217,6 +1263,9 @@ def prefill_chunk_paged(
     sink_rows: int = 0,  # static sink rows (window+sink KV compression)
     moe_dense: bool = False,
     layout=None,  # paged.PoolLayout: the pool of a stack of two kinds
+    states=(),  # the state kind's arrays (a stack with kda layers), and
+    slot=None,  # whose state the chunk advances,
+    n_valid=None,  # and how many of its rows are real (None: all)
 ):
     """One chunk of an incremental prefill against the PAGED cache.
 
@@ -1248,7 +1297,8 @@ def prefill_chunk_paged(
 
         return latent.prefill_chunk_paged(
             params, cfg, tokens, start, k_pool, v_pool, table_row,
-            qmm=qmm, moe_dense=moe_dense,
+            qmm=qmm, moe_dense=moe_dense, states=states, slot=slot,
+            n_valid=n_valid,
         )
     B, Tc = tokens.shape
     P = k_pool.shape[2]
@@ -1427,6 +1477,7 @@ def decode_step_paged(
     win_starts: Optional[jnp.ndarray] = None,  # [B] int32 live-window start
     sink_rows: int = 0,  # static sink rows (window+sink KV compression)
     layout=None,  # paged.PoolLayout: the pool of a stack of two kinds
+    states=(),  # the state kind's arrays (a stack with kda layers)
 ):
     """One batched decode step over the PAGED slot cache.
 
@@ -1470,6 +1521,7 @@ def decode_step_paged(
         return latent.decode_step_paged(
             params, cfg, tokens, lengths, k_pool, v_pool, tables,
             kernels=kernels, active=active, moe_dense=moe_dense, qmm=qmm,
+            states=states,
         )
     if layout is not None:
         return _decode_step_kinds(

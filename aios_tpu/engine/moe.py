@@ -95,6 +95,17 @@ def _expert_einsum(spec: str, x: jnp.ndarray, w) -> jnp.ndarray:
     return jnp.einsum(spec, x, w)
 
 
+def _within_best_groups(choice: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """Scores [N, X] with every expert outside the ``cfg.topk_group`` best of
+    the ``cfg.n_group`` groups of consecutive experts at -inf; a group's
+    score is the sum of its two largest."""
+    by_group = choice.reshape(choice.shape[0], cfg.n_group, -1)
+    group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, cfg.topk_group)
+    kept = jnp.any(best[:, :, None] == jnp.arange(cfg.n_group), axis=1)
+    return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(choice.shape)
+
+
 def route(
     h: jnp.ndarray,  # [N, E] normalized hidden states
     w_router,  # [E, X]
@@ -112,18 +123,22 @@ def route(
     A selection ``bias`` (the layer tree's ``router_bias`` leaf: the
     auxiliary-loss-free balancing of that family) is added to the scores for
     the CHOICE of the top-k only; the weights are the unbiased scores of
-    the chosen."""
+    the chosen. With ``cfg.n_group`` the choice is group-limited: the
+    experts are n_group groups of consecutive experts, a group's score is
+    the sum of its two largest (biased) scores, and only the experts of
+    the ``cfg.topk_group`` best groups can be chosen."""
     if isinstance(w_router, dict):  # never quantized, but be safe
         w_router = w_router["q"].astype(jnp.float32) * w_router["s"]
     logits = (h.astype(jnp.float32) @ w_router.astype(jnp.float32))
     if cfg.moe_scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
-        if bias is None:
+        choice = scores if bias is None else scores + bias.astype(jnp.float32)
+        if cfg.n_group:
+            choice = _within_best_groups(choice, cfg)
+        if choice is scores:
             weights, idx = jax.lax.top_k(scores, cfg.num_experts_per_tok)
         else:
-            _, idx = jax.lax.top_k(
-                scores + bias.astype(jnp.float32), cfg.num_experts_per_tok
-            )
+            _, idx = jax.lax.top_k(choice, cfg.num_experts_per_tok)
             weights = jnp.take_along_axis(scores, idx, axis=-1)
         if cfg.norm_topk_prob:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)

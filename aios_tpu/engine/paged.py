@@ -49,6 +49,31 @@ served only where every window-kind block that holds a row of (m - window, m)
 is held too: else the longest shorter hit that is, else none
 (``prefix_hits_refused_window`` counts the hits cut short or refused).
 
+A THIRD kind of per-slot memory holds no rows at all: the STATE KIND of a
+stack whose layers are mostly linear-attention (``kda``) layers
+(ModelConfig.state_kinds; engine/kda.py). Such a layer keeps, a slot, one
+float32 recurrent state [heads, key, value] and the last taps - 1 rows of its
+convolution's input, which do not grow with the context and are overwritten
+by every token. They live in ONE array pair a model, indexed by SLOT and not
+through a table: states [L_kda, S + 1, heads, key, value] float32 and tails
+[L_kda, taps - 1, S', width] bfloat16 (``SlotStates``; slot S is the
+scratch slot a decode step hands its dead slots, as page 0 is the pools'; the
+tails' slots lie beside the width, S' = S + 1 rounded up to whole bfloat16
+tiles of 16 rows, because the TPU compiler relaid a [.., S + 1, taps - 1,
+width] array, and one of 49 slots, out into whole tiles and back in every
+decode program: 58 MB copied twice, tests/test_mosaic_aot.py -k state_kind).
+There are no pages to take or hand back: ``ensure`` and a slot's row budget
+count the latent kind's rows alone (only the stack's ``mla`` layers have a
+layer of the pool), a slot's state is RESET by its first chunk (a chunk that
+starts at row 0 reads zeros, whatever the last tenant left: nothing is zeroed
+at release), and ``free_slot`` has nothing to return. PREFIX SHARING there: a
+hit is REFUSED (``prefix_hits_refused_state``, with the rows it would have
+served in ``prefix_rows_refused_state``) and nothing is registered: the index
+would hold the latent rows of a prefix and not the state at its end, and rows
+served without the state are wrong, not slow. (A snapshot of the state at a
+block boundary, kept beside the index, is what would serve such a hit; there
+is none yet.)
+
 Allocation is a free-list pop, release a push — O(1), no compaction, no
 device traffic beyond the [S, MAX_BLOCKS] int32 table that rides along with
 each dispatch (a few hundred bytes). The scheduler's admission/retire cycle calls
@@ -386,6 +411,71 @@ class PageAllocator:
 
     def slot_rows_backed(self, slot: int) -> int:
         return int(self._blocks_used[slot]) * self.page_size
+
+
+class SlotStates:
+    """The host's account of the state kind (the module's header): the two
+    arrays' shapes and which slots' states are live. The arrays themselves
+    ride the engine's donated state beside the pools."""
+
+    def __init__(self, layers: int, num_slots: int, state_shape: Tuple[int, ...],
+                 tail_shape: Tuple[int, ...]) -> None:
+        self.layers = layers
+        self.num_slots = num_slots
+        # row num_slots: the scratch slot of a decode step's dead slots
+        self.state_shape = (layers, num_slots + 1, *state_shape)
+        taps, width = tail_shape
+        # (slots rounded up to whole bfloat16 tiles of 16 rows: the header)
+        self.tail_shape = (layers, taps, -(-(num_slots + 1) // 16) * 16, width)
+        self.live = np.zeros(num_slots, dtype=bool)
+
+    @property
+    def slot_bytes(self) -> int:
+        """One slot's states and tails over all the kda layers."""
+        state = int(np.prod(self.state_shape[2:])) * 4
+        tail = self.tail_shape[1] * self.tail_shape[3] * 2
+        return self.layers * (state + tail)
+
+    def take(self, slot: int) -> None:
+        self.live[slot] = True
+
+    def free_slot(self, slot: int) -> None:
+        self.live[slot] = False
+
+    def stats(self) -> Dict[str, int]:
+        return {
+            "kv_state_slots": int(self.live.sum()),
+            "kv_state_bytes": self.slot_bytes * (self.num_slots + 1),
+            "kv_state_bytes_live": self.slot_bytes * int(self.live.sum()),
+        }
+
+
+class SeenPrefixes:
+    """The block hashes of the prompts admitted, and no pages: what a model
+    with a state kind keeps in the prefix index's place, to count the hits
+    it refuses (the module's header). Bounded like the index, by the pool's
+    pages, oldest out first."""
+
+    def __init__(self, max_blocks: int) -> None:
+        self.max_blocks = max_blocks
+        self._seen: "OrderedDict[bytes, None]" = OrderedDict()
+
+    def match(self, hashes: Sequence[bytes]) -> int:
+        """How many leading blocks of the chain an earlier prompt had."""
+        n = 0
+        for h in hashes:
+            if h not in self._seen:
+                break
+            self._seen.move_to_end(h)
+            n += 1
+        return n
+
+    def put(self, hashes: Sequence[bytes]) -> None:
+        for h in hashes:
+            self._seen[h] = None
+            self._seen.move_to_end(h)
+        while len(self._seen) > self.max_blocks:
+            self._seen.popitem(last=False)
 
 
 class PoolLayout(NamedTuple):

@@ -197,6 +197,9 @@ DEVICE_WAIT_PHASES = ("engine.prefill", "engine.readback", "batcher.consume",
 SETUP_PHASES = (
     "load.model", "load.weights", "load.engine", "load.warmup", "load.attach",
     "warmup.trace", "warmup.lower", "warmup.compile",
+    # inside load.engine, for a model with a state kind alone (engine/paged.py
+    # header): the recurrent states' and tails' arrays made and placed
+    "load.states",
 )
 # every name a ``Phases`` object closes
 _ALL_PHASES = PHASES + SETUP_PHASES

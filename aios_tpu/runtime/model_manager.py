@@ -346,7 +346,7 @@ class ModelManager:
     def _kv_row_bytes(cfg, cache_dtype) -> float:
         """Bytes one KV row (both k and v, all layers) occupies."""
         item = 1 if cache_dtype == jnp.int8 else 2
-        return cfg.num_layers * sum(cfg.kv_row_dims) * item
+        return cfg.row_layers * sum(cfg.kv_row_dims) * item
 
     def _kv_bytes_per_chip(self, cfg, ctx, cache_dtype, kw) -> float:
         """Estimated per-chip HBM the KV cache will pin under the current
@@ -359,6 +359,15 @@ class ModelManager:
         if self.plan is not None:
             dp, tp = self.plan.dp, self.plan.tp
         rows = kw.get("paged_pool_rows") or self.num_slots * ctx
+        if cfg.state_kinds:
+            # the state kind (engine/paged.py header): a fixed size a slot
+            # beside the latent rows of the layers that have rows
+            from ..engine.paged import SlotStates
+
+            states = SlotStates(
+                cfg.layers_of("kda"), self.num_slots, *cfg.kda_state_shapes
+            )
+            return row * rows + states.stats()["kv_state_bytes"]
         if cfg.kinds and kw.get("paged_pool_rows"):
             # pages by kind (engine/paged.py header): the full layers hold
             # the pool's rows, the window layers what the same share of
@@ -514,9 +523,13 @@ class ModelManager:
                     )
             from ..engine.engine import (
                 refuse_for_latent_pool,
+                refuse_for_state_kind,
                 refuse_for_two_kinds,
             )
 
+            refuse_for_state_kind(
+                cfg, speculative_decoding_and_its_rollback=spec_on
+            )
             refuse_for_latent_pool(
                 cfg, speculative_decoding_with_verify_step_paged=spec_on
             )

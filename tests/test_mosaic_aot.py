@@ -849,3 +849,73 @@ def test_aot_two_kinds_compile_and_fit(rep_sharding, monkeypatch):
         assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"
         print(f"{name}: {live / 1e9:.2f} GB live, temp "
               f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+
+
+# --- a recurrent state a slot beside the latent pages (PR 40) ---------------
+
+
+def test_aot_state_kind_compile_and_fit(rep_sharding, monkeypatch):
+    """Ling-3.0-flash as the benchmark cuts it (published widths, 1 dense + 18
+    layers in three periods K K K M K K, 64 of 512 experts held, 48 slots x
+    4,096 rows): the recurrence's two kernels alone (`kda_step` in place in the
+    state pool, `kda_chunk` over eight sub-chunks), then the composed decode
+    step, a mid chunk and two final chunks, traced as on the chip. Each must
+    fit beside the 8.2 GB of weights, the 1.6 GB of states and the 0.8 GB
+    latent pool, and none may copy the state pool, a pool array or an expert
+    stack (a decode step that copied the states would double its bytes)."""
+    from aios_tpu import backend
+    from aios_tpu.engine import model as M
+    from aios_tpu.ops import kda as kda_ops
+
+    cfg, shapes = _bench_model("ling-3.0-flash-int8-ep8-d19.json",
+                               "bailing_hybrid.py", 4096)
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    rep = rep_sharding
+    params = jax.tree.map(lambda a: sds(rep, a.shape, a.dtype), shapes)
+    slots, blocks, pages = 48, 32, 49 * 32 + 1
+    pools = tuple(sds(rep, (cfg.row_layers, pages, 128, w), jnp.bfloat16)
+                  for w in cfg.kv_row_dims)
+    from aios_tpu.engine.paged import SlotStates
+
+    kind = SlotStates(cfg.layers_of("kda"), slots, *cfg.kda_state_shapes)
+    states = (sds(rep, kind.state_shape, jnp.float32),
+              sds(rep, kind.tail_shape, jnp.bfloat16))
+    big = {int(np.prod(a.shape)) for a in (*pools, *states)}
+    big |= {int(np.prod(a.shape)) for name, a in shapes["layers"].items()
+            if name.startswith("we_") for a in jax.tree.leaves(a)}
+    i32 = lambda *shape: sds(rep, shape, jnp.int32)  # noqa: E731
+    f32 = lambda *shape: sds(rep, shape, jnp.float32)  # noqa: E731
+    H, K = cfg.kda_heads, cfg.kda_key_dim
+
+    aot_compile(rep, kda_ops.kda_step, f32(slots, H, K), f32(slots, H, K),
+                f32(slots, H, K), f32(slots, H, K), f32(slots, H), states[0],
+                i32(), i32(slots))
+    aot_compile(rep, functools.partial(kda_ops.chunked, use_kernel=True),
+                f32(512, H, K), f32(512, H, K), f32(512, H, K), f32(512, H, K),
+                f32(512, H), f32(H, K, K))
+
+    def chunk(p, c, r, s, t, toks, start, row, slot, n):
+        return M.prefill_chunk_paged(p, cfg, toks, start, c, r, row,
+                                     states=(s, t), slot=slot, n_valid=n)
+
+    def step(p, c, r, s, t, toks, lens, tables, active):
+        return M.decode_step_paged(p, cfg, toks, lens, c, r, tables,
+                                   kernels=True, active=active, states=(s, t))
+
+    graphs = {"decode-step": (step, (params, *pools, *states, i32(slots),
+                                     i32(slots), i32(slots, blocks),
+                                     sds(rep, (slots,), jnp.bool_)))}
+    for t in (512, 128, 16):
+        graphs[f"chunk-{t}"] = (chunk, (params, *pools, *states, i32(1, t),
+                                        i32(), i32(blocks), i32(), i32()))
+    for name, (fn, args) in graphs.items():
+        compiled = jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(*args).compile()
+        copied = [res for op, res in _hlo_results(compiled.as_text())
+                  if op == "copy" and big & set(res)]
+        assert copied == [], f"{name}: copies a pool, the states or an expert stack"
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        print(f"{name}: {live / 1e9:.2f} GB live, "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB of temporaries")
+        assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"

@@ -280,7 +280,8 @@ def test_compile_events_ride_the_engine_lane_of_the_chrome_trace(loaded):
     assert len(compiles) >= PARENT_COMPILES
     assert all(e["ph"] == "i" and e["tid"] == 0 and "graph" in e["args"] for e in compiles)
     spans = {e["name"] for e in events if e.get("cat") == "phase"}
-    assert set(flightrec.SETUP_PHASES) <= spans
+    # (`load.states` is a model's with a state kind alone: tests/test_ling3.py)
+    assert set(flightrec.SETUP_PHASES) - {"load.states"} <= spans
 
 
 def test_with_the_recorder_disabled_set_up_still_counts_and_leaves_no_event(monkeypatch):
