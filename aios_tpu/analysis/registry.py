@@ -21,7 +21,9 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
-# hazard classes rule lock-discipline knows how to spot
+# hazard classes rule lock-discipline knows how to spot. A lock forbids
+# these three unless it says otherwise; "placement" (below) is forbidden
+# only by a lock that names it
 HAZARDS = ("dispatch", "readback", "rpc")
 
 
@@ -48,8 +50,13 @@ class LockDecl:
 # the router/scheduler/scrape threads that share it.
 
 LOCKS: Tuple[LockDecl, ...] = (
+    # ... and "placement": the lock is what the dispatch worker waits for
+    # between two decode dispatches, so what the scheduler's thread does
+    # under it (a retirement, an admission's set-up) holds the device up.
+    # Operands are built before the lock and handed to the compiled graph
+    # as numpy values; what stays is waived with its reason (PR 41)
     LockDecl("engine", "aios_tpu.engine.engine", "TPUEngine", "_lock",
-             forbids=("readback", "rpc")),
+             forbids=("readback", "rpc", "placement")),
     LockDecl("engine_spill", "aios_tpu.engine.engine", "TPUEngine",
              "_spill_lock"),
     LockDecl("prefix_index", "aios_tpu.engine.paged", "_PrefixIndexBase",
@@ -168,6 +175,14 @@ HOOK_TARGETS: Dict[Tuple[str, str], Tuple[str, str]] = {
         ("aios_tpu.engine.paged", "_PrefixIndexBase.reclaim"),
 }
 
+# locals whose class the AST cannot infer: (module, qualname, local name)
+# -> (module, class), so that `eng._lock` in a driver object's method
+# resolves to the engine's lock
+LOCAL_TYPES: Dict[Tuple[str, str, str], Tuple[str, str]] = {
+    ("aios_tpu.engine.engine", "ChunkedPrefill.step_async", "eng"):
+        ("aios_tpu.engine.engine", "TPUEngine"),
+}
+
 # closure-passed locks: (module, qualname, local name) -> lock name
 # (the static spill worker receives the spill lock as a parameter)
 LOCAL_LOCKS: Dict[Tuple[str, str, str], str] = {
@@ -194,6 +209,17 @@ READBACK_CHAINS = frozenset({("np", "asarray")})
 READBACK_TERMINALS = frozenset({
     "block_until_ready", "device_get", "item", "copy_to_host_async",
 })
+
+# Host->device placement of an operand one value at a time, and an eager
+# `x.at[...].set(...)` (several small programs): each a point at which the
+# thread gives the interpreter up while it holds the lock. Reported in the
+# lock-readback family (the same cure: move it out of the lock's body).
+# The `.at[...]` shape is matched on the AST (rules._hazard_class).
+PLACEMENT_CHAINS = frozenset({
+    ("jnp", "asarray"), ("jnp", "array"), ("jnp", "int32"),
+    ("jnp", "float32"),
+})
+AT_UPDATE_TERMINALS = frozenset({"set", "add", "multiply", "min", "max"})
 
 # blocking RPC / host waits: gRPC stubs, channel readiness, future
 # results, sleeps, joins. `.get(` is deliberately absent (dict.get).
@@ -279,6 +305,8 @@ class Registry:
         default_factory=lambda: dict(HOOK_TARGETS))
     local_locks: Dict[Tuple[str, str, str], str] = field(
         default_factory=lambda: dict(LOCAL_LOCKS))
+    local_types: Dict[Tuple[str, str, str], Tuple[str, str]] = field(
+        default_factory=lambda: dict(LOCAL_TYPES))
     dispatch_hygiene_modules: Tuple[str, ...] = DISPATCH_HYGIENE_MODULES
     silent_except_prefixes: Tuple[str, ...] = SILENT_EXCEPT_PREFIXES
     silent_except_recorders: frozenset = SILENT_EXCEPT_RECORDERS

@@ -6,7 +6,9 @@ Rule ids (the ``--rule`` filter and waiver pragmas use these):
     discipline: no device dispatch, no D2H readback, no blocking
     RPC/wait inside a declared lock's body (call-graph-aware one level
     deep; each lock declares which classes it forbids — the engine lock
-    shelters dispatch by design, so it forbids only readback + RPC);
+    shelters dispatch by design, so it forbids readback + RPC, and, in
+    the readback family, PLACEMENT: an operand put on the device one
+    value at a time or an eager ``.at[...]`` update under it);
   * ``lock-order`` — the static acquired-while-holding graph over the
     declared locks must be acyclic;
   * ``guarded-by`` — fields annotated ``#: guarded_by <lock-attr>`` may
@@ -148,8 +150,13 @@ class Analyzer:
             owner, attr = expr.value.id, expr.attr
             if owner == "self" and func is not None:
                 return self._decl_for_class_attr(mi, func.class_name, attr)
-            # `<global-or-param>.attr` — only registry globals resolve
+            # `<global-or-param>.attr` — only registry globals and the
+            # registry's typed locals resolve
             tgt = self.reg.global_types.get(owner)
+            if tgt is None and func is not None:
+                tgt = self.reg.local_types.get(
+                    (mi.name, func.qualname, owner)
+                )
             if tgt is not None:
                 tmod = self.by_name.get(tgt[0])
                 if tmod is not None:
@@ -233,11 +240,22 @@ class Analyzer:
     @staticmethod
     def _hazard_class(call: ast.Call) -> Optional[Tuple[str, str]]:
         """(hazard, description) for a call, else None."""
+        f = call.func
+        if (
+            isinstance(f, ast.Attribute)
+            and f.attr in reg.AT_UPDATE_TERMINALS
+            and isinstance(f.value, ast.Subscript)
+            and isinstance(f.value.value, ast.Attribute)
+            and f.value.value.attr == "at"
+        ):
+            return ("placement", f".at[...].{f.attr}")
         chain = callee_chain(call)
         if not chain:
             return None
         term = chain[-1]
         dotted = ".".join(chain)
+        if tuple(chain[-2:]) in reg.PLACEMENT_CHAINS:
+            return ("placement", dotted)
         if tuple(chain[-2:]) in reg.READBACK_CHAINS or (
             term in reg.READBACK_TERMINALS
         ):
@@ -379,18 +397,17 @@ class Analyzer:
             if hz is not None and hz[0] in decl.forbids:
                 # waivable at the inner hazard line, the call site, or
                 # the governing with statement
-                key = ("lock-" + hz[0], cmod.path, sub.lineno, decl.name)
+                rule = _RULE_OF[hz[0]]
+                key = (rule, cmod.path, sub.lineno, decl.name)
                 if key in self._seen:
                     continue
                 self._seen.add(key)
                 reason = (
-                    cmod.waiver_for("lock-" + hz[0], sub.lineno)
-                    or call_mi.waiver_for(
-                        "lock-" + hz[0], call_line, scope_line
-                    )
+                    cmod.waiver_for(rule, sub.lineno)
+                    or call_mi.waiver_for(rule, call_line, scope_line)
                 )
                 self.findings.append(Finding(
-                    "lock-" + hz[0], cmod.path, sub.lineno,
+                    rule, cmod.path, sub.lineno,
                     f"{hz[1]}(...) runs under lock '{decl.name}' via "
                     f"{cfn.qualname} (called at {call_mi.path}:"
                     f"{call_line}) — {_HAZARD_WHY[hz[0]]}",
@@ -406,7 +423,7 @@ class Analyzer:
         hz: Tuple[str, str],
         scope_line: int,
     ) -> None:
-        rule = "lock-" + hz[0]
+        rule = _RULE_OF[hz[0]]
         key = (rule, mi.path, line, decl.name)
         if key in self._seen:
             return
@@ -789,6 +806,11 @@ class Analyzer:
                         ))
 
 
+# the rule id a hazard class is reported (and waived) under: a placement
+# is of the lock-readback family
+_RULE_OF = {"dispatch": "lock-dispatch", "readback": "lock-readback",
+            "rpc": "lock-rpc", "placement": "lock-readback"}
+
 _HAZARD_WHY = {
     "dispatch": "a graph call/compile stalls every thread sharing the "
                 "lock (router probes, scrape callbacks, the scheduler)",
@@ -796,6 +818,11 @@ _HAZARD_WHY = {
                 "transfer (the PR 4/6 bug class)",
     "rpc": "a blocking wait under a lock invites deadlock and "
            "convoying",
+    "placement": "an operand placed one value at a time, or an eager "
+                 "update's small programs, are each a point at which the "
+                 "thread gives the interpreter up with the lock held, and "
+                 "the dispatch worker waits (build the operands before the "
+                 "lock, as numpy values for the compiled graph)",
 }
 
 

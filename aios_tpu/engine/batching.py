@@ -98,6 +98,18 @@ STALL_MEDIAN_MIN = 8
 # and a queue behind full slots legitimately stands for seconds), leaves
 # one recorder snapshot with cause "no_progress". Looked at once every
 # NO_PROGRESS_CHECK_SECS.
+# A chunk's operands are placed on the device a tick ahead (behind the
+# hand-over, ChunkedPrefill.stage) where the scheduler's last wait for a
+# dispatch's tokens, the room it has a tick, was under this. An unstaged
+# issue is 8-11 ms of placements on the scheduler's thread and 5 ms of
+# enqueue on the worker's, between the read of one dispatch's tokens and
+# the enqueue of the next but one: with less room than that the device
+# waits for them (a 14 ms dispatch: 12 ms of room, 11 % of the device
+# idle), with more they are hidden already, and the placements' work
+# right after an emission is then where it has always been for the
+# streams' delivery (a 22-24 ms dispatch: PERF.md, PR 41)
+STAGE_UNDER_SLACK_S = 0.018
+
 NO_PROGRESS_DISPATCHES = 64
 NO_PROGRESS_MIN_SECS = 10.0
 NO_PROGRESS_CHECK_SECS = 1.0
@@ -135,6 +147,12 @@ class Request:
     # claims the terminal event and resumes the stream on a surviving
     # replica instead of surfacing a truncation. None = no failover.
     failover: object = field(default=None, repr=False, compare=False)
+    # the prompt's chain hashes (paged.PromptHashes), computed once on the
+    # thread that submits: by the pool, which routes on them, else by
+    # ContinuousBatcher.submit. The engine's prefix match takes them where
+    # they are its own truncation's (engine.prompt_hashes) and then does
+    # not hash under its lock. None: not computed, or nothing reads them.
+    prefix_hashes: object = field(default=None, repr=False, compare=False)
 
 
 @dataclass
@@ -301,6 +319,19 @@ class ContinuousBatcher:
         self._firsts: List[_PendingFirst] = []
         self.admissions = 0
         self.admissions_read_after_dispatch = 0
+        # retirements whose engine half (page frees, device resets, the
+        # timeline's close, _END) is still to run: _finish frees the slot
+        # on the host and queues the rest, _settle_retired runs it, at
+        # once unless the pipelined tick holds it back until the dispatch
+        # it has just handed over holds the engine lock (_decode_tick).
+        # Never kept across two ticks. And how many ran behind a dispatch
+        self._retired: List[_Live] = []
+        self._hold_retired = False
+        self.retirements_behind_dispatch = 0
+        # the pipelined tick's last wait for a dispatch's tokens in a tick
+        # that read no first token before it: the room the host has a
+        # tick (STAGE_UNDER_SLACK_S). 0 until one is seen: stage
+        self._slack_s = 0.0
         # host-gap accounting: wall time between consecutive decode
         # dispatches spent on the host (the device-idle window the
         # pipeline exists to close); bench_dispatch reads the totals
@@ -743,6 +774,12 @@ class ContinuousBatcher:
             from . import jsonmode
 
             live.constraint = jsonmode.JsonConstraint(self._json_mask_cache())
+        # hashed here, on the caller's thread, where the pool has not (and
+        # again where its hashes are another truncation's): the admission's
+        # prefix match then takes the engine lock without hashing under it
+        req.prefix_hashes = self.engine.prompt_hashes(
+            req.prompt_ids, req.prefix_hashes
+        )
         with self._qlock:
             if self._closed:
                 # shutdown() already drained the queue; an enqueue now
@@ -854,9 +891,11 @@ class ContinuousBatcher:
         self._admitted(live, first, t0, fields)
 
     def _free_slots(self) -> List[int]:
-        return [
-            s for s in self.engine.free_slots() if s != self._reserved_slot
-        ]
+        """Slots a new tenant may take: free on the host, not reserved by
+        the chunked admission, and with their last tenant's pages back."""
+        held = {live.slot for live in self._retired}
+        held.add(self._reserved_slot)
+        return [s for s in self.engine.free_slots() if s not in held]
 
     def _admit(self) -> None:
         while True:
@@ -969,6 +1008,7 @@ class ContinuousBatcher:
                         temperature=live.req.temperature,
                         top_p=live.req.top_p,
                         chunk=self.prefill_chunk,
+                        given=live.req.prefix_hashes,
                     ),
                 )
                 self._prefill_chunks = 0
@@ -983,6 +1023,7 @@ class ContinuousBatcher:
                     ids,
                     temperature=live.req.temperature,
                     top_p=live.req.top_p,
+                    given=live.req.prefix_hashes,
                 )
             except PoolExhausted as e:
                 with self._qlock:
@@ -1364,17 +1405,43 @@ class ContinuousBatcher:
             live.abort_reason = abort_reason
         with self._lock:
             self._live.pop(live.slot, None)
-        self.engine.release(live.slot)
+        # free on the host at once (what the next dispatch's backing and
+        # the occupancy read); the engine's half follows in
+        # _settle_retired, and no new tenant takes the slot before it
+        self._retired.append(live)
+        self.engine.retire(live.slot)
         if was_cancelled:
             self.cancellations += 1
             self._obs_cancelled.inc()
         else:
             self.completed += 1
             self._obs_completed.inc()
-        self._rec_close(live)
-        # _END goes last: when a consumer unblocks, all scheduler-side state
-        # (slot freed, counters bumped) is already final
-        live.out_q.put(_END)
+        if not self._hold_retired:
+            self._settle_retired()
+
+    def _settle_retired(self) -> None:
+        """The engine's half of the retirements _finish queued: the page
+        frees and the two device resets under the engine lock, the
+        timeline's close, the consumer's end of stream. With a pipelined
+        dispatch handed over it runs BEHIND it: only once that dispatch
+        holds the engine lock, so that the worker's enqueue is never kept
+        waiting by a retirement (the device idled 5-24 ms where it was:
+        PERF.md, PR 41); every later engine call orders after the
+        dispatch anyway. With none pending it runs as it is. A stream
+        leaves the queue before its turn: one that fails still ends, and
+        the rest stay queued for the teardown."""
+        if self._retired and self._pending is not None:
+            self._pending.pending.wait_started()
+            self.retirements_behind_dispatch += len(self._retired)
+        while self._retired:
+            live = self._retired.pop(0)
+            try:
+                self.engine.release_pages(live.slot)
+                self._rec_close(live)
+            finally:
+                # _END goes last: when a consumer unblocks, the slot is
+                # free, its pages are back and the counters are final
+                live.out_q.put(_END)
 
     def _reap_cancelled(self) -> None:
         """Free every cancelled request before admission/decode: queued ones
@@ -1486,6 +1553,15 @@ class ContinuousBatcher:
         # on the device goes the same way, unread
         self._pending = None
         self._firsts = []
+        # retirements a failing tick left queued ended as they ended:
+        # their streams close without an abort reason
+        self._hold_retired = False
+        while self._retired:
+            try:
+                self._settle_retired()
+            # aios: waive(silent-except): best-effort page release during teardown of a stream that had already finished — the failure that brought the scheduler down is recorded by _abort_all
+            except Exception:  # noqa: BLE001
+                pass
         if self._prefilling is not None:
             victims.append(self._prefilling[0])
             self._prefilling = None
@@ -1662,6 +1738,9 @@ class ContinuousBatcher:
         out["admissions_read_after_dispatch"] = (
             self.admissions_read_after_dispatch
         )
+        # retirements whose engine half ran behind a dispatch that held
+        # the engine lock (the rest had none to run behind)
+        out["retirements_behind_dispatch"] = self.retirements_behind_dispatch
         out["oldest_no_progress_s"] = round(self.oldest_no_progress_s(), 3)
         return out
 
@@ -2068,9 +2147,29 @@ class ContinuousBatcher:
             # measured on the chip, the streams' p99 gap is 1.6-2 ms
             # shorter than with ``prev`` consumed first (PERF.md, PR 39)
             self.admissions_read_after_dispatch += len(self._firsts)
-            self._land_firsts()
-            if prev is not None:
-                self._consume(prev)
+            if (self._prefilling is not None
+                    and self._slack_s < STAGE_UNDER_SLACK_S):
+                # the chunk the NEXT tick issues: its operands go to the
+                # device now, while the dispatch before this one still
+                # runs and this thread would only wait for its tokens
+                with phase("batcher.prefill"):
+                    self._prefilling[1].stage()
+            landing = bool(self._firsts)
+            # a stream that ends in these tokens is retired behind the
+            # dispatch just handed over (_settle_retired): the worker
+            # takes the engine lock first, the retirement's frees after
+            self._hold_retired = True
+            try:
+                self._land_firsts()
+                if prev is not None:
+                    self._consume(prev)
+                    if not landing:
+                        self._slack_s = self._gap_wait
+            finally:
+                self._hold_retired = False
+            if self._retired:
+                with phase("batcher.retire"):
+                    self._settle_retired()
             return
         try:
             with phase("batcher.dispatch"):

@@ -24,11 +24,12 @@ price of a fixed-shape graph and it is what keeps XLA fast.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -333,6 +334,16 @@ def refuse_for_state_kind(cfg, **asked) -> None:
 # Device-resident decode state, threaded through the jitted cores as one
 # donated pytree: {k, v, lengths, last_tokens, temps, top_ps, key}
 DecodeState = Dict[str, jnp.ndarray]
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _reset_slot(lengths, active, slot):
+    """A freed slot's two device resets as ONE small program with one
+    scalar operand. As two eager ``.at[slot].set(...)`` they were a dozen
+    (index arithmetic, conversions, two scatters), each a point at which
+    the calling thread gave the interpreter up with the engine lock held:
+    14 ms a retirement among the serving threads (PERF.md, PR 41)."""
+    return lengths.at[slot].set(0), active.at[slot].set(False)
 
 
 class PendingDecode:
@@ -1152,6 +1163,11 @@ class TPUEngine:
         self.decode_steps = 0
         self.prefix_rows_reused = 0
         self.prefix_rows_restored = 0
+        # prefix matches that took the submitter's hashes (the prompt was
+        # not hashed under the engine lock), and matched prefixes whose
+        # history backfill was not issued because no history is kept
+        self.admissions_prehashed = 0
+        self.history_backfills_skipped = 0
 
         # Host-RAM spill tier behind the prefix cache: HBM evictions copy
         # their page KV device->host (paged.HostPageStore) instead of
@@ -1471,8 +1487,10 @@ class TPUEngine:
         the donated state — it changes only at prune events, exactly like
         the tables change only at alloc events). Caller holds the engine
         lock."""
+        # aios: waive(lock-readback): the decode dispatch's own operand, placed by the thread that makes the graph call (the worker); the tables change under the lock until this instant, and one placement a dispatch is what engine.enqueue_ms has always held
         t = jnp.asarray(self.allocator.tables)
         if self.kv_compress_armed:
+            # aios: waive(lock-readback): as the tables beside it
             return (t, jnp.asarray(self._win_starts))
         return t
 
@@ -2731,9 +2749,13 @@ class TPUEngine:
             self._prefill_fns[key] = fn
         return fn
 
-    def _write_history(self, slot: int, ids: List[int], start: int = 0) -> None:
+    def _write_history(self, slot: int, ids: Sequence[int],
+                       start: int = 0) -> None:
         """Backfill history cols [start, start+len(ids)) in bucket-sized
-        dispatches (a matched prefix can exceed the largest bucket)."""
+        dispatches (a matched prefix can exceed the largest bucket).
+        Caller holds the engine lock: the operands go to the graph call
+        as numpy values, placed by the call itself."""
+        slot_op = np.int32(slot)
         pos = 0
         while pos < len(ids):
             seg = ids[pos : pos + self.buckets[-1]]
@@ -2742,8 +2764,7 @@ class TPUEngine:
             padded[0, : len(seg)] = seg
             self._devprof_note("hist", ("hist", bucket))
             self.state = self._hist_fn(bucket)(
-                self.state, jnp.asarray(padded), jnp.int32(slot),
-                jnp.int32(start + pos),
+                self.state, padded, slot_op, np.int32(start + pos),
             )
             pos += len(seg)
 
@@ -3040,9 +3061,14 @@ class TPUEngine:
         )
         return pages
 
-    def _match_prefix(self, slot: int, ids: List[int]):
+    def _match_prefix(self, slot: int, ids: Sequence[int],
+                      given: Optional[paged.PromptHashes] = None):
         """Map the longest hash-matched prompt prefix into ``slot``'s page
-        table and backfill its token history. HBM-resident blocks map as
+        table and, where the engine keeps a history, backfill it. ``ids``
+        is the admission-truncated prompt (an int32 array or a list);
+        ``given`` the submitter's hashes of it, taken where they are this
+        truncation's (``prompt_hashes``): the prompt is then not hashed
+        under the lock. HBM-resident blocks map as
         shared read-only pages (zero compute, zero new pages); when the
         hash chain continues into the host spill tier — and the run
         clears ``host_restore_min_pages`` — fresh pages are allocated and
@@ -3057,13 +3083,15 @@ class TPUEngine:
         chunk starts inherit the misalignment, which the chunk writers are
         built for (prefill_chunk_paged's sacrificial-page slice padding,
         _chunk_history's clamped scatter)."""
-        if self.prefix_index is None and self.refused_prefixes is None:
+        mine = self.prompt_hashes(ids, given)
+        if mine is None:
+            return 0, []
+        if mine is given:
+            self.admissions_prehashed += 1
+        if not mine.hashes:
             return 0, []
         P = self.allocator.page_size
-        full = (len(ids) - 1) // P  # cap: at least one tail row remains
-        if full <= 0:
-            return 0, []
-        hashes = paged.chain_hashes(ids, P, full)
+        hashes = mine.hashes
         if self.refused_prefixes is not None:
             # the state kind: the hit is refused and counted, not served
             seen = self.refused_prefixes.match(hashes)
@@ -3073,7 +3101,7 @@ class TPUEngine:
             return 0, hashes
         pages = self.prefix_index.match(hashes)
         entries = []
-        if self.host_store is not None and len(pages) < full:
+        if self.host_store is not None and len(pages) < len(hashes):
             entries = self.host_store.match_chain(hashes[len(pages) :])
             if len(entries) < self.host_restore_min_pages:
                 entries = []  # below the floor: recompute beats device_put
@@ -3111,10 +3139,17 @@ class TPUEngine:
         matched = (len(pages) + len(restored)) * P
         if not matched:
             return 0, hashes
-        # the n-gram proposer reads history[0:length] — backfill the
-        # shared region (padding past `matched` inside the last segment's
-        # bucket is overwritten by the tail chunks writing [matched, len))
-        self._write_history(slot, ids[:matched])
+        if self.track_history:
+            # the n-gram proposer reads history[0:length] — backfill the
+            # shared region (padding past `matched` inside the last
+            # segment's bucket is overwritten by the tail chunks writing
+            # [matched, len))
+            self._write_history(slot, ids[:matched])
+        else:
+            # no history is kept: the decode step leaves the array alone
+            # and every proposer that reads it refuses to build, so the
+            # programs that would fill it are not issued
+            self.history_backfills_skipped += 1
         return matched, hashes
 
     def _register_prefix(self, slot: int, ids: List[int], hashes) -> None:
@@ -3152,19 +3187,40 @@ class TPUEngine:
         pages = [int(self.allocator.tables[slot, b]) for b in range(len(hashes))]
         self.prefix_index.put(hashes, pages)
 
+    def prompt_hashes(
+        self, token_ids: Sequence[int],
+        given: Optional[paged.PromptHashes] = None,
+    ) -> Optional[paged.PromptHashes]:
+        """The chain hashes admission matches and registers a prompt
+        under — THE rule, in this one place: those of the
+        ``(rows - 1) // page_size`` full blocks (at least one tail row
+        remains) of the prompt's last ``rows = min(len, max_context - 1)``
+        ids. Computed once a request on the thread that submits it (the
+        serving pool for routing, else ``ContinuousBatcher.submit``) and
+        handed down as ``given``, which is taken as it is where it was
+        computed over this truncation and hashed again where not
+        (another context length or page size). None where nothing would
+        read them: no prefix index and no state kind's seen prefixes."""
+        if self.prefix_index is None and self.refused_prefixes is None:
+            return None
+        P = self.allocator.page_size
+        rows = min(len(token_ids), self.max_context - 1)
+        if given is not None and (given.rows, given.page_size) == (rows, P):
+            return given
+        full = (rows - 1) // P
+        ids = token_ids[len(token_ids) - rows :]
+        return paged.PromptHashes(
+            rows, P, paged.chain_hashes(ids, P, full) if full > 0 else []
+        )
+
     def prefix_hashes(self, token_ids: List[int]) -> List[bytes]:
-        """Chain hashes of the prompt's full blocks, truncated exactly as
-        admission truncates — computed ONCE per request by the serving
-        pool and shared across its replicas' overlap probes (replicas of
-        one model share page size and truncation)."""
+        """``prompt_hashes`` as the bare list the routers' overlap probes
+        and the fleet's exports take; empty where there is no prefix
+        index to probe (replicas of one model share page size and
+        truncation)."""
         if self.prefix_index is None:
             return []
-        ids = list(token_ids)[-(self.max_context - 1) :]
-        P = self.allocator.page_size
-        full = (len(ids) - 1) // P
-        if full <= 0:
-            return []
-        return paged.chain_hashes(ids, P, full)
+        return self.prompt_hashes(token_ids).hashes
 
     def prefix_overlap_rows(self, token_ids: List[int],
                             hashes: Optional[List[bytes]] = None) -> int:
@@ -3302,14 +3358,18 @@ class TPUEngine:
         token_ids: List[int],
         temperature: float = 0.0,
         top_p: float = 1.0,
+        given: Optional[paged.PromptHashes] = None,
     ) -> PendingFirstToken:
         """Fill ``slot`` with a prompt and return as soon as its last
         prefill program is issued: the slot is active and a decode
         dispatch may follow at once; ``wait()`` of what comes back yields
-        the first generated token."""
+        the first generated token. ``given``: the submitter's
+        ``prompt_hashes`` of the prompt, if it has them."""
         if not 0 <= slot < self.num_slots:
             raise ValueError(f"slot {slot} out of range")
-        token_ids = list(token_ids)[-(self.max_context - 1) :]
+        # one int32 array, made before the lock: the hash, the history
+        # backfill and the chunks' operands are slices of it
+        token_ids = np.asarray(token_ids, np.int32)[-(self.max_context - 1) :]
         true_len = len(token_ids)
         if true_len == 0:
             raise ValueError("empty prompt")
@@ -3317,7 +3377,9 @@ class TPUEngine:
         matched, hashes = 0, []
         if self.prefix_index is not None or self.refused_prefixes is not None:
             with self._lock:
-                matched, hashes = self._match_prefix(slot, token_ids)
+                matched, hashes = self._match_prefix(
+                    slot, token_ids, given
+                )
         if matched or (
             self._whole_prompt_rows is not None
             and true_len > self._whole_prompt_rows
@@ -3349,25 +3411,24 @@ class TPUEngine:
         bucket = self.bucket_for(true_len)
         padded = np.zeros((1, bucket), dtype=np.int32)
         padded[0, :true_len] = token_ids
+        # numpy operands, placed by the graph call itself: nothing is
+        # built or put on the device one by one under the lock
+        ops = [
+            padded, np.int32(slot), np.int32(true_len),
+            np.float32(temperature), np.float32(top_p),
+        ]
 
         with self.phases.phase("engine.prefill"), self._lock:
-            args = [
-                self.params,
-                self.state,
-                jnp.asarray(padded),
-                jnp.int32(slot),
-                jnp.int32(true_len),
-                jnp.float32(temperature),
-                jnp.float32(top_p),
-            ]
             if self.paged:
                 # back the prompt's rows NOW (raises PoolExhausted before
                 # any state is touched); the bucket's padding rows beyond
                 # true_len land on the sacrificial page and are never read
                 self.allocator.ensure(slot, true_len)
-                args.append(jnp.asarray(self.allocator.tables[slot]))
+                ops.append(self.allocator.tables[slot].copy())
             dtok = self._devprof_note("prefill", bucket)
-            self.state, first = self._prefill_fn(bucket)(*args)
+            self.state, first = self._prefill_fn(bucket)(
+                self.params, self.state, *ops
+            )
             self.active[slot] = True
             self._host_greedy[slot] = temperature < sampling.GREEDY_EPS
             self._host_lengths[slot] = true_len
@@ -3402,18 +3463,16 @@ class TPUEngine:
         bucket = self.bucket_for(true_len)
         padded = np.zeros((1, bucket), dtype=np.int32)
         padded[0, :true_len] = ids
+        ops = (
+            padded, np.int32(slot), np.int32(true_len),
+            np.float32(temperature), np.float32(top_p),
+        )
         with self.phases.phase("engine.prefill"), self._lock:
             self.allocator.ensure(slot, true_len)
             dtok = self._devprof_note("seq_prefill", bucket)
             self.state, first = self._seq_prefill_fn(bucket)(
-                self.params,
-                self.state,
-                jnp.asarray(padded),
-                jnp.int32(slot),
-                jnp.int32(true_len),
-                jnp.float32(temperature),
-                jnp.float32(top_p),
-                jnp.asarray(self.allocator.tables[slot]),
+                self.params, self.state, *ops,
+                self.allocator.tables[slot].copy(),
             )
             self.active[slot] = True
             self._host_greedy[slot] = temperature < sampling.GREEDY_EPS
@@ -3433,12 +3492,14 @@ class TPUEngine:
         temperature: float = 0.0,
         top_p: float = 1.0,
         chunk: int = 512,
+        given: Optional[paged.PromptHashes] = None,
     ) -> "ChunkedPrefill":
         """Begin an incremental prefill of ``slot``; the caller drives it by
         calling ``.step()`` once per chunk and may run decode dispatches for
         the other slots in between (the continuous batcher does exactly
         that). Requires ``chunk`` to be a prefill bucket dividing
-        max_context so chunk writes never spill past the cache end."""
+        max_context so chunk writes never spill past the cache end.
+        ``given``: the submitter's ``prompt_hashes`` of the prompt."""
         if not 0 <= slot < self.num_slots:
             raise ValueError(f"slot {slot} out of range")
         if chunk not in self.buckets or self.max_context % chunk:
@@ -3452,11 +3513,11 @@ class TPUEngine:
                 "page pool (chunks read the pool during admission); use "
                 "whole-prompt prefill"
             )
-        ids = list(token_ids)[-(self.max_context - 1) :]
+        ids = np.asarray(token_ids, np.int32)[-(self.max_context - 1) :]
         matched, hashes = 0, []
         if self.prefix_index is not None or self.refused_prefixes is not None:
             with self._lock:
-                matched, hashes = self._match_prefix(slot, ids)
+                matched, hashes = self._match_prefix(slot, ids, given)
         if not matched and self._seq_route_ok(len(ids)):
             # the whole mesh prefills this prompt in one dispatch; the
             # driver keeps the ChunkedPrefill duck interface so the
@@ -3588,8 +3649,8 @@ class TPUEngine:
         [num_slots, vocab] fp32 (0 = allowed, -inf = forbidden) applied
         before sampling — grammar-constrained decoding (jsonmode.py).
         Returns tokens [1, num_slots]."""
+        m = jnp.asarray(mask, jnp.float32)  # placed before the lock
         with self._lock:
-            m = jnp.asarray(mask, jnp.float32)
             dtok = self._devprof_note("masked", "masked")
             if self.paged:
                 self._back_active_slots(1)
@@ -3669,8 +3730,7 @@ class TPUEngine:
                 args = (self._tables_operand(),)
             dtok = self._devprof_note("jump", kb)
             self.state = self._jump_fn(kb)(
-                self.params, self.state, *args,
-                jnp.asarray(forced), jnp.asarray(counts),
+                self.params, self.state, *args, forced, counts,
             )
             self.decode_steps += 1
             self._obs_decode_steps.inc()
@@ -3694,6 +3754,7 @@ class TPUEngine:
         prefill graph samples the first token UNMASKED, so the batcher
         overwrites it with the grammar's forced opener (e.g. "{" for
         json_object mode) before any decode dispatch consumes it."""
+        # aios: waive(lock-readback): two eager updates of the donated state, which only the lock's holder may touch; a constrained admission has flushed the pipeline, so no dispatch worker waits for the lock meanwhile
         with self._lock:
             col = int(self._host_lengths[slot])
             self.state["last_tokens"] = (
@@ -3877,11 +3938,29 @@ class TPUEngine:
             self._devprof_sample(dtok)
 
     def release(self, slot: int) -> None:
+        """Free ``slot``: its host half (``retire``) and its pages and
+        device flags (``release_pages``) at once. The continuous batcher
+        calls the halves apart, the second behind its next dispatch."""
+        self.retire(slot)
+        self.release_pages(slot)
+
+    def retire(self, slot: int) -> None:
+        """The host half of a release, no lock taken: the slot reads free
+        (``free_slots``, the occupancy) and the next dispatch backs no
+        rows for it. Its pages stay its own until ``release_pages``: the
+        caller gives the slot to no new tenant before that."""
         self.active[slot] = False
         self._host_lengths[slot] = 0
         self._draft_host_lengths[slot] = 0
         self._host_greedy[slot] = False
         self._win_starts[slot] = 0  # next occupant starts uncompressed
+
+    def release_pages(self, slot: int) -> None:
+        """The engine half of a release, under the engine lock: the
+        slot's pages (and state) go back and the device's length and
+        active flag are reset (one small program: ``_reset_slot``)."""
+        slot_op = np.int32(slot)
+        # aios: waive(lock-readback): the draft length's eager reset, of an engine that speculates with a draft model alone; the continuous batcher calls this behind the dispatch it has handed over (_settle_retired), so the worker's enqueue does not wait for it
         with self._lock:
             if self.allocator is not None:
                 self.allocator.free_slot(slot)  # pages recycle instantly
@@ -3889,8 +3968,9 @@ class TPUEngine:
                 # nothing to hand back or to zero: the next tenant's first
                 # chunk starts from zeros (paged.py's header)
                 self.slot_states.free_slot(slot)
-            self.state["lengths"] = self.state["lengths"].at[slot].set(0)
-            self.state["active"] = self.state["active"].at[slot].set(False)
+            self.state["lengths"], self.state["active"] = _reset_slot(
+                self.state["lengths"], self.state["active"], slot_op
+            )
             if self.draft_state is not None:
                 # the next occupant's draft KV rebuilds from history via
                 # ingest; zeroing the length is the whole reset
@@ -3997,6 +4077,12 @@ class TPUEngine:
             out["kv_compress_resident_pages"] = self.compressed_resident_pages()
         if self._seq_attn is not None:
             out["prefill_seq_sharded"] = self.prefill_seq_sharded
+        if self.prefix_index is not None or self.refused_prefixes is not None:
+            # beside the batcher's `admissions`: those matched on hashes
+            # the submitter's thread had computed, and the matched
+            # prefixes whose history backfill was left out
+            out["admissions_prehashed"] = self.admissions_prehashed
+            out["history_backfills_skipped"] = self.history_backfills_skipped
         if self.prefix_index is not None:
             out["prefix_hits"] = self.prefix_index.hits
             out["prefix_misses"] = self.prefix_index.misses
@@ -4308,8 +4394,9 @@ class ChunkedPrefill:
         start_pos: int = 0,  # rows already in the cache (matched prefix)
         hashes=(),  # block hashes to publish to the prefix index when done
     ) -> None:
-        ids = list(token_ids)[-(engine.max_context - 1) :]
-        if not ids:
+        # one int32 array, made once: a chunk's operand is a slice of it
+        ids = np.asarray(token_ids, np.int32)[-(engine.max_context - 1) :]
+        if not len(ids):
             raise ValueError("empty prompt")
         self.engine = engine
         self.slot = slot
@@ -4320,6 +4407,8 @@ class ChunkedPrefill:
         self.pos = int(start_pos)
         self.hashes = hashes
         self.first: Optional[PendingFirstToken] = None
+        # the next chunk's operands, where ``stage`` has placed them
+        self._staged: Optional[tuple] = None
 
     @property
     def done(self) -> bool:
@@ -4331,20 +4420,42 @@ class ChunkedPrefill:
         first = self.step_async()
         return None if first is None else first.wait()
 
+    def stage(self) -> None:
+        """Put the next chunk's operands on the device, ahead of the lock
+        and the graph call that issue it: the ones that need no lock (the
+        slot's table and window start join them under it, as numpy values
+        the call places). ``step_async`` does it first thing where nobody
+        has; the pipelined batcher does it a tick ahead, behind a decode
+        dispatch it has handed over and while the device still runs the
+        one before, where it has little room (a placement is a
+        millisecond of the calling thread among the serving threads, and
+        a final chunk has seven), so that the next tick's issue is the
+        lock, the table and the graph call (PERF.md, PR 41)."""
+        if self.done or self._staged is not None:
+            return
+        remaining = len(self.ids) - self.pos
+        final = remaining <= self.chunk
+        n = min(self.chunk, remaining)
+        bucket = self.engine.bucket_for(n) if final else self.chunk
+        padded = np.zeros((1, bucket), dtype=np.int32)
+        padded[0, :n] = self.ids[self.pos : self.pos + n]
+        ops = [padded, np.int32(self.slot), np.int32(self.pos)]
+        if final:
+            ops += [
+                np.int32(n), np.int32(len(self.ids)),
+                np.float32(self.temperature), np.float32(self.top_p),
+            ]
+        self._staged = (n, final, bucket, tuple(jnp.asarray(o) for o in ops))
+
     def step_async(self) -> Optional[PendingFirstToken]:
         """Issue the next chunk; returns the pending first token once the
         final chunk is issued, else None."""
         if self.done:
             return self.first
         eng = self.engine
-        remaining = len(self.ids) - self.pos
-        final = remaining <= self.chunk
-        n = min(self.chunk, remaining)
-        bucket = eng.bucket_for(n) if final else self.chunk
-        padded = np.zeros((1, bucket), dtype=np.int32)
-        padded[0, :n] = self.ids[self.pos : self.pos + n]
+        self.stage()
+        n, final, bucket, ops = self._staged
         with eng.phases.phase("engine.prefill"), eng._lock:
-            extra = ()
             if eng.paged:
                 # back this chunk's rows before dispatching; PoolExhausted
                 # surfaces to the batcher with all state untouched. On
@@ -4364,24 +4475,13 @@ class ChunkedPrefill:
                 eng.allocator.ensure(self.slot, self.pos + n)
                 if eng.slot_states is not None:
                     eng.slot_states.take(self.slot)
-                extra = (jnp.asarray(eng.allocator.tables[self.slot]),)
+                ops += (eng.allocator.tables[self.slot].copy(),)
                 if eng.kv_compress_armed:
-                    extra += (
-                        jnp.int32(int(eng._win_starts[self.slot])),
-                    )
+                    ops += (np.int32(eng._win_starts[self.slot]),)
             dtok = eng._devprof_note("chunk", (bucket, final))
             if final:
                 eng.state, first = eng._chunk_fn(bucket, True)(
-                    eng.params,
-                    eng.state,
-                    jnp.asarray(padded),
-                    jnp.int32(self.slot),
-                    jnp.int32(self.pos),
-                    jnp.int32(n),
-                    jnp.int32(len(self.ids)),
-                    jnp.float32(self.temperature),
-                    jnp.float32(self.top_p),
-                    *extra,
+                    eng.params, eng.state, *ops
                 )
                 eng.active[self.slot] = True
                 eng._host_greedy[self.slot] = (
@@ -4392,18 +4492,14 @@ class ChunkedPrefill:
                 self.first = PendingFirstToken(eng, first, dtok)
             else:
                 eng.state = eng._chunk_fn(bucket, False)(
-                    eng.params,
-                    eng.state,
-                    jnp.asarray(padded),
-                    jnp.int32(self.slot),
-                    jnp.int32(self.pos),
-                    *extra,
+                    eng.params, eng.state, *ops
                 )
         if not final:
             # mid-chunk samples are submit-side (their writes overlap the
             # next chunk's staging); a final chunk's lands where its
             # token is read
             eng._devprof_sample(dtok)
+        self._staged = None  # kept until here: a PoolExhausted retries it
         self.pos += n
         return self.first
 
@@ -4434,6 +4530,9 @@ class _SeqShardedPrefill:
 
     def step(self) -> Optional[int]:
         return self.step_async().wait()
+
+    def stage(self) -> None:
+        """Nothing to place ahead: the one dispatch takes the prompt whole."""
 
     def step_async(self) -> PendingFirstToken:
         if not self.done:

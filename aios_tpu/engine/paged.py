@@ -684,15 +684,26 @@ def chain_hashes(
     enough to craft collisions in a multi-tenant deployment."""
     import hashlib
 
+    ids = np.asarray(token_ids[: num_blocks * page_size], np.int32)
     hashes: List[bytes] = []
     h = b""
     for b in range(num_blocks):
-        block = np.asarray(
-            token_ids[b * page_size : (b + 1) * page_size], np.int32
-        )
+        block = ids[b * page_size : (b + 1) * page_size]
         h = hashlib.sha256(h + block.tobytes()).digest()
         hashes.append(h)
     return hashes
+
+
+class PromptHashes(NamedTuple):
+    """A prompt's chain hashes with what they were computed over, so that
+    whoever is handed them (the batcher from the pool, the engine from the
+    batcher) can tell its own truncation's from another's: the prompt's
+    last ``rows`` ids, in blocks of ``page_size``
+    (``TPUEngine.prompt_hashes`` is the rule)."""
+
+    rows: int
+    page_size: int
+    hashes: List[bytes]
 
 
 # -- host-tier wire format (fleet KV transfer, aios_tpu/fleet/kvx.py) -------

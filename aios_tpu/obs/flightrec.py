@@ -158,7 +158,9 @@ SNAPSHOT_CAUSES = ("shed_spike", "crash_respawn", "slo_breach", "abort",
 # fence (wait_started of a pipelined dispatch), reap (_reap_cancelled
 # and the loop's bookkeeping: the last tick's stall verdict, the rate
 # gauge, the no-progress check), prefill (_advance_prefill, in the ticks
-# that have one), admit (_admit, likewise), idle (the 50 ms wake
+# that have one; and, in a pipelined tick with little room for it, the
+# placing of the NEXT chunk's operands behind the hand-over,
+# ChunkedPrefill.stage: a second span of the name), admit (_admit, likewise), idle (the 50 ms wake
 # wait), dispatch (_note_dispatch through the engine call's return),
 # emit (the token loops, _finish, _rec_close), consume (the pipelined
 # tick's wait for the tokens of the dispatch it consumes: a name of its
@@ -166,7 +168,12 @@ SNAPSHOT_CAUSES = ("shed_spike", "crash_respawn", "slo_breach", "abort",
 # that made it), first_token (the wait for the first tokens of the tick's
 # admissions, whose prefill programs were issued and not waited for: in
 # the pipelined loop after the decode dispatch behind them is issued),
-# evict (_evict_longest). engine.* wrap the dispatch bodies: lock_wait (until
+# evict (_evict_longest), retire (the pipelined tick's _settle_retired: the
+# engine's half of the retirements its emission made, page frees, device
+# resets, the timeline's close and the end of stream, behind the dispatch
+# just handed over, the wait for that dispatch to hold the engine lock
+# included; a retirement with no dispatch to run behind stays inside the
+# phase that made it, emit, reap or evict, as before). engine.* wrap the dispatch bodies: lock_wait (until
 # the engine lock is held), enqueue (lock held until the graph call
 # returns), readback (the blocking device->host copy of the tokens),
 # prefill (one prefill / chunk dispatch, lock held until the graph call
@@ -177,6 +184,7 @@ PHASES = (
     "batcher.idle", "batcher.dispatch", "batcher.emit", "batcher.consume",
     "batcher.evict", "engine.lock_wait", "engine.enqueue", "engine.readback",
     "engine.prefill", "engine.compile", "batcher.first_token",
+    "batcher.retire",
 )
 # phases that wait on the device, not on the host: a tick's host time
 # leaves them out wherever they nest

@@ -54,9 +54,11 @@ class Replica:
         fn = getattr(self.engine, "prefix_overlap_rows", None)
         return fn(prompt_ids, hashes=hashes) if fn is not None else 0
 
-    def prefix_hashes(self, prompt_ids: List[int]):
-        fn = getattr(self.engine, "prefix_hashes", None)
-        return fn(prompt_ids) if fn is not None else []
+    def prompt_hashes(self, prompt_ids: List[int]):
+        """The prompt's chain hashes as the engine's admission takes them
+        (``paged.PromptHashes``), or None where it would read none."""
+        fn = getattr(self.engine, "prompt_hashes", None)
+        return fn(prompt_ids) if fn is not None else None
 
     def outstanding_tokens(self) -> int:
         return self.batcher.outstanding_tokens()
@@ -305,9 +307,9 @@ class ReplicaPool:
             idx, reason = self.router.least_loaded(reps), \
                 "least_loaded"
         else:
-            hashes = reps[0].prefix_hashes(route_ids)
             idx, reason = self.router.select(
-                reps, route_ids, req.request_id, hashes=hashes,
+                reps, route_ids, req.request_id,
+                hashes=self._hash_once(reps[0], req, route_ids),
                 detail=route_detail,
             )
         rec = getattr(req, "rec", None)
@@ -322,6 +324,15 @@ class ReplicaPool:
         handle = reps[idx].batcher.submit(req)
         self._count_route(reason, task_id, idx)
         return handle
+
+    @staticmethod
+    def _hash_once(replica, req, route_ids) -> list:
+        """Hash the prompt's blocks here, on the submitting thread, and
+        keep the result on the request: the routers' probes read the
+        list, and the admitting engine's prefix match takes it instead of
+        hashing again under its lock (``TPUEngine.prompt_hashes``)."""
+        req.prefix_hashes = replica.prompt_hashes(route_ids)
+        return [] if req.prefix_hashes is None else req.prefix_hashes.hashes
 
     def _route_ids(self, req):
         """The ADMISSION-TRUNCATED prompt (engines keep only the last
@@ -368,7 +379,7 @@ class ReplicaPool:
         # hash the blocks ONCE; every replica's probe reuses the digests
         # (replicas share page size and truncation — see _route_ids)
         route_ids, cap = self._route_ids(req)
-        hashes = reps[0].prefix_hashes(route_ids)
+        hashes = self._hash_once(reps[0], req, route_ids)
         rec = getattr(req, "rec", None)
         route_detail: Dict[str, int] = {}
         idx, reason = self.router.select(

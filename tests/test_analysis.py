@@ -44,6 +44,7 @@ def _registry(**kw):
         context_fns=kw.pop("context_fns", {}),
         hook_targets={},
         local_locks={},
+        local_types={},
         dispatch_hygiene_modules=kw.pop("dispatch_hygiene_modules", ()),
     )
 
@@ -153,6 +154,70 @@ def test_lock_discipline_context_fn():
     """
     reg = _registry(context_fns={(FIX, "Eng.hook"): ("fix",)})
     assert _unwaived(_analyze(src, reg), "lock-readback")
+
+
+PLACEMENT_SRC = """
+    class Eng:
+        def admit(self, slot, ids):
+            with self._lock:
+                self.state = self._chunk_fn(8)(
+                    self.state, {operand},
+                )
+"""
+ENGINE_LIKE = (LockDecl("fix", FIX, "Eng", "_lock",
+                        forbids=("readback", "rpc", "placement")),)
+
+
+@pytest.mark.parametrize("operand,what", [
+    ("jnp.int32(slot)", "jnp.int32"),
+    ("jnp.float32(0.5)", "jnp.float32"),
+    ("jnp.asarray(ids)", "jnp.asarray"),
+    ("self.lengths.at[slot].set(0)", ".at[...].set"),
+])
+def test_a_placement_under_a_lock_that_names_it_is_of_the_readback_family(
+        operand, what):
+    """The shape ISSUE 41 removed from the scheduler's path: an operand put
+    on the device one value at a time, or an eager ``.at[...]`` update,
+    inside the engine lock. A lock that forbids "placement" reports it
+    under ``lock-readback``; the other locks' discipline is what it was
+    (``jnp.asarray`` is H2D, not a readback); numpy operands pass."""
+    src = PLACEMENT_SRC.format(operand=operand)
+    found = _unwaived(_analyze(src, _registry(locks=ENGINE_LIKE)),
+                      "lock-readback")
+    assert len(found) == 1 and what in found[0].message
+    assert "numpy values" in found[0].message
+    assert not _unwaived(_analyze(src), "lock-readback")
+    numpy_operands = PLACEMENT_SRC.format(operand="np.int32(slot), padded")
+    assert not _unwaived(_analyze(numpy_operands, _registry(locks=ENGINE_LIKE)))
+    waived = src.replace(
+        "with self._lock:",
+        "with self._lock:  # aios: waive(lock-readback): behind the hand-over",
+    )
+    findings = _analyze(waived, _registry(locks=ENGINE_LIKE))
+    assert not _unwaived(findings, "lock-readback")
+    assert any(f.waived and f.waive_reason == "behind the hand-over"
+               for f in findings)
+
+
+def test_a_typed_local_resolves_to_its_class_s_lock():
+    """``eng._lock`` in a driver object's method (ChunkedPrefill.step_async)
+    is the engine's lock once the registry says what ``eng`` is: its body is
+    held to the lock's discipline, and was not seen at all before."""
+    src = """
+        class Eng:
+            pass
+
+        class Driver:
+            def step(self):
+                eng = self.engine
+                with eng._lock:
+                    first = eng.fn(jnp.int32(self.slot))
+    """
+    reg = _registry(locks=ENGINE_LIKE)
+    assert not _analyze(src, reg)
+    reg.local_types[(FIX, "Driver.step", "eng")] = (FIX, "Eng")
+    found = _unwaived(_analyze(src, reg), "lock-readback")
+    assert len(found) == 1 and "jnp.int32" in found[0].message
 
 
 def test_waiver_without_reason_rejected():

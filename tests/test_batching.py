@@ -418,8 +418,9 @@ def test_gateway_disconnect_while_queued_cancels_without_slot(monkeypatch):
             toks[toks == eos] = 7
             return toks
 
-        def never_stopping_prefill(slot, ids, temperature=0.0, top_p=1.0):
-            first = real_prefill(slot, ids, temperature, top_p)
+        def never_stopping_prefill(slot, ids, temperature=0.0, top_p=1.0,
+                                   **kw):
+            first = real_prefill(slot, ids, temperature, top_p, **kw)
             if first.wait() == eos:
                 eng.force_pending_token(slot, 7)
                 first.token = 7

@@ -378,13 +378,13 @@ def test_a_stream_that_ends_frees_its_slot_within_two_dispatches(params):
     eng = make_engine(params, num_slots=1)
     b = ContinuousBatcher(eng)
     released = []
-    release = eng.release
+    release = eng.release_pages  # the slot's pages are back: a tenant may come
 
     def noted(slot):
         released.append(eng.decode_steps)
         release(slot)
 
-    eng.release = noted
+    eng.release_pages = noted
     tokens = 4 * DECODE_STEPS + 2  # the prefill's, then one a step
     try:
         out = b.submit(Request(prompt_ids=[3, 17, 91, 4], max_tokens=tokens,

@@ -271,6 +271,41 @@ def test_a_slot_taken_twice_gives_its_second_tenant_what_a_fresh_slot_gives(para
     assert eng.phases.counts["load.states"] == 1  # the arrays' allocation, named
 
 
+@pytest.mark.parametrize("given", ["handed_in", "none"])
+def test_the_state_kind_counts_its_refused_hits_on_the_submitters_hashes(params, given):
+    """The state kind's branch of the match (``refused_prefixes``): no prefix
+    index, so the pool's routing hashes nothing and the batcher's ``submit``
+    is the one that hashes, on its caller's thread. With its hashes and
+    with none (the engine hashes under its lock, as it did) the refusals
+    counted and the streams are the same, and a fresh engine's."""
+    from aios_tpu.engine.batching import Request
+
+    ids = _ids(45, 3)
+    want = [_engine(params).generate(p, max_new_tokens=5, temperature=0.0)
+            for p in (ids, ids[:30])]
+    eng = _engine(params)
+    assert eng.prefix_hashes(ids) == [] and len(eng.prompt_hashes(ids).hashes) == 2
+    if given == "none":
+        for name in ("prefill_async", "start_chunked_prefill"):
+            def bare(*a, _real=getattr(eng, name), **k):
+                return _real(*a, **dict(k, given=None))
+            setattr(eng, name, bare)
+    batcher = ContinuousBatcher(eng)
+    try:
+        got = [batcher.submit(Request(prompt_ids=p, max_tokens=5,
+                                      temperature=0.0)).tokens()
+               for p in (ids, ids[:30])]
+    finally:
+        batcher.shutdown()
+    assert got == want
+    stats = eng.stats()
+    # the second prompt shared one block of 16 rows with the first: refused
+    assert stats["prefix_hits_refused_state"] == 1
+    assert stats["prefix_rows_refused_state"] == 16
+    assert stats["admissions_prehashed"] == (2 if given == "handed_in" else 0)
+    assert stats["history_backfills_skipped"] == 0  # nothing is ever matched
+
+
 def test_the_flight_recorder_s_admission_record_has_the_state_s_bytes(params):
     from aios_tpu.engine.batching import Request
     from aios_tpu.obs import flightrec

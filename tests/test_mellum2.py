@@ -338,6 +338,41 @@ def test_a_prefix_hit_is_served_where_the_window_rows_are_held(params):
     assert _agrees(params, again, token)
 
 
+@pytest.mark.parametrize("given", ["handed_in", "none"])
+def test_the_batcher_serves_both_kinds_prefix_hits_on_the_submitters_hashes(params, given):
+    """Through the continuous batcher, the window kind's branch of the match
+    (``WindowPrefixPages``): a short shared prefix served, a long prompt's
+    first blocks refused where the window kind let them go. With the
+    hashes ``submit`` made on its caller's thread and with none (the engine
+    hashes under its lock, as it did) the hits, the refusals and every
+    stream are the same, and the plain forward's."""
+    eng = _engine(params, slots=2)
+    if given == "none":
+        for name in ("prefill_async", "start_chunked_prefill"):
+            def bare(*a, _real=getattr(eng, name), **k):
+                return _real(*a, **dict(k, given=None))
+            setattr(eng, name, bare)
+    system, long = _ids(16, 7), _ids(44, 11)
+    prompts = [system + _ids(5, 8), system + _ids(9, 9),  # the second: served
+               long + _ids(3, 12), long[:16] + _ids(9, 14)]  # the fourth: refused
+    batcher = ContinuousBatcher(eng, prefill_chunk=16)
+    try:
+        streams = [batcher.submit(Request(prompt_ids=p, max_tokens=6,
+                                          temperature=0.0)).tokens()
+                   for p in prompts]  # one after another: each finds the last's blocks
+    finally:
+        batcher.shutdown()
+    for prompt, stream in zip(prompts, streams):
+        seq = list(prompt)
+        for token in stream:
+            assert _agrees(params, seq, token), (len(prompt), len(seq))
+            seq.append(token)
+    stats = eng.stats()
+    assert stats["prefix_rows_reused"] == 16 and stats["prefix_hits_refused_window"] == 1
+    assert stats["admissions_prehashed"] == (4 if given == "handed_in" else 0)
+    assert stats["history_backfills_skipped"] == 0  # this engine keeps a history
+
+
 def test_a_prefix_hit_is_refused_where_the_window_rows_are_gone_and_counted(params):
     """A prompt that shares only the FIRST 16 rows of a 44-row prompt admitted
     in chunks: the full kind still holds those blocks, the window kind let

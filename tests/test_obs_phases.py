@@ -77,7 +77,7 @@ def test_every_phase_of_the_closed_list_is_counted_and_kept_nested_as_the_code_n
         assert stats[f"phase_{name}_seconds"] == pytest.approx(
             sum(t1 - t0 for _, t0, t1 in got)), name
     assert set(by) == set(flightrec.PHASES) - {
-        "batcher.fence", "batcher.consume", "batcher.evict"}
+        "batcher.fence", "batcher.consume", "batcher.evict", "batcher.retire"}
     # nested as the code nests them
     for name in DEVICE_SIDE:
         assert all(_inside(s, by["batcher.dispatch"]) for s in by[name]), name
@@ -116,6 +116,10 @@ def test_every_phase_of_the_closed_list_is_counted_and_kept_nested_as_the_code_n
     assert b.stats()["phase_batcher.fence_count"] >= 1
     assert b.stats()["phase_batcher.consume_count"] >= 1
     assert {"batcher.fence", "batcher.consume"} <= {n for n, _, _ in _spans("phases-pipe")}
+    # and retires behind the dispatch it has just handed over: the engine's
+    # half of a tick's retirements is a phase of the tick's own, after its emit
+    assert b.stats()["phase_batcher.retire_count"] >= 1
+    assert b.stats()["retirements_behind_dispatch"] == 2
     # and reads first tokens behind the dispatch it issued after their
     # prefill: a phase of the tick's own, after every admit and dispatch of it
     pipe = [s for s in _spans("phases-pipe") if s[0].startswith("batcher.")]
