@@ -1306,8 +1306,10 @@ class ContinuousBatcher:
             fields["chunk"] = chunk
         states = getattr(self.engine, "slot_states", None)
         if states is not None:
-            # the state kind: what the slot holds beside its latent rows
+            # the state kind: what the slot holds beside its cache rows,
+            # over how many state layers
             fields["state_bytes"] = states.slot_bytes
+            fields["state_layers"] = states.layers
         if self.engine.cfg.kinds and live.slot >= 0:
             # pages by kind: what this admission left the slot holding
             alloc = self.engine.allocator

@@ -5,6 +5,12 @@ One architecture class covers every local model tier the reference routes to
 Mistral-7B (tactical, GQA + sliding window), DeepSeek-R1-Distill-8B
 (tactical, Llama-3 shape), Qwen3-14B (strategic, QK-norm). Configs can be
 built from presets, GGUF metadata, or HF config dicts.
+
+``layer_types`` names a kind a layer from one of THREE closed lists, one a
+form of stack: LAYER_KINDS (a grouped-query stack of window and full layers),
+STATE_KINDS (a latent-attention stack with kda layers) and SUBLAYER_KINDS (a
+stack whose layers are one sub-layer each; why a third list: at its
+definition).
 """
 
 from __future__ import annotations
@@ -37,6 +43,13 @@ LAYER_KINDS = ("full", "window")
 # ``kda`` (Kimi Delta Attention, engine/kda.py) one fixed-size recurrent
 # state a slot and no rows (engine/paged.py header: the state kind)
 STATE_KINDS = ("kda", "mla")
+# and those of a stack of SUB-LAYERS (engine/mamba2.py), whose every layer is
+# ONE sub-layer under one norm, x + F(norm(x)): ``mamba2`` a state-space mixer
+# that keeps a state a slot (the state kind again, another shape), ``full`` a
+# grouped-query attention layer that keeps rows in pages, ``moe`` an expert
+# FFN alone. A third closed list and not the second widened: the second's
+# layers are mixer AND FFN under two norms, and no stack mixes the two forms.
+SUBLAYER_KINDS = ("full", "mamba2", "moe")
 
 
 @dataclass(frozen=True)
@@ -149,6 +162,24 @@ class ModelConfig:
     latent_qk_norm: bool = False
     rope_interleave: bool = False
     attn_head_gate: bool = False
+    # A stack of sub-layers (SUBLAYER_KINDS in layer_types; engine/mamba2.py
+    # has the equations): a ``mamba2`` layer has ssm_heads heads of
+    # ssm_head_dim channels, a state of ssm_state values a channel, its B and
+    # C projections in ssm_groups groups of heads, and a depthwise causal
+    # convolution of ssm_conv taps before them; a slot keeps a float32 state
+    # [ssm_heads, ssm_head_dim, ssm_state] a layer in place of rows.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    ssm_conv: int = 4
+    # what an expert (routed, shared, or the dense FFN) does between its two
+    # products: "swiglu" = down(silu(gate(x)) * up(x)), three matrices with
+    # [gate | up] fused; "relu2" = down(relu(up(x))^2), two matrices, no gate
+    expert_act: str = "swiglu"
+    # False: a grouped-query layer applies no rotary embedding to q and k
+    # (the state-space layers of its stack carry position)
+    rotary: bool = True
     # group-limited routing (sigmoid scoring): the router's experts are
     # n_group groups of consecutive experts, a group scores the sum of its
     # two largest biased scores, and the top-k is taken among the topk_group
@@ -240,6 +271,8 @@ class ModelConfig:
         ))
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"{self.name}: unknown moe_scoring {self.moe_scoring!r}")
+        if self.expert_act not in ("swiglu", "relu2"):
+            raise ValueError(f"{self.name}: unknown expert_act {self.expert_act!r}")
         if self.experts_held and not (
             0 <= self.first_expert
             and self.first_expert + self.experts_held <= self.num_experts
@@ -307,6 +340,8 @@ class ModelConfig:
             return
         if "kda" in types:
             return self._check_state_kinds()
+        if "mamba2" in types or "moe" in types:
+            return self._check_sublayers()
         unknown = (set(types) | {k for k, _ in self.rope_by_kind}) - set(LAYER_KINDS)
         if unknown or (types and len(types) != self.num_layers):
             raise ValueError(
@@ -348,8 +383,10 @@ class ModelConfig:
                 and self.kda_value_dim and self.kda_conv >= 2
                 and self.kda_lower_bound < 0):
             raise ValueError(
-                f"{self.name}: kda layers stand in a latent-attention stack "
-                "(kv_lora_rank > 0) and need kda_heads, kda_key_dim, "
+                f"{self.name}: kda layers stand beside mla layers alone, in "
+                "a latent-attention stack (kv_lora_rank > 0; a state-space "
+                "layer beside grouped-query pages is the mamba2 kind of "
+                "SUBLAYER_KINDS), and need kda_heads, kda_key_dim, "
                 "kda_value_dim, kda_conv >= 2 taps and kda_lower_bound < 0"
             )
         if self.hc or self.sandwich_norm or self.sliding_window is not None:
@@ -358,11 +395,62 @@ class ModelConfig:
                 "block: no mixed streams, sandwich norms or window"
             )
 
+    def _check_sublayers(self) -> None:
+        types = self.layer_types
+        unknown = set(types) - set(SUBLAYER_KINDS)
+        if unknown or len(types) != self.num_layers or self.rope_by_kind:
+            raise ValueError(
+                f"{self.name}: a stack of sub-layers names one of "
+                f"{SUBLAYER_KINDS} for each of the {self.num_layers} layers "
+                f"and has no rotary table by kind; got {len(types)} entries, "
+                f"the unknown kinds {sorted(unknown)} and rope_by_kind "
+                f"{self.rope_by_kind!r}"
+            )
+        if not ("mamba2" in types and self.ssm_heads and self.ssm_head_dim
+                and self.ssm_state and self.ssm_groups
+                and self.ssm_heads % self.ssm_groups == 0
+                and self.ssm_conv >= 2):
+            raise ValueError(
+                f"{self.name}: a stack of sub-layers has mamba2 layers (a "
+                "stack without them is the grouped-query block's) and needs "
+                "ssm_heads in whole ssm_groups, ssm_head_dim, ssm_state and "
+                "ssm_conv >= 2 taps"
+            )
+        if "moe" in types and not self.moe:
+            raise ValueError(
+                f"{self.name}: moe sub-layers need num_experts (the dense "
+                "FFN kind of such a stack is not built)"
+            )
+        if (self.mla or self.hc or self.sandwich_norm or self.first_k_dense
+                or self.sliding_window is not None or self.qk_norm):
+            raise ValueError(
+                f"{self.name}: a stack of sub-layers is built beside the "
+                "plain grouped-query layer: no latent attention, mixed "
+                "streams, sandwich norms, leading dense layers, window or "
+                "q/k norms"
+            )
+
+    @property
+    def state_kind(self) -> Optional[str]:
+        """The kind of layer that keeps a recurrent state a slot in place of
+        cache rows (engine/paged.py's state kind): ``kda`` in a
+        latent-attention stack, ``mamba2`` in a stack of sub-layers, None in
+        a stack without one."""
+        for kind in ("kda", "mamba2"):
+            if kind in self.layer_types:
+                return kind
+        return None
+
     @property
     def state_kinds(self) -> bool:
-        """True when some layers keep a recurrent state a slot (``kda``) in
-        place of cache rows: engine/paged.py's state kind."""
-        return "kda" in self.layer_types
+        """True when some layers keep a recurrent state a slot."""
+        return self.state_kind is not None
+
+    @property
+    def sublayers(self) -> bool:
+        """True for a stack whose layers are one sub-layer each
+        (SUBLAYER_KINDS; engine/mamba2.py)."""
+        return "mamba2" in self.layer_types
 
     @property
     def kinds(self) -> bool:
@@ -375,7 +463,10 @@ class ModelConfig:
 
     @property
     def row_layers(self) -> int:
-        """Layers that keep cache ROWS in pages: all but the ``kda`` ones."""
+        """Layers that keep cache ROWS in pages: all but the ``kda`` ones;
+        of a stack of sub-layers its ``full`` layers alone."""
+        if self.sublayers:
+            return self.layers_of("full")
         return self.num_layers - self.layers_of("kda")
 
     @property
@@ -385,6 +476,27 @@ class ModelConfig:
         kda_conv - 1 rows of the q | k | v projections)."""
         h, k, v = self.kda_heads, self.kda_key_dim, self.kda_value_dim
         return (h, k, v), (self.kda_conv - 1, h * (2 * k + v))
+
+    @property
+    def ssm_inner(self) -> int:
+        """A mamba2 layer's inner width: heads x channels."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Columns the mamba2 convolution runs over: x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def state_shapes(self) -> tuple:
+        """One slot's state kind a layer of ``state_kind``: (the float32
+        state's shape, the bfloat16 convolution tail's [taps - 1, width])."""
+        if self.state_kind == "mamba2":
+            return (
+                (self.ssm_heads, self.ssm_head_dim, self.ssm_state),
+                (self.ssm_conv - 1, self.ssm_conv_dim),
+            )
+        return self.kda_state_shapes
 
     @property
     def period(self) -> int:

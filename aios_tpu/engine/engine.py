@@ -115,13 +115,24 @@ def _to_default_device(a):
     return jax.device_put(jnp.asarray(a), target)
 
 
+def _layer_leaves(params):
+    """The values of a params tree's layer stack, a kind's own leaves
+    (``by_kind``: model._scan_periods) beside those every layer has."""
+    layers = params.get("layers", {}) if isinstance(params, dict) else {}
+    for name, v in layers.items():
+        if name == "by_kind":
+            for own in v.values():
+                yield from own.values()
+        else:
+            yield v
+
+
 def _is_prequantized(params) -> bool:
     """True when the params tree already holds serving-quantized leaves
     ({"q","s"} int8 or {"q4","s4"} int4 dicts from quantize_params)."""
-    layers = params.get("layers", {}) if isinstance(params, dict) else {}
     return any(
         isinstance(v, dict) and ("q" in v or "q4" in v)
-        for v in layers.values()
+        for v in _layer_leaves(params)
     )
 
 
@@ -129,7 +140,7 @@ def _prequantized_mode(params) -> str:
     """The dominant stored serving mode of a prequantized tree ("int4" when
     any packed-nibble leaf exists — mixed trees are int4-with-int8-fallback
     by construction)."""
-    for v in params.get("layers", {}).values():
+    for v in _layer_leaves(params):
         if isinstance(v, dict) and "q4" in v:
             return "int4"
     return "int8"
@@ -316,16 +327,21 @@ def refuse_for_two_kinds(cfg, **asked) -> None:
 
 def refuse_for_state_kind(cfg, **asked) -> None:
     """Raise, naming the model and the feature, for a serving feature that
-    cannot take a recurrent state a slot (a stack with kda layers: the state
-    kind, engine/paged.py header). No quiet fallback: ``LoadModel`` fails
-    with this error, as its two siblings' do."""
-    if not getattr(cfg, "state_kinds", False):  # (a test's stub has none)
+    cannot take a recurrent state a slot (a stack with kda or mamba2 layers:
+    the state kind, engine/paged.py header). No quiet fallback: ``LoadModel``
+    fails with this error, as its two siblings' do."""
+    kind = getattr(cfg, "state_kind", None)  # (a test's stub has none)
+    if kind is None:
         return
     wanted = [what for what, on in asked.items() if on]
     if wanted:
+        layers, pool = {
+            "kda": ("linear-attention (kda)", "latent pool"),
+            "mamba2": ("state-space (mamba2)", "grouped-query page pool"),
+        }[kind]
         raise ValueError(
-            f"{cfg.name}: linear-attention (kda) layers keep one recurrent "
-            f"state a slot beside the bf16 latent pool; this load asks for "
+            f"{cfg.name}: {layers} layers keep one recurrent "
+            f"state a slot beside the bf16 {pool}; this load asks for "
             f"{' and '.join(w.replace('_', ' ') for w in wanted)}, which "
             f"cannot take a state yet"
         )
@@ -524,7 +540,7 @@ class TPUEngine:
             a_context_sharded_cache=bool(seq_sharded_cache),
             a_draft_model_and_its_speculation=draft is not None,
         )
-        if cfg.state_kinds:
+        if cfg.state_kind == "kda":
             kda.check(cfg)
         # Pallas kernels are per-device programs; under a sharding plan the
         # global-array paths must stay pure XLA (GSPMD partitions those) —
@@ -713,14 +729,17 @@ class TPUEngine:
         self.prefix_hits_refused_window = 0
         # the state kind (a stack with kda layers: paged.py's header): its
         # host-side account, the hits refused for want of a state and the
-        # rows they would have served, and the rows through kda layers by
-        # graph kind (rows x kda layers, padding included)
+        # rows they would have served, and the rows through the state
+        # layers by graph kind (rows x such layers, padding included:
+        # ``kda_rows_*`` or ``mamba_rows_*`` in stats(), by the kind)
         self.slot_states: Optional[paged.SlotStates] = None
         self.refused_prefixes: Optional[paged.SeenPrefixes] = None
         self.prefix_hits_refused_state = 0
         self.prefix_rows_refused_state = 0
-        self.kda_rows_prefill = 0
-        self.kda_rows_decode = 0
+        self.state_rows_prefill = 0
+        self.state_rows_decode = 0
+        # the donated state's two keys of the state kind's arrays
+        self._state_keys = (f"{cfg.state_kind}_s", f"{cfg.state_kind}_tail")
         if self.paged:
             # sp in the mesh: the pool (like any non-seq-sharded cache)
             # REPLICATES over the sp axis — its shard_map specs name only
@@ -775,12 +794,10 @@ class TPUEngine:
                 self.allocator = paged.PageAllocator(
                     num_pages, page_size, num_slots, max_blocks, replicas=R
                 )
-                # (a stack with kda layers: its mla layers alone hold rows)
+                # (a stack with a state kind: the layers that keep rows alone)
                 pool_shape = (cfg.row_layers, num_pages)
             if cfg.state_kinds:
-                self.slot_states = paged.SlotStates(
-                    cfg.layers_of("kda"), num_slots, *cfg.kda_state_shapes
-                )
+                self.slot_states = paged.SlotStates.of(cfg, num_slots)
                 # every prompt admits in chunks: a chunk graph takes the
                 # slot and the count of real rows, which the state needs
                 self._whole_prompt_rows = 0
@@ -826,7 +843,7 @@ class TPUEngine:
             )
             if cfg.state_kinds and self._prefix_chunk is None:
                 raise ValueError(
-                    f"{cfg.name}: a stack with kda layers admits every "
+                    f"{cfg.name}: a stack with {cfg.state_kind} layers admits every "
                     f"prompt in chunks, and no prefill bucket up to "
                     f"{self.prefill_chunk_default} divides the context "
                     f"{self.max_context}"
@@ -1055,7 +1072,8 @@ class TPUEngine:
         if self.counts_picks:
             self.state["moe_stats"] = model.zero_stats(cfg)[0]
         if states:
-            self.state["kda_s"], self.state["kda_tail"] = states
+            for key, array in zip(self._state_keys, states):
+                self.state[key] = array
         # rows x sub-layers whose residual was mixed (engine/residual.py),
         # from each dispatched program's static shapes: host-side, no
         # device work (`_devprof_note` sees every dispatch)
@@ -1499,7 +1517,7 @@ class TPUEngine:
         no such kind."""
         if self.slot_states is None:
             return ()
-        return (st["kda_s"], st["kda_tail"])
+        return tuple(st[key] for key in self._state_keys)
 
     @staticmethod
     def _split_tables(tables):
@@ -1547,7 +1565,7 @@ class TPUEngine:
             else:
                 logits, k, v, *picks = out
             if self.slot_states is not None:
-                kda_s, kda_tail, *picks = picks
+                slot_s, slot_tail, *picks = picks
         elif self.quant_cache:
             logits, k, v, (k_s, v_s), *picks = model.decode_step(
                 params,
@@ -1615,7 +1633,7 @@ class TPUEngine:
         if self.counts_picks:
             st["moe_stats"] = moe_stats + picks[0]
         if self.slot_states is not None:
-            st["kda_s"], st["kda_tail"] = kda_s, kda_tail
+            st[self._state_keys[0]], st[self._state_keys[1]] = slot_s, slot_tail
         return st, next_tokens
 
     def _step_impl(self, params, state: DecodeState, n_steps: int, tables=None,
@@ -2148,7 +2166,7 @@ class TPUEngine:
         else:
             logits, upd["k"], upd["v"], *picks = out
         if self.slot_states is not None:
-            upd["kda_s"], upd["kda_tail"], *picks = picks
+            upd[self._state_keys[0]], upd[self._state_keys[1]], *picks = picks
         if self.counts_picks:
             upd["moe_stats"] = state["moe_stats"] + picks[0]
         return logits, upd
@@ -2249,9 +2267,9 @@ class TPUEngine:
         if self.slot_states is not None:
             rows = self._program_rows(kind, key) * self.slot_states.layers
             if kind in ("step", "masked"):
-                self.kda_rows_decode += rows
+                self.state_rows_decode += rows
             else:
-                self.kda_rows_prefill += rows
+                self.state_rows_prefill += rows
         dp = self._devprof
         if dp is None:
             return None
@@ -4059,8 +4077,9 @@ class TPUEngine:
             )
         if self.slot_states is not None:
             out.update(self.slot_states.stats())
-            out["kda_rows_prefill"] = self.kda_rows_prefill
-            out["kda_rows_decode"] = self.kda_rows_decode
+            rows = {"kda": "kda", "mamba2": "mamba"}[self.cfg.state_kind]
+            out[f"{rows}_rows_prefill"] = self.state_rows_prefill
+            out[f"{rows}_rows_decode"] = self.state_rows_decode
             out["prefix_hits_refused_state"] = self.prefix_hits_refused_state
             out["prefix_rows_refused_state"] = self.prefix_rows_refused_state
         if self.counts_picks:

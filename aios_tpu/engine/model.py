@@ -424,8 +424,14 @@ def causal_mask(T: int, window: Optional[int]) -> jnp.ndarray:
 
 
 def _project_qkv(x, lp, cfg: ModelConfig, cos, sin, qmm=None):
-    B, T, E = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    return qkv_of(h, lp, cfg, cos, sin, qmm)
+
+
+def qkv_of(h, lp, cfg: ModelConfig, cos, sin, qmm=None):
+    """Normed rows h [B, T, E] -> (q [B, T, H, D], k, v [B, T, KH, D]), q and
+    k rotated unless the model applies no rotary embedding (``cfg.rotary``)."""
+    B, T, E = h.shape
     if "w_qkv" in lp:  # fused serving layout (quantize_params)
         Q, KV = cfg.q_dim, cfg.kv_dim
         qkv = matmul(h, lp["w_qkv"], qmm)
@@ -444,8 +450,9 @@ def _project_qkv(x, lp, cfg: ModelConfig, cos, sin, qmm=None):
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.rotary:
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     return q, k, v
 
 
@@ -514,10 +521,16 @@ def _add_mlp(x, stats, lp, cfg: ModelConfig, moe_dense, qmm, live=None):
     return x + out, add_stats(stats, new)
 
 
-def _swiglu(h, lp, prefix: str, width: int, qmm=None):
+def _swiglu(h, lp, prefix: str, width: int, qmm=None, act: str = "swiglu"):
     """One SwiGLU FFN over normed rows from the leaves ``<prefix>gateup``
     (the fused serving layout [gate | up], quantize_params) or
-    ``<prefix>gate`` / ``<prefix>up``, and ``<prefix>down``."""
+    ``<prefix>gate`` / ``<prefix>up``, and ``<prefix>down``. With ``act``
+    "relu2" (ModelConfig.expert_act) the FFN has no gate:
+    ``down(relu(up(x))^2)`` from ``<prefix>up`` and ``<prefix>down``."""
+    if act == "relu2":
+        u = matmul(h, lp[prefix + "up"], qmm).astype(jnp.float32)
+        z = jnp.square(jax.nn.relu(u)).astype(h.dtype)
+        return matmul(z, lp[prefix + "down"], qmm, "row")
     if prefix + "gateup" in lp:
         gu = matmul(h, lp[prefix + "gateup"], qmm)
         gate_pre, u = gu[..., :width], gu[..., width:]
@@ -561,7 +574,7 @@ def ffn(
     None: every serving graph of such a model carries the counters.
     """
     if "w_router" not in lp:
-        out = _swiglu(h, lp, "w_", cfg.intermediate_size, qmm)
+        out = _swiglu(h, lp, "w_", cfg.intermediate_size, qmm, cfg.expert_act)
         return out, jnp.float32(0.0), None
     n_tok = h.shape[0] * h.shape[1]
     stats = None
@@ -585,10 +598,11 @@ def ffn(
             out, aux, stats = moe_mod.moe_ffn_dense(
                 h, lp, cfg, with_stats=True
             )
-    if "ws_gateup" in lp or "ws_gate" in lp:
+    if "ws_gateup" in lp or "ws_gate" in lp or "ws_up" in lp:
         with jax.named_scope("moe_shared"):
             out = out + _swiglu(
-                h, lp, "ws_", cfg.n_shared_experts * cfg.expert_dim, qmm
+                h, lp, "ws_", cfg.n_shared_experts * cfg.expert_dim, qmm,
+                cfg.expert_act,
             )
     if stats is None:  # the training forward's dispatch counts nothing
         stats = jnp.zeros((moe_mod.PICK_STATS,), jnp.int32)
@@ -728,6 +742,13 @@ def _forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None, kernels=Non
         from . import latent
 
         return latent.forward_with_kv(
+            params, cfg, tokens, attn_fn=attn_fn, with_aux=with_aux,
+            qmm=qmm, moe_dense=moe_dense, logit_row=logit_row,
+        )
+    if cfg.sublayers:
+        from . import mamba2
+
+        return mamba2.forward_with_kv(
             params, cfg, tokens, attn_fn=attn_fn, with_aux=with_aux,
             qmm=qmm, moe_dense=moe_dense, logit_row=logit_row,
         )
@@ -1115,6 +1136,11 @@ def _scan_periods(block, carry, segments, kinds, lead_kinds=()):
     together, with ``kind_index``, the layer's place among the stack's
     layers of its kind (the index of its state or of its pages' layer).
 
+    In a stack of sub-layers (engine/mamba2.py) the FFN is a kind's own too:
+    all that the layers have alike is their one norm, and the ``moe`` kind's
+    leaves, its expert stacks among them, stand under ``by_kind`` like a
+    mixer's (``expert_layer`` is then the layer's place among the moe layers).
+
     The expert stacks stay whole at EVERY token count, so model.ffn reads
     them in place (the visit path in a decode step, the grouped path
     elsewhere, below moe.grouped_pays too): the body slices the stacked
@@ -1147,6 +1173,7 @@ def _scan_periods(block, carry, segments, kinds, lead_kinds=()):
     common = {k: v for k, v in seg.items() if k != "by_kind"}
     n = jax.tree.leaves(common)[0].shape[0]
     scanned, whole = _experts_apart(common, True)
+    own_leaves = {k: _experts_apart(v, True) for k, v in by_kind.items()}
     # a layer's place among its period's layers of its kind, and how many
     # layers of each kind stand before the pattern
     place = [kinds[:i].count(kind) for i, kind in enumerate(kinds)]
@@ -1168,11 +1195,13 @@ def _scan_periods(block, carry, segments, kinds, lead_kinds=()):
                   "layer_kind": kind, "layer_in_period": i}
             if by_kind:
                 own = l0 // period * kinds.count(kind) + place[i]
+                sliced, stacks = own_leaves[kind]
                 lp.update(jax.tree.map(
                     lambda a, own=own: jax.lax.dynamic_index_in_dim(
                         a, own, 0, keepdims=False
-                    ), by_kind[kind],
+                    ), sliced,
                 ), kind_index=before[kind] + own)
+                lp = _with_experts(lp, stacks, own)
             carry, y = block(carry, (lp, first + l0 + i if first else l0 + i))
             ys.append(y)
         if ys[0] is None:
@@ -1296,6 +1325,14 @@ def prefill_chunk_paged(
         from . import latent
 
         return latent.prefill_chunk_paged(
+            params, cfg, tokens, start, k_pool, v_pool, table_row,
+            qmm=qmm, moe_dense=moe_dense, states=states, slot=slot,
+            n_valid=n_valid,
+        )
+    if cfg.sublayers:
+        from . import mamba2
+
+        return mamba2.prefill_chunk_paged(
             params, cfg, tokens, start, k_pool, v_pool, table_row,
             qmm=qmm, moe_dense=moe_dense, states=states, slot=slot,
             n_valid=n_valid,
@@ -1519,6 +1556,14 @@ def decode_step_paged(
         from . import latent
 
         return latent.decode_step_paged(
+            params, cfg, tokens, lengths, k_pool, v_pool, tables,
+            kernels=kernels, active=active, moe_dense=moe_dense, qmm=qmm,
+            states=states,
+        )
+    if cfg.sublayers:
+        from . import mamba2
+
+        return mamba2.decode_step_paged(
             params, cfg, tokens, lengths, k_pool, v_pool, tables,
             kernels=kernels, active=active, moe_dense=moe_dense, qmm=qmm,
             states=states,
@@ -1752,6 +1797,12 @@ def verify_step_paged(
         return latent.verify_step_paged(
             params, cfg, tokens, lengths, k_pool, v_pool, tables,
             active=active, moe_dense=moe_dense, qmm=qmm,
+        )
+    if cfg.state_kinds:
+        raise ValueError(
+            f"{cfg.name}: a verify step over {cfg.state_kind} layers would "
+            "have to roll a rejected token back out of the recurrent state; "
+            "no graph does"
         )
     B, T = tokens.shape
     MB = tables.shape[1] if layout is None else layout.max_blocks

@@ -1,6 +1,12 @@
 """Mixture-of-experts FFN: router + expert computation, TPU-first.
 
-What serves (top-k routed SwiGLU experts, one math; which of the three a
+An expert is a SwiGLU, ``down(silu(gate(x)) * up(x))`` with [gate | up] fused,
+or, with ``cfg.expert_act`` "relu2", ungated: ``down(relu(up(x))^2)``, two
+matrices, the up matrices stored transposed (``we_up_t``: EXPERT_LEAVES). The
+activation is a static property of the model and goes through the three
+paths and both kernels; nothing of the SwiGLU paths' graphs changes for it.
+
+What serves (top-k routed experts, one math; which of the three a
 graph takes follows from what the graph IS and from static shapes, never
 from an option: ``visit_serves``, ``grouped_serves``):
 
@@ -93,6 +99,12 @@ def _expert_einsum(spec: str, x: jnp.ndarray, w) -> jnp.ndarray:
         # s [X, 1, out] -> [X, 1, out] broadcasting over the token/queue axis
         return (y * jnp.squeeze(s, axis=-2)[:, None, :]).astype(x.dtype)
     return jnp.einsum(spec, x, w)
+
+
+def _relu2(u: jnp.ndarray) -> jnp.ndarray:
+    """An ungated expert's activation (``cfg.expert_act`` "relu2"):
+    relu(u)^2, squared in float32, in u's dtype."""
+    return jnp.square(jax.nn.relu(u.astype(jnp.float32))).astype(u.dtype)
 
 
 def _within_best_groups(choice: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
@@ -231,14 +243,17 @@ def moe_ffn_dense(
     weights, idx_here, here = local_picks(weights, idx, cfg)
     gates = gate_matrix(weights, idx_here, cfg.held_experts).astype(h.dtype)
 
-    if "we_gateup" in lp:  # fused serving layout (model.quantize_params)
-        F = cfg.expert_dim
-        gu = _expert_einsum("ne,xef->xnf", flat, lp["we_gateup"])
-        g, u = gu[..., :F], gu[..., F:]
+    if cfg.expert_act == "relu2":  # ungated: one up stack, [X, F, E]
+        z = _relu2(_expert_einsum("ne,xfe->xnf", flat, lp["we_up_t"]))
     else:
-        g = _expert_einsum("ne,xef->xnf", flat, lp["we_gate"])
-        u = _expert_einsum("ne,xef->xnf", flat, lp["we_up"])
-    z = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * u  # [X, N, F]
+        if "we_gateup" in lp:  # fused serving layout (model.quantize_params)
+            F = cfg.expert_dim
+            gu = _expert_einsum("ne,xef->xnf", flat, lp["we_gateup"])
+            g, u = gu[..., :F], gu[..., F:]
+        else:
+            g = _expert_einsum("ne,xef->xnf", flat, lp["we_gate"])
+            u = _expert_einsum("ne,xef->xnf", flat, lp["we_up"])
+        z = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * u  # [X, N, F]
     z = z * gates.T[..., None]  # gate before down-proj: scales per (x, n)
     # Down-project then contract the expert axis — one psum over ep under
     # GSPMD. Quantized leaves need the per-expert scale applied before the
@@ -305,14 +320,17 @@ def moe_ffn_dispatch(
     dispatch = jnp.einsum("nkx,nkc->nxc", exp_oh, slot_oh)
 
     xe = jnp.einsum("nxc,ne->xce", dispatch, flat)  # [X, cap, E]
-    if "we_gateup" in lp:
-        F = cfg.expert_dim
-        gu = _expert_einsum("xce,xef->xcf", xe, lp["we_gateup"])
-        g, u = gu[..., :F], gu[..., F:]
+    if cfg.expert_act == "relu2":
+        z = _relu2(_expert_einsum("xce,xfe->xcf", xe, lp["we_up_t"]))
     else:
-        g = _expert_einsum("xce,xef->xcf", xe, lp["we_gate"])
-        u = _expert_einsum("xce,xef->xcf", xe, lp["we_up"])
-    z = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * u
+        if "we_gateup" in lp:
+            F = cfg.expert_dim
+            gu = _expert_einsum("xce,xef->xcf", xe, lp["we_gateup"])
+            g, u = gu[..., :F], gu[..., F:]
+        else:
+            g = _expert_einsum("xce,xef->xcf", xe, lp["we_gate"])
+            u = _expert_einsum("xce,xef->xcf", xe, lp["we_up"])
+        z = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * u
     ye = _expert_einsum("xcf,xfe->xce", z, lp["we_down"])
     out = jnp.einsum("nxc,xce->ne", combine, ye)
     aux = load_balance_aux(probs, idx_all, cfg.num_experts)
@@ -320,7 +338,11 @@ def moe_ffn_dispatch(
 
 
 # the leaves of an expert layer that hold one matrix an expert
-EXPERT_LEAVES = ("we_gateup", "we_gate", "we_up", "we_down")
+# (``we_up_t``: an UNGATED expert's up matrix, transposed: ``[X, F, E]`` with
+# its scales ``[X, 1, F]``, a column of the matrix a row of the leaf, so that
+# a width F that is no whole lane tiles lies along rows in both its matrices:
+# ops/expert_visit.py ``supports_pallas``)
+EXPERT_LEAVES = ("we_gateup", "we_gate", "we_up", "we_down", "we_up_t")
 
 
 def grouped_pays(n_tok: int, cfg: ModelConfig) -> bool:
@@ -370,9 +392,23 @@ def visit_serves(cfg: ModelConfig, moe_dense: bool = False) -> bool:
     return cfg.moe and not moe_dense
 
 
-def _experts_in_place(lp, F: int):
+def _first_stack(lp, cfg: ModelConfig):
+    """The stack an expert's first product reads: the fused [gate | up], or
+    an ungated expert's up matrices alone, transposed (EXPERT_LEAVES; None
+    where the layout has neither: the unfused leaves of a float tree)."""
+    return lp.get("we_up_t" if cfg.expert_act == "relu2" else "we_gateup")
+
+
+def _kernel_act(cfg: ModelConfig) -> dict:
+    """The two expert kernels' static ``act`` argument: nothing for the
+    SwiGLU they default to (their jitted calls stay as they were)."""
+    return {} if cfg.expert_act == "swiglu" else {"act": cfg.expert_act}
+
+
+def _experts_in_place(lp, F: int, act: str = "swiglu"):
     """(swiglu(x, e), down(z, e)) of one layer's experts read WHERE THEY
-    LIE: expert ``e``'s SwiGLU over rows ``x`` (in x's dtype) and its down
+    LIE: expert ``e``'s SwiGLU (``act`` "relu2": its ungated relu(up)^2) over
+    rows ``x`` (in x's dtype) and its down
     product (float32), each matrix product indexing ``w[l, e]`` itself.
     ``lp``'s expert leaves may be one layer's ``[X, in, out]`` or, with
     ``lp["expert_layer"]`` the layer's index into them, the whole stacks
@@ -386,18 +422,19 @@ def _experts_in_place(lp, F: int):
             stack, (l, e, 0, 0), (1, 1) + stack.shape[2:]
         )[0, 0]
 
-    def qdot(x, w, e):  # [rows, in] @ expert e's [in, out]; float32 out
+    def qdot(x, w, e, spec="ni,io->no"):
+        # [rows, in] @ expert e's [in, out] (``spec``: or its [out, in]);
+        # float32 out
         if isinstance(w, dict):
             y = jnp.einsum(
-                "ni,io->no", x, at(w["q"], e),
-                preferred_element_type=jnp.float32,
+                spec, x, at(w["q"], e), preferred_element_type=jnp.float32,
             )
             return y * at(w["s"], e)[0]
-        return jnp.einsum(
-            "ni,io->no", x, at(w, e), preferred_element_type=jnp.float32
-        )
+        return jnp.einsum(spec, x, at(w, e), preferred_element_type=jnp.float32)
 
     def swiglu(x, e):
+        if act == "relu2":
+            return _relu2(qdot(x, lp["we_up_t"], e, "ni,oi->no").astype(x.dtype))
         if "we_gateup" in lp:  # fused serving layout (quantize_params)
             gu = qdot(x, lp["we_gateup"], e).astype(x.dtype)
             a, u = gu[:, :F], gu[:, F:]
@@ -445,17 +482,17 @@ def moe_ffn_visit(
     )
     visit, n = expert_visit.visit_list(touched)
 
-    gu, dn = lp.get("we_gateup"), lp["we_down"]
+    gu, dn, act = _first_stack(lp, cfg), lp["we_down"], _kernel_act(cfg)
     if (ops.use_pallas() and isinstance(gu, dict) and isinstance(dn, dict)
-            and expert_visit.supports_pallas(E, F)):
+            and expert_visit.supports_pallas(E, F, **act)):
         stacks = (gu["q"], gu["s"], dn["q"], dn["s"])
         if "expert_layer" not in lp:
             stacks = tuple(a[None] for a in stacks)
         out = expert_visit.expert_visit(
-            flat, gates, visit, n, lp.get("expert_layer", 0), *stacks
+            flat, gates, visit, n, lp.get("expert_layer", 0), *stacks, **act
         )
     else:
-        swiglu, down = _experts_in_place(lp, F)
+        swiglu, down = _experts_in_place(lp, F, cfg.expert_act)
 
         def one(i, acc):
             e = visit[i]
@@ -539,19 +576,19 @@ def moe_ffn_grouped(
     )
     x_rows = jnp.concatenate([flat, jnp.zeros((1, E), flat.dtype)])[src]
 
-    gu, dn = lp.get("we_gateup"), lp["we_down"]
+    gu, dn, act = _first_stack(lp, cfg), lp["we_down"], _kernel_act(cfg)
     if (ops.use_pallas() and isinstance(gu, dict) and isinstance(dn, dict)
-            and expert_group.supports_pallas(E, F)):
+            and expert_group.supports_pallas(E, F, **act)):
         stacks = (gu["q"], gu["s"], dn["q"], dn["s"])
         if "expert_layer" not in lp:
             stacks = tuple(a[None] for a in stacks)
-        cap = expert_group.row_cap(E, F, flat.dtype.itemsize)
+        cap = expert_group.row_cap(E, F, flat.dtype.itemsize, **act)
         y_rows = expert_group.expert_group(
             x_rows, *expert_group.unit_list(blocks, cap, N * k),
-            lp.get("expert_layer", 0), *stacks, cap=cap,
+            lp.get("expert_layer", 0), *stacks, cap=cap, **act,
         )
     else:
-        swiglu, down = _experts_in_place(lp, F)
+        swiglu, down = _experts_in_place(lp, F, cfg.expert_act)
         block_end = jnp.cumsum(blocks)
 
         def block(i, y_rows):

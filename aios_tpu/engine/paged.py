@@ -51,8 +51,12 @@ is held too: else the longest shorter hit that is, else none
 
 A THIRD kind of per-slot memory holds no rows at all: the STATE KIND of a
 stack whose layers are mostly linear-attention (``kda``) layers
-(ModelConfig.state_kinds; engine/kda.py). Such a layer keeps, a slot, one
-float32 recurrent state [heads, key, value] and the last taps - 1 rows of its
+(ModelConfig.state_kind; engine/kda.py) or in which state-space (``mamba2``)
+layers stand beside grouped-query ones (engine/mamba2.py: there the pool is
+the GROUPED-QUERY pool, and only the stack's ``full`` layers have a layer of
+it). Such a layer keeps, a slot, one float32 recurrent state ([heads, key,
+value]; for mamba2 [heads, channels, state values]: ``ModelConfig.state_shapes``)
+and the last taps - 1 rows of its
 convolution's input, which do not grow with the context and are overwritten
 by every token. They live in ONE array pair a model, indexed by SLOT and not
 through a table: states [L_kda, S + 1, heads, key, value] float32 and tails
@@ -428,6 +432,11 @@ class SlotStates:
         # (slots rounded up to whole bfloat16 tiles of 16 rows: the header)
         self.tail_shape = (layers, taps, -(-(num_slots + 1) // 16) * 16, width)
         self.live = np.zeros(num_slots, dtype=bool)
+
+    @classmethod
+    def of(cls, cfg, num_slots: int) -> "SlotStates":
+        """The account of ``cfg``'s state kind over ``num_slots`` slots."""
+        return cls(cfg.layers_of(cfg.state_kind), num_slots, *cfg.state_shapes)
 
     @property
     def slot_bytes(self) -> int:

@@ -206,7 +206,8 @@ SETUP_PHASES = (
     "load.model", "load.weights", "load.engine", "load.warmup", "load.attach",
     "warmup.trace", "warmup.lower", "warmup.compile",
     # inside load.engine, for a model with a state kind alone (engine/paged.py
-    # header): the recurrent states' and tails' arrays made and placed
+    # header: kda or mamba2 layers): the recurrent states' and tails' arrays
+    # made and placed
     "load.states",
 )
 # every name a ``Phases`` object closes

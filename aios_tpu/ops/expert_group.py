@@ -62,11 +62,12 @@ ROW_CAP_MAX = 512  # rows of one unit at most
 _COLS = 2048  # columns of one product: what bounds the kernel's temporaries
 
 
-def row_cap(E: int, F: int, itemsize: int = 2) -> int:
+def row_cap(E: int, F: int, itemsize: int = 2, act: str = "swiglu") -> int:
     """The most rows of one unit: what half the kernel's VMEM holds of a row's
-    float32 ``[gate | up]`` accumulator, SwiGLU result, float32 down product
-    and two copies of the row itself, in whole PASSes, ROW_CAP_MAX at most."""
-    a_row = 2 * F * 4 + F * itemsize + E * 4 + 2 * E * itemsize
+    float32 ``[gate | up]`` accumulator (``up`` alone for an ungated expert),
+    SwiGLU result, float32 down product and two copies of the row itself, in
+    whole PASSes, ROW_CAP_MAX at most."""
+    a_row = (1 if act == "relu2" else 2) * F * 4 + F * itemsize + E * 4 + 2 * E * itemsize
     return max(min(_VMEM_LIMIT // 2 // a_row, ROW_CAP_MAX) // PASS, 1) * PASS
 
 
@@ -124,11 +125,13 @@ def _col_chunk(width: int) -> int:
 
 def _group_kernel(exp_ref, blk_ref, nb_ref, n_ref, lyr_ref, x_hbm, wgu_ref,
                   sgu_ref, wd_ref, sd_ref, y_hbm, x_scr, gu_acc, z_scr, y_scr,
-                  in_sem, out_sem, *, nku: int, nkd: int, F: int):
+                  in_sem, out_sem, *, nku: int, nkd: int, F: int,
+                  act: str = "swiglu", width: int = 0):
     i, s = pl.program_id(0), pl.program_id(1)
     f32 = jnp.float32
     dt = x_scr.dtype
     E = y_scr.shape[1]
+    W = gu_acc.shape[1]  # 2F, or F without a gate
     tk, tkd = E // nku, F // nkd
     RB = ROW_BLOCK
     last = nku + nkd - 1
@@ -180,7 +183,7 @@ def _group_kernel(exp_ref, blk_ref, nb_ref, n_ref, lyr_ref, x_hbm, wgu_ref,
 
     def arrive(b):
         rows_in(i, slot, b).wait()
-        block(gu_acc, b)[...] = jnp.zeros((RB, 2 * F), f32)
+        block(gu_acc, b)[...] = jnp.zeros((RB, W), f32)
 
     each(own + ahead, start, first)
     each(nb, arrive, first)
@@ -191,7 +194,7 @@ def _group_kernel(exp_ref, blk_ref, nb_ref, n_ref, lyr_ref, x_hbm, wgu_ref,
 
     # a weight block's product: whole PASSes of rows in a rolled loop, one
     # body, then a rest of up to TAIL rows in a product of its own
-    cg, cd = _col_chunk(2 * F), _col_chunk(E)
+    cg, cd = _col_chunk(W), _col_chunk(E)
     whole = (nb * RB) // PASS
     rest = nb * RB - whole * PASS
     passes = whole + (rest > TAIL).astype(jnp.int32)
@@ -204,10 +207,16 @@ def _group_kernel(exp_ref, blk_ref, nb_ref, n_ref, lyr_ref, x_hbm, wgu_ref,
         x = x_scr[slot, at, cols(s, tk)]
         # unrolled: chunk c + 1's int8 -> bf16 under chunk c's product (rolled,
         # Mixtral's 14 chunks a block read 3.18 ms a layer call against 2.60)
-        for c in range(0, 2 * F, cg):
-            gu_acc[at, c:c + cg] += jnp.dot(
-                x, wgu_ref[:, c:c + cg].astype(dt), preferred_element_type=f32
-            )
+        for c in range(0, W, cg):
+            if act == "swiglu":
+                gu_acc[at, c:c + cg] += jnp.dot(
+                    x, wgu_ref[:, c:c + cg].astype(dt), preferred_element_type=f32
+                )
+            else:  # the up matrix lies transposed: its columns are rows here
+                gu_acc[at, c:c + cg] += jax.lax.dot_general(
+                    x, wgu_ref[c:c + cg, :].astype(dt), (((1,), (1,)), ((), ())),
+                    preferred_element_type=f32,
+                )
 
     def down(first, rows):
         at = pl.ds(pl.multiple_of(first, RB), rows)
@@ -229,6 +238,13 @@ def _group_kernel(exp_ref, blk_ref, nb_ref, n_ref, lyr_ref, x_hbm, wgu_ref,
             gate, up = cols(k, tkd), pl.ds(pl.multiple_of(F + k * tkd, 128), tkd)
             acc = block(gu_acc, b)
             a = (acc[:, gate] * sgu_ref[:, gate]).astype(dt)
+            if act == "relu2":  # no gate matrix: the one product, squared
+                z = jnp.square(jax.nn.relu(a.astype(f32))).astype(dt)
+                if width:  # columns beyond the expert's width: blocks' rest
+                    col = k * tkd + jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+                    z = jnp.where(col < width, z, jnp.zeros_like(z))
+                block(z_scr, b)[:, gate] = z
+                return
             u = (acc[:, up] * sgu_ref[:, up]).astype(dt)
             block(z_scr, b)[:, gate] = jax.nn.silu(a.astype(f32)).astype(dt) * u
 
@@ -246,7 +262,7 @@ def _group_kernel(exp_ref, blk_ref, nb_ref, n_ref, lyr_ref, x_hbm, wgu_ref,
     each(nb, lambda b: rows_out(i, b).wait(), live & (s == last) & (i == n - 1))
 
 
-@functools.partial(jax.jit, static_argnames=("cap", "interpret"))
+@functools.partial(jax.jit, static_argnames=("cap", "interpret", "act"))
 def expert_group(
     x: jnp.ndarray,  # [M, E] — the picks' rows laid out by expert (``segments``)
     expert: jnp.ndarray,  # [U] int32 — ``unit_list``
@@ -261,13 +277,28 @@ def expert_group(
     *,
     cap: int,
     interpret: bool = False,
+    act: str = "swiglu",
 ):
     """Row by row ``swiglu(x @ gateup[l, e]) @ down[l, e]`` for the expert
     ``e`` whose segment the row lies in: [M, E] float32, defined on the rows
-    of the units' row blocks alone."""
+    of the units' row blocks alone. With ``act`` "relu2" the first stack is
+    the up matrices alone, TRANSPOSED, ``[L, X, F, E]``
+    (expert_visit.supports_pallas): ``relu(x @ up^T)^2 @ down``."""
     M, E = x.shape
     F = wd_q.shape[2]
-    tk, tkd = _tile_rows(E, 2 * F), _tile_rows(F, E)
+    W = wgu_s.shape[3]  # 2F, or F without a gate
+    extra = {} if act == "swiglu" else {"act": act}
+    if F % 128:
+        # an ungated expert's width in whole int8 tiles of 32 rows only
+        # (expert_visit.supports_pallas): a scratch row that is not whole
+        # lane tiles cannot be sliced at a traced row, so the kernel runs at
+        # the width rounded UP to lane tiles: the weight blocks overhang
+        # their stacks (what lies beyond is never defined and always finite
+        # int8), and the activation is zeroed beyond the true width, so the
+        # overhanging rows of ``down`` multiply zeros
+        extra["width"] = F
+        F = W = -(-F // 128) * 128
+    tk, tkd = _tile_rows(E, W), _tile_rows(F, E)
     nku, nkd = E // tk, F // tkd
 
     def at(rows, width, tile):  # a block of unit i's expert's matrix
@@ -276,9 +307,16 @@ def expert_group(
             lambda i, s, exp, blk, nb, n, lyr: (lyr[0], exp[i], tile(s), 0),
         )
 
+    up = at(tk, W, lambda s: jnp.minimum(s, nku - 1))
+    if act != "swiglu":  # [F, tk] of the transposed up matrix
+        up = pl.BlockSpec(
+            (None, None, W, tk),
+            lambda i, s, exp, blk, nb, n, lyr: (
+                lyr[0], exp[i], 0, jnp.minimum(s, nku - 1)),
+        )
     n1 = jnp.asarray(n, jnp.int32).reshape(1)
     return pl.pallas_call(
-        functools.partial(_group_kernel, nku=nku, nkd=nkd, F=F),
+        functools.partial(_group_kernel, nku=nku, nkd=nkd, F=F, **extra),
         out_shape=jax.ShapeDtypeStruct((M, E), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
@@ -286,15 +324,15 @@ def expert_group(
             grid=(jnp.maximum(n1[0], 1), nku + nkd),
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),
-                at(tk, 2 * F, lambda s: jnp.minimum(s, nku - 1)),
-                at(1, 2 * F, lambda s: 0),
+                up,
+                at(1, W, lambda s: 0),
                 at(tkd, E, lambda s: jnp.maximum(s - nku, 0)),
                 at(1, E, lambda s: 0),
             ],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
                 pltpu.VMEM((2, cap, E), x.dtype),
-                pltpu.VMEM((cap, 2 * F), jnp.float32),
+                pltpu.VMEM((cap, W), jnp.float32),
                 pltpu.VMEM((cap, F), x.dtype),
                 pltpu.VMEM((cap, E), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),

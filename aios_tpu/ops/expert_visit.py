@@ -61,6 +61,8 @@ def visit_list(touched: jnp.ndarray):
 def _tile_rows(rows: int, width: int) -> int:
     """The most rows, a multiple of 128 that divides ``rows``, of an int8
     block ``[rows', width]`` within TILE_BYTES (128 where none is)."""
+    if rows % 128:  # (an ungated expert's width: ``supports_pallas``)
+        return rows
     best = 128
     for t in range(128, rows + 1, 128):
         if rows % t == 0 and t * width <= TILE_BYTES:
@@ -68,13 +70,22 @@ def _tile_rows(rows: int, width: int) -> int:
     return best
 
 
-def supports_pallas(E: int, F: int) -> bool:
-    """Lane-aligned widths: the row tiles are multiples of 128."""
-    return E % 128 == 0 and F % 128 == 0
+def supports_pallas(E: int, F: int, act: str = "swiglu") -> bool:
+    """Lane-aligned widths: the row tiles are multiples of 128. An UNGATED
+    expert (``act`` "relu2": one up matrix and no [gate | up] to split at a
+    lane boundary) may have a width that is whole int8 tiles of 32 rows only,
+    because BOTH its matrices are stored with the width along their rows (the
+    up matrix transposed, ``[F, E]``: the TPU's own layout of an int8
+    ``[.., E, F]`` array with F no whole lane tiles puts E on the lanes, and
+    a kernel handed it would be handed a COPY of the stack every call:
+    tests/test_mosaic_aot.py -k sublayers); its down matrix is then ONE block
+    (``_tile_rows``)."""
+    return E % 128 == 0 and (F % 128 == 0 or (act == "relu2" and F % 32 == 0))
 
 
 def _visit_kernel(visit_ref, n_ref, lyr_ref, x_ref, g_ref, wgu_ref, sgu_ref,
-                  wd_ref, sd_ref, o_ref, gu_acc, z_scr, *, nku: int, F: int):
+                  wd_ref, sd_ref, o_ref, gu_acc, z_scr, *, nku: int, F: int,
+                  act: str = "swiglu"):
     i, s = pl.program_id(0), pl.program_id(1)
     f32 = jnp.float32
     dt = x_ref.dtype
@@ -88,8 +99,14 @@ def _visit_kernel(visit_ref, n_ref, lyr_ref, x_ref, g_ref, wgu_ref, sgu_ref,
 
     @pl.when(live & (s < nku))
     def _gate_up():
-        part = jnp.dot(x_ref[s], wgu_ref[...].astype(dt),
-                       preferred_element_type=f32)
+        if act == "swiglu":
+            part = jnp.dot(x_ref[s], wgu_ref[...].astype(dt),
+                           preferred_element_type=f32)
+        else:  # the up matrix lies transposed: a K-tile is a run of columns
+            part = jax.lax.dot_general(
+                x_ref[s], wgu_ref[...].astype(dt), (((1,), (1,)), ((), ())),
+                preferred_element_type=f32,
+            )
 
         @pl.when(s == 0)
         def _():
@@ -102,12 +119,16 @@ def _visit_kernel(visit_ref, n_ref, lyr_ref, x_ref, g_ref, wgu_ref, sgu_ref,
     @pl.when(live & (s == nku - 1))
     def _swiglu():
         gu = (gu_acc[...] * sgu_ref[...]).astype(dt)
-        a, u = gu[:, :F], gu[:, F:]
+        if act == "swiglu":
+            a, u = gu[:, :F], gu[:, F:]
         e = visit_ref[i]
         lane = jax.lax.broadcasted_iota(jnp.int32, g_ref.shape, 1)
         gate = jnp.sum(jnp.where(lane == e, g_ref[...], 0.0), axis=1,
                        keepdims=True)
-        z = jax.nn.silu(a.astype(f32)).astype(dt) * u * gate.astype(dt)
+        if act == "swiglu":
+            z = jax.nn.silu(a.astype(f32)).astype(dt) * u * gate.astype(dt)
+        else:  # relu2, no gate matrix: the one product, squared
+            z = jnp.square(jax.nn.relu(gu.astype(f32))).astype(dt) * gate.astype(dt)
         for k in range(nkd):
             z_scr[k] = z[:, k * tkd:(k + 1) * tkd]
 
@@ -118,7 +139,7 @@ def _visit_kernel(visit_ref, n_ref, lyr_ref, x_ref, g_ref, wgu_ref, sgu_ref,
         o_ref[...] += y * sd_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "act"))
 def expert_visit(
     x: jnp.ndarray,  # [N, E] — the step's normed rows, every one of them
     gates: jnp.ndarray,  # [N, X] float32 — a row's weight for each held expert
@@ -131,13 +152,17 @@ def expert_visit(
     wd_s: jnp.ndarray,  # [L, X, 1, E] float32
     *,
     interpret: bool = False,
+    act: str = "swiglu",
 ):
     """sum over the touched experts e of
     ``(swiglu(x @ gateup[l, e]) * gates[:, e]) @ down[l, e]``: [N, E]
-    float32."""
+    float32. With ``act`` "relu2" the first stack is the up matrices alone,
+    TRANSPOSED, ``[L, X, F, E]`` (``supports_pallas``; their scales
+    ``[L, X, 1, F]``), and an expert is ``relu(x @ up^T)^2 @ down``."""
     N, E = x.shape
     X, F = wd_q.shape[1], wd_q.shape[2]
-    tk, tkd = _tile_rows(E, 2 * F), _tile_rows(F, E)
+    W = wgu_s.shape[3]  # 2F, or F without a gate
+    tk, tkd = _tile_rows(E, W), _tile_rows(F, E)
     nku, nkd = E // tk, F // tkd
     x3 = x.reshape(N, nku, tk).transpose(1, 0, 2)  # a K-tile a leading index
 
@@ -150,9 +175,16 @@ def expert_visit(
             lambda i, s, visit, n, lyr: (lyr[0], visit[i], tile(s), 0),
         )
 
+    up = at(tk, W, lambda s: jnp.minimum(s, nku - 1))
+    if act != "swiglu":  # [F, tk] of the transposed up matrix
+        up = pl.BlockSpec(
+            (None, None, F, tk),
+            lambda i, s, visit, n, lyr: (lyr[0], visit[i], 0, jnp.minimum(s, nku - 1)),
+        )
     n1 = jnp.asarray(n, jnp.int32).reshape(1)
     return pl.pallas_call(
-        functools.partial(_visit_kernel, nku=nku, F=F),
+        functools.partial(_visit_kernel, nku=nku, F=F, **(
+            {} if act == "swiglu" else {"act": act})),
         out_shape=jax.ShapeDtypeStruct((N, E), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -162,14 +194,14 @@ def expert_visit(
             in_specs=[
                 whole(nku, N, tk),
                 whole(N, X),
-                at(tk, 2 * F, lambda s: jnp.minimum(s, nku - 1)),
-                at(1, 2 * F, lambda s: 0),
+                up,
+                at(1, W, lambda s: 0),
                 at(tkd, E, lambda s: jnp.maximum(s - nku, 0)),
                 at(1, E, lambda s: 0),
             ],
             out_specs=whole(N, E),
             scratch_shapes=[
-                pltpu.VMEM((N, 2 * F), jnp.float32),
+                pltpu.VMEM((N, W), jnp.float32),
                 pltpu.VMEM((nkd, N, tkd), x.dtype),
             ],
         ),

@@ -361,12 +361,10 @@ class ModelManager:
         rows = kw.get("paged_pool_rows") or self.num_slots * ctx
         if cfg.state_kinds:
             # the state kind (engine/paged.py header): a fixed size a slot
-            # beside the latent rows of the layers that have rows
+            # beside the cache rows of the layers that have rows
             from ..engine.paged import SlotStates
 
-            states = SlotStates(
-                cfg.layers_of("kda"), self.num_slots, *cfg.kda_state_shapes
-            )
+            states = SlotStates.of(cfg, self.num_slots)
             return row * rows + states.stats()["kv_state_bytes"]
         if cfg.kinds and kw.get("paged_pool_rows"):
             # pages by kind (engine/paged.py header): the full layers hold
