@@ -738,6 +738,10 @@ class TPUEngine:
         self.prefix_rows_refused_state = 0
         self.state_rows_prefill = 0
         self.state_rows_decode = 0
+        # key tiles the paged chunks' attention folded, and those the
+        # slots' tables map (ChunkedPrefill.step_async)
+        self.prefill_kv_tiles_read = 0
+        self.prefill_kv_tiles_mapped = 0
         # the donated state's two keys of the state kind's arrays
         self._state_keys = (f"{cfg.state_kind}_s", f"{cfg.state_kind}_tail")
         if self.paged:
@@ -4075,6 +4079,9 @@ class TPUEngine:
             out["kv_row_bytes"] = sum(self.cfg.kv_row_dims) * (
                 self.state["k"].dtype.itemsize if self.state else 0
             )
+            # model.chunk_tiles_on_host, summed over the chunks issued
+            out["prefill_kv_tiles_read"] = self.prefill_kv_tiles_read
+            out["prefill_kv_tiles_mapped"] = self.prefill_kv_tiles_mapped
         if self.slot_states is not None:
             out.update(self.slot_states.stats())
             rows = {"kda": "kda", "mamba2": "mamba"}[self.cfg.state_kind]
@@ -4495,6 +4502,12 @@ class ChunkedPrefill:
                 if eng.slot_states is not None:
                     eng.slot_states.take(self.slot)
                 ops += (eng.allocator.tables[self.slot].copy(),)
+                read, mapped = model.chunk_tiles_on_host(
+                    eng.cfg, self.pos, bucket, eng.allocator.max_blocks,
+                    eng.allocator.page_size,
+                )
+                eng.prefill_kv_tiles_read += read
+                eng.prefill_kv_tiles_mapped += mapped
                 if eng.kv_compress_armed:
                     ops += (np.int32(eng._win_starts[self.slot]),)
             dtok = eng._devprof_note("chunk", (bucket, final))

@@ -276,7 +276,9 @@ def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, k_pool,
     positions = start + jnp.arange(Tc)[None, :]
     rope = _rope(positions, cfg)
     pages, off = model.chunk_pages(table_row, start, Tc, P)
-    kv_tile = model._kv_tile(table_row.shape[0] * P, P)
+    rows = table_row.shape[0] * P  # the slot's table, of which a chunk
+    kv_tile = model._kv_tile(rows, P)  # folds the tiles up to its last row's
+    n_tiles = model.chunk_kv_tiles(start, Tc, rows, kv_tile)
     # the scan carries the slot's own tails, not every slot's (engine/kda.py)
     states, tails = (states[0], slot_tails(states[1], slot)), states[1]
 
@@ -299,12 +301,12 @@ def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, k_pool,
             )
             k_pool = ops.write_rows(k_pool, l, ops.merge_heads(k_new[0]), pages, off)
             v_pool = ops.write_rows(v_pool, l, ops.merge_heads(v_new[0]), pages, off)
-            k_all = ops.gather_pages(k_pool, l, table_row, cfg.head_dim)[None]
-            v_all = ops.gather_pages(v_pool, l, table_row, cfg.head_dim)[None]
             with jax.named_scope("attention"):
                 attn = model.blockwise_cache_attention(
-                    q, k_all.astype(q.dtype), v_all.astype(q.dtype),
-                    positions[0], None, kv_tile,
+                    q,
+                    model.paged_kv_block(k_pool, v_pool, l, table_row, kv_tile,
+                                         cfg.head_dim, q.dtype),
+                    n_tiles, positions[0], None,
                 )
             x = x + model.matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
         return (x, k_pool, v_pool, tuple(states), *stats), None

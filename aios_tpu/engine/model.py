@@ -342,43 +342,42 @@ def gqa_attention(
 
 def blockwise_cache_attention(
     q: jnp.ndarray,  # [1, Tc, H, D]
-    k: jnp.ndarray,  # [1, C, KH, D]
-    v: jnp.ndarray,  # [1, C, KH, D]
+    kv_block,  # j -> (k, v) [block, KH, D] each: key tile j of the view
+    n_blocks,  # tiles 0 .. n_blocks-1 are folded; may be traced
     abs_pos: jnp.ndarray,  # [Tc] absolute position of each query row
     window: Optional[int],
-    block: int = 512,
     live_from: Optional[jnp.ndarray] = None,  # scalar: live window start
     sink: int = 0,  # static sink rows (window+sink KV compression)
-    col0: Optional[jnp.ndarray] = None,  # scalar: position of k[:, 0]
+    col0=0,  # scalar, may be traced: the position of tile 0's first row
 ) -> jnp.ndarray:
-    """Chunk-vs-cache attention via an online softmax over KV blocks.
+    """Chunk-vs-cache attention via an online softmax over KV tiles.
 
-    The [Tc, C] score matrix never materializes: each [Tc, block] tile is
-    folded into running (max, denom, accumulator) stats under ``lax.scan``
-    (the flash recurrence in plain XLA, so it runs on every backend). This
-    is what keeps chunked admission of an 8k prompt from allocating
-    hundreds of MB of fp32 scores per layer. Query row i sees cache col j
-    iff j <= abs_pos[i] (and inside the sliding window) — the row's own
-    K/V was written to the cache before this is called, so the diagonal is
-    always visible and the denominator can't be zero.
+    The [Tc, C] score matrix never materializes, and neither does the
+    slot's view of the pool: ``kv_block(j)`` reads tile j where it lies (a
+    slot's pages gathered from the pool, ``paged_kv_block``; the slot-cache
+    form hands tiles of a view it sliced once) and each [Tc, block] tile is
+    folded into running (max,
+    denom, accumulator) stats under ``lax.fori_loop`` (the flash recurrence
+    in plain XLA, so it runs on every backend). ``n_blocks`` is the caller's
+    ``chunk_kv_tiles``: the loop ends at the tile of the chunk's last row,
+    not at the table's. A tile above it is masked whole and would add exact
+    zeros (p = exp(-1e30 - m) = 0, alpha = 1), so the bounded fold equals
+    the fold over the whole table element for element. Query row i sees
+    cache col j iff j <= abs_pos[i] (and inside the sliding window) — the
+    row's own K/V was written to the cache before this is called, so the
+    diagonal is always visible and the denominator can't be zero.
     """
     B, Tc, H, D = q.shape
-    C = k.shape[1]
-    KH = k.shape[2]
+    block, KH, _ = jax.eval_shape(kv_block, 0)[0].shape
     G = H // KH
     qf = q[0].reshape(Tc, KH, G, D).astype(jnp.float32) / np.sqrt(D)
-    nb = C // block
-    kb = k[0].astype(jnp.float32).reshape(nb, block, KH, D)
-    vb = v[0].astype(jnp.float32).reshape(nb, block, KH, D)
-    colsb = jnp.arange(C).reshape(nb, block)
-    if col0 is not None:
-        # k and v are the rows from position col0 on (a window layer's
-        # chunk gathers from its window's first page, not from row 0)
-        colsb = colsb + col0
 
-    def fold(carry, xs):
+    def fold(j, carry):
         m, l, acc = carry
-        kblk, vblk, cols = xs
+        kblk, vblk = (t.astype(jnp.float32) for t in kv_block(j))
+        # tile 0 starts at position col0 (a window layer's chunk reads from
+        # its window's first page, not from row 0)
+        cols = col0 + j * block + jnp.arange(block)
         s = jnp.einsum("tkgd,ckd->kgtc", qf, kblk)  # [KH, G, Tc, block]
         visible = cols[None, :] <= abs_pos[:, None]  # [Tc, block]
         if window is not None:
@@ -395,17 +394,27 @@ def blockwise_cache_attention(
         alpha = jnp.exp(m - m_new)  # rescale of previous stats
         l = l * alpha + jnp.sum(p, axis=-1)
         acc = acc * alpha[..., None] + jnp.einsum("kgtc,ckd->kgtd", p, vblk)
-        return (m_new, l, acc), None
+        return m_new, l, acc
 
     init = (
         jnp.full((KH, G, Tc), -1e30, jnp.float32),
         jnp.zeros((KH, G, Tc), jnp.float32),
         jnp.zeros((KH, G, Tc, D), jnp.float32),
     )
-    (m, l, acc), _ = jax.lax.scan(fold, init, (kb, vb, colsb))
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, fold, init)
     out = acc / l[..., None]
     # [KH, G, Tc, D] -> [1, Tc, H, D]
     return out.transpose(2, 0, 1, 3).reshape(B, Tc, H, D).astype(q.dtype)
+
+
+def chunk_kv_tiles(start, Tc: int, rows: int, tile: int, col0=0,
+                   minimum=jnp.minimum):
+    """Key tiles of ``tile`` rows a chunk's attention folds, of a view of
+    ``rows`` rows from position ``col0`` on: those up to the tile of the
+    chunk's last row, start + Tc - 1, and no more than the view has (a final
+    bucket's padding may overrun it). ``start`` and ``col0`` traced in a
+    graph; ``minimum=min`` reckons the same count from the host's ints."""
+    return minimum((start + Tc - 1 - col0) // tile + 1, rows // tile)
 
 
 def causal_mask(T: int, window: Optional[int]) -> jnp.ndarray:
@@ -840,14 +849,8 @@ def prefill_chunk(
     cos, sin = rope_tables_of(positions, cfg.head_dim, cfg.rope_of(None))
 
     kv_tile = min(512, C)  # NB: local `block` below would shadow this
-    if C % kv_tile == 0:
-        mask = None  # blockwise online-softmax path; mask built per tile
-
-        def attend(q, k_all, v_all):
-            return blockwise_cache_attention(
-                q, k_all, v_all, positions[0], cfg.sliding_window, kv_tile
-            )
-    else:
+    blockwise = C % kv_tile == 0  # else one mask over the slot's whole view
+    if not blockwise:
         # chunk row i (abs pos start+i) sees cache col j iff j <= start+i
         cols = jnp.arange(C)[None, :]  # [1, C]
         abs_pos = positions[0][:, None]  # [Tc, 1]
@@ -855,9 +858,6 @@ def prefill_chunk(
         if cfg.sliding_window is not None:
             mask = mask & (cols > abs_pos - cfg.sliding_window)
         mask = mask[None]  # [1, Tc, C]
-
-        def attend(q, k_all, v_all):
-            return gqa_attention(q, k_all, v_all, mask)
 
     write_at = (slot, start, jnp.int32(0), jnp.int32(0))
 
@@ -875,16 +875,6 @@ def prefill_chunk(
             v_l = jax.lax.dynamic_update_slice(v_l, vq, write_at)
             k_s = jax.lax.dynamic_update_slice(k_s, ks_new, write_at[:-1])
             v_s = jax.lax.dynamic_update_slice(v_s, vs_new, write_at[:-1])
-            k_all = dequantize_kv(
-                jax.lax.dynamic_slice_in_dim(k_l, slot, 1, axis=0),
-                jax.lax.dynamic_slice_in_dim(k_s, slot, 1, axis=0),
-                q.dtype,
-            )
-            v_all = dequantize_kv(
-                jax.lax.dynamic_slice_in_dim(v_l, slot, 1, axis=0),
-                jax.lax.dynamic_slice_in_dim(v_s, slot, 1, axis=0),
-                q.dtype,
-            )
         else:
             k_l = jax.lax.dynamic_update_slice(
                 k_l, k_new.astype(k_l.dtype), write_at
@@ -892,9 +882,32 @@ def prefill_chunk(
             v_l = jax.lax.dynamic_update_slice(
                 v_l, v_new.astype(v_l.dtype), write_at
             )
-            k_all = jax.lax.dynamic_slice_in_dim(k_l, slot, 1, axis=0)
-            v_all = jax.lax.dynamic_slice_in_dim(v_l, slot, 1, axis=0)
-        attn = attend(q, k_all.astype(q.dtype), v_all.astype(q.dtype))
+
+        def view_of(cache, scale):
+            """The slot's rows [C, KH, D] in q's dtype, sliced out once."""
+            rows = jax.lax.dynamic_index_in_dim(cache, slot, 0, keepdims=False)
+            if not quant_cache:
+                return rows.astype(q.dtype)
+            s = jax.lax.dynamic_index_in_dim(scale, slot, 0, keepdims=False)
+            return dequantize_kv(rows, s, q.dtype)
+
+        k_all, v_all = view_of(k_l, k_s), view_of(v_l, v_s)
+        if blockwise:
+            # the view cast once and every tile of it folded, as before PR 44:
+            # tiles sliced from the carried cache inside a loop that ends at
+            # the chunk's last tile cost a 512-row Mistral chunk 95-102 ms
+            # where this costs 87 (PERF.md section 6, PR 44)
+            k32, v32 = k_all.astype(jnp.float32), v_all.astype(jnp.float32)
+            attn = blockwise_cache_attention(
+                q,
+                lambda j: (
+                    jax.lax.dynamic_slice_in_dim(k32, j * kv_tile, kv_tile),
+                    jax.lax.dynamic_slice_in_dim(v32, j * kv_tile, kv_tile),
+                ),
+                C // kv_tile, positions[0], cfg.sliding_window,
+            )
+        else:
+            attn = gqa_attention(q, k_all[None], v_all[None], mask)
         x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
         x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
         return (x, stats), (k_l, v_l, *((k_s, v_s) if quant_cache else ()))
@@ -1305,21 +1318,23 @@ def prefill_chunk_paged(
     pages (Tc >= P, start page-aligned) or sits inside one page (Tc < P) —
     the rows are written by whole pages or as one slice of a page
     (ops.write_rows), never by an index-array scatter of single rows.
-    Chunk attention gathers the slot's logical view from the pool per layer
-    (a copy, but prefill is compute-bound; the decode hot path reads pages
-    in place via the kernel). The caller must have backed rows
+    Chunk attention makes no view of the slot: its online softmax gathers
+    one key tile at a time from the pool through ``table_row``
+    (``paged_kv_block``) and ends at the tile of the chunk's last row
+    (``chunk_kv_tiles``), so a chunk reads the pages its rows can see and
+    not the table's ``MB x P`` rows. The caller must have backed rows
     [0, start+Tc) — unbacked blocks map the sacrificial page 0, which the
     mask never exposes below ``start+Tc``.
 
-    ``cache_scales`` marks an int8 pool (rows quantize on write, the
-    gathered view dequantizes). Returns (logits [1, Tc, V] fp32, k_pool',
+    ``cache_scales`` marks an int8 pool (rows quantize on write, a
+    gathered tile dequantizes). Returns (logits [1, Tc, V] fp32, k_pool',
     v_pool'[, scales'][, stats]).
 
     ``layout`` (a stack of window and full layers; ``table_row`` is then the
     slot's two tables side by side): a layer writes and reads its kind's
-    pages of its period (engine/paged.py header), a full layer every page
-    of the slot, a window layer the pages from its window's first block to
-    the chunk's end alone.
+    pages of its period (engine/paged.py header), a full layer the slot's
+    pages up to the chunk's end, a window layer those from its window's
+    first block to the chunk's end.
     """
     if cfg.mla:
         from . import latent
@@ -1354,6 +1369,7 @@ def prefill_chunk_paged(
     pages, off = chunk_pages(table_row, start, Tc, P)
 
     kv_tile = _kv_tile(C_log, P)
+    n_tiles = chunk_kv_tiles(start, Tc, C_log, kv_tile)
 
     def block(carry, layer):
         x, k_pool, v_pool, scales, stats = carry
@@ -1367,8 +1383,6 @@ def prefill_chunk_paged(
             v_pool = ops.write_rows(v_pool, l, ops.merge_heads(vq), pages, off)
             k_s = ops.write_rows(k_s, l, ks, pages, off)
             v_s = ops.write_rows(v_s, l, vs, pages, off)
-            k_all = gather_dequant(k_pool, k_s, l, table_row, q.dtype)[None]
-            v_all = gather_dequant(v_pool, v_s, l, table_row, q.dtype)[None]
             scales = (k_s, v_s)
         else:
             k_pool = ops.write_rows(
@@ -1377,15 +1391,13 @@ def prefill_chunk_paged(
             v_pool = ops.write_rows(
                 v_pool, l, ops.merge_heads(v_new[0]), pages, off
             )
-            k_all = ops.gather_pages(k_pool, l, table_row, cfg.head_dim)[None]
-            v_all = ops.gather_pages(v_pool, l, table_row, cfg.head_dim)[None]
         attn = blockwise_cache_attention(
             q,
-            k_all.astype(q.dtype),
-            v_all.astype(q.dtype),
+            paged_kv_block(k_pool, v_pool, l, table_row, kv_tile,
+                           cfg.head_dim, q.dtype, scales),
+            n_tiles,
             positions[0],
             cfg.sliding_window,
-            kv_tile,
             live_from=win_start,
             sink=sink_rows,
         )
@@ -1404,9 +1416,34 @@ def prefill_chunk_paged(
 
 
 def _kv_tile(rows: int, P: int) -> int:
-    """The key tile of a chunk's blockwise attention over ``rows`` rows."""
+    """The key tile of a chunk's blockwise attention over ``rows`` rows of
+    pages of ``P``: whole pages."""
     t = min(512, rows)
-    return t if rows % t == 0 else P
+    return t if rows % t == 0 and t % P == 0 else P
+
+
+def paged_kv_block(k_pool, v_pool, layer, pages, tile: int, head_dim: int,
+                   dtype, scales=()):
+    """``blockwise_cache_attention``'s ``kv_block`` over a slot's pages:
+    tile j is the ``tile // P`` pages ``pages[j * bp:(j + 1) * bp]`` of
+    ``layer``, gathered from the pool where they lie and split to heads
+    [tile, KH, D]. An int8 pool (``scales`` = its (k_s, v_s)) dequantizes
+    the tile it gathered."""
+    bp = tile // k_pool.shape[2]
+
+    def kv_block(j):
+        pg = jax.lax.dynamic_slice_in_dim(pages, j * bp, bp)
+        if scales:
+            return tuple(
+                gather_dequant(pool, s, layer, pg, dtype)
+                for pool, s in zip((k_pool, v_pool), scales)
+            )
+        return tuple(
+            ops.gather_pages(pool, layer, pg, head_dim).astype(dtype)
+            for pool in (k_pool, v_pool)
+        )
+
+    return kv_block
 
 
 def window_chunk_blocks(window: int, Tc: int, P: int) -> int:
@@ -1430,15 +1467,22 @@ def _prefill_chunk_kinds(params, cfg: ModelConfig, x, positions, start,
     # query of this chunk sees is start - W + 1
     nbw = window_chunk_blocks(W, Tc, P)
     first_blk = jnp.maximum(start - W + 1, 0) // P
+
+    def view(pages, col0, rows):
+        """A kind's view of the slot: (its pages, the position of its first
+        row, its key tile, how many of its tiles this chunk folds)."""
+        tile = _kv_tile(rows, P)
+        return pages, col0, tile, chunk_kv_tiles(start, Tc, rows, tile, col0)
+
     reads = {
-        "full": (tables["full"], None, _kv_tile(layout.max_blocks * P, P)),
-        "window": (
+        "full": view(tables["full"], 0, layout.max_blocks * P),
+        "window": view(
             jax.lax.dynamic_slice(
                 jnp.concatenate(
                     [tables["window"], jnp.zeros((nbw,), table_row.dtype)]
                 ), (first_blk,), (nbw,),
             ),
-            first_blk * P, _kv_tile(nbw * P, P),
+            first_blk * P, nbw * P,
         ),
     }
     writes = {k: chunk_pages(tables[k], start, Tc, P) for k in ropes}
@@ -1456,13 +1500,13 @@ def _prefill_chunk_kinds(params, cfg: ModelConfig, x, positions, start,
         v_pool = ops.write_rows(
             v_pool, at, ops.merge_heads(v_new[0]), pages + base, off
         )
-        read, col0, tile = reads[kind]
-        k_all = ops.gather_pages(k_pool, at, read + base, cfg.head_dim)[None]
-        v_all = ops.gather_pages(v_pool, at, read + base, cfg.head_dim)[None]
+        read, col0, tile, n_tiles = reads[kind]
         with jax.named_scope(f"attention_{kind}"):
             attn = blockwise_cache_attention(
-                q, k_all.astype(q.dtype), v_all.astype(q.dtype),
-                positions[0], cfg.window_of(kind), tile, col0=col0,
+                q,
+                paged_kv_block(k_pool, v_pool, at, read + base, tile,
+                               cfg.head_dim, q.dtype),
+                n_tiles, positions[0], cfg.window_of(kind), col0=col0,
             )
         x = x + matmul(attn.reshape(B, Tc, -1), lp["wo"], qmm, "row")
         x, stats = _add_mlp(x, stats, lp, cfg, moe_dense, qmm)
@@ -1473,6 +1517,25 @@ def _prefill_chunk_kinds(params, cfg: ModelConfig, x, positions, start,
         moe_mod.grouped_serves(B * Tc, cfg, moe_dense),
     )
     return (_final_logits(x, params, cfg, qmm), k_pool, v_pool, *stats)
+
+
+def chunk_tiles_on_host(cfg: ModelConfig, start: int, Tc: int,
+                        max_blocks: int, P: int) -> Tuple[int, int]:
+    """(key tiles folded, key tiles the slot's table maps) by one chunk of
+    ``prefill_chunk_paged``, summed over the attention layers whose view is
+    the slot's whole table (every layer of a one-kind stack, the ``full``
+    kind of a stack of two), reckoned from the host's ints where the chunk
+    is issued: the engine's ``prefill_kv_tiles_read`` / ``_mapped``. A window
+    kind's view is already its window and the chunk (``kv_window_pages_*``
+    tell how far that engages), and a latent stack's chunks fold in
+    engine/latent.py: neither counts here."""
+    if cfg.mla:
+        return 0, 0
+    layers = cfg.layers_of("full") if cfg.kinds else cfg.row_layers
+    rows = max_blocks * P
+    tile = _kv_tile(rows, P)
+    return (layers * chunk_kv_tiles(start, Tc, rows, tile, minimum=min),
+            layers * (rows // tile))
 
 
 def write_prompt_rows(pools, rows, table_row, layout=None):

@@ -18,8 +18,10 @@ layer's index and DMAs the pages it needs from where they lie
 (ops/paged_attention.py); every other reader and writer indexes it by
 (layer, page, row) and reshapes only what is small — new rows on the way
 in (ops.merge_heads; a prompt's or a chunk's rows go in by whole pages,
-ops.write_rows), a slot's gathered pages on the way out
-(ops.gather_pages). Nothing slices, reshapes or copies a layer of it. An
+ops.write_rows), gathered pages on the way out (ops.gather_pages: a chunk's
+attention one key tile at a time up to the chunk's last row, the verify
+step and the CPU reference a slot's table). Nothing slices, reshapes or
+copies a layer of it. An
 int8 pool keeps its per-(row, kv head) scales beside it as [L, N, P, KH].
 
 A stack that mixes WINDOW and FULL attention layers (ModelConfig.layer_types)

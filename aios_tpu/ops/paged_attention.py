@@ -22,8 +22,9 @@ layer's pages stands between the scan's carry and the `pallas_call`; kv
 head h of a page is its lanes [h*D, (h+1)*D), for any head size. Everything
 else that reads or writes the pool reshapes only what is small: new rows
 [.., KH, D] -> [.., KH*D] on the way in (`merge_heads`, and `write_rows`
-for a prompt's consecutive rows), a slot's gathered pages back on the way
-out (`gather_pages`). Shapes stay static everywhere —
+for a prompt's consecutive rows), gathered pages back on the way out
+(`gather_pages`: a key tile's pages for a prefill chunk, a slot's for the
+verify step and the CPU reference). Shapes stay static everywhere —
 the table is [B, MAX_BLOCKS] with garbage entries beyond each slot's
 length, never read because the loop bound comes from `lengths`.
 """
@@ -418,7 +419,9 @@ def gather_pages(
     ``layer`` from the stacked pool [L, N, P, KH*D], one per table row
     [..., MB]. Copies the pages the tables name and nothing else — every
     reader but the decode kernel (which reads pages in place) comes through
-    here: chunked prefill, the speculative verify, the CPU reference."""
+    here: a chunked prefill with ONE key tile's slice of the slot's table at
+    a time (model.paged_kv_block), the speculative verify and the CPU
+    reference with whole tables."""
     pages = pool[layer, tables]  # [..., MB, P, KH*D]
     rows = pages.reshape(*tables.shape[:-1], -1, pages.shape[-1])
     return split_heads(rows, head_dim)

@@ -539,10 +539,13 @@ def test_a_stack_of_one_kind_under_yarn_reads_the_table_it_wrote_with(window):
 
 # sha256 (first 16 hex digits) of the lowered text of a paged decode step, a
 # paged chunk and a whole-prompt prefill at the PARENT commit (280ee42), made
-# there by this very function
+# there by this very function; the CHUNK's is PR 44's tree's (its attention
+# folds key tiles read from the pool up to the chunk's last row: a change to
+# every grouped-query chunk, made on purpose; tests/test_chunk_attention_bound.py
+# holds it to the parent's formulation)
 PARENT = {
-    "mistral": ["e4271ea22153727a", "a06c67288b7cc476", "00e78a00d231f314"],
-    "mixtral": ["d03baabe4dc9304f", "75485e1263ab104d", "c4651cfc6953f120"],
+    "mistral": ["e4271ea22153727a", "8b1b931c47ba5ce6", "00e78a00d231f314"],
+    "mixtral": ["d03baabe4dc9304f", "19aee64d03155112", "c4651cfc6953f120"],
 }
 
 

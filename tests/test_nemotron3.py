@@ -512,12 +512,13 @@ def test_the_configuration_s_new_fields_are_checked(fields, words):
 
 # sha256 (first 16 hex digits) of the lowered text of a paged decode step and a
 # paged chunk at the PARENT commit (bc560d3), made there by `_lowered` below
-# under this suite's own conftest (the device count is in the text)
+# under this suite's own conftest (the device count is in the text); the two
+# grouped-query CHUNKS' are PR 44's tree's (tests/test_mellum2.py says why)
 PARENT = {
-    "mixtral": ["e6721efaa0108966", "ce31ace7976b5f7e"],
+    "mixtral": ["e6721efaa0108966", "3fb524e1445269a1"],
     "pangu_ultra_moe": ["5640d1d9f3af8b01", "24b7681bcc06eb75"],
     "xing4": ["f264715ded8e9cad", "0a3869c7abbc7211"],
-    "mellum": ["184a45bb2dc10b44", "5a8905afe7ddd074"],
+    "mellum": ["184a45bb2dc10b44", "1b9f82fa5e4b5f73"],
     "bailing_hybrid": ["ba6750ce973860a9", "0716ba842e818ae1"],
 }
 MIXTRAL = ModelConfig(

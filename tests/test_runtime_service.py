@@ -24,6 +24,24 @@ def runtime_stub():
     server.stop(grace=None)
 
 
+@pytest.fixture()
+def loaded_stub(runtime_stub):
+    """``runtime_stub`` with ``tinyllama-test`` loaded, whichever worker runs
+    the test: xdist's ``--dist load`` may hand this module's tests to several
+    workers, each with its own module fixture, and a test that leaned on
+    ``test_load_model_and_infer`` having run before it then found no model
+    (4 failures in one of PR 44's whole runs). Loading a loaded model again
+    returns the one that is there."""
+    stub, _ = runtime_stub
+    status = stub.LoadModel(
+        runtime_pb2.LoadModelRequest(
+            model_name="tinyllama-test", model_path="synthetic://tiny-test"
+        )
+    )
+    assert status.status == "ready"
+    return runtime_stub
+
+
 def test_no_models_unavailable(runtime_stub):
     stub, _ = runtime_stub
     with pytest.raises(grpc.RpcError) as err:
@@ -62,8 +80,8 @@ def test_load_model_and_infer(runtime_stub):
     assert models.models[0].request_count >= 1
 
 
-def test_operational_level_routes_to_tinyllama(runtime_stub):
-    stub, _ = runtime_stub
+def test_operational_level_routes_to_tinyllama(loaded_stub):
+    stub, _ = loaded_stub
     resp = stub.Infer(
         runtime_pb2.InferRequest(
             prompt="status?", intelligence_level="operational", max_tokens=4
@@ -91,16 +109,16 @@ def test_explicit_unknown_model_not_found(runtime_stub):
     assert err.value.code() == grpc.StatusCode.NOT_FOUND
 
 
-def test_partial_name_matching(runtime_stub):
-    stub, _ = runtime_stub
+def test_partial_name_matching(loaded_stub):
+    stub, _ = loaded_stub
     resp = stub.Infer(
         runtime_pb2.InferRequest(prompt="x", model="TinyLlama", max_tokens=4)
     )
     assert resp.model_used == "tinyllama-test"
 
 
-def test_stream_infer_token_by_token(runtime_stub):
-    stub, _ = runtime_stub
+def test_stream_infer_token_by_token(loaded_stub):
+    stub, _ = loaded_stub
     chunks = list(
         stub.StreamInfer(
             runtime_pb2.InferRequest(prompt="hello", max_tokens=6, temperature=0.0)
@@ -112,8 +130,8 @@ def test_stream_infer_token_by_token(runtime_stub):
     assert len(chunks) >= 2
 
 
-def test_health_reports_models(runtime_stub):
-    stub, _ = runtime_stub
+def test_health_reports_models(loaded_stub):
+    stub, _ = loaded_stub
     h = stub.HealthCheck(common_pb2.Empty())
     assert h.healthy
     assert h.details["backend"] == "jax-tpu"
