@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import kda, model, paged, sampling, spec
+from . import kda, latent, model, paged, sampling, spec
 from .config import ModelConfig
 from .. import backend, faults, ops
 from ..analysis.locks import make_lock
@@ -639,6 +639,23 @@ class TPUEngine:
                     self.params = model.quantize_params(
                         self.params, mode=quantize
                     )
+        # a latent model's per-head matrices, heads-major from here on
+        # (latent.serving_layout): the graphs read no other layout. The
+        # engine keeps no reference to the checkpoint layout's arrays;
+        # they go when the caller drops its tree (LoadModel does)
+        self.latent_leaves_relaid = 0
+        if cfg.mla:
+            t0 = time.monotonic()
+            self.params, self.latent_leaves_relaid = latent.serving_layout(
+                self.params, cfg
+            )
+            if self.latent_leaves_relaid:
+                jax.block_until_ready(self.params)
+                log.info(
+                    "%s: %d latent per-head matrices (w_uq, w_uk, w_uv) "
+                    "re-laid heads-major in %.2f s", cfg.name,
+                    self.latent_leaves_relaid, time.monotonic() - t0,
+                )
 
         # Context-sharded KV: the cache's C axis splits over the mesh's sp
         # axis, so one slot's KV can exceed a single chip's HBM — XLA
@@ -4033,6 +4050,9 @@ class TPUEngine:
             # spans: their wall seconds less this is what the compiling
             # thread stood off the processor (the GIL, I/O)
             "warmup_trace_cpu_seconds": self.warmup_trace_cpu_seconds,
+            # per-head matrices put heads-major at load
+            # (latent.serving_layout); 0 without latent attention
+            "latent_leaves_relaid": self.latent_leaves_relaid,
         }
         # JAX's persistent compile cache, process-wide (the pool reports
         # them once, not summed over replicas): misses = requests - hits

@@ -12,9 +12,15 @@ residual ``x``, ``h = rms(x)``:
 
 The cache holds ``[c | k_r]`` a row a layer, not per-head keys and values
 (engine/paged.py header: two pool arrays, ``[.., kv_lora_rank]`` and the
-rotary part padded to 128 lanes). Serving leaves: ``w_dqkv`` is
-[W_dq | W_dkv] along columns, ``w_uk`` / ``w_uv`` are the two column groups
-of the published W_ukv, each ``[kv_lora_rank, heads * dim]``.
+rotary part padded to 128 lanes). Checkpoint leaves: ``w_dqkv`` is
+[W_dq | W_dkv] along columns, ``w_uq`` ``[q_lora_rank, heads * (nope + rope)]``,
+``w_uk`` / ``w_uv`` the two column groups of the published W_ukv, each
+``[kv_lora_rank, heads * dim]``. The graphs below read the per-head matrices
+HEADS-MAJOR (``serving_layout``, once at load: ``w_uq_nope``
+``[heads, q_lora_rank, nope]``, ``w_uq_rope`` ``[q_lora_rank, heads * rope]``,
+``w_uk`` / ``w_uv`` ``[heads, kv_lora_rank, dim]``): the absorbed form's
+products are batched over the heads, and the TPU compiler otherwise re-lays
+the WEIGHTS heads-major inside every decode dispatch.
 
 Two forms of the same attention:
 
@@ -63,6 +69,7 @@ layers as one more value where the model has a router.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -128,6 +135,90 @@ def _rope_pairs(x, cos, sin):
     ).astype(x.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _lay_heads(tree, heads: int, nope: int):
+    """The checkpoint-layout leaves of a layer tree ({``w_uq`` / ``w_uk`` /
+    ``w_uv``: an int8 leaf or a plain array}) in the serving layout: a column
+    permutation and a transpose of whole blocks, no value changes. An int8
+    leaf's scales [.., 1, heads * d] go the way of its values."""
+
+    def cut(a):  # [.., K, heads * d] -> [.., K, heads, d]
+        return a.reshape(*a.shape[:-1], heads, a.shape[-1] // heads)
+
+    def by_head(a):  # -> [.., heads, K, d]
+        return jnp.moveaxis(cut(a), -2, -3)
+
+    out = {}
+    for name, w in tree.items():
+        if name == "w_uq":
+            out["w_uq_nope"] = jax.tree.map(lambda a: by_head(a)[..., :nope], w)
+            out["w_uq_rope"] = jax.tree.map(
+                lambda a: cut(a)[..., nope:].reshape(*a.shape[:-1], -1), w
+            )
+        else:
+            out[name] = jax.tree.map(by_head, w)
+    return out
+
+
+def _in_checkpoint_layout(name: str, w, cfg: ModelConfig) -> bool:
+    """Whether leaf ``name`` of a layer tree still lies as the checkpoint
+    stores it; False where it is heads-major already. Read from its shape."""
+    if isinstance(w, dict) and "q4" in w:
+        raise ValueError(
+            f"{cfg.name}: {name} is an int4 leaf; the latent block's per-head "
+            "products read int8 leaves and plain arrays only"
+        )
+    if name == "w_uq":
+        return True  # the serving layout has no leaf of this name
+    shape = (w["q"] if isinstance(w, dict) else w).shape
+    rank = cfg.kv_lora_rank
+    d = cfg.v_head_dim if name == "w_uv" else cfg.qk_nope_head_dim
+    if shape[-3:] == (cfg.num_heads, rank, d):
+        return False
+    if shape[-2:] == (rank, cfg.num_heads * d):
+        return True
+    raise ValueError(
+        f"{cfg.name}: {name} {shape} is neither [.., {rank}, "
+        f"{cfg.num_heads} x {d}] nor [.., {cfg.num_heads}, {rank}, {d}]"
+    )
+
+
+def serving_layout(params, cfg: ModelConfig):
+    """``params`` with every latent layer's per-head matrices in the layout
+    the graphs below read (module header), and how many matrices were
+    re-laid: (tree, count). Applied once where the weights reach the device
+    (TPUEngine); a tree already in the serving layout passes through, count
+    0. Int8 leaves and plain arrays alike; scales follow their columns."""
+    relaid = 0
+
+    def walk(tree):
+        nonlocal relaid
+        if not isinstance(tree, dict) or "q" in tree or "q4" in tree:
+            return tree
+        tree = {k: walk(v) for k, v in tree.items()}
+        old = {
+            name: tree[name] for name in ("w_uq", "w_uk", "w_uv")
+            if name in tree and _in_checkpoint_layout(name, tree[name], cfg)
+        }
+        if not old:
+            return tree
+        relaid += len(old)
+        new = _lay_heads(old, cfg.num_heads, cfg.qk_nope_head_dim)
+        return {**{k: v for k, v in tree.items() if k not in old}, **new}
+
+    return walk(params), relaid
+
+
+def _head_rows(spec: str, x, w):
+    """einsum ``spec`` of rows ``x`` with a heads-major leaf ``w`` (int8
+    {"q", "s"} or a plain array) whose OUTPUT columns are the last axis of
+    both the leaf and the result: the int8 values multiplied as they are,
+    float32 accumulation, the per-column scales on the result."""
+    if isinstance(w, dict):
+        return (_einsum32(spec, x, w["q"]) * w["s"][:, 0]).astype(x.dtype)
+    return _einsum32(spec, x, w).astype(x.dtype)
+
+
 def _project(h, lp, cfg: ModelConfig, positions, qmm=None):
     """Normed rows h [B, T, E] -> (q_nope [B,T,H,nope], q_rope [B,T,H,rope]
     rotated, c [B,T,kv_lora_rank] normed, k_r [B,T,rope] rotated)."""
@@ -141,15 +232,18 @@ def _project(h, lp, cfg: ModelConfig, positions, qmm=None):
         down = model.matmul(h, lp["w_dqkv"], qmm)
         if ql:
             cq = model.rms_norm(down[..., :ql], lp["q_a_norm"], eps)
-            q = model.matmul(cq, lp["w_uq"], qmm)
+            q_nope = _head_rows("btk,hkd->bthd", cq, lp["w_uq_nope"])
+            q_rope = model.matmul(cq, lp["w_uq_rope"], qmm)
+            q_rope = q_rope.reshape(B, T, cfg.num_heads, dr)
         else:  # a direct query projection: w_dqkv is [W_q | W_dkv]
             ql = cfg.num_heads * (dn + dr)
-            q = down[..., :ql]
-        q = q.reshape(B, T, cfg.num_heads, dn + dr)
-        if cfg.latent_qk_norm:
+            q = down[..., :ql].reshape(B, T, cfg.num_heads, dn + dr)
+            q_nope, q_rope = q[..., :dn], q[..., dn:]
+        if cfg.latent_qk_norm:  # over a head's nope and rope parts together
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
             q = model.rms_norm(q, lp["q_head_norm"], eps)
-        q_nope = q[..., :dn]
-        q_rope = rotate(q[..., dn:], cos, sin)
+            q_nope, q_rope = q[..., :dn], q[..., dn:]
+        q_rope = rotate(q_rope, cos, sin)
     c = model.rms_norm(down[..., ql:ql + kl], lp["kv_a_norm"], eps)
     k_r = down[..., ql + kl:]
     if cfg.latent_qk_norm:
@@ -166,41 +260,28 @@ def _pad_rope(x, cfg: ModelConfig):
     )
 
 
-def _per_head(w, heads: int):
-    """A column-grouped leaf [K, heads * d] as (values [K, heads, d], scales
-    [heads, d] or None)."""
-    if isinstance(w, dict):
-        q = w["q"]
-        return q.reshape(q.shape[0], heads, -1), w["s"].reshape(heads, -1)
-    return w.reshape(w.shape[0], heads, -1), None
-
-
-def _absorb_q(q_nope, lp, cfg: ModelConfig):
+def _absorb_q(q_nope, lp):
     """q_lat_i = q_nope_i W_uk_i^T: [..., H, nope] -> [..., H, kv_lora_rank].
     An int8 leaf's per-column scales lie on the contracted axis here, so
     they go onto the query first."""
-    w, s = _per_head(lp["w_uk"], cfg.num_heads)
+    w = lp["w_uk"]
     q = q_nope.astype(jnp.float32)
-    if s is not None:
-        q = q * s
-    return _einsum32("...hd,chd->...hc", q, w).astype(q_nope.dtype)
+    if isinstance(w, dict):
+        q, w = q * w["s"][:, 0], w["q"]
+    return _einsum32("...hd,hcd->...hc", q, w).astype(q_nope.dtype)
 
 
-def _unabsorb_o(o_lat, lp, cfg: ModelConfig):
+def _unabsorb_o(o_lat, lp):
     """o_i = o_lat_i W_uv_i: [..., H, kv_lora_rank] -> [..., H * v_head_dim]."""
-    w, s = _per_head(lp["w_uv"], cfg.num_heads)
-    o = _einsum32("...hc,chd->...hd", o_lat, w)
-    if s is not None:
-        o = o * s
-    return o.astype(o_lat.dtype).reshape(*o_lat.shape[:-2], -1)
+    o = _head_rows("...hc,hcd->...hd", o_lat, lp["w_uv"])
+    return o.reshape(*o_lat.shape[:-2], -1)
 
 
-def _expand(c_rows, lp, cfg: ModelConfig, qmm=None):
+def _expand(c_rows, lp):
     """Latent rows [S, kv_lora_rank] -> (k_nope [S, H, nope], v [S, H, v])."""
-    S, H = c_rows.shape[0], cfg.num_heads
     return (
-        model.matmul(c_rows, lp["w_uk"], qmm).reshape(S, H, -1),
-        model.matmul(c_rows, lp["w_uv"], qmm).reshape(S, H, -1),
+        _head_rows("sc,hcd->shd", c_rows, lp["w_uk"]),
+        _head_rows("sc,hcd->shd", c_rows, lp["w_uv"]),
     )
 
 
@@ -242,12 +323,12 @@ def _attend_expanded(q_nope, q_rope, q_pos, kv_block, n_blocks, blk: int,
     return (acc / l[..., None]).transpose(1, 0, 2).astype(q_nope.dtype)
 
 
-def _attend_own_rows(q_nope, q_rope, c, k_r, lp, cfg: ModelConfig, qmm=None):
+def _attend_own_rows(q_nope, q_rope, c, k_r, lp, cfg: ModelConfig):
     """Causal expanded attention of one sequence over its own T rows (the
     whole-prompt prefill): keys and values are expanded once, queries go a
     Q_TILE at a time and each tile stops at its own diagonal block."""
     T = q_nope.shape[0]
-    k_nope, v = _expand(c, lp, cfg, qmm)
+    k_nope, v = _expand(c, lp)
     tiled = T > Q_TILE and T % Q_TILE == 0
     blk = Q_TILE if tiled else T
 
@@ -368,7 +449,7 @@ def forward_with_kv(params, cfg: ModelConfig, tokens, attn_fn=None,
         with jax.named_scope("attention"):
             attn = jax.vmap(
                 lambda qn, qr, c1, kr1: _attend_own_rows(
-                    qn, qr, c1, kr1, lp, cfg, qmm
+                    qn, qr, c1, kr1, lp, cfg
                 )
             )(q_nope, q_rope, c, k_r)
         x, aux, new = _finish_block(
@@ -466,7 +547,7 @@ def prefill_chunk_paged(params, cfg: ModelConfig, tokens, start, c_pool,
             def kv_block(j):
                 c_blk = jax.lax.dynamic_slice_in_dim(c_all, j * blk, blk)
                 r_blk = jax.lax.dynamic_slice_in_dim(r_all, j * blk, blk)
-                k_nope, v = _expand(c_blk.astype(h.dtype), lp, cfg, qmm)
+                k_nope, v = _expand(c_blk.astype(h.dtype), lp)
                 return k_nope, r_blk[:, :dr].astype(h.dtype), v
 
             attn = _attend_expanded(
@@ -541,7 +622,7 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
         l = _pool_layer(lp, l)
         q_nope, q_rope, c, k_r = _project(h, lp, cfg, lengths[:, None], qmm)
         with jax.named_scope("mla_q"):
-            q_lat = _absorb_q(q_nope[:, 0], lp, cfg)
+            q_lat = _absorb_q(q_nope[:, 0], lp)
         with jax.named_scope("mla_kv_write"):
             c_pool = c_pool.at[l, pages, offs].set(c[:, 0].astype(c_pool.dtype))
             r_pool = r_pool.at[l, pages, offs].set(
@@ -553,7 +634,7 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
                 tables, rows, sm_scale=sm_scale(cfg),
             )
         with jax.named_scope("mla_out"):
-            attn = _unabsorb_o(o_lat, lp, cfg)[:, None]
+            attn = _unabsorb_o(o_lat, lp)[:, None]
         x, _, new = _finish_block(
             x, mix, attn, lp, cfg, moe_dense, qmm, live=active, gate_rows=h
         )
@@ -614,12 +695,12 @@ def verify_step_paged(params, cfg: ModelConfig, tokens, lengths, c_pool,
             _pad_rope(k_r, cfg).astype(r_pool.dtype)
         )
         o_lat = jax.lax.map(slot_attend, (
-            _absorb_q(q_nope, lp, cfg), _pad_rope(q_rope, cfg),
+            _absorb_q(q_nope, lp), _pad_rope(q_rope, cfg),
             c_pool[l, tables].reshape(B, C, -1).astype(h.dtype),
             r_pool[l, tables].reshape(B, C, -1).astype(h.dtype), qpos,
         ))
         x, _, new = _finish_block(
-            x, mix, _unabsorb_o(o_lat, lp, cfg), lp, cfg, moe_dense, qmm,
+            x, mix, _unabsorb_o(o_lat, lp), lp, cfg, moe_dense, qmm,
             gate_rows=h,
         )
         return (x, c_pool, r_pool, *model.add_stats(stats, new)), None

@@ -65,7 +65,9 @@ LOGIT_TOL = 0.03
 
 @pytest.fixture(scope="module")
 def params():
-    return A.build_params(D, SEED)
+    """The benchmark's tree (the checkpoint layout), converted once as the
+    engine converts it at load: every graph reads the serving layout."""
+    return latent.serving_layout(A.build_params(D, SEED), CFG)[0]
 
 
 def _ids(n, seed=0):
@@ -188,7 +190,7 @@ def test_absorbed_decode_matches_expanded_attention(params):
     h = jax.random.normal(jax.random.PRNGKey(3), (1, n, CFG.hidden_size), jnp.bfloat16)
     pos = jnp.arange(n)[None]
     q_nope, q_rope, c, k_r = latent._project(h, lp, CFG, pos)
-    k_nope, v = latent._expand(c[0], lp, CFG)
+    k_nope, v = latent._expand(c[0], lp)
 
     def whole(j):
         return k_nope, k_r[0], v
@@ -196,7 +198,7 @@ def test_absorbed_decode_matches_expanded_attention(params):
     expanded = latent._attend_expanded(
         q_nope[0, -1:], q_rope[0, -1:], jnp.asarray([n - 1]), whole, 1, n,
         latent.sm_scale(CFG), CFG.v_head_dim)[0].reshape(-1)
-    q_lat = latent._absorb_q(q_nope[0, -1], lp, CFG)  # [H, Dc]
+    q_lat = latent._absorb_q(q_nope[0, -1], lp)  # [H, Dc]
 
     f32 = jnp.float32
     s = (jnp.einsum("hc,sc->hs", q_lat.astype(f32), c[0].astype(f32))
@@ -205,13 +207,105 @@ def test_absorbed_decode_matches_expanded_attention(params):
     p = _bf(jax.nn.softmax(s, axis=-1))
 
     def finish(o_lat):
-        return np.asarray(latent._unabsorb_o(o_lat.astype(c.dtype), lp, CFG), f32)
+        return np.asarray(latent._unabsorb_o(o_lat.astype(c.dtype), lp), f32)
 
     want = np.asarray(expanded, f32)
     tol = 0.01 * np.abs(want).max()
     assert np.abs(finish(jnp.einsum("hs,sc->hc", p, c[0].astype(f32))) - want).max() < tol
     low = finish(_weighted_sum_bf16(p[:, None, :], c[0].astype(f32)[None, None])[:, 0])
     assert np.abs(low - want).max() > tol
+
+
+# (heads, nope, rope, v, q_rank, kv_rank): the Pangu-like model of this file
+# and a Xing4-like one (more heads, wider ranks, a value head narrower than a key's)
+LAYOUT_SHAPES = {"pangu-like": (4, 16, 8, 16, 24, 16), "xing4-like": (8, 16, 8, 8, 48, 32)}
+
+
+def _checkpoint_tree(cfg, int8, key):
+    """lead_layers (1 layer) and layers (2) holding what `_project` reads, in the
+    checkpoint layout: int8 leaves {"q", "s"} or plain float32 arrays."""
+    H, dn, dr, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ql, kl, E = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.hidden_size
+    shapes = {"w_dqkv": (E, ql + kl + dr), "w_uq": (ql, H * (dn + dr)),
+              "w_uk": (kl, H * dn), "w_uv": (kl, H * dv)}
+
+    def stack(L, key):
+        out = {"q_a_norm": 1 + 0.1 * jax.random.normal(key, (L, ql)),
+               "kv_a_norm": 1 + 0.1 * jax.random.normal(key, (L, kl))}
+        for i, (name, (K, N)) in enumerate(shapes.items()):
+            kq, ks = jax.random.split(jax.random.fold_in(key, i))
+            if int8:
+                out[name] = {
+                    "q": jax.random.randint(kq, (L, K, N), -127, 128, jnp.int8),
+                    "s": 0.002 * (0.5 + jax.random.uniform(ks, (L, 1, N)))}
+            else:
+                out[name] = 0.2 * jax.random.normal(kq, (L, K, N))
+        return out
+
+    return {"lead_layers": stack(1, jax.random.fold_in(key, 100)),
+            "layers": stack(2, jax.random.fold_in(key, 200))}
+
+
+def _plain(w):
+    return w["q"].astype(jnp.float32) * w["s"] if isinstance(w, dict) else w
+
+
+@pytest.mark.parametrize("leaves", ["int8", "plain"])
+@pytest.mark.parametrize("shape", sorted(LAYOUT_SHAPES))
+def test_serving_layout_is_exact_and_idempotent(shape, leaves):
+    """`serving_layout` permutes columns and transposes whole blocks: over the
+    converted tree `_project`'s four results, `_absorb_q`, `_unabsorb_o` and
+    `_expand` are the checkpoint layout's products written out in plain
+    jax.numpy (`cq @ w_uq` reshaped and cut, a per-head einsum), to float32
+    round-off (float32 rows in: the two sides differ in where an int8 leaf's
+    scales are applied and in the order of the sums; 1e-6 of the largest value
+    read, held to 2e-5 of it). Converting twice is converting once."""
+    H, dn, dr, dv, ql, kl = LAYOUT_SHAPES[shape]
+    cfg = dataclasses.replace(
+        CFG, num_heads=H, qk_nope_head_dim=dn, qk_rope_head_dim=dr, v_head_dim=dv,
+        q_lora_rank=ql, kv_lora_rank=kl)
+    raw = _checkpoint_tree(cfg, leaves == "int8", jax.random.PRNGKey(7))
+    laid, relaid = latent.serving_layout(raw, cfg)
+    assert relaid == 6  # w_uq, w_uk, w_uv of both layer trees
+    assert "w_uq" not in laid["layers"] and "w_uq" in raw["layers"]
+    nope = laid["layers"]["w_uq_nope"]
+    assert (nope["q"] if leaves == "int8" else nope).shape == (2, H, ql, dn)
+    again, second = latent.serving_layout(laid, cfg)
+    assert second == 0
+    assert all(a is b for a, b in zip(jax.tree.leaves(laid), jax.tree.leaves(again)))
+
+    def close(got, want):
+        want = np.asarray(want, np.float32)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=0,
+                                   atol=2e-5 * np.abs(want).max())
+
+    B, T, eps = 2, 5, cfg.rms_norm_eps
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    h = jax.random.normal(ks[0], (B, T, cfg.hidden_size))
+    pos = jnp.broadcast_to(jnp.arange(T), (B, T))
+    cos, sin = latent.rope_tables(pos, cfg)
+    for seg, l in (("lead_layers", 0), ("layers", 1)):
+        lp = jax.tree.map(lambda a: a[l], laid[seg])
+        w = {k: _plain(jax.tree.map(lambda a: a[l], raw[seg][k]))
+             for k in ("w_dqkv", "w_uq", "w_uk", "w_uv")}
+        down = h @ w["w_dqkv"]
+        cq = model.rms_norm(down[..., :ql], raw[seg]["q_a_norm"][l], eps)
+        q = (cq @ w["w_uq"]).reshape(B, T, H, dn + dr)
+        c = model.rms_norm(down[..., ql:ql + kl], raw[seg]["kv_a_norm"][l], eps)
+        want = (q[..., :dn], model.apply_rope(q[..., dn:], cos, sin), c,
+                model.apply_rope(down[..., None, ql + kl:], cos, sin)[:, :, 0])
+        got = latent._project(h, lp, cfg, pos)
+        for g, x in zip(got, want):
+            close(g, x)
+        q_lat = jnp.einsum("bthd,chd->bthc", want[0], w["w_uk"].reshape(kl, H, dn))
+        close(latent._absorb_q(got[0], lp), q_lat)
+        o_lat = jax.random.normal(ks[1], (B, H, kl))
+        close(latent._unabsorb_o(o_lat, lp),
+              jnp.einsum("bhc,chd->bhd", o_lat, w["w_uv"].reshape(kl, H, dv)).reshape(B, -1))
+        rows = jax.random.normal(ks[2], (7, kl))
+        k_nope, v = latent._expand(rows, lp)
+        close(k_nope, (rows @ w["w_uk"]).reshape(7, H, dn))
+        close(v, (rows @ w["w_uv"]).reshape(7, H, dv))
 
 
 @pytest.mark.parametrize("pages_per_iter", [1, 2, 4])
@@ -403,6 +497,48 @@ def test_engine_serves_through_prefix_cache_and_counts_picks(params):
         assert gaps[decided[0][rows]].max() < 2 * LOGIT_TOL
     finally:
         eng.close()
+
+
+def test_engine_lays_the_checkpoint_tree_out_at_load_and_counts_it():
+    """The benchmark's tree as it is made (the checkpoint layout) through
+    TPUEngine: the engine converts it, says how many matrices it re-laid
+    (`latent_leaves_relaid`; none for a tree that is laid out already, which is
+    how a scale-up's engine gets replica 0's, and none for a grouped-query
+    model), and the greedy tokens it decodes are `forward_with_kv`'s on the
+    converted tree: each lies within the two forms' rounding (absorbed against
+    expanded, LOGIT_TOL) of that forward's best."""
+    from aios_tpu.engine.config import TINY_TEST
+    from aios_tpu.engine.engine import TPUEngine
+
+    raw = A.build_params(D, SEED)
+    eng = _engine(raw)
+    try:
+        assert eng.stats()["latent_leaves_relaid"] == 6
+        assert "w_uq" not in eng.params["layers"] and "w_uq" in raw["layers"]
+        prompt = _ids(40, 5)
+        served = eng.generate(prompt, max_new_tokens=8, temperature=0.0)
+        laid, _ = latent.serving_layout(raw, CFG)
+        seq = prompt + served
+        logits = np.asarray(model.forward_full(
+            laid, CFG, jnp.asarray([seq]), kernels=False))[0]
+        rows = logits[len(prompt) - 1:len(seq) - 1]
+        gaps = rows.max(-1) - rows[np.arange(len(served)), served]
+        assert gaps.max() < LOGIT_TOL
+        assert (gaps == 0).sum() >= len(served) - 1
+        again = _engine(eng.params)
+        try:
+            assert again.stats()["latent_leaves_relaid"] == 0
+            assert again.generate(prompt, max_new_tokens=8, temperature=0.0) == served
+        finally:
+            again.close()
+    finally:
+        eng.close()
+    plain = TPUEngine(TINY_TEST, model.init_params(TINY_TEST, jax.random.PRNGKey(0)),
+                      num_slots=2, max_context=64)
+    try:
+        assert plain.stats()["latent_leaves_relaid"] == 0
+    finally:
+        plain.close()
 
 
 def test_speculation_is_refused_for_a_latent_pool(params):

@@ -77,7 +77,8 @@ LOGIT_TOL = 0.25
 
 @pytest.fixture(scope="module")
 def params():
-    return A.build_params(D, SEED)
+    # converted once, as the engine converts it at load (latent.serving_layout)
+    return latent.serving_layout(A.build_params(D, SEED), CFG)[0]
 
 
 def _dense(tree):
@@ -463,10 +464,14 @@ def test_the_configuration_s_new_fields_are_checked(fields, words):
 # sha256 (first 16 hex digits) of the lowered text of a paged decode step and a
 # paged chunk at the PARENT commit (f58dabb), made there by `_lowered` below
 # under this suite's own conftest (the device count is in the text); the
-# grouped-query CHUNK's (mellum) is PR 44's tree's (tests/test_mellum2.py says why)
+# grouped-query CHUNK's (mellum) is PR 44's tree's (tests/test_mellum2.py says why),
+# and the two latent models' are PR 46's tree's, over the tree the engine lays
+# out at load (latent.serving_layout: the per-head matrices heads-major, the
+# products written over them; tests/test_latent.py holds those to the
+# checkpoint layout's products)
 PARENT = {
-    "pangu_ultra_moe": ["5640d1d9f3af8b01", "24b7681bcc06eb75"],
-    "xing4": ["f264715ded8e9cad", "0a3869c7abbc7211"],
+    "pangu_ultra_moe": ["5ec7cd5da090e555", "2ec10ad65d0f7a6b"],
+    "xing4": ["bbcd5bba40fe2099", "37850bb33a4385fa"],
     "mellum": ["184a45bb2dc10b44", "1b9f82fa5e4b5f73"],
 }
 PANGU = dict(
@@ -539,7 +544,7 @@ def test_the_models_before_this_one_lower_to_the_graphs_they_had(name, monkeypat
         arch = _arch(name)
         tiny = PANGU if name == "pangu_ultra_moe" else XING
         cfg = ModelConfig(**arch.model_fields(tiny, 128))
-        texts = _lowered(cfg, jax.eval_shape(
-            lambda: arch.build_params(arch.dims_of(tiny), 1)))
+        texts = _lowered(cfg, jax.eval_shape(lambda: latent.serving_layout(
+            arch.build_params(arch.dims_of(tiny), 1), cfg)[0]))
     assert [hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts] == PARENT[name]
     assert all("kda" not in t for t in texts)

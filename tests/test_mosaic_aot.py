@@ -262,6 +262,16 @@ def _hlo_results(text):
         ]
 
 
+def _hlo_copies(text, dtype):
+    """Element count of every ``dtype`` array a `copy` of the module makes."""
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is not None and m["op"] == "copy":
+            for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]", m["type"]):
+                if dt == dtype:
+                    yield int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
 def sds(rep, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
 
@@ -410,10 +420,12 @@ def test_aot_mistral_serving_graphs_compile_and_fit(
 
 def _bench_model(config_name, arch_file, context):
     """(program ModelConfig, shapes of the benchmark's serving tree) of a
-    committed configuration, built as `benchmark/harness/manager.py` does."""
+    committed configuration, built as `benchmark/harness/manager.py` does and,
+    with latent attention, laid out as the engine lays it at load."""
     import json
     import sys
 
+    from aios_tpu.engine import latent
     from aios_tpu.engine.config import ModelConfig
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -426,7 +438,12 @@ def _bench_model(config_name, arch_file, context):
     with open(os.path.join(root, "benchmark", "configs", config_name)) as fh:
         config = json.load(fh)
     cfg = ModelConfig(**arch.model_fields(config, context))
-    return cfg, jax.eval_shape(lambda: arch.build_params(arch.dims_of(config), 1))
+
+    def build():
+        params = arch.build_params(arch.dims_of(config), 1)
+        return latent.serving_layout(params, cfg)[0] if cfg.mla else params
+
+    return cfg, jax.eval_shape(build)
 
 
 # --- the latent-attention configuration of the benchmark (PR 27) -----------
@@ -485,6 +502,70 @@ def test_aot_latent_serving_graphs_compile_and_fit(rep_sharding, monkeypatch):
         live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
         assert live < 15.75e9, f"{name}: {live / 1e9:.2f} GB live"
+
+
+@pytest.mark.parametrize("config_name, arch_file, context, slots", [
+    ("openpangu-ultra-moe-int8-ep16-d5.json", "pangu_ultra_moe.py", 16384, 32),
+    ("xing4-29b-a4b-int8-d13.json", "xing4.py", 8192, 16),
+])
+def test_aot_latent_graphs_relay_no_per_head_matrix(
+    rep_sharding, monkeypatch, config_name, arch_file, context, slots
+):
+    """The latent block's per-head matrices lie heads-major from load
+    (latent.serving_layout, PR 46): neither the decode dispatch as the engine
+    builds it (a scan over `engine.DECODE_STEPS` steps, where a re-laid weight
+    is hoisted to one copy of the whole stack) nor a chunk of 64 / 128 / 256 /
+    512 rows copies anything with the element count of a layer's, or of a
+    stack's, `w_uq` (whole, or its nope or rope part), `w_uk` or `w_uv` (int8
+    results alone: a chunk's float32 accumulator `[128,512,128]` has `w_uk`'s
+    count). In the checkpoint layout the Pangu dispatch copied `s8[4,1536,24576]`,
+    `s8[1,1536,24576]`, `s8[4,512,16384]` and two `s8[512,128,128]` a layer
+    step, the Xing4 one `s8[12,768,6144]` and two `s8[12,512,4096]`."""
+    from aios_tpu import backend
+    from aios_tpu.engine import engine as E
+    from aios_tpu.engine import model as M
+
+    cfg, shapes = _bench_model(config_name, arch_file, context)
+    assert shapes["layers"]["w_uk"]["q"].dtype == jnp.int8
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    rep = rep_sharding
+    params = jax.tree.map(lambda a: sds(rep, a.shape, a.dtype), shapes)
+    blocks = context // 128
+    pages = (slots + 1) * blocks + 1
+    pools = tuple(sds(rep, (cfg.num_layers, pages, 128, w), jnp.bfloat16)
+                  for w in cfg.kv_row_dims)
+    i32 = lambda *shape: sds(rep, shape, jnp.int32)  # noqa: E731
+    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    layer = {cfg.q_lora_rank * H * (dn + dr), cfg.q_lora_rank * H * dn,
+             cfg.q_lora_rank * H * dr, cfg.kv_lora_rank * H * dn,
+             cfg.kv_lora_rank * H * cfg.v_head_dim}
+    depths = {jax.tree.leaves(seg)[0].shape[0] for seg in M.layer_segments(shapes)}
+    per_head = {n * elems for n in depths | {1} for elems in layer}
+
+    def dispatch(p, c, r, toks, lens, tables):
+        def one(carry, _):
+            toks, lens, c, r = carry
+            logits, c, r, *_ = M.decode_step_paged(
+                p, cfg, toks, lens, c, r, tables, kernels=True)
+            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (toks, lens + 1, c, r), toks
+
+        (_, _, c, r), toks = jax.lax.scan(
+            one, (toks, lens, c, r), None, length=E.DECODE_STEPS)
+        return toks, c, r
+
+    def chunk(p, c, r, toks, start, row):
+        return M.prefill_chunk_paged(p, cfg, toks, start, c, r, row)
+
+    graphs = {"decode-dispatch": (dispatch, (params, *pools, i32(slots), i32(slots),
+                                             i32(slots, blocks)))}
+    for t in (64, 128, 256, 512):
+        graphs[f"chunk-{t}"] = (chunk, (params, *pools, i32(1, t), i32(),
+                                        i32(blocks)))
+    for name, (fn, args) in graphs.items():
+        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+        copied = [n for n in _hlo_copies(compiled.as_text(), "s8") if n in per_head]
+        assert copied == [], f"{name}: re-lays a per-head matrix: {copied[:4]}"
 
 
 # --- the grouped expert path reads the stacked experts in place (PR 28) -----

@@ -29,7 +29,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import test_ling3 as ling3  # noqa: E402
 
-from aios_tpu.engine import mamba2, model, moe, paged  # noqa: E402
+from aios_tpu.engine import latent, mamba2, model, moe, paged  # noqa: E402
 from aios_tpu.engine.batching import ContinuousBatcher  # noqa: E402
 from aios_tpu.engine.config import ModelConfig  # noqa: E402
 from aios_tpu.engine.engine import TPUEngine, refuse_for_state_kind  # noqa: E402
@@ -513,13 +513,15 @@ def test_the_configuration_s_new_fields_are_checked(fields, words):
 # sha256 (first 16 hex digits) of the lowered text of a paged decode step and a
 # paged chunk at the PARENT commit (bc560d3), made there by `_lowered` below
 # under this suite's own conftest (the device count is in the text); the two
-# grouped-query CHUNKS' are PR 44's tree's (tests/test_mellum2.py says why)
+# grouped-query CHUNKS' are PR 44's tree's (tests/test_mellum2.py says why) and
+# the three latent models' PR 46's, over the tree the engine lays out at load
+# (tests/test_ling3.py says why)
 PARENT = {
     "mixtral": ["e6721efaa0108966", "3fb524e1445269a1"],
-    "pangu_ultra_moe": ["5640d1d9f3af8b01", "24b7681bcc06eb75"],
-    "xing4": ["f264715ded8e9cad", "0a3869c7abbc7211"],
+    "pangu_ultra_moe": ["5ec7cd5da090e555", "2ec10ad65d0f7a6b"],
+    "xing4": ["bbcd5bba40fe2099", "37850bb33a4385fa"],
     "mellum": ["184a45bb2dc10b44", "1b9f82fa5e4b5f73"],
-    "bailing_hybrid": ["ba6750ce973860a9", "0716ba842e818ae1"],
+    "bailing_hybrid": ["26fb444b3905063a", "d9ffba9f39abd430"],
 }
 MIXTRAL = ModelConfig(
     name="tiny-mixtral", vocab_size=512, hidden_size=64, intermediate_size=128,
@@ -576,8 +578,8 @@ def lowered_hashes(name):
         arch = _arch(name)
         tiny = {"pangu_ultra_moe": PANGU, "xing4": XING, "bailing_hybrid": LING}[name]
         cfg = ModelConfig(**arch.model_fields(tiny, 128))
-        texts = _lowered(cfg, jax.eval_shape(
-            lambda: arch.build_params(arch.dims_of(tiny), 1)))
+        texts = _lowered(cfg, jax.eval_shape(lambda: latent.serving_layout(
+            arch.build_params(arch.dims_of(tiny), 1), cfg)[0]))
     return [hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts], texts
 
 

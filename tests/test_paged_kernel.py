@@ -362,7 +362,8 @@ def test_the_live_mask_reaches_only_the_graphs_that_use_it(monkeypatch):
         rms_norm_eps=1e-5, max_position_embeddings=128,
         assumed={"served_name": "tiny-pangu"})
     pangu = ModelConfig(**arch.model_fields(tiny, 128))
-    pangu_shapes = jax.eval_shape(lambda: arch.build_params(arch.dims_of(tiny), 1))
+    pangu_shapes = jax.eval_shape(lambda: latent.serving_layout(
+        arch.build_params(arch.dims_of(tiny), 1), pangu)[0])
     mistral = dataclasses.replace(TINY_TEST, num_layers=3)
     mixtral = dataclasses.replace(TINY_MOE, num_layers=3)
 

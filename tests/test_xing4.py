@@ -64,7 +64,8 @@ LOGIT_TOL = 0.04
 
 @pytest.fixture(scope="module")
 def params():
-    return A.build_params(D, SEED)
+    # converted once, as the engine converts it at load (latent.serving_layout)
+    return latent.serving_layout(A.build_params(D, SEED), CFG)[0]
 
 
 def _ids(n, seed=0):
@@ -270,8 +271,9 @@ def _pangu_tiny():
         rms_norm_eps=1e-5, max_position_embeddings=128,
         assumed={"served_name": "tiny-pangu"})
     d = arch.dims_of(tiny)
-    return ModelConfig(**arch.model_fields(tiny, 128)), jax.eval_shape(
-        lambda: arch.build_params(d, 1))
+    cfg = ModelConfig(**arch.model_fields(tiny, 128))
+    return cfg, jax.eval_shape(
+        lambda: latent.serving_layout(arch.build_params(d, 1), cfg)[0])
 
 
 def _graph_texts(cfg, shapes):
@@ -316,7 +318,8 @@ def test_a_one_row_residual_lowers_to_the_graphs_it_had(monkeypatch):
     assert all("hc_" not in text for text in _graph_texts(mixtral, mshapes))
 
     # a scope lives in an operation's name stack, which the jaxpr prints
-    streams = jax.eval_shape(lambda: A.build_params(D, 1))
+    streams = jax.eval_shape(
+        lambda: latent.serving_layout(A.build_params(D, 1), CFG)[0])
     jaxpr = str(jax.make_jaxpr(
         lambda p, t: model.forward_full(p, CFG, t, kernels=False))(
         streams, jax.ShapeDtypeStruct((1, 16), jnp.int32)).jaxpr.pretty_print(
